@@ -64,6 +64,12 @@ def init(num_nodes: int = 1,
     import os as _os
     if not kwargs.get("address") and _os.environ.get("RAY_TPU_ADDRESS"):
         kwargs["address"] = _os.environ["RAY_TPU_ADDRESS"]
+    if _os.environ.get("JAX_PLATFORMS", "").lower() != "cpu":
+        # the driver is the process that owns the chip, and what it
+        # compiles there is worth keeping; a process that asked for the
+        # CPU (tests, workers) persists nothing
+        from ray_tpu._private.platform import enable_compile_cache
+        enable_compile_cache()
     return _worker.init_runtime(
         num_nodes=num_nodes, resources_per_node=resources,
         object_store_memory=object_store_memory, namespace=namespace,

@@ -4,12 +4,16 @@ Reference: `python/ray/_private/accelerators/tpu.py:15-58` (GKE/GCE
 metadata, TPU_VISIBLE_CHIPS, pod topology env vars) and
 `util/accelerators/tpu.py` pod helpers. Detection here is env-var and
 jax-based; cloud metadata endpoints are stubbed (zero-egress image).
+Asking jax initializes the backend, and a backend that cannot
+initialize raises: "no TPU" is an answer only a working backend gives.
 """
 
 from __future__ import annotations
 
 import os
 from typing import Dict, List, Optional
+
+from ray_tpu._private.platform import chip_devices
 
 TPU_VISIBLE_CHIPS_ENV = "TPU_VISIBLE_CHIPS"
 TPU_ACCELERATOR_TYPE_ENV = "TPU_ACCELERATOR_TYPE"   # e.g. "v5p-64"
@@ -22,11 +26,7 @@ def detect_tpu_chips() -> int:
     visible = os.environ.get(TPU_VISIBLE_CHIPS_ENV)
     if visible:
         return len([c for c in visible.split(",") if c.strip()])
-    try:
-        import jax
-        return len([d for d in jax.devices() if d.platform == "tpu"])
-    except Exception:
-        return 0
+    return len(chip_devices())
 
 
 def get_accelerator_type() -> Optional[str]:
@@ -34,14 +34,10 @@ def get_accelerator_type() -> Optional[str]:
     env = os.environ.get(TPU_ACCELERATOR_TYPE_ENV)
     if env:
         return env
-    try:
-        import jax
-        tpus = [d for d in jax.devices() if d.platform == "tpu"]
-        if tpus:
-            kind = tpus[0].device_kind.lower().replace(" ", "")
-            return f"{kind}-{len(tpus)}"
-    except Exception:
-        pass
+    tpus = chip_devices()
+    if tpus:
+        kind = tpus[0].device_kind.lower().replace(" ", "")
+        return f"{kind}-{len(tpus)}"
     return None
 
 

@@ -195,9 +195,6 @@ FLAG_DEFS = [
          "execution pool (the dispatch loop feeds admitted tasks to "
          "this sized pool instead of spawning per task); 0 = the "
          "node's max_worker_threads (256)"),
-    # -- bench --
-    Flag("bench_total_deadline", int, 540, "bench.py total wall-clock "
-         "budget (seconds)"),
     # -- sanitizers (SURVEY §5.2: the reference's TSAN-in-CI role) --
     Flag("lock_sanitizer", bool, False, "track runtime lock acquisition "
          "order and warn on inversion cycles (potential deadlocks); "

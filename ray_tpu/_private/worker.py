@@ -333,14 +333,13 @@ class Runtime:
     # ------------------------------------------------------------------
     @staticmethod
     def _detect_resources() -> Dict[str, float]:
+        # the driver owns the chip: a backend that cannot initialize
+        # raises here instead of registering a node with no TPU
+        from ray_tpu._private.platform import chip_devices
         res: Dict[str, float] = {"CPU": float(os.cpu_count() or 1)}
-        try:
-            import jax
-            chips = [d for d in jax.devices() if d.platform != "cpu"]
-            if chips:
-                res["TPU"] = float(len(chips))
-        except Exception:
-            pass
+        chips = chip_devices()
+        if chips:
+            res["TPU"] = float(len(chips))
         return res
 
     def add_node(self, resources: Dict[str, float],

@@ -49,6 +49,10 @@ class _JobSupervisor:
         self._proc: Optional[subprocess.Popen] = None
         self._lock = threading.Lock()
         env = dict(os.environ)
+        # a chip has one owner, and it is not a child of this cluster's
+        # processes: the entrypoint gets the CPU unless its runtime_env
+        # names a platform itself
+        env["JAX_PLATFORMS"] = "cpu"
         for k, v in (runtime_env or {}).get("env_vars", {}).items():
             env[k] = str(v)
         self._env = env

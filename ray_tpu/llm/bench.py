@@ -16,7 +16,17 @@ from __future__ import annotations
 
 import os
 import time
-from typing import Optional
+
+
+def _require_chip(dev, tiny_cpu: bool) -> None:
+    """The model is chosen by the caller's explicit ``tiny_cpu``, never
+    by what the platform turns out to be: a full-size run that finds no
+    chip fails instead of quietly measuring the debug model."""
+    from ray_tpu._private.platform import on_chip
+    if not tiny_cpu and not on_chip(dev):
+        raise RuntimeError(
+            f"serving bench needs a TPU; platform is {dev.platform!r} "
+            f"(tiny_cpu=True is the explicit CPU smoke)")
 
 
 def _percentile(vals, q: float) -> float:
@@ -27,9 +37,21 @@ def _percentile(vals, q: float) -> float:
     return float(np.percentile(vals, q, method="nearest"))
 
 
-def serving_section(report: dict) -> dict:
+def serving_section(report: dict, tiny_cpu: bool = False) -> dict:
     """Flatten a loadgen report into the stable ``serving.*`` keys the
-    BENCH json publishes (the driver greps these across rounds)."""
+    BENCH json publishes (the driver greps these across rounds). From a
+    CPU smoke only the counts come back: no rate or latency of a CPU
+    run goes out under a serving metric's name."""
+    counts = {
+        "offered_rate": report["spec"]["rate"],
+        "arrival": report["spec"]["arrival"],
+        "clients": report["spec"]["clients"],
+        "completed": report["requests"]["completed"],
+        "errors": report["requests"]["errors"],
+        "open_loop": True,
+    }
+    if tiny_cpu:
+        return counts
     good = report.get("goodput", {})
     return {
         "requests_per_second": report["requests_per_second"],
@@ -43,16 +65,11 @@ def serving_section(report: dict) -> dict:
                                                 0.0),
         "goodput_fraction": good.get("fraction", 0.0),
         "slo": good.get("slo", {}),
-        "offered_rate": report["spec"]["rate"],
-        "arrival": report["spec"]["arrival"],
-        "clients": report["spec"]["clients"],
-        "completed": report["requests"]["completed"],
-        "errors": report["requests"]["errors"],
-        "open_loop": True,
+        **counts,
     }
 
 
-def run_serving_bench(error: Optional[str] = None) -> dict:
+def run_serving_bench(tiny_cpu: bool = False) -> dict:
     """Open-loop serving bench through the full Serve data plane:
     handle -> depth-aware P2C router -> replica -> engine, measured at
     the client (streaming chunks, so TTFT is real)."""
@@ -65,9 +82,9 @@ def run_serving_bench(error: Optional[str] = None) -> dict:
     from ray_tpu.models.llama import LlamaConfig
 
     dev = jax.devices()[0]
-    on_tpu = dev.platform != "cpu"
+    _require_chip(dev, tiny_cpu)
 
-    if on_tpu:
+    if not tiny_cpu:
         model_cfg = LlamaConfig.bench_400m(max_seq_len=1024)
         if os.environ.get("BENCH_DECODE"):   # "pallas" = paged kernel
             import dataclasses
@@ -85,7 +102,7 @@ def run_serving_bench(error: Optional[str] = None) -> dict:
         # uniform:32:256 prompt can land in — a cold bucket pays XLA
         # compile inside the timed window
         warm_lens = (32, 64, 128, 256)
-    else:  # CPU smoke path (debug model, small burst)
+    else:  # debug model, small burst
         model_cfg = None    # LLMServer debug config
         replicas, max_slots, max_seq = 2, 4, 128
         spec = LoadSpec(rate=12.0, duration_s=2.5, clients=8,
@@ -114,42 +131,35 @@ def run_serving_bench(error: Optional[str] = None) -> dict:
 
     report = run_load(HandleTarget(handle, stream=True,
                                    timeout_s=spec.timeout_s), spec)
-    engine_stats = {}
-    try:
-        engine_stats = ray_tpu.get(reps[0].handle_request.remote(
-            "stats", (), {}), timeout=30)
-    except Exception:
-        pass
+    engine_stats = ray_tpu.get(reps[0].handle_request.remote(
+        "stats", (), {}), timeout=30)
     serve.shutdown()
     if own:
         ray_tpu.shutdown()
 
-    serving = serving_section(report)
+    serving = serving_section(report, tiny_cpu)
     serving["replicas"] = replicas
-    out = {
+    return {
         "metric": "llm_serve_requests_per_second",
-        "value": serving["requests_per_second"],
+        "value": serving.get("requests_per_second"),
         "unit": "req/s",
         # No published reference serving numbers (BASELINE.md) — report
         # p50 TTFT (seconds) as the comparable headline alongside req/s.
-        "vs_baseline": round(serving["ttft_p50_s"], 4),
+        "vs_baseline": (None if tiny_cpu
+                        else round(serving["ttft_p50_s"], 4)),
         "serving": serving,
         "detail": {
-            **report,
+            **({"spec": report["spec"]} if tiny_cpu else report),
             "max_slots": max_slots,
-            "config": "llama_400m" if on_tpu else "debug",
-            "device": getattr(dev, "device_kind", dev.platform),
+            "config": "debug" if tiny_cpu else "llama_400m",
+            "device": dev.device_kind,
             "engine_stats": engine_stats,
         },
         "platform": dev.platform,
-        "tpu_fallback": not on_tpu,
     }
-    if error:
-        out["error"] = error
-    return out
 
 
-def run_http_proxy_bench(error: Optional[str] = None) -> dict:
+def run_http_proxy_bench(tiny_cpu: bool = False) -> dict:
     """Proxy-level serving bench: p50 TTFT + output tok/s measured AT
     THE HTTP CLIENT through the asyncio ingress + Serve data plane +
     engine — the full serving path the reference drives
@@ -168,8 +178,8 @@ def run_http_proxy_bench(error: Optional[str] = None) -> dict:
     from ray_tpu.models.llama import LlamaConfig
 
     dev = jax.devices()[0]
-    on_tpu = dev.platform != "cpu"
-    if on_tpu:
+    _require_chip(dev, tiny_cpu)
+    if not tiny_cpu:
         model_cfg = LlamaConfig.bench_400m(max_seq_len=1024)
         n_requests, concurrency, max_tokens = 64, 16, 64
         prompt_len = 64
@@ -182,7 +192,7 @@ def run_http_proxy_bench(error: Optional[str] = None) -> dict:
     if own:
         ray_tpu.init(num_nodes=1, resources={"CPU": 8})
     cfg = LLMConfig(model_config=model_cfg, max_slots=16,
-                    max_seq=(1024 if on_tpu else 128))
+                    max_seq=(128 if tiny_cpu else 1024))
     serve.run(build_llm_app(cfg))
     port = serve.start_http_proxy(port=0, max_ongoing_requests=256)
 
@@ -256,27 +266,28 @@ def run_http_proxy_bench(error: Optional[str] = None) -> dict:
 
     ttfts = sorted(t for t, _ in results.values() if t is not None)
     total_tokens = sum(n for _, n in results.values())
-    out = {
-        "metric": "llm_serve_http_output_tokens_per_sec",
-        "value": round(total_tokens / wall, 1) if wall else 0.0,
-        "unit": "tokens/s",
-        "vs_baseline": round(_percentile(ttfts, 50), 4),
-        "detail": {
-            "ttft_p50_ms": round(_percentile(ttfts, 50) * 1e3, 2),
-            "ttft_p90_ms": round(_percentile(ttfts, 90) * 1e3, 2),
-            "requests": n_requests,
-            "concurrency": concurrency,
-            "output_tokens": total_tokens,
-            "wall_s": round(wall, 3),
-            "plane": "asyncio-http-proxy",
-            "device": getattr(dev, "device_kind", dev.platform),
-        },
-        "platform": dev.platform,
-        "tpu_fallback": not on_tpu,
+    detail = {
+        "requests": n_requests,
+        "concurrency": concurrency,
+        "output_tokens": total_tokens,
+        "plane": "asyncio-http-proxy",
+        "device": dev.device_kind,
     }
+    value = vs_baseline = None           # device rates: chip only
+    if not tiny_cpu:
+        value = round(total_tokens / wall, 1)
+        vs_baseline = round(_percentile(ttfts, 50), 4)
+        detail.update(ttft_p50_ms=round(_percentile(ttfts, 50) * 1e3, 2),
+                      ttft_p90_ms=round(_percentile(ttfts, 90) * 1e3, 2),
+                      wall_s=round(wall, 3))
     serve.shutdown()
     if own:
         ray_tpu.shutdown()
-    if error:
-        out["error"] = error
-    return out
+    return {
+        "metric": "llm_serve_http_output_tokens_per_sec",
+        "value": value,
+        "unit": "tokens/s",
+        "vs_baseline": vs_baseline,
+        "detail": detail,
+        "platform": dev.platform,
+    }

@@ -46,6 +46,11 @@ from ray_tpu.llm.paged_cache import (BlockPool, SlotAllocation,
                                      seal_prompt_blocks)
 
 
+class EngineDeadError(RuntimeError):
+    """The engine loop died (``__cause__`` is what killed it): every
+    request it held has failed and it accepts no more."""
+
+
 @dataclasses.dataclass
 class SamplingParams:
     max_tokens: int = 64
@@ -69,6 +74,7 @@ class Request:
         self.finished_at: Optional[float] = None
         self.done = threading.Event()
         self.finish_reason: Optional[str] = None
+        self.error: Optional[BaseException] = None
         self.preemptions = 0
 
     @property
@@ -84,12 +90,28 @@ class Request:
         return self.prompt + self.output
 
     def iter_tokens(self):
-        """Stream tokens as they are generated."""
+        """Stream tokens as they are generated; raises if the engine
+        died under the request."""
         while True:
             tok = self.stream.get()
             if tok is None:
+                self.raise_if_failed()
                 return
             yield tok
+
+    def fail(self, err: BaseException) -> None:
+        if self.done.is_set():
+            return
+        self.error = err
+        self.finish_reason = "error"
+        self.finished_at = time.perf_counter()
+        self.stream.put(None)
+        self.done.set()
+
+    def raise_if_failed(self) -> None:
+        if self.error is not None:
+            raise EngineDeadError(
+                f"engine loop died: {self.error!r}") from self.error
 
 
 class ContinuousBatchingEngine:
@@ -149,8 +171,12 @@ class ContinuousBatchingEngine:
                                num_blocks, np.int32)
         self._admit_order: List[int] = []   # oldest-first slot ids
         self.waiting: "deque[Request]" = deque()
+        # popped from ``waiting`` but not yet in a slot: where a failed
+        # prefill finds the requests it was carrying
+        self._admitting: List[Request] = []
         self._lock = threading.Lock()
         self._rng_key = jax.random.key(0)
+        self.error: Optional[BaseException] = None   # set once, by run_forever
 
         # jitted programs ------------------------------------------------
         self._decode = jax.jit(model.decode_step_paged,
@@ -229,6 +255,9 @@ class ContinuousBatchingEngine:
         # deque.append is atomic — submitters never contend on the
         # engine-step lock (a step can span a whole prefill+decode)
         self.waiting.append(req)
+        if self.error is not None:
+            # the loop died: its sweep may have run before this append
+            self._fail_all(self.error)
         return req
 
     def has_work(self) -> bool:
@@ -261,6 +290,7 @@ class ContinuousBatchingEngine:
         chunked_group: List = []
         while free and self.waiting:
             req = self.waiting.popleft()
+            self._admitting.append(req)
             toks = req.cache_tokens()
             n = len(toks)
             never_fits = ((n + 1 + self.block_size - 1)
@@ -312,6 +342,7 @@ class ContinuousBatchingEngine:
             self._admit_chunked(slot, req, alloc, shared_tok)
         for bucket, group in by_bucket.items():
             self._admit_bucket(bucket, group)
+        self._admitting.clear()
 
     def _pad_pow2(self, n: int, cap: int) -> int:
         p = 1
@@ -617,7 +648,30 @@ class ContinuousBatchingEngine:
 
     def run_forever(self, stop_event: threading.Event,
                     idle_sleep_s: float = 0.002) -> None:
-        """Background engine loop (used by the serving integration)."""
-        while not stop_event.is_set():
-            if self.step() == 0 and not self.waiting:
-                time.sleep(idle_sleep_s)
+        """Background engine loop (used by the serving integration).
+        A step that raises (a program that does not compile, device
+        OOM) ends the loop — the donated pool is gone — but never
+        silently: every held and waiting request fails with the cause,
+        later submits fail at once, and the thread dies with the
+        traceback."""
+        try:
+            while not stop_event.is_set():
+                if self.step() == 0 and not self.waiting:
+                    time.sleep(idle_sleep_s)
+        except Exception as err:
+            self.error = err
+            self._fail_all(err)
+            raise
+
+    def _fail_all(self, err: BaseException) -> None:
+        for req in self._admitting:
+            req.fail(err)
+        for slot, req in enumerate(self.slots):
+            if req is not None:
+                self.slots[slot] = None
+                req.fail(err)
+        while self.waiting:
+            try:
+                self.waiting.popleft().fail(err)
+            except IndexError:      # a concurrent sweep drained it
+                break

@@ -18,6 +18,9 @@ from ray_tpu.llm.engine import (ContinuousBatchingEngine, SamplingParams)
 from ray_tpu.llm.tokenizer import ByteTokenizer, load_tokenizer
 
 
+REQUEST_TIMEOUT_S = 300.0    # a unary request's whole generation
+
+
 @dataclasses.dataclass
 class LLMConfig:
     model_id: str = "llama-debug"
@@ -47,7 +50,9 @@ class LLMServer:
         cfg = config.model_config or LlamaConfig.debug(
             vocab_size=512, max_seq_len=config.max_seq)
         self.model = LlamaModel(cfg)
-        params = self.model.init(jax.random.key(config.seed))
+        # one program, not one per tensor: eager init compiles ~30 small
+        # programs and holds each tensor twice (normal, then scaled)
+        params = jax.jit(self.model.init)(jax.random.key(config.seed))
         self.tokenizer = (load_tokenizer(config.tokenizer)
                           if config.tokenizer else ByteTokenizer())
         self.engine = ContinuousBatchingEngine(
@@ -97,7 +102,11 @@ class LLMServer:
             return self.stream(request)
         ids, sampling = self._parse(request)
         req = self.engine.submit(ids, sampling)
-        req.done.wait(timeout=300)
+        if not req.done.wait(timeout=REQUEST_TIMEOUT_S):
+            raise TimeoutError(
+                f"request {req.id} not finished after "
+                f"{REQUEST_TIMEOUT_S:.0f}s ({len(req.output)} tokens)")
+        req.raise_if_failed()
         text = self.tokenizer.decode(req.output)
         return {
             "id": f"cmpl-{req.id}",

@@ -222,8 +222,6 @@ class LlamaModel:
             return table[tokens]
         from jax.sharding import PartitionSpec as P
 
-        from ray_tpu.parallel.mesh import shard_map_compat
-
         present = set(mesh.shape.keys())
         sp = mesh.shape.get("sp", 1)
         # decode steps carry T=1 (or odd prefill lengths): only shard the
@@ -247,10 +245,10 @@ class LlamaModel:
                 table_local.dtype)
             return jax.lax.psum(out, "tp")
 
-        fn = shard_map_compat(
-            lookup, mesh,
-            (P("tp", fsdp_ax), P(dp_ax, seq_ax)),
-            P(dp_ax, seq_ax, fsdp_ax))
+        fn = jax.shard_map(
+            lookup, mesh=mesh,
+            in_specs=(P("tp", fsdp_ax), P(dp_ax, seq_ax)),
+            out_specs=P(dp_ax, seq_ax, fsdp_ax), check_vma=False)
         return fn(table, tokens)
 
     def _attention(self, q, k, v, positions):

@@ -19,19 +19,9 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 
+from ray_tpu._private.platform import on_chip, pallas_interpret
+
 NEG_INF = -1e30
-
-
-def axis_size(axis_name) -> int:
-    """Static size of a bound mesh axis, across jax versions:
-    ``jax.lax.axis_size`` only exists in newer releases, and on older
-    ones ``jax.core.axis_frame`` returns either the size itself or a
-    frame object carrying it."""
-    fn = getattr(jax.lax, "axis_size", None)
-    if fn is not None:
-        return fn(axis_name)
-    frame = jax.core.axis_frame(axis_name)
-    return frame if isinstance(frame, int) else frame.size
 
 
 def _repeat_kv(k: jax.Array, num_q_heads: int) -> jax.Array:
@@ -188,31 +178,6 @@ def _flash_fwd_kernel(q_ref, k_ref, v_ref, o_ref, acc_ref, m_ref, l_ref, *,
         o_ref[0] = (acc_ref[...] / l).astype(o_ref.dtype)
 
 
-def _interpret_default() -> bool:
-    """Pallas kernels only compile for TPU; elsewhere (CPU test meshes)
-    run the SAME kernel under the Pallas interpreter so tests exercise the
-    real kernel logic."""
-    try:
-        return jax.devices()[0].platform != "tpu"
-    except Exception:  # pragma: no cover - no backend at all
-        return True
-
-
-def _compiler_params(**kw):
-    """Pallas-TPU compiler params across the TPUCompilerParams ->
-    CompilerParams rename; a clear error beats a NoneType call when a
-    jax release exposes neither name."""
-    from jax.experimental.pallas import tpu as pltpu
-    cls = getattr(pltpu, "CompilerParams",
-                  getattr(pltpu, "TPUCompilerParams", None))
-    if cls is None:
-        raise RuntimeError(
-            f"jax {jax.__version__}: pallas.tpu exposes neither "
-            f"CompilerParams nor TPUCompilerParams; flash attention "
-            f"needs a supported jax release")
-    return cls(**kw)
-
-
 @functools.partial(jax.jit,
                    static_argnames=("causal", "block_q", "block_k",
                                     "interpret"))
@@ -259,7 +224,7 @@ def _flash_forward(q, k, v, *, causal: bool, block_q: int, block_k: int,
             pltpu.VMEM((bq, 128), jnp.float32),
             pltpu.VMEM((bq, 128), jnp.float32),
         ],
-        compiler_params=_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(qt, kt, vt)
@@ -272,14 +237,14 @@ def flash_attention(q, k, v, causal: bool = True, block_q: int = 128,
     """Pallas TPU flash attention. O(S) memory forward; backward recomputes
     blockwise (remat scan), so training memory stays O(S·block) too."""
     if interpret is None:
-        interpret = _interpret_default()
+        interpret = pallas_interpret()
     return _flash_forward(q, k, v, causal=causal, block_q=block_q,
                           block_k=block_k, interpret=interpret)
 
 
 def _flash_fwd_rule(q, k, v, causal, block_q, block_k, interpret):
     if interpret is None:
-        interpret = _interpret_default()
+        interpret = pallas_interpret()
     out = _flash_forward(q, k, v, causal=causal, block_q=block_q,
                          block_k=block_k, interpret=interpret)
     return out, (q, k, v)
@@ -296,13 +261,6 @@ def _flash_bwd_rule(causal, block_q, block_k, interpret, res, g):
 flash_attention.defvjp(_flash_fwd_rule, _flash_bwd_rule)
 
 
-def _on_tpu() -> bool:
-    try:
-        return jax.devices()[0].platform == "tpu"
-    except Exception:  # pragma: no cover - no backend at all
-        return False
-
-
 def attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
               causal: bool = True,
               positions_q: Optional[jax.Array] = None,
@@ -312,7 +270,7 @@ def attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
     reference otherwise. Explicit position vectors force the reference path
     (the kernel assumes contiguous 0..S-1 positions)."""
     if use_flash is None:
-        use_flash = (_on_tpu() and positions_q is None and positions_k is None
+        use_flash = (on_chip() and positions_q is None and positions_k is None
                      and q.shape[-1] % 128 == 0 and q.shape[1] >= 128)
     if use_flash:
         return flash_attention(q, k, v, causal)

@@ -83,8 +83,6 @@ def expert_alltoall_ffn(h, router, e_gate, e_up, e_down, mesh, *,
     """
     from jax.sharding import PartitionSpec as P
 
-    from ray_tpu.parallel.mesh import shard_map_compat
-
     ep = mesh.shape.get(axis_name, 1)
 
     def body(x, rtr, eg, eu, ed):
@@ -123,7 +121,8 @@ def expert_alltoall_ffn(h, router, e_gate, e_up, e_down, mesh, *,
     x_spec = P(batch_axes or None, seq_axes or None, None)
     w_spec = P(axis_name if axis_name in present else None, None, None)
     aux_spec = P(batch_axes + seq_axes or None)
-    fn = shard_map_compat(
-        body, mesh, (x_spec, P(None, None), w_spec, w_spec, w_spec),
-        (x_spec, aux_spec))
+    fn = jax.shard_map(
+        body, mesh=mesh,
+        in_specs=(x_spec, P(None, None), w_spec, w_spec, w_spec),
+        out_specs=(x_spec, aux_spec), check_vma=False)
     return fn(h, router, e_gate, e_up, e_down)

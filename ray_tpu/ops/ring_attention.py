@@ -20,7 +20,7 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 
-from ray_tpu.ops.attention import NEG_INF, _repeat_kv, axis_size
+from ray_tpu.ops.attention import NEG_INF, _repeat_kv
 
 
 def _block_attn(q, k, v, q_offset, k_offset, scale, causal):
@@ -56,7 +56,7 @@ def ring_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
     k = _repeat_kv(k, q.shape[-2])
     v = _repeat_kv(v, q.shape[-2])
 
-    sp = axis_size(axis_name)
+    sp = jax.lax.axis_size(axis_name)
     idx = jax.lax.axis_index(axis_name)
     chunk = q.shape[1]
     q_offset = idx * chunk
@@ -103,10 +103,9 @@ def ring_attention_sharded(q, k, v, mesh, *, axis_name: str = "sp",
     """Convenience wrapper: shard_map ring_attention over ``mesh``."""
     from jax.sharding import PartitionSpec as P
 
-    from ray_tpu.parallel.mesh import shard_map_compat
-
     spec = P(batch_axes, axis_name, head_axis, None)
     ring = functools.partial(ring_attention, axis_name=axis_name,
                              causal=causal)
-    fn = shard_map_compat(ring, mesh, (spec, spec, spec), spec)
+    fn = jax.shard_map(ring, mesh=mesh, in_specs=(spec, spec, spec),
+                       out_specs=spec, check_vma=False)
     return fn(q, k, v)

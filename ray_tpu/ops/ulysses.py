@@ -39,10 +39,9 @@ def ulysses_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
     [B, S_local, H|H_kv, D]. Requires H % sp == 0 (and H_kv % sp == 0, so
     grouped-query K/V are repeated up to H first when needed).
     """
-    from ray_tpu.ops.attention import (_repeat_kv, axis_size,
-                                       blockwise_attention)
+    from ray_tpu.ops.attention import _repeat_kv, blockwise_attention
 
-    sp = axis_size(axis_name)
+    sp = jax.lax.axis_size(axis_name)
     heads = q.shape[2]
     if sp == 1:
         k = _repeat_kv(k, heads)
@@ -87,10 +86,9 @@ def ulysses_attention_sharded(q, k, v, mesh, *, axis_name: str = "sp",
     (mirror of ``ring_attention_sharded``)."""
     from jax.sharding import PartitionSpec as P
 
-    from ray_tpu.parallel.mesh import shard_map_compat
-
     spec = P(batch_axes, axis_name, head_axis, None)
     fn = functools.partial(ulysses_attention, axis_name=axis_name,
                            causal=causal)
-    wrapped = shard_map_compat(fn, mesh, (spec, spec, spec), spec)
+    wrapped = jax.shard_map(fn, mesh=mesh, in_specs=(spec, spec, spec),
+                            out_specs=spec, check_vma=False)
     return wrapped(q, k, v)

@@ -96,22 +96,6 @@ def mesh_from_string(desc: str, devices: Optional[Sequence] = None) -> Mesh:
     return build_mesh(MeshSpec(**kwargs), devices)
 
 
-def shard_map_compat(fn, mesh, in_specs, out_specs):
-    """shard_map across jax versions: import location and the replication-
-    check kwarg (check_vma vs check_rep) both moved; every SPMD module
-    shares this one compat seam."""
-    try:
-        from jax import shard_map
-    except ImportError:  # older jax
-        from jax.experimental.shard_map import shard_map
-    try:
-        return shard_map(fn, mesh=mesh, in_specs=in_specs,
-                         out_specs=out_specs, check_vma=False)
-    except TypeError:  # older jax spells it check_rep
-        return shard_map(fn, mesh=mesh, in_specs=in_specs,
-                         out_specs=out_specs, check_rep=False)
-
-
 # ---------------------------------------------------------------------------
 # Logical axis rules: map tensor-dimension names to mesh axes.
 # ---------------------------------------------------------------------------

@@ -99,8 +99,6 @@ def pipelined(stage_fn: Callable,
         axes (e.g. Megatron tp) — inside the shard_map the stage_fn sees
         its local shard and owns the matching collectives.
     """
-    from ray_tpu.parallel.mesh import shard_map_compat
-
     num_stages = mesh.shape[axis_name]
 
     def in_params_spec(leaf_ndim):
@@ -121,10 +119,9 @@ def pipelined(stage_fn: Callable,
         # microbatch the (locally sharded) batch dim
         mb = batch.reshape((num_microbatches, -1) + batch.shape[1:])
         mb_spec = P(None, batch_axes, *([None] * (batch.ndim - 1)))
-        out = shard_map_compat(
-            inner, mesh,
-            (p_specs, mb_spec),
-            mb_spec,
+        out = jax.shard_map(
+            inner, mesh=mesh, in_specs=(p_specs, mb_spec),
+            out_specs=mb_spec, check_vma=False,
         )(stacked_params, mb)
         return out.reshape((-1,) + out.shape[2:])
 

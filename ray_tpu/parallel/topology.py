@@ -244,32 +244,43 @@ class TpuTopologyManager:
         return out
 
 
+# jax ``device_kind`` -> generation key of TPU_GENERATIONS; only kinds
+# read off a real client belong here
+DEVICE_KIND_GENERATION = {
+    "TPU v5 lite": "v5e",
+}
+
+
 def detect_local_topology() -> Optional[TpuTopology]:
-    """Best-effort topology detection from the JAX runtime / env vars.
+    """The slice this process sees: from ``TPU_ACCELERATOR_TYPE`` +
+    ``TPU_TOPOLOGY`` when both are set, else from the JAX client — the
+    generation from ``device_kind`` and the box from the chips' own
+    coordinates. None on a CPU backend, and for fewer chips than one
+    host block (a one-chip v5e has no sub-slice to hand out). A device
+    kind or an env value this module does not know raises.
 
     Parity with the detection duties of the reference's
-    ``_private/accelerators/tpu.py`` (env vars + metadata) — here the JAX
-    client is the authority when present.
+    ``_private/accelerators/tpu.py`` (env vars + metadata).
     """
     import os
+
+    from ray_tpu._private.platform import chip_devices
 
     env_type = os.environ.get("TPU_ACCELERATOR_TYPE")  # e.g. "v5p-64"
     env_topo = os.environ.get("TPU_TOPOLOGY")  # e.g. "4x4x4"
     if env_type and env_topo:
-        gen = env_type.split("-")[0]
-        try:
-            return TpuTopology(gen, env_topo)
-        except ValueError:
-            pass
-    try:
-        import jax
-        devs = [d for d in jax.devices() if d.platform != "cpu"]
-        if not devs:
-            return None
-        n = len(devs)
-        # Single-host fallback: model as a flat 2D slice.
-        if n in (1, 4, 8):
-            return TpuTopology("v5e", f"{max(n // 2, 1)}x{min(n, 2)}")
-    except Exception:
+        return TpuTopology(env_type.split("-")[0], env_topo)
+    devs = chip_devices()
+    if not devs:
         return None
-    return None
+    kind = devs[0].device_kind
+    if kind not in DEVICE_KIND_GENERATION:
+        raise ValueError(
+            f"unknown TPU device_kind {kind!r}; known: "
+            f"{sorted(DEVICE_KIND_GENERATION)}")
+    gen = DEVICE_KIND_GENERATION[kind]
+    _, host_block, ndims = TPU_GENERATIONS[gen]
+    dims = tuple(max(d.coords[i] for d in devs) + 1 for i in range(ndims))
+    if any(d % hb for d, hb in zip(dims, host_block)):
+        return None
+    return TpuTopology(gen, "x".join(map(str, dims)))

@@ -78,7 +78,7 @@ def make_train_step(model, optimizer: Optional[optax.GradientTransformation]
                          param_shardings=p_sh, batch_sharding=batch_sh)
 
     step_fn = jax.jit(step, donate_argnums=(0, 1) if donate else ())
-    return TrainStep(step_fn=step_fn, init_fn=init_fn, mesh=None,
+    return TrainStep(step_fn=step_fn, init_fn=jax.jit(init_fn), mesh=None,
                      param_shardings=None, batch_sharding=None)
 
 

@@ -124,8 +124,17 @@ def test_byte_tokenizer_roundtrip():
     assert tok.decode(ids) == "hello TPU"
 
 
-def test_llm_serve_app(ray_start_regular):
+@pytest.mark.filterwarnings(      # the loop thread dies loudly, by design
+    "ignore::pytest.PytestUnhandledThreadExceptionWarning")
+def test_llm_serve_app_then_a_dead_engine(ray_start_regular):
+    """The app answers through the handle; then a step that raises (on
+    the chip: a program that does not compile) must not leave callers
+    waiting on a thread that is gone — the request it carried fails with
+    the cause, later ones fail at once, and both surface through the
+    Serve handle as exceptions in bounded time."""
+    import ray_tpu
     from ray_tpu import serve
+    from ray_tpu._private import worker
     try:
         app = build_llm_app(LLMConfig(max_slots=2, max_seq=128))
         handle = serve.run(app)
@@ -136,6 +145,31 @@ def test_llm_serve_app(ray_start_regular):
         assert isinstance(out["text"], str)
         stats = handle.stats.remote().result()
         assert stats["requests"] == 1
+
+        controller = ray_tpu.get_actor("serve_controller")
+        rep = ray_tpu.get(controller.get_replicas.remote(
+            "llama-debug"))["replicas"][0]
+        server = worker.global_runtime()._actor_executors[
+            rep._actor_id].instance._callable
+
+        def broken(*args, **kwargs):
+            raise RuntimeError("kernel does not lower")
+
+        server.engine._prefill = broken
+        t0 = time.monotonic()
+        with pytest.raises(Exception, match="kernel does not lower"):
+            handle.remote({"prompt": "x" * 20, "max_tokens": 4}).result(
+                timeout=60)
+        server._thread.join(10)
+        assert not server._thread.is_alive()
+        with pytest.raises(Exception, match="engine loop died"):
+            list(handle.options(stream=True).remote(
+                {"prompt": "abc", "max_tokens": 4, "stream": True}))
+        with pytest.raises(Exception, match="engine loop died"):
+            handle.remote({"prompt": "abc", "max_tokens": 4}).result(
+                timeout=60)
+        assert time.monotonic() - t0 < 30
+        assert not server.engine.waiting
     finally:
         serve.shutdown()
 

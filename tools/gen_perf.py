@@ -1,8 +1,10 @@
-"""Generate PERF.md: the committed core-op perf envelope (VERDICT r2 #7).
+"""Generate the host-side core-op envelope kept in PERF.md section 3.
 
 Runs `_private/perf.py` in both execution modes (in-process virtual
 nodes, and real head+daemon OS processes) in fresh subprocesses and
-renders one markdown table. Usage: `python tools/gen_perf.py > PERF.md`.
+renders one markdown table. These are HOST rates (control plane, CPU):
+paste the output under "Control plane" in PERF.md; device metrics never
+come from here. Usage: `python tools/gen_perf.py`.
 """
 
 from __future__ import annotations
@@ -72,7 +74,7 @@ def main() -> int:
 
     env_names = [n for n in rows["in-process"] if is_envelope(n)]
     names = [n for n in rows["in-process"] if n not in env_names]
-    print("# PERF — core-op envelope (committed record)")
+    print("### Control plane: core-op envelope (host rates, CPU)")
     print()
     print(f"Recorded {time.strftime('%Y-%m-%d')} on "
           f"{os.cpu_count()} CPUs ({platform.machine()}), "
@@ -80,7 +82,7 @@ def main() -> int:
           f"Harness: `ray_tpu/_private/perf.py` "
           f"(reference: `python/ray/_private/ray_perf.py:95`, envelope "
           f"targets `release/benchmarks/README.md:25-31`). Regenerate "
-          f"with `python tools/gen_perf.py > PERF.md`.")
+          f"with `python tools/gen_perf.py`.")
     print()
     print("| benchmark | in-process | daemons (wire protocol) |")
     print("|---|---|---|")
