@@ -1,0 +1,16 @@
+"""Device time of the decode program per call (``XLA Modules`` events of
+``jit_decode_step_paged``) in the traced part of the window."""
+
+from benchmark.lib import readers
+
+PROGRAM = "decode_step_paged"
+
+LAYER = "Model step"
+UNIT = "ms"
+BETTER = "lower"
+SOURCE = "device_trace"
+MOVES = "serve_out_tokens_per_s"
+
+
+def read(rec):
+    return readers.program_ms_per_call(rec, PROGRAM)

@@ -1,0 +1,13 @@
+"""1 - union of device-op intervals over the traced window."""
+
+from benchmark.lib import readers
+
+LAYER = "Device"
+UNIT = "%"
+BETTER = "lower"
+SOURCE = "device_trace"
+MOVES = "train_tokens_per_s_chip"
+
+
+def read(rec):
+    return readers.device_idle_share(rec)
