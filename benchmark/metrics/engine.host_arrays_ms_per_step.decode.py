@@ -1,0 +1,15 @@
+"""Phase ``engine.host_arrays`` (the decode step's three numpy arrays, five
+host-to-device arrays and the key split)
+per decode step: ``t_host_arrays_s`` / ``decode_steps``."""
+
+from benchmark.lib import engine_phases
+
+LAYER = "Engine scheduler"
+UNIT = "ms"
+BETTER = "lower"
+SOURCE = "program_counter"
+MOVES = "serve_out_tokens_per_s"
+
+
+def read(rec):
+    return engine_phases.ms_per_step(rec, "t_host_arrays_s")

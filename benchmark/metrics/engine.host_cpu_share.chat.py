@@ -1,0 +1,15 @@
+"""The engine thread's own CPU time (``cpu_host_s``, ``time.thread_time``) over
+the wall time of the four host phases: near 100 the loop's Python fills the
+gap between decode programs, well under it the thread waited for the GIL."""
+
+from benchmark.lib import engine_phases
+
+LAYER = "Engine scheduler"
+UNIT = "%"
+BETTER = "higher"
+SOURCE = "program_counter"
+MOVES = "tpot_p50_ms"
+
+
+def read(rec):
+    return engine_phases.host_cpu_share(rec)

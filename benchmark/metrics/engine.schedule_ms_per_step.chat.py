@@ -1,0 +1,15 @@
+"""Phase ``engine.schedule`` (admission's queue pops, slot and block allocation,
+grouping; growth or preemption; the list of active slots)
+per decode step: ``t_schedule_s`` / ``decode_steps``."""
+
+from benchmark.lib import engine_phases
+
+LAYER = "Engine scheduler"
+UNIT = "ms"
+BETTER = "lower"
+SOURCE = "program_counter"
+MOVES = "tpot_p50_ms"
+
+
+def read(rec):
+    return engine_phases.ms_per_step(rec, "t_schedule_s")

@@ -1,0 +1,15 @@
+"""Wall time inside ``engine.step()`` per decode step of the window
+(``t_step_s`` / ``decode_steps``): admission, prefill, the host phases and the
+wait for the device, from before the step takes the engine's lock."""
+
+from benchmark.lib import engine_phases
+
+LAYER = "Engine scheduler"
+UNIT = "ms"
+BETTER = "lower"
+SOURCE = "program_counter"
+MOVES = "tpot_p50_ms"
+
+
+def read(rec):
+    return engine_phases.ms_per_step(rec, "t_step_s")

@@ -506,12 +506,8 @@ class Runtime:
             self._execute_inline(spec, node, args, kwargs)
             return
         fid, args_blob = payload
-        from ray_tpu.util import tracing
         try:
-            with tracing.span(f"task::{spec.name}",
-                              task_id=spec.task_id.hex()[:16]):
-                kind, value = node.daemon.execute_task(spec, fid,
-                                                       args_blob)
+            kind, value = node.daemon.execute_task(spec, fid, args_blob)
         except RemoteWorkerCrashed as crash:
             # one worker died; the daemon (node) is fine — plain retry
             self._on_process_task_crash(spec, node, crash)
@@ -1344,12 +1340,6 @@ class Runtime:
         self.task_events.record(
             task_id=spec.task_id.hex(), name=spec.name, event="RUNNING",
             node_id=node.node_id.hex())
-        from ray_tpu.util import tracing
-        with tracing.span(f"task::{spec.name}",
-                          task_id=spec.task_id.hex()[:16]):
-            self._execute_on_node_traced(spec, node)
-
-    def _execute_on_node_traced(self, spec: TaskSpec, node: Node) -> None:
         try:
             args, kwargs = self._resolve_args(spec)
         except exc.TaskError as te:

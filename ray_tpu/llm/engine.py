@@ -24,7 +24,10 @@ is the in-tree TPU-native equivalent (BASELINE.md config 5):
   slots masked), block tables riding along as a tiny int32 array;
   sampling on-device, only B int32s return to host per step;
 - per-request TTFT / throughput stats (the reference's
-  `release/llm_tests/serve/benchmark/load_test.py` metrics).
+  `release/llm_tests/serve/benchmark/load_test.py` metrics);
+- the loop accounts for itself: every part of ``step()`` runs inside one
+  of seven sibling PHASES (``_Phase``), each a span on the profiler's
+  clock and a seconds counter in ``stats`` (docs/serving.md).
 """
 
 from __future__ import annotations
@@ -70,6 +73,7 @@ class Request:
         self.output: List[int] = []
         self.stream: "queue.Queue" = queue.Queue()
         self.submitted_at = time.perf_counter()
+        self.queued_at = self.submitted_at   # reset when a preemption requeues
         self.first_token_at: Optional[float] = None
         self.finished_at: Optional[float] = None
         self.done = threading.Event()
@@ -112,6 +116,42 @@ class Request:
         if self.error is not None:
             raise EngineDeadError(
                 f"engine loop died: {self.error!r}") from self.error
+
+
+class _Phase:
+    """One phase of the engine loop, timed once for two readers: a
+    ``jax.profiler.TraceAnnotation`` (a span on the device trace's
+    clock; a flag check while no profiler runs) and the elapsed seconds
+    added to ``engine.stats[key]``. Phases are siblings that tile
+    ``step()``; none encloses another (the gap attribution gives a
+    device gap to the ONE span that covers most of it). ``waits`` marks
+    a phase that blocks on the device: its thread CPU time is kept out
+    of ``stats["cpu_host_s"]``."""
+
+    __slots__ = ("engine", "name", "key", "waits", "attrs", "span", "t0",
+                 "c0")
+
+    def __init__(self, engine, name: str, key: str, waits: bool = False,
+                 **attrs):
+        self.engine, self.name, self.key = engine, name, key
+        self.waits, self.attrs = waits, attrs
+
+    def __enter__(self):
+        # the annotation's span starts when it is constructed
+        self.span = jax.profiler.TraceAnnotation(self.name, **self.attrs)
+        self.span.__enter__()
+        if self.waits:
+            self.c0 = time.thread_time()
+        self.t0 = time.perf_counter()
+        return self
+
+    def __exit__(self, *exc):
+        eng = self.engine
+        eng.stats[self.key] += time.perf_counter() - self.t0
+        if self.waits:
+            eng._cpu_waiting += time.thread_time() - self.c0
+        self.span.__exit__(*exc)
+        return False
 
 
 class ContinuousBatchingEngine:
@@ -187,10 +227,23 @@ class ContinuousBatchingEngine:
         self._gather = jax.jit(self._gather_impl)
         self._sample = jax.jit(self._sample_impl)
 
+        # Every key exists from here on (another thread copies the dict
+        # while the loop writes it), flat and JSON-plain; units and
+        # meanings in docs/serving.md. ``t_*_s`` are the phases' wall
+        # seconds (``_Phase``); ``t_step_s`` is all of ``step()`` from
+        # before it takes the lock; ``cpu_host_s`` is this thread's CPU
+        # time in ``step()`` outside the phases that wait for the device.
         self.stats = {"requests": 0, "tokens_generated": 0,
                       "decode_steps": 0, "prefills": 0,
                       "prefix_prefills": 0, "prefix_tokens_reused": 0,
-                      "preemptions": 0}
+                      "preemptions": 0,
+                      "admitted": 0, "queue_wait_s": 0.0,
+                      "prefill_tokens": 0, "prefill_padded_tokens": 0,
+                      "t_step_s": 0.0, "t_schedule_s": 0.0,
+                      "t_prefill_s": 0.0, "t_host_arrays_s": 0.0,
+                      "t_enqueue_s": 0.0, "t_readback_s": 0.0,
+                      "t_emit_s": 0.0, "t_idle_s": 0.0, "cpu_host_s": 0.0}
+        self._cpu_waiting = 0.0     # CPU seconds of this step's waiting phases
 
     # -- jitted internals --------------------------------------------------
     def _prefill_impl(self, params, tokens, lengths):
@@ -267,9 +320,16 @@ class ContinuousBatchingEngine:
     def step(self) -> int:
         """One engine iteration: admit+prefill, then one decode step for
         all active slots. Returns number of active slots."""
+        t0 = time.perf_counter()
         with self._lock:
+            c0 = time.thread_time()
+            self._cpu_waiting = 0.0
             self._admit()
-            return self._decode_step()
+            active = self._decode_step()
+            self.stats["cpu_host_s"] += (time.thread_time() - c0
+                                         - self._cpu_waiting)
+        self.stats["t_step_s"] += time.perf_counter() - t0
+        return active
 
     def _bucket_for(self, n: int) -> Optional[int]:
         for b in self.buckets:
@@ -283,9 +343,23 @@ class ContinuousBatchingEngine:
         allow. Prefix-hit requests prefill one-by-one through the
         suffix path; the rest batch per bucket (one forward per
         bucket). Pool exhaustion stops admission (FIFO order held)."""
-        free = [i for i, r in enumerate(self.slots) if r is None]
-        if not free or not self.waiting:
+        if not self.waiting:
             return
+        with _Phase(self, "engine.schedule", "t_schedule_s"):
+            by_shape, singles, by_bucket = self._plan_admission()
+        for (pb_pad, s_bucket), group in by_shape.items():
+            self._admit_prefix_batch(pb_pad, s_bucket, group)
+        for slot, req, alloc, shared_tok in singles:
+            self._admit_chunked(slot, req, alloc, shared_tok)
+        for bucket, group in by_bucket.items():
+            self._admit_bucket(bucket, group)
+        self._admitting.clear()
+
+    def _plan_admission(self):
+        """Pop what fits, give each a slot and its blocks, and group the
+        admitted by prefill shape: ``(by_shape, singles, by_bucket)``."""
+        now = time.perf_counter()
+        free = [i for i, r in enumerate(self.slots) if r is None]
         by_bucket: Dict[int, List] = {}
         chunked_group: List = []
         while free and self.waiting:
@@ -310,6 +384,8 @@ class ContinuousBatchingEngine:
                 break
             alloc, shared_tok = alloc
             slot = free.pop(0)
+            self.stats["admitted"] += 1
+            self.stats["queue_wait_s"] += now - req.queued_at
             bucket = self._bucket_for(n)
             if shared_tok > 0 or bucket is None:
                 # prefix hit, or context longer than the largest
@@ -336,13 +412,7 @@ class ContinuousBatchingEngine:
                 by_shape.setdefault(key, []).append(item)
             else:
                 singles.append(item)
-        for (pb_pad, s_bucket), group in by_shape.items():
-            self._admit_prefix_batch(pb_pad, s_bucket, group)
-        for slot, req, alloc, shared_tok in singles:
-            self._admit_chunked(slot, req, alloc, shared_tok)
-        for bucket, group in by_bucket.items():
-            self._admit_bucket(bucket, group)
-        self._admitting.clear()
+        return by_shape, singles, by_bucket
 
     def _pad_pow2(self, n: int, cap: int) -> int:
         p = 1
@@ -358,25 +428,35 @@ class ContinuousBatchingEngine:
         # pad the group to the next power of two so each bucket has
         # O(log max_slots) jit specializations, not one per N
         n_pad = self._pad_pow2(len(group), self.max_slots)
-        lengths = np.ones(n_pad, np.int32)
-        toks = np.zeros((n_pad, bucket), np.int32)
-        block_ids = np.full(n_pad * nb, self.num_blocks, np.int32)
-        for row, (slot, req, alloc) in enumerate(group):
-            seq = req.cache_tokens()
-            lengths[row] = len(seq)
-            toks[row, :len(seq)] = seq
-            ids = alloc.blocks[:nb]
-            block_ids[row * nb:row * nb + len(ids)] = ids
-        last_logits, small = self._prefill(
-            self.params, jnp.asarray(toks), jnp.asarray(lengths))
-        self.kv = self._insert(self.kv, small, jnp.asarray(block_ids))
-        self.stats["prefills"] += 1
-        toks_out = self._sample_batch(last_logits,
-                                      [req for _, req, _ in group], n_pad)
-        now = time.perf_counter()
-        for row, (slot, req, alloc) in enumerate(group):
-            self._activate(slot, req, alloc, int(lengths[row]), now)
-            self._emit(slot, int(toks_out[row]))
+        with self._prefill_phase(bucket, len(group), n_pad):
+            lengths = np.ones(n_pad, np.int32)
+            toks = np.zeros((n_pad, bucket), np.int32)
+            block_ids = np.full(n_pad * nb, self.num_blocks, np.int32)
+            for row, (slot, req, alloc) in enumerate(group):
+                seq = req.cache_tokens()
+                lengths[row] = len(seq)
+                toks[row, :len(seq)] = seq
+                ids = alloc.blocks[:nb]
+                block_ids[row * nb:row * nb + len(ids)] = ids
+                self.stats["prefill_tokens"] += len(seq)
+            last_logits, small = self._prefill(
+                self.params, jnp.asarray(toks), jnp.asarray(lengths))
+            self.kv = self._insert(self.kv, small, jnp.asarray(block_ids))
+            self.stats["prefills"] += 1
+            self.stats["prefill_padded_tokens"] += n_pad * bucket
+            toks_out = self._sample_batch(
+                last_logits, [req for _, req, _ in group], n_pad)
+        with _Phase(self, "engine.emit", "t_emit_s"):
+            now = time.perf_counter()
+            for row, (slot, req, alloc) in enumerate(group):
+                self._activate(slot, req, alloc, int(lengths[row]), now)
+                self._emit(slot, int(toks_out[row]))
+
+    def _prefill_phase(self, bucket: int, n: int, n_pad: int) -> _Phase:
+        """One admitted group's prefill, host work and device wait alike:
+        decode is held up for all of it."""
+        return _Phase(self, "engine.prefill", "t_prefill_s", waits=True,
+                      bucket=bucket, n=n, n_pad=n_pad)
 
     def _admit_prefix_batch(self, pb_pad: int, s_bucket: int,
                             group: List) -> None:
@@ -385,36 +465,40 @@ class ContinuousBatchingEngine:
         bs = self.block_size
         nb = s_bucket // bs
         n_pad = self._pad_pow2(len(group), self.max_slots)
-        ids = np.zeros((n_pad, pb_pad), np.int32)
-        toks = np.zeros((n_pad, s_bucket), np.int32)
-        plens = np.zeros(n_pad, np.int32)
-        slens = np.ones(n_pad, np.int32)
-        block_ids = np.full(n_pad * nb, self.num_blocks, np.int32)
-        for row, (slot, req, alloc, shared) in enumerate(group):
-            seq = req.cache_tokens()
-            pb = shared // bs
-            ids[row, :pb] = alloc.blocks[:pb]
-            suffix = seq[shared:]
-            toks[row, :len(suffix)] = suffix
-            plens[row] = shared
-            slens[row] = len(suffix)
-            avail = alloc.blocks[pb:pb + nb]
-            block_ids[row * nb:row * nb + len(avail)] = avail
-            self.stats["prefix_prefills"] += 1
-            self.stats["prefix_tokens_reused"] += shared
-        pk, pv = self._gather(self.kv, jnp.asarray(ids))
-        last_logits, small = self._prefill_prefix(
-            self.params, jnp.asarray(toks), pk, pv,
-            jnp.asarray(plens), jnp.asarray(slens))
-        self.kv = self._insert(self.kv, small, jnp.asarray(block_ids))
-        self.stats["prefills"] += 1
-        toks_out = self._sample_batch(last_logits,
-                                      [req for _, req, _, _ in group],
-                                      n_pad)
-        now = time.perf_counter()
-        for row, (slot, req, alloc, shared) in enumerate(group):
-            self._activate(slot, req, alloc, len(req.cache_tokens()), now)
-            self._emit(slot, int(toks_out[row]))
+        with self._prefill_phase(s_bucket, len(group), n_pad):
+            ids = np.zeros((n_pad, pb_pad), np.int32)
+            toks = np.zeros((n_pad, s_bucket), np.int32)
+            plens = np.zeros(n_pad, np.int32)
+            slens = np.ones(n_pad, np.int32)
+            block_ids = np.full(n_pad * nb, self.num_blocks, np.int32)
+            for row, (slot, req, alloc, shared) in enumerate(group):
+                seq = req.cache_tokens()
+                pb = shared // bs
+                ids[row, :pb] = alloc.blocks[:pb]
+                suffix = seq[shared:]
+                toks[row, :len(suffix)] = suffix
+                plens[row] = shared
+                slens[row] = len(suffix)
+                avail = alloc.blocks[pb:pb + nb]
+                block_ids[row * nb:row * nb + len(avail)] = avail
+                self.stats["prefix_prefills"] += 1
+                self.stats["prefix_tokens_reused"] += shared
+                self.stats["prefill_tokens"] += len(suffix)
+            pk, pv = self._gather(self.kv, jnp.asarray(ids))
+            last_logits, small = self._prefill_prefix(
+                self.params, jnp.asarray(toks), pk, pv,
+                jnp.asarray(plens), jnp.asarray(slens))
+            self.kv = self._insert(self.kv, small, jnp.asarray(block_ids))
+            self.stats["prefills"] += 1
+            self.stats["prefill_padded_tokens"] += n_pad * s_bucket
+            toks_out = self._sample_batch(
+                last_logits, [req for _, req, _, _ in group], n_pad)
+        with _Phase(self, "engine.emit", "t_emit_s"):
+            now = time.perf_counter()
+            for row, (slot, req, alloc, shared) in enumerate(group):
+                self._activate(slot, req, alloc, len(req.cache_tokens()),
+                               now)
+                self._emit(slot, int(toks_out[row]))
 
     def _prefill_chunk(self, alloc: SlotAllocation, seq: List[int],
                        pos: int, chunk_len: int):
@@ -445,6 +529,8 @@ class ContinuousBatchingEngine:
         # chunk cache is [L, 1, Tb, ...]: reuse the batched scatter
         self.kv = self._insert(self.kv, small, jnp.asarray(block_ids))
         self.stats["prefills"] += 1
+        self.stats["prefill_tokens"] += len(chunk)
+        self.stats["prefill_padded_tokens"] += s_bucket
         return last_logits
 
     def _admit_chunked(self, slot: int, req: Request,
@@ -462,13 +548,17 @@ class ContinuousBatchingEngine:
         pos = shared_tok
         big = self.buckets[-1]
         last_logits = None
-        while pos < n:
-            chunk_len = min(big, n - pos)
-            last_logits = self._prefill_chunk(alloc, seq, pos, chunk_len)
-            pos += chunk_len
-        toks_out = self._sample_batch(last_logits, [req], 1)
-        self._activate(slot, req, alloc, n, time.perf_counter())
-        self._emit(slot, int(toks_out[0]))
+        # one phase for all its chunks; ``bucket`` is the first chunk's
+        with self._prefill_phase(self._bucket_for(min(big, n - pos)), 1, 1):
+            while pos < n:
+                chunk_len = min(big, n - pos)
+                last_logits = self._prefill_chunk(alloc, seq, pos,
+                                                  chunk_len)
+                pos += chunk_len
+            toks_out = self._sample_batch(last_logits, [req], 1)
+        with _Phase(self, "engine.emit", "t_emit_s"):
+            self._activate(slot, req, alloc, n, time.perf_counter())
+            self._emit(slot, int(toks_out[0]))
 
     def _activate(self, slot: int, req: Request, alloc: SlotAllocation,
                   n_cached: int, now: float) -> None:
@@ -505,6 +595,7 @@ class ContinuousBatchingEngine:
         self._tables[slot] = self.num_blocks   # idle writes go to scratch
         self._admit_order.remove(slot)
         req.preemptions += 1
+        req.queued_at = time.perf_counter()
         self.stats["preemptions"] += 1
         self.waiting.appendleft(req)
 
@@ -532,29 +623,55 @@ class ContinuousBatchingEngine:
                 self._tables[slot, :len(alloc.blocks)] = alloc.blocks
 
     def _decode_step(self) -> int:
-        self._grow_or_preempt()
-        active = [i for i, r in enumerate(self.slots) if r is not None]
+        with _Phase(self, "engine.schedule", "t_schedule_s"):
+            self._grow_or_preempt()
+            active = [i for i, r in enumerate(self.slots) if r is not None]
         if not active:
             return 0
-        last_tokens = np.zeros(self.max_slots, np.int32)
-        temps = np.zeros(self.max_slots, np.float32)
-        top_ks = np.zeros(self.max_slots, np.int32)
-        for i in active:
-            req = self.slots[i]
-            last_tokens[i] = req.output[-1] if req.output else \
-                (req.prompt[-1] if req.prompt else 0)
-            temps[i] = req.sampling.temperature
-            top_ks[i] = req.sampling.top_k
-        logits, self.kv = self._decode(
-            self.params, jnp.asarray(last_tokens), self.kv,
-            jnp.asarray(self._tables), jnp.asarray(self.offsets))
-        self._rng_key, sub = jax.random.split(self._rng_key)
-        toks = np.asarray(self._sample(
-            logits, jnp.asarray(temps), jnp.asarray(top_ks), sub))
-        self.stats["decode_steps"] += 1
-        for i in active:
-            self.offsets[i] += 1
-            self._emit(i, int(toks[i]))
+        # host arrays and dispatch alternate, in the order that puts the
+        # decode program on the device first: what the host still does
+        # for sampling then runs under it (building everything before
+        # the first dispatch read 1.3 % fewer tokens/s: PERF.md, PR 24)
+        with _Phase(self, "engine.host_arrays", "t_host_arrays_s"):
+            last_tokens = np.zeros(self.max_slots, np.int32)
+            temps = np.zeros(self.max_slots, np.float32)
+            top_ks = np.zeros(self.max_slots, np.int32)
+            for i in active:
+                req = self.slots[i]
+                last_tokens[i] = req.output[-1] if req.output else \
+                    (req.prompt[-1] if req.prompt else 0)
+                temps[i] = req.sampling.temperature
+                top_ks[i] = req.sampling.top_k
+            last_tokens, tables, offsets = (
+                jnp.asarray(last_tokens), jnp.asarray(self._tables),
+                jnp.asarray(self.offsets))
+        # dispatch only: the calls return before the device is done
+        with _Phase(self, "engine.decode_enqueue", "t_enqueue_s"):
+            logits, self.kv = self._decode(self.params, last_tokens,
+                                           self.kv, tables, offsets)
+            # a step's device inputs die under the running program:
+            # freeing a jax array releases the GIL (see ``emit`` below)
+            del last_tokens, tables, offsets
+        with _Phase(self, "engine.host_arrays", "t_host_arrays_s"):
+            self._rng_key, sub = jax.random.split(self._rng_key)
+            temps, top_ks = jnp.asarray(temps), jnp.asarray(top_ks)
+        with _Phase(self, "engine.decode_enqueue", "t_enqueue_s"):
+            toks = self._sample(logits, temps, top_ks, sub)
+            del temps, top_ks
+        with _Phase(self, "engine.sample_readback", "t_readback_s",
+                    waits=True):
+            toks = np.asarray(toks)
+        with _Phase(self, "engine.emit", "t_emit_s"):
+            self.stats["decode_steps"] += 1
+            for i in active:
+                self.offsets[i] += 1
+                self._emit(i, int(toks[i]))
+            # the step's last device arrays die inside the phase, not at
+            # the return a few bytecodes on: with the first GIL release
+            # the stream threads that the puts woke run their part of
+            # every token, and this thread waits for the GIL back; that
+            # wait is emit's, and a gap the device idles in
+            del logits, sub
         return len(active)
 
     def _emit(self, slot: int, tok: int) -> None:
@@ -657,7 +774,8 @@ class ContinuousBatchingEngine:
         try:
             while not stop_event.is_set():
                 if self.step() == 0 and not self.waiting:
-                    time.sleep(idle_sleep_s)
+                    with _Phase(self, "engine.idle", "t_idle_s"):
+                        time.sleep(idle_sleep_s)
         except Exception as err:
             self.error = err
             self._fail_all(err)

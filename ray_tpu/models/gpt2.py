@@ -118,24 +118,32 @@ class GPT2Model:
     def _block(self, x, layer):
         cfg = self.cfg
         dt = cfg.dtype
-        h = layer_norm(x, layer["ln1_w"], layer["ln1_b"], eps=cfg.norm_eps)
-        qkv = jnp.einsum("bsd,dthk->bsthk", h, layer["wqkv"].astype(dt))
-        qkv = qkv + layer["bqkv"].astype(dt)
-        q, kk, vv = qkv[:, :, 0], qkv[:, :, 1], qkv[:, :, 2]
-        o = attention(q, kk, vv, causal=True)
-        o = jnp.einsum("bshk,hkd->bsd", o, layer["wo"].astype(dt))
-        x = x + o + layer["bo"].astype(dt)
-        h = layer_norm(x, layer["ln2_w"], layer["ln2_b"], eps=cfg.norm_eps)
-        up = jnp.einsum("bsd,df->bsf", h, layer["w_up"].astype(dt))
-        up = jax.nn.gelu(up + layer["b_up"].astype(dt))
-        down = jnp.einsum("bsf,fd->bsd", up, layer["w_down"].astype(dt))
-        return x + down + layer["b_down"].astype(dt)
+        with jax.named_scope("norm_residual"):
+            h = layer_norm(x, layer["ln1_w"], layer["ln1_b"],
+                           eps=cfg.norm_eps)
+        with jax.named_scope("attention"):
+            qkv = jnp.einsum("bsd,dthk->bsthk", h, layer["wqkv"].astype(dt))
+            qkv = qkv + layer["bqkv"].astype(dt)
+            q, kk, vv = qkv[:, :, 0], qkv[:, :, 1], qkv[:, :, 2]
+            o = attention(q, kk, vv, causal=True)
+            o = jnp.einsum("bshk,hkd->bsd", o, layer["wo"].astype(dt))
+        with jax.named_scope("norm_residual"):
+            x = x + o + layer["bo"].astype(dt)
+            h = layer_norm(x, layer["ln2_w"], layer["ln2_b"],
+                           eps=cfg.norm_eps)
+        with jax.named_scope("mlp"):
+            up = jnp.einsum("bsd,df->bsf", h, layer["w_up"].astype(dt))
+            up = jax.nn.gelu(up + layer["b_up"].astype(dt))
+            down = jnp.einsum("bsf,fd->bsd", up, layer["w_down"].astype(dt))
+        with jax.named_scope("norm_residual"):
+            return x + down + layer["b_down"].astype(dt)
 
     def apply(self, params: Params, tokens: jax.Array) -> jax.Array:
         cfg = self.cfg
         B, S = tokens.shape
-        x = params["wte"].astype(cfg.dtype)[tokens]
-        x = x + params["wpe"].astype(cfg.dtype)[:S][None]
+        with jax.named_scope("embed"):
+            x = params["wte"].astype(cfg.dtype)[tokens]
+            x = x + params["wpe"].astype(cfg.dtype)[:S][None]
 
         block = self._block
         if cfg.remat:
@@ -145,15 +153,17 @@ class GPT2Model:
             return block(x, layer), None
 
         x, _ = jax.lax.scan(scan_body, x, params["layers"])
-        x = layer_norm(x, params["lnf_w"], params["lnf_b"],
-                       eps=cfg.norm_eps)
-        logits = jnp.einsum("bsd,vd->bsv", x,
-                            params["wte"].astype(cfg.dtype))  # tied head
-        return logits.astype(jnp.float32)
+        with jax.named_scope("logits"):
+            x = layer_norm(x, params["lnf_w"], params["lnf_b"],
+                           eps=cfg.norm_eps)
+            logits = jnp.einsum("bsd,vd->bsv", x,
+                                params["wte"].astype(cfg.dtype))  # tied head
+            return logits.astype(jnp.float32)
 
     def loss(self, params: Params, tokens: jax.Array,
              targets: jax.Array) -> jax.Array:
         logits = self.apply(params, tokens)
-        logp = jax.nn.log_softmax(logits, axis=-1)
-        return -jnp.mean(jnp.take_along_axis(
-            logp, targets[..., None], axis=-1))
+        with jax.named_scope("loss"):
+            logp = jax.nn.log_softmax(logits, axis=-1)
+            return -jnp.mean(jnp.take_along_axis(
+                logp, targets[..., None], axis=-1))

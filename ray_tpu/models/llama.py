@@ -285,31 +285,36 @@ class LlamaModel:
     def _block(self, x, layer: Params, positions):
         cfg = self.cfg
         dt = cfg.dtype
-        h = rms_norm(x, layer["attn_norm"], eps=cfg.norm_eps)
-        q = jnp.einsum("bsd,dhk->bshk", h, layer["wq"].astype(dt))
-        kk = jnp.einsum("bsd,dhk->bshk", h, layer["wk"].astype(dt))
-        vv = jnp.einsum("bsd,dhk->bshk", h, layer["wv"].astype(dt))
-        q = self._constrain(q, "batch", "seq", "heads", None)
-        q = apply_rope(q, self._angles, positions)
-        kk = apply_rope(kk, self._angles, positions)
-        o = self._attention(q, kk, vv, positions)
-        o = jnp.einsum("bshk,hkd->bsd", o, layer["wo"].astype(dt))
-        x = x + self._constrain(o, "batch", "seq", "embed")
-
-        h = rms_norm(x, layer["mlp_norm"], eps=cfg.norm_eps)
-        gate = jnp.einsum("bsd,df->bsf", h, layer["w_gate"].astype(dt))
-        up = jnp.einsum("bsd,df->bsf", h, layer["w_up"].astype(dt))
-        ff = jax.nn.silu(gate) * up
-        ff = self._constrain(ff, "batch", "seq", "mlp")
-        down = jnp.einsum("bsf,fd->bsd", ff, layer["w_down"].astype(dt))
-        return x + self._constrain(down, "batch", "seq", "embed")
+        with jax.named_scope("norm_residual"):
+            h = rms_norm(x, layer["attn_norm"], eps=cfg.norm_eps)
+        with jax.named_scope("attention"):
+            q = jnp.einsum("bsd,dhk->bshk", h, layer["wq"].astype(dt))
+            kk = jnp.einsum("bsd,dhk->bshk", h, layer["wk"].astype(dt))
+            vv = jnp.einsum("bsd,dhk->bshk", h, layer["wv"].astype(dt))
+            q = self._constrain(q, "batch", "seq", "heads", None)
+            q = apply_rope(q, self._angles, positions)
+            kk = apply_rope(kk, self._angles, positions)
+            o = self._attention(q, kk, vv, positions)
+            o = jnp.einsum("bshk,hkd->bsd", o, layer["wo"].astype(dt))
+        with jax.named_scope("norm_residual"):
+            x = x + self._constrain(o, "batch", "seq", "embed")
+            h = rms_norm(x, layer["mlp_norm"], eps=cfg.norm_eps)
+        with jax.named_scope("mlp"):
+            gate = jnp.einsum("bsd,df->bsf", h, layer["w_gate"].astype(dt))
+            up = jnp.einsum("bsd,df->bsf", h, layer["w_up"].astype(dt))
+            ff = jax.nn.silu(gate) * up
+            ff = self._constrain(ff, "batch", "seq", "mlp")
+            down = jnp.einsum("bsf,fd->bsd", ff, layer["w_down"].astype(dt))
+        with jax.named_scope("norm_residual"):
+            return x + self._constrain(down, "batch", "seq", "embed")
 
     def apply(self, params: Params, tokens: jax.Array,
               positions: Optional[jax.Array] = None) -> jax.Array:
         """tokens [B, S] int32 -> logits [B, S, V] (f32)."""
         cfg = self.cfg
-        x = self._embed_lookup(params["embed"].astype(cfg.dtype), tokens)
-        x = self._constrain(x, "batch", "seq", "embed")
+        with jax.named_scope("embed"):
+            x = self._embed_lookup(params["embed"].astype(cfg.dtype), tokens)
+            x = self._constrain(x, "batch", "seq", "embed")
 
         block = self._block
         if cfg.remat:
@@ -323,12 +328,13 @@ class LlamaModel:
             return block(x, layer, positions), None
 
         x, _ = jax.lax.scan(scan_body, x, params["layers"])
-        x = rms_norm(x, params["norm_f"], eps=cfg.norm_eps)
-        head = (params["embed"].T if cfg.tie_embeddings
-                else params["lm_head"])
-        logits = jnp.einsum("bsd,dv->bsv", x, head.astype(cfg.dtype))
-        logits = self._constrain(logits, "batch", "seq", "vocab")
-        return logits.astype(jnp.float32)
+        with jax.named_scope("logits"):
+            x = rms_norm(x, params["norm_f"], eps=cfg.norm_eps)
+            head = (params["embed"].T if cfg.tie_embeddings
+                    else params["lm_head"])
+            logits = jnp.einsum("bsd,dv->bsv", x, head.astype(cfg.dtype))
+            logits = self._constrain(logits, "batch", "seq", "vocab")
+            return logits.astype(jnp.float32)
 
     # -- KV-cache inference path (serving; BASELINE.md config 5) ----------
     def init_kv_cache(self, batch: int, max_seq: int) -> Params:
@@ -352,7 +358,8 @@ class LlamaModel:
         B, T = tokens.shape
         S = cache["k"].shape[2]
         q_pos = offsets[:, None] + jnp.arange(T)[None, :]        # [B, T]
-        x = self._embed_lookup(params["embed"].astype(cfg.dtype), tokens)
+        with jax.named_scope("embed"):
+            x = self._embed_lookup(params["embed"].astype(cfg.dtype), tokens)
 
         batch_idx = jnp.arange(B)[:, None]
 
@@ -360,50 +367,63 @@ class LlamaModel:
             x = carry
             layer, k_cache, v_cache = layer_and_cache
             dt = cfg.dtype
-            h = rms_norm(x, layer["attn_norm"], eps=cfg.norm_eps)
-            q = jnp.einsum("bsd,dhk->bshk", h, layer["wq"].astype(dt))
-            k_new = jnp.einsum("bsd,dhk->bshk", h, layer["wk"].astype(dt))
-            v_new = jnp.einsum("bsd,dhk->bshk", h, layer["wv"].astype(dt))
-            q = apply_rope(q, self._angles, q_pos)
-            k_new = apply_rope(k_new, self._angles, q_pos)
-            # scatter new k/v into the cache at each slot's write offsets
-            k_cache = k_cache.at[batch_idx, q_pos].set(k_new)
-            v_cache = v_cache.at[batch_idx, q_pos].set(v_new)
-            if T == 1 and cfg.decode_attention == "pallas":
-                # single-token decode: ragged kernel skips KV blocks past
-                # each slot's live length
-                from ray_tpu.ops.decode_attention import \
-                    ragged_decode_attention_pallas
-                o = ragged_decode_attention_pallas(
-                    q[:, 0], k_cache, v_cache, q_pos[:, 0] + 1)[:, None]
-            else:
-                # attend over cache positions <= own position
-                from ray_tpu.ops.attention import NEG_INF, _repeat_kv
-                kk = _repeat_kv(k_cache, cfg.n_heads)
-                vv = _repeat_kv(v_cache, cfg.n_heads)
-                s = jnp.einsum("bthd,bshd->bhts", q, kk,
-                               preferred_element_type=jnp.float32)
-                s = s * (cfg.head_dim ** -0.5)
-                mask = (jnp.arange(S)[None, None, :] <= q_pos[:, :, None])
-                s = jnp.where(mask[:, None], s, NEG_INF)
-                p = jax.nn.softmax(s, axis=-1)
-                o = jnp.einsum("bhts,bshd->bthd", p.astype(dt), vv)
-            o = jnp.einsum("bshk,hkd->bsd", o, layer["wo"].astype(dt))
-            x = x + o
-            h = rms_norm(x, layer["mlp_norm"], eps=cfg.norm_eps)
-            gate = jnp.einsum("bsd,df->bsf", h, layer["w_gate"].astype(dt))
-            up = jnp.einsum("bsd,df->bsf", h, layer["w_up"].astype(dt))
-            down = jnp.einsum("bsf,fd->bsd", jax.nn.silu(gate) * up,
-                              layer["w_down"].astype(dt))
-            return x + down, (k_cache, v_cache)
+            with jax.named_scope("norm_residual"):
+                h = rms_norm(x, layer["attn_norm"], eps=cfg.norm_eps)
+            with jax.named_scope("attention"):
+                q = jnp.einsum("bsd,dhk->bshk", h, layer["wq"].astype(dt))
+                k_new = jnp.einsum("bsd,dhk->bshk", h,
+                                   layer["wk"].astype(dt))
+                v_new = jnp.einsum("bsd,dhk->bshk", h,
+                                   layer["wv"].astype(dt))
+                q = apply_rope(q, self._angles, q_pos)
+                k_new = apply_rope(k_new, self._angles, q_pos)
+            with jax.named_scope("kv_update"):
+                # scatter new k/v into the cache at each slot's write
+                # offsets
+                k_cache = k_cache.at[batch_idx, q_pos].set(k_new)
+                v_cache = v_cache.at[batch_idx, q_pos].set(v_new)
+            with jax.named_scope("attention"):
+                if T == 1 and cfg.decode_attention == "pallas":
+                    # single-token decode: ragged kernel skips KV blocks
+                    # past each slot's live length
+                    from ray_tpu.ops.decode_attention import \
+                        ragged_decode_attention_pallas
+                    o = ragged_decode_attention_pallas(
+                        q[:, 0], k_cache, v_cache, q_pos[:, 0] + 1)[:, None]
+                else:
+                    # attend over cache positions <= own position
+                    from ray_tpu.ops.attention import NEG_INF, _repeat_kv
+                    kk = _repeat_kv(k_cache, cfg.n_heads)
+                    vv = _repeat_kv(v_cache, cfg.n_heads)
+                    s = jnp.einsum("bthd,bshd->bhts", q, kk,
+                                   preferred_element_type=jnp.float32)
+                    s = s * (cfg.head_dim ** -0.5)
+                    mask = (jnp.arange(S)[None, None, :]
+                            <= q_pos[:, :, None])
+                    s = jnp.where(mask[:, None], s, NEG_INF)
+                    p = jax.nn.softmax(s, axis=-1)
+                    o = jnp.einsum("bhts,bshd->bthd", p.astype(dt), vv)
+                o = jnp.einsum("bshk,hkd->bsd", o, layer["wo"].astype(dt))
+            with jax.named_scope("norm_residual"):
+                x = x + o
+                h = rms_norm(x, layer["mlp_norm"], eps=cfg.norm_eps)
+            with jax.named_scope("mlp"):
+                gate = jnp.einsum("bsd,df->bsf", h,
+                                  layer["w_gate"].astype(dt))
+                up = jnp.einsum("bsd,df->bsf", h, layer["w_up"].astype(dt))
+                down = jnp.einsum("bsf,fd->bsd", jax.nn.silu(gate) * up,
+                                  layer["w_down"].astype(dt))
+            with jax.named_scope("norm_residual"):
+                return x + down, (k_cache, v_cache)
 
         x, (k_out, v_out) = jax.lax.scan(
             block, x, (params["layers"], cache["k"], cache["v"]))
-        x = rms_norm(x, params["norm_f"], eps=cfg.norm_eps)
-        head = (params["embed"].T if cfg.tie_embeddings
-                else params["lm_head"])
-        logits = jnp.einsum("bsd,dv->bsv", x, head.astype(cfg.dtype))
-        return logits.astype(jnp.float32), {"k": k_out, "v": v_out}
+        with jax.named_scope("logits"):
+            x = rms_norm(x, params["norm_f"], eps=cfg.norm_eps)
+            head = (params["embed"].T if cfg.tie_embeddings
+                    else params["lm_head"])
+            logits = jnp.einsum("bsd,dv->bsv", x, head.astype(cfg.dtype))
+            return logits.astype(jnp.float32), {"k": k_out, "v": v_out}
 
     # -- paged KV-cache path (llm/engine.py + llm/paged_cache.py) ---------
     def init_kv_pool(self, num_blocks: int, block_size: int) -> Params:
@@ -435,8 +455,9 @@ class LlamaModel:
         dest_off = offsets % bs
         lengths = offsets + 1
         q_pos = offsets[:, None]                                   # [B, 1]
-        x = self._embed_lookup(params["embed"].astype(cfg.dtype),
-                               tokens[:, None])                    # [B,1,D]
+        with jax.named_scope("embed"):
+            x = self._embed_lookup(params["embed"].astype(cfg.dtype),
+                                   tokens[:, None])                # [B,1,D]
         impl = "pallas" if cfg.decode_attention == "pallas" else "xla"
         from ray_tpu.ops.paged_attention import paged_decode_attention
 
@@ -444,36 +465,48 @@ class LlamaModel:
             x = carry
             layer, k_pool, v_pool = layer_and_pool
             dt = cfg.dtype
-            h = rms_norm(x, layer["attn_norm"], eps=cfg.norm_eps)
-            q = jnp.einsum("bsd,dhk->bshk", h, layer["wq"].astype(dt))
-            k_new = jnp.einsum("bsd,dhk->bshk", h, layer["wk"].astype(dt))
-            v_new = jnp.einsum("bsd,dhk->bshk", h, layer["wv"].astype(dt))
-            q = apply_rope(q, self._angles, q_pos)
-            k_new = apply_rope(k_new, self._angles, q_pos)
-            # each slot writes its own private tail block (refcount 1 —
-            # shared prefix blocks are never write targets)
-            k_pool = k_pool.at[dest_block, dest_off].set(
-                k_new[:, 0].astype(dt))
-            v_pool = v_pool.at[dest_block, dest_off].set(
-                v_new[:, 0].astype(dt))
-            o = paged_decode_attention(q[:, 0], k_pool, v_pool,
-                                       block_tables, lengths, impl=impl)
-            o = jnp.einsum("bhk,hkd->bd", o, layer["wo"].astype(dt))
-            x = x + o[:, None]
-            h = rms_norm(x, layer["mlp_norm"], eps=cfg.norm_eps)
-            gate = jnp.einsum("bsd,df->bsf", h, layer["w_gate"].astype(dt))
-            up = jnp.einsum("bsd,df->bsf", h, layer["w_up"].astype(dt))
-            down = jnp.einsum("bsf,fd->bsd", jax.nn.silu(gate) * up,
-                              layer["w_down"].astype(dt))
-            return x + down, (k_pool, v_pool)
+            with jax.named_scope("norm_residual"):
+                h = rms_norm(x, layer["attn_norm"], eps=cfg.norm_eps)
+            with jax.named_scope("attention"):
+                q = jnp.einsum("bsd,dhk->bshk", h, layer["wq"].astype(dt))
+                k_new = jnp.einsum("bsd,dhk->bshk", h,
+                                   layer["wk"].astype(dt))
+                v_new = jnp.einsum("bsd,dhk->bshk", h,
+                                   layer["wv"].astype(dt))
+                q = apply_rope(q, self._angles, q_pos)
+                k_new = apply_rope(k_new, self._angles, q_pos)
+            with jax.named_scope("kv_update"):
+                # each slot writes its own private tail block (refcount
+                # 1 — shared prefix blocks are never write targets)
+                k_pool = k_pool.at[dest_block, dest_off].set(
+                    k_new[:, 0].astype(dt))
+                v_pool = v_pool.at[dest_block, dest_off].set(
+                    v_new[:, 0].astype(dt))
+            with jax.named_scope("attention"):
+                o = paged_decode_attention(q[:, 0], k_pool, v_pool,
+                                           block_tables, lengths, impl=impl)
+                o = jnp.einsum("bhk,hkd->bd", o, layer["wo"].astype(dt))
+            with jax.named_scope("norm_residual"):
+                x = x + o[:, None]
+                h = rms_norm(x, layer["mlp_norm"], eps=cfg.norm_eps)
+            with jax.named_scope("mlp"):
+                gate = jnp.einsum("bsd,df->bsf", h,
+                                  layer["w_gate"].astype(dt))
+                up = jnp.einsum("bsd,df->bsf", h, layer["w_up"].astype(dt))
+                down = jnp.einsum("bsf,fd->bsd", jax.nn.silu(gate) * up,
+                                  layer["w_down"].astype(dt))
+            with jax.named_scope("norm_residual"):
+                return x + down, (k_pool, v_pool)
 
         x, (k_out, v_out) = jax.lax.scan(
             block, x, (params["layers"], pool["k"], pool["v"]))
-        x = rms_norm(x, params["norm_f"], eps=cfg.norm_eps)
-        head = (params["embed"].T if cfg.tie_embeddings
-                else params["lm_head"])
-        logits = jnp.einsum("bsd,dv->bsv", x, head.astype(cfg.dtype))
-        return logits[:, 0].astype(jnp.float32), {"k": k_out, "v": v_out}
+        with jax.named_scope("logits"):
+            x = rms_norm(x, params["norm_f"], eps=cfg.norm_eps)
+            head = (params["embed"].T if cfg.tie_embeddings
+                    else params["lm_head"])
+            logits = jnp.einsum("bsd,dv->bsv", x, head.astype(cfg.dtype))
+            return (logits[:, 0].astype(jnp.float32),
+                    {"k": k_out, "v": v_out})
 
     def prefill_with_prefix(self, params: Params, tokens: jax.Array,
                             prefix_k: jax.Array, prefix_v: jax.Array,
@@ -503,62 +536,73 @@ class LlamaModel:
         pos_prefix = jnp.where(
             jnp.arange(Pmax)[None, :] < prefix_len[:, None],
             jnp.arange(Pmax)[None, :], far)                          # [N,Pmax]
-        x = self._embed_lookup(params["embed"].astype(dt), tokens)
+        with jax.named_scope("embed"):
+            x = self._embed_lookup(params["embed"].astype(dt), tokens)
 
         from ray_tpu.ops.attention import NEG_INF, _repeat_kv
 
         def block(carry, layer_and_prefix):
             x = carry
             layer, kp, vp = layer_and_prefix       # kp/vp [N, Pmax, Hkv, D]
-            h = rms_norm(x, layer["attn_norm"], eps=cfg.norm_eps)
-            q = jnp.einsum("bsd,dhk->bshk", h, layer["wq"].astype(dt))
-            k_new = jnp.einsum("bsd,dhk->bshk", h, layer["wk"].astype(dt))
-            v_new = jnp.einsum("bsd,dhk->bshk", h, layer["wv"].astype(dt))
-            q = apply_rope(q, self._angles, pos_q)
-            k_new = apply_rope(k_new, self._angles, pos_q)
-            k_all = jnp.concatenate([kp.astype(dt), k_new], axis=1)
-            v_all = jnp.concatenate([vp.astype(dt), v_new], axis=1)
-            pos_k = jnp.concatenate(
-                [pos_prefix, pos_q], axis=1)                        # [N,P+Tb]
-            # per-row positions (prefix_len varies by row) — masked
-            # attention inline; padded prefix rows have pos_k=2^30 so
-            # the causal test drops them
-            kk = _repeat_kv(k_all, cfg.n_heads)
-            vv = _repeat_kv(v_all, cfg.n_heads)
-            s = jnp.einsum("bqhd,bkhd->bhqk", q, kk,
-                           preferred_element_type=jnp.float32)
-            s = s * (cfg.head_dim ** -0.5)
-            mask = pos_q[:, None, :, None] >= pos_k[:, None, None, :]
-            s = jnp.where(mask, s, NEG_INF)
-            p = jax.nn.softmax(s, axis=-1)
-            o = jnp.einsum("bhqk,bkhd->bqhd", p.astype(dt), vv)
-            o = jnp.einsum("bshk,hkd->bsd", o, layer["wo"].astype(dt))
-            x = x + o
-            h = rms_norm(x, layer["mlp_norm"], eps=cfg.norm_eps)
-            gate = jnp.einsum("bsd,df->bsf", h, layer["w_gate"].astype(dt))
-            up = jnp.einsum("bsd,df->bsf", h, layer["w_up"].astype(dt))
-            down = jnp.einsum("bsf,fd->bsd", jax.nn.silu(gate) * up,
-                              layer["w_down"].astype(dt))
-            return x + down, (k_new, v_new)
+            with jax.named_scope("norm_residual"):
+                h = rms_norm(x, layer["attn_norm"], eps=cfg.norm_eps)
+            with jax.named_scope("attention"):
+                q = jnp.einsum("bsd,dhk->bshk", h, layer["wq"].astype(dt))
+                k_new = jnp.einsum("bsd,dhk->bshk", h,
+                                   layer["wk"].astype(dt))
+                v_new = jnp.einsum("bsd,dhk->bshk", h,
+                                   layer["wv"].astype(dt))
+                q = apply_rope(q, self._angles, pos_q)
+                k_new = apply_rope(k_new, self._angles, pos_q)
+                k_all = jnp.concatenate([kp.astype(dt), k_new], axis=1)
+                v_all = jnp.concatenate([vp.astype(dt), v_new], axis=1)
+                pos_k = jnp.concatenate(
+                    [pos_prefix, pos_q], axis=1)                    # [N,P+Tb]
+                # per-row positions (prefix_len varies by row) — masked
+                # attention inline; padded prefix rows have pos_k=2^30 so
+                # the causal test drops them
+                kk = _repeat_kv(k_all, cfg.n_heads)
+                vv = _repeat_kv(v_all, cfg.n_heads)
+                s = jnp.einsum("bqhd,bkhd->bhqk", q, kk,
+                               preferred_element_type=jnp.float32)
+                s = s * (cfg.head_dim ** -0.5)
+                mask = pos_q[:, None, :, None] >= pos_k[:, None, None, :]
+                s = jnp.where(mask, s, NEG_INF)
+                p = jax.nn.softmax(s, axis=-1)
+                o = jnp.einsum("bhqk,bkhd->bqhd", p.astype(dt), vv)
+                o = jnp.einsum("bshk,hkd->bsd", o, layer["wo"].astype(dt))
+            with jax.named_scope("norm_residual"):
+                x = x + o
+                h = rms_norm(x, layer["mlp_norm"], eps=cfg.norm_eps)
+            with jax.named_scope("mlp"):
+                gate = jnp.einsum("bsd,df->bsf", h,
+                                  layer["w_gate"].astype(dt))
+                up = jnp.einsum("bsd,df->bsf", h, layer["w_up"].astype(dt))
+                down = jnp.einsum("bsf,fd->bsd", jax.nn.silu(gate) * up,
+                                  layer["w_down"].astype(dt))
+            with jax.named_scope("norm_residual"):
+                return x + down, (k_new, v_new)
 
         x, (k_out, v_out) = jax.lax.scan(
             block, x, (params["layers"], prefix_k, prefix_v))
-        x = rms_norm(x, params["norm_f"], eps=cfg.norm_eps)
-        head = (params["embed"].T if cfg.tie_embeddings
-                else params["lm_head"])
-        last = jnp.take_along_axis(x, (lengths - 1)[:, None, None],
-                                   axis=1)[:, 0]                    # [N, D]
-        logits = jnp.einsum("bd,dv->bv", last, head.astype(dt))
-        return logits.astype(jnp.float32), {"k": k_out, "v": v_out}
+        with jax.named_scope("logits"):
+            x = rms_norm(x, params["norm_f"], eps=cfg.norm_eps)
+            head = (params["embed"].T if cfg.tie_embeddings
+                    else params["lm_head"])
+            last = jnp.take_along_axis(x, (lengths - 1)[:, None, None],
+                                       axis=1)[:, 0]                # [N, D]
+            logits = jnp.einsum("bd,dv->bv", last, head.astype(dt))
+            return logits.astype(jnp.float32), {"k": k_out, "v": v_out}
 
     def loss(self, params: Params, tokens: jax.Array,
              targets: jax.Array,
              mask: Optional[jax.Array] = None) -> jax.Array:
         """Mean next-token cross-entropy."""
         logits = self.apply(params, tokens)
-        logp = jax.nn.log_softmax(logits, axis=-1)
-        nll = -jnp.take_along_axis(logp, targets[..., None],
-                                   axis=-1).squeeze(-1)
-        if mask is not None:
-            return jnp.sum(nll * mask) / jnp.maximum(jnp.sum(mask), 1)
-        return jnp.mean(nll)
+        with jax.named_scope("loss"):
+            logp = jax.nn.log_softmax(logits, axis=-1)
+            nll = -jnp.take_along_axis(logp, targets[..., None],
+                                       axis=-1).squeeze(-1)
+            if mask is not None:
+                return jnp.sum(nll * mask) / jnp.maximum(jnp.sum(mask), 1)
+            return jnp.mean(nll)

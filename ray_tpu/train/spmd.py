@@ -57,11 +57,12 @@ def make_train_step(model, optimizer: Optional[optax.GradientTransformation]
 
     def step(params, opt_state, batch):
         loss, grads = jax.value_and_grad(loss_fn)(params, batch)
-        updates, opt_state = optimizer.update(updates=grads,
-                                              state=opt_state,
-                                              params=params)
-        params = optax.apply_updates(params, updates)
-        gnorm = optax.global_norm(grads)
+        with jax.named_scope("optimizer"):
+            updates, opt_state = optimizer.update(updates=grads,
+                                                  state=opt_state,
+                                                  params=params)
+            params = optax.apply_updates(params, updates)
+            gnorm = optax.global_norm(grads)
         return params, opt_state, {"loss": loss, "grad_norm": gnorm}
 
     if mesh is not None and p_sh is not None:

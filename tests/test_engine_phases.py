@@ -1,0 +1,230 @@
+"""The engine loop accounts for itself: seven sibling phases, each a
+``jax.profiler.TraceAnnotation`` span and a seconds counter in
+``engine.stats`` (``llm/engine.py:_Phase``), and counts taken at the
+same boundaries. CPU, debug widths, no timing thresholds: what is
+checked is names, nesting, exact counts and that the sums close."""
+
+import glob
+import json
+import os
+import re
+import threading
+
+import jax
+import pytest
+
+from ray_tpu.llm import ContinuousBatchingEngine, SamplingParams
+from ray_tpu.models.llama import LlamaConfig, LlamaModel
+
+PHASES = {"engine.schedule": "t_schedule_s", "engine.prefill": "t_prefill_s",
+          "engine.host_arrays": "t_host_arrays_s",
+          "engine.decode_enqueue": "t_enqueue_s",
+          "engine.sample_readback": "t_readback_s",
+          "engine.emit": "t_emit_s", "engine.idle": "t_idle_s"}
+IN_STEP = [k for name, k in PHASES.items() if name != "engine.idle"]
+
+
+@pytest.fixture(scope="module")
+def tiny_model():
+    cfg = LlamaConfig.debug(vocab_size=512, max_seq_len=128)
+    model = LlamaModel(cfg)
+    return model, model.init(jax.random.key(0))
+
+
+def make_engine(tiny_model, **kw):
+    model, params = tiny_model
+    kw = {"max_slots": 4, "max_seq": 128, "prefill_buckets": (16, 64),
+          "block_size": 8, **kw}
+    return ContinuousBatchingEngine(model, params, **kw)
+
+
+def distinct(n, start):
+    """``n`` tokens whose first block no other prompt of this file shares."""
+    return [(start + 7 * i) % 500 + 1 for i in range(n)]
+
+
+class Recorder:
+    """Stands in for ``jax.profiler.TraceAnnotation``: what was opened,
+    by which thread, at which depth."""
+
+    log = []
+    depth = threading.local()
+
+    def __init__(self, name, **attrs):
+        self.name, self.attrs = name, attrs
+
+    def __enter__(self):
+        d = getattr(Recorder.depth, "n", 0)
+        Recorder.log.append((self.name, self.attrs, threading.get_ident(), d))
+        Recorder.depth.n = d + 1
+        return self
+
+    def __exit__(self, *exc):
+        Recorder.depth.n -= 1
+        return False
+
+
+def test_spans_are_the_seven_phases_siblings_on_one_thread(tiny_model,
+                                                           monkeypatch):
+    eng = make_engine(tiny_model)
+    eng.generate([distinct(5, 0)], SamplingParams(max_tokens=2))   # compile
+    Recorder.log = []
+    monkeypatch.setattr(jax.profiler, "TraceAnnotation", Recorder)
+    before = dict(eng.stats)
+    # one alone, a group of three in one bucket, one longer than the
+    # largest bucket (chunked), and a repeat of the first (prefix hit)
+    eng.generate([distinct(9, 100)] + [distinct(40, 200 + k) for k in range(3)]
+                 + [distinct(70, 300)], SamplingParams(max_tokens=6))
+    eng.generate([distinct(9, 100) + [3, 4]], SamplingParams(max_tokens=3))
+    stop = threading.Event()
+    loop = threading.Thread(target=eng.run_forever, args=(stop, 0.001))
+    loop.start()                         # nothing to do: the loop idles
+    while eng.stats["t_idle_s"] == 0.0 and loop.is_alive():
+        stop.wait(0.001)
+    stop.set()
+    loop.join(30)
+    assert not loop.is_alive()
+
+    # by thread: an engine that an earlier test file of this process left
+    # idling on its own thread records too
+    driven = [e for e in Recorder.log if e[2] == threading.get_ident()]
+    idled = [e for e in Recorder.log if e[2] == loop.ident]
+    names = [n for n, _, _, _ in driven]
+    assert set(names) == set(PHASES) - {"engine.idle"}
+    assert {n for n, _, _, _ in idled} == {"engine.schedule", "engine.idle"}
+    assert all(depth == 0 for _, _, _, depth in driven + idled), "nested"
+    steps = eng.stats["decode_steps"] - before["decode_steps"]
+    groups = names.count("engine.prefill")
+    assert steps > 0 and groups == 4      # 16 x1, 64 x3, chunked, prefix hit
+    assert eng.stats["prefix_prefills"] - before["prefix_prefills"] == 1
+    # one span around all of a step's (or a group's) tokens, never one each
+    assert names.count("engine.emit") == steps + groups
+    assert names.count("engine.sample_readback") == steps
+    assert eng.stats["tokens_generated"] - before["tokens_generated"] \
+        > 2 * names.count("engine.emit")
+    attrs = [a for n, a, _, _ in driven if n == "engine.prefill"]
+    assert {"bucket": 64, "n": 3, "n_pad": 4} in attrs
+    assert all(set(a) == {"bucket", "n", "n_pad"} for a in attrs)
+    assert all(not a for n, a, _, _ in driven if n != "engine.prefill")
+
+
+def test_admission_and_prefill_counts_are_exact(tiny_model):
+    eng = make_engine(tiny_model)
+    eng.generate([distinct(40, 10 * k) for k in range(3)],
+                 SamplingParams(max_tokens=2))
+    assert eng.stats["admitted"] == 3
+    assert eng.stats["prefill_tokens"] == 120
+    assert eng.stats["prefill_padded_tokens"] == 4 * 64
+    assert eng.stats["prefills"] == 1
+    assert eng.stats["queue_wait_s"] > 0.0
+    # longer than the largest bucket: its chunks count, 64 + 36 tokens,
+    # the second padded to the smallest bucket that holds it
+    eng = make_engine(tiny_model)
+    eng.generate([distinct(100, 400)], SamplingParams(max_tokens=2))
+    assert eng.stats["admitted"] == 1 and eng.stats["prefills"] == 2
+    assert eng.stats["prefill_tokens"] == 100
+    assert eng.stats["prefill_padded_tokens"] == 64 + 64
+
+
+def test_a_preempted_request_is_admitted_and_waits_again(tiny_model):
+    eng = make_engine(tiny_model, max_slots=2, max_seq=64,
+                      prefill_buckets=(8, 16), num_blocks=5)
+    reqs = eng.generate([distinct(6, 0), distinct(6, 50)],
+                        SamplingParams(max_tokens=20))
+    assert all(len(r.output) == 20 for r in reqs)
+    assert eng.stats["preemptions"] >= 1
+    assert eng.stats["admitted"] == 2 + eng.stats["preemptions"]
+    assert all(r.queued_at > r.submitted_at for r in reqs if r.preemptions)
+
+
+def test_stats_keys_are_fixed_plain_monotone_and_the_phases_sum_to_the_step():
+    from ray_tpu.llm.serving import LLMConfig, LLMServer
+
+    server = LLMServer(LLMConfig(max_slots=2, max_seq=128))
+    try:
+        eng = server.engine
+        first = server.stats()
+        assert set(PHASES.values()) | {
+            "t_step_s", "cpu_host_s", "admitted", "queue_wait_s",
+            "prefill_tokens", "prefill_padded_tokens"} <= set(first)
+        assert all(type(v) in (int, float) for v in first.values())
+        snaps = [first]
+        for k in range(3):
+            req = eng.submit(distinct(12 + k, 60 * k),
+                             SamplingParams(max_tokens=5))
+            assert req.done.wait(120)
+            snaps.append(server.stats())
+        while server.stats()["t_idle_s"] == snaps[-1]["t_idle_s"]:
+            assert server._thread.is_alive()
+            server._stop.wait(0.002)
+        snaps.append(server.stats())
+    finally:
+        server._stop.set()
+        server._thread.join(30)
+    assert not server._thread.is_alive()
+    last = server.stats()
+    for a, b in zip(snaps, snaps[1:] + [last]):
+        assert set(a) == set(b) == set(first)
+        assert all(b[k] >= a[k] for k in a), (a, b)
+    assert json.loads(json.dumps(last)) == last
+    assert all(last[k] > 0.0 for k in PHASES.values())
+    assert 0.0 < sum(last[k] for k in IN_STEP) <= last["t_step_s"]
+    assert 0.0 < last["cpu_host_s"] <= last["t_step_s"]
+
+
+def test_the_real_profiler_records_the_phases_on_its_host_plane(tiny_model,
+                                                                tmp_path):
+    from jax.profiler import ProfileData
+
+    eng = make_engine(tiny_model)
+    eng.generate([distinct(5, 0)], SamplingParams(max_tokens=2))   # compile
+    options = jax.profiler.ProfileOptions()
+    options.python_tracer_level = 0
+    jax.profiler.start_trace(str(tmp_path), profiler_options=options)
+    try:
+        eng.generate([distinct(9, 100)], SamplingParams(max_tokens=3))
+    finally:
+        jax.profiler.stop_trace()
+    path, = glob.glob(os.path.join(str(tmp_path), "plugins", "profile", "*",
+                                   "*.xplane.pb"))
+    seen = {}
+    for plane in ProfileData.from_file(path).planes:
+        if not plane.name.startswith("/host:"):
+            continue
+        for line in plane.lines:
+            for ev in line.events:
+                if ev.name.startswith("engine."):
+                    seen.setdefault(ev.name, []).append(ev)
+    # (an engine left idling by an earlier test file adds ``engine.idle``)
+    assert set(PHASES) - {"engine.idle"} <= set(seen) <= set(PHASES)
+    assert all(ev.duration_ns > 0 for evs in seen.values() for ev in evs)
+    prefill, = seen["engine.prefill"]
+    assert dict(prefill.stats) == {"bucket": 16, "n": 1, "n_pad": 1}
+
+
+def test_program_names_the_benchmark_readers_match_are_pinned(tiny_model):
+    """``benchmark/metrics/decode_program_ms.*`` match ``decode_step_paged``
+    and ``prefill_program_ms_per_ktok`` matches ``prefill`` in the names
+    of the trace's ``XLA Modules`` events, which are the lowered
+    modules' names: a rename makes those metrics read nothing."""
+    import jax.numpy as jnp
+
+    model, params = tiny_model
+    eng = make_engine(tiny_model)
+    i32 = jnp.int32
+
+    def module_name(jitted, *args):
+        text = jitted.lower(*args).as_text()
+        return re.search(r"module @(\S+)", text).group(1)
+
+    B, nb = eng.max_slots, eng.blocks_per_slot
+    assert module_name(
+        eng._decode, params, jnp.zeros(B, i32), eng.kv,
+        jnp.zeros((B, nb), i32), jnp.zeros(B, i32)) == "jit_decode_step_paged"
+    bucket = module_name(eng._prefill, params, jnp.zeros((1, 16), i32),
+                         jnp.ones(1, i32))
+    pk, pv = eng._gather(eng.kv, jnp.zeros((1, 1), i32))
+    chunk = module_name(eng._prefill_prefix, params, jnp.zeros((1, 16), i32),
+                        pk, pv, jnp.zeros(1, i32), jnp.ones(1, i32))
+    for name in (bucket, chunk):
+        assert name.startswith("jit_") and "prefill" in name, name

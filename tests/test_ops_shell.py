@@ -189,26 +189,6 @@ def test_debug_state_and_loop_instrumentation(ray_start_regular):
     assert node.loop_stats["max_queue_lag_ms"] >= 0
 
 
-def test_tracing_spans(ray_start_regular):
-    from ray_tpu.util import tracing
-
-    @ray_tpu.remote
-    def traced_work():
-        return 1
-
-    tracing.enable_tracing()
-    try:
-        ray_tpu.get([traced_work.remote() for _ in range(3)])
-        spans = tracing.get_spans()
-        named = [s for s in spans if "traced_work" in s["name"]]
-        assert len(named) >= 3
-        assert all(s["end_ns"] > s["start_ns"] for s in named)
-        assert tracing.chrome_trace()
-    finally:
-        tracing.disable_tracing()
-        tracing.clear_spans()
-
-
 def test_gcs_kv_snapshot_restore(ray_start_regular, tmp_path):
     from ray_tpu._private import worker as _worker
 
