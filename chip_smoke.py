@@ -189,8 +189,10 @@ def sizes() -> dict:
         gpt2=GPT2Config.gpt2_125m(), gpt2_batch=(8, 1024),
         sharded_cfg=dataclasses.replace(llama, max_seq_len=2048),
         sharded_batch=(8, 2048),
-        # (slots, H, Hkv, D): llama3_1b's and bench_400m's attention
-        kernel_shapes=((MAX_SLOTS, 32, 8, 64), (MAX_SLOTS, 8, 4, 128)),
+        # (slots, H, Hkv, D): llama3_1b's and bench_400m's attention, and
+        # what the benchmark's serve cells decode (mistral-7b at 32 slots)
+        kernel_shapes=((MAX_SLOTS, 32, 8, 64), (MAX_SLOTS, 8, 4, 128),
+                       (32, 32, 8, 128)),
         kernel_seq=2048)
 
 
@@ -217,15 +219,22 @@ def kernels_phase(rep: Report, sz: dict) -> None:
     import jax.numpy as jnp
     import numpy as np
 
-    from ray_tpu._private.platform import pallas_interpret
+    from ray_tpu._private.platform import on_chip, pallas_interpret
     from ray_tpu.ops.attention import _flash_forward, reference_attention
     from ray_tpu.ops.paged_attention import (
-        paged_decode_attention_pallas, paged_decode_attention_reference)
+        default_impl, paged_decode_attention_pallas,
+        paged_decode_attention_reference)
 
     interpret = pallas_interpret()
     rep.check("Pallas interprets only on a CPU backend",
               interpret == (jax.default_backend() == "cpu"),
               f"interpret={interpret}, backend={jax.default_backend()}")
+    picked = {(D, Hkv): default_impl(D, Hkv)
+              for _, _, Hkv, D in sz["kernel_shapes"]}
+    rep.check("unforced, paged decode takes the kernel exactly on the chip",
+              set(picked.values()) == {"pallas" if on_chip() else "xla"},
+              f"default_impl by (head_dim, kv_heads): {picked}, "
+              f"on chip: {on_chip()}")
 
     def max_diff(a, b) -> float:
         return float(jnp.max(jnp.abs(a.astype(jnp.float32)
@@ -423,8 +432,10 @@ def check_engine(rep: Report, eng, impl: str) -> None:
          "sample")))
     rep.check("decode holds a Mosaic kernel exactly when it should",
               programs["decode"]["mosaic"] == (impl == "pallas"
-                                               and on_chip()),
+                                               and on_chip())
+              and eng.decode_attention_impl == impl,
               f"decode_attention={impl!r}, on chip: {on_chip()}, "
+              f"engine built with {eng.decode_attention_impl!r}, "
               f"tpu_custom_call in compiled text: "
               f"{programs['decode']['mosaic']}")
 

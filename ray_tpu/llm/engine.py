@@ -219,6 +219,10 @@ class ContinuousBatchingEngine:
         self.error: Optional[BaseException] = None   # set once, by run_forever
 
         # jitted programs ------------------------------------------------
+        # "pallas" (the Mosaic kernel that reads only the live blocks)
+        # or "xla" (the gather over every table): chosen by the model
+        # from its configuration and the platform when the engine is built
+        self.decode_attention_impl = model.paged_decode_impl()
         self._decode = jax.jit(model.decode_step_paged,
                                donate_argnums=(2,))
         self._prefill = jax.jit(self._prefill_impl)
@@ -239,6 +243,8 @@ class ContinuousBatchingEngine:
                       "preemptions": 0,
                       "admitted": 0, "queue_wait_s": 0.0,
                       "prefill_tokens": 0, "prefill_padded_tokens": 0,
+                      "decode_kv_blocks_live": 0,
+                      "decode_kv_blocks_table": 0,
                       "t_step_s": 0.0, "t_schedule_s": 0.0,
                       "t_prefill_s": 0.0, "t_host_arrays_s": 0.0,
                       "t_enqueue_s": 0.0, "t_readback_s": 0.0,
@@ -658,6 +664,14 @@ class ContinuousBatchingEngine:
         with _Phase(self, "engine.decode_enqueue", "t_enqueue_s"):
             toks = self._sample(logits, temps, top_ks, sub)
             del temps, top_ks
+        # what the dispatched program's attention reads (the kernel:
+        # ceil((offset + 1) / bs) blocks a slot) of what its tables hold;
+        # counted under the running program, in no phase's span
+        bs = self.block_size
+        self.stats["decode_kv_blocks_live"] += int(
+            ((self.offsets[active] + bs) // bs).sum())
+        self.stats["decode_kv_blocks_table"] += (
+            len(active) * self.blocks_per_slot)
         with _Phase(self, "engine.sample_readback", "t_readback_s",
                     waits=True):
             toks = np.asarray(toks)

@@ -61,10 +61,14 @@ class LlamaConfig:
     # Pallas flash tile sizes (the per-grid-step overhead vs VMEM dial)
     flash_block_q: int = 128
     flash_block_k: int = 128
-    # KV-cache decode attention: "xla" masked fallback or the "pallas"
-    # ragged kernel (skips KV blocks past each slot's length —
-    # ops/decode_attention.py).
-    decode_attention: str = "xla"
+    # KV-cache decode attention. None (the default): the paged decode
+    # program takes the Mosaic kernel on a TPU backend and the XLA
+    # reference elsewhere (``LlamaModel.paged_decode_impl``,
+    # ops/paged_attention.py); "xla" / "pallas" force a side (tests,
+    # chip_smoke.py). ``forward_step``'s T == 1 branch takes its
+    # slot-major kernel (ops/decode_attention.py, which does not lower
+    # for the v5e) only on an explicit "pallas".
+    decode_attention: Optional[str] = None
 
     def __post_init__(self):
         if self.attention_impl not in ("ring", "ulysses", "flash", "xla"):
@@ -75,9 +79,9 @@ class LlamaConfig:
             raise ValueError(
                 f"remat_policy must be 'full' or 'dots', "
                 f"got {self.remat_policy!r}")
-        if self.decode_attention not in ("xla", "pallas"):
+        if self.decode_attention not in (None, "xla", "pallas"):
             raise ValueError(
-                f"decode_attention must be 'xla' or 'pallas', "
+                f"decode_attention must be None, 'xla' or 'pallas', "
                 f"got {self.decode_attention!r}")
 
     @property
@@ -435,6 +439,20 @@ class LlamaModel:
         return {"k": jnp.zeros(shape, cfg.dtype),
                 "v": jnp.zeros(shape, cfg.dtype)}
 
+    def paged_decode_impl(self) -> str:
+        """The attention ``decode_step_paged`` is built with, and the one
+        place that decides it: what the configuration forces; else under
+        a mesh the XLA reference (the kernel is one chip's program and
+        carries no partitioning rule); else the platform's
+        (``ops.paged_attention.default_impl``: the Mosaic kernel on a
+        TPU backend)."""
+        from ray_tpu.ops.paged_attention import default_impl
+        if self.cfg.decode_attention is not None:
+            return self.cfg.decode_attention
+        if self.mesh is not None:
+            return "xla"
+        return default_impl(self.cfg.head_dim, self.cfg.n_kv_heads)
+
     def decode_step_paged(self, params: Params, tokens: jax.Array,
                           pool: Params, block_tables: jax.Array,
                           offsets: jax.Array
@@ -458,7 +476,7 @@ class LlamaModel:
         with jax.named_scope("embed"):
             x = self._embed_lookup(params["embed"].astype(cfg.dtype),
                                    tokens[:, None])                # [B,1,D]
-        impl = "pallas" if cfg.decode_attention == "pallas" else "xla"
+        impl = self.paged_decode_impl()
         from ray_tpu.ops.paged_attention import paged_decode_attention
 
         def block(carry, layer_and_pool):

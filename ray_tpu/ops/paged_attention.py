@@ -9,17 +9,29 @@ TPU-native design:
   each slot's logical sequence is a list of physical block ids (the
   block table). Blocks are immutable once full, so identical prompt
   prefixes SHARE physical blocks (see ``llm/paged_cache.py``).
-- ``paged_decode_attention`` — dispatcher (XLA gather fallback or the
-  Pallas kernel).
-- ``paged_decode_attention_pallas`` — flash-style online-softmax,
-  grid (batch, logical_block). The block table and lengths ride scalar
-  prefetch: the KV BlockSpec index map translates LOGICAL block ``kb``
-  of slot ``b`` to PHYSICAL ``tables[b, kb]`` — the kernel never sees
-  more than ``ceil(length/bs)`` blocks per slot, and no gather of the
-  pool into a dense cache ever materializes.
+- ``paged_decode_attention`` — the Mosaic kernel or the XLA gather
+  reference (the kernel's oracle), as ``impl`` says; ``default_impl`` is
+  the platform's side of that choice (``LlamaModel.paged_decode_impl``
+  is the one place that makes it).
+- ``paged_decode_attention_pallas`` — flash-style online softmax, grid
+  (batch,). The pools stay in HBM; the block table and the lengths ride
+  scalar prefetch, and each slot loops over ITS OWN live chunks of
+  ``CHUNK_ROWS`` rows of pages: the pages' copies (one contiguous
+  ``[bs*Hkv, D]`` tile each) start together into one contiguous
+  ``[pages*bs*Hkv, D]`` VMEM tile, double-buffered across chunks and
+  across slots (a slot's last chunk starts the next slot's first). A
+  block past ``ceil(length/bs)`` is never copied, there is no grid step
+  per dead block, and no dense view of the pool ever materializes.
 - GQA stays grouped: the pool keeps Hkv heads and nothing is repeated,
-  in HBM or in VMEM — each KV head's tile meets its own group of query
-  rows in a 2-D dot.
+  in HBM or in VMEM. A page is read as the 2-D tile ``[bs*Hkv, D]`` it
+  already is in HBM (rows are (token, kv head) pairs), one 2-D dot
+  scores all H query heads against a chunk's rows, an additive bias
+  drops the rows of the other KV heads, and one more dot weighs V.
+- Heads narrower than the 128 lanes (D 64: llama3_1b, gpt2) are read
+  ``128 // D`` KV heads to a row, the same bytes viewed as
+  ``[bs*Hkv*D/128, 128]``: q sits in its own head's lanes of a zero
+  row, so the same dot scores it against that head alone, and the
+  wrapper takes each q head's own lanes of the output.
 
 Shapes: q [B, H, D]; k_pool/v_pool [NB, bs, Hkv, D];
 block_tables [B, MAXB] int32 (physical ids; entries past a slot's
@@ -29,14 +41,18 @@ length are ignored); lengths [B] int32.
 from __future__ import annotations
 
 import functools
+import logging
+import math
 from typing import Optional
 
 import jax
 import jax.numpy as jnp
 
-from ray_tpu._private.platform import pallas_interpret
+from ray_tpu._private.platform import on_chip, pallas_interpret
 from ray_tpu.ops.attention import NEG_INF
 from ray_tpu.ops.decode_attention import ragged_decode_attention_reference
+
+logger = logging.getLogger(__name__)
 
 
 def paged_decode_attention_reference(q, k_pool, v_pool, block_tables,
@@ -54,58 +70,127 @@ def paged_decode_attention_reference(q, k_pool, v_pool, block_tables,
     return ragged_decode_attention_reference(q, k, v, lengths, scale=scale)
 
 
-def _paged_kernel(lens_ref, tables_ref, q_ref, k_ref, v_ref, o_ref,
-                  m_ref, l_ref, acc_ref, *, block_size: int, scale: float,
-                  num_kb: int, kv_heads: int, head_dim: int):
+def _paged_kernel(lens_ref, tables_ref, q_ref, bias_ref, k_hbm, v_hbm, o_ref,
+                  k_buf, v_buf, sems, first_buf_ref, m_ref, l_ref, acc_ref,
+                  *, block_size: int, pages: int, max_blocks: int,
+                  scale: float, row_heads: int):
     import jax.experimental.pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
 
     b = pl.program_id(0)
-    kb = pl.program_id(1)
+    # a page: rows (token, kv head), or (token, group of packed kv heads)
+    page_rows = block_size * row_heads
+    chunk_len = pages * block_size
     length = lens_ref[b]
 
-    @pl.when(kb == 0)
-    def _init():
-        m_ref[...] = jnp.full_like(m_ref, NEG_INF)
-        l_ref[...] = jnp.zeros_like(l_ref)
-        acc_ref[...] = jnp.zeros_like(acc_ref)
+    def live_pages(slot):
+        n = (lens_ref[slot] + block_size - 1) // block_size
+        return jnp.clip(n, 1, max_blocks)
 
-    start = kb * block_size
+    def chunk_copies(slot, chunk, buf, act: str):
+        """``act`` ("start" or "wait") on the page copies of one chunk of
+        one slot: page ``j`` of the slot's table lands at rows
+        ``[i*bs*Hkv, (i+1)*bs*Hkv)`` of buffer ``buf``, so the chunk is
+        one contiguous ``[pages*bs*Hkv, D]`` tile. Dead pages are not
+        copied at all."""
+        n_live = live_pages(slot)
+        for i in range(pages):
+            j = chunk * pages + i
 
-    @pl.when(start < length)
-    def _compute():
-        # One plain 2-D dot pair per KV head: Mosaic has no dot with a
-        # batch dim and no free lhs dim (what "hd,khd->hk" asks for).
-        # The head's G query rows sit on a leading ref dim and its K/V
-        # columns are a static lane slice of the [bs, Hkv*D] tile, so
-        # GQA needs no repeat and no in-kernel reshape. Operands stay in
-        # the pool dtype (bf16 on the chip), accumulation is f32.
-        for h in range(kv_heads):
-            cols = slice(h * head_dim, (h + 1) * head_dim)
-            q = q_ref[0, h]                                # [G, D]
-            k = k_ref[0, :, cols]                          # [bs, D]
-            v = v_ref[0, :, cols]
-            s = jax.lax.dot_general(
-                q, k, (((1,), (1,)), ((), ())),
-                preferred_element_type=jnp.float32) * scale    # [G, bs]
-            idx = start + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
-            s = jnp.where(idx < length, s, NEG_INF)
-            m_prev = m_ref[h, :, :1]                       # [G, 1]
-            l_prev = l_ref[h, :, :1]
-            m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
-            alpha = jnp.exp(m_prev - m_new)
-            p = jnp.exp(s - m_new)                         # [G, bs]
-            l_new = alpha * l_prev + jnp.sum(p, -1, keepdims=True)
-            acc_ref[h] = acc_ref[h] * alpha + jax.lax.dot_general(
-                p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
-                preferred_element_type=jnp.float32)        # [G, D]
-            m_ref[h] = jnp.broadcast_to(m_new, m_ref.shape[1:])
-            l_ref[h] = jnp.broadcast_to(l_new, l_ref.shape[1:])
+            @pl.when(j < n_live)
+            def _():
+                page = tables_ref[slot, j]
+                rows = pl.ds(i * page_rows, page_rows)
+                for n, (hbm, vmem) in enumerate(((k_hbm, k_buf),
+                                                 (v_hbm, v_buf))):
+                    getattr(pltpu.make_async_copy(
+                        hbm.at[page], vmem.at[buf, rows],
+                        sems.at[n, buf]), act)()
 
-    @pl.when(kb == num_kb - 1)
-    def _finish():
-        denom = l_ref[:, :, :1]
-        denom = jnp.where(denom == 0.0, 1.0, denom)
-        o_ref[0] = (acc_ref[...] / denom).astype(o_ref.dtype)
+    @pl.when(b == 0)
+    def _first():
+        first_buf_ref[0] = 0
+        # rows no copy ever fills meet p == 0 in the PV dot; what VMEM
+        # held before the call must not be a NaN there
+        v_buf[...] = jnp.zeros_like(v_buf)
+        chunk_copies(0, 0, 0, "start")
+
+    first_buf = first_buf_ref[0]
+    n_chunks = (live_pages(b) + pages - 1) // pages
+    m_ref[...] = jnp.full_like(m_ref, NEG_INF)
+    l_ref[...] = jnp.zeros_like(l_ref)
+    acc_ref[...] = jnp.zeros_like(acc_ref)
+
+    def chunk_body(c, carry):
+        buf = (first_buf + c) % 2
+        # the next chunk's pages (this slot's, or the next slot's first)
+        # fly while this one is computed
+        @pl.when(c + 1 < n_chunks)
+        def _():
+            chunk_copies(b, c + 1, 1 - buf, "start")
+
+        @pl.when(jnp.logical_and(c + 1 == n_chunks,
+                                 b + 1 < pl.num_programs(0)))
+        def _():
+            chunk_copies(b + 1, 0, 1 - buf, "start")
+
+        chunk_copies(b, c, buf, "wait")
+        # Rows of the chunk are (token, kv head) pairs, the pool's own
+        # order, so ONE 2-D dot scores every q head against every row
+        # and ``bias`` (0 where the row's kv head is the q head's own,
+        # NEG_INF elsewhere) drops the other heads' rows: GQA with no
+        # repeat, no per-head loop and no relayout of a page. Operands
+        # stay in the pool dtype (bf16 on the chip), accumulation is f32.
+        k = k_buf[buf]                                     # [T*Hkv, D]
+        v = v_buf[buf]
+        s = jax.lax.dot_general(
+            q_ref[0], k, (((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32)            # [H, T*Hkv]
+        s = s * scale + bias_ref[...]
+        row = jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
+        s = jnp.where(row < (length - c * chunk_len) * row_heads, s, NEG_INF)
+        m_prev = m_ref[:, :1]                              # [H, 1]
+        l_prev = l_ref[:, :1]
+        m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
+        alpha = jnp.exp(m_prev - m_new)
+        p = jnp.exp(s - m_new)
+        l_new = alpha * l_prev + jnp.sum(p, axis=-1, keepdims=True)
+        acc_ref[...] = acc_ref[...] * alpha + jax.lax.dot_general(
+            p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32)            # [H, D]
+        m_ref[...] = jnp.broadcast_to(m_new, m_ref.shape)
+        l_ref[...] = jnp.broadcast_to(l_new, l_ref.shape)
+        return carry
+
+    jax.lax.fori_loop(0, n_chunks, chunk_body, 0)
+    first_buf_ref[0] = (first_buf + n_chunks) % 2
+
+    # length 0: every row was masked, and the output is 0, not their mean
+    out = jnp.where(length > 0, acc_ref[...] / l_ref[:, :1], 0.0)
+    o_ref[0] = out.astype(o_ref.dtype)
+
+
+# Rows of a chunk (a page is bs * Hkv rows): what bounds the kernel's
+# VMEM (2 x 2 tiles of CHUNK_ROWS x lanes, and [H, CHUNK_ROWS] f32 of
+# bias and scores) at any block_size. On the v5e at 32-token pages of
+# 8 KV heads x 128 (kernel alone, 81 MB of live K/V), pages a chunk:
+# 2 -> 0.225 ms, 4 -> 0.147, 8 -> 0.127, 16 -> 0.119, but 16 costs a
+# one-page slot 0.055 ms for 0.038 (PERF.md, PR 25). 8 of those pages:
+CHUNK_ROWS = 2048
+LANES = 128
+
+
+def _lane_pack(head_dim: int, kv_heads: int) -> int:
+    """KV heads the kernel reads as one row: as many as fill the 128
+    lanes, where the head count divides so."""
+    return math.gcd(LANES // head_dim, kv_heads) if LANES % head_dim == 0 \
+        else 1
+
+
+def kernel_lowers(head_dim: int, kv_heads: int) -> bool:
+    """Mosaic copies a page as the 2-D tile it is in HBM, and a tile's
+    lanes are 128 wide: the (packed) row has to fill them."""
+    return _lane_pack(head_dim, kv_heads) * head_dim % LANES == 0
 
 
 @functools.partial(jax.jit, static_argnames=("scale", "interpret"))
@@ -119,60 +204,101 @@ def paged_decode_attention_pallas(q, k_pool, v_pool, block_tables,
     B, H, D = q.shape
     NB, bs, Hkv, _ = k_pool.shape
     maxb = block_tables.shape[1]
-    groups = H // Hkv
+    if not (interpret or kernel_lowers(D, Hkv)):
+        raise ValueError(
+            f"the paged decode kernel reads rows of 128 lanes on the chip: "
+            f"head_dim {D} x {Hkv} KV heads does not fill them; use the "
+            f"XLA reference (impl='xla')")
     scale = scale if scale is not None else D ** -0.5
+    pack = _lane_pack(D, Hkv)
+    row_heads, lanes = Hkv // pack, pack * D     # a row: ``pack`` KV heads
+    kv_head = jnp.arange(H) // (H // Hkv)        # of each q head
+    if pack > 1:
+        # q head h in the lanes of its KV head's place in the row, zeros
+        # in the other heads' lanes: the row's dot is q . k of that head
+        place = jax.nn.one_hot(kv_head % pack, pack, dtype=q.dtype)
+        q = (q[:, :, None, :] * place[None, :, :, None]).reshape(B, H, lanes)
+    page_rows = bs * row_heads
+    pages = max(1, min(maxb, CHUNK_ROWS // page_rows))
+    chunk_rows = pages * page_rows
     lengths = lengths.astype(jnp.int32)
     block_tables = block_tables.astype(jnp.int32)
 
-    def q_map(b, kb, lens, tables):
-        return (b, 0, 0, 0)
-
-    def kv_map(b, kb, lens, tables):
-        # logical->physical translation; past-length logical blocks clamp
-        # to the slot's last valid entry so the skipped iteration re-DMAs
-        # one already-resident block at worst
-        last_valid = jnp.maximum((lens[b] + bs - 1) // bs - 1, 0)
-        return (tables[b, jnp.minimum(kb, last_valid)], 0, 0)
+    # q head h attends row r of a chunk iff r's KV heads hold its own
+    own = (kv_head[:, None] // pack
+           == jnp.arange(chunk_rows)[None, :] % row_heads)
+    bias = jnp.where(own, 0.0, NEG_INF).astype(jnp.float32)
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
-        grid=(B, maxb),
+        grid=(B,),
         in_specs=[
-            pl.BlockSpec((1, Hkv, groups, D), q_map),
-            pl.BlockSpec((1, bs, Hkv * D), kv_map),
-            pl.BlockSpec((1, bs, Hkv * D), kv_map),
+            pl.BlockSpec((1, H, lanes), lambda b, lens, tables: (b, 0, 0)),
+            pl.BlockSpec((H, chunk_rows), lambda b, lens, tables: (0, 0)),
+            # the pools stay in HBM; the kernel copies the live pages
+            pl.BlockSpec(memory_space=pl.ANY),
+            pl.BlockSpec(memory_space=pl.ANY),
         ],
-        out_specs=pl.BlockSpec((1, Hkv, groups, D), q_map),
+        out_specs=pl.BlockSpec((1, H, lanes),
+                               lambda b, lens, tables: (b, 0, 0)),
         scratch_shapes=[
-            pltpu.VMEM((Hkv, groups, 128), jnp.float32),
-            pltpu.VMEM((Hkv, groups, 128), jnp.float32),
-            pltpu.VMEM((Hkv, groups, D), jnp.float32),
+            pltpu.VMEM((2, chunk_rows, lanes), k_pool.dtype),
+            pltpu.VMEM((2, chunk_rows, lanes), v_pool.dtype),
+            pltpu.SemaphoreType.DMA((2, 2)),
+            pltpu.SMEM((1,), jnp.int32),
+            pltpu.VMEM((H, 128), jnp.float32),
+            pltpu.VMEM((H, 128), jnp.float32),
+            pltpu.VMEM((H, lanes), jnp.float32),
         ],
     )
-    # free (contiguous) views: q heads grouped by their KV head, and each
-    # pool block as one 2-D [bs, Hkv*D] tile
+    # A pool block as one 2-D [bs*Hkv, D] tile, rows (token, kv head):
+    # at D % 128 == 0 the pool's own order in HBM under XLA's tiling of
+    # its two minor dims, so this view is free. ([bs, Hkv*D] is NOT: XLA
+    # copies the whole pool to build it, 0.6 ms a layer at 201 MB;
+    # PERF.md, PR 25.) A narrower D sits padded to the lanes in HBM, and
+    # XLA re-tiles the pool into the packed rows, one copy a layer.
     out = pl.pallas_call(
-        functools.partial(_paged_kernel, block_size=bs, scale=scale,
-                          num_kb=maxb, kv_heads=Hkv, head_dim=D),
+        functools.partial(_paged_kernel, block_size=bs, pages=pages,
+                          max_blocks=maxb, scale=scale, row_heads=row_heads),
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((B, Hkv, groups, D), q.dtype),
+        out_shape=jax.ShapeDtypeStruct((B, H, lanes), q.dtype),
+        # slots run in order: each starts the next one's first copies
         compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "arbitrary")),
+            dimension_semantics=("arbitrary",)),
         interpret=interpret,
-    )(lengths, block_tables, q.reshape(B, Hkv, groups, D),
-      k_pool.reshape(NB, bs, Hkv * D), v_pool.reshape(NB, bs, Hkv * D))
-    return out.reshape(B, H, D)
+    )(lengths, block_tables, q, bias,
+      k_pool.reshape(NB, page_rows, lanes),
+      v_pool.reshape(NB, page_rows, lanes))
+    if pack > 1:     # each q head's own lanes of its packed row
+        out = out.reshape(B, H, pack, D)[:, jnp.arange(H), kv_head % pack]
+    return out
+
+
+def default_impl(head_dim: int, kv_heads: int) -> str:
+    """The platform's choice where nobody forces one: the Mosaic kernel
+    on a TPU backend at widths it lowers for, the XLA reference (the
+    gather over every slot's whole table) elsewhere."""
+    if not on_chip():
+        return "xla"
+    if kernel_lowers(head_dim, kv_heads):
+        return "pallas"
+    logger.warning(
+        "paged decode attention falls back to the XLA gather on the chip: "
+        "head_dim %d x %d KV heads does not fill the kernel's 128 lanes",
+        head_dim, kv_heads)
+    return "xla"
 
 
 def paged_decode_attention(q, k_pool, v_pool, block_tables, lengths, *,
-                           impl: str = "xla",
-                           scale: Optional[float] = None,
-                           interpret: Optional[bool] = None):
+                           impl: str, scale: Optional[float] = None):
+    """One algorithm, two implementations: ``impl`` is "pallas" (the
+    kernel, interpreted where the backend is the CPU) or "xla" (its
+    oracle)."""
     if impl == "pallas":
-        if interpret is None:
-            interpret = pallas_interpret()
         return paged_decode_attention_pallas(
             q, k_pool, v_pool, block_tables, lengths, scale=scale,
-            interpret=interpret)
+            interpret=pallas_interpret())
+    if impl != "xla":
+        raise ValueError(f"impl must be 'xla' or 'pallas', got {impl!r}")
     return paged_decode_attention_reference(
         q, k_pool, v_pool, block_tables, lengths, scale=scale)

@@ -7,6 +7,7 @@ lowers through Mosaic for the v5e at the smoke's shapes (libtpu compiles
 for a described topology with no chip present).
 """
 
+import dataclasses
 import importlib.util
 import json
 import os
@@ -83,15 +84,21 @@ def test_compile_cache_defaults_to_the_checkout(monkeypatch):
 # Mosaic lowering for the v5e, no chip needed
 # ---------------------------------------------------------------------------
 @pytest.fixture(scope="module")
-def v5e():
-    """An abstract-array factory placed on a described v5e chip."""
+def v5e_topo():
     from jax.experimental import topologies
-    from jax.sharding import SingleDeviceSharding
 
     topo = topologies.get_topology_desc(platform="tpu",
                                         topology_name="v5e:2x2")
     assert topo.devices[0].device_kind == "TPU v5 lite"
-    sharding = SingleDeviceSharding(topo.devices[0])
+    return topo
+
+
+@pytest.fixture(scope="module")
+def v5e(v5e_topo):
+    """An abstract-array factory placed on a described v5e chip."""
+    from jax.sharding import SingleDeviceSharding
+
+    sharding = SingleDeviceSharding(v5e_topo.devices[0])
     return lambda *shape, dtype=jnp.bfloat16: jax.ShapeDtypeStruct(
         shape, dtype, sharding=sharding)
 
@@ -118,6 +125,159 @@ def test_paged_decode_kernel_lowers_for_v5e(v5e, smoke_sizes):
         assert _mosaic(paged_decode_attention_pallas.lower(
             v5e(B, H, D), pool, pool, v5e(B, maxb, dtype=jnp.int32),
             v5e(B, dtype=jnp.int32), interpret=False))
+
+
+@pytest.mark.parametrize("shape", [
+    (8, 32, 8, 128, 128, 16),     # a 128-token block: two pages a chunk
+    (8, 32, 8, 128, 512, 4),      # one page over the row budget
+    (8, 12, 12, 64, 16, 64),      # gpt2's widths, packed two heads a row
+    (8, 64, 8, 128, 32, 64),      # 64 q heads (llama-70b's attention)
+])
+def test_paged_decode_kernel_lowers_at_other_blocks_and_widths(v5e, shape):
+    """The kernel's VMEM is bounded by rows, not pages: it lowers (the
+    compiler refuses a kernel over its scoped VMEM) whatever block_size
+    the engine is built with."""
+    from ray_tpu.ops.paged_attention import paged_decode_attention_pallas
+
+    B, H, Hkv, D, bs, maxb = shape
+    pool = v5e(B * maxb + 1, bs, Hkv, D)
+    assert _mosaic(paged_decode_attention_pallas.lower(
+        v5e(B, H, D), pool, pool, v5e(B, maxb, dtype=jnp.int32),
+        v5e(B, dtype=jnp.int32), interpret=False))
+
+
+def test_paged_decode_kernel_refuses_widths_it_cannot_copy(v5e):
+    from ray_tpu.ops.paged_attention import paged_decode_attention_pallas
+
+    pool = v5e(65, 32, 8, 80)       # 128 lanes are no multiple of 80
+    with pytest.raises(ValueError, match="128 lanes"):
+        paged_decode_attention_pallas.lower(
+            v5e(8, 32, 80), pool, pool, v5e(8, 8, dtype=jnp.int32),
+            v5e(8, dtype=jnp.int32), interpret=False)
+
+
+# the serve cells' decode shapes (BENCHMARK.json: mistral-7b-v0.3-d6 at 32
+# slots, block_size 32): table entries a slot in batch_decode, chat_mixed
+CELL_SLOTS, CELL_BS, CELL_TABLES = 32, 32, (96, 64)
+
+
+@pytest.mark.parametrize("maxb", CELL_TABLES)
+def test_paged_decode_kernel_lowers_at_the_cells_shapes(v5e, maxb):
+    from ray_tpu.ops.paged_attention import paged_decode_attention_pallas
+
+    B, H, Hkv, D, bs = CELL_SLOTS, 32, 8, 128, CELL_BS
+    pool = v5e(B * maxb + 1, bs, Hkv, D)
+    assert _mosaic(paged_decode_attention_pallas.lower(
+        v5e(B, H, D), pool, pool, v5e(B, maxb, dtype=jnp.int32),
+        v5e(B, dtype=jnp.int32), interpret=False))
+
+
+def _as_on_the_chip(monkeypatch):
+    """This process's backend is the CPU, where the model rightly takes
+    the reference: tell it what it would see on the chip (the compile
+    is the v5e's)."""
+    from ray_tpu.ops import paged_attention
+
+    monkeypatch.setattr(paged_attention, "on_chip", lambda: True)
+    monkeypatch.setattr(paged_attention, "pallas_interpret", lambda: False)
+
+
+@pytest.mark.parametrize("maxb", CELL_TABLES)
+def test_decode_program_holds_the_kernel_on_a_tpu_backend(
+        v5e, monkeypatch, maxb):
+    """A whole ``decode_step_paged`` at the cells' widths (depth 1), no
+    ``decode_attention`` set. This process's backend is the CPU, where
+    the dispatcher rightly takes the reference, so the test tells the
+    dispatcher what it would see on the chip; the compile is the v5e's."""
+    from ray_tpu.models.llama import LlamaConfig, LlamaModel
+
+    _as_on_the_chip(monkeypatch)
+    B, bs = CELL_SLOTS, CELL_BS
+    model = LlamaModel(LlamaConfig(
+        vocab_size=32768, dim=4096, n_layers=1, n_heads=32, n_kv_heads=8,
+        ffn_dim=14336, max_seq_len=maxb * bs, rope_theta=1e6))
+    assert model.cfg.decode_attention is None
+    assert model.paged_decode_impl() == "pallas"
+
+    def placed(tree):
+        return jax.tree.map(lambda a: v5e(*a.shape, dtype=a.dtype), tree)
+
+    args = (placed(jax.eval_shape(model.init, jax.random.key(0))),
+            v5e(B, dtype=jnp.int32),
+            placed(jax.eval_shape(
+                lambda: model.init_kv_pool(B * maxb + 1, bs))),
+            v5e(B, maxb, dtype=jnp.int32), v5e(B, dtype=jnp.int32))
+    assert _mosaic(jax.jit(model.decode_step_paged,
+                           donate_argnums=(2,)).lower(*args))
+    # forced to the reference, the same program holds no kernel
+    ref = LlamaModel(dataclasses.replace(model.cfg, decode_attention="xla"))
+    assert not _mosaic(jax.jit(ref.decode_step_paged).lower(*args))
+
+
+def test_llama3_1b_decode_program_holds_the_kernel(v5e, monkeypatch,
+                                                   smoke_sizes):
+    """The smoke's served config as published (32/8 heads of 64), no
+    ``decode_attention`` set: two KV heads to a 128-lane row."""
+    from ray_tpu.models.llama import LlamaModel
+
+    _as_on_the_chip(monkeypatch)
+    cfg = dataclasses.replace(smoke_sizes["serve_cfg"], n_layers=1)
+    assert (cfg.n_heads, cfg.n_kv_heads, cfg.head_dim) == (32, 8, 64)
+    model = LlamaModel(cfg)
+    assert cfg.decode_attention is None
+    assert model.paged_decode_impl() == "pallas"
+    B, bs, maxb = 8, 32, cfg.max_seq_len // 32
+
+    def placed(tree):
+        return jax.tree.map(lambda a: v5e(*a.shape, dtype=a.dtype), tree)
+
+    assert _mosaic(jax.jit(model.decode_step_paged, donate_argnums=(2,)).lower(
+        placed(jax.eval_shape(model.init, jax.random.key(0))),
+        v5e(B, dtype=jnp.int32),
+        placed(jax.eval_shape(lambda: model.init_kv_pool(B * maxb + 1, bs))),
+        v5e(B, maxb, dtype=jnp.int32), v5e(B, dtype=jnp.int32)))
+
+
+def test_decode_under_a_mesh_keeps_the_reference(v5e_topo, monkeypatch):
+    """Why ``paged_decode_impl`` answers "xla" under a mesh: XLA refuses
+    to partition a Mosaic call, so the sharded decode program compiles
+    for the v5e's four chips only with the reference."""
+    import numpy as np
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+
+    from ray_tpu.models.llama import LlamaConfig, LlamaModel
+
+    _as_on_the_chip(monkeypatch)
+    mesh = Mesh(np.array(v5e_topo.devices).reshape(1, 4), ("fsdp", "tp"))
+    cfg = LlamaConfig(vocab_size=4096, dim=1024, n_layers=1, n_heads=8,
+                      n_kv_heads=4, ffn_dim=2048, max_seq_len=512)
+    B, bs, maxb = 8, 32, 16
+
+    def lower(model):
+        def on(sharding):
+            return lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype,
+                                                  sharding=sharding)
+        params = jax.tree.map(
+            lambda a, s: on(s)(a),
+            jax.eval_shape(model.init, jax.random.key(0)),
+            model.param_shardings())
+        pool = jax.tree.map(
+            on(NamedSharding(mesh, P(None, None, None, "tp", None))),
+            jax.eval_shape(lambda: model.init_kv_pool(B * maxb + 1, bs)))
+        rep = on(NamedSharding(mesh, P()))
+        ints = [rep(jax.ShapeDtypeStruct(s, jnp.int32))
+                for s in ((B,), (B, maxb), (B,))]
+        return jax.jit(model.decode_step_paged).lower(
+            params, ints[0], pool, ints[1], ints[2])
+
+    assert LlamaModel(cfg).paged_decode_impl() == "pallas"
+    sharded = LlamaModel(cfg, mesh=mesh)
+    assert sharded.paged_decode_impl() == "xla"
+    assert not _mosaic(lower(sharded))
+    forced = LlamaModel(dataclasses.replace(cfg, decode_attention="pallas"),
+                        mesh=mesh)
+    with pytest.raises(NotImplementedError, match="partitioned"):
+        lower(forced).compile()
 
 
 def test_flash_forward_kernel_lowers_for_v5e(v5e, smoke_sizes):

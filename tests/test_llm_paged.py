@@ -5,6 +5,8 @@ Reference capability: vLLM's BlockSpaceManager/prefix caching behind
 vllm_models.py:126-207`); PAPERS.md paged attention.
 """
 
+import functools
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -240,6 +242,191 @@ def test_paged_kernel_matches_reference():
                                         interpret=True)
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
                                atol=1e-4, rtol=1e-4)
+
+
+def _engine_like_case(name):
+    """What the engine really sends the kernel: tables padded with the
+    scratch block (the pool's last), lengths = offsets + 1. 72 table
+    entries a slot are three of the kernel's chunks (32 pages of 64
+    tokens). At D 32 four KV heads share a row of the kernel's pages
+    (``_lane_pack``), as two do at llama3_1b's D 64 on the chip;
+    "one_head_a_row" is the unpacked layout of the cells' D 128."""
+    rng = np.random.default_rng(5)
+    B, Hkv, G, D, bs, maxb = 4, 4, 4, 32, 64, 72
+    dtype, tol = jnp.float32, 2e-5
+    scratch = B * maxb
+    lengths = {
+        "idle_slot": [1, 2100, 1, 4200],
+        "block_boundary": [64, 2048, 4096, 4608],
+        "boundary_plus_one": [65, 2049, 4097, 4545],
+        "full_table": [4608, 4608, 4608, 4608],
+        "mixed_gqa4": [3, 1300, 2500, 4607],
+        "bf16_pool": [5, 1025, 2048, 4500],
+        "one_head_a_row": [3, 1300, 2500, 4607],
+        "empty_slot": [0, 1300, 0, 4607],
+    }[name]
+    if name == "bf16_pool":
+        dtype, tol = jnp.bfloat16, 2e-2
+    if name == "one_head_a_row":
+        Hkv, D = 1, 128
+    tables = rng.permutation(scratch).reshape(B, maxb)
+    for b, n in enumerate(lengths):
+        tables[b, -(-n // bs):] = scratch          # never allocated
+    if name == "idle_slot":
+        tables[[0, 2]] = scratch                    # the whole row
+    q = jnp.asarray(rng.normal(size=(B, Hkv * G, D)), dtype)
+    kp = jnp.asarray(rng.normal(size=(scratch + 1, bs, Hkv, D)), dtype)
+    vp = jnp.asarray(rng.normal(size=(scratch + 1, bs, Hkv, D)), dtype)
+    return (q, kp, vp, jnp.asarray(tables, jnp.int32),
+            jnp.asarray(lengths, jnp.int32)), tol
+
+
+@pytest.mark.parametrize("case", [
+    "idle_slot", "block_boundary", "boundary_plus_one", "full_table",
+    "mixed_gqa4", "bf16_pool", "one_head_a_row", "empty_slot"])
+def test_paged_kernel_on_engine_inputs(case):
+    from ray_tpu.ops.paged_attention import (
+        CHUNK_ROWS, _lane_pack, paged_decode_attention_pallas,
+        paged_decode_attention_reference)
+    args, tol = _engine_like_case(case)
+    _, bs, Hkv, D = args[1].shape
+    assert _lane_pack(D, Hkv) == (1 if case == "one_head_a_row" else 4)
+    page_rows = bs * Hkv // _lane_pack(D, Hkv)
+    assert args[3].shape[1] * page_rows > 2 * CHUNK_ROWS   # three chunks
+    ref = paged_decode_attention_reference(*args)
+    out = paged_decode_attention_pallas(*args, interpret=True)
+    assert out.shape == ref.shape and out.dtype == ref.dtype
+    live = np.asarray(args[4]) > 0
+    np.testing.assert_allclose(np.asarray(out, np.float32)[live],
+                               np.asarray(ref, np.float32)[live],
+                               atol=tol, rtol=tol)
+    # a slot of length 0 attends nothing: 0, not the mean of stale rows
+    assert not np.asarray(out, np.float32)[~live].any()
+
+
+@pytest.mark.parametrize("head_dim,kv_heads,pack,lowers", [
+    (128, 8, 1, True),      # the serve cells (mistral-7b)
+    (64, 8, 2, True),       # llama3_1b: two KV heads fill the 128 lanes
+    (64, 12, 2, True),      # gpt2's widths
+    (256, 4, 1, True),
+    (32, 8, 4, True),
+    (64, 1, 1, False),      # one KV head of 64 cannot fill a row
+    (80, 8, 1, False),      # 128 is no multiple of 80
+    (96, 8, 1, False),
+])
+def test_kernel_lane_packing_rule(head_dim, kv_heads, pack, lowers):
+    from ray_tpu.ops.paged_attention import _lane_pack, kernel_lowers
+    assert _lane_pack(head_dim, kv_heads) == pack
+    assert kernel_lowers(head_dim, kv_heads) is lowers
+
+
+def test_chunk_is_sized_by_rows_not_pages():
+    """The kernel's VMEM follows CHUNK_ROWS whatever the block_size: a
+    chunk holds as many whole pages as fit, at least one."""
+    from ray_tpu.ops.paged_attention import (CHUNK_ROWS,
+                                             paged_decode_attention_pallas)
+
+    def chunk_rows(bs, Hkv, D, maxb):
+        pool = jax.ShapeDtypeStruct((9, bs, Hkv, D), jnp.float32)
+        jaxpr = jax.make_jaxpr(functools.partial(
+            paged_decode_attention_pallas, interpret=True))(
+            jax.ShapeDtypeStruct((2, Hkv, D), jnp.float32), pool, pool,
+            jax.ShapeDtypeStruct((2, maxb), jnp.int32),
+            jax.ShapeDtypeStruct((2,), jnp.int32))
+        call = [e for e in jaxpr.jaxpr.eqns[0].params["jaxpr"].eqns
+                if e.primitive.name == "pallas_call"][0]
+        return call.invars[3].aval.shape[1]      # the bias: [H, rows]
+
+    assert chunk_rows(32, 8, 128, 96) == CHUNK_ROWS == 8 * 32 * 8
+    assert chunk_rows(128, 8, 128, 24) == CHUNK_ROWS       # 2 pages
+    assert chunk_rows(32, 8, 64, 64) == CHUNK_ROWS         # 16 packed
+    assert chunk_rows(512, 8, 128, 6) == 512 * 8           # one page
+    assert chunk_rows(32, 8, 128, 4) == 4 * 32 * 8         # the table
+
+
+def _has_kernel(fn, *args) -> bool:
+    return "pallas_call" in str(jax.make_jaxpr(fn)(*args))
+
+
+def test_dispatcher_runs_the_side_it_is_told():
+    from ray_tpu.ops.paged_attention import (default_impl,
+                                             paged_decode_attention)
+    args, _ = _engine_like_case("mixed_gqa4")
+    # the platform's side of the choice: the reference on this backend
+    assert jax.default_backend() == "cpu"
+    assert default_impl(128, 8) == default_impl(64, 8) == "xla"
+    forced = functools.partial(paged_decode_attention, impl="pallas")
+    oracle = functools.partial(paged_decode_attention, impl="xla")
+    assert _has_kernel(forced, *args)
+    assert not _has_kernel(oracle, *args)
+    np.testing.assert_allclose(np.asarray(forced(*args)),
+                               np.asarray(oracle(*args)),
+                               atol=2e-5, rtol=2e-5)
+    with pytest.raises(ValueError):
+        paged_decode_attention(*args, impl="mosaic")
+    with pytest.raises(TypeError):       # nobody resolves in here
+        paged_decode_attention(*args)
+
+
+def test_decode_program_choice_by_configuration(tiny_model):
+    """No ``decode_attention`` set: the platform decides for
+    ``decode_step_paged`` (the reference on this CPU backend); a set one
+    forces its side; ``forward_step`` at T == 1 never follows the
+    default into the slot-major kernel."""
+    import dataclasses
+    model, params = tiny_model
+    assert LlamaConfig().decode_attention is None
+    assert model.cfg.decode_attention is None
+    assert model.paged_decode_impl() == "xla"
+    forced = LlamaModel(dataclasses.replace(model.cfg,
+                                            decode_attention="pallas"))
+    assert forced.paged_decode_impl() == "pallas"
+    with pytest.raises(ValueError):
+        dataclasses.replace(model.cfg, decode_attention="auto")
+
+    pool = model.init_kv_pool(5, 8)
+    step_args = (params, jnp.zeros((2,), jnp.int32), pool,
+                 jnp.zeros((2, 2), jnp.int32), jnp.zeros((2,), jnp.int32))
+    assert not _has_kernel(model.decode_step_paged, *step_args)
+    assert _has_kernel(forced.decode_step_paged, *step_args)
+    cache = model.init_kv_cache(2, 16)
+    fwd_args = (params, jnp.zeros((2, 1), jnp.int32), cache,
+                jnp.zeros((2,), jnp.int32))
+    assert not _has_kernel(model.forward_step, *fwd_args)
+
+    eng = ContinuousBatchingEngine(model, params, max_slots=2, max_seq=32,
+                                   prefill_buckets=(8,), block_size=8)
+    assert eng.decode_attention_impl == "xla"
+    eng_p = ContinuousBatchingEngine(forced, params, max_slots=2,
+                                     max_seq=32, prefill_buckets=(8,),
+                                     block_size=8)
+    assert eng_p.decode_attention_impl == "pallas"
+
+
+def test_engine_counts_the_blocks_decode_reads(tiny_model):
+    model, params = tiny_model
+    eng = ContinuousBatchingEngine(model, params, max_slots=4, max_seq=64,
+                                   prefill_buckets=(8, 16), block_size=8)
+    assert eng.stats["decode_kv_blocks_live"] == 0
+    assert eng.stats["decode_kv_blocks_table"] == 0
+    eng.submit([3, 1, 4, 1, 5], SamplingParams(max_tokens=30))
+    eng.submit(list(range(1, 14)), SamplingParams(max_tokens=30))
+    live = table = 0
+    for _ in range(6):
+        before = eng.stats["decode_steps"]
+        eng.step()
+        assert eng.stats["decode_steps"] == before + 1
+        # after the step each live slot's offset counts the token the
+        # step cached: the program read ceil(offset / bs) blocks of it
+        offs = [int(eng.offsets[i]) for i, r in enumerate(eng.slots)
+                if r is not None]
+        assert len(offs) == 2
+        live += sum(-(-o // 8) for o in offs)
+        table += len(offs) * eng.blocks_per_slot
+        assert eng.stats["decode_kv_blocks_live"] == live
+        assert eng.stats["decode_kv_blocks_table"] == table
+    assert eng.blocks_per_slot == 8 and table == 6 * 2 * 8
+    assert 0 < live < table
 
 
 def test_paged_reference_gather_equals_dense():
