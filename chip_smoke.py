@@ -9,7 +9,9 @@ in ONE process (the one that owns the chip):
           serve.start_http_proxy -> Replica -> LLMServer ->
           ContinuousBatchingEngine, at the full widths of
           LlamaConfig.llama3_1b() (max_seq_len cut to 1024), once per
-          decode-attention implementation ("xla", then "pallas").
+          decode-attention implementation ("xla", then "pallas"); then
+          once with an expert model (OLMoE's block: MoEConfig with 16 =
+          16 heads of 128, experts of 2048 x 1024, few of them).
   train   JaxTrainer(...).fit() whose loop steps make_train_step on
           GPT2Config.gpt2_125m(); with >= 4 devices also a sharded
           llama3_1b step on MeshSpec.auto(4, fsdp=2, tp=2), and a look
@@ -171,10 +173,13 @@ def join_all(threads, what: str) -> None:
 def sizes() -> dict:
     from ray_tpu.models.gpt2 import GPT2Config
     from ray_tpu.models.llama import LlamaConfig
+    from ray_tpu.models.moe import MoEConfig
 
     if TINY:
         return dict(
             serve_cfg=LlamaConfig.debug(vocab_size=512, max_seq_len=MAX_SEQ),
+            moe_cfg=MoEConfig.debug_olmoe(vocab_size=512,
+                                          max_seq_len=MAX_SEQ),
             max_tokens=6,
             gpt2=GPT2Config.debug(), gpt2_batch=(4, 128),
             sharded_cfg=LlamaConfig(
@@ -185,14 +190,20 @@ def sizes() -> dict:
     llama = LlamaConfig.llama3_1b()
     return dict(
         serve_cfg=dataclasses.replace(llama, max_seq_len=MAX_SEQ),
+        # OLMoE's block at its published attention (16 = 16 heads of
+        # 128) and expert widths; few experts, layers and vocabulary rows
+        moe_cfg=MoEConfig.debug_olmoe(
+            vocab_size=4096, max_seq_len=MAX_SEQ, dim=2048, n_heads=16,
+            n_kv_heads=16, ffn_dim=1024, num_experts=16, expert_top_k=4),
         max_tokens=12,
         gpt2=GPT2Config.gpt2_125m(), gpt2_batch=(8, 1024),
         sharded_cfg=dataclasses.replace(llama, max_seq_len=2048),
         sharded_batch=(8, 2048),
         # (slots, H, Hkv, D): llama3_1b's and bench_400m's attention, and
         # what the benchmark's serve cells decode (mistral-7b at 32 slots)
+        # and OLMoE's 16 = 16 heads of 128
         kernel_shapes=((MAX_SLOTS, 32, 8, 64), (MAX_SLOTS, 8, 4, 128),
-                       (32, 32, 8, 128)),
+                       (32, 32, 8, 128), (32, 16, 16, 128)),
         kernel_seq=2048)
 
 
@@ -440,6 +451,17 @@ def check_engine(rep: Report, eng, impl: str) -> None:
               f"{programs['decode']['mosaic']}")
 
 
+def check_released(rep: Report, alive) -> None:
+    """The next phase needs the memory back: the replica is gone, so its
+    engine (weights, pool, loop thread; ``alive`` is a weak reference to
+    it) must be too."""
+    deadline = time.monotonic() + 60
+    while alive() is not None and time.monotonic() < deadline:
+        time.sleep(0.2)
+        gc.collect()
+    rep.check("engine released after serve.shutdown()", alive() is None)
+
+
 def serve_phase(rep: Report, sz: dict, impl: str, outputs: dict) -> None:
     import jax
 
@@ -488,14 +510,60 @@ def serve_phase(rep: Report, sz: dict, impl: str, outputs: dict) -> None:
         del server
     finally:
         serve.shutdown()
-    # the next phase needs the memory back: the replica is gone, so its
-    # engine (weights, pool, loop thread) must be too
-    deadline = time.monotonic() + 60
-    while alive() is not None and time.monotonic() < deadline:
-        time.sleep(0.2)
-        gc.collect()
-    rep.check("engine released after serve.shutdown()", alive() is None)
+    check_released(rep, alive)
     rep.info(mem_line(jax.devices()[:1]))
+
+
+# ---------------------------------------------------------------------------
+# phase: Serve -> engine, an expert model
+# ---------------------------------------------------------------------------
+def serve_moe_phase(rep: Report, sz: dict) -> None:
+    """The same path with a ``MoEConfig``: Serve picks the expert model,
+    the engine prefills (a bucket and a chunked prompt) and decodes it
+    through the platform's paged attention, and its dropless FFN
+    processed every chosen expert."""
+    from ray_tpu import serve
+    from ray_tpu._private.platform import on_chip
+    from ray_tpu.llm.serving import LLMConfig, build_llm_app
+    from ray_tpu.models.moe import MoEModel
+
+    cfg = sz["moe_cfg"]
+    model_id = "smoke-moe"
+    n = sz["max_tokens"]
+    prompts = {k: v for k, v in make_prompts(cfg.vocab_size).items()
+               if k in ("short", "long")}
+    handle = serve.run(build_llm_app(LLMConfig(
+        model_id=model_id, model_config=cfg, max_slots=MAX_SLOTS,
+        max_seq=MAX_SEQ)))
+    try:
+        server = local_servers(model_id)[0]
+        rep.check("Serve built the expert model from its config",
+                  type(server.model) is MoEModel
+                  and "e_gate" in server.engine.params["layers"],
+                  type(server.model).__name__)
+        got = {"short": ask_unary(handle, prompts["short"], n),
+               "long": ask_streamed(handle, prompts["long"], n)}
+        rep.check("two requests answered: unary, streamed (chunked prefill)",
+                  all(len(t) == n for t, _ in got.values()),
+                  ", ".join(f"{k}:{len(t)}tok/{r}"
+                            for k, (t, r) in got.items()))
+        check_tokens(rep, server, prompts, got)
+        eng = server.engine
+        st = eng.stats
+        rep.check("the expert FFN dropped nothing in decode",
+                  st["moe_assignments"] == st["moe_assignments_expected"]
+                  > 0, f"{st['moe_assignments']} rows of "
+                  f"{st['moe_assignments_expected']} over "
+                  f"{st['decode_steps']} decode steps")
+        impl = "pallas" if on_chip() else "xla"
+        rep.check("decode attention is the platform's",
+                  eng.decode_attention_impl == impl,
+                  f"engine built with {eng.decode_attention_impl!r}")
+        alive = weakref.ref(eng)
+        del server, eng
+    finally:
+        serve.shutdown()
+    check_released(rep, alive)
 
 
 # ---------------------------------------------------------------------------
@@ -771,6 +839,7 @@ def main() -> int:
         for impl in ("xla", "pallas"):
             rep.phase(f"serve llama decode_attention={impl}", serve_phase,
                       sz, impl, outputs)
+        rep.phase("serve olmoe-shaped expert model", serve_moe_phase, sz)
         rep.phase("train gpt2 on one device", train_phase, sz)
         if len(devices) >= 4:
             rep.phase("train llama sharded fsdp=2 x tp=2", sharded_phase, sz)

@@ -125,8 +125,8 @@ class _Phase:
     added to ``engine.stats[key]``. Phases are siblings that tile
     ``step()``; none encloses another (the gap attribution gives a
     device gap to the ONE span that covers most of it). ``waits`` marks
-    a phase that blocks on the device: its thread CPU time is kept out
-    of ``stats["cpu_host_s"]``."""
+    a phase that blocks on the device or runs under its program: its
+    thread CPU time is kept out of ``stats["cpu_host_s"]``."""
 
     __slots__ = ("engine", "name", "key", "waits", "attrs", "span", "t0",
                  "c0")
@@ -147,7 +147,7 @@ class _Phase:
 
     def __exit__(self, *exc):
         eng = self.engine
-        eng.stats[self.key] += time.perf_counter() - self.t0
+        eng._stats[self.key] += time.perf_counter() - self.t0
         if self.waits:
             eng._cpu_waiting += time.thread_time() - self.c0
         self.span.__exit__(*exc)
@@ -210,10 +210,27 @@ class ContinuousBatchingEngine:
         self._tables = np.full((max_slots, self.blocks_per_slot),
                                num_blocks, np.int32)
         self._admit_order: List[int] = []   # oldest-first slot ids
+        # The decode step's inputs live on the device from step to step
+        # and are sent again only when the host's copy changed (``None``
+        # = stale): what stands between a step's read-back and the next
+        # dispatch is host time the device idles in. ``_dev_tokens`` is
+        # the last sample's own output until a slot is activated;
+        # ``_last_tokens`` is the host's copy, kept by ``_emit``.
+        self._last_tokens = np.zeros(max_slots, np.int32)
+        self._dev_tokens = None
+        self._dev_tables = None
+        self._dev_offsets = None            # sent a step ahead, see there
+        self._dev_sampling = None           # (temperatures, top-ks)
         self.waiting: "deque[Request]" = deque()
         # popped from ``waiting`` but not yet in a slot: where a failed
         # prefill finds the requests it was carrying
         self._admitting: List[Request] = []
+        # (request, token) in order, ``None`` for a stream's end: what
+        # ``_emit`` decided and the streams have not been handed yet
+        self._undelivered: List[tuple] = []
+        # (tokens on the device, active slots) of the decode step that is
+        # dispatched and not read yet, between two ``step()``s
+        self._in_flight: Optional[tuple] = None
         self._lock = threading.Lock()
         self._rng_key = jax.random.key(0)
         self.error: Optional[BaseException] = None   # set once, by run_forever
@@ -223,8 +240,23 @@ class ContinuousBatchingEngine:
         # or "xla" (the gather over every table): chosen by the model
         # from its configuration and the platform when the engine is built
         self.decode_attention_impl = model.paged_decode_impl()
-        self._decode = jax.jit(model.decode_step_paged,
-                               donate_argnums=(2,))
+        # An expert model's FFN reports, each decode step, the rows it
+        # handed to each expert: summed ON THE DEVICE by the decode
+        # program itself and read only when ``stats`` is asked for, so
+        # no step gains a transfer. ``_ffn_counts`` pairs that array
+        # [layers, experts] with the host's count of what a dropless FFN
+        # must process; the loop replaces the pair after each dispatch.
+        load_shape = model.ffn_load_shape()
+        if load_shape is None:
+            self._ffn_counts = None
+            self._decode = jax.jit(model.decode_step_paged,
+                                   donate_argnums=(2,))
+        else:
+            self._ffn_counts = (jnp.zeros(load_shape, jnp.int32), 0)
+            self._ffn_rows_per_slot = (model.cfg.expert_top_k
+                                       * model.cfg.n_layers)
+            self._decode = jax.jit(self._decode_step_paged_counted,
+                                   donate_argnums=(2,))
         self._prefill = jax.jit(self._prefill_impl)
         self._prefill_prefix = jax.jit(model.prefill_with_prefix)
         self._insert = jax.jit(self._insert_impl, donate_argnums=(0,))
@@ -237,7 +269,7 @@ class ContinuousBatchingEngine:
         # seconds (``_Phase``); ``t_step_s`` is all of ``step()`` from
         # before it takes the lock; ``cpu_host_s`` is this thread's CPU
         # time in ``step()`` outside the phases that wait for the device.
-        self.stats = {"requests": 0, "tokens_generated": 0,
+        self._stats = {"requests": 0, "tokens_generated": 0,
                       "decode_steps": 0, "prefills": 0,
                       "prefix_prefills": 0, "prefix_tokens_reused": 0,
                       "preemptions": 0,
@@ -248,10 +280,40 @@ class ContinuousBatchingEngine:
                       "t_step_s": 0.0, "t_schedule_s": 0.0,
                       "t_prefill_s": 0.0, "t_host_arrays_s": 0.0,
                       "t_enqueue_s": 0.0, "t_readback_s": 0.0,
-                      "t_emit_s": 0.0, "t_idle_s": 0.0, "cpu_host_s": 0.0}
+                      "t_emit_s": 0.0, "t_deliver_s": 0.0, "t_idle_s": 0.0,
+                      "cpu_host_s": 0.0,
+                      # expert models (0 / empty for a dense one): rows
+                      # the expert FFN processed for live slots in decode
+                      # steps, what a dropless FFN must have processed
+                      # (live slots x top-k x layers, counted on the
+                      # host), and the rows per [layer][expert]
+                      "moe_assignments": 0, "moe_assignments_expected": 0,
+                      "moe_expert_load": []}
         self._cpu_waiting = 0.0     # CPU seconds of this step's waiting phases
 
+    @property
+    def stats(self) -> Dict[str, Any]:
+        """The counters (one dict, updated in place). Asking for them is
+        what reads an expert model's load back from the device."""
+        if self._ffn_counts is not None:
+            load, expected = self._ffn_counts       # one pair, one step
+            load = np.asarray(load)
+            self._stats.update(moe_assignments=int(load.sum()),
+                               moe_assignments_expected=expected,
+                               moe_expert_load=load.tolist())
+        return self._stats
+
     # -- jitted internals --------------------------------------------------
+    def _decode_step_paged_counted(self, params, tokens, pool, block_tables,
+                                   offsets, ffn_load):
+        """The model's decode step, with its FFN's per-expert rows of
+        the LIVE slots (an idle slot's table points at the scratch
+        block) added to ``ffn_load``."""
+        live = block_tables[:, 0] != self.num_blocks
+        logits, pool, extras = self.model.decode_step_paged_counted(
+            params, tokens, pool, block_tables, offsets, live)
+        return logits, pool, ffn_load + extras["load"]
+
     def _prefill_impl(self, params, tokens, lengths):
         """BATCHED prefill: tokens [N, Tb], lengths [N]; returns each
         request's last-valid-token logits [N, V] + a BUCKET-SIZED cache
@@ -310,7 +372,7 @@ class ContinuousBatchingEngine:
     def submit(self, prompt_tokens: List[int],
                sampling: Optional[SamplingParams] = None) -> Request:
         req = Request(prompt_tokens, sampling or SamplingParams())
-        self.stats["requests"] += 1
+        self._stats["requests"] += 1
         # deque.append is atomic — submitters never contend on the
         # engine-step lock (a step can span a whole prefill+decode)
         self.waiting.append(req)
@@ -332,9 +394,9 @@ class ContinuousBatchingEngine:
             self._cpu_waiting = 0.0
             self._admit()
             active = self._decode_step()
-            self.stats["cpu_host_s"] += (time.thread_time() - c0
+            self._stats["cpu_host_s"] += (time.thread_time() - c0
                                          - self._cpu_waiting)
-        self.stats["t_step_s"] += time.perf_counter() - t0
+        self._stats["t_step_s"] += time.perf_counter() - t0
         return active
 
     def _bucket_for(self, n: int) -> Optional[int]:
@@ -351,6 +413,8 @@ class ContinuousBatchingEngine:
         bucket). Pool exhaustion stops admission (FIFO order held)."""
         if not self.waiting:
             return
+        # a prefill would hold the last decode step's tokens up
+        self._deliver_deferred()
         with _Phase(self, "engine.schedule", "t_schedule_s"):
             by_shape, singles, by_bucket = self._plan_admission()
         for (pb_pad, s_bucket), group in by_shape.items():
@@ -390,8 +454,8 @@ class ContinuousBatchingEngine:
                 break
             alloc, shared_tok = alloc
             slot = free.pop(0)
-            self.stats["admitted"] += 1
-            self.stats["queue_wait_s"] += now - req.queued_at
+            self._stats["admitted"] += 1
+            self._stats["queue_wait_s"] += now - req.queued_at
             bucket = self._bucket_for(n)
             if shared_tok > 0 or bucket is None:
                 # prefix hit, or context longer than the largest
@@ -444,12 +508,12 @@ class ContinuousBatchingEngine:
                 toks[row, :len(seq)] = seq
                 ids = alloc.blocks[:nb]
                 block_ids[row * nb:row * nb + len(ids)] = ids
-                self.stats["prefill_tokens"] += len(seq)
+                self._stats["prefill_tokens"] += len(seq)
             last_logits, small = self._prefill(
                 self.params, jnp.asarray(toks), jnp.asarray(lengths))
             self.kv = self._insert(self.kv, small, jnp.asarray(block_ids))
-            self.stats["prefills"] += 1
-            self.stats["prefill_padded_tokens"] += n_pad * bucket
+            self._stats["prefills"] += 1
+            self._stats["prefill_padded_tokens"] += n_pad * bucket
             toks_out = self._sample_batch(
                 last_logits, [req for _, req, _ in group], n_pad)
         with _Phase(self, "engine.emit", "t_emit_s"):
@@ -457,6 +521,7 @@ class ContinuousBatchingEngine:
             for row, (slot, req, alloc) in enumerate(group):
                 self._activate(slot, req, alloc, int(lengths[row]), now)
                 self._emit(slot, int(toks_out[row]))
+            self._deliver()
 
     def _prefill_phase(self, bucket: int, n: int, n_pad: int) -> _Phase:
         """One admitted group's prefill, host work and device wait alike:
@@ -487,16 +552,16 @@ class ContinuousBatchingEngine:
                 slens[row] = len(suffix)
                 avail = alloc.blocks[pb:pb + nb]
                 block_ids[row * nb:row * nb + len(avail)] = avail
-                self.stats["prefix_prefills"] += 1
-                self.stats["prefix_tokens_reused"] += shared
-                self.stats["prefill_tokens"] += len(suffix)
+                self._stats["prefix_prefills"] += 1
+                self._stats["prefix_tokens_reused"] += shared
+                self._stats["prefill_tokens"] += len(suffix)
             pk, pv = self._gather(self.kv, jnp.asarray(ids))
             last_logits, small = self._prefill_prefix(
                 self.params, jnp.asarray(toks), pk, pv,
                 jnp.asarray(plens), jnp.asarray(slens))
             self.kv = self._insert(self.kv, small, jnp.asarray(block_ids))
-            self.stats["prefills"] += 1
-            self.stats["prefill_padded_tokens"] += n_pad * s_bucket
+            self._stats["prefills"] += 1
+            self._stats["prefill_padded_tokens"] += n_pad * s_bucket
             toks_out = self._sample_batch(
                 last_logits, [req for _, req, _, _ in group], n_pad)
         with _Phase(self, "engine.emit", "t_emit_s"):
@@ -505,6 +570,7 @@ class ContinuousBatchingEngine:
                 self._activate(slot, req, alloc, len(req.cache_tokens()),
                                now)
                 self._emit(slot, int(toks_out[row]))
+            self._deliver()
 
     def _prefill_chunk(self, alloc: SlotAllocation, seq: List[int],
                        pos: int, chunk_len: int):
@@ -534,9 +600,9 @@ class ContinuousBatchingEngine:
         block_ids[:len(avail)] = avail
         # chunk cache is [L, 1, Tb, ...]: reuse the batched scatter
         self.kv = self._insert(self.kv, small, jnp.asarray(block_ids))
-        self.stats["prefills"] += 1
-        self.stats["prefill_tokens"] += len(chunk)
-        self.stats["prefill_padded_tokens"] += s_bucket
+        self._stats["prefills"] += 1
+        self._stats["prefill_tokens"] += len(chunk)
+        self._stats["prefill_padded_tokens"] += s_bucket
         return last_logits
 
     def _admit_chunked(self, slot: int, req: Request,
@@ -549,8 +615,8 @@ class ContinuousBatchingEngine:
         seq = req.cache_tokens()
         n = len(seq)
         if shared_tok > 0:
-            self.stats["prefix_prefills"] += 1
-            self.stats["prefix_tokens_reused"] += shared_tok
+            self._stats["prefix_prefills"] += 1
+            self._stats["prefix_tokens_reused"] += shared_tok
         pos = shared_tok
         big = self.buckets[-1]
         last_logits = None
@@ -565,6 +631,7 @@ class ContinuousBatchingEngine:
         with _Phase(self, "engine.emit", "t_emit_s"):
             self._activate(slot, req, alloc, n, time.perf_counter())
             self._emit(slot, int(toks_out[0]))
+            self._deliver()
 
     def _activate(self, slot: int, req: Request, alloc: SlotAllocation,
                   n_cached: int, now: float) -> None:
@@ -576,6 +643,8 @@ class ContinuousBatchingEngine:
         self.offsets[slot] = n_cached
         self._tables[slot] = self.num_blocks
         self._tables[slot, :len(alloc.blocks)] = alloc.blocks
+        self._dev_tokens = self._dev_tables = self._dev_sampling = None
+        self._dev_offsets = None
         self._admit_order.append(slot)
 
     def _sample_batch(self, logits, reqs: List[Request], n_pad: int):
@@ -599,10 +668,11 @@ class ContinuousBatchingEngine:
         self.allocs[slot] = None
         self.offsets[slot] = 0
         self._tables[slot] = self.num_blocks   # idle writes go to scratch
+        self._dev_tables = self._dev_offsets = None
         self._admit_order.remove(slot)
         req.preemptions += 1
         req.queued_at = time.perf_counter()
-        self.stats["preemptions"] += 1
+        self._stats["preemptions"] += 1
         self.waiting.appendleft(req)
 
     def _grow_or_preempt(self) -> None:
@@ -614,6 +684,7 @@ class ContinuousBatchingEngine:
             if self.slots[slot] is None:
                 continue
             alloc = self.allocs[slot]
+            held = len(alloc.blocks)
             while not ensure_capacity(self.pool, alloc,
                                       int(self.offsets[slot]) + 1):
                 # chunked prefill re-admits ANY context length, so plain
@@ -625,74 +696,154 @@ class ContinuousBatchingEngine:
                 self._preempt(victim)
                 if victim == slot:
                     break
-            if self.slots[slot] is not None:
+            if self.slots[slot] is not None and len(alloc.blocks) != held:
                 self._tables[slot, :len(alloc.blocks)] = alloc.blocks
+                self._dev_tables = None
 
     def _decode_step(self) -> int:
+        """Read one decode step's tokens; before that, put the next step
+        on the device where nothing can come between (``_may_run_ahead``):
+        the device then goes from one step to the next with no host in
+        the way, and the read-back, the emit and the streams' work all
+        run under a program."""
+        if self._in_flight is None:
+            self._in_flight = self._dispatch_decode(ahead=False)
+            if self._in_flight is None:
+                self._deliver_deferred()
+                return 0
+        toks, active = self._in_flight
+        self._in_flight = (self._dispatch_decode(ahead=True)
+                           if self._may_run_ahead(active) else None)
+        # the step before's tokens reach their streams now, under the
+        # program just dispatched: each put wakes a stream thread, and
+        # their part of a token (~11 ms of Python for 32 streams) then
+        # runs while this thread waits for the device, not while the
+        # device waits for this thread to have the GIL back
+        self._deliver_deferred()
+        with _Phase(self, "engine.sample_readback", "t_readback_s",
+                    waits=True):
+            toks = np.asarray(toks)
+        with _Phase(self, "engine.emit", "t_emit_s"):
+            self._stats["decode_steps"] += 1
+            for i in active:
+                self.offsets[i] += 1
+                self._emit(i, int(toks[i]))
+        if self._in_flight is not None or not self._admit_order:
+            # the device is busy with the next step, or the last slot
+            # ended and there is no next dispatch to deliver under
+            self._deliver_deferred()
+        return len(active)
+
+    def _may_run_ahead(self, active: List[int]) -> bool:
+        """May the step after the one in flight be dispatched before the
+        one in flight is read? Only if that changes nothing for anyone:
+        every slot is taken (with one free, a request that arrives now
+        would find its prefill queued behind a whole further step: TTFT
+        paid for TPOT) and no request waits, nothing came in since the
+        dispatch (the device's inputs still stand), the step in flight
+        ends no request (none stops on a token's VALUE, none reaches
+        its length), and every slot has room for one more token without
+        a preemption."""
+        if (len(active) < self.max_slots or self.waiting
+                or self._dev_tokens is None or self._dev_offsets is None):
+            return False
+        for i in active:
+            req = self.slots[i]
+            sampling = req.sampling
+            if (sampling.stop_token_ids
+                    or len(req.output) + 1 >= sampling.max_tokens
+                    or self.offsets[i] + 2 >= self.max_seq):
+                return False
+        for i in active:
+            alloc = self.allocs[i]
+            held = len(alloc.blocks)
+            if not ensure_capacity(self.pool, alloc,
+                                   int(self.offsets[i]) + 2):
+                return False
+            if len(alloc.blocks) != held:
+                self._tables[i, :len(alloc.blocks)] = alloc.blocks
+                self._dev_tables = None
+        return True
+
+    def _dispatch_decode(self, ahead: bool):
+        """Enqueue one decode step and its sampling: ``(tokens on the
+        device, active slots)``, or None with no active slot. ``ahead``:
+        the step before is still in flight, so every active slot stands
+        one token further than the host's ``offsets`` say."""
         with _Phase(self, "engine.schedule", "t_schedule_s"):
-            self._grow_or_preempt()
+            if not ahead:
+                self._grow_or_preempt()
             active = [i for i, r in enumerate(self.slots) if r is not None]
         if not active:
-            return 0
+            return None
         # host arrays and dispatch alternate, in the order that puts the
         # decode program on the device first: what the host still does
         # for sampling then runs under it (building everything before
         # the first dispatch read 1.3 % fewer tokens/s: PERF.md, PR 24)
         with _Phase(self, "engine.host_arrays", "t_host_arrays_s"):
-            last_tokens = np.zeros(self.max_slots, np.int32)
-            temps = np.zeros(self.max_slots, np.float32)
-            top_ks = np.zeros(self.max_slots, np.int32)
-            for i in active:
-                req = self.slots[i]
-                last_tokens[i] = req.output[-1] if req.output else \
-                    (req.prompt[-1] if req.prompt else 0)
-                temps[i] = req.sampling.temperature
-                top_ks[i] = req.sampling.top_k
-            last_tokens, tables, offsets = (
-                jnp.asarray(last_tokens), jnp.asarray(self._tables),
-                jnp.asarray(self.offsets))
+            # ``jnp.array`` copies: these outlive the step, and the
+            # host's arrays change in place under them
+            if self._dev_tokens is None:
+                self._dev_tokens = jnp.array(self._last_tokens)
+            if self._dev_tables is None:
+                self._dev_tables = jnp.array(self._tables)
+            if self._dev_offsets is None:
+                self._dev_offsets = jnp.array(self.offsets)
+            offsets = self._dev_offsets
         # dispatch only: the calls return before the device is done
         with _Phase(self, "engine.decode_enqueue", "t_enqueue_s"):
-            logits, self.kv = self._decode(self.params, last_tokens,
-                                           self.kv, tables, offsets)
-            # a step's device inputs die under the running program:
-            # freeing a jax array releases the GIL (see ``emit`` below)
-            del last_tokens, tables, offsets
+            if self._ffn_counts is None:
+                logits, self.kv = self._decode(
+                    self.params, self._dev_tokens, self.kv,
+                    self._dev_tables, offsets)
+            else:
+                load, expected = self._ffn_counts
+                logits, self.kv, load = self._decode(
+                    self.params, self._dev_tokens, self.kv,
+                    self._dev_tables, offsets, load)
+                self._ffn_counts = (
+                    load, expected + len(active) * self._ffn_rows_per_slot)
+            del offsets
         with _Phase(self, "engine.host_arrays", "t_host_arrays_s"):
             self._rng_key, sub = jax.random.split(self._rng_key)
-            temps, top_ks = jnp.asarray(temps), jnp.asarray(top_ks)
+            if self._dev_sampling is None:
+                temps = np.zeros(self.max_slots, np.float32)
+                top_ks = np.zeros(self.max_slots, np.int32)
+                for i in active:
+                    sampling = self.slots[i].sampling
+                    temps[i] = sampling.temperature
+                    top_ks[i] = sampling.top_k
+                self._dev_sampling = (jnp.asarray(temps), jnp.asarray(top_ks))
+            # the NEXT step's offsets go now, under the running program:
+            # every active slot will have advanced by one, unless a slot
+            # ends, is preempted or comes in, which drops them. The next
+            # dispatch then waits for no transfer at all
+            at = self.offsets.copy()          # where this step writes
+            at[active] += ahead
+            following = at.copy()
+            following[active] += 1
+            self._dev_offsets = jnp.asarray(following)
         with _Phase(self, "engine.decode_enqueue", "t_enqueue_s"):
-            toks = self._sample(logits, temps, top_ks, sub)
-            del temps, top_ks
+            toks = self._dev_tokens = self._sample(
+                logits, *self._dev_sampling, sub)
         # what the dispatched program's attention reads (the kernel:
         # ceil((offset + 1) / bs) blocks a slot) of what its tables hold;
         # counted under the running program, in no phase's span
         bs = self.block_size
-        self.stats["decode_kv_blocks_live"] += int(
-            ((self.offsets[active] + bs) // bs).sum())
-        self.stats["decode_kv_blocks_table"] += (
+        self._stats["decode_kv_blocks_live"] += int(
+            ((at[active] + bs) // bs).sum())
+        self._stats["decode_kv_blocks_table"] += (
             len(active) * self.blocks_per_slot)
-        with _Phase(self, "engine.sample_readback", "t_readback_s",
-                    waits=True):
-            toks = np.asarray(toks)
-        with _Phase(self, "engine.emit", "t_emit_s"):
-            self.stats["decode_steps"] += 1
-            for i in active:
-                self.offsets[i] += 1
-                self._emit(i, int(toks[i]))
-            # the step's last device arrays die inside the phase, not at
-            # the return a few bytecodes on: with the first GIL release
-            # the stream threads that the puts woke run their part of
-            # every token, and this thread waits for the GIL back; that
-            # wait is emit's, and a gap the device idles in
-            del logits, sub
-        return len(active)
+        return toks, active
 
     def _emit(self, slot: int, tok: int) -> None:
+        """Book one sampled token: the request's output, the stop test,
+        a finished slot's blocks. The stream gets it from ``_deliver``."""
         req = self.slots[slot]
         req.output.append(tok)
-        req.stream.put(tok)
-        self.stats["tokens_generated"] += 1
+        self._last_tokens[slot] = tok
+        self._undelivered.append((req, tok))
+        self._stats["tokens_generated"] += 1
         stop = (tok in req.sampling.stop_token_ids
                 or len(req.output) >= req.sampling.max_tokens
                 or self.offsets[slot] + 1 >= self.max_seq)
@@ -700,8 +851,7 @@ class ContinuousBatchingEngine:
             req.finish_reason = ("stop" if tok in req.sampling.stop_token_ids
                                  else "length")
             req.finished_at = time.perf_counter()
-            req.stream.put(None)
-            req.done.set()
+            self._undelivered.append((req, None))
             # blocks go cached-free: content stays prefix-reusable
             # until the pool reallocates them
             self.pool.unref_all(self.allocs[slot].blocks)
@@ -709,7 +859,23 @@ class ContinuousBatchingEngine:
             self.allocs[slot] = None
             self.offsets[slot] = 0
             self._tables[slot] = self.num_blocks   # idle writes → scratch
+            self._dev_tables = self._dev_offsets = None
             self._admit_order.remove(slot)
+
+    def _deliver(self) -> None:
+        """Hand the streams what ``_emit`` booked, in its order."""
+        for req, tok in self._undelivered:
+            req.stream.put(tok)
+            if tok is None:
+                req.done.set()
+        self._undelivered.clear()
+
+    def _deliver_deferred(self) -> None:
+        """A decode step's tokens, delivered after the step: as a rule
+        under the next step's program (``_decode_step``)."""
+        if self._undelivered:
+            with _Phase(self, "engine.deliver", "t_deliver_s", waits=True):
+                self._deliver()
 
     # -- prefill/decode disaggregation handoff -----------------------------
     def prefill_only(self, prompt_tokens: List[int]):
@@ -726,7 +892,7 @@ class ContinuousBatchingEngine:
         last_logits, small = self._prefill(
             self.params, jnp.asarray(toks), jnp.asarray([n], np.int32))
         kv = {"k": np.asarray(small["k"]), "v": np.asarray(small["v"])}
-        self.stats["prefills"] += 1
+        self._stats["prefills"] += 1
         return kv, np.asarray(last_logits[0]), n
 
     def submit_prefilled(self, prompt_tokens: List[int], kv: Dict,
@@ -763,9 +929,10 @@ class ContinuousBatchingEngine:
             slot = free[0]
             toks_out = self._sample_batch(jnp.asarray(last_logits)[None],
                                           [req], 1)
-            self.stats["requests"] += 1
+            self._stats["requests"] += 1
             self._activate(slot, req, alloc, n, time.perf_counter())
             self._emit(slot, int(toks_out[0]))
+            self._deliver()
         return req
 
     # -- convenience -------------------------------------------------------
@@ -796,6 +963,7 @@ class ContinuousBatchingEngine:
             raise
 
     def _fail_all(self, err: BaseException) -> None:
+        self._deliver()             # what was generated before the failure
         for req in self._admitting:
             req.fail(err)
         for slot, req in enumerate(self.slots):

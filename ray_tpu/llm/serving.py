@@ -24,7 +24,9 @@ REQUEST_TIMEOUT_S = 300.0    # a unary request's whole generation
 @dataclasses.dataclass
 class LLMConfig:
     model_id: str = "llama-debug"
-    model_config: Optional[Any] = None       # LlamaConfig; debug if None
+    # LlamaConfig or MoEConfig (an expert model is served by the same
+    # path: ``models.model_for`` picks its class); debug Llama if None
+    model_config: Optional[Any] = None
     tokenizer: Optional[str] = None          # None -> ByteTokenizer
     max_slots: int = 8
     max_seq: int = 512
@@ -44,12 +46,12 @@ class LLMServer:
     def __init__(self, config: LLMConfig):
         import jax
 
-        from ray_tpu.models.llama import LlamaConfig, LlamaModel
+        from ray_tpu.models import LlamaConfig, model_for
 
         self.config = config
         cfg = config.model_config or LlamaConfig.debug(
             vocab_size=512, max_seq_len=config.max_seq)
-        self.model = LlamaModel(cfg)
+        self.model = model_for(cfg)
         # one program, not one per tensor: eager init compiles ~30 small
         # programs and holds each tensor twice (normal, then scaled)
         params = jax.jit(self.model.init)(jax.random.key(config.seed))

@@ -17,4 +17,19 @@ from ray_tpu.models.vit import ViTConfig, ViTModel
 
 __all__ = ["LlamaConfig", "LlamaModel", "MLPConfig", "MLPModel",
            "GPT2Config", "GPT2Model", "ViTConfig", "ViTModel",
-           "MoEConfig", "MoEModel"]
+           "MoEConfig", "MoEModel", "model_for"]
+
+_MODEL_OF = {LlamaConfig: LlamaModel, MoEConfig: MoEModel,
+             GPT2Config: GPT2Model, MLPConfig: MLPModel,
+             ViTConfig: ViTModel}
+
+
+def model_for(cfg, **kwargs):
+    """The model a configuration describes: its class follows from the
+    config's class (``kwargs``, e.g. ``mesh=``, go to its constructor)."""
+    try:
+        return _MODEL_OF[type(cfg)](cfg, **kwargs)
+    except KeyError:
+        raise TypeError(
+            f"no model for a {type(cfg).__name__}; known: "
+            + ", ".join(c.__name__ for c in _MODEL_OF)) from None

@@ -286,7 +286,33 @@ class LlamaModel:
         return attention(q, k, v, causal=True, positions_q=positions,
                          positions_k=positions, use_flash=None)
 
+    # -- the two places a model of this family may differ from the dense
+    # block; every copy of the block (``_block`` and the three serving
+    # closures below) goes through them ------------------------------------
+    def _qk_norm(self, q, k, layer: Params):
+        """Between the q/k projections and RoPE. q [B, T, H, hd], k
+        [B, T, Hkv, hd]; the dense block does nothing here."""
+        return q, k
+
+    def _ffn(self, h, layer: Params, live=None, constrain: bool = False):
+        """The feed-forward half: h [B, T, D] (already normed) ->
+        ``(out [B, T, D], extra)``. ``extra`` is whatever the model
+        wants carried out of the layer scan (``None`` here); ``live``
+        [B] bool marks the rows it should count (all, if ``None``);
+        ``constrain`` (the training block) pins the inner activation's
+        sharding to the mesh."""
+        dt = self.cfg.dtype
+        with jax.named_scope("mlp"):
+            gate = jnp.einsum("bsd,df->bsf", h, layer["w_gate"].astype(dt))
+            up = jnp.einsum("bsd,df->bsf", h, layer["w_up"].astype(dt))
+            ff = jax.nn.silu(gate) * up
+            if constrain:
+                ff = self._constrain(ff, "batch", "seq", "mlp")
+            down = jnp.einsum("bsf,fd->bsd", ff, layer["w_down"].astype(dt))
+        return down, None
+
     def _block(self, x, layer: Params, positions):
+        """-> (x, the layer's ``_ffn`` extra)."""
         cfg = self.cfg
         dt = cfg.dtype
         with jax.named_scope("norm_residual"):
@@ -295,6 +321,7 @@ class LlamaModel:
             q = jnp.einsum("bsd,dhk->bshk", h, layer["wq"].astype(dt))
             kk = jnp.einsum("bsd,dhk->bshk", h, layer["wk"].astype(dt))
             vv = jnp.einsum("bsd,dhk->bshk", h, layer["wv"].astype(dt))
+            q, kk = self._qk_norm(q, kk, layer)
             q = self._constrain(q, "batch", "seq", "heads", None)
             q = apply_rope(q, self._angles, positions)
             kk = apply_rope(kk, self._angles, positions)
@@ -303,18 +330,19 @@ class LlamaModel:
         with jax.named_scope("norm_residual"):
             x = x + self._constrain(o, "batch", "seq", "embed")
             h = rms_norm(x, layer["mlp_norm"], eps=cfg.norm_eps)
-        with jax.named_scope("mlp"):
-            gate = jnp.einsum("bsd,df->bsf", h, layer["w_gate"].astype(dt))
-            up = jnp.einsum("bsd,df->bsf", h, layer["w_up"].astype(dt))
-            ff = jax.nn.silu(gate) * up
-            ff = self._constrain(ff, "batch", "seq", "mlp")
-            down = jnp.einsum("bsf,fd->bsd", ff, layer["w_down"].astype(dt))
+        down, extra = self._ffn(h, layer, constrain=True)
         with jax.named_scope("norm_residual"):
-            return x + self._constrain(down, "batch", "seq", "embed")
+            return x + self._constrain(down, "batch", "seq", "embed"), extra
 
     def apply(self, params: Params, tokens: jax.Array,
               positions: Optional[jax.Array] = None) -> jax.Array:
         """tokens [B, S] int32 -> logits [B, S, V] (f32)."""
+        return self._apply_with_extras(params, tokens, positions)[0]
+
+    def _apply_with_extras(self, params: Params, tokens: jax.Array,
+                           positions: Optional[jax.Array] = None):
+        """``apply`` and, stacked over layers, each layer's ``_ffn``
+        extra (``None`` for the dense block)."""
         cfg = self.cfg
         with jax.named_scope("embed"):
             x = self._embed_lookup(params["embed"].astype(cfg.dtype), tokens)
@@ -329,16 +357,16 @@ class LlamaModel:
                 block = jax.checkpoint(block, static_argnums=())
 
         def scan_body(x, layer):
-            return block(x, layer, positions), None
+            return block(x, layer, positions)
 
-        x, _ = jax.lax.scan(scan_body, x, params["layers"])
+        x, extras = jax.lax.scan(scan_body, x, params["layers"])
         with jax.named_scope("logits"):
             x = rms_norm(x, params["norm_f"], eps=cfg.norm_eps)
             head = (params["embed"].T if cfg.tie_embeddings
                     else params["lm_head"])
             logits = jnp.einsum("bsd,dv->bsv", x, head.astype(cfg.dtype))
             logits = self._constrain(logits, "batch", "seq", "vocab")
-            return logits.astype(jnp.float32)
+            return logits.astype(jnp.float32), extras
 
     # -- KV-cache inference path (serving; BASELINE.md config 5) ----------
     def init_kv_cache(self, batch: int, max_seq: int) -> Params:
@@ -379,6 +407,7 @@ class LlamaModel:
                                    layer["wk"].astype(dt))
                 v_new = jnp.einsum("bsd,dhk->bshk", h,
                                    layer["wv"].astype(dt))
+                q, k_new = self._qk_norm(q, k_new, layer)
                 q = apply_rope(q, self._angles, q_pos)
                 k_new = apply_rope(k_new, self._angles, q_pos)
             with jax.named_scope("kv_update"):
@@ -411,12 +440,7 @@ class LlamaModel:
             with jax.named_scope("norm_residual"):
                 x = x + o
                 h = rms_norm(x, layer["mlp_norm"], eps=cfg.norm_eps)
-            with jax.named_scope("mlp"):
-                gate = jnp.einsum("bsd,df->bsf", h,
-                                  layer["w_gate"].astype(dt))
-                up = jnp.einsum("bsd,df->bsf", h, layer["w_up"].astype(dt))
-                down = jnp.einsum("bsf,fd->bsd", jax.nn.silu(gate) * up,
-                                  layer["w_down"].astype(dt))
+            down, _ = self._ffn(h, layer)
             with jax.named_scope("norm_residual"):
                 return x + down, (k_cache, v_cache)
 
@@ -466,6 +490,22 @@ class LlamaModel:
         Returns (logits [B, V], updated pool). Slots whose table rows
         point at garbage simply compute garbage that the engine masks.
         """
+        return self.decode_step_paged_counted(
+            params, tokens, pool, block_tables, offsets)[:2]
+
+    def ffn_load_shape(self) -> Optional[Tuple[int, int]]:
+        """Shape of the per-layer counts ``decode_step_paged_counted``
+        reports third (under ``"load"``), or ``None`` where the FFN
+        counts nothing, as the dense one."""
+        return None
+
+    def decode_step_paged_counted(self, params: Params, tokens: jax.Array,
+                                  pool: Params, block_tables: jax.Array,
+                                  offsets: jax.Array,
+                                  live: Optional[jax.Array] = None):
+        """``decode_step_paged`` and, third, each layer's ``_ffn`` extra
+        stacked over layers (``None`` for the dense block), counted over
+        the slots ``live`` [B] bool marks."""
         cfg = self.cfg
         bs = pool["k"].shape[2]
         dest_block = jnp.take_along_axis(
@@ -491,6 +531,7 @@ class LlamaModel:
                                    layer["wk"].astype(dt))
                 v_new = jnp.einsum("bsd,dhk->bshk", h,
                                    layer["wv"].astype(dt))
+                q, k_new = self._qk_norm(q, k_new, layer)
                 q = apply_rope(q, self._angles, q_pos)
                 k_new = apply_rope(k_new, self._angles, q_pos)
             with jax.named_scope("kv_update"):
@@ -507,16 +548,11 @@ class LlamaModel:
             with jax.named_scope("norm_residual"):
                 x = x + o[:, None]
                 h = rms_norm(x, layer["mlp_norm"], eps=cfg.norm_eps)
-            with jax.named_scope("mlp"):
-                gate = jnp.einsum("bsd,df->bsf", h,
-                                  layer["w_gate"].astype(dt))
-                up = jnp.einsum("bsd,df->bsf", h, layer["w_up"].astype(dt))
-                down = jnp.einsum("bsf,fd->bsd", jax.nn.silu(gate) * up,
-                                  layer["w_down"].astype(dt))
+            down, extra = self._ffn(h, layer, live)
             with jax.named_scope("norm_residual"):
-                return x + down, (k_pool, v_pool)
+                return x + down, (k_pool, v_pool, extra)
 
-        x, (k_out, v_out) = jax.lax.scan(
+        x, (k_out, v_out, extras) = jax.lax.scan(
             block, x, (params["layers"], pool["k"], pool["v"]))
         with jax.named_scope("logits"):
             x = rms_norm(x, params["norm_f"], eps=cfg.norm_eps)
@@ -524,7 +560,7 @@ class LlamaModel:
                     else params["lm_head"])
             logits = jnp.einsum("bsd,dv->bsv", x, head.astype(cfg.dtype))
             return (logits[:, 0].astype(jnp.float32),
-                    {"k": k_out, "v": v_out})
+                    {"k": k_out, "v": v_out}, extras)
 
     def prefill_with_prefix(self, params: Params, tokens: jax.Array,
                             prefix_k: jax.Array, prefix_v: jax.Array,
@@ -570,6 +606,7 @@ class LlamaModel:
                                    layer["wk"].astype(dt))
                 v_new = jnp.einsum("bsd,dhk->bshk", h,
                                    layer["wv"].astype(dt))
+                q, k_new = self._qk_norm(q, k_new, layer)
                 q = apply_rope(q, self._angles, pos_q)
                 k_new = apply_rope(k_new, self._angles, pos_q)
                 k_all = jnp.concatenate([kp.astype(dt), k_new], axis=1)
@@ -592,12 +629,7 @@ class LlamaModel:
             with jax.named_scope("norm_residual"):
                 x = x + o
                 h = rms_norm(x, layer["mlp_norm"], eps=cfg.norm_eps)
-            with jax.named_scope("mlp"):
-                gate = jnp.einsum("bsd,df->bsf", h,
-                                  layer["w_gate"].astype(dt))
-                up = jnp.einsum("bsd,df->bsf", h, layer["w_up"].astype(dt))
-                down = jnp.einsum("bsf,fd->bsd", jax.nn.silu(gate) * up,
-                                  layer["w_down"].astype(dt))
+            down, _ = self._ffn(h, layer)
             with jax.named_scope("norm_residual"):
                 return x + down, (k_new, v_new)
 
@@ -616,7 +648,12 @@ class LlamaModel:
              targets: jax.Array,
              mask: Optional[jax.Array] = None) -> jax.Array:
         """Mean next-token cross-entropy."""
-        logits = self.apply(params, tokens)
+        # by class: ``PipelinedLlama`` borrows this method
+        return LlamaModel._cross_entropy(self.apply(params, tokens), targets,
+                                         mask)
+
+    @staticmethod
+    def _cross_entropy(logits, targets, mask):
         with jax.named_scope("loss"):
             logp = jax.nn.log_softmax(logits, axis=-1)
             nll = -jnp.take_along_axis(logp, targets[..., None],

@@ -1,15 +1,19 @@
-"""Mixture-of-Experts Llama — expert parallelism (SURVEY.md §2.3: EP is
+"""Mixture-of-experts decoder on the Llama block (SURVEY.md §2.3: EP is
 absent in the reference — vLLM handles MoE internally — so this is a
 native capability).
 
-GShard/Switch-style top-k routing with capacity-based einsum dispatch:
-- all routing math is dense one-hot einsums (no gather/scatter in the hot
-  path — XLA maps these straight onto the MXU);
-- the expert dimension carries the ``experts`` logical axis → ``ep`` mesh
-  axis; expert FFNs run where their weights live, dispatch/combine
-  einsums become all-to-alls over ICI;
-- tokens beyond an expert's capacity are dropped (standard
-  capacity_factor trade).
+``MoEModel`` is ``LlamaModel`` with two methods overridden: ``_ffn`` (a
+router and ``num_experts`` SwiGLU experts, ``expert_top_k`` a token) and
+``_qk_norm`` (OLMoE's RMSNorm over all heads' lanes of q and of k). Every
+path of the parent — training ``apply``/``loss``, ``forward_step``,
+``decode_step_paged``, ``prefill_with_prefix``, so Serve and the engine —
+runs the expert block through them.
+
+Off an ``ep`` mesh axis the FFN is DROPLESS (``ops/moe_dispatch.
+dropless_expert_ffn``): every chosen expert is computed. Under ``ep`` > 1
+it is the capacity-bounded GShard dispatch (tokens beyond an expert's
+capacity are dropped), as one-hot einsums or as an explicit all-to-all
+(``moe_dispatch``); the mesh decides, not a flag.
 """
 
 from __future__ import annotations
@@ -21,18 +25,28 @@ import jax
 import jax.numpy as jnp
 
 from ray_tpu.models.llama import LlamaConfig, LlamaModel, Params
+from ray_tpu.ops.norms import rms_norm
 
 
 @dataclasses.dataclass(frozen=True)
 class MoEConfig(LlamaConfig):
+    """``ffn_dim`` is ONE expert's width."""
     num_experts: int = 8
     expert_top_k: int = 2
-    capacity_factor: float = 1.25
+    # the top-k router weights renormalised to sum to 1 (Mixtral, GShard)
+    # or used as the softmax gave them (OLMoE: ``norm_topk_prob`` false)
+    norm_topk_prob: bool = True
+    # RMSNorm of q and of k over ALL heads' lanes, before the split into
+    # heads and before RoPE (OLMoE); adds ``q_norm``/``k_norm`` params
+    qk_norm: bool = False
     router_z_loss: float = 1e-3
     load_balance_loss: float = 1e-2
-    # "einsum" = dense one-hot dispatch, XLA chooses collectives;
-    # "alltoall" = explicit capacity-bounded expert all-to-all inside
-    # shard_map (ops/moe_dispatch.py) — VERDICT r1 #7.
+    # Under an ``ep`` mesh axis only (off it the FFN is dropless):
+    # rows an expert takes = capacity_factor * T * K / E, and which of
+    # the two capacity dispatches runs: "einsum" = dense one-hot, XLA
+    # chooses collectives; "alltoall" = explicit expert all-to-all
+    # inside shard_map (ops/moe_dispatch.py).
+    capacity_factor: float = 1.25
     moe_dispatch: str = "einsum"
 
     def __post_init__(self):
@@ -42,11 +56,30 @@ class MoEConfig(LlamaConfig):
                 f"moe_dispatch must be 'einsum' or 'alltoall', "
                 f"got {self.moe_dispatch!r}")
 
+    def num_params(self) -> int:
+        d, f, v, E = self.dim, self.ffn_dim, self.vocab_size, self.num_experts
+        kv = self.n_kv_heads * self.head_dim
+        per_layer = (2 * d * d + 2 * d * kv + 2 * d + d * E + 3 * E * d * f
+                     + (d + kv if self.qk_norm else 0))
+        heads = 0 if self.tie_embeddings else v * d
+        return v * d + self.n_layers * per_layer + d + heads
+
     @staticmethod
     def debug_moe(num_experts: int = 4) -> "MoEConfig":
         return MoEConfig(vocab_size=256, dim=64, n_layers=2, n_heads=4,
                          n_kv_heads=2, ffn_dim=128, max_seq_len=128,
                          remat=False, num_experts=num_experts)
+
+    @staticmethod
+    def debug_olmoe(vocab_size: int = 512, max_seq_len: int = 128,
+                    **overrides) -> "MoEConfig":
+        """OLMoE's block at debug widths: 8 experts, top-2 weights as
+        they are, QK-norm, as many KV heads as heads."""
+        return MoEConfig(**{**dict(
+            vocab_size=vocab_size, dim=64, n_layers=2, n_heads=4,
+            n_kv_heads=4, ffn_dim=32, max_seq_len=max_seq_len, remat=False,
+            rope_theta=10_000.0, num_experts=8, expert_top_k=2,
+            norm_topk_prob=False, qk_norm=True), **overrides})
 
 
 def moe_param_logical_axes(cfg: MoEConfig) -> Params:
@@ -59,16 +92,20 @@ def moe_param_logical_axes(cfg: MoEConfig) -> Params:
     layers["e_gate"] = (None, "experts", "embed_in", "mlp")
     layers["e_up"] = (None, "experts", "embed_in", "mlp")
     layers["e_down"] = (None, "experts", "mlp", "embed_in")
+    if cfg.qk_norm:
+        layers["q_norm"] = (None, "heads", None)
+        layers["k_norm"] = (None, "kv_heads", None)
     axes["layers"] = layers
     return axes
 
 
 class MoEModel(LlamaModel):
-    """Llama with MoE FFN blocks. Aux losses accumulated per forward."""
+    """Llama block with an expert FFN (and, if configured, QK-norm)."""
 
     def __init__(self, cfg: MoEConfig, mesh=None,
                  rules: Optional[Dict] = None):
         super().__init__(cfg, mesh=mesh, rules=rules)
+        self._ep = 1 if mesh is None else mesh.shape.get("ep", 1)
 
     def init(self, rng: jax.Array) -> Params:
         params = super().init(rng)
@@ -86,6 +123,11 @@ class MoEModel(LlamaModel):
             keys[2], (L, E, d, f), jnp.float32) * d ** -0.5
         layers["e_down"] = jax.random.normal(
             keys[3], (L, E, f, d), jnp.float32) * f ** -0.5
+        if cfg.qk_norm:
+            layers["q_norm"] = jnp.ones(
+                (L, cfg.n_heads, cfg.head_dim), jnp.float32)
+            layers["k_norm"] = jnp.ones(
+                (L, cfg.n_kv_heads, cfg.head_dim), jnp.float32)
         return params
 
     def param_shardings(self):
@@ -96,104 +138,68 @@ class MoEModel(LlamaModel):
                                          rules=self.rules),
             axes, is_leaf=lambda x: isinstance(x, tuple))
 
-    # -- MoE FFN -----------------------------------------------------------
-    def _moe_ffn(self, h: jax.Array, layer: Params
-                 ) -> Tuple[jax.Array, jax.Array]:
-        """h [B, S, D] → (out [B, S, D], aux_loss scalar)."""
+    # -- the block's two overrides ------------------------------------------
+    def _qk_norm(self, q, k, layer: Params):
         cfg: MoEConfig = self.cfg
-        if cfg.moe_dispatch == "alltoall":
-            if self.mesh is None:
-                raise ValueError(
-                    "moe_dispatch='alltoall' needs a device mesh "
-                    "(pass mesh= to MoEModel)")
-            from ray_tpu.ops.moe_dispatch import expert_alltoall_ffn
-            out, aux = expert_alltoall_ffn(
-                h, layer["router"], layer["e_gate"], layer["e_up"],
-                layer["e_down"], self.mesh,
-                num_experts=cfg.num_experts, top_k=cfg.expert_top_k,
-                capacity_factor=cfg.capacity_factor,
-                z_coef=cfg.router_z_loss, lb_coef=cfg.load_balance_loss,
-                dtype=cfg.dtype)
-            return out, jnp.mean(aux)
-        dt = cfg.dtype
-        B, S, D = h.shape
-        E, K = cfg.num_experts, cfg.expert_top_k
-        T = B * S
-        C = max(1, int(cfg.capacity_factor * T * K / E))
+        if not cfg.qk_norm:
+            return q, k
 
-        x = h.reshape(T, D)
-        # Shared GShard-style router math (collision-free slot positions
-        # across the top-k passes): ops/moe_dispatch._topk_dispatch.
-        from ray_tpu.ops.moe_dispatch import topk_dispatch
-        dispatch, combine, aux = topk_dispatch(
-            x, layer["router"], E, K, C,
-            cfg.router_z_loss, cfg.load_balance_loss)
+        def norm(x, w):
+            # one RMS over every head's lanes: [B, T, H, hd] as [B, T, H*hd]
+            flat = rms_norm(x.reshape(*x.shape[:2], -1), w.reshape(-1),
+                            eps=cfg.norm_eps)
+            return flat.reshape(x.shape)
 
-        expert_in = jnp.einsum("tec,td->ecd", dispatch.astype(dt),
-                               x.astype(dt))                   # [E, C, D]
-        gate = jnp.einsum("ecd,edf->ecf", expert_in,
-                          layer["e_gate"].astype(dt))
-        up = jnp.einsum("ecd,edf->ecf", expert_in,
-                        layer["e_up"].astype(dt))
-        act = jax.nn.silu(gate) * up
-        expert_out = jnp.einsum("ecf,efd->ecd", act,
-                                layer["e_down"].astype(dt))    # [E, C, D]
-        out = jnp.einsum("tec,ecd->td", combine.astype(dt), expert_out)
-        return out.reshape(B, S, D), aux
+        with jax.named_scope("qk_norm"):
+            return norm(q, layer["q_norm"]), norm(k, layer["k_norm"])
 
-    def _moe_block(self, x, layer: Params, positions):
-        """Returns (x, aux) — aux threads through the scan carry."""
-        from ray_tpu.ops.norms import rms_norm
-        from ray_tpu.ops.rope import apply_rope
-        cfg = self.cfg
-        dt = cfg.dtype
-        h = rms_norm(x, layer["attn_norm"], eps=cfg.norm_eps)
-        q = jnp.einsum("bsd,dhk->bshk", h, layer["wq"].astype(dt))
-        kk = jnp.einsum("bsd,dhk->bshk", h, layer["wk"].astype(dt))
-        vv = jnp.einsum("bsd,dhk->bshk", h, layer["wv"].astype(dt))
-        q = apply_rope(q, self._angles, positions)
-        kk = apply_rope(kk, self._angles, positions)
-        o = self._attention(q, kk, vv, positions)
-        o = jnp.einsum("bshk,hkd->bsd", o, layer["wo"].astype(dt))
-        x = x + o
-        h = rms_norm(x, layer["mlp_norm"], eps=cfg.norm_eps)
-        ffn, aux = self._moe_ffn(h, layer)
-        return x + ffn, aux
+    def _ffn(self, h, layer: Params, live=None, constrain: bool = False):
+        """(``constrain`` is the dense FFN's: the expert dispatches place
+        their own.) h [B, T, D] -> (out, {"aux": training loss of the router,
+        "load": [E] rows handed to each expert, of ``live`` slots,
+        "experts": [B, T, K] each token's chosen experts}). Under an
+        ``ep`` mesh axis the capacity dispatch runs and only "aux" is
+        there (``ffn_load_shape`` says so)."""
+        cfg: MoEConfig = self.cfg
+        shared = dict(top_k=cfg.expert_top_k, dtype=cfg.dtype,
+                      norm_topk_prob=cfg.norm_topk_prob,
+                      z_coef=cfg.router_z_loss,
+                      lb_coef=cfg.load_balance_loss)
+        weights = (layer["router"], layer["e_gate"], layer["e_up"],
+                   layer["e_down"])
+        if self._ep > 1:
+            from ray_tpu.ops.moe_dispatch import (capacity_einsum_ffn,
+                                                  expert_alltoall_ffn)
+            shared.update(num_experts=cfg.num_experts,
+                          capacity_factor=cfg.capacity_factor)
+            if cfg.moe_dispatch == "alltoall":
+                out, aux = expert_alltoall_ffn(h, *weights, self.mesh,
+                                               **shared)
+                aux = jnp.mean(aux)
+            else:
+                out, aux = capacity_einsum_ffn(h, *weights, **shared)
+            return out, {"aux": aux}
+        from ray_tpu.ops.moe_dispatch import dropless_expert_ffn
+        B, T, D = h.shape
+        rows_live = None if live is None else jnp.repeat(live, T)
+        out, load, experts, aux = dropless_expert_ffn(
+            h.reshape(B * T, D), *weights, live=rows_live, **shared)
+        return out.reshape(B, T, D), {
+            "aux": aux, "load": load,
+            "experts": experts.reshape(B, T, cfg.expert_top_k)}
+
+    def ffn_load_shape(self) -> Optional[Tuple[int, int]]:
+        """[layers, experts]; nothing under an ``ep`` mesh axis."""
+        if self._ep > 1:
+            return None
+        return self.cfg.n_layers, self.cfg.num_experts
 
     def apply_with_aux(self, params: Params, tokens: jax.Array,
                        positions=None):
-        from ray_tpu.ops.norms import rms_norm
-        cfg = self.cfg
-        x = self._embed_lookup(params["embed"].astype(cfg.dtype), tokens)
-        x = self._constrain(x, "batch", "seq", "embed")
-
-        block = self._moe_block
-        if cfg.remat:
-            block = jax.checkpoint(block)
-
-        def scan_body(carry, layer):
-            x, aux = carry
-            x, aux_i = block(x, layer, positions)
-            return (x, aux + aux_i), None
-
-        (x, aux), _ = jax.lax.scan(
-            scan_body, (x, jnp.float32(0.0)), params["layers"])
-        x = rms_norm(x, params["norm_f"], eps=cfg.norm_eps)
-        head = (params["embed"].T if cfg.tie_embeddings
-                else params["lm_head"])
-        logits = jnp.einsum("bsd,dv->bsv", x, head.astype(cfg.dtype))
-        return logits.astype(jnp.float32), aux
-
-    def apply(self, params: Params, tokens: jax.Array,
-              positions=None) -> jax.Array:
-        return self.apply_with_aux(params, tokens, positions)[0]
+        logits, extras = self._apply_with_extras(params, tokens, positions)
+        return logits, jnp.sum(extras["aux"])
 
     def loss(self, params: Params, tokens: jax.Array, targets: jax.Array,
              mask=None) -> jax.Array:
         logits, aux = self.apply_with_aux(params, tokens)
-        logp = jax.nn.log_softmax(logits, axis=-1)
-        nll = -jnp.take_along_axis(logp, targets[..., None],
-                                   axis=-1).squeeze(-1)
-        ce = (jnp.sum(nll * mask) / jnp.maximum(jnp.sum(mask), 1)
-              if mask is not None else jnp.mean(nll))
-        return ce + aux
+        return self._cross_entropy(logits, targets, mask) + aux

@@ -1,24 +1,22 @@
-"""Explicit capacity-bounded expert-parallel MoE dispatch (all-to-all).
+"""The expert FFN of a mixture-of-experts block, two ways.
 
-SURVEY.md §2.3: EP is absent in the reference (vLLM internals handle it);
-this is native design. Two selectable schemes in :class:`MoEModel`:
-
-- ``einsum`` (models/moe.py): dense one-hot dispatch/combine einsums;
-  XLA's SPMD partitioner turns the [T,E,C]x[T,D] contractions into
-  collectives. Zero custom communication code, but the compiler chooses
-  the schedule.
-- ``alltoall`` (this module): GShard-style explicit dispatch inside
-  shard_map — tokens are bucketed per expert with a hard capacity,
-  buffers cross the ``ep`` axis as two `jax.lax.all_to_all` collectives
-  (dispatch and return), and expert FFNs run exactly where their weights
-  live. The communication volume is explicit and capacity-bounded:
-  2 * E * C_local * D per device per layer, independent of routing skew.
-
-Sharding contract (enforced by the shard_map specs): tokens arrive
-sharded [batch -> (dp, fsdp), seq -> (sp, ep)], expert weights sharded
-[E -> ep]. Expert FFN weights are NOT additionally tensor-parallel in
-this path — use the einsum scheme when tp-sharded experts matter more
-than explicit dispatch.
+- ``dropless_expert_ffn``: what every model runs off a mesh, serving and
+  training alike. Router in float32, top-k, the (token, choice)
+  assignments sorted by expert, the three expert matmuls as grouped
+  matmuls over the sorted rows (``jax.lax.ragged_dot``: a native grouped
+  kernel on the TPU, plain loops elsewhere), outputs un-sorted and
+  summed with the router's weights. Every chosen expert is computed: no
+  capacity, no drop, and nothing whose size grows faster than T * K.
+- the capacity-bounded GShard pair, kept for an ``ep`` mesh axis
+  (ROADMAP R2 decides their future): ``capacity_einsum_ffn`` (dense
+  one-hot ``[T, E, C]`` dispatch/combine einsums, XLA's partitioner
+  chooses the collectives) and ``expert_alltoall_ffn`` (the same
+  buckets crossing ``ep`` as two explicit ``jax.lax.all_to_all``
+  inside ``shard_map``; 2 * E * C_local * D per device per layer,
+  independent of routing skew). Both DROP what exceeds an expert's
+  capacity. Sharding contract of the all-to-all (enforced by the
+  shard_map specs): tokens arrive sharded [batch -> (dp, fsdp), seq ->
+  (sp, ep)], expert weights [E -> ep], not additionally tensor-parallel.
 """
 
 from __future__ import annotations
@@ -30,22 +28,83 @@ import jax
 import jax.numpy as jnp
 
 
-def topk_dispatch(xf, router, num_experts: int, top_k: int,
-                   capacity: int, z_coef: float, lb_coef: float):
-    """Shared router math: returns (dispatch [T,E,C] bool,
-    combine [T,E,C] f32, aux scalar)."""
-    logits = jnp.einsum("td,de->te", xf.astype(jnp.float32), router)
+def route_topk(xf, router, top_k: int, norm_topk_prob: bool,
+               precision=None):
+    """Router in float32: ``(logits [T, E], probs [T, E], weights [T, K],
+    experts [T, K])``. The top-k softmax weights are used as they are
+    unless ``norm_topk_prob`` (then they sum to 1)."""
+    logits = jnp.einsum("td,de->te", xf.astype(jnp.float32),
+                        router.astype(jnp.float32), precision=precision)
     probs = jax.nn.softmax(logits, axis=-1)
-    z = jax.scipy.special.logsumexp(logits, axis=-1)
-    z_loss = jnp.mean(z ** 2) * z_coef
-    me = jnp.mean(probs, axis=0)
-    top1 = jnp.argmax(probs, axis=-1)
-    ce = jnp.mean(jax.nn.one_hot(top1, num_experts), axis=0)
-    aux = z_loss + lb_coef * num_experts * jnp.sum(me * ce)
-
     gate_vals, gate_idx = jax.lax.top_k(probs, top_k)
-    gate_vals = gate_vals / jnp.maximum(
-        jnp.sum(gate_vals, -1, keepdims=True), 1e-9)
+    if norm_topk_prob:
+        gate_vals = gate_vals / jnp.maximum(
+            jnp.sum(gate_vals, -1, keepdims=True), 1e-9)
+    return logits, probs, gate_vals, gate_idx
+
+
+def router_aux_loss(logits, probs, z_coef: float, lb_coef: float):
+    """Router z-loss + Switch-style load-balance loss (training)."""
+    num_experts = probs.shape[-1]
+    z = jax.scipy.special.logsumexp(logits, axis=-1)
+    me = jnp.mean(probs, axis=0)
+    ce = jnp.mean(jax.nn.one_hot(jnp.argmax(probs, axis=-1), num_experts),
+                  axis=0)
+    return (jnp.mean(z ** 2) * z_coef
+            + lb_coef * num_experts * jnp.sum(me * ce))
+
+
+def dropless_expert_ffn(x, router, e_gate, e_up, e_down, *, top_k: int,
+                        norm_topk_prob: bool, dtype, live=None,
+                        z_coef: float = 0.0, lb_coef: float = 0.0):
+    """x [T, D] -> ``(out [T, D] in ``dtype``, load [E] int32, experts
+    [T, K] int32, aux)``.
+
+    router [D, E]; e_gate/e_up [E, D, F]; e_down [E, F, D]. ``load[e]``
+    is the number of rows handed to expert ``e``'s grouped matmuls, of
+    the tokens ``live`` [T] bool marks (all, if ``None``): it sums to
+    ``live.sum() * top_k`` because nothing is dropped. ``aux`` is the
+    training loss of ``router_aux_loss``.
+    """
+    T, D = x.shape
+    E = router.shape[-1]
+    with jax.named_scope("moe_router"):
+        # 2*T*D*E operations: full float32 passes cost nothing, and a
+        # bf16 pass of the logits would swap more near-tied experts
+        logits, probs, weights, experts = route_topk(
+            x, router, top_k, norm_topk_prob,
+            precision=jax.lax.Precision.HIGHEST)
+        aux = router_aux_loss(logits, probs, z_coef, lb_coef)
+    with jax.named_scope("moe_dispatch"):
+        flat = experts.reshape(T * top_k)
+        order = jnp.argsort(flat, stable=True)        # rows by expert
+        sizes = jnp.zeros((E,), jnp.int32).at[flat].add(1)
+        rows = x.astype(dtype)[order // top_k]        # [T*K, D]
+        load = sizes if live is None else jnp.zeros((E,), jnp.int32).at[
+            flat].add(jnp.repeat(live.astype(jnp.int32), top_k))
+    with jax.named_scope("moe_experts"):
+        def grouped(lhs, w):
+            return jax.lax.ragged_dot(lhs, w.astype(dtype), sizes,
+                                      preferred_element_type=dtype)
+        act = jax.nn.silu(grouped(rows, e_gate)) * grouped(rows, e_up)
+        rows = grouped(act, e_down)                   # [T*K, D]
+    with jax.named_scope("moe_combine"):
+        back = jnp.argsort(order)                     # un-sort
+        rows = rows[back].reshape(T, top_k, D)
+        # elementwise, so the weights keep their float32 (a dot would
+        # round them to bf16 on the TPU)
+        out = jnp.sum(rows.astype(jnp.float32) * weights[:, :, None], axis=1)
+    return out.astype(dtype), load, experts, aux
+
+
+def topk_dispatch(xf, router, num_experts: int, top_k: int,
+                   capacity: int, z_coef: float, lb_coef: float,
+                   norm_topk_prob: bool = True):
+    """Capacity-bounded router math: returns (dispatch [T,E,C] bool,
+    combine [T,E,C] f32, aux scalar)."""
+    logits, probs, gate_vals, gate_idx = route_topk(
+        xf, router, top_k, norm_topk_prob)
+    aux = router_aux_loss(logits, probs, z_coef, lb_coef)
     T = xf.shape[0]
     combine = jnp.zeros((T, num_experts, capacity), jnp.float32)
     dispatch = jnp.zeros((T, num_experts, capacity), jnp.bool_)
@@ -69,10 +128,35 @@ def topk_dispatch(xf, router, num_experts: int, top_k: int,
     return dispatch, combine, aux
 
 
+def capacity_einsum_ffn(h, router, e_gate, e_up, e_down, *,
+                        num_experts: int, top_k: int,
+                        capacity_factor: float, z_coef: float,
+                        lb_coef: float, dtype,
+                        norm_topk_prob: bool = True):
+    """h [B, S, D] -> (out, aux): one-hot ``[T, E, C]`` dispatch and
+    combine einsums with ``C = capacity_factor * T * K / E`` rows an
+    expert; assignments past an expert's capacity are dropped."""
+    B, S, D = h.shape
+    T = B * S
+    C = max(1, int(capacity_factor * T * top_k / num_experts))
+    x = h.reshape(T, D)
+    dispatch, combine, aux = topk_dispatch(
+        x, router, num_experts, top_k, C, z_coef, lb_coef, norm_topk_prob)
+    expert_in = jnp.einsum("tec,td->ecd", dispatch.astype(dtype),
+                           x.astype(dtype))                   # [E, C, D]
+    gate = jnp.einsum("ecd,edf->ecf", expert_in, e_gate.astype(dtype))
+    up = jnp.einsum("ecd,edf->ecf", expert_in, e_up.astype(dtype))
+    expert_out = jnp.einsum("ecf,efd->ecd", jax.nn.silu(gate) * up,
+                            e_down.astype(dtype))             # [E, C, D]
+    out = jnp.einsum("tec,ecd->td", combine.astype(dtype), expert_out)
+    return out.reshape(B, S, D), aux
+
+
 def expert_alltoall_ffn(h, router, e_gate, e_up, e_down, mesh, *,
                         num_experts: int, top_k: int,
                         capacity_factor: float, z_coef: float,
                         lb_coef: float, dtype,
+                        norm_topk_prob: bool = True,
                         axis_name: str = "ep") -> Tuple[jax.Array,
                                                         jax.Array]:
     """MoE FFN with explicit expert all-to-all over ``axis_name``.
@@ -92,7 +176,7 @@ def expert_alltoall_ffn(h, router, e_gate, e_up, e_down, mesh, *,
         C = max(1, int(capacity_factor * T_l * top_k / num_experts))
         xf = x.reshape(T_l, D)
         dispatch, combine, aux = topk_dispatch(
-            xf, rtr, num_experts, top_k, C, z_coef, lb_coef)
+            xf, rtr, num_experts, top_k, C, z_coef, lb_coef, norm_topk_prob)
         if ep > 1:
             aux = jax.lax.pmean(aux, axis_name)
 

@@ -238,6 +238,45 @@ def test_llama3_1b_decode_program_holds_the_kernel(v5e, monkeypatch,
         v5e(B, maxb, dtype=jnp.int32), v5e(B, dtype=jnp.int32)))
 
 
+def test_olmoe_cell_decode_program_fits_the_v5e(v5e, monkeypatch):
+    """``olmoe-1b-7b-d3.batch_decode_moe``'s decode program as the engine
+    jits it (the counted step: 32 slots x 3072, depth 3, all 64 experts,
+    weights in float32): the v5e's compiler takes it (15.31 GiB of 15.75
+    when this was written; depth 4 is refused), with the paged kernel
+    and the grouped matmuls as Mosaic calls."""
+    from ray_tpu.models import MoEConfig, model_for
+
+    _as_on_the_chip(monkeypatch)
+    B, bs, maxb, L, E = CELL_SLOTS, CELL_BS, 96, 3, 64
+    model = model_for(MoEConfig(
+        vocab_size=50304, dim=2048, n_layers=L, n_heads=16, n_kv_heads=16,
+        ffn_dim=1024, max_seq_len=maxb * bs, rope_theta=1e4, num_experts=E,
+        expert_top_k=8, norm_topk_prob=False, qk_norm=True))
+    assert model.paged_decode_impl() == "pallas"
+    assert model.ffn_load_shape() == (L, E)
+
+    def placed(tree):
+        return jax.tree.map(lambda a: v5e(*a.shape, dtype=a.dtype), tree)
+
+    def step(params, tokens, pool, tables, offsets, load):
+        logits, pool, extras = model.decode_step_paged_counted(
+            params, tokens, pool, tables, offsets, tables[:, 0] != B * maxb)
+        return logits, pool, load + extras["load"]
+
+    compiled = jax.jit(step, donate_argnums=(2,)).lower(
+        placed(jax.eval_shape(model.init, jax.random.key(0))),
+        v5e(B, dtype=jnp.int32),
+        placed(jax.eval_shape(lambda: model.init_kv_pool(B * maxb + 1, bs))),
+        v5e(B, maxb, dtype=jnp.int32), v5e(B, dtype=jnp.int32),
+        v5e(L, E, dtype=jnp.int32)).compile()
+    # one attention kernel + three grouped matmuls in the layer scan
+    assert compiled.as_text().count("tpu_custom_call") >= 4
+    mem = compiled.memory_analysis()
+    total = (mem.argument_size_in_bytes + mem.temp_size_in_bytes
+             + mem.output_size_in_bytes - mem.alias_size_in_bytes)
+    assert total < 15.75 * 2**30
+
+
 def test_decode_under_a_mesh_keeps_the_reference(v5e_topo, monkeypatch):
     """Why ``paged_decode_impl`` answers "xla" under a mesh: XLA refuses
     to partition a Mosaic call, so the sharded decode program compiles

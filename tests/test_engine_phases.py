@@ -1,4 +1,4 @@
-"""The engine loop accounts for itself: seven sibling phases, each a
+"""The engine loop accounts for itself: eight sibling phases, each a
 ``jax.profiler.TraceAnnotation`` span and a seconds counter in
 ``engine.stats`` (``llm/engine.py:_Phase``), and counts taken at the
 same boundaries. CPU, debug widths, no timing thresholds: what is
@@ -20,7 +20,8 @@ PHASES = {"engine.schedule": "t_schedule_s", "engine.prefill": "t_prefill_s",
           "engine.host_arrays": "t_host_arrays_s",
           "engine.decode_enqueue": "t_enqueue_s",
           "engine.sample_readback": "t_readback_s",
-          "engine.emit": "t_emit_s", "engine.idle": "t_idle_s"}
+          "engine.emit": "t_emit_s", "engine.deliver": "t_deliver_s",
+          "engine.idle": "t_idle_s"}
 IN_STEP = [k for name, k in PHASES.items() if name != "engine.idle"]
 
 
@@ -64,7 +65,7 @@ class Recorder:
         return False
 
 
-def test_spans_are_the_seven_phases_siblings_on_one_thread(tiny_model,
+def test_spans_are_the_eight_phases_siblings_on_one_thread(tiny_model,
                                                            monkeypatch):
     eng = make_engine(tiny_model)
     eng.generate([distinct(5, 0)], SamplingParams(max_tokens=2))   # compile
@@ -100,12 +101,144 @@ def test_spans_are_the_seven_phases_siblings_on_one_thread(tiny_model,
     # one span around all of a step's (or a group's) tokens, never one each
     assert names.count("engine.emit") == steps + groups
     assert names.count("engine.sample_readback") == steps
-    assert eng.stats["tokens_generated"] - before["tokens_generated"] \
-        > 2 * names.count("engine.emit")
+    # a decode step's tokens reach their streams once, after the step:
+    # at once where the next step is already on the device or the last
+    # slot ended, else under the next dispatch or before an admission
+    assert names.count("engine.deliver") == steps
+    assert all(names[i - 1] in ("engine.decode_enqueue", "engine.emit")
+               for i, n in enumerate(names) if n == "engine.deliver")
     attrs = [a for n, a, _, _ in driven if n == "engine.prefill"]
     assert {"bucket": 64, "n": 3, "n_pad": 4} in attrs
     assert all(set(a) == {"bucket", "n", "n_pad"} for a in attrs)
     assert all(not a for n, a, _, _ in driven if n != "engine.prefill")
+
+
+def test_streams_get_every_token_in_order_then_their_end(tiny_model):
+    """Delivery after the step loses and reorders nothing: requests of
+    unequal lengths that join and leave a running batch."""
+    eng = make_engine(tiny_model)
+    stop = threading.Event()
+    loop = threading.Thread(target=eng.run_forever, args=(stop, 0.001))
+    loop.start()
+    try:
+        reqs = [eng.submit(distinct(9 + 7 * k, 40 * k),
+                           SamplingParams(max_tokens=3 + 4 * k))
+                for k in range(6)]              # six requests, four slots
+        streamed = [list(r.iter_tokens()) for r in reqs]
+    finally:
+        stop.set()
+        loop.join(30)
+    assert [len(t) for t in streamed] == [3 + 4 * k for k in range(6)]
+    assert streamed == [r.output for r in reqs]
+    assert all(r.done.is_set() and r.finish_reason == "length" for r in reqs)
+    assert eng._undelivered == []
+
+
+def test_a_dying_loop_delivers_what_it_generated_before_the_cause(tiny_model):
+    from ray_tpu.llm.engine import EngineDeadError
+
+    eng = make_engine(tiny_model)
+    # a stop token (never sampled: the vocabulary ends at 512) keeps the
+    # engine from running a step ahead, so a step's tokens wait for the
+    # next dispatch
+    req = eng.submit(distinct(9, 0), SamplingParams(
+        max_tokens=50, stop_token_ids=(9999,)))
+    for _ in range(3):
+        eng.step()                      # first token + three decode steps
+    assert len(req.output) == 4 and req.stream.qsize() == 3   # one deferred
+
+    def boom(*a, **k):
+        raise RuntimeError("device lost")
+    eng._decode = boom
+    with pytest.raises(RuntimeError):
+        eng.run_forever(threading.Event())
+    got = []
+    with pytest.raises(EngineDeadError):
+        for tok in req.iter_tokens():
+            got.append(tok)
+    assert got == req.output and len(got) == 4
+
+
+def test_decode_inputs_stay_on_the_device_until_the_hosts_copy_changes(
+        tiny_model):
+    """Tokens, tables and sampling parameters are sent when a slot is
+    activated, freed or grows a block, and otherwise reused: the last
+    sample's output IS the next step's tokens."""
+    eng = make_engine(tiny_model)                       # block_size 8
+    # (a stop token that is never sampled: the engine reads each step
+    # before it dispatches the next, so the host's numbers are current)
+    first = eng.submit(distinct(9, 0), SamplingParams(
+        max_tokens=40, stop_token_ids=(9999,)))
+    eng.step()
+    sent = {"tables": 1, "sampling": 1}
+    seen = (eng._dev_tables, eng._dev_sampling)
+    # the offsets went a step ahead, under the running program
+    assert eng._dev_offsets.tolist() == eng.offsets.tolist()
+    grown = 0
+    for k in range(30):
+        held = len(eng.allocs[0].blocks)
+        if k == 12:         # a second slot: everything is sent again
+            eng.submit(distinct(5, 50), SamplingParams(
+                max_tokens=3, stop_token_ids=(9999,)))
+        toks = eng._dev_tokens
+        eng.step()
+        grown += len(eng.allocs[0].blocks) != held
+        sent["tables"] += eng._dev_tables is not seen[0]
+        sent["sampling"] += eng._dev_sampling is not seen[1]
+        seen = (eng._dev_tables, eng._dev_sampling)
+        assert (eng._dev_offsets is None) == (k == 13)   # the slot ended
+        # the step read the sample's own output unless a slot came in
+        assert (toks is not None) or k == 12
+        assert list(eng._dev_tokens.shape) == [eng.max_slots]
+    assert grown == 3                  # 19 -> 49 tokens cached, blocks of 8
+    # first send, three grown blocks, one activation, one freed slot
+    assert sent == {"tables": 1 + grown + 2, "sampling": 2}
+    assert int(eng._last_tokens[0]) == first.output[-1]
+    assert eng._decode._cache_size() == 1   # host-sent or device: one program
+
+
+def test_a_step_runs_ahead_only_where_nothing_can_come_between(tiny_model):
+    """With every slot taken, no request waiting, no stop token and no
+    length reached, the next step is dispatched before the one in flight
+    is read (the device never waits for the host) and tokens reach their
+    stream at once; otherwise the engine reads first. Either way the
+    tokens are the same."""
+    never = (9999,)
+    outs = {}
+    eng = make_engine(tiny_model)               # four slots, one taken
+    eng.submit(distinct(9, 0), SamplingParams(max_tokens=4))
+    eng.step()
+    assert eng._in_flight is None
+    for stop in ((), never):
+        eng = make_engine(tiny_model, max_slots=1)
+        req = eng.submit(distinct(9, 0), SamplingParams(
+            max_tokens=12, stop_token_ids=stop))
+        flying, lag = [], []
+        while eng.has_work():
+            eng.step()
+            flying.append(eng._in_flight is not None)
+            lag.append(len(req.output) - req.stream.qsize())
+        outs[stop] = req.output
+        assert len(req.output) == 12 and eng._in_flight is None
+        assert lag[-1] == -1                    # the stream's end marker
+        assert eng.stats["decode_steps"] == 11
+        if stop:
+            assert not any(flying) and lag[:-1] == [1] * 10
+        else:   # the step in flight as the 11th token comes ends the request
+            assert flying == [True] * 10 + [False] and lag[:-1] == [0] * 10
+    assert outs[()] == outs[never]
+    # a request that waits for a slot stops the run-ahead: it is admitted
+    # with nothing in flight
+    eng = make_engine(tiny_model, max_slots=1)
+    first = eng.submit(distinct(9, 0), SamplingParams(max_tokens=6))
+    eng.step()
+    assert eng._in_flight is not None
+    second = eng.submit(distinct(9, 40), SamplingParams(max_tokens=3))
+    eng.step()
+    assert eng._in_flight is None
+    while eng.has_work():
+        eng.step()
+    assert len(first.output) == 6 and len(second.output) == 3
 
 
 def test_admission_and_prefill_counts_are_exact(tiny_model):
@@ -147,7 +280,11 @@ def test_stats_keys_are_fixed_plain_monotone_and_the_phases_sum_to_the_step():
         assert set(PHASES.values()) | {
             "t_step_s", "cpu_host_s", "admitted", "queue_wait_s",
             "prefill_tokens", "prefill_padded_tokens"} <= set(first)
-        assert all(type(v) in (int, float) for v in first.values())
+        # numbers, but for an expert model's per-expert rows (a list,
+        # empty for this dense one: tests/test_olmoe_serving.py)
+        assert first["moe_expert_load"] == []
+        assert all(type(v) in (int, float) for k, v in first.items()
+                   if k != "moe_expert_load")
         snaps = [first]
         for k in range(3):
             req = eng.submit(distinct(12 + k, 60 * k),
