@@ -12,6 +12,9 @@ in ONE process (the one that owns the chip):
           decode-attention implementation ("xla", then "pallas"); then
           once with an expert model (OLMoE's block: MoEConfig with 16 =
           16 heads of 128, experts of 2048 x 1024, few of them).
+  pool    the engine's decode program compiled at a shape whose KV pool
+          outweighs its weights: its temporaries must stay under one
+          pool's bytes (the pool is written in place, never copied).
   train   JaxTrainer(...).fit() whose loop steps make_train_step on
           GPT2Config.gpt2_125m(); with >= 4 devices also a sharded
           llama3_1b step on MeshSpec.auto(4, fsdp=2, tp=2), and a look
@@ -61,6 +64,7 @@ LOSS_BAND = 1.0             # |step-0 loss - ln(vocab)|; unit-variance
 MEM_SPREAD = 1.10           # sharded step: max/min bytes_in_use per device
 TRAIN_STEPS = 5
 MAX_SLOTS, MAX_SEQ = 8, 1024
+POOL_SLOTS = 32             # the pool phase: 32 slots x MAX_SEQ
 EXIT_FAILED, EXIT_NO_CHIP = 1, 4
 
 
@@ -145,12 +149,20 @@ def abstract(x):
             a.shape, a.dtype, sharding=getattr(a, "sharding", None)), x)
 
 
+def i32(*shape):
+    import jax
+    import jax.numpy as jnp
+    return jax.ShapeDtypeStruct(shape, jnp.int32)
+
+
 def program_facts(jitted, *args, **static) -> dict:
     """What XLA built for ``jitted`` at these shapes, read from the
     compiled text so nobody assumes which attention ran: whether it holds
     a Mosaic (Pallas TPU) kernel, and which collectives."""
-    text = jitted.lower(*abstract(args), **static).compile().as_text()
+    compiled = jitted.lower(*abstract(args), **static).compile()
+    text = compiled.as_text()
     return {"mosaic": "tpu_custom_call" in text,
+            "temp_bytes": compiled.memory_analysis().temp_size_in_bytes,
             "collectives": [op for op in ("all-gather", "reduce-scatter",
                                           "all-reduce", "all-to-all",
                                           "collective-permute")
@@ -180,6 +192,7 @@ def sizes() -> dict:
             serve_cfg=LlamaConfig.debug(vocab_size=512, max_seq_len=MAX_SEQ),
             moe_cfg=MoEConfig.debug_olmoe(vocab_size=512,
                                           max_seq_len=MAX_SEQ),
+            pool_cfg=LlamaConfig.debug(vocab_size=512, max_seq_len=MAX_SEQ),
             max_tokens=6,
             gpt2=GPT2Config.debug(), gpt2_batch=(4, 128),
             sharded_cfg=LlamaConfig(
@@ -195,6 +208,12 @@ def sizes() -> dict:
         moe_cfg=MoEConfig.debug_olmoe(
             vocab_size=4096, max_seq_len=MAX_SEQ, dim=2048, n_heads=16,
             n_kv_heads=16, ffn_dim=1024, num_experts=16, expert_top_k=4),
+        # the serve cells' attention (8 KV heads of 128) over narrow
+        # layers: 0.5 GiB of pool at POOL_SLOTS x MAX_SEQ against 0.14
+        # GiB of bf16 weight copies, the temporaries that remain
+        pool_cfg=LlamaConfig(
+            vocab_size=4096, dim=1024, n_layers=4, n_heads=8, n_kv_heads=8,
+            ffn_dim=4096, max_seq_len=MAX_SEQ, remat=False),
         max_tokens=12,
         gpt2=GPT2Config.gpt2_125m(), gpt2_batch=(8, 1024),
         sharded_cfg=dataclasses.replace(llama, max_seq_len=2048),
@@ -288,6 +307,42 @@ def kernels_phase(rep: Report, sz: dict) -> None:
         rep.check(f"flash forward kernel {shape} is a Mosaic kernel "
                   f"unless interpreted", facts["mosaic"] != interpret,
                   f"tpu_custom_call in compiled text: {facts['mosaic']}")
+
+
+# ---------------------------------------------------------------------------
+# phase: the decode program keeps its pool in place
+# ---------------------------------------------------------------------------
+def pool_phase(rep: Report, sz: dict) -> None:
+    """The engine's decode program where the pool outweighs the weights.
+    Scanned over as the layer scan's ``xs``/``ys`` the pool was copied
+    whole around the loop and sliced out and back a layer: 1.3 pools of
+    temporaries, half of a decode step on the chip (PERF.md, PR 27).
+    Carried whole it is written in place, and what is left are the
+    weights' bf16 copies. The verdict is the chip's: a CPU backend
+    widens a bf16 scatter to float32 and back, two float32 stacks."""
+    import jax
+
+    from ray_tpu._private.platform import on_chip
+    from ray_tpu.llm.engine import ContinuousBatchingEngine
+    from ray_tpu.models import model_for
+
+    model = model_for(sz["pool_cfg"])
+    eng = ContinuousBatchingEngine(
+        model, jax.jit(model.init)(jax.random.key(0)),
+        max_slots=POOL_SLOTS, max_seq=MAX_SEQ)
+
+    facts = program_facts(
+        eng._decode, eng.params, i32(POOL_SLOTS), eng.kv,
+        i32(POOL_SLOTS, eng.blocks_per_slot), i32(POOL_SLOTS))
+    pool_bytes = sum(a.nbytes for a in jax.tree.leaves(eng.kv))
+    detail = (f"temporaries {facts['temp_bytes'] / 2**20:.1f} MiB, pool "
+              f"{pool_bytes / 2**20:.1f} MiB {tuple(eng.kv['k'].shape)} x 2, "
+              f"decode attention {eng.decode_attention_impl!r}")
+    if on_chip():
+        rep.check("decode program's temporaries are under one pool's bytes",
+                  facts["temp_bytes"] < pool_bytes, detail)
+    else:
+        rep.info(f"decode program, not judged off the chip: {detail}")
 
 
 # ---------------------------------------------------------------------------
@@ -400,7 +455,6 @@ def check_engine(rep: Report, eng, impl: str) -> None:
     """What the engine counted, that its pool drained, and which of its
     programs hold a Mosaic kernel."""
     import jax
-    import jax.numpy as jnp
 
     from ray_tpu._private.platform import on_chip
 
@@ -417,9 +471,6 @@ def check_engine(rep: Report, eng, impl: str) -> None:
         time.sleep(0.05)
     rep.check("block pool drained", eng.pool.num_free == eng.num_blocks,
               f"{eng.pool.num_free} of {eng.num_blocks} blocks free")
-
-    def i32(*shape):
-        return jax.ShapeDtypeStruct(shape, jnp.int32)
 
     L, _, bs, Hkv, D = eng.kv["k"].shape
     prefix_kv = jax.ShapeDtypeStruct((L, 1, 4 * bs, Hkv, D),
@@ -831,6 +882,7 @@ def main() -> int:
     sz = sizes()
     rep.phase("native libraries", native_phase)
     rep.phase("kernels", kernels_phase, sz)
+    rep.phase("decode program keeps its pool in place", pool_phase, sz)
 
     import ray_tpu
     ray_tpu.init()

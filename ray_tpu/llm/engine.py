@@ -22,7 +22,12 @@ is the in-tree TPU-native equivalent (BASELINE.md config 5):
   per bucket, no recompilation churn), scattered into pool blocks;
 - decode is ONE jitted step for all slots every iteration (inactive
   slots masked), block tables riding along as a tiny int32 array;
-  sampling on-device, only B int32s return to host per step;
+  sampling on-device, only B int32s return to host per step. The pool
+  is DONATED to that step and lives through it whole and in place: the
+  program carries it through its layer scan as one stack addressed by
+  layer (block ``p`` of layer ``l`` is block ``l*NB + p``), writes each
+  slot's new row a layer into it and hands it back aliased — nothing
+  pool-sized is copied or sliced (docs/serving.md);
 - per-request TTFT / throughput stats (the reference's
   `release/llm_tests/serve/benchmark/load_test.py` metrics);
 - the loop accounts for itself: every part of ``step()`` runs inside one

@@ -505,9 +505,20 @@ class LlamaModel:
                                   live: Optional[jax.Array] = None):
         """``decode_step_paged`` and, third, each layer's ``_ffn`` extra
         stacked over layers (``None`` for the dense block), counted over
-        the slots ``live`` [B] bool marks."""
+        the slots ``live`` [B] bool marks.
+
+        The pool lives through the step WHOLE: the layer scan carries
+        the stack ``[L*NB, bs, Hkv, D]`` (the pool with its two leading
+        dimensions merged, the same bytes) and never a layer's slice of
+        it, so a caller that donates the pool gets it back written in
+        place. Layer ``l``'s page ``p`` is page ``l*NB + p`` of the
+        stack: the layer writes its B rows at ``(l*NB + dest_block,
+        dest_off)`` and attention, kernel and reference alike, reads
+        through the block table plus ``l*NB``. (Handed to the scan as
+        ``xs``/``ys`` the pool cost two whole copies a step and a slice
+        out and back a layer: PERF.md, PR 27.)"""
         cfg = self.cfg
-        bs = pool["k"].shape[2]
+        L, NB, bs = pool["k"].shape[:3]
         dest_block = jnp.take_along_axis(
             block_tables, (offsets // bs)[:, None], axis=1)[:, 0]  # [B]
         dest_off = offsets % bs
@@ -519,9 +530,10 @@ class LlamaModel:
         impl = self.paged_decode_impl()
         from ray_tpu.ops.paged_attention import paged_decode_attention
 
-        def block(carry, layer_and_pool):
-            x = carry
-            layer, k_pool, v_pool = layer_and_pool
+        def block(carry, layer_and_base):
+            x, k_pool, v_pool = carry
+            # ``base``: where this layer's blocks start in the stack
+            layer, base = layer_and_base
             dt = cfg.dtype
             with jax.named_scope("norm_residual"):
                 h = rms_norm(x, layer["attn_norm"], eps=cfg.norm_eps)
@@ -537,30 +549,35 @@ class LlamaModel:
             with jax.named_scope("kv_update"):
                 # each slot writes its own private tail block (refcount
                 # 1 — shared prefix blocks are never write targets)
-                k_pool = k_pool.at[dest_block, dest_off].set(
+                k_pool = k_pool.at[base + dest_block, dest_off].set(
                     k_new[:, 0].astype(dt))
-                v_pool = v_pool.at[dest_block, dest_off].set(
+                v_pool = v_pool.at[base + dest_block, dest_off].set(
                     v_new[:, 0].astype(dt))
             with jax.named_scope("attention"):
                 o = paged_decode_attention(q[:, 0], k_pool, v_pool,
-                                           block_tables, lengths, impl=impl)
+                                           block_tables, lengths, impl=impl,
+                                           first_block=base, num_blocks=NB)
                 o = jnp.einsum("bhk,hkd->bd", o, layer["wo"].astype(dt))
             with jax.named_scope("norm_residual"):
                 x = x + o[:, None]
                 h = rms_norm(x, layer["mlp_norm"], eps=cfg.norm_eps)
             down, extra = self._ffn(h, layer, live)
             with jax.named_scope("norm_residual"):
-                return x + down, (k_pool, v_pool, extra)
+                return (x + down, k_pool, v_pool), extra
 
-        x, (k_out, v_out, extras) = jax.lax.scan(
-            block, x, (params["layers"], pool["k"], pool["v"]))
+        stack = (L * NB,) + pool["k"].shape[2:]
+        (x, k_out, v_out), extras = jax.lax.scan(
+            block,
+            (x, pool["k"].reshape(stack), pool["v"].reshape(stack)),
+            (params["layers"], jnp.arange(L, dtype=jnp.int32) * NB))
+        pool = {"k": k_out.reshape(pool["k"].shape),
+                "v": v_out.reshape(pool["v"].shape)}
         with jax.named_scope("logits"):
             x = rms_norm(x, params["norm_f"], eps=cfg.norm_eps)
             head = (params["embed"].T if cfg.tie_embeddings
                     else params["lm_head"])
             logits = jnp.einsum("bsd,dv->bsv", x, head.astype(cfg.dtype))
-            return (logits[:, 0].astype(jnp.float32),
-                    {"k": k_out, "v": v_out}, extras)
+            return logits[:, 0].astype(jnp.float32), pool, extras
 
     def prefill_with_prefix(self, params: Params, tokens: jax.Array,
                             prefix_k: jax.Array, prefix_v: jax.Array,

@@ -35,7 +35,9 @@ TPU-native design:
 
 Shapes: q [B, H, D]; k_pool/v_pool [NB, bs, Hkv, D];
 block_tables [B, MAXB] int32 (physical ids; entries past a slot's
-length are ignored); lengths [B] int32.
+length are ignored); lengths [B] int32. The pools may be a STACK of
+windows of NB blocks (every layer's, ``[L*NB, bs, Hkv, D]``): the tables
+then count from ``first_block``.
 """
 
 from __future__ import annotations
@@ -193,16 +195,30 @@ def kernel_lowers(head_dim: int, kv_heads: int) -> bool:
     return _lane_pack(head_dim, kv_heads) * head_dim % LANES == 0
 
 
-@functools.partial(jax.jit, static_argnames=("scale", "interpret"))
+@functools.partial(jax.jit,
+                   static_argnames=("num_blocks", "scale", "interpret"))
 def paged_decode_attention_pallas(q, k_pool, v_pool, block_tables,
-                                  lengths, *,
+                                  lengths, *, first_block=0,
+                                  num_blocks: Optional[int] = None,
                                   scale: Optional[float] = None,
                                   interpret: bool = False):
     import jax.experimental.pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
     B, H, D = q.shape
-    NB, bs, Hkv, _ = k_pool.shape
+    _, bs, Hkv, _ = k_pool.shape
+    pack = _lane_pack(D, Hkv)                    # KV heads read as one row
+    block_tables = block_tables.astype(jnp.int32)
+    if num_blocks is None or pack == 1:
+        # a page's view below is free: the kernel reads the window's
+        # pages out of the pools where they lie
+        block_tables = block_tables + first_block
+    else:
+        # packed rows are a copy of what is viewed (below): of the
+        # window alone, not of a stack of L windows once a layer
+        k_pool, v_pool = (jax.lax.dynamic_slice_in_dim(
+            pool, first_block, num_blocks) for pool in (k_pool, v_pool))
+    NB = k_pool.shape[0]
     maxb = block_tables.shape[1]
     if not (interpret or kernel_lowers(D, Hkv)):
         raise ValueError(
@@ -210,7 +226,6 @@ def paged_decode_attention_pallas(q, k_pool, v_pool, block_tables,
             f"head_dim {D} x {Hkv} KV heads does not fill them; use the "
             f"XLA reference (impl='xla')")
     scale = scale if scale is not None else D ** -0.5
-    pack = _lane_pack(D, Hkv)
     row_heads, lanes = Hkv // pack, pack * D     # a row: ``pack`` KV heads
     kv_head = jnp.arange(H) // (H // Hkv)        # of each q head
     if pack > 1:
@@ -222,7 +237,6 @@ def paged_decode_attention_pallas(q, k_pool, v_pool, block_tables,
     pages = max(1, min(maxb, CHUNK_ROWS // page_rows))
     chunk_rows = pages * page_rows
     lengths = lengths.astype(jnp.int32)
-    block_tables = block_tables.astype(jnp.int32)
 
     # q head h attends row r of a chunk iff r's KV heads hold its own
     own = (kv_head[:, None] // pack
@@ -256,7 +270,8 @@ def paged_decode_attention_pallas(q, k_pool, v_pool, block_tables,
     # its two minor dims, so this view is free. ([bs, Hkv*D] is NOT: XLA
     # copies the whole pool to build it, 0.6 ms a layer at 201 MB;
     # PERF.md, PR 25.) A narrower D sits padded to the lanes in HBM, and
-    # XLA re-tiles the pool into the packed rows, one copy a layer.
+    # XLA re-tiles the pool (the window, above) into the packed rows,
+    # one copy a layer.
     out = pl.pallas_call(
         functools.partial(_paged_kernel, block_size=bs, pages=pages,
                           max_blocks=maxb, scale=scale, row_heads=row_heads),
@@ -290,15 +305,26 @@ def default_impl(head_dim: int, kv_heads: int) -> str:
 
 
 def paged_decode_attention(q, k_pool, v_pool, block_tables, lengths, *,
-                           impl: str, scale: Optional[float] = None):
+                           impl: str, first_block=0,
+                           num_blocks: Optional[int] = None,
+                           scale: Optional[float] = None):
     """One algorithm, two implementations: ``impl`` is "pallas" (the
     kernel, interpreted where the backend is the CPU) or "xla" (its
-    oracle)."""
+    oracle).
+
+    ``first_block`` (it may be traced) and ``num_blocks`` name a
+    WINDOW of the pools that the tables count from: blocks
+    ``[first_block, first_block + num_blocks)``, one layer's of the
+    stack ``[L*NB, bs, Hkv, D]`` that ``decode_step_paged`` carries.
+    Both sides read it through ``first_block + block_tables`` with no
+    slice of the stack built; only the kernel's packed rows (D < 128),
+    a copy in any case, copy the window alone."""
     if impl == "pallas":
         return paged_decode_attention_pallas(
-            q, k_pool, v_pool, block_tables, lengths, scale=scale,
+            q, k_pool, v_pool, block_tables, lengths,
+            first_block=first_block, num_blocks=num_blocks, scale=scale,
             interpret=pallas_interpret())
     if impl != "xla":
         raise ValueError(f"impl must be 'xla' or 'pallas', got {impl!r}")
     return paged_decode_attention_reference(
-        q, k_pool, v_pool, block_tables, lengths, scale=scale)
+        q, k_pool, v_pool, first_block + block_tables, lengths, scale=scale)

@@ -104,6 +104,13 @@ def v5e(v5e_topo):
 
 
 @pytest.fixture(scope="module")
+def placed(v5e):
+    """A tree of arrays or shapes as abstract arrays on that chip."""
+    return lambda tree: jax.tree.map(
+        lambda a: v5e(*a.shape, dtype=a.dtype), tree)
+
+
+@pytest.fixture(scope="module")
 def smoke_sizes():
     spec = importlib.util.spec_from_file_location("chip_smoke", SMOKE)
     mod = importlib.util.module_from_spec(spec)
@@ -184,7 +191,7 @@ def _as_on_the_chip(monkeypatch):
 
 @pytest.mark.parametrize("maxb", CELL_TABLES)
 def test_decode_program_holds_the_kernel_on_a_tpu_backend(
-        v5e, monkeypatch, maxb):
+        v5e, placed, monkeypatch, maxb):
     """A whole ``decode_step_paged`` at the cells' widths (depth 1), no
     ``decode_attention`` set. This process's backend is the CPU, where
     the dispatcher rightly takes the reference, so the test tells the
@@ -199,9 +206,6 @@ def test_decode_program_holds_the_kernel_on_a_tpu_backend(
     assert model.cfg.decode_attention is None
     assert model.paged_decode_impl() == "pallas"
 
-    def placed(tree):
-        return jax.tree.map(lambda a: v5e(*a.shape, dtype=a.dtype), tree)
-
     args = (placed(jax.eval_shape(model.init, jax.random.key(0))),
             v5e(B, dtype=jnp.int32),
             placed(jax.eval_shape(
@@ -214,7 +218,7 @@ def test_decode_program_holds_the_kernel_on_a_tpu_backend(
     assert not _mosaic(jax.jit(ref.decode_step_paged).lower(*args))
 
 
-def test_llama3_1b_decode_program_holds_the_kernel(v5e, monkeypatch,
+def test_llama3_1b_decode_program_holds_the_kernel(v5e, placed, monkeypatch,
                                                    smoke_sizes):
     """The smoke's served config as published (32/8 heads of 64), no
     ``decode_attention`` set: two KV heads to a 128-lane row."""
@@ -228,9 +232,6 @@ def test_llama3_1b_decode_program_holds_the_kernel(v5e, monkeypatch,
     assert model.paged_decode_impl() == "pallas"
     B, bs, maxb = 8, 32, cfg.max_seq_len // 32
 
-    def placed(tree):
-        return jax.tree.map(lambda a: v5e(*a.shape, dtype=a.dtype), tree)
-
     assert _mosaic(jax.jit(model.decode_step_paged, donate_argnums=(2,)).lower(
         placed(jax.eval_shape(model.init, jax.random.key(0))),
         v5e(B, dtype=jnp.int32),
@@ -238,12 +239,13 @@ def test_llama3_1b_decode_program_holds_the_kernel(v5e, monkeypatch,
         v5e(B, maxb, dtype=jnp.int32), v5e(B, dtype=jnp.int32)))
 
 
-def test_olmoe_cell_decode_program_fits_the_v5e(v5e, monkeypatch):
+def test_olmoe_cell_decode_program_fits_the_v5e(v5e, placed, monkeypatch):
     """``olmoe-1b-7b-d3.batch_decode_moe``'s decode program as the engine
     jits it (the counted step: 32 slots x 3072, depth 3, all 64 experts,
-    weights in float32): the v5e's compiler takes it (15.31 GiB of 15.75
-    when this was written; depth 4 is refused), with the paged kernel
-    and the grouped matmuls as Mosaic calls."""
+    weights in float32): the v5e's compiler takes it (10.81 GiB of 15.75
+    since the pool is no scanned operand; 15.31 before, with depth 4
+    refused), with the paged kernel and the grouped matmuls as Mosaic
+    calls."""
     from ray_tpu.models import MoEConfig, model_for
 
     _as_on_the_chip(monkeypatch)
@@ -254,9 +256,6 @@ def test_olmoe_cell_decode_program_fits_the_v5e(v5e, monkeypatch):
         expert_top_k=8, norm_topk_prob=False, qk_norm=True))
     assert model.paged_decode_impl() == "pallas"
     assert model.ffn_load_shape() == (L, E)
-
-    def placed(tree):
-        return jax.tree.map(lambda a: v5e(*a.shape, dtype=a.dtype), tree)
 
     def step(params, tokens, pool, tables, offsets, load):
         logits, pool, extras = model.decode_step_paged_counted(
@@ -275,6 +274,30 @@ def test_olmoe_cell_decode_program_fits_the_v5e(v5e, monkeypatch):
     total = (mem.argument_size_in_bytes + mem.temp_size_in_bytes
              + mem.output_size_in_bytes - mem.alias_size_in_bytes)
     assert total < 15.75 * 2**30
+
+
+def test_smokes_decode_program_keeps_its_pool_in_place_on_the_v5e(
+        v5e, placed, monkeypatch, smoke_sizes):
+    """chip_smoke.py's pool phase, compiled here for the described v5e:
+    the decode program's temporaries are under ONE pool's bytes (what is
+    left are the bf16 copies of the weights). With the pool scanned over
+    by the layer scan they held a whole copy of it and a layer's slice
+    twice more (5.44 GiB for 2.44 at ``batch_decode``'s shape)."""
+    from ray_tpu.models import model_for
+
+    _as_on_the_chip(monkeypatch)
+    model = model_for(smoke_sizes["pool_cfg"])
+    assert model.paged_decode_impl() == "pallas"
+    B, bs = 32, 32
+    maxb = model.cfg.max_seq_len // bs
+
+    pool = placed(jax.eval_shape(lambda: model.init_kv_pool(B * maxb + 1, bs)))
+    compiled = jax.jit(model.decode_step_paged, donate_argnums=(2,)).lower(
+        placed(jax.eval_shape(model.init, jax.random.key(0))),
+        v5e(B, dtype=jnp.int32), pool, v5e(B, maxb, dtype=jnp.int32),
+        v5e(B, dtype=jnp.int32)).compile()
+    pool_bytes = sum(a.size * a.dtype.itemsize for a in jax.tree.leaves(pool))
+    assert compiled.memory_analysis().temp_size_in_bytes < pool_bytes
 
 
 def test_decode_under_a_mesh_keeps_the_reference(v5e_topo, monkeypatch):

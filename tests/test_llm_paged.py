@@ -304,6 +304,29 @@ def test_paged_kernel_on_engine_inputs(case):
     assert not np.asarray(out, np.float32)[~live].any()
 
 
+@pytest.mark.parametrize("case", ["mixed_gqa4", "one_head_a_row"])
+def test_paged_attention_reads_a_window_of_a_stack(case):
+    """``first_block``/``num_blocks``: the tables count from a window of
+    the pools (a layer's blocks of the stack ``decode_step_paged``
+    carries). Kernel (packed rows and one head a row) and reference read
+    window 1 of three as they read that window alone; the windows either
+    side hold other values."""
+    from ray_tpu.ops.paged_attention import paged_decode_attention
+    (q, kp, vp, tables, lengths), tol = _engine_like_case(case)
+    NB = kp.shape[0]
+    k3, v3 = (jnp.concatenate([p + 1.0, p, p - 1.0]) for p in (kp, vp))
+    for impl in ("xla", "pallas"):
+        alone = paged_decode_attention(q, kp, vp, tables, lengths, impl=impl)
+        windowed = jax.jit(functools.partial(
+            paged_decode_attention, impl=impl, num_blocks=NB))(
+                q, k3, v3, tables, lengths, first_block=jnp.int32(NB))
+        np.testing.assert_allclose(np.asarray(windowed), np.asarray(alone),
+                                   atol=tol, rtol=tol)
+        other = paged_decode_attention(q, k3, v3, tables, lengths, impl=impl,
+                                       first_block=2 * NB, num_blocks=NB)
+        assert float(jnp.max(jnp.abs(other - alone))) > 0.5
+
+
 @pytest.mark.parametrize("head_dim,kv_heads,pack,lowers", [
     (128, 8, 1, True),      # the serve cells (mistral-7b)
     (64, 8, 2, True),       # llama3_1b: two KV heads fill the 128 lanes
@@ -512,3 +535,176 @@ def test_paged_engine_soak_no_leaks(tiny_model):
     assert eng.pool.num_free == 40            # fully reclaimed
     assert all(c == 0 for c in eng.pool.refcount)
     assert eng.stats["prefix_prefills"] > 0   # sharing actually happened
+
+
+# ---------------------------------------------------------------------------
+# The pool through a decode step: whole, written in place, addressed by
+# layer (the layer scan carries the stack [L*NB, bs, Hkv, D]; PERF.md, PR 27)
+# ---------------------------------------------------------------------------
+
+def _deep_model(kind, impl, layers=3, dtype=jnp.float32):
+    """A debug model of three layers (the first, a middle and the last
+    one's blocks each have a neighbour layer to spill into), its decode
+    attention forced to ``impl``."""
+    import dataclasses
+
+    from ray_tpu.models import MoEConfig, model_for
+    cfg = {"dense": LlamaConfig.debug(vocab_size=512),
+           "olmoe": MoEConfig.debug_olmoe(),
+           # the cells' head_dim: the kernel reads one KV head to a row
+           # and the stack's pages where they lie (no packed copy)
+           "dense_d128": LlamaConfig(
+               vocab_size=512, dim=256, n_heads=2, n_kv_heads=1,
+               ffn_dim=256, max_seq_len=128, remat=False)}[kind]
+    model = model_for(dataclasses.replace(
+        cfg, n_layers=layers, dtype=dtype, decode_attention=impl))
+    return model, jax.jit(model.init)(jax.random.key(3))
+
+
+def _scans(jaxpr):
+    """Every ``scan`` equation of a jaxpr, nested ones included."""
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "scan":
+            yield eqn
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            yield from _scans(sub)
+
+
+def layer_scan_operands(model, params, slots=2, num_blocks=7, bs=8, maxb=2):
+    """(xs shapes, ys shapes, carry shapes, the pool's per-layer shape,
+    the stack's shape) of the layer scan of ``decode_step_paged``."""
+    pool = model.init_kv_pool(num_blocks, bs)
+    jaxpr = jax.make_jaxpr(model.decode_step_paged)(
+        params, jnp.zeros((slots,), jnp.int32), pool,
+        jnp.zeros((slots, maxb), jnp.int32), jnp.zeros((slots,), jnp.int32))
+    L = model.cfg.n_layers
+    scan, = [e for e in _scans(jaxpr.jaxpr) if e.params["length"] == L]
+    n_consts, n_carry = scan.params["num_consts"], scan.params["num_carry"]
+    shapes = lambda vs: [tuple(v.aval.shape) for v in vs]
+    per_layer = tuple(pool["k"].shape[1:])
+    return (shapes(scan.invars[n_consts + n_carry:]),
+            shapes(scan.outvars[n_carry:]),
+            shapes(scan.invars[n_consts:n_consts + n_carry]),
+            per_layer, (L * per_layer[0],) + per_layer[1:])
+
+
+@pytest.mark.parametrize("impl", ["xla", "pallas"])
+def test_layer_scan_does_not_scan_over_the_pool(impl):
+    """Handed to the layer scan as ``xs`` and returned as ``ys``, the
+    pool is sliced out of its stack and written back a layer and copied
+    whole around the loop (22 of a 40 ms step on the chip). The scan
+    carries the whole stack instead, twice (k and v), and no operand it
+    scans over or stacks up has a layer's pool shape."""
+    model, params = _deep_model("dense", impl)
+    xs, ys, carry, per_layer, stack = layer_scan_operands(model, params)
+    assert per_layer not in xs and per_layer not in ys
+    assert ys == []                     # the dense block stacks nothing
+    assert carry.count(stack) == 2
+    # ... and the step's result is still the pool in its own layout
+    pool = model.init_kv_pool(7, 8)
+    _, out = jax.eval_shape(
+        model.decode_step_paged, params, jnp.zeros((2,), jnp.int32), pool,
+        jnp.zeros((2, 2), jnp.int32), jnp.zeros((2,), jnp.int32))
+    assert jax.tree.map(lambda a: (a.shape, a.dtype), out) == jax.tree.map(
+        lambda a: (a.shape, a.dtype), pool)
+
+
+def paged_against_dense(model, params, prompt=13, steps=6, bs=8, seed=0):
+    """Prefill two sequences into ``forward_step``'s dense cache, lay it
+    into a pool through SHUFFLED block tables, then decode ``steps``
+    tokens down both paths. Returns the largest |logit difference| of
+    the steps, and of the pool read back through the tables against the
+    dense cache, per layer. Each layer's K/V differ (they are the
+    layer's own projections), so a row written to, or a page read from,
+    another layer's blocks shows in both."""
+    import dataclasses
+    I32 = jnp.int32
+    dense = type(model)(dataclasses.replace(model.cfg,
+                                            decode_attention="xla"))
+    rng = np.random.default_rng(seed)
+    B, total = 2, prompt + steps
+    maxb = -(-total // bs)
+    toks = jnp.asarray(rng.integers(1, model.cfg.vocab_size, (B, total)), I32)
+    cache = dense.init_kv_cache(B, maxb * bs)
+    padded = jnp.zeros((B, maxb * bs), I32).at[:, :prompt].set(
+        toks[:, :prompt])
+    _, cache = dense.forward_step(params, padded, cache, jnp.zeros((B,), I32))
+    L = model.cfg.n_layers
+    NB = B * maxb + 3                  # two blocks nobody owns, + scratch
+    ids = jnp.asarray(rng.permutation(NB - 1)[:B * maxb], I32)
+    tables = ids.reshape(B, maxb)
+    pool = model.init_kv_pool(NB, bs)
+    pool = {n: pool[n].at[:, ids].set(
+        cache[n].reshape(L, B * maxb, bs, *cache[n].shape[3:]))
+        for n in ("k", "v")}
+    worst = 0.0
+    step = jax.jit(model.decode_step_paged)
+    for pos in range(prompt, total):
+        offsets = jnp.full((B,), pos, I32)
+        got, pool = step(params, toks[:, pos], pool, tables, offsets)
+        want, cache = dense.forward_step(params, toks[:, pos:pos + 1], cache,
+                                         offsets)
+        worst = max(worst, float(jnp.max(jnp.abs(got - want[:, 0]))))
+    per_layer = [max(
+        float(jnp.max(jnp.abs(
+            pool[n][l][tables].reshape(B, maxb * bs, *pool[n].shape[3:])
+            [:, :total] - cache[n][l][:, :total])))
+        for n in ("k", "v")) for l in range(L)]
+    return worst, per_layer
+
+
+@pytest.mark.parametrize("impl", ["xla", "pallas"])
+@pytest.mark.parametrize("kind", ["dense", "olmoe", "dense_d128"])
+def test_paged_decode_keeps_each_layers_blocks_apart(kind, impl):
+    """Six steps (across a block boundary) of ``decode_step_paged``
+    against ``forward_step``'s dense cache on the same tokens, in
+    float32: with layer ``l`` addressed as pages ``l*NB + p`` of one
+    stack, an index that is off lands in another layer's live blocks,
+    not out of range, and only the values can tell."""
+    from ray_tpu.ops.paged_attention import _lane_pack
+    model, params = _deep_model(kind, impl)
+    assert model.cfg.n_layers == 3 and model.paged_decode_impl() == impl
+    assert (_lane_pack(model.cfg.head_dim, model.cfg.n_kv_heads) == 1) == (
+        kind == "dense_d128")
+    worst, per_layer = paged_against_dense(model, params)
+    assert worst < 1e-4, worst
+    assert max(per_layer) < 1e-5, per_layer
+
+
+@pytest.mark.parametrize("impl", ["xla", "pallas"])
+def test_a_decode_step_writes_only_its_own_rows(impl):
+    """One step on a pool full of noise: every element outside
+    ``(layer, dest_block[b], dest_off[b])`` is bit-identical afterwards:
+    the other layers' copies of the same block ids, the blocks either
+    side of the scratch block in the stack (the last free block of a
+    layer and block 0 of the next), a dead slot's row in scratch aside."""
+    model, params = _deep_model("dense", impl, dtype=jnp.bfloat16)
+    rng = np.random.default_rng(2)
+    L, NB, bs, maxb = model.cfg.n_layers, 9, 8, 3
+    scratch = NB - 1
+    shape = model.init_kv_pool(NB, bs)["k"].shape
+    pool = {n: jnp.asarray(rng.normal(size=shape), jnp.bfloat16)
+            for n in ("k", "v")}
+    # slots 0-2 live at blocks 1-6; slot 3 is dead (all scratch); blocks
+    # 0 and 7, the scratch block's neighbours in the stack, are nobody's
+    tables = np.array([[1, 4, scratch], [5, scratch, scratch],
+                       [2, 6, 3], [scratch] * maxb], np.int32)
+    offsets = np.array([9, 7, 16, 0], np.int32)
+    dest_block = tables[np.arange(4), offsets // bs]
+    assert dest_block.tolist() == [4, 5, 3, scratch]
+    _, out = jax.jit(model.decode_step_paged)(
+        params, jnp.asarray([5, 6, 7, 0], jnp.int32), pool,
+        jnp.asarray(tables), jnp.asarray(offsets))
+    written = np.zeros(shape[:3], bool)             # [L, NB, bs]
+    written[:, dest_block, offsets % bs] = True
+    assert written.sum() == L * 4
+    for n in ("k", "v"):
+        before = np.asarray(pool[n]).view(np.uint16)
+        after = np.asarray(out[n]).view(np.uint16)
+        assert out[n].shape == shape
+        assert (before[~written] == after[~written]).all(), n
+        # the live slots' rows did change, in every layer
+        changed = (before != after).any(axis=(-1, -2))
+        assert changed[:, dest_block[:3], (offsets % bs)[:3]].all(), n
+        for untouched in (0, scratch - 1):
+            assert not changed[:, untouched].any()

@@ -446,3 +446,18 @@ def test_expert_scopes_are_in_the_lowered_programs_metadata(method):
                            "moe_combine", "qk_norm")
                if not re.search(rf'[/("]{s}[/)]', text)]
     assert not missing, f"{method}: no operation under scope(s) {missing}"
+
+
+def test_the_expert_models_layer_scan_stacks_its_extras_and_never_the_pool():
+    """The expert model shares the dense decode step's layer scan (it
+    overrides the q/k treatment and the FFN, not the scan): the pool
+    rides its carry as one stack, and all it stacks up over layers are
+    its FFN's extras: router loss, per-expert load, chosen experts."""
+    from tests.test_llm_paged import layer_scan_operands
+
+    cfg, model, params = make(n_layers=3)
+    xs, ys, carry, per_layer, stack = layer_scan_operands(model, params)
+    assert per_layer not in xs and per_layer not in ys
+    assert carry.count(stack) == 2
+    L, E, K = cfg.n_layers, cfg.num_experts, cfg.expert_top_k
+    assert sorted(ys) == sorted([(L,), (L, E), (L, 2, 1, K)])
