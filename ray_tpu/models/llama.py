@@ -7,6 +7,12 @@ it is in-tree and TPU-first:
 
 - params are a pytree of stacked-layer arrays; the transformer stack is a
   single ``lax.scan`` (one compiled block regardless of depth);
+- the decoder layer is written ONCE (``LlamaModel._layer``). The four
+  programs — training ``apply``, the slot-cache ``forward_step`` (bucket
+  prefill, the tests' dense oracle), ``decode_step_paged`` and
+  ``prefill_with_prefix`` — are a scan over it each, and differ in the
+  one thing they hand it: how this call's K/V are written and the
+  earlier ones read (``attend``);
 - every param/activation carries logical axis names resolved to the 6-axis
   mesh (dp/fsdp/pp/tp/sp/ep) by ``ray_tpu.parallel.mesh`` rules —
   Megatron-style TP, ZeRO-style fsdp sharding, ring-attention SP all come
@@ -23,7 +29,7 @@ from typing import Any, Dict, Optional, Tuple
 import jax
 import jax.numpy as jnp
 
-from ray_tpu.ops.attention import attention
+from ray_tpu.ops.attention import attention, reference_attention
 from ray_tpu.ops.norms import rms_norm
 from ray_tpu.ops.ring_attention import ring_attention
 from ray_tpu.ops.rope import apply_rope, rope_frequencies
@@ -65,9 +71,7 @@ class LlamaConfig:
     # program takes the Mosaic kernel on a TPU backend and the XLA
     # reference elsewhere (``LlamaModel.paged_decode_impl``,
     # ops/paged_attention.py); "xla" / "pallas" force a side (tests,
-    # chip_smoke.py). ``forward_step``'s T == 1 branch takes its
-    # slot-major kernel (ops/decode_attention.py, which does not lower
-    # for the v5e) only on an explicit "pallas".
+    # chip_smoke.py).
     decode_attention: Optional[str] = None
 
     def __post_init__(self):
@@ -286,12 +290,13 @@ class LlamaModel:
         return attention(q, k, v, causal=True, positions_q=positions,
                          positions_k=positions, use_flash=None)
 
-    # -- the two places a model of this family may differ from the dense
-    # block; every copy of the block (``_block`` and the three serving
-    # closures below) goes through them ------------------------------------
+    # -- the decoder layer: ONE body (``_layer``) for every program. A
+    # MODEL of the family differs in ``_qk_norm`` and ``_ffn`` (``MoEModel``
+    # overrides both and nothing else), a PROGRAM in the ``attend`` it
+    # hands the layer ------------------------------------------------------
     def _qk_norm(self, q, k, layer: Params):
         """Between the q/k projections and RoPE. q [B, T, H, hd], k
-        [B, T, Hkv, hd]; the dense block does nothing here."""
+        [B, T, Hkv, hd]; the dense layer does nothing here."""
         return q, k
 
     def _ffn(self, h, layer: Params, live=None, constrain: bool = False):
@@ -299,7 +304,7 @@ class LlamaModel:
         ``(out [B, T, D], extra)``. ``extra`` is whatever the model
         wants carried out of the layer scan (``None`` here); ``live``
         [B] bool marks the rows it should count (all, if ``None``);
-        ``constrain`` (the training block) pins the inner activation's
+        ``constrain`` (the training program) pins the inner activation's
         sharding to the mesh."""
         dt = self.cfg.dtype
         with jax.named_scope("mlp"):
@@ -311,28 +316,69 @@ class LlamaModel:
             down = jnp.einsum("bsf,fd->bsd", ff, layer["w_down"].astype(dt))
         return down, None
 
-    def _block(self, x, layer: Params, positions):
-        """-> (x, the layer's ``_ffn`` extra)."""
+    def _layer(self, x, layer: Params, positions, attend, live=None,
+               constrain: bool = False):
+        """One decoder layer. x [B, T, D]; ``positions`` what RoPE turns
+        q and k by (``None``: 0..T-1); ``attend(q, k, v) -> (o, kv)``
+        with q/o [B, T, H, hd] and k/v [B, T, Hkv, hd], the calling
+        program's own: it writes this call's K/V where the program keeps
+        them, reads the earlier ones, and hands back as ``kv`` whatever
+        the program's layer scan carries on or stacks up. ``live`` is
+        ``_ffn``'s; ``constrain`` (the training program alone) pins the
+        activations' sharding to the mesh.
+        -> (x, ``kv``, the layer's ``_ffn`` extra)."""
         cfg = self.cfg
         dt = cfg.dtype
+
+        def pin(a, *names):
+            return self._constrain(a, *names) if constrain else a
+
         with jax.named_scope("norm_residual"):
             h = rms_norm(x, layer["attn_norm"], eps=cfg.norm_eps)
         with jax.named_scope("attention"):
             q = jnp.einsum("bsd,dhk->bshk", h, layer["wq"].astype(dt))
-            kk = jnp.einsum("bsd,dhk->bshk", h, layer["wk"].astype(dt))
-            vv = jnp.einsum("bsd,dhk->bshk", h, layer["wv"].astype(dt))
-            q, kk = self._qk_norm(q, kk, layer)
-            q = self._constrain(q, "batch", "seq", "heads", None)
+            k = jnp.einsum("bsd,dhk->bshk", h, layer["wk"].astype(dt))
+            v = jnp.einsum("bsd,dhk->bshk", h, layer["wv"].astype(dt))
+            q, k = self._qk_norm(q, k, layer)
+            q = pin(q, "batch", "seq", "heads", None)
             q = apply_rope(q, self._angles, positions)
-            kk = apply_rope(kk, self._angles, positions)
-            o = self._attention(q, kk, vv, positions)
+            k = apply_rope(k, self._angles, positions)
+        o, kv = attend(q, k, v)
+        with jax.named_scope("attention"):
             o = jnp.einsum("bshk,hkd->bsd", o, layer["wo"].astype(dt))
         with jax.named_scope("norm_residual"):
-            x = x + self._constrain(o, "batch", "seq", "embed")
+            x = x + pin(o, "batch", "seq", "embed")
             h = rms_norm(x, layer["mlp_norm"], eps=cfg.norm_eps)
-        down, extra = self._ffn(h, layer, constrain=True)
+        down, extra = self._ffn(h, layer, live, constrain)
         with jax.named_scope("norm_residual"):
-            return x + self._constrain(down, "batch", "seq", "embed"), extra
+            return x + pin(down, "batch", "seq", "embed"), kv, extra
+
+    def _embed(self, params: Params, tokens: jax.Array,
+               constrain: bool = False) -> jax.Array:
+        """tokens [B, T] -> x [B, T, D] in the compute dtype."""
+        with jax.named_scope("embed"):
+            x = self._embed_lookup(params["embed"].astype(self.cfg.dtype),
+                                   tokens)
+            return (self._constrain(x, "batch", "seq", "embed") if constrain
+                    else x)
+
+    def _head(self, params: Params, x: jax.Array,
+              last: Optional[jax.Array] = None,
+              constrain: bool = False) -> jax.Array:
+        """Final norm and LM head: x [B, T, D] -> f32 logits [B, T, V];
+        with ``last`` [B], of row ``last[b]`` of each sequence alone
+        ([B, 1, V]: the other rows never meet the head)."""
+        cfg = self.cfg
+        with jax.named_scope("logits"):
+            x = rms_norm(x, params["norm_f"], eps=cfg.norm_eps)
+            if last is not None:
+                x = jnp.take_along_axis(x, last[:, None, None], axis=1)
+            head = (params["embed"].T if cfg.tie_embeddings
+                    else params["lm_head"])
+            logits = jnp.einsum("bsd,dv->bsv", x, head.astype(cfg.dtype))
+            if constrain:
+                logits = self._constrain(logits, "batch", "seq", "vocab")
+            return logits.astype(jnp.float32)
 
     def apply(self, params: Params, tokens: jax.Array,
               positions: Optional[jax.Array] = None) -> jax.Array:
@@ -342,31 +388,27 @@ class LlamaModel:
     def _apply_with_extras(self, params: Params, tokens: jax.Array,
                            positions: Optional[jax.Array] = None):
         """``apply`` and, stacked over layers, each layer's ``_ffn``
-        extra (``None`` for the dense block)."""
+        extra (``None`` for the dense layer)."""
         cfg = self.cfg
-        with jax.named_scope("embed"):
-            x = self._embed_lookup(params["embed"].astype(cfg.dtype), tokens)
-            x = self._constrain(x, "batch", "seq", "embed")
 
-        block = self._block
+        def attend(q, k, v):        # training keeps no K/V
+            with jax.named_scope("attention"):
+                return self._attention(q, k, v, positions), None
+
+        def layer_fn(x, layer):
+            x, _, extra = self._layer(x, layer, positions, attend,
+                                      constrain=True)
+            return x, extra
+
         if cfg.remat:
             if cfg.remat_policy == "dots":
-                block = jax.checkpoint(
-                    block, policy=jax.checkpoint_policies.dots_saveable)
+                layer_fn = jax.checkpoint(
+                    layer_fn, policy=jax.checkpoint_policies.dots_saveable)
             else:
-                block = jax.checkpoint(block, static_argnums=())
-
-        def scan_body(x, layer):
-            return block(x, layer, positions)
-
-        x, extras = jax.lax.scan(scan_body, x, params["layers"])
-        with jax.named_scope("logits"):
-            x = rms_norm(x, params["norm_f"], eps=cfg.norm_eps)
-            head = (params["embed"].T if cfg.tie_embeddings
-                    else params["lm_head"])
-            logits = jnp.einsum("bsd,dv->bsv", x, head.astype(cfg.dtype))
-            logits = self._constrain(logits, "batch", "seq", "vocab")
-            return logits.astype(jnp.float32), extras
+                layer_fn = jax.checkpoint(layer_fn)
+        x = self._embed(params, tokens, constrain=True)
+        x, extras = jax.lax.scan(layer_fn, x, params["layers"])
+        return self._head(params, x, constrain=True), extras
 
     # -- KV-cache inference path (serving; BASELINE.md config 5) ----------
     def init_kv_cache(self, batch: int, max_seq: int) -> Params:
@@ -386,72 +428,34 @@ class LlamaModel:
         Returns (logits [B, T, V], updated cache). Static shapes: the same
         jit specialization serves every request of a given (B, T, S).
         """
-        cfg = self.cfg
         B, T = tokens.shape
         S = cache["k"].shape[2]
         q_pos = offsets[:, None] + jnp.arange(T)[None, :]        # [B, T]
-        with jax.named_scope("embed"):
-            x = self._embed_lookup(params["embed"].astype(cfg.dtype), tokens)
-
         batch_idx = jnp.arange(B)[:, None]
 
-        def block(carry, layer_and_cache):
-            x = carry
+        def step(x, layer_and_cache):
             layer, k_cache, v_cache = layer_and_cache
-            dt = cfg.dtype
-            with jax.named_scope("norm_residual"):
-                h = rms_norm(x, layer["attn_norm"], eps=cfg.norm_eps)
-            with jax.named_scope("attention"):
-                q = jnp.einsum("bsd,dhk->bshk", h, layer["wq"].astype(dt))
-                k_new = jnp.einsum("bsd,dhk->bshk", h,
-                                   layer["wk"].astype(dt))
-                v_new = jnp.einsum("bsd,dhk->bshk", h,
-                                   layer["wv"].astype(dt))
-                q, k_new = self._qk_norm(q, k_new, layer)
-                q = apply_rope(q, self._angles, q_pos)
-                k_new = apply_rope(k_new, self._angles, q_pos)
-            with jax.named_scope("kv_update"):
-                # scatter new k/v into the cache at each slot's write
-                # offsets
-                k_cache = k_cache.at[batch_idx, q_pos].set(k_new)
-                v_cache = v_cache.at[batch_idx, q_pos].set(v_new)
-            with jax.named_scope("attention"):
-                if T == 1 and cfg.decode_attention == "pallas":
-                    # single-token decode: ragged kernel skips KV blocks
-                    # past each slot's live length
-                    from ray_tpu.ops.decode_attention import \
-                        ragged_decode_attention_pallas
-                    o = ragged_decode_attention_pallas(
-                        q[:, 0], k_cache, v_cache, q_pos[:, 0] + 1)[:, None]
-                else:
+
+            def attend(q, k_new, v_new):
+                with jax.named_scope("kv_update"):
+                    # scatter new k/v into the cache at each slot's write
+                    # offsets
+                    k_all = k_cache.at[batch_idx, q_pos].set(k_new)
+                    v_all = v_cache.at[batch_idx, q_pos].set(v_new)
+                with jax.named_scope("attention"):
                     # attend over cache positions <= own position
-                    from ray_tpu.ops.attention import NEG_INF, _repeat_kv
-                    kk = _repeat_kv(k_cache, cfg.n_heads)
-                    vv = _repeat_kv(v_cache, cfg.n_heads)
-                    s = jnp.einsum("bthd,bshd->bhts", q, kk,
-                                   preferred_element_type=jnp.float32)
-                    s = s * (cfg.head_dim ** -0.5)
-                    mask = (jnp.arange(S)[None, None, :]
-                            <= q_pos[:, :, None])
-                    s = jnp.where(mask[:, None], s, NEG_INF)
-                    p = jax.nn.softmax(s, axis=-1)
-                    o = jnp.einsum("bhts,bshd->bthd", p.astype(dt), vv)
-                o = jnp.einsum("bshk,hkd->bsd", o, layer["wo"].astype(dt))
-            with jax.named_scope("norm_residual"):
-                x = x + o
-                h = rms_norm(x, layer["mlp_norm"], eps=cfg.norm_eps)
-            down, _ = self._ffn(h, layer)
-            with jax.named_scope("norm_residual"):
-                return x + down, (k_cache, v_cache)
+                    o = reference_attention(q, k_all, v_all,
+                                            positions_q=q_pos,
+                                            positions_k=jnp.arange(S))
+                return o, (k_all, v_all)
+
+            x, kv, _ = self._layer(x, layer, q_pos, attend)
+            return x, kv
 
         x, (k_out, v_out) = jax.lax.scan(
-            block, x, (params["layers"], cache["k"], cache["v"]))
-        with jax.named_scope("logits"):
-            x = rms_norm(x, params["norm_f"], eps=cfg.norm_eps)
-            head = (params["embed"].T if cfg.tie_embeddings
-                    else params["lm_head"])
-            logits = jnp.einsum("bsd,dv->bsv", x, head.astype(cfg.dtype))
-            return logits.astype(jnp.float32), {"k": k_out, "v": v_out}
+            step, self._embed(params, tokens),
+            (params["layers"], cache["k"], cache["v"]))
+        return self._head(params, x), {"k": k_out, "v": v_out}
 
     # -- paged KV-cache path (llm/engine.py + llm/paged_cache.py) ---------
     def init_kv_pool(self, num_blocks: int, block_size: int) -> Params:
@@ -504,7 +508,7 @@ class LlamaModel:
                                   offsets: jax.Array,
                                   live: Optional[jax.Array] = None):
         """``decode_step_paged`` and, third, each layer's ``_ffn`` extra
-        stacked over layers (``None`` for the dense block), counted over
+        stacked over layers (``None`` for the dense layer), counted over
         the slots ``live`` [B] bool marks.
 
         The pool lives through the step WHOLE: the layer scan carries
@@ -517,67 +521,47 @@ class LlamaModel:
         through the block table plus ``l*NB``. (Handed to the scan as
         ``xs``/``ys`` the pool cost two whole copies a step and a slice
         out and back a layer: PERF.md, PR 27.)"""
-        cfg = self.cfg
         L, NB, bs = pool["k"].shape[:3]
         dest_block = jnp.take_along_axis(
             block_tables, (offsets // bs)[:, None], axis=1)[:, 0]  # [B]
         dest_off = offsets % bs
         lengths = offsets + 1
         q_pos = offsets[:, None]                                   # [B, 1]
-        with jax.named_scope("embed"):
-            x = self._embed_lookup(params["embed"].astype(cfg.dtype),
-                                   tokens[:, None])                # [B,1,D]
         impl = self.paged_decode_impl()
         from ray_tpu.ops.paged_attention import paged_decode_attention
 
-        def block(carry, layer_and_base):
+        def step(carry, layer_and_base):
             x, k_pool, v_pool = carry
             # ``base``: where this layer's blocks start in the stack
             layer, base = layer_and_base
-            dt = cfg.dtype
-            with jax.named_scope("norm_residual"):
-                h = rms_norm(x, layer["attn_norm"], eps=cfg.norm_eps)
-            with jax.named_scope("attention"):
-                q = jnp.einsum("bsd,dhk->bshk", h, layer["wq"].astype(dt))
-                k_new = jnp.einsum("bsd,dhk->bshk", h,
-                                   layer["wk"].astype(dt))
-                v_new = jnp.einsum("bsd,dhk->bshk", h,
-                                   layer["wv"].astype(dt))
-                q, k_new = self._qk_norm(q, k_new, layer)
-                q = apply_rope(q, self._angles, q_pos)
-                k_new = apply_rope(k_new, self._angles, q_pos)
-            with jax.named_scope("kv_update"):
-                # each slot writes its own private tail block (refcount
-                # 1 — shared prefix blocks are never write targets)
-                k_pool = k_pool.at[base + dest_block, dest_off].set(
-                    k_new[:, 0].astype(dt))
-                v_pool = v_pool.at[base + dest_block, dest_off].set(
-                    v_new[:, 0].astype(dt))
-            with jax.named_scope("attention"):
-                o = paged_decode_attention(q[:, 0], k_pool, v_pool,
-                                           block_tables, lengths, impl=impl,
-                                           first_block=base, num_blocks=NB)
-                o = jnp.einsum("bhk,hkd->bd", o, layer["wo"].astype(dt))
-            with jax.named_scope("norm_residual"):
-                x = x + o[:, None]
-                h = rms_norm(x, layer["mlp_norm"], eps=cfg.norm_eps)
-            down, extra = self._ffn(h, layer, live)
-            with jax.named_scope("norm_residual"):
-                return (x + down, k_pool, v_pool), extra
+
+            def attend(q, k_new, v_new):
+                with jax.named_scope("kv_update"):
+                    # each slot writes its own private tail block (refcount
+                    # 1 — shared prefix blocks are never write targets)
+                    k_all = k_pool.at[base + dest_block, dest_off].set(
+                        k_new[:, 0])
+                    v_all = v_pool.at[base + dest_block, dest_off].set(
+                        v_new[:, 0])
+                with jax.named_scope("attention"):
+                    o = paged_decode_attention(
+                        q[:, 0], k_all, v_all, block_tables, lengths,
+                        impl=impl, first_block=base, num_blocks=NB)
+                return o[:, None], (k_all, v_all)
+
+            x, (k_pool, v_pool), extra = self._layer(
+                x, layer, q_pos, attend, live=live)
+            return (x, k_pool, v_pool), extra
 
         stack = (L * NB,) + pool["k"].shape[2:]
         (x, k_out, v_out), extras = jax.lax.scan(
-            block,
-            (x, pool["k"].reshape(stack), pool["v"].reshape(stack)),
+            step,
+            (self._embed(params, tokens[:, None]),                 # [B,1,D]
+             pool["k"].reshape(stack), pool["v"].reshape(stack)),
             (params["layers"], jnp.arange(L, dtype=jnp.int32) * NB))
         pool = {"k": k_out.reshape(pool["k"].shape),
                 "v": v_out.reshape(pool["v"].shape)}
-        with jax.named_scope("logits"):
-            x = rms_norm(x, params["norm_f"], eps=cfg.norm_eps)
-            head = (params["embed"].T if cfg.tie_embeddings
-                    else params["lm_head"])
-            logits = jnp.einsum("bsd,dv->bsv", x, head.astype(cfg.dtype))
-            return logits[:, 0].astype(jnp.float32), pool, extras
+        return self._head(params, x)[:, 0], pool, extras
 
     def prefill_with_prefix(self, params: Params, tokens: jax.Array,
                             prefix_k: jax.Array, prefix_v: jax.Array,
@@ -595,10 +579,8 @@ class LlamaModel:
         prefix blocks are never copied or rewritten (prefix-reuse skips
         their FLOPs entirely).
         """
-        cfg = self.cfg
-        N, Tb = tokens.shape
+        Tb = tokens.shape[1]
         Pmax = prefix_k.shape[2]
-        dt = cfg.dtype
         # absolute positions: suffix token t sits at prefix_len + t;
         # padded prefix rows get a position PAST every query so the
         # causal mask drops them
@@ -607,59 +589,29 @@ class LlamaModel:
         pos_prefix = jnp.where(
             jnp.arange(Pmax)[None, :] < prefix_len[:, None],
             jnp.arange(Pmax)[None, :], far)                          # [N,Pmax]
-        with jax.named_scope("embed"):
-            x = self._embed_lookup(params["embed"].astype(dt), tokens)
+        pos_k = jnp.concatenate([pos_prefix, pos_q], axis=1)      # [N,P+Tb]
 
-        from ray_tpu.ops.attention import NEG_INF, _repeat_kv
-
-        def block(carry, layer_and_prefix):
-            x = carry
+        def step(x, layer_and_prefix):
             layer, kp, vp = layer_and_prefix       # kp/vp [N, Pmax, Hkv, D]
-            with jax.named_scope("norm_residual"):
-                h = rms_norm(x, layer["attn_norm"], eps=cfg.norm_eps)
-            with jax.named_scope("attention"):
-                q = jnp.einsum("bsd,dhk->bshk", h, layer["wq"].astype(dt))
-                k_new = jnp.einsum("bsd,dhk->bshk", h,
-                                   layer["wk"].astype(dt))
-                v_new = jnp.einsum("bsd,dhk->bshk", h,
-                                   layer["wv"].astype(dt))
-                q, k_new = self._qk_norm(q, k_new, layer)
-                q = apply_rope(q, self._angles, pos_q)
-                k_new = apply_rope(k_new, self._angles, pos_q)
-                k_all = jnp.concatenate([kp.astype(dt), k_new], axis=1)
-                v_all = jnp.concatenate([vp.astype(dt), v_new], axis=1)
-                pos_k = jnp.concatenate(
-                    [pos_prefix, pos_q], axis=1)                    # [N,P+Tb]
-                # per-row positions (prefix_len varies by row) — masked
-                # attention inline; padded prefix rows have pos_k=2^30 so
-                # the causal test drops them
-                kk = _repeat_kv(k_all, cfg.n_heads)
-                vv = _repeat_kv(v_all, cfg.n_heads)
-                s = jnp.einsum("bqhd,bkhd->bhqk", q, kk,
-                               preferred_element_type=jnp.float32)
-                s = s * (cfg.head_dim ** -0.5)
-                mask = pos_q[:, None, :, None] >= pos_k[:, None, None, :]
-                s = jnp.where(mask, s, NEG_INF)
-                p = jax.nn.softmax(s, axis=-1)
-                o = jnp.einsum("bhqk,bkhd->bqhd", p.astype(dt), vv)
-                o = jnp.einsum("bshk,hkd->bsd", o, layer["wo"].astype(dt))
-            with jax.named_scope("norm_residual"):
-                x = x + o
-                h = rms_norm(x, layer["mlp_norm"], eps=cfg.norm_eps)
-            down, _ = self._ffn(h, layer)
-            with jax.named_scope("norm_residual"):
-                return x + down, (k_new, v_new)
+
+            def attend(q, k_new, v_new):
+                with jax.named_scope("attention"):
+                    o = reference_attention(
+                        q, jnp.concatenate([kp.astype(k_new.dtype), k_new],
+                                           axis=1),
+                        jnp.concatenate([vp.astype(v_new.dtype), v_new],
+                                        axis=1),
+                        positions_q=pos_q, positions_k=pos_k)
+                return o, (k_new, v_new)
+
+            x, kv, _ = self._layer(x, layer, pos_q, attend)
+            return x, kv
 
         x, (k_out, v_out) = jax.lax.scan(
-            block, x, (params["layers"], prefix_k, prefix_v))
-        with jax.named_scope("logits"):
-            x = rms_norm(x, params["norm_f"], eps=cfg.norm_eps)
-            head = (params["embed"].T if cfg.tie_embeddings
-                    else params["lm_head"])
-            last = jnp.take_along_axis(x, (lengths - 1)[:, None, None],
-                                       axis=1)[:, 0]                # [N, D]
-            logits = jnp.einsum("bd,dv->bv", last, head.astype(dt))
-            return logits.astype(jnp.float32), {"k": k_out, "v": v_out}
+            step, self._embed(params, tokens),
+            (params["layers"], prefix_k, prefix_v))
+        return (self._head(params, x, last=lengths - 1)[:, 0],
+                {"k": k_out, "v": v_out})
 
     def loss(self, params: Params, tokens: jax.Array,
              targets: jax.Array,

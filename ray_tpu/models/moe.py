@@ -4,10 +4,11 @@ native capability).
 
 ``MoEModel`` is ``LlamaModel`` with two methods overridden: ``_ffn`` (a
 router and ``num_experts`` SwiGLU experts, ``expert_top_k`` a token) and
-``_qk_norm`` (OLMoE's RMSNorm over all heads' lanes of q and of k). Every
-path of the parent — training ``apply``/``loss``, ``forward_step``,
+``_qk_norm`` (OLMoE's RMSNorm over all heads' lanes of q and of k). The
+parent's one decoder layer (``LlamaModel._layer``) calls both, so every
+program built on it — training ``apply``/``loss``, ``forward_step``,
 ``decode_step_paged``, ``prefill_with_prefix``, so Serve and the engine —
-runs the expert block through them.
+runs the expert block.
 
 Off an ``ep`` mesh axis the FFN is DROPLESS (``ops/moe_dispatch.
 dropless_expert_ffn``): every chosen expert is computed. Under ``ep`` > 1
@@ -138,7 +139,7 @@ class MoEModel(LlamaModel):
                                          rules=self.rules),
             axes, is_leaf=lambda x: isinstance(x, tuple))
 
-    # -- the block's two overrides ------------------------------------------
+    # -- the layer's two overrides -----------------------------------------
     def _qk_norm(self, q, k, layer: Params):
         cfg: MoEConfig = self.cfg
         if not cfg.qk_norm:

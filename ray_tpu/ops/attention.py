@@ -38,7 +38,13 @@ def reference_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
                         positions_k: Optional[jax.Array] = None,
                         scale: Optional[float] = None) -> jax.Array:
     """Plain softmax attention in f32; XLA fuses this well on TPU for
-    moderate sequence lengths and it is fully differentiable."""
+    moderate sequence lengths and it is fully differentiable.
+
+    A query sees a key iff its position is >= the key's. Positions are
+    one vector for the batch ([T] / [S]) or PER ROW ([B, T] / [B, S]):
+    the serving prefills' masked attention over a slot cache or a
+    gathered prefix, where every row has its own offset (a key to be
+    dropped carries a position past every query's)."""
     head_dim = q.shape[-1]
     scale = scale if scale is not None else head_dim ** -0.5
     k = _repeat_kv(k, q.shape[-2])
@@ -50,8 +56,9 @@ def reference_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
             positions_q = jnp.arange(q.shape[1])
         if positions_k is None:
             positions_k = jnp.arange(k.shape[1])
-        mask = positions_q[:, None] >= positions_k[None, :]
-        s = jnp.where(mask[None, None], s, NEG_INF)
+        mask = positions_q[..., :, None] >= positions_k[..., None, :]
+        # [T, S] or [B, T, S] -> broadcast over heads
+        s = jnp.where(jnp.expand_dims(mask, -3), s, NEG_INF)
     p = jax.nn.softmax(s, axis=-1)
     return jnp.einsum("bhqk,bkhd->bqhd", p.astype(v.dtype), v)
 

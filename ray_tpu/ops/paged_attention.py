@@ -13,6 +13,9 @@ TPU-native design:
   reference (the kernel's oracle), as ``impl`` says; ``default_impl`` is
   the platform's side of that choice (``LlamaModel.paged_decode_impl``
   is the one place that makes it).
+- ``paged_decode_attention_reference`` — gathers a slot's blocks into a
+  dense view and runs ``ragged_decode_attention_reference`` (one query
+  token against a length-masked dense cache) over it.
 - ``paged_decode_attention_pallas`` — flash-style online softmax, grid
   (batch,). The pools stay in HBM; the block table and the lengths ride
   scalar prefetch, and each slot loops over ITS OWN live chunks of
@@ -51,10 +54,27 @@ import jax
 import jax.numpy as jnp
 
 from ray_tpu._private.platform import on_chip, pallas_interpret
-from ray_tpu.ops.attention import NEG_INF
-from ray_tpu.ops.decode_attention import ragged_decode_attention_reference
+from ray_tpu.ops.attention import NEG_INF, _repeat_kv
 
 logger = logging.getLogger(__name__)
+
+
+def ragged_decode_attention_reference(q, k, v, lengths, *,
+                                      scale: Optional[float] = None):
+    """One query token against a dense, length-bounded cache, masked
+    past each row's length: q [B, H, D] x k/v [B, S, Hkv, D], lengths
+    [B] -> [B, H, D]. The arithmetic of the paged reference below, and
+    the tests' oracle."""
+    head_dim = q.shape[-1]
+    scale = scale if scale is not None else head_dim ** -0.5
+    k = _repeat_kv(k, q.shape[1])
+    v = _repeat_kv(v, q.shape[1])
+    s = jnp.einsum("bhd,bshd->bhs", q, k,
+                   preferred_element_type=jnp.float32) * scale
+    mask = jnp.arange(k.shape[1])[None, :] < lengths[:, None]   # [B,S]
+    s = jnp.where(mask[:, None, :], s, NEG_INF)
+    p = jax.nn.softmax(s, axis=-1)
+    return jnp.einsum("bhs,bshd->bhd", p.astype(v.dtype), v)
 
 
 def paged_decode_attention_reference(q, k_pool, v_pool, block_tables,

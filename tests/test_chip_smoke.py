@@ -351,15 +351,3 @@ def test_flash_forward_kernel_lowers_for_v5e(v5e, smoke_sizes):
         assert _mosaic(_flash_forward.lower(
             v5e(1, S, H, D), kv, kv, causal=True, block_q=128, block_k=128,
             interpret=False))
-
-
-@pytest.mark.xfail(strict=True, reason=(
-    "the slot-major decode kernel asks Mosaic for a dot with a batch dim "
-    "and no free lhs dim; it is on no engine path (ROADMAP D5) — when this "
-    "starts passing, the kernel was fixed or deleted: drop the mark"))
-def test_slot_major_decode_kernel_lowers_for_v5e(v5e):
-    from ray_tpu.ops.decode_attention import ragged_decode_attention_pallas
-
-    kv = v5e(8, 1024, 8, 64)
-    assert _mosaic(ragged_decode_attention_pallas.lower(
-        v5e(8, 32, 64), kv, kv, v5e(8, dtype=jnp.int32), interpret=False))

@@ -394,8 +394,7 @@ def test_dispatcher_runs_the_side_it_is_told():
 def test_decode_program_choice_by_configuration(tiny_model):
     """No ``decode_attention`` set: the platform decides for
     ``decode_step_paged`` (the reference on this CPU backend); a set one
-    forces its side; ``forward_step`` at T == 1 never follows the
-    default into the slot-major kernel."""
+    forces its side; ``forward_step`` holds no kernel."""
     import dataclasses
     model, params = tiny_model
     assert LlamaConfig().decode_attention is None
@@ -455,9 +454,8 @@ def test_engine_counts_the_blocks_decode_reads(tiny_model):
 def test_paged_reference_gather_equals_dense():
     """The paged XLA fallback must equal ragged attention on the dense
     equivalent of the same block layout."""
-    from ray_tpu.ops.decode_attention import \
-        ragged_decode_attention_reference
-    from ray_tpu.ops.paged_attention import paged_decode_attention_reference
+    from ray_tpu.ops.paged_attention import (
+        paged_decode_attention_reference, ragged_decode_attention_reference)
     rng = np.random.default_rng(1)
     B, H, Hkv, D, bs, NB, maxb = 2, 4, 2, 16, 8, 16, 4
     q = jnp.asarray(rng.normal(size=(B, H, D)), jnp.float32)
@@ -669,6 +667,90 @@ def test_paged_decode_keeps_each_layers_blocks_apart(kind, impl):
     worst, per_layer = paged_against_dense(model, params)
     assert worst < 1e-4, worst
     assert max(per_layer) < 1e-5, per_layer
+
+
+@pytest.mark.parametrize("program", ["apply", "forward_step",
+                                     "decode_step_paged",
+                                     "prefill_with_prefix"])
+def test_one_override_of_the_layer_reaches_every_program(program):
+    """The decoder layer is written ONCE (``LlamaModel._layer``); what a
+    program supplies is how K/V are kept. So a subclass that changes the
+    layer in one place (here: the attention output, louder) changes the
+    training forward, the bucket prefill and its T == 1 steps, the paged
+    decode step and the prefix prefill alike: each agrees with ``apply``
+    of the same subclass on the same tokens, in float32, and none with
+    the plain model's."""
+    import dataclasses
+    I32 = jnp.int32
+
+    class Louder(LlamaModel):
+        def _layer(self, x, layer, positions, attend, **kw):
+            def louder(q, k, v):
+                o, kv = attend(q, k, v)
+                return 1.5 * o, kv
+            return super()._layer(x, layer, positions, louder, **kw)
+
+    cfg = dataclasses.replace(LlamaConfig.debug(vocab_size=512),
+                              dtype=jnp.float32)
+    model, plain = Louder(cfg), LlamaModel(cfg)
+    params = model.init(jax.random.key(5))
+    B, prompt, total, bs = 2, 11, 16, 8
+    toks = jnp.asarray(np.random.default_rng(5).integers(
+        1, cfg.vocab_size, (B, total)), I32)
+    want = model.apply(params, toks)                         # [B, total, V]
+    assert float(jnp.max(jnp.abs(want - plain.apply(params, toks)))) > 0.1
+
+    def prefilled():
+        """(logits, cache) of the first ``prompt`` tokens, by the bucket
+        prefill into a cache of ``total`` rows."""
+        padded = jnp.zeros((B, total), I32).at[:, :prompt].set(
+            toks[:, :prompt])
+        return model.forward_step(params, padded,
+                                  model.init_kv_cache(B, total),
+                                  jnp.zeros((B,), I32))
+
+    if program == "apply":      # under remat, and at explicit positions
+        got = Louder(dataclasses.replace(cfg, remat=True)).apply(
+            params, toks, jnp.arange(total))
+    elif program == "forward_step":
+        logits, cache = prefilled()
+        got = [logits[:, :prompt]]
+        for pos in range(prompt, total):
+            step, cache = model.forward_step(
+                params, toks[:, pos:pos + 1], cache, jnp.full((B,), pos, I32))
+            got.append(step)
+        got = jnp.concatenate(got, axis=1)
+    elif program == "decode_step_paged":
+        _, cache = prefilled()
+        maxb = total // bs
+        tables = jnp.asarray([[3, 0], [1, 4]], I32)      # 5 = scratch
+        pool = model.init_kv_pool(6, bs)
+        pool = {n: pool[n].at[:, tables.reshape(-1)].set(
+            cache[n].reshape(cfg.n_layers, B * maxb, bs,
+                             *cache[n].shape[3:])) for n in ("k", "v")}
+        got = []
+        for pos in range(prompt, total):
+            step, pool = model.decode_step_paged(
+                params, toks[:, pos], pool, tables, jnp.full((B,), pos, I32))
+            got.append(step[:, None])
+        got, want = jnp.concatenate(got, axis=1), want[:, prompt:]
+    else:
+        # rows of the prefix past ``prefix_len`` hold the prompt's own
+        # later K/V: they must be masked, not merely zero
+        _, cache = prefilled()
+        prefix_len = jnp.asarray([8, 5], I32)
+        lengths = jnp.asarray([8, 11], I32)              # to ``total``
+        Tb = 12
+        cols = prefix_len[:, None] + jnp.arange(Tb)[None, :]
+        suffix = jnp.take_along_axis(
+            jnp.pad(toks, ((0, 0), (0, Tb))), cols, axis=1)
+        got, kv = model.prefill_with_prefix(
+            params, suffix, cache["k"][:, :, :prompt],
+            cache["v"][:, :, :prompt], prefix_len, lengths)
+        assert kv["k"].shape == (cfg.n_layers, B, Tb, cfg.n_kv_heads,
+                                 cfg.head_dim)
+        want = want[:, -1]
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want), atol=2e-4)
 
 
 @pytest.mark.parametrize("impl", ["xla", "pallas"])

@@ -270,40 +270,44 @@ def test_moe_alltoall_matches_einsum_dispatch():
 
 
 # ---------------------------------------------------------------------------
-# Ragged decode attention (paged-attention role for KV-cache serving)
+# Per-row positions: the serving prefills' masked attention (a slot
+# cache, a gathered prefix), and one query token against a ragged cache
 # ---------------------------------------------------------------------------
 
-def test_ragged_decode_attention_matches_reference():
-    from ray_tpu.ops.decode_attention import (
-        ragged_decode_attention_pallas, ragged_decode_attention_reference)
+@pytest.mark.parametrize("case", ["contiguous", "row_offsets",
+                                  "one_query_token"])
+def test_reference_attention_with_per_row_positions(case):
+    from ray_tpu.ops.paged_attention import ragged_decode_attention_reference
 
     rng = np.random.default_rng(7)
-    B, S, H, Hkv, D = 4, 256, 8, 2, 32
-    q = jnp.asarray(rng.normal(size=(B, H, D)), jnp.float32)
+    B, S, H, Hkv, D = 3, 24, 4, 2, 16
+    T = 1 if case == "one_query_token" else 8
+    q = jnp.asarray(rng.normal(size=(B, T, H, D)), jnp.float32)
     k = jnp.asarray(rng.normal(size=(B, S, Hkv, D)), jnp.float32)
     v = jnp.asarray(rng.normal(size=(B, S, Hkv, D)), jnp.float32)
-    lengths = jnp.asarray([1, 100, 200, 256], jnp.int32)
-    ref = ragged_decode_attention_reference(q, k, v, lengths)
-    out = ragged_decode_attention_pallas(q, k, v, lengths, block_k=64,
-                                         interpret=True)
-    np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
-                               rtol=2e-5, atol=2e-6)
-
-
-def test_ragged_decode_attention_unpadded_lengths():
-    from ray_tpu.ops.decode_attention import (
-        ragged_decode_attention_pallas, ragged_decode_attention_reference)
-
-    rng = np.random.default_rng(8)
-    B, S, H, D = 2, 96, 4, 16   # S not a multiple of block_k
-    q = jnp.asarray(rng.normal(size=(B, H, D)), jnp.float32)
-    k = jnp.asarray(rng.normal(size=(B, S, H, D)), jnp.float32)
-    v = jnp.asarray(rng.normal(size=(B, S, H, D)), jnp.float32)
-    lengths = jnp.asarray([37, 96], jnp.int32)
-    ref = ragged_decode_attention_reference(q, k, v, lengths)
-    out = ragged_decode_attention_pallas(q, k, v, lengths, block_k=64,
-                                         interpret=True)
-    np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
+    if case == "contiguous":
+        # every row at 0..T-1 against its own first T keys: no positions
+        k, v = k[:, :T], v[:, :T]
+        pos_q = pos_k = jnp.broadcast_to(jnp.arange(T), (B, T))
+        want = reference_attention(q, k, v)
+    elif case == "row_offsets":
+        # each row sits at its own offset into the keys, and drops its
+        # own tail of them by a position past every query
+        pos_q = jnp.asarray([0, 5, 16])[:, None] + jnp.arange(T)[None, :]
+        pos_k = jnp.where(jnp.arange(S)[None, :]
+                          < jnp.asarray([8, 13, 24])[:, None],
+                          jnp.arange(S)[None, :], 2 ** 30)
+        want = jnp.concatenate([
+            reference_attention(q[b:b + 1], k[b:b + 1], v[b:b + 1],
+                                positions_q=pos_q[b], positions_k=pos_k[b])
+            for b in range(B)])
+    else:
+        lengths = jnp.asarray([1, 13, 24], jnp.int32)
+        pos_q, pos_k = (lengths - 1)[:, None], jnp.arange(S)
+        want = ragged_decode_attention_reference(q[:, 0], k, v,
+                                                 lengths)[:, None]
+    out = reference_attention(q, k, v, positions_q=pos_q, positions_k=pos_k)
+    np.testing.assert_allclose(np.asarray(out), np.asarray(want),
                                rtol=2e-5, atol=2e-6)
 
 
