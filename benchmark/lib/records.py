@@ -27,7 +27,8 @@ class RequestRecord:
     max_tokens: int = 0
     sent_at: Optional[float] = None
     first_token_at: Optional[float] = None
-    finished_at: Optional[float] = None
+    finished_at: Optional[float] = None     # the last token's arrival
+    done_at: Optional[float] = None         # the finish chunk's arrival
     output_tokens: int = 0
     engine_ttft_s: Optional[float] = None   # engine: first token - submit
     finish_reason: Optional[str] = None

@@ -19,36 +19,6 @@ import numpy as np
 from benchmark.lib.records import RequestRecord
 
 CHUNK_TIMEOUT_S = 600.0       # a first call may compile
-SERVERS: list = []            # the in-process replicas' servers
-
-
-class IdTokenizer:
-    """Token ids in, token ids out: the benchmark sends prompts as ids
-    and reads ``token_id`` from each chunk. Not a ``ByteTokenizer``, so
-    ``LLMServer._parse`` sets NO stop token: with random weights a stop
-    id (the byte tokenizer's 257) would end streams at random, and a
-    window would not hold the same work in every run."""
-
-    def encode(self, text, add_bos: bool = True):
-        raise TypeError("the benchmark sends token ids, not text")
-
-    def decode(self, ids) -> str:
-        return ""
-
-
-def make_server_class():
-    from ray_tpu.llm.serving import LLMServer
-
-    class BenchLLMServer(LLMServer):
-        """``LLMServer`` with the benchmark's tokenizer; registers itself
-        so the correctness check can reach the model and its weights."""
-
-        def __init__(self, config):
-            super().__init__(config)
-            self.tokenizer = IdTokenizer()
-            SERVERS.append(self)
-
-    return BenchLLMServer
 
 
 def start(model_config, *, model_id: str, engine: Dict, seed: int):
@@ -56,15 +26,17 @@ def start(model_config, *, model_id: str, engine: Dict, seed: int):
     from ray_tpu import serve
     from ray_tpu.llm.serving import LLMConfig
 
+    from benchmark.lib.bench_server import BenchLLMServer
+
     config = LLMConfig(
         model_id=model_id, model_config=model_config,
         max_slots=engine["max_slots"], max_seq=engine["max_seq"],
         block_size=engine.get("block_size"), num_replicas=1,
         max_ongoing_requests=engine.get("max_ongoing_requests", 256),
         seed=seed)
-    # build_llm_app's four lines, with the subclass above
+    # build_llm_app's four lines, with the benchmark's subclass
     dep = serve.deployment(
-        make_server_class(), name=model_id, num_replicas=1,
+        BenchLLMServer, name=model_id, num_replicas=1,
         max_ongoing_requests=config.max_ongoing_requests)
     return serve.run(dep.bind(config))
 
@@ -73,6 +45,8 @@ def deploy_and_check(run):
     """Runtime up, one replica deployed with weights from the seed, the
     paged path checked against the reference: ``(handle, checks)``."""
     import ray_tpu
+
+    from benchmark.lib.bench_server import SERVERS
 
     tr, cfg = run.traffic, run.config
     eng = tr["engine"]
@@ -143,6 +117,7 @@ def stream_request(handle, prompt: List[int], max_tokens: int,
                 if on_token is not None:
                     on_token(len(toks))
             elif chunk.get("done"):
+                rec.done_at = now
                 rec.finish_reason = chunk.get("finish_reason")
                 rec.engine_ttft_s = chunk.get("ttft_s")
         if rec.finish_reason is None:

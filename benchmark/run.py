@@ -89,6 +89,7 @@ class Run:
         self._last = T_START
         self.t_open = None
         self._trace_thread = None
+        self.trace_edges: list = []
 
     def log(self, msg: str) -> None:
         print(f"[bench {time.perf_counter() - T_START:7.1f}s] {msg}",
@@ -105,9 +106,14 @@ class Run:
         self.phase("to_window_open")
         return self.t_open
 
-    def trace_during(self, t_open: float, seconds: float) -> None:
+    def trace_during(self, t_open: float, seconds: float,
+                     snapshot=None) -> None:
         """Trace ``seconds`` of the window from a timer thread (serve
-        drivers; the trainer starts and stops its own at step ends)."""
+        drivers; the trainer starts and stops its own at step ends).
+        ``snapshot()`` is called at both edges INSIDE the traced span,
+        after the tracer has started and before it stops, and what it
+        returns is kept in ``trace_edges``: a metric that divides work
+        by traced time counts the work of the same span."""
         if self.tracer is None:
             return
 
@@ -115,8 +121,14 @@ class Run:
             time.sleep(max(0.0, t_open + min(2.0, self.seconds / 4)
                            - time.perf_counter()))
             self.tracer.start()
-            time.sleep(min(seconds, self.seconds / 2))
-            self.tracer.stop()
+            try:
+                if snapshot is not None:
+                    self.trace_edges.append(snapshot())
+                time.sleep(min(seconds, self.seconds / 2))
+                if snapshot is not None:
+                    self.trace_edges.append(snapshot())
+            finally:
+                self.tracer.stop()
 
         self._trace_thread = threading.Thread(target=work, daemon=True,
                                               name="bench-tracer")
@@ -258,12 +270,19 @@ def main(argv=None) -> int:
     line["compile_cache"] = run.compiles.snapshot()
     line["counts"] = {k: record[k] for k in (
         "compiles_in_window", "tokens_received_in_window",
-        "first_tokens_in_window", "backlog_at_close", "warm",
+        "first_tokens_in_window", "requests_ended_in_window",
+        "stalled_at_close",
+        "decode_steps_per_s", "cap_steps_per_s", "token_gap_ms",
+        "backlog_at_close", "warm",
         "offered_rate_per_s", "client_ms") if k in record}
-    for k in ("engine_before", "engine_after"):
+    for k in ("engine_before", "engine_after", "engine_trace_edges"):
         if k in record:
             line["counts"][k] = record[k]
     reap_children(run.log)
+    # each number compared beside its limit, as the last line of stderr
+    run.log(f"correct={line['correct']} failed={line['failed']} of "
+            f"{line['attempted']} (limit 0); checks: "
+            + json.dumps(record["checks"]))
     faulthandler.cancel_dump_traceback_later()
     sys.stderr.flush()
     print(json.dumps(line), flush=True)

@@ -64,12 +64,12 @@ def test_decode_step_bytes_by_hand():
 
 
 def test_builder_maps_the_published_keys():
-    cfg = builder.program_config(CFG, 3072)
+    cfg = builder.program_config(CFG, 4096)
     assert (cfg.dim, cfg.n_heads, cfg.n_kv_heads, cfg.head_dim) == (
         2048, 16, 16, 128)
     assert (cfg.num_experts, cfg.expert_top_k, cfg.ffn_dim) == (64, 8, 1024)
     assert cfg.norm_topk_prob is False and cfg.qk_norm is True
-    assert cfg.rope_theta == 10000.0 and cfg.max_seq_len == 3072
+    assert cfg.rope_theta == 10000.0 and cfg.max_seq_len == 4096
     assert cfg.dtype == jnp.bfloat16
     assert builder.program_config(TINY, 64).dtype == jnp.float32
     with pytest.raises(ValueError, match="clip_qkv"):
