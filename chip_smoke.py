@@ -210,7 +210,7 @@ def sizes() -> dict:
             n_kv_heads=16, ffn_dim=1024, num_experts=16, expert_top_k=4),
         # the serve cells' attention (8 KV heads of 128) over narrow
         # layers: 0.5 GiB of pool at POOL_SLOTS x MAX_SEQ against 0.14
-        # GiB of bf16 weight copies, the temporaries that remain
+        # GiB of bf16 weights, of which the temporaries hold no copy
         pool_cfg=LlamaConfig(
             vocab_size=4096, dim=1024, n_layers=4, n_heads=8, n_kv_heads=8,
             ffn_dim=4096, max_seq_len=MAX_SEQ, remat=False),
@@ -317,9 +317,12 @@ def pool_phase(rep: Report, sz: dict) -> None:
     Scanned over as the layer scan's ``xs``/``ys`` the pool was copied
     whole around the loop and sliced out and back a layer: 1.3 pools of
     temporaries, half of a decode step on the chip (PERF.md, PR 27).
-    Carried whole it is written in place, and what is left are the
-    weights' bf16 copies. The verdict is the chip's: a CPU backend
-    widens a bf16 scatter to float32 and back, two float32 stacks."""
+    Carried whole it is written in place. What was left then were the
+    weights' bf16 copies, cast from float32 by every step; the engine
+    now holds its matmul weights in bf16 (``serving_params``), so the
+    temporaries hold no copy of any weight (PERF.md, PR 31). The verdict
+    is the chip's: a CPU backend widens a bf16 scatter to float32 and
+    back, two float32 stacks."""
     import jax
 
     from ray_tpu._private.platform import on_chip
@@ -335,12 +338,18 @@ def pool_phase(rep: Report, sz: dict) -> None:
         eng._decode, eng.params, i32(POOL_SLOTS), eng.kv,
         i32(POOL_SLOTS, eng.blocks_per_slot), i32(POOL_SLOTS))
     pool_bytes = sum(a.nbytes for a in jax.tree.leaves(eng.kv))
-    detail = (f"temporaries {facts['temp_bytes'] / 2**20:.1f} MiB, pool "
+    smallest = min(eng.params["layers"][k].nbytes
+                   for k in model.MATMUL_LAYER_LEAVES)
+    detail = (f"temporaries {facts['temp_bytes'] / 2**20:.2f} MiB, pool "
               f"{pool_bytes / 2**20:.1f} MiB {tuple(eng.kv['k'].shape)} x 2, "
+              f"smallest matmul weight {smallest / 2**20:.1f} MiB of "
+              f"{eng.stats['param_bytes'] / 2**20:.1f} MiB held, "
               f"decode attention {eng.decode_attention_impl!r}")
     if on_chip():
         rep.check("decode program's temporaries are under one pool's bytes",
                   facts["temp_bytes"] < pool_bytes, detail)
+        rep.check("decode program's temporaries hold no copy of a weight",
+                  facts["temp_bytes"] < smallest, detail)
     else:
         rep.info(f"decode program, not judged off the chip: {detail}")
 

@@ -28,6 +28,10 @@ is the in-tree TPU-native equivalent (BASELINE.md config 5):
   layer (block ``p`` of layer ``l`` is block ``l*NB + p``), writes each
   slot's new row a layer into it and hands it back aliased — nothing
   pool-sized is copied or sliced (docs/serving.md);
+- the parameters are held in the dtype the programs compute in: the
+  matmul weights cast once at construction (``model.serving_params``),
+  not by every program that reads them; norms and an expert model's
+  router stay float32 (docs/serving.md);
 - per-request TTFT / throughput stats (the reference's
   `release/llm_tests/serve/benchmark/load_test.py` metrics);
 - the loop accounts for itself: every part of ``step()`` runs inside one
@@ -166,7 +170,9 @@ class ContinuousBatchingEngine:
                  block_size: Optional[int] = None,
                  num_blocks: Optional[int] = None):
         self.model = model
-        self.params = params
+        # matmul weights cast to the compute dtype once, here; float32
+        # arrays the caller passed are the caller's to drop
+        self.params = model.serving_params(params)
         self.max_slots = max_slots
         self.max_seq = max_seq
         self.buckets = tuple(b for b in sorted(prefill_buckets)
@@ -293,7 +299,10 @@ class ContinuousBatchingEngine:
                       # (live slots x top-k x layers, counted on the
                       # host), and the rows per [layer][expert]
                       "moe_assignments": 0, "moe_assignments_expected": 0,
-                      "moe_expert_load": []}
+                      "moe_expert_load": [],
+                      # bytes of the parameters as the engine holds them
+                      "param_bytes": sum(
+                          a.nbytes for a in jax.tree.leaves(self.params))}
         self._cpu_waiting = 0.0     # CPU seconds of this step's waiting phases
 
     @property

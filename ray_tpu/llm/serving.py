@@ -53,8 +53,11 @@ class LLMServer:
             vocab_size=512, max_seq_len=config.max_seq)
         self.model = model_for(cfg)
         # one program, not one per tensor: eager init compiles ~30 small
-        # programs and holds each tensor twice (normal, then scaled)
-        params = jax.jit(self.model.init)(jax.random.key(config.seed))
+        # programs and holds each tensor twice (normal, then scaled).
+        # Drawn AND cast to what the engine stores in that one program,
+        # so the float32 set never stands whole beside the bf16 one
+        params = jax.jit(lambda key: self.model.serving_params(
+            self.model.init(key)))(jax.random.key(config.seed))
         self.tokenizer = (load_tokenizer(config.tokenizer)
                           if config.tokenizer else ByteTokenizer())
         self.engine = ContinuousBatchingEngine(

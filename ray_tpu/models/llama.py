@@ -17,7 +17,12 @@ it is in-tree and TPU-first:
   mesh (dp/fsdp/pp/tp/sp/ep) by ``ray_tpu.parallel.mesh`` rules —
   Megatron-style TP, ZeRO-style fsdp sharding, ring-attention SP all come
   from the same annotations;
-- compute dtype bfloat16 (MXU-native), params/optimizer f32;
+- compute dtype bfloat16 (MXU-native). ``init`` and training keep
+  params/optimizer f32 and the layer casts each matmul weight at its use;
+  the serving engine stores those weights already cast
+  (``serving_params``: bf16 ``embed``/``lm_head``/``wq``/``wk``/``wv``/
+  ``wo`` and FFN stacks, f32 norms), so its programs read them as they
+  are and the same casts lower to nothing;
 - ``remat`` on each layer trades FLOPs for HBM (the standard TPU recipe).
 """
 
@@ -198,6 +203,35 @@ class LlamaModel:
         if not cfg.tie_embeddings:
             params["lm_head"] = dense(next(k), (d, cfg.vocab_size), d)
         return params
+
+    # -- the serving path's storage dtype -----------------------------------
+    # The leaves of ``params["layers"]`` the layer body casts to the
+    # compute dtype at each use (``embed`` and ``lm_head`` beside them);
+    # ``MoEModel`` names its expert stacks instead of the dense FFN's.
+    MATMUL_LAYER_LEAVES: Tuple[str, ...] = (
+        "wq", "wk", "wv", "wo", "w_gate", "w_up", "w_down")
+
+    def serving_params(self, params: Params) -> Params:
+        """``params`` as a serving engine stores them: every weight the
+        layer body ``.astype(cfg.dtype)``s is held in ``cfg.dtype``, cast
+        ONCE here instead of in every program that reads it (in float32
+        those casts were three quarters of a decode step: PERF.md, PR 31).
+        The values the matmuls see are bit for bit the same. Everything
+        the body uses in float32 stays float32: the norms' scales (and
+        ``MoEModel``'s QK-norm scales and router). A leaf already in the
+        compute dtype is handed back as it is, so this is idempotent.
+        Training keeps float32 master weights and never calls this."""
+        dt = self.cfg.dtype
+
+        def cast(a):
+            return a if a.dtype == dt else a.astype(dt)
+
+        out = {k: cast(v) if k in ("embed", "lm_head") else v
+               for k, v in params.items()}
+        out["layers"] = {
+            k: cast(v) if k in self.MATMUL_LAYER_LEAVES else v
+            for k, v in params["layers"].items()}
+        return out
 
     # -- sharding helpers ---------------------------------------------------
     def _constrain(self, x, *names):

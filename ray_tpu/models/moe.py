@@ -103,6 +103,12 @@ def moe_param_logical_axes(cfg: MoEConfig) -> Params:
 class MoEModel(LlamaModel):
     """Llama block with an expert FFN (and, if configured, QK-norm)."""
 
+    # the expert stacks for the dense FFN's three; the ROUTER is not
+    # among them: its logits are computed in float32 (``route_topk``) and
+    # a bf16 router would choose other experts
+    MATMUL_LAYER_LEAVES = ("wq", "wk", "wv", "wo",
+                           "e_gate", "e_up", "e_down")
+
     def __init__(self, cfg: MoEConfig, mesh=None,
                  rules: Optional[Dict] = None):
         super().__init__(cfg, mesh=mesh, rules=rules)

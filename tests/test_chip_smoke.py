@@ -123,6 +123,12 @@ def _mosaic(lowered) -> bool:
     return "tpu_custom_call" in lowered.compile().as_text()
 
 
+def _engine_params(model):
+    """Shapes and dtypes of the parameters as an engine holds them."""
+    return jax.eval_shape(lambda key: model.serving_params(model.init(key)),
+                          jax.random.key(0))
+
+
 def test_paged_decode_kernel_lowers_for_v5e(v5e, smoke_sizes):
     from ray_tpu.ops.paged_attention import paged_decode_attention_pallas
 
@@ -163,16 +169,19 @@ def test_paged_decode_kernel_refuses_widths_it_cannot_copy(v5e):
             v5e(8, dtype=jnp.int32), interpret=False)
 
 
-# the serve cells' decode shapes (BENCHMARK.json: mistral-7b-v0.3-d6 at 32
-# slots, block_size 32): table entries a slot in batch_decode, chat_mixed
-CELL_SLOTS, CELL_BS, CELL_TABLES = 32, 32, (96, 64)
+# the serve cells' decode shapes (BENCHMARK.json, 32 slots, block_size 32):
+# table entries a slot in batch_decode (mistral-7b-v0.3-d6 at max_seq 8192),
+# batch_decode_moe (olmoe-1b-7b-d3 at 4096) and chat_mixed (mistral at 2048),
+# and each cell's query and KV heads
+CELL_SLOTS, CELL_BS, CELL_TABLES = 32, 32, (256, 128, 64)
+CELL_HEADS = {256: (32, 8), 128: (16, 16), 64: (32, 8)}
 
 
 @pytest.mark.parametrize("maxb", CELL_TABLES)
 def test_paged_decode_kernel_lowers_at_the_cells_shapes(v5e, maxb):
     from ray_tpu.ops.paged_attention import paged_decode_attention_pallas
 
-    B, H, Hkv, D, bs = CELL_SLOTS, 32, 8, 128, CELL_BS
+    B, (H, Hkv), D, bs = CELL_SLOTS, CELL_HEADS[maxb], 128, CELL_BS
     pool = v5e(B * maxb + 1, bs, Hkv, D)
     assert _mosaic(paged_decode_attention_pallas.lower(
         v5e(B, H, D), pool, pool, v5e(B, maxb, dtype=jnp.int32),
@@ -192,10 +201,13 @@ def _as_on_the_chip(monkeypatch):
 @pytest.mark.parametrize("maxb", CELL_TABLES)
 def test_decode_program_holds_the_kernel_on_a_tpu_backend(
         v5e, placed, monkeypatch, maxb):
-    """A whole ``decode_step_paged`` at the cells' widths (depth 1), no
-    ``decode_attention`` set. This process's backend is the CPU, where
-    the dispatcher rightly takes the reference, so the test tells the
-    dispatcher what it would see on the chip; the compile is the v5e's."""
+    """A whole ``decode_step_paged`` at the Mistral cells' widths (depth
+    1; at 128 entries too, the expert cell's table, whose own program is
+    ``test_olmoe_cell_decode_program_fits_the_v5e``), on the parameters
+    as an engine holds them, no ``decode_attention`` set. This process's
+    backend is the CPU, where the dispatcher rightly takes the reference,
+    so the test tells the dispatcher what it would see on the chip; the
+    compile is the v5e's."""
     from ray_tpu.models.llama import LlamaConfig, LlamaModel
 
     _as_on_the_chip(monkeypatch)
@@ -206,7 +218,7 @@ def test_decode_program_holds_the_kernel_on_a_tpu_backend(
     assert model.cfg.decode_attention is None
     assert model.paged_decode_impl() == "pallas"
 
-    args = (placed(jax.eval_shape(model.init, jax.random.key(0))),
+    args = (placed(_engine_params(model)),
             v5e(B, dtype=jnp.int32),
             placed(jax.eval_shape(
                 lambda: model.init_kv_pool(B * maxb + 1, bs))),
@@ -233,23 +245,25 @@ def test_llama3_1b_decode_program_holds_the_kernel(v5e, placed, monkeypatch,
     B, bs, maxb = 8, 32, cfg.max_seq_len // 32
 
     assert _mosaic(jax.jit(model.decode_step_paged, donate_argnums=(2,)).lower(
-        placed(jax.eval_shape(model.init, jax.random.key(0))),
-        v5e(B, dtype=jnp.int32),
+        placed(_engine_params(model)), v5e(B, dtype=jnp.int32),
         placed(jax.eval_shape(lambda: model.init_kv_pool(B * maxb + 1, bs))),
         v5e(B, maxb, dtype=jnp.int32), v5e(B, dtype=jnp.int32)))
 
 
 def test_olmoe_cell_decode_program_fits_the_v5e(v5e, placed, monkeypatch):
     """``olmoe-1b-7b-d3.batch_decode_moe``'s decode program as the engine
-    jits it (the counted step: 32 slots x 3072, depth 3, all 64 experts,
-    weights in float32): the v5e's compiler takes it (10.81 GiB of 15.75
-    since the pool is no scanned operand; 15.31 before, with depth 4
-    refused), with the paged kernel and the grouped matmuls as Mosaic
-    calls."""
+    jits it (the counted step: 32 slots x 4096, depth 3, all 64 experts,
+    matmul weights in bf16 as the engine holds them): the v5e's compiler
+    takes it (6.49 GiB of 15.75; 11.56 on float32 weights, whose bf16
+    copies were 3.10 GiB of temporaries), with the paged kernel and the
+    grouped matmuls as Mosaic calls. The temporaries that remain (0.75
+    GiB) are ONE layer's three expert stacks, sliced out of the stacked
+    weights as operands of the grouped-matmul calls: under the bf16
+    weights' bytes, where a cast of every weight would equal them."""
     from ray_tpu.models import MoEConfig, model_for
 
     _as_on_the_chip(monkeypatch)
-    B, bs, maxb, L, E = CELL_SLOTS, CELL_BS, 96, 3, 64
+    B, bs, maxb, L, E = CELL_SLOTS, CELL_BS, 128, 3, 64
     model = model_for(MoEConfig(
         vocab_size=50304, dim=2048, n_layers=L, n_heads=16, n_kv_heads=16,
         ffn_dim=1024, max_seq_len=maxb * bs, rope_theta=1e4, num_experts=E,
@@ -262,9 +276,9 @@ def test_olmoe_cell_decode_program_fits_the_v5e(v5e, placed, monkeypatch):
             params, tokens, pool, tables, offsets, tables[:, 0] != B * maxb)
         return logits, pool, load + extras["load"]
 
+    params = placed(_engine_params(model))
     compiled = jax.jit(step, donate_argnums=(2,)).lower(
-        placed(jax.eval_shape(model.init, jax.random.key(0))),
-        v5e(B, dtype=jnp.int32),
+        params, v5e(B, dtype=jnp.int32),
         placed(jax.eval_shape(lambda: model.init_kv_pool(B * maxb + 1, bs))),
         v5e(B, maxb, dtype=jnp.int32), v5e(B, dtype=jnp.int32),
         v5e(L, E, dtype=jnp.int32)).compile()
@@ -274,15 +288,20 @@ def test_olmoe_cell_decode_program_fits_the_v5e(v5e, placed, monkeypatch):
     total = (mem.argument_size_in_bytes + mem.temp_size_in_bytes
              + mem.output_size_in_bytes - mem.alias_size_in_bytes)
     assert total < 15.75 * 2**30
+    experts = sum(params["layers"][k].size * 2
+                  for k in ("e_gate", "e_up", "e_down"))
+    assert mem.temp_size_in_bytes < experts / L * 1.05
 
 
 def test_smokes_decode_program_keeps_its_pool_in_place_on_the_v5e(
         v5e, placed, monkeypatch, smoke_sizes):
     """chip_smoke.py's pool phase, compiled here for the described v5e:
-    the decode program's temporaries are under ONE pool's bytes (what is
-    left are the bf16 copies of the weights). With the pool scanned over
-    by the layer scan they held a whole copy of it and a layer's slice
-    twice more (5.44 GiB for 2.44 at ``batch_decode``'s shape)."""
+    the decode program's temporaries are under ONE pool's bytes, and
+    under its smallest matmul weight's: no copy of a weight is among
+    them, since the engine holds those in bf16. With the pool scanned
+    over by the layer scan they held a whole copy of it and a layer's
+    slice twice more (5.44 GiB for 2.44 at ``batch_decode``'s shape); on
+    float32 weights, a bf16 copy of every one (those 2.44 GiB)."""
     from ray_tpu.models import model_for
 
     _as_on_the_chip(monkeypatch)
@@ -292,12 +311,20 @@ def test_smokes_decode_program_keeps_its_pool_in_place_on_the_v5e(
     maxb = model.cfg.max_seq_len // bs
 
     pool = placed(jax.eval_shape(lambda: model.init_kv_pool(B * maxb + 1, bs)))
-    compiled = jax.jit(model.decode_step_paged, donate_argnums=(2,)).lower(
-        placed(jax.eval_shape(model.init, jax.random.key(0))),
-        v5e(B, dtype=jnp.int32), pool, v5e(B, maxb, dtype=jnp.int32),
-        v5e(B, dtype=jnp.int32)).compile()
+    def temporaries(params):
+        return jax.jit(model.decode_step_paged, donate_argnums=(2,)).lower(
+            placed(params), v5e(B, dtype=jnp.int32), pool,
+            v5e(B, maxb, dtype=jnp.int32), v5e(B, dtype=jnp.int32)
+        ).compile().memory_analysis().temp_size_in_bytes
+
+    held = _engine_params(model)
     pool_bytes = sum(a.size * a.dtype.itemsize for a in jax.tree.leaves(pool))
-    assert compiled.memory_analysis().temp_size_in_bytes < pool_bytes
+    smallest = min(held["layers"][k].size * 2
+                   for k in model.MATMUL_LAYER_LEAVES)
+    assert temporaries(held) < min(pool_bytes, smallest)
+    # the witness can see one: float32 weights are cast by the program
+    assert temporaries(jax.eval_shape(model.init, jax.random.key(0))) \
+        > smallest
 
 
 def test_decode_under_a_mesh_keeps_the_reference(v5e_topo, monkeypatch):
