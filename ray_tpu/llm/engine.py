@@ -28,6 +28,13 @@ is the in-tree TPU-native equivalent (BASELINE.md config 5):
   layer (block ``p`` of layer ``l`` is block ``l*NB + p``), writes each
   slot's new row a layer into it and hands it back aliased — nothing
   pool-sized is copied or sliced (docs/serving.md);
+- a model whose layers are of two KINDS (full and sliding-window
+  attention: ``model.layer_kinds``) gets a block pool and a table a
+  kind: full layers ``max_seq`` rows a slot, sliding layers what a
+  window, a tail block and one prefill chunk need, and a sliding block
+  is freed as the slot's window leaves it (``llm/paged_cache.py``,
+  docs/serving.md). A model with one kind is served as it always was:
+  the same pool, tables, counters and programs;
 - the parameters are held in the dtype the programs compute in: the
   matmul weights cast once at construction (``model.serving_params``),
   not by every program that reads them; norms and an expert model's
@@ -54,8 +61,11 @@ import jax.numpy as jnp
 import numpy as np
 
 from ray_tpu.llm.paged_cache import (BlockPool, SlotAllocation,
-                                     allocate_slot, ensure_capacity,
-                                     seal_prompt_blocks)
+                                     WindowAllocation, allocate_slot,
+                                     ensure_capacity, first_window_block,
+                                     seal_prompt_blocks, seal_window_blocks,
+                                     slide_window, window_blocks_per_slot)
+from ray_tpu.models.llama import FULL, SLIDING
 
 
 class EngineDeadError(RuntimeError):
@@ -213,7 +223,27 @@ class ContinuousBatchingEngine:
         # bucket padding write garbage into scratch instead of a live
         # block, and every device index stays in-bounds (no OOB DMA for
         # the Pallas path to trip on)
-        self.kv = model.init_kv_pool(num_blocks + 1, block_size)
+        # A model with sliding-window layers: a second pool, of the
+        # blocks a layer of THAT kind holds, a second table, and on the
+        # device one stack with each layer's window of its kind's size
+        # (``model.init_kv_pools``). ``window`` None: one kind, and
+        # nothing below this line differs from what it always was.
+        kinds = model.layer_kinds
+        self.window: Optional[int] = (
+            model.cfg.sliding_window if kinds and SLIDING in kinds else None)
+        if self.window is None:
+            self.window_pool = self._tables_win = None
+            self.kv = model.init_kv_pool(num_blocks + 1, block_size)
+        else:
+            self._layer_kinds = np.asarray(kinds, np.int32)
+            self.num_window_blocks = max_slots * window_blocks_per_slot(
+                self.window, block_size, self.buckets[-1])
+            self.window_pool = BlockPool(self.num_window_blocks, block_size)
+            # each pool's last block is its kind's scratch block
+            self.kv = model.init_kv_pools(
+                (num_blocks + 1, self.num_window_blocks + 1), block_size)
+            self._tables_win = np.full((max_slots, self.blocks_per_slot),
+                                       self.num_window_blocks, np.int32)
 
         self.slots: List[Optional[Request]] = [None] * max_slots
         self.allocs: List[Optional[SlotAllocation]] = [None] * max_slots
@@ -270,8 +300,11 @@ class ContinuousBatchingEngine:
                                    donate_argnums=(2,))
         self._prefill = jax.jit(self._prefill_impl)
         self._prefill_prefix = jax.jit(model.prefill_with_prefix)
-        self._insert = jax.jit(self._insert_impl, donate_argnums=(0,))
-        self._gather = jax.jit(self._gather_impl)
+        self._insert = jax.jit(
+            self._insert_impl if self.window is None
+            else self._insert_kinds_impl, donate_argnums=(0,))
+        self._gather = jax.jit(self._gather_impl if self.window is None
+                               else self._gather_kinds_impl)
         self._sample = jax.jit(self._sample_impl)
 
         # Every key exists from here on (another thread copies the dict
@@ -288,6 +321,17 @@ class ContinuousBatchingEngine:
                       "prefill_tokens": 0, "prefill_padded_tokens": 0,
                       "decode_kv_blocks_live": 0,
                       "decode_kv_blocks_table": 0,
+                      # a model with sliding layers (0 with one kind):
+                      # blocks of the slots' SLIDING-kind tables a
+                      # step's attention reads, blocks that kind freed
+                      # behind its windows, and a layer's capacity of
+                      # each kind
+                      "decode_kv_blocks_live_window": 0,
+                      "kv_window_blocks_freed": 0,
+                      "kv_pool_blocks_full": num_blocks,
+                      "kv_pool_blocks_window": (
+                          0 if self.window is None
+                          else self.num_window_blocks),
                       "t_step_s": 0.0, "t_schedule_s": 0.0,
                       "t_prefill_s": 0.0, "t_host_arrays_s": 0.0,
                       "t_enqueue_s": 0.0, "t_readback_s": 0.0,
@@ -323,7 +367,8 @@ class ContinuousBatchingEngine:
         """The model's decode step, with its FFN's per-expert rows of
         the LIVE slots (an idle slot's table points at the scratch
         block) added to ``ffn_load``."""
-        live = block_tables[:, 0] != self.num_blocks
+        full = block_tables if block_tables.ndim == 2 else block_tables[FULL]
+        live = full[:, 0] != self.num_blocks
         logits, pool, extras = self.model.decode_step_paged_counted(
             params, tokens, pool, block_tables, offsets, live)
         return logits, pool, ffn_load + extras["load"]
@@ -360,6 +405,33 @@ class ContinuousBatchingEngine:
         """Gather prefix blocks [N, Pb] -> dense [L, N, Pb*bs, Hkv, D]."""
         k = pool["k"][:, block_ids]          # [L, N, Pb, bs, Hkv, D]
         v = pool["v"][:, block_ids]
+        L, N, Pb, bs = k.shape[:4]
+        return (k.reshape(L, N, Pb * bs, *k.shape[4:]),
+                v.reshape(L, N, Pb * bs, *v.shape[4:]))
+
+    def _insert_kinds_impl(self, pool, small, block_ids):
+        """``_insert_impl`` for a pool a kind: ``block_ids`` [kinds,
+        N*nb], each kind's own physical ids (pad with that kind's
+        scratch block); layer ``l`` writes at ``bases[l]`` plus its
+        kind's."""
+        L, N, Tb = small["k"].shape[:3]
+        bs = self.block_size
+        rows = (pool["bases"][:, None]
+                + block_ids[self._layer_kinds]).reshape(-1)   # [L*N*nb]
+
+        def to_blocks(x):
+            return x.reshape(L * N * (Tb // bs), bs, *x.shape[3:])
+
+        return dict(pool, k=pool["k"].at[rows].set(to_blocks(small["k"])),
+                    v=pool["v"].at[rows].set(to_blocks(small["v"])))
+
+    def _gather_kinds_impl(self, pool, block_ids):
+        """``_gather_impl`` for a pool a kind: ``block_ids`` [kinds, N,
+        Pb]. A sliding layer's rows behind its window are whatever its
+        scratch block holds: ``prefill_with_prefix`` masks them."""
+        rows = (pool["bases"][:, None, None]
+                + block_ids[self._layer_kinds])               # [L, N, Pb]
+        k, v = pool["k"][rows], pool["v"][rows]
         L, N, Pb, bs = k.shape[:4]
         return (k.reshape(L, N, Pb * bs, *k.shape[4:]),
                 v.reshape(L, N, Pb * bs, *v.shape[4:]))
@@ -461,7 +533,9 @@ class ContinuousBatchingEngine:
                 req.stream.put(None)
                 continue
             # +1 so the first decode write never needs a growth step
-            alloc = allocate_slot(self.pool, toks, n + 1)
+            alloc = allocate_slot(self.pool, toks, n + 1,
+                                  window_pool=self.window_pool,
+                                  window=self.window)
             if alloc is None:
                 # pool can't host it right now — put it back, stop
                 self.waiting.appendleft(req)
@@ -498,6 +572,66 @@ class ContinuousBatchingEngine:
                 singles.append(item)
         return by_shape, singles, by_bucket
 
+    # -- the blocks of a model with two kinds of layer ----------------------
+    def _slide(self, alloc: SlotAllocation, n_cached: int,
+               needed_tokens: int) -> bool:
+        """A model with sliding layers: the slot's blocks of THAT kind
+        follow its window (``paged_cache.slide_window``): those a query
+        at position ``n_cached`` no longer sees go back to the pool, and
+        blocks for ``needed_tokens`` are held. True if the set of blocks
+        changed. With one kind there is nothing to do."""
+        if self.window_pool is None:
+            return False
+        w = alloc.window
+        before = (w.first, len(w.blocks))
+        self._stats["kv_window_blocks_freed"] += slide_window(
+            self.window_pool, w,
+            first_window_block(n_cached, self.window, self.block_size),
+            needed_tokens)
+        return before != (w.first, len(w.blocks))
+
+    def _set_window_table(self, slot: int, alloc: SlotAllocation) -> None:
+        w = alloc.window
+        self._tables_win[slot] = self.num_window_blocks
+        self._tables_win[slot, w.first:w.first + len(w.blocks)] = w.blocks
+        self._dev_tables = None
+
+    def _follow_window(self, slot: int, alloc: SlotAllocation,
+                       at: int) -> None:
+        """Before a decode step that writes a slot's token at offset
+        ``at``: its sliding-kind blocks and their table follow."""
+        if self._slide(alloc, at, at + 1):
+            self._set_window_table(slot, alloc)
+
+    def _block_ids(self, rows: List[tuple], n: int, n_rows: int,
+                   gather: bool = False):
+        """Physical ids of the logical blocks ``[lo, hi)`` of each of
+        ``rows`` = [(allocation, lo, hi)], ``n`` entries a row and
+        ``n_rows`` rows: [n_rows, n], or with two kinds [kinds, n_rows,
+        n], each kind's own ids. An entry with no block behind it is the
+        kind's scratch block (a scatter's padding lands there); for a
+        ``gather`` the full kind's is block 0 as ever (the prefix's
+        padding is masked by position). A sliding layer's blocks behind
+        its window are such entries."""
+        ids = np.full((n_rows, n), 0 if gather else self.num_blocks,
+                      np.int32)
+        for r, (alloc, lo, hi) in enumerate(rows):
+            held = alloc.blocks[lo:hi]
+            ids[r, :len(held)] = held
+        if self.window_pool is None:
+            return ids
+        win = np.full((n_rows, n), self.num_window_blocks, np.int32)
+        for r, (alloc, lo, hi) in enumerate(rows):
+            win[r, :hi - lo] = alloc.window.ids(lo, hi,
+                                                self.num_window_blocks)
+        return np.stack([ids, win])
+
+    @staticmethod
+    def _flat(ids: np.ndarray):
+        """[.., rows, n] -> [.., rows*n] on the device: what the scatter
+        of a group's blocks takes."""
+        return jnp.asarray(ids.reshape(*ids.shape[:-2], -1))
+
     def _pad_pow2(self, n: int, cap: int) -> int:
         p = 1
         while p < n:
@@ -515,17 +649,17 @@ class ContinuousBatchingEngine:
         with self._prefill_phase(bucket, len(group), n_pad):
             lengths = np.ones(n_pad, np.int32)
             toks = np.zeros((n_pad, bucket), np.int32)
-            block_ids = np.full(n_pad * nb, self.num_blocks, np.int32)
             for row, (slot, req, alloc) in enumerate(group):
                 seq = req.cache_tokens()
                 lengths[row] = len(seq)
                 toks[row, :len(seq)] = seq
-                ids = alloc.blocks[:nb]
-                block_ids[row * nb:row * nb + len(ids)] = ids
+                self._slide(alloc, len(seq), len(seq) + 1)
                 self._stats["prefill_tokens"] += len(seq)
+            block_ids = self._block_ids(
+                [(alloc, 0, nb) for _, _, alloc in group], nb, n_pad)
             last_logits, small = self._prefill(
                 self.params, jnp.asarray(toks), jnp.asarray(lengths))
-            self.kv = self._insert(self.kv, small, jnp.asarray(block_ids))
+            self.kv = self._insert(self.kv, small, self._flat(block_ids))
             self._stats["prefills"] += 1
             self._stats["prefill_padded_tokens"] += n_pad * bucket
             toks_out = self._sample_batch(
@@ -551,29 +685,30 @@ class ContinuousBatchingEngine:
         nb = s_bucket // bs
         n_pad = self._pad_pow2(len(group), self.max_slots)
         with self._prefill_phase(s_bucket, len(group), n_pad):
-            ids = np.zeros((n_pad, pb_pad), np.int32)
             toks = np.zeros((n_pad, s_bucket), np.int32)
             plens = np.zeros(n_pad, np.int32)
             slens = np.ones(n_pad, np.int32)
-            block_ids = np.full(n_pad * nb, self.num_blocks, np.int32)
+            prefix, fresh = [], []
             for row, (slot, req, alloc, shared) in enumerate(group):
                 seq = req.cache_tokens()
                 pb = shared // bs
-                ids[row, :pb] = alloc.blocks[:pb]
                 suffix = seq[shared:]
                 toks[row, :len(suffix)] = suffix
                 plens[row] = shared
                 slens[row] = len(suffix)
-                avail = alloc.blocks[pb:pb + nb]
-                block_ids[row * nb:row * nb + len(avail)] = avail
+                self._slide(alloc, shared, len(seq) + 1)
+                prefix.append((alloc, 0, pb))
+                fresh.append((alloc, pb, pb + nb))
                 self._stats["prefix_prefills"] += 1
                 self._stats["prefix_tokens_reused"] += shared
                 self._stats["prefill_tokens"] += len(suffix)
+            ids = self._block_ids(prefix, pb_pad, n_pad, gather=True)
+            block_ids = self._block_ids(fresh, nb, n_pad)
             pk, pv = self._gather(self.kv, jnp.asarray(ids))
             last_logits, small = self._prefill_prefix(
                 self.params, jnp.asarray(toks), pk, pv,
                 jnp.asarray(plens), jnp.asarray(slens))
-            self.kv = self._insert(self.kv, small, jnp.asarray(block_ids))
+            self.kv = self._insert(self.kv, small, self._flat(block_ids))
             self._stats["prefills"] += 1
             self._stats["prefill_padded_tokens"] += n_pad * s_bucket
             toks_out = self._sample_batch(
@@ -599,8 +734,10 @@ class ContinuousBatchingEngine:
         # pad the gathered prefix to a power-of-two block count to bound
         # jit specializations; padded rows are position-masked
         pb_pad = self._pad_pow2(max(pb, 1), self.blocks_per_slot)
-        ids = np.zeros((1, pb_pad), np.int32)
-        ids[0, :pb] = alloc.blocks[:pb]
+        # a sliding layer holds, and gathers, only the blocks the chunk's
+        # first token still sees, and the chunk's own
+        self._slide(alloc, pos, pos + len(chunk) + 1)
+        ids = self._block_ids([(alloc, 0, pb)], pb_pad, 1, gather=True)
         pk, pv = self._gather(self.kv, jnp.asarray(ids))
         toks = np.zeros((1, s_bucket), np.int32)
         toks[0, :len(chunk)] = chunk
@@ -609,11 +746,9 @@ class ContinuousBatchingEngine:
             jnp.asarray([pos], np.int32),
             jnp.asarray([len(chunk)], np.int32))
         nb = s_bucket // bs
-        block_ids = np.full(nb, self.num_blocks, np.int32)
-        avail = alloc.blocks[pb:pb + nb]
-        block_ids[:len(avail)] = avail
+        block_ids = self._block_ids([(alloc, pb, pb + nb)], nb, 1)
         # chunk cache is [L, 1, Tb, ...]: reuse the batched scatter
-        self.kv = self._insert(self.kv, small, jnp.asarray(block_ids))
+        self.kv = self._insert(self.kv, small, self._flat(block_ids))
         self._stats["prefills"] += 1
         self._stats["prefill_tokens"] += len(chunk)
         self._stats["prefill_padded_tokens"] += s_bucket
@@ -650,6 +785,12 @@ class ContinuousBatchingEngine:
     def _activate(self, slot: int, req: Request, alloc: SlotAllocation,
                   n_cached: int, now: float) -> None:
         seal_prompt_blocks(self.pool, alloc, req.cache_tokens())
+        if self.window_pool is not None:
+            # the prefill is in: what is left of it behind the window
+            # goes, the prompt's blocks still held are indexed
+            self._slide(alloc, n_cached, n_cached + 1)
+            seal_window_blocks(self.window_pool, alloc.window)
+            self._set_window_table(slot, alloc)
         if req.first_token_at is None:
             req.first_token_at = now
         self.slots[slot] = req
@@ -677,17 +818,26 @@ class ContinuousBatchingEngine:
         preemption): generated tokens fold into the prompt so the
         re-admission prefill rebuilds the full context."""
         req = self.slots[slot]
-        self.pool.unref_all(self.allocs[slot].blocks)
-        self.slots[slot] = None
-        self.allocs[slot] = None
-        self.offsets[slot] = 0
-        self._tables[slot] = self.num_blocks   # idle writes go to scratch
-        self._dev_tables = self._dev_offsets = None
-        self._admit_order.remove(slot)
+        self._release(slot)
         req.preemptions += 1
         req.queued_at = time.perf_counter()
         self._stats["preemptions"] += 1
         self.waiting.appendleft(req)
+
+    def _release(self, slot: int) -> None:
+        """Give a slot's blocks back (cached-free: their content stays
+        prefix-reusable until the pool reallocates them) and empty it."""
+        alloc = self.allocs[slot]
+        self.pool.unref_all(alloc.blocks)
+        self._tables[slot] = self.num_blocks   # idle writes go to scratch
+        if self.window_pool is not None:
+            self.window_pool.unref_all(alloc.window.blocks)
+            self._tables_win[slot] = self.num_window_blocks
+        self.slots[slot] = None
+        self.allocs[slot] = None
+        self.offsets[slot] = 0
+        self._dev_tables = self._dev_offsets = None
+        self._admit_order.remove(slot)
 
     def _grow_or_preempt(self) -> None:
         """Every active slot must have capacity for its next token's
@@ -710,9 +860,12 @@ class ContinuousBatchingEngine:
                 self._preempt(victim)
                 if victim == slot:
                     break
-            if self.slots[slot] is not None and len(alloc.blocks) != held:
+            if self.slots[slot] is None:
+                continue
+            if len(alloc.blocks) != held:
                 self._tables[slot, :len(alloc.blocks)] = alloc.blocks
                 self._dev_tables = None
+            self._follow_window(slot, alloc, int(self.offsets[slot]))
 
     def _decode_step(self) -> int:
         """Read one decode step's tokens; before that, put the next step
@@ -777,6 +930,9 @@ class ContinuousBatchingEngine:
             if len(alloc.blocks) != held:
                 self._tables[i, :len(alloc.blocks)] = alloc.blocks
                 self._dev_tables = None
+            # the step ahead writes at offset + 1; the step in flight,
+            # queued before it, has read the block this may free
+            self._follow_window(i, alloc, int(self.offsets[i]) + 1)
         return True
 
     def _dispatch_decode(self, ahead: bool):
@@ -800,7 +956,9 @@ class ContinuousBatchingEngine:
             if self._dev_tokens is None:
                 self._dev_tokens = jnp.array(self._last_tokens)
             if self._dev_tables is None:
-                self._dev_tables = jnp.array(self._tables)
+                self._dev_tables = jnp.array(
+                    self._tables if self.window_pool is None
+                    else np.stack([self._tables, self._tables_win]))
             if self._dev_offsets is None:
                 self._dev_offsets = jnp.array(self.offsets)
             offsets = self._dev_offsets
@@ -848,6 +1006,11 @@ class ContinuousBatchingEngine:
             ((at[active] + bs) // bs).sum())
         self._stats["decode_kv_blocks_table"] += (
             len(active) * self.blocks_per_slot)
+        if self.window is not None:
+            # a sliding layer's kernel starts at its window's first block
+            first = np.maximum(at[active] - self.window + 1, 0) // bs
+            self._stats["decode_kv_blocks_live_window"] += int(
+                (at[active] // bs - first + 1).sum())
         return toks, active
 
     def _emit(self, slot: int, tok: int) -> None:
@@ -866,15 +1029,7 @@ class ContinuousBatchingEngine:
                                  else "length")
             req.finished_at = time.perf_counter()
             self._undelivered.append((req, None))
-            # blocks go cached-free: content stays prefix-reusable
-            # until the pool reallocates them
-            self.pool.unref_all(self.allocs[slot].blocks)
-            self.slots[slot] = None
-            self.allocs[slot] = None
-            self.offsets[slot] = 0
-            self._tables[slot] = self.num_blocks   # idle writes → scratch
-            self._dev_tables = self._dev_offsets = None
-            self._admit_order.remove(slot)
+            self._release(slot)
 
     def _deliver(self) -> None:
         """Hand the streams what ``_emit`` booked, in its order."""
@@ -933,13 +1088,13 @@ class ContinuousBatchingEngine:
             blocks = self.pool.alloc(max(need, 0))
             if blocks is None:
                 return None
-            alloc = SlotAllocation(blocks, 0)
-            block_ids = np.full(nb, self.num_blocks, np.int32)
-            avail = blocks[:nb]
-            block_ids[:len(avail)] = avail
+            alloc = SlotAllocation(
+                blocks, 0, None if self.window_pool is None
+                else WindowAllocation(0, []))
+            self._slide(alloc, n, n + 1)
+            block_ids = self._block_ids([(alloc, 0, nb)], nb, 1)
             small = {"k": jnp.asarray(kv["k"]), "v": jnp.asarray(kv["v"])}
-            self.kv = self._insert(self.kv, small,
-                                   jnp.asarray(block_ids))
+            self.kv = self._insert(self.kv, small, self._flat(block_ids))
             slot = free[0]
             toks_out = self._sample_batch(jnp.asarray(last_logits)[None],
                                           [req], 1)

@@ -23,6 +23,16 @@ Design (vLLM-v1-shaped, TPU-adapted):
   (content stays valid in HBM) until the block is reallocated — a later
   request with the same prefix can resurrect a "free" block. This is
   the cross-request prefix cache; eviction is allocation itself.
+- A model whose layers are of two KINDS (full and sliding-window
+  attention, ``models/llama.py``) gets a ``BlockPool`` a kind. The full
+  kind's is the one above. The SLIDING kind's holds, a slot, only the
+  blocks its window still overlaps (``WindowAllocation``): as the slot's
+  offset passes a block boundary the block that left the window goes
+  back to that pool's free list (``slide_window``; freed, not reused as
+  a ring: a freed block keeps its prefix-index entry like any other).
+  A prefix hit needs BOTH kinds: ``allocate_slot`` takes the longest
+  prefix whose blocks the full kind's index holds AND whose last
+  window's worth of blocks the sliding kind's index still holds.
 """
 
 from __future__ import annotations
@@ -153,26 +163,118 @@ class SlotAllocation:
     """A slot's logical->physical block mapping plus which of its
     blocks were prefix hits (already containing K/V)."""
 
-    __slots__ = ("blocks", "shared_blocks", "sealed_upto")
+    __slots__ = ("blocks", "shared_blocks", "sealed_upto", "window")
 
-    def __init__(self, blocks: List[int], shared_blocks: int):
+    def __init__(self, blocks: List[int], shared_blocks: int,
+                 window: Optional["WindowAllocation"] = None):
         self.blocks = blocks              # physical ids, logical order
         self.shared_blocks = shared_blocks
         self.sealed_upto = shared_blocks  # blocks already hash-indexed
+        self.window = window              # the sliding kind's blocks, if any
 
     @property
     def capacity(self) -> int:
         return len(self.blocks)
 
 
+class WindowAllocation:
+    """A slot's blocks of the SLIDING kind: the logical blocks
+    ``[first, first + len(blocks))`` of its sequence, the ones its
+    window still overlaps (and, in a prefill, the chunk being written).
+    Blocks before ``first`` were freed, or never held. ``hashes`` is the
+    chain of the request's full prompt blocks (by logical index): a
+    prompt block is indexed before it is freed, so a later request can
+    still find it."""
+
+    __slots__ = ("first", "blocks", "hashes")
+
+    def __init__(self, first: int, blocks: List[int],
+                 hashes: Sequence[int] = ()):
+        self.first = first
+        self.blocks = blocks
+        self.hashes = hashes
+
+    def ids(self, lo: int, hi: int, fill: int) -> List[int]:
+        """Physical ids of the logical blocks ``[lo, hi)``, ``fill``
+        where none is held."""
+        end = self.first + len(self.blocks)
+        return [self.blocks[j - self.first] if self.first <= j < end
+                else fill for j in range(lo, hi)]
+
+
+def first_window_block(n_cached: int, window: int, block_size: int) -> int:
+    """The logical block of the first position a sliding layer's query
+    at position ``n_cached`` sees (key j is visible iff i - j < window)."""
+    return max(0, n_cached - window + 1) // block_size
+
+
+def window_blocks_per_slot(window: int, block_size: int, chunk: int) -> int:
+    """Most blocks of the sliding kind one slot ever holds: those a
+    window overlaps (it need not start on a block boundary), the partly
+    filled tail block the next token goes to, and the blocks of one
+    prefill chunk of ``chunk`` tokens being written beside them."""
+    return -(-(window - 1) // block_size) + 2 + -(-chunk // block_size)
+
+
+def slide_window(pool: BlockPool, alloc: WindowAllocation,
+                 first_block: int, needed_tokens: int) -> int:
+    """Move a slot's sliding-kind blocks on: free those before logical
+    block ``first_block`` (the window has left them), then hold blocks
+    up to the one ``needed_tokens`` ends in. Returns how many were
+    freed. A full PROMPT block is indexed by its hash before it goes, so
+    it stays a prefix hit until the pool reallocates it. The pool is
+    sized for every slot's most (``window_blocks_per_slot``), so running
+    out is a fault of the caller's accounting, not load."""
+    bs = pool.block_size
+    freed = 0
+    while alloc.blocks and alloc.first < first_block:
+        block = alloc.blocks.pop(0)
+        if alloc.first < len(alloc.hashes):
+            pool.seal(block, alloc.hashes[alloc.first])
+        pool.unref(block)
+        alloc.first += 1
+        freed += 1
+    if not alloc.blocks:
+        alloc.first = max(alloc.first, first_block)
+    need = (needed_tokens + bs - 1) // bs - (alloc.first + len(alloc.blocks))
+    if need > 0:
+        fresh = pool.alloc(need)
+        if fresh is None:
+            raise RuntimeError(
+                f"the sliding-window block pool ({pool.num_blocks} blocks) "
+                f"cannot give a slot {need} more: it is sized so that "
+                f"this cannot happen")
+        alloc.blocks.extend(fresh)
+    return freed
+
+
+def seal_window_blocks(pool: BlockPool, alloc: WindowAllocation) -> None:
+    """After prefill lands: index the full prompt blocks still held."""
+    for k, block in enumerate(alloc.blocks):
+        if alloc.first + k < len(alloc.hashes):
+            pool.seal(block, alloc.hashes[alloc.first + k])
+
+
 def allocate_slot(pool: BlockPool, prompt: Sequence[int],
                   reserve_tokens: Optional[int] = None,
-                  extra_key: Optional[Tuple] = None
+                  extra_key: Optional[Tuple] = None,
+                  window_pool: Optional[BlockPool] = None,
+                  window: Optional[int] = None
                   ) -> Optional[Tuple[SlotAllocation, int]]:
     """Allocate blocks for a request: longest shared prefix from the
     pool's index + fresh blocks covering the rest of ``reserve_tokens``
     (default: the prompt). Decode-time growth goes through
     ``ensure_capacity``; exhaustion there triggers engine preemption.
+
+    With a ``window_pool`` (a model with sliding layers of that
+    ``window``) a prefix of n blocks is shared only if the sliding
+    kind can serve it too: the suffix's first token sees the ``window -
+    1`` positions before it, so every block from the one that holds
+    position ``n*bs - window + 1`` up to block n - 1 has to be in the
+    sliding kind's index still. The longest n for which both hold is
+    taken (it may be 0), and the allocation's ``window`` then holds
+    those blocks; the rest of the sliding kind's come with
+    ``slide_window`` as the prefill and the decode go.
 
     Returns (allocation, shared_token_count) or None if the pool cannot
     cover the non-shared remainder right now.
@@ -186,6 +288,20 @@ def allocate_slot(pool: BlockPool, prompt: Sequence[int],
     # last-token logits — keep >=1 token of real prefill.
     if len(shared) * bs >= len(prompt):
         shared = shared[:max(0, (len(prompt) - 1) // bs)]
+    window_alloc = None
+    if window_pool is not None:
+        def in_window(n):      # the sliding kind's blocks a hit of n needs
+            return range(first_window_block(n * bs, window, bs), n)
+
+        n = len(shared)
+        while n and any(hashes[j] not in window_pool._by_hash
+                        for j in in_window(n)):
+            n -= 1
+        shared = shared[:n]
+        held = [window_pool._by_hash[hashes[j]] for j in in_window(n)]
+        for b in held:
+            window_pool.ref(b)
+        window_alloc = WindowAllocation(n - len(held), held, hashes)
     n_shared_tok = len(shared) * bs
     total_blocks = (reserve_tokens + bs - 1) // bs
     n_fresh = total_blocks - len(shared)
@@ -196,8 +312,10 @@ def allocate_slot(pool: BlockPool, prompt: Sequence[int],
     fresh = pool.alloc(n_fresh)
     if fresh is None:
         pool.unref_all(shared)
+        if window_alloc is not None:
+            window_pool.unref_all(window_alloc.blocks)
         return None
-    alloc = SlotAllocation(list(shared) + fresh, len(shared))
+    alloc = SlotAllocation(list(shared) + fresh, len(shared), window_alloc)
     return alloc, n_shared_tok
 
 

@@ -23,7 +23,16 @@ it is in-tree and TPU-first:
   (``serving_params``: bf16 ``embed``/``lm_head``/``wq``/``wk``/``wv``/
   ``wo`` and FFN stacks, f32 norms), so its programs read them as they
   are and the same casts lower to nothing;
-- ``remat`` on each layer trades FLOPs for HBM (the standard TPU recipe).
+- ``remat`` on each layer trades FLOPs for HBM (the standard TPU recipe);
+- layers may be of two KINDS (``LlamaConfig.layer_types``): full causal
+  attention, and SLIDING-WINDOW attention in which a query at ``i`` sees
+  a key at ``j`` only if ``i - j < sliding_window``. Both kinds have the
+  same parameters, so the stack and the one scan stay; each program's
+  scan hands the layer body its kind, and the kind picks the rotary
+  table (``rope_scaling``: a kind may turn by a YaRN table) and the
+  window that the program's ``attend`` applies. A model with no sliding
+  layer has no kinds (``layer_kinds`` is ``None``) and lowers to the
+  programs it lowered to before kinds existed.
 """
 
 from __future__ import annotations
@@ -37,9 +46,17 @@ import jax.numpy as jnp
 from ray_tpu.ops.attention import attention, reference_attention
 from ray_tpu.ops.norms import rms_norm
 from ray_tpu.ops.ring_attention import ring_attention
-from ray_tpu.ops.rope import apply_rope, rope_frequencies
+from ray_tpu.ops.rope import (YarnScaling, apply_rope, apply_rope_of_kind,
+                              rope_frequencies, yarn_inv_freq)
 
 Params = Dict[str, Any]
+
+# the kinds of layer, by the names published configs use; a kind's INDEX
+# (what the scans hand the layer body) is its place here
+LAYER_KINDS = ("full_attention", "sliding_attention")
+FULL, SLIDING = 0, 1
+# a full layer's window: past any position, so ``i - j < window`` holds
+NO_WINDOW = 2 ** 30
 
 
 @dataclasses.dataclass(frozen=True)
@@ -78,6 +95,19 @@ class LlamaConfig:
     # ops/paged_attention.py); "xla" / "pallas" force a side (tests,
     # chip_smoke.py).
     decode_attention: Optional[str] = None
+    # Width of one head; None: ``dim // n_heads``. A model may publish
+    # another (q and o are then ``dim x n_heads*head_dim``). Filled in
+    # here, so ``dataclasses.replace`` of ``dim`` or ``n_heads`` has to
+    # pass ``head_dim=None`` to have it derived again.
+    head_dim: Optional[int] = None
+    # Each layer's kind, one of ``LAYER_KINDS`` a layer (None: every
+    # layer full attention), and the sliding kind's window: a query at
+    # ``i`` sees a key at ``j`` only if ``i - j < sliding_window``
+    layer_types: Optional[Tuple[str, ...]] = None
+    sliding_window: Optional[int] = None
+    # (kind, YarnScaling) pairs: the kinds whose rotary table is YaRN's;
+    # a kind not named turns by the default table of ``rope_theta``
+    rope_scaling: Tuple[Tuple[str, YarnScaling], ...] = ()
 
     def __post_init__(self):
         if self.attention_impl not in ("ring", "ulysses", "flash", "xla"):
@@ -92,15 +122,31 @@ class LlamaConfig:
             raise ValueError(
                 f"decode_attention must be None, 'xla' or 'pallas', "
                 f"got {self.decode_attention!r}")
-
-    @property
-    def head_dim(self) -> int:
-        return self.dim // self.n_heads
+        if self.head_dim is None:
+            object.__setattr__(self, "head_dim", self.dim // self.n_heads)
+        if self.layer_types is not None:
+            object.__setattr__(self, "layer_types", tuple(self.layer_types))
+            unknown = set(self.layer_types) - set(LAYER_KINDS)
+            if unknown or len(self.layer_types) != self.n_layers:
+                raise ValueError(
+                    f"layer_types must name one of {LAYER_KINDS} for each "
+                    f"of the {self.n_layers} layers, got {self.layer_types}")
+            if (LAYER_KINDS[SLIDING] in self.layer_types
+                    and not self.sliding_window):
+                raise ValueError("a sliding_attention layer needs a "
+                                 "sliding_window")
+        object.__setattr__(self, "rope_scaling",
+                           tuple(map(tuple, self.rope_scaling)))
+        if {kind for kind, _ in self.rope_scaling} - set(LAYER_KINDS):
+            raise ValueError(
+                f"rope_scaling names kinds out of {LAYER_KINDS}, got "
+                f"{self.rope_scaling}")
 
     def num_params(self) -> int:
         d, f, v = self.dim, self.ffn_dim, self.vocab_size
+        q = self.n_heads * self.head_dim
         kv = self.n_kv_heads * self.head_dim
-        per_layer = d * d + 2 * d * kv + d * d + 3 * d * f + 2 * d
+        per_layer = d * q + 2 * d * kv + q * d + 3 * d * f + 2 * d
         heads = 0 if self.tie_embeddings else v * d
         return v * d + self.n_layers * per_layer + d + heads
 
@@ -171,8 +217,29 @@ class LlamaModel:
             raise ValueError(
                 "attention_impl='flash' is a single-device kernel; with an "
                 "sp>1 mesh use 'ring' or 'ulysses' context parallelism")
-        self._angles = rope_frequencies(cfg.head_dim, cfg.max_seq_len,
-                                        theta=cfg.rope_theta)
+        # ``layer_kinds``: each layer's index into ``LAYER_KINDS``, or
+        # None for the plain model (every layer full attention on the
+        # default rotary table), which then carries none of what follows
+        types = cfg.layer_types or (LAYER_KINDS[FULL],) * cfg.n_layers
+        yarn = dict(cfg.rope_scaling)
+        if LAYER_KINDS[SLIDING] not in types and not yarn:
+            self.layer_kinds: Optional[Tuple[int, ...]] = None
+            self._angles = rope_frequencies(cfg.head_dim, cfg.max_seq_len,
+                                            theta=cfg.rope_theta)
+            self._rope_scales = self._windows = None
+        else:
+            self.layer_kinds = tuple(LAYER_KINDS.index(t) for t in types)
+            # inverse frequencies, a factor on cos and sin and a window
+            # a KIND: [kinds, hd/2], [kinds], [kinds]; no table (the
+            # angles are computed from the positions: ``ops/rope.py``)
+            self._inv_freq = jnp.stack([
+                yarn_inv_freq(cfg.head_dim, cfg.rope_theta, yarn.get(kind))
+                for kind in LAYER_KINDS])
+            self._rope_scales = jnp.asarray(
+                [yarn[kind].cos_sin_scale if kind in yarn else 1.0
+                 for kind in LAYER_KINDS], jnp.float32)
+            self._windows = jnp.asarray(
+                [NO_WINDOW, cfg.sliding_window or NO_WINDOW], jnp.int32)
 
     # -- init ---------------------------------------------------------------
     def init(self, rng: jax.Array) -> Params:
@@ -293,7 +360,28 @@ class LlamaModel:
             out_specs=P(dp_ax, seq_ax, fsdp_ax), check_vma=False)
         return fn(table, tokens)
 
-    def _attention(self, q, k, v, positions):
+    def _kinds_xs(self) -> Optional[jax.Array]:
+        """What a program's layer scan hands the layer body beside the
+        layer's parameters: its kind's index [L], or None (no leaf: the
+        plain model's scans are what they were)."""
+        if self.layer_kinds is None:
+            return None
+        return jnp.asarray(self.layer_kinds, jnp.int32)
+
+    def _window(self, kind):
+        """The window of a layer of ``kind`` (a traced index), or None
+        for the plain model."""
+        return None if kind is None else self._windows[kind]
+
+    def _attention(self, q, k, v, positions, window=None):
+        if window is not None:
+            # a layer of a model with kinds (training and the tests'
+            # oracle; no cell trains one): the masked reference
+            if self._sp > 1:
+                raise NotImplementedError(
+                    "sliding-window layers are not supported with sp>1")
+            return reference_attention(q, k, v, positions_q=positions,
+                                       positions_k=positions, window=window)
         if self._sp > 1:
             if positions is not None:
                 raise NotImplementedError(
@@ -350,10 +438,18 @@ class LlamaModel:
             down = jnp.einsum("bsf,fd->bsd", ff, layer["w_down"].astype(dt))
         return down, None
 
+    def _rope(self, x, positions, kind):
+        if kind is None:
+            return apply_rope(x, self._angles, positions)
+        return apply_rope_of_kind(x, self._inv_freq, self._rope_scales,
+                                  kind, positions)
+
     def _layer(self, x, layer: Params, positions, attend, live=None,
-               constrain: bool = False):
+               constrain: bool = False, kind=None):
         """One decoder layer. x [B, T, D]; ``positions`` what RoPE turns
-        q and k by (``None``: 0..T-1); ``attend(q, k, v) -> (o, kv)``
+        q and k by (``None``: 0..T-1), by the table of the layer's
+        ``kind`` (its index, traced; None in the plain model);
+        ``attend(q, k, v) -> (o, kv)``
         with q/o [B, T, H, hd] and k/v [B, T, Hkv, hd], the calling
         program's own: it writes this call's K/V where the program keeps
         them, reads the earlier ones, and hands back as ``kv`` whatever
@@ -375,8 +471,8 @@ class LlamaModel:
             v = jnp.einsum("bsd,dhk->bshk", h, layer["wv"].astype(dt))
             q, k = self._qk_norm(q, k, layer)
             q = pin(q, "batch", "seq", "heads", None)
-            q = apply_rope(q, self._angles, positions)
-            k = apply_rope(k, self._angles, positions)
+            q = self._rope(q, positions, kind)
+            k = self._rope(k, positions, kind)
         o, kv = attend(q, k, v)
         with jax.named_scope("attention"):
             o = jnp.einsum("bshk,hkd->bsd", o, layer["wo"].astype(dt))
@@ -425,13 +521,16 @@ class LlamaModel:
         extra (``None`` for the dense layer)."""
         cfg = self.cfg
 
-        def attend(q, k, v):        # training keeps no K/V
-            with jax.named_scope("attention"):
-                return self._attention(q, k, v, positions), None
+        def layer_fn(x, layer_and_kind):
+            layer, kind = layer_and_kind
 
-        def layer_fn(x, layer):
+            def attend(q, k, v):        # training keeps no K/V
+                with jax.named_scope("attention"):
+                    return self._attention(q, k, v, positions,
+                                           self._window(kind)), None
+
             x, _, extra = self._layer(x, layer, positions, attend,
-                                      constrain=True)
+                                      constrain=True, kind=kind)
             return x, extra
 
         if cfg.remat:
@@ -441,7 +540,8 @@ class LlamaModel:
             else:
                 layer_fn = jax.checkpoint(layer_fn)
         x = self._embed(params, tokens, constrain=True)
-        x, extras = jax.lax.scan(layer_fn, x, params["layers"])
+        x, extras = jax.lax.scan(layer_fn, x,
+                                 (params["layers"], self._kinds_xs()))
         return self._head(params, x, constrain=True), extras
 
     # -- KV-cache inference path (serving; BASELINE.md config 5) ----------
@@ -468,7 +568,7 @@ class LlamaModel:
         batch_idx = jnp.arange(B)[:, None]
 
         def step(x, layer_and_cache):
-            layer, k_cache, v_cache = layer_and_cache
+            layer, k_cache, v_cache, kind = layer_and_cache
 
             def attend(q, k_new, v_new):
                 with jax.named_scope("kv_update"):
@@ -480,15 +580,16 @@ class LlamaModel:
                     # attend over cache positions <= own position
                     o = reference_attention(q, k_all, v_all,
                                             positions_q=q_pos,
-                                            positions_k=jnp.arange(S))
+                                            positions_k=jnp.arange(S),
+                                            window=self._window(kind))
                 return o, (k_all, v_all)
 
-            x, kv, _ = self._layer(x, layer, q_pos, attend)
+            x, kv, _ = self._layer(x, layer, q_pos, attend, kind=kind)
             return x, kv
 
         x, (k_out, v_out) = jax.lax.scan(
             step, self._embed(params, tokens),
-            (params["layers"], cache["k"], cache["v"]))
+            (params["layers"], cache["k"], cache["v"], self._kinds_xs()))
         return self._head(params, x), {"k": k_out, "v": v_out}
 
     # -- paged KV-cache path (llm/engine.py + llm/paged_cache.py) ---------
@@ -500,6 +601,26 @@ class LlamaModel:
                  cfg.head_dim)
         return {"k": jnp.zeros(shape, cfg.dtype),
                 "v": jnp.zeros(shape, cfg.dtype)}
+
+    def init_kv_pools(self, num_blocks: Tuple[int, ...],
+                      block_size: int) -> Params:
+        """A pool a KIND, for a model with kinds: a layer of kind ``i``
+        gets ``num_blocks[i]`` blocks of its own, numbered from 0 by
+        that kind's block tables (a sliding layer holds a window's
+        worth a slot, not ``max_seq``: ``llm/engine.py``). On the device
+        the pools are ONE stack, k/v ``[sum over layers, block_size,
+        Hkv, D]``, the layers' windows one after another in layer
+        order, with ``"bases"`` [L] int32, where each layer's begins:
+        what ``decode_step_paged`` carries through its scan anyway (the
+        uniform pool is the stack in which every window has NB blocks).
+        """
+        cfg = self.cfg
+        per_layer = [num_blocks[kind] for kind in self.layer_kinds]
+        bases = [sum(per_layer[:i]) for i in range(len(per_layer))]
+        shape = (sum(per_layer), block_size, cfg.n_kv_heads, cfg.head_dim)
+        return {"k": jnp.zeros(shape, cfg.dtype),
+                "v": jnp.zeros(shape, cfg.dtype),
+                "bases": jnp.asarray(bases, jnp.int32)}
 
     def paged_decode_impl(self) -> str:
         """The attention ``decode_step_paged`` is built with, and the one
@@ -522,8 +643,11 @@ class LlamaModel:
         """One decode step for every slot against the block pool.
 
         tokens [B] int32 (each slot's last sampled token)
-        pool   k/v [L, NB, bs, Hkv, D]
-        block_tables [B, MAXB] int32 physical ids (logical order)
+        pool   k/v [L, NB, bs, Hkv, D], or a model with kinds' pool a
+               kind (``init_kv_pools``)
+        block_tables [B, MAXB] int32 physical ids (logical order); for
+               a model with kinds also [kinds, B, MAXB], a table a kind
+               (given one table, every kind reads it)
         offsets [B] tokens already cached per slot
         Returns (logits [B, V], updated pool). Slots whose table rows
         point at garbage simply compute garbage that the engine masks.
@@ -549,52 +673,82 @@ class LlamaModel:
         the stack ``[L*NB, bs, Hkv, D]`` (the pool with its two leading
         dimensions merged, the same bytes) and never a layer's slice of
         it, so a caller that donates the pool gets it back written in
-        place. Layer ``l``'s page ``p`` is page ``l*NB + p`` of the
-        stack: the layer writes its B rows at ``(l*NB + dest_block,
-        dest_off)`` and attention, kernel and reference alike, reads
-        through the block table plus ``l*NB``. (Handed to the scan as
-        ``xs``/``ys`` the pool cost two whole copies a step and a slice
-        out and back a layer: PERF.md, PR 27.)"""
-        L, NB, bs = pool["k"].shape[:3]
+        place. Layer ``l``'s page ``p`` is page ``base[l] + p`` of the
+        stack, ``base[l] = l*NB``: the layer writes its B rows at
+        ``(base + dest_block, dest_off)`` and attention, kernel and
+        reference alike, reads through the block table plus ``base``.
+        (Handed to the scan as ``xs``/``ys`` the pool cost two whole
+        copies a step and a slice out and back a layer: PERF.md, PR 27.)
+
+        A model with KINDS runs the same body. Its scan hands each layer
+        its kind beside its base, and the kind picks the table the layer
+        writes and reads through and its first visible position
+        (``offset + 1 - window``, 0 in a full layer): the kernel starts
+        at that position's page and reads nothing older. Its pool may be
+        the uniform one (one table: a sliding layer then keeps, and
+        skips, the rows behind its window) or a pool a kind
+        (``init_kv_pools``: the stack as it comes, ``bases`` beside it)
+        with a table a kind."""
+        if "bases" in pool:
+            NB, stack = None, pool["k"].shape
+        else:
+            L, NB = pool["k"].shape[:2]
+            stack = (L * NB,) + pool["k"].shape[2:]
+        bs = stack[1]
+        kinds = self._kinds_xs()
+        if kinds is not None and block_tables.ndim == 2:
+            block_tables = jnp.broadcast_to(
+                block_tables, (len(LAYER_KINDS),) + block_tables.shape)
+        # [B], or [kinds, B] where there are kinds
+        at_block = (offsets // bs)[:, None]
         dest_block = jnp.take_along_axis(
-            block_tables, (offsets // bs)[:, None], axis=1)[:, 0]  # [B]
+            block_tables, at_block if kinds is None else at_block[None],
+            axis=-1)[..., 0]
         dest_off = offsets % bs
         lengths = offsets + 1
+        starts = None if kinds is None else jnp.maximum(
+            lengths - self._windows[:, None], 0)
         q_pos = offsets[:, None]                                   # [B, 1]
         impl = self.paged_decode_impl()
         from ray_tpu.ops.paged_attention import paged_decode_attention
 
-        def step(carry, layer_and_base):
+        def step(carry, layer_base_kind):
             x, k_pool, v_pool = carry
             # ``base``: where this layer's blocks start in the stack
-            layer, base = layer_and_base
+            layer, base, kind = layer_base_kind
+
+            def own(a):       # the layer's kind's row of a per-kind array
+                return a if kind is None else a[kind]
 
             def attend(q, k_new, v_new):
                 with jax.named_scope("kv_update"):
                     # each slot writes its own private tail block (refcount
                     # 1 — shared prefix blocks are never write targets)
-                    k_all = k_pool.at[base + dest_block, dest_off].set(
+                    k_all = k_pool.at[base + own(dest_block), dest_off].set(
                         k_new[:, 0])
-                    v_all = v_pool.at[base + dest_block, dest_off].set(
+                    v_all = v_pool.at[base + own(dest_block), dest_off].set(
                         v_new[:, 0])
                 with jax.named_scope("attention"):
                     o = paged_decode_attention(
-                        q[:, 0], k_all, v_all, block_tables, lengths,
-                        impl=impl, first_block=base, num_blocks=NB)
+                        q[:, 0], k_all, v_all, own(block_tables), lengths,
+                        impl=impl, starts=own(starts), first_block=base,
+                        num_blocks=NB)
                 return o[:, None], (k_all, v_all)
 
             x, (k_pool, v_pool), extra = self._layer(
-                x, layer, q_pos, attend, live=live)
+                x, layer, q_pos, attend, live=live, kind=kind)
             return (x, k_pool, v_pool), extra
 
-        stack = (L * NB,) + pool["k"].shape[2:]
         (x, k_out, v_out), extras = jax.lax.scan(
             step,
             (self._embed(params, tokens[:, None]),                 # [B,1,D]
              pool["k"].reshape(stack), pool["v"].reshape(stack)),
-            (params["layers"], jnp.arange(L, dtype=jnp.int32) * NB))
-        pool = {"k": k_out.reshape(pool["k"].shape),
-                "v": v_out.reshape(pool["v"].shape)}
+            (params["layers"],
+             pool["bases"] if NB is None
+             else jnp.arange(L, dtype=jnp.int32) * NB,
+             kinds))
+        pool = dict(pool, k=k_out.reshape(pool["k"].shape),
+                    v=v_out.reshape(pool["v"].shape))
         return self._head(params, x)[:, 0], pool, extras
 
     def prefill_with_prefix(self, params: Params, tokens: jax.Array,
@@ -611,7 +765,9 @@ class LlamaModel:
         Returns (last-token logits [N, V], suffix K/V [L, N, Tb, Hkv, D])
         — the caller scatters the suffix K/V into fresh pool blocks; the
         prefix blocks are never copied or rewritten (prefix-reuse skips
-        their FLOPs entirely).
+        their FLOPs entirely). A sliding layer masks the prefix rows
+        behind each query's window, so its rows there may hold anything
+        (the engine has freed their blocks and gathers none of them).
         """
         Tb = tokens.shape[1]
         Pmax = prefix_k.shape[2]
@@ -626,7 +782,7 @@ class LlamaModel:
         pos_k = jnp.concatenate([pos_prefix, pos_q], axis=1)      # [N,P+Tb]
 
         def step(x, layer_and_prefix):
-            layer, kp, vp = layer_and_prefix       # kp/vp [N, Pmax, Hkv, D]
+            layer, kp, vp, kind = layer_and_prefix  # kp/vp [N, Pmax, Hkv, D]
 
             def attend(q, k_new, v_new):
                 with jax.named_scope("attention"):
@@ -635,15 +791,16 @@ class LlamaModel:
                                            axis=1),
                         jnp.concatenate([vp.astype(v_new.dtype), v_new],
                                         axis=1),
-                        positions_q=pos_q, positions_k=pos_k)
+                        positions_q=pos_q, positions_k=pos_k,
+                        window=self._window(kind))
                 return o, (k_new, v_new)
 
-            x, kv, _ = self._layer(x, layer, pos_q, attend)
+            x, kv, _ = self._layer(x, layer, pos_q, attend, kind=kind)
             return x, kv
 
         x, (k_out, v_out) = jax.lax.scan(
             step, self._embed(params, tokens),
-            (params["layers"], prefix_k, prefix_v))
+            (params["layers"], prefix_k, prefix_v, self._kinds_xs()))
         return (self._head(params, x, last=lengths - 1)[:, 0],
                 {"k": k_out, "v": v_out})
 

@@ -59,9 +59,10 @@ class MoEConfig(LlamaConfig):
 
     def num_params(self) -> int:
         d, f, v, E = self.dim, self.ffn_dim, self.vocab_size, self.num_experts
+        q = self.n_heads * self.head_dim
         kv = self.n_kv_heads * self.head_dim
-        per_layer = (2 * d * d + 2 * d * kv + 2 * d + d * E + 3 * E * d * f
-                     + (d + kv if self.qk_norm else 0))
+        per_layer = (2 * d * q + 2 * d * kv + 2 * d + d * E + 3 * E * d * f
+                     + (q + kv if self.qk_norm else 0))
         heads = 0 if self.tie_embeddings else v * d
         return v * d + self.n_layers * per_layer + d + heads
 

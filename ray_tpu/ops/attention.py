@@ -36,7 +36,8 @@ def reference_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
                         causal: bool = True,
                         positions_q: Optional[jax.Array] = None,
                         positions_k: Optional[jax.Array] = None,
-                        scale: Optional[float] = None) -> jax.Array:
+                        scale: Optional[float] = None,
+                        window=None) -> jax.Array:
     """Plain softmax attention in f32; XLA fuses this well on TPU for
     moderate sequence lengths and it is fully differentiable.
 
@@ -44,7 +45,9 @@ def reference_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
     one vector for the batch ([T] / [S]) or PER ROW ([B, T] / [B, S]):
     the serving prefills' masked attention over a slot cache or a
     gathered prefix, where every row has its own offset (a key to be
-    dropped carries a position past every query's)."""
+    dropped carries a position past every query's). ``window`` (an int,
+    it may be traced) is a sliding-window layer's: a query at ``i``
+    sees a key at ``j`` only if ``i - j < window`` besides."""
     head_dim = q.shape[-1]
     scale = scale if scale is not None else head_dim ** -0.5
     k = _repeat_kv(k, q.shape[-2])
@@ -57,6 +60,9 @@ def reference_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
         if positions_k is None:
             positions_k = jnp.arange(k.shape[1])
         mask = positions_q[..., :, None] >= positions_k[..., None, :]
+        if window is not None:
+            mask &= (positions_q[..., :, None] - positions_k[..., None, :]
+                     < window)
         # [T, S] or [B, T, S] -> broadcast over heads
         s = jnp.where(jnp.expand_dims(mask, -3), s, NEG_INF)
     p = jax.nn.softmax(s, axis=-1)

@@ -41,6 +41,14 @@ block_tables [B, MAXB] int32 (physical ids; entries past a slot's
 length are ignored); lengths [B] int32. The pools may be a STACK of
 windows of NB blocks (every layer's, ``[L*NB, bs, Hkv, D]``): the tables
 then count from ``first_block``.
+
+A SLIDING-WINDOW layer hands ``starts`` [B] int32 besides, each slot's
+first visible position: the query sees positions ``[start, length)``.
+The kernel begins its walk at the page that holds ``start``, masks that
+page's rows behind it, and reads nothing older (the table's entries
+before that page may point anywhere: the engine has freed their blocks);
+the reference masks the same rows of its gather. Without ``starts``
+both are the programs they were.
 """
 
 from __future__ import annotations
@@ -59,10 +67,11 @@ from ray_tpu.ops.attention import NEG_INF, _repeat_kv
 logger = logging.getLogger(__name__)
 
 
-def ragged_decode_attention_reference(q, k, v, lengths, *,
+def ragged_decode_attention_reference(q, k, v, lengths, *, starts=None,
                                       scale: Optional[float] = None):
     """One query token against a dense, length-bounded cache, masked
-    past each row's length: q [B, H, D] x k/v [B, S, Hkv, D], lengths
+    past each row's length (and, with ``starts`` [B], before each row's
+    first visible position): q [B, H, D] x k/v [B, S, Hkv, D], lengths
     [B] -> [B, H, D]. The arithmetic of the paged reference below, and
     the tests' oracle."""
     head_dim = q.shape[-1]
@@ -72,13 +81,15 @@ def ragged_decode_attention_reference(q, k, v, lengths, *,
     s = jnp.einsum("bhd,bshd->bhs", q, k,
                    preferred_element_type=jnp.float32) * scale
     mask = jnp.arange(k.shape[1])[None, :] < lengths[:, None]   # [B,S]
+    if starts is not None:
+        mask &= jnp.arange(k.shape[1])[None, :] >= starts[:, None]
     s = jnp.where(mask[:, None, :], s, NEG_INF)
     p = jax.nn.softmax(s, axis=-1)
     return jnp.einsum("bhs,bshd->bhd", p.astype(v.dtype), v)
 
 
 def paged_decode_attention_reference(q, k_pool, v_pool, block_tables,
-                                     lengths, *,
+                                     lengths, *, starts=None,
                                      scale: Optional[float] = None):
     """XLA fallback: gather the slot's blocks into a dense view, then
     run the masked ragged reference. One extra HBM round-trip of the
@@ -89,15 +100,23 @@ def paged_decode_attention_reference(q, k_pool, v_pool, block_tables,
     v = v_pool[block_tables]
     k = k.reshape(B, maxb * bs, *k.shape[3:])
     v = v.reshape(B, maxb * bs, *v.shape[3:])
-    return ragged_decode_attention_reference(q, k, v, lengths, scale=scale)
+    return ragged_decode_attention_reference(q, k, v, lengths, starts=starts,
+                                             scale=scale)
 
 
-def _paged_kernel(lens_ref, tables_ref, q_ref, bias_ref, k_hbm, v_hbm, o_ref,
-                  k_buf, v_buf, sems, first_buf_ref, m_ref, l_ref, acc_ref,
-                  *, block_size: int, pages: int, max_blocks: int,
-                  scale: float, row_heads: int):
+def _paged_kernel(*refs, block_size: int, pages: int, max_blocks: int,
+                  scale: float, row_heads: int, windowed: bool):
     import jax.experimental.pallas as pl
     from jax.experimental.pallas import tpu as pltpu
+
+    # ``windowed``: a third prefetched scalar array, each slot's first
+    # visible position; without it the kernel is the one it was
+    if windowed:
+        lens_ref, tables_ref, starts_ref, *refs = refs
+    else:
+        lens_ref, tables_ref, *refs = refs
+    (q_ref, bias_ref, k_hbm, v_hbm, o_ref, k_buf, v_buf, sems,
+     first_buf_ref, m_ref, l_ref, acc_ref) = refs
 
     b = pl.program_id(0)
     # a page: rows (token, kv head), or (token, group of packed kv heads)
@@ -105,8 +124,16 @@ def _paged_kernel(lens_ref, tables_ref, q_ref, bias_ref, k_hbm, v_hbm, o_ref,
     chunk_len = pages * block_size
     length = lens_ref[b]
 
+    def first_page(slot):
+        """The page that holds the slot's first visible position: where
+        its walk begins."""
+        return jnp.minimum(starts_ref[slot] // block_size, max_blocks - 1)
+
     def live_pages(slot):
         n = (lens_ref[slot] + block_size - 1) // block_size
+        if windowed:
+            first = first_page(slot)
+            return jnp.clip(n - first, 1, max_blocks - first)
         return jnp.clip(n, 1, max_blocks)
 
     def chunk_copies(slot, chunk, buf, act: str):
@@ -121,7 +148,8 @@ def _paged_kernel(lens_ref, tables_ref, q_ref, bias_ref, k_hbm, v_hbm, o_ref,
 
             @pl.when(j < n_live)
             def _():
-                page = tables_ref[slot, j]
+                page = tables_ref[slot,
+                                  first_page(slot) + j if windowed else j]
                 rows = pl.ds(i * page_rows, page_rows)
                 for n, (hbm, vmem) in enumerate(((k_hbm, k_buf),
                                                  (v_hbm, v_buf))):
@@ -170,7 +198,16 @@ def _paged_kernel(lens_ref, tables_ref, q_ref, bias_ref, k_hbm, v_hbm, o_ref,
             preferred_element_type=jnp.float32)            # [H, T*Hkv]
         s = s * scale + bias_ref[...]
         row = jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
-        s = jnp.where(row < (length - c * chunk_len) * row_heads, s, NEG_INF)
+        if windowed:
+            # position of the chunk's first row; rows before the first
+            # visible position (the walk's first page only) drop too
+            at = first_page(b) * block_size + c * chunk_len
+            seen = jnp.logical_and(row >= (starts_ref[b] - at) * row_heads,
+                                   row < (length - at) * row_heads)
+            s = jnp.where(seen, s, NEG_INF)
+        else:
+            s = jnp.where(row < (length - c * chunk_len) * row_heads, s,
+                          NEG_INF)
         m_prev = m_ref[:, :1]                              # [H, 1]
         l_prev = l_ref[:, :1]
         m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
@@ -218,7 +255,7 @@ def kernel_lowers(head_dim: int, kv_heads: int) -> bool:
 @functools.partial(jax.jit,
                    static_argnames=("num_blocks", "scale", "interpret"))
 def paged_decode_attention_pallas(q, k_pool, v_pool, block_tables,
-                                  lengths, *, first_block=0,
+                                  lengths, starts=None, *, first_block=0,
                                   num_blocks: Optional[int] = None,
                                   scale: Optional[float] = None,
                                   interpret: bool = False):
@@ -257,6 +294,9 @@ def paged_decode_attention_pallas(q, k_pool, v_pool, block_tables,
     pages = max(1, min(maxb, CHUNK_ROWS // page_rows))
     chunk_rows = pages * page_rows
     lengths = lengths.astype(jnp.int32)
+    windowed = starts is not None
+    scalars = ((lengths, block_tables, starts.astype(jnp.int32)) if windowed
+               else (lengths, block_tables))
 
     # q head h attends row r of a chunk iff r's KV heads hold its own
     own = (kv_head[:, None] // pack
@@ -264,17 +304,16 @@ def paged_decode_attention_pallas(q, k_pool, v_pool, block_tables,
     bias = jnp.where(own, 0.0, NEG_INF).astype(jnp.float32)
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
+        num_scalar_prefetch=len(scalars),
         grid=(B,),
         in_specs=[
-            pl.BlockSpec((1, H, lanes), lambda b, lens, tables: (b, 0, 0)),
-            pl.BlockSpec((H, chunk_rows), lambda b, lens, tables: (0, 0)),
+            pl.BlockSpec((1, H, lanes), lambda b, *_: (b, 0, 0)),
+            pl.BlockSpec((H, chunk_rows), lambda b, *_: (0, 0)),
             # the pools stay in HBM; the kernel copies the live pages
             pl.BlockSpec(memory_space=pl.ANY),
             pl.BlockSpec(memory_space=pl.ANY),
         ],
-        out_specs=pl.BlockSpec((1, H, lanes),
-                               lambda b, lens, tables: (b, 0, 0)),
+        out_specs=pl.BlockSpec((1, H, lanes), lambda b, *_: (b, 0, 0)),
         scratch_shapes=[
             pltpu.VMEM((2, chunk_rows, lanes), k_pool.dtype),
             pltpu.VMEM((2, chunk_rows, lanes), v_pool.dtype),
@@ -294,14 +333,15 @@ def paged_decode_attention_pallas(q, k_pool, v_pool, block_tables,
     # one copy a layer.
     out = pl.pallas_call(
         functools.partial(_paged_kernel, block_size=bs, pages=pages,
-                          max_blocks=maxb, scale=scale, row_heads=row_heads),
+                          max_blocks=maxb, scale=scale, row_heads=row_heads,
+                          windowed=windowed),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B, H, lanes), q.dtype),
         # slots run in order: each starts the next one's first copies
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",)),
         interpret=interpret,
-    )(lengths, block_tables, q, bias,
+    )(*scalars, q, bias,
       k_pool.reshape(NB, page_rows, lanes),
       v_pool.reshape(NB, page_rows, lanes))
     if pack > 1:     # each q head's own lanes of its packed row
@@ -325,7 +365,7 @@ def default_impl(head_dim: int, kv_heads: int) -> str:
 
 
 def paged_decode_attention(q, k_pool, v_pool, block_tables, lengths, *,
-                           impl: str, first_block=0,
+                           impl: str, starts=None, first_block=0,
                            num_blocks: Optional[int] = None,
                            scale: Optional[float] = None):
     """One algorithm, two implementations: ``impl`` is "pallas" (the
@@ -338,13 +378,16 @@ def paged_decode_attention(q, k_pool, v_pool, block_tables, lengths, *,
     stack ``[L*NB, bs, Hkv, D]`` that ``decode_step_paged`` carries.
     Both sides read it through ``first_block + block_tables`` with no
     slice of the stack built; only the kernel's packed rows (D < 128),
-    a copy in any case, copy the window alone."""
+    a copy in any case, copy the window alone. ``starts`` [B]: a
+    sliding-window layer's first visible positions (the module's
+    docstring)."""
     if impl == "pallas":
         return paged_decode_attention_pallas(
-            q, k_pool, v_pool, block_tables, lengths,
+            q, k_pool, v_pool, block_tables, lengths, starts,
             first_block=first_block, num_blocks=num_blocks, scale=scale,
             interpret=pallas_interpret())
     if impl != "xla":
         raise ValueError(f"impl must be 'xla' or 'pallas', got {impl!r}")
     return paged_decode_attention_reference(
-        q, k_pool, v_pool, first_block + block_tables, lengths, scale=scale)
+        q, k_pool, v_pool, first_block + block_tables, lengths,
+        starts=starts, scale=scale)

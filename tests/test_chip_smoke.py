@@ -171,10 +171,11 @@ def test_paged_decode_kernel_refuses_widths_it_cannot_copy(v5e):
 
 # the serve cells' decode shapes (BENCHMARK.json, 32 slots, block_size 32):
 # table entries a slot in batch_decode (mistral-7b-v0.3-d6 at max_seq 8192),
-# batch_decode_moe (olmoe-1b-7b-d3 at 4096) and chat_mixed (mistral at 2048),
-# and each cell's query and KV heads
-CELL_SLOTS, CELL_BS, CELL_TABLES = 32, 32, (256, 128, 64)
-CELL_HEADS = {256: (32, 8), 128: (16, 16), 64: (32, 8)}
+# batch_decode_moe (olmoe-1b-7b-d3 at 4096), chat_mixed (mistral at 2048)
+# and long_decode_hybrid (mellum2-12b-a2.5b-d8 at 16384), and each cell's
+# query and KV heads
+CELL_SLOTS, CELL_BS, CELL_TABLES = 32, 32, (256, 128, 64, 512)
+CELL_HEADS = {256: (32, 8), 128: (16, 16), 64: (32, 8), 512: (32, 4)}
 
 
 @pytest.mark.parametrize("maxb", CELL_TABLES)
@@ -186,6 +187,20 @@ def test_paged_decode_kernel_lowers_at_the_cells_shapes(v5e, maxb):
     assert _mosaic(paged_decode_attention_pallas.lower(
         v5e(B, H, D), pool, pool, v5e(B, maxb, dtype=jnp.int32),
         v5e(B, dtype=jnp.int32), interpret=False))
+
+
+def test_windowed_kernel_lowers_at_the_hybrid_cells_shape(v5e):
+    """``long_decode_hybrid``'s sliding layers: the same kernel with each
+    slot's first visible position as a third prefetched scalar array;
+    the sliding kind's table is as long as the full kind's (512 entries
+    a slot, those behind the window dead)."""
+    from ray_tpu.ops.paged_attention import paged_decode_attention_pallas
+
+    B, (H, Hkv), D, bs, maxb = CELL_SLOTS, CELL_HEADS[512], 128, CELL_BS, 512
+    pool = v5e(B * 50 + 1, bs, Hkv, D)       # a window's worth a slot
+    assert _mosaic(paged_decode_attention_pallas.lower(
+        v5e(B, H, D), pool, pool, v5e(B, maxb, dtype=jnp.int32),
+        v5e(B, dtype=jnp.int32), v5e(B, dtype=jnp.int32), interpret=False))
 
 
 def _as_on_the_chip(monkeypatch):
@@ -291,6 +306,54 @@ def test_olmoe_cell_decode_program_fits_the_v5e(v5e, placed, monkeypatch):
     experts = sum(params["layers"][k].size * 2
                   for k in ("e_gate", "e_up", "e_down"))
     assert mem.temp_size_in_bytes < experts / L * 1.05
+
+
+def test_hybrid_cell_decode_program_fits_the_v5e(v5e, placed, monkeypatch):
+    """``mellum2-12b-a2.5b-d8.long_decode_hybrid``'s decode program as
+    the engine jits it: 32 slots x 16,384, depth 8 (S S S F twice), all
+    64 experts, a K/V pool a kind as one stack (2 full layers of 16,385
+    blocks, 6 sliding layers of 1,601) and a table a kind. The v5e's
+    compiler takes it at 10.41 GiB of 15.75 (9.66 of arguments: 7.07
+    weights + 2.59 of pools; 0.74 of temporaries, one layer's three
+    expert stacks); ONE pool for all eight layers would hold 8.0 GiB
+    of K/V where the two hold 2.59, and with weights and temporaries
+    pass the chip's 15.75."""
+    from benchmark import run as harness
+    from benchmark.builders import mellum
+    from ray_tpu.llm.paged_cache import window_blocks_per_slot
+
+    _as_on_the_chip(monkeypatch)
+    cfg = harness.load_json(harness.ROOT,
+                            "benchmark/configs/mellum2-12b-a2.5b-d8.json")
+    B, bs, maxb, L, E = CELL_SLOTS, CELL_BS, 512, 8, 64
+    model = mellum.build_model(cfg, maxb * bs)
+    assert model.paged_decode_impl() == "pallas"
+    assert model.layer_kinds == (1, 1, 1, 0) * 2
+    full = B * maxb
+    window = B * window_blocks_per_slot(cfg["sliding_window"], bs, 512)
+    assert window == B * 50
+
+    def step(params, tokens, pool, tables, offsets, load):
+        logits, pool, extras = model.decode_step_paged_counted(
+            params, tokens, pool, tables, offsets, tables[0][:, 0] != full)
+        return logits, pool, load + extras["load"]
+
+    pool = jax.eval_shape(lambda: model.init_kv_pools(
+        (full + 1, window + 1), bs))
+    assert pool["k"].shape[0] == 2 * (full + 1) + 6 * (window + 1)
+    compiled = jax.jit(step, donate_argnums=(2,)).lower(
+        placed(_engine_params(model)), v5e(B, dtype=jnp.int32), placed(pool),
+        v5e(2, B, maxb, dtype=jnp.int32), v5e(B, dtype=jnp.int32),
+        v5e(L, E, dtype=jnp.int32)).compile()
+    assert compiled.as_text().count("tpu_custom_call") >= 4
+    mem = compiled.memory_analysis()
+    total = (mem.argument_size_in_bytes + mem.temp_size_in_bytes
+             + mem.output_size_in_bytes - mem.alias_size_in_bytes)
+    assert total < 15.75 * 2**30
+    # the pool is written in place: nothing pool-sized among the
+    # temporaries, which are one layer's expert stacks and no more
+    one_layer_experts = 3 * E * 2304 * 896 * 2
+    assert mem.temp_size_in_bytes < one_layer_experts * 1.05
 
 
 def test_smokes_decode_program_keeps_its_pool_in_place_on_the_v5e(

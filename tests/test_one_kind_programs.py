@@ -1,0 +1,76 @@
+"""A model with ONE kind of layer is served by the programs it was served
+by before layer kinds existed (PR 32): the StableHLO of an engine's five
+programs at the benchmark configurations' debug widths, hashed, against
+the hashes the parent of PR 32 (c95a537) gave with this container's jax.
+
+A PR that changes what these programs compute changes a hash, and says
+in ``CHANGES.md`` which operation and why, and writes the new hash here.
+A model with kinds has programs of its own and is not held to these.
+"""
+
+import hashlib
+import importlib
+
+import jax
+import jax.numpy as jnp
+import pytest
+
+from benchmark import run as harness
+from ray_tpu.llm.engine import ContinuousBatchingEngine
+
+# sha256[:16] of ``lowered.as_text()``, computed on c95a537
+PARENT = {
+    "mistral-7b-v0.3-d6": {
+        "decode": "e05ab974c4792cde", "prefill": "8d7bc32d9dda3104",
+        "insert": "1b106dfa26607af4", "gather": "c3d4dde8973ad705",
+        "prefill_prefix": "625968f268b7e03b"},
+    "olmoe-1b-7b-d3": {
+        "decode": "33f851cce56d2734", "prefill": "3806801bc4a4887c",
+        "insert": "47860a4b15fc27e3", "gather": "58895b3540c687ed",
+        "prefill_prefix": "da9c8600e9ee52c6"},
+}
+
+
+def lowered_programs(name: str) -> dict:
+    cfg = harness.load_json(harness.ROOT, f"benchmark/configs/{name}.json")
+    builder = importlib.import_module("benchmark.builders." + cfg["builder"])
+    model = builder.build_model({**cfg, **cfg["tiny_cpu"]}, 128)
+    params = jax.eval_shape(
+        lambda key: model.serving_params(model.init(key)), jax.random.key(0))
+    eng = ContinuousBatchingEngine(
+        model, jax.tree.map(lambda a: jnp.zeros(a.shape, a.dtype), params),
+        max_slots=4, max_seq=128, prefill_buckets=(16, 32), block_size=8)
+    assert eng.window is None and model.layer_kinds is None
+
+    def S(*shape):
+        return jax.ShapeDtypeStruct(shape, jnp.int32)
+
+    pool = jax.eval_shape(lambda: eng.kv)
+    decode = [params, S(4), pool, S(4, eng.blocks_per_slot), S(4)]
+    if eng._ffn_counts is not None:
+        decode.append(S(*eng._ffn_counts[0].shape))
+    prefix = jax.eval_shape(lambda: model.init_kv_cache(1, 32))
+    return {
+        "decode": eng._decode.lower(*decode),
+        "prefill": eng._prefill.lower(params, S(2, 32), S(2)),
+        "insert": eng._insert.lower(
+            pool, jax.eval_shape(lambda: model.init_kv_cache(2, 32)), S(8)),
+        "gather": eng._gather.lower(pool, S(1, 4)),
+        "prefill_prefix": eng._prefill_prefix.lower(
+            params, S(1, 16), prefix["k"], prefix["v"], S(1), S(1)),
+    }
+
+
+@pytest.fixture(scope="module", params=sorted(PARENT))
+def programs(request):
+    return request.param, lowered_programs(request.param)
+
+
+@pytest.mark.parametrize("program", sorted(PARENT["olmoe-1b-7b-d3"]))
+def test_one_kind_program_is_the_parents(programs, program):
+    name, lowered = programs
+    text = lowered[program].as_text()
+    assert hashlib.sha256(text.encode()).hexdigest()[:16] \
+        == PARENT[name][program], (
+            f"{name}'s {program} program is no longer the one c95a537 "
+            f"lowered: say in CHANGES.md which operation changed and why")
