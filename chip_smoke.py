@@ -155,6 +155,19 @@ def i32(*shape):
     return jax.ShapeDtypeStruct(shape, jnp.int32)
 
 
+def decode_inputs(eng) -> tuple:
+    """What the engine's decode program takes after the parameters:
+    tokens, pool, tables, offsets, temperatures, top-ks, the key and the
+    expert load (None for a dense model)."""
+    import jax
+    import jax.numpy as jnp
+
+    n = eng.max_slots
+    return (i32(n), eng.kv, i32(n, eng.blocks_per_slot), i32(n),
+            jax.ShapeDtypeStruct((n,), jnp.float32), i32(n), eng._rng_key,
+            eng._ffn_counts and eng._ffn_counts[0])
+
+
 def program_facts(jitted, *args, **static) -> dict:
     """What XLA built for ``jitted`` at these shapes, read from the
     compiled text so nobody assumes which attention ran: whether it holds
@@ -334,9 +347,7 @@ def pool_phase(rep: Report, sz: dict) -> None:
         model, jax.jit(model.init)(jax.random.key(0)),
         max_slots=POOL_SLOTS, max_seq=MAX_SEQ)
 
-    facts = program_facts(
-        eng._decode, eng.params, i32(POOL_SLOTS), eng.kv,
-        i32(POOL_SLOTS, eng.blocks_per_slot), i32(POOL_SLOTS))
+    facts = program_facts(eng._decode, eng.params, *decode_inputs(eng))
     pool_bytes = sum(a.nbytes for a in jax.tree.leaves(eng.kv))
     smallest = min(eng.params["layers"][k].nbytes
                    for k in model.MATMUL_LAYER_LEAVES)
@@ -485,9 +496,8 @@ def check_engine(rep: Report, eng, impl: str) -> None:
     prefix_kv = jax.ShapeDtypeStruct((L, 1, 4 * bs, Hkv, D),
                                      eng.kv["k"].dtype)
     programs = {
-        "decode": program_facts(
-            eng._decode, eng.params, i32(MAX_SLOTS), eng.kv,
-            i32(MAX_SLOTS, eng.blocks_per_slot), i32(MAX_SLOTS)),
+        "decode": program_facts(eng._decode, eng.params,
+                                *decode_inputs(eng)),
         "prefill[1x64]": program_facts(
             eng._prefill, eng.params, i32(1, 64), i32(1)),
         "prefill_prefix[1x32 after 128]": program_facts(

@@ -21,8 +21,10 @@ is the in-tree TPU-native equivalent (BASELINE.md config 5):
 - prefill at bucketed lengths (static shapes → one jit specialization
   per bucket, no recompilation churn), scattered into pool blocks;
 - decode is ONE jitted step for all slots every iteration (inactive
-  slots masked), block tables riding along as a tiny int32 array;
-  sampling on-device, only B int32s return to host per step. The pool
+  slots masked), block tables riding along as a tiny int32 array; the
+  sampler is that program's tail and does only what the batch's
+  sampling parameters ask for (a greedy batch: one argmax), so only B
+  int32s return to host per step. The pool
   is DONATED to that step and lives through it whole and in place: the
   program carries it through its layer scan as one stack addressed by
   layer (block ``p`` of layer ``l`` is block ``l*NB + p``), writes each
@@ -255,13 +257,16 @@ class ContinuousBatchingEngine:
         # and are sent again only when the host's copy changed (``None``
         # = stale): what stands between a step's read-back and the next
         # dispatch is host time the device idles in. ``_dev_tokens`` is
-        # the last sample's own output until a slot is activated;
-        # ``_last_tokens`` is the host's copy, kept by ``_emit``.
+        # the last step's own output until a slot is activated;
+        # ``_last_tokens`` is the host's copy, kept by ``_emit``. The
+        # sampler's key never has a host copy: every program that
+        # samples returns the next one (``_rng_key``).
         self._last_tokens = np.zeros(max_slots, np.int32)
         self._dev_tokens = None
         self._dev_tables = None
         self._dev_offsets = None            # sent a step ahead, see there
         self._dev_sampling = None           # (temperatures, top-ks)
+        self._sampling_asked = (False, False)   # any of each above 0
         self.waiting: "deque[Request]" = deque()
         # popped from ``waiting`` but not yet in a slot: where a failed
         # prefill finds the requests it was carrying
@@ -290,14 +295,12 @@ class ContinuousBatchingEngine:
         load_shape = model.ffn_load_shape()
         if load_shape is None:
             self._ffn_counts = None
-            self._decode = jax.jit(model.decode_step_paged,
-                                   donate_argnums=(2,))
         else:
             self._ffn_counts = (jnp.zeros(load_shape, jnp.int32), 0)
             self._ffn_rows_per_slot = (model.cfg.expert_top_k
                                        * model.cfg.n_layers)
-            self._decode = jax.jit(self._decode_step_paged_counted,
-                                   donate_argnums=(2,))
+        # ONE program a decode step: the model's step and the sampler
+        self._decode = jax.jit(self._decode_step_paged, donate_argnums=(2,))
         self._prefill = jax.jit(self._prefill_impl)
         self._prefill_prefix = jax.jit(model.prefill_with_prefix)
         self._insert = jax.jit(
@@ -305,6 +308,7 @@ class ContinuousBatchingEngine:
             else self._insert_kinds_impl, donate_argnums=(0,))
         self._gather = jax.jit(self._gather_impl if self.window is None
                                else self._gather_kinds_impl)
+        # the prefill's first token: the decode program's sampler, alone
         self._sample = jax.jit(self._sample_impl)
 
         # Every key exists from here on (another thread copies the dict
@@ -314,7 +318,12 @@ class ContinuousBatchingEngine:
         # before it takes the lock; ``cpu_host_s`` is this thread's CPU
         # time in ``step()`` outside the phases that wait for the device.
         self._stats = {"requests": 0, "tokens_generated": 0,
-                      "decode_steps": 0, "prefills": 0,
+                      "decode_steps": 0,
+                      # decode steps whose batch held a row with
+                      # temperature > 0 / top_k > 0: the steps whose
+                      # program took the sampler's draw / its sort
+                      "decode_steps_sampled": 0, "decode_steps_topk": 0,
+                      "prefills": 0,
                       "prefix_prefills": 0, "prefix_tokens_reused": 0,
                       "preemptions": 0,
                       "admitted": 0, "queue_wait_s": 0.0,
@@ -362,16 +371,26 @@ class ContinuousBatchingEngine:
         return self._stats
 
     # -- jitted internals --------------------------------------------------
-    def _decode_step_paged_counted(self, params, tokens, pool, block_tables,
-                                   offsets, ffn_load):
-        """The model's decode step, with its FFN's per-expert rows of
-        the LIVE slots (an idle slot's table points at the scratch
-        block) added to ``ffn_load``."""
-        full = block_tables if block_tables.ndim == 2 else block_tables[FULL]
-        live = full[:, 0] != self.num_blocks
-        logits, pool, extras = self.model.decode_step_paged_counted(
-            params, tokens, pool, block_tables, offsets, live)
-        return logits, pool, ffn_load + extras["load"]
+    def _decode_step_paged(self, params, tokens, pool, block_tables, offsets,
+                           temps, top_ks, key, ffn_load):
+        """One decode step as ONE program: the model's step, then the
+        sampler on its logits, which never leave the program. Returns
+        the next tokens [B], the pool, the next key and, for an expert
+        model (``ffn_load`` not None), that array with the FFN's
+        per-expert rows of the LIVE slots added (an idle slot's table
+        points at the scratch block)."""
+        if ffn_load is None:
+            logits, pool = self.model.decode_step_paged(
+                params, tokens, pool, block_tables, offsets)
+        else:
+            full = (block_tables if block_tables.ndim == 2
+                    else block_tables[FULL])
+            live = full[:, 0] != self.num_blocks
+            logits, pool, extras = self.model.decode_step_paged_counted(
+                params, tokens, pool, block_tables, offsets, live)
+            ffn_load = ffn_load + extras["load"]
+        tokens, key = self._sample_impl(logits, temps, top_ks, key)
+        return tokens, pool, key, ffn_load
 
     def _prefill_impl(self, params, tokens, lengths):
         """BATCHED prefill: tokens [N, Tb], lengths [N]; returns each
@@ -437,22 +456,35 @@ class ContinuousBatchingEngine:
                 v.reshape(L, N, Pb * bs, *v.shape[4:]))
 
     def _sample_impl(self, logits, temps, top_ks, key):
-        """logits [B, V] → tokens [B] on-device."""
+        """logits [B, V] → (tokens [B], the next key), on the device.
+        Only the work the batch asks for: the predicates are scalars of
+        the whole batch, so each ``cond`` is a real conditional and an
+        all-greedy batch costs one ``argmax``. A batch with a row of
+        temperature > 0 splits the key and draws; one that also has a
+        row of top-k > 0 sorts. Row by row the arithmetic does not
+        depend on what the other rows asked for."""
         B, V = logits.shape
-        keys = jax.random.split(key, B)
         greedy = jnp.argmax(logits, axis=-1)
 
-        def sample_row(lg, temp, tk, k):
-            scaled = lg / jnp.maximum(temp, 1e-6)
-            # top-k masking with static k = full V (mask below threshold)
-            def apply_topk(s):
-                kth = jnp.sort(s)[V - jnp.maximum(tk, 1)]
-                return jnp.where(s >= kth, s, -1e30)
-            scaled = jax.lax.cond(tk > 0, apply_topk, lambda s: s, scaled)
-            return jax.random.categorical(k, scaled)
+        def mask_below_kth(scaled):
+            # top-k with static k = full V: a row with top-k keeps what
+            # reaches its k-th largest (ties too), another row all of it
+            def row(s, tk):
+                kth = jnp.sort(s)[V - jnp.clip(tk, 1, V)]
+                return jnp.where((tk <= 0) | (s >= kth), s, -1e30)
+            return jax.vmap(row)(scaled, top_ks)
 
-        sampled = jax.vmap(sample_row)(logits, temps, top_ks, keys)
-        return jnp.where(temps <= 0.0, greedy, sampled)
+        def draw(key):
+            key, sub = jax.random.split(key)
+            scaled = logits / jnp.maximum(temps, 1e-6)[:, None]
+            scaled = jax.lax.cond(jnp.any(top_ks > 0), mask_below_kth,
+                                  lambda s: s, scaled)
+            sampled = jax.vmap(jax.random.categorical)(
+                jax.random.split(sub, B), scaled)
+            return jnp.where(temps <= 0.0, greedy, sampled), key
+
+        return jax.lax.cond(jnp.any(temps > 0.0), draw,
+                            lambda key: (greedy, key), key)
 
     # -- public API --------------------------------------------------------
     def submit(self, prompt_tokens: List[int],
@@ -803,14 +835,14 @@ class ContinuousBatchingEngine:
         self._admit_order.append(slot)
 
     def _sample_batch(self, logits, reqs: List[Request], n_pad: int):
-        self._rng_key, sub = jax.random.split(self._rng_key)
         temps = np.zeros(n_pad, np.float32)
         top_ks = np.zeros(n_pad, np.int32)
         for row, req in enumerate(reqs):
             temps[row] = req.sampling.temperature
             top_ks[row] = req.sampling.top_k
-        return np.asarray(self._sample(
-            logits, jnp.asarray(temps), jnp.asarray(top_ks), sub))
+        toks, self._rng_key = self._sample(
+            logits, jnp.asarray(temps), jnp.asarray(top_ks), self._rng_key)
+        return np.asarray(toks)
 
     # -- decode ------------------------------------------------------------
     def _preempt(self, slot: int) -> None:
@@ -836,7 +868,9 @@ class ContinuousBatchingEngine:
         self.slots[slot] = None
         self.allocs[slot] = None
         self.offsets[slot] = 0
-        self._dev_tables = self._dev_offsets = None
+        # (the sampling parameters too: a batch whose last sampled row
+        # left decodes greedily again, without the draw)
+        self._dev_tables = self._dev_offsets = self._dev_sampling = None
         self._admit_order.remove(slot)
 
     def _grow_or_preempt(self) -> None:
@@ -936,7 +970,7 @@ class ContinuousBatchingEngine:
         return True
 
     def _dispatch_decode(self, ahead: bool):
-        """Enqueue one decode step and its sampling: ``(tokens on the
+        """Enqueue one decode step, sampling included: ``(tokens on the
         device, active slots)``, or None with no active slot. ``ahead``:
         the step before is still in flight, so every active slot stands
         one token further than the host's ``offsets`` say."""
@@ -946,10 +980,6 @@ class ContinuousBatchingEngine:
             active = [i for i, r in enumerate(self.slots) if r is not None]
         if not active:
             return None
-        # host arrays and dispatch alternate, in the order that puts the
-        # decode program on the device first: what the host still does
-        # for sampling then runs under it (building everything before
-        # the first dispatch read 1.3 % fewer tokens/s: PERF.md, PR 24)
         with _Phase(self, "engine.host_arrays", "t_host_arrays_s"):
             # ``jnp.array`` copies: these outlive the step, and the
             # host's arrays change in place under them
@@ -961,23 +991,6 @@ class ContinuousBatchingEngine:
                     else np.stack([self._tables, self._tables_win]))
             if self._dev_offsets is None:
                 self._dev_offsets = jnp.array(self.offsets)
-            offsets = self._dev_offsets
-        # dispatch only: the calls return before the device is done
-        with _Phase(self, "engine.decode_enqueue", "t_enqueue_s"):
-            if self._ffn_counts is None:
-                logits, self.kv = self._decode(
-                    self.params, self._dev_tokens, self.kv,
-                    self._dev_tables, offsets)
-            else:
-                load, expected = self._ffn_counts
-                logits, self.kv, load = self._decode(
-                    self.params, self._dev_tokens, self.kv,
-                    self._dev_tables, offsets, load)
-                self._ffn_counts = (
-                    load, expected + len(active) * self._ffn_rows_per_slot)
-            del offsets
-        with _Phase(self, "engine.host_arrays", "t_host_arrays_s"):
-            self._rng_key, sub = jax.random.split(self._rng_key)
             if self._dev_sampling is None:
                 temps = np.zeros(self.max_slots, np.float32)
                 top_ks = np.zeros(self.max_slots, np.int32)
@@ -986,6 +999,19 @@ class ContinuousBatchingEngine:
                     temps[i] = sampling.temperature
                     top_ks[i] = sampling.top_k
                 self._dev_sampling = (jnp.asarray(temps), jnp.asarray(top_ks))
+                # what the program's conditionals will find
+                self._sampling_asked = (bool((temps > 0).any()),
+                                        bool((top_ks > 0).any()))
+        # dispatch only: the call returns before the device is done
+        with _Phase(self, "engine.decode_enqueue", "t_enqueue_s"):
+            load, expected = self._ffn_counts or (None, 0)
+            self._dev_tokens, self.kv, self._rng_key, load = self._decode(
+                self.params, self._dev_tokens, self.kv, self._dev_tables,
+                self._dev_offsets, *self._dev_sampling, self._rng_key, load)
+            if load is not None:
+                self._ffn_counts = (
+                    load, expected + len(active) * self._ffn_rows_per_slot)
+        with _Phase(self, "engine.host_arrays", "t_host_arrays_s"):
             # the NEXT step's offsets go now, under the running program:
             # every active slot will have advanced by one, unless a slot
             # ends, is preempted or comes in, which drops them. The next
@@ -995,9 +1021,8 @@ class ContinuousBatchingEngine:
             following = at.copy()
             following[active] += 1
             self._dev_offsets = jnp.asarray(following)
-        with _Phase(self, "engine.decode_enqueue", "t_enqueue_s"):
-            toks = self._dev_tokens = self._sample(
-                logits, *self._dev_sampling, sub)
+        self._stats["decode_steps_sampled"] += self._sampling_asked[0]
+        self._stats["decode_steps_topk"] += self._sampling_asked[1]
         # what the dispatched program's attention reads (the kernel:
         # ceil((offset + 1) / bs) blocks a slot) of what its tables hold;
         # counted under the running program, in no phase's span
@@ -1011,7 +1036,7 @@ class ContinuousBatchingEngine:
             first = np.maximum(at[active] - self.window + 1, 0) // bs
             self._stats["decode_kv_blocks_live_window"] += int(
                 (at[active] // bs - first + 1).sum())
-        return toks, active
+        return self._dev_tokens, active
 
     def _emit(self, slot: int, tok: int) -> None:
         """Book one sampled token: the request's output, the stop test,

@@ -129,6 +129,24 @@ def _engine_params(model):
                           jax.random.key(0))
 
 
+def _engine_decode(model, num_blocks):
+    """The engine's decode program (the model's step and the sampler,
+    ``_decode_step_paged``) of an engine that is never built: building
+    one allocates its pool, and a described chip holds no array."""
+    from ray_tpu.llm.engine import ContinuousBatchingEngine
+
+    eng = object.__new__(ContinuousBatchingEngine)
+    eng.model, eng.num_blocks = model, num_blocks
+    return jax.jit(eng._decode_step_paged, donate_argnums=(2,))
+
+
+def _sampling(v5e, B):
+    """Temperatures, top-ks and the key, as the decode program takes
+    them after the offsets."""
+    return (v5e(B, dtype=jnp.float32), v5e(B, dtype=jnp.int32),
+            jax.eval_shape(lambda: jax.random.key(0)))
+
+
 def test_paged_decode_kernel_lowers_for_v5e(v5e, smoke_sizes):
     from ray_tpu.ops.paged_attention import paged_decode_attention_pallas
 
@@ -216,7 +234,8 @@ def _as_on_the_chip(monkeypatch):
 @pytest.mark.parametrize("maxb", CELL_TABLES)
 def test_decode_program_holds_the_kernel_on_a_tpu_backend(
         v5e, placed, monkeypatch, maxb):
-    """A whole ``decode_step_paged`` at the Mistral cells' widths (depth
+    """The engine's decode program (``decode_step_paged`` and the sampler
+    over the 32,768-token vocabulary) at the Mistral cells' widths (depth
     1; at 128 entries too, the expert cell's table, whose own program is
     ``test_olmoe_cell_decode_program_fits_the_v5e``), on the parameters
     as an engine holds them, no ``decode_attention`` set. This process's
@@ -237,12 +256,12 @@ def test_decode_program_holds_the_kernel_on_a_tpu_backend(
             v5e(B, dtype=jnp.int32),
             placed(jax.eval_shape(
                 lambda: model.init_kv_pool(B * maxb + 1, bs))),
-            v5e(B, maxb, dtype=jnp.int32), v5e(B, dtype=jnp.int32))
-    assert _mosaic(jax.jit(model.decode_step_paged,
-                           donate_argnums=(2,)).lower(*args))
+            v5e(B, maxb, dtype=jnp.int32), v5e(B, dtype=jnp.int32),
+            *_sampling(v5e, B), None)
+    assert _mosaic(_engine_decode(model, B * maxb).lower(*args))
     # forced to the reference, the same program holds no kernel
     ref = LlamaModel(dataclasses.replace(model.cfg, decode_attention="xla"))
-    assert not _mosaic(jax.jit(ref.decode_step_paged).lower(*args))
+    assert not _mosaic(_engine_decode(ref, B * maxb).lower(*args))
 
 
 def test_llama3_1b_decode_program_holds_the_kernel(v5e, placed, monkeypatch,
@@ -286,17 +305,12 @@ def test_olmoe_cell_decode_program_fits_the_v5e(v5e, placed, monkeypatch):
     assert model.paged_decode_impl() == "pallas"
     assert model.ffn_load_shape() == (L, E)
 
-    def step(params, tokens, pool, tables, offsets, load):
-        logits, pool, extras = model.decode_step_paged_counted(
-            params, tokens, pool, tables, offsets, tables[:, 0] != B * maxb)
-        return logits, pool, load + extras["load"]
-
     params = placed(_engine_params(model))
-    compiled = jax.jit(step, donate_argnums=(2,)).lower(
+    compiled = _engine_decode(model, B * maxb).lower(
         params, v5e(B, dtype=jnp.int32),
         placed(jax.eval_shape(lambda: model.init_kv_pool(B * maxb + 1, bs))),
         v5e(B, maxb, dtype=jnp.int32), v5e(B, dtype=jnp.int32),
-        v5e(L, E, dtype=jnp.int32)).compile()
+        *_sampling(v5e, B), v5e(L, E, dtype=jnp.int32)).compile()
     # one attention kernel + three grouped matmuls in the layer scan
     assert compiled.as_text().count("tpu_custom_call") >= 4
     mem = compiled.memory_analysis()
@@ -333,18 +347,13 @@ def test_hybrid_cell_decode_program_fits_the_v5e(v5e, placed, monkeypatch):
     window = B * window_blocks_per_slot(cfg["sliding_window"], bs, 512)
     assert window == B * 50
 
-    def step(params, tokens, pool, tables, offsets, load):
-        logits, pool, extras = model.decode_step_paged_counted(
-            params, tokens, pool, tables, offsets, tables[0][:, 0] != full)
-        return logits, pool, load + extras["load"]
-
     pool = jax.eval_shape(lambda: model.init_kv_pools(
         (full + 1, window + 1), bs))
     assert pool["k"].shape[0] == 2 * (full + 1) + 6 * (window + 1)
-    compiled = jax.jit(step, donate_argnums=(2,)).lower(
+    compiled = _engine_decode(model, full).lower(
         placed(_engine_params(model)), v5e(B, dtype=jnp.int32), placed(pool),
         v5e(2, B, maxb, dtype=jnp.int32), v5e(B, dtype=jnp.int32),
-        v5e(L, E, dtype=jnp.int32)).compile()
+        *_sampling(v5e, B), v5e(L, E, dtype=jnp.int32)).compile()
     assert compiled.as_text().count("tpu_custom_call") >= 4
     mem = compiled.memory_analysis()
     total = (mem.argument_size_in_bytes + mem.temp_size_in_bytes
@@ -375,9 +384,10 @@ def test_smokes_decode_program_keeps_its_pool_in_place_on_the_v5e(
 
     pool = placed(jax.eval_shape(lambda: model.init_kv_pool(B * maxb + 1, bs)))
     def temporaries(params):
-        return jax.jit(model.decode_step_paged, donate_argnums=(2,)).lower(
+        return _engine_decode(model, B * maxb).lower(
             placed(params), v5e(B, dtype=jnp.int32), pool,
-            v5e(B, maxb, dtype=jnp.int32), v5e(B, dtype=jnp.int32)
+            v5e(B, maxb, dtype=jnp.int32), v5e(B, dtype=jnp.int32),
+            *_sampling(v5e, B), None
         ).compile().memory_analysis().temp_size_in_bytes
 
     held = _engine_params(model)

@@ -105,7 +105,11 @@ def test_spans_are_the_eight_phases_siblings_on_one_thread(tiny_model,
     # at once where the next step is already on the device or the last
     # slot ended, else under the next dispatch or before an admission
     assert names.count("engine.deliver") == steps
-    assert all(names[i - 1] in ("engine.decode_enqueue", "engine.emit")
+    # (a dispatch ends with the next step's offsets, sent under the
+    # program: the second ``engine.host_arrays`` span)
+    assert all(names[i - 1] in ("engine.host_arrays", "engine.emit")
+               and names[i - 2] in ("engine.decode_enqueue",
+                                    "engine.sample_readback")
                for i, n in enumerate(names) if n == "engine.deliver")
     attrs = [a for n, a, _, _ in driven if n == "engine.prefill"]
     assert {"bucket": 64, "n": 3, "n_pad": 4} in attrs
@@ -163,7 +167,7 @@ def test_decode_inputs_stay_on_the_device_until_the_hosts_copy_changes(
         tiny_model):
     """Tokens, tables and sampling parameters are sent when a slot is
     activated, freed or grows a block, and otherwise reused: the last
-    sample's output IS the next step's tokens."""
+    step's output IS the next step's tokens."""
     eng = make_engine(tiny_model)                       # block_size 8
     # (a stop token that is never sampled: the engine reads each step
     # before it dispatches the next, so the host's numbers are current)
@@ -187,12 +191,14 @@ def test_decode_inputs_stay_on_the_device_until_the_hosts_copy_changes(
         sent["sampling"] += eng._dev_sampling is not seen[1]
         seen = (eng._dev_tables, eng._dev_sampling)
         assert (eng._dev_offsets is None) == (k == 13)   # the slot ended
-        # the step read the sample's own output unless a slot came in
+        # the step read the step before's own output unless a slot came in
         assert (toks is not None) or k == 12
         assert list(eng._dev_tokens.shape) == [eng.max_slots]
     assert grown == 3                  # 19 -> 49 tokens cached, blocks of 8
-    # first send, three grown blocks, one activation, one freed slot
-    assert sent == {"tables": 1 + grown + 2, "sampling": 2}
+    # first send, three grown blocks, one activation, one freed slot;
+    # the sampling parameters: first send, the activation, and dropped
+    # then sent again for the freed slot
+    assert sent == {"tables": 1 + grown + 2, "sampling": 4}
     assert int(eng._last_tokens[0]) == first.output[-1]
     assert eng._decode._cache_size() == 1   # host-sent or device: one program
 
@@ -357,7 +363,9 @@ def test_program_names_the_benchmark_readers_match_are_pinned(tiny_model):
     B, nb = eng.max_slots, eng.blocks_per_slot
     assert module_name(
         eng._decode, params, jnp.zeros(B, i32), eng.kv,
-        jnp.zeros((B, nb), i32), jnp.zeros(B, i32)) == "jit_decode_step_paged"
+        jnp.zeros((B, nb), i32), jnp.zeros(B, i32), jnp.zeros(B),
+        jnp.zeros(B, i32), jax.random.key(0), None
+    ) == "jit__decode_step_paged"
     bucket = module_name(eng._prefill, params, jnp.zeros((1, 16), i32),
                          jnp.ones(1, i32))
     pk, pv = eng._gather(eng.kv, jnp.zeros((1, 1), i32))

@@ -6,6 +6,12 @@ the hashes the parent of PR 32 (c95a537) gave with this container's jax.
 A PR that changes what these programs compute changes a hash, and says
 in ``CHANGES.md`` which operation and why, and writes the new hash here.
 A model with kinds has programs of its own and is not held to these.
+
+PR 33: the two ``decode`` hashes are its own. The sampler joined the
+decode program (an ``argmax`` over the logits; the key split, the top-k
+sort and the categorical draw inside conditionals of batch-level
+predicates) and the logits no longer leave it; the model's part of the
+program and the other four programs are the parent's.
 """
 
 import hashlib
@@ -18,14 +24,15 @@ import pytest
 from benchmark import run as harness
 from ray_tpu.llm.engine import ContinuousBatchingEngine
 
-# sha256[:16] of ``lowered.as_text()``, computed on c95a537
+# sha256[:16] of ``lowered.as_text()``, computed on c95a537 (``decode``: on
+# PR 33's tree)
 PARENT = {
     "mistral-7b-v0.3-d6": {
-        "decode": "e05ab974c4792cde", "prefill": "8d7bc32d9dda3104",
+        "decode": "20fa90ecbf267886", "prefill": "8d7bc32d9dda3104",
         "insert": "1b106dfa26607af4", "gather": "c3d4dde8973ad705",
         "prefill_prefix": "625968f268b7e03b"},
     "olmoe-1b-7b-d3": {
-        "decode": "33f851cce56d2734", "prefill": "3806801bc4a4887c",
+        "decode": "da59bfd928507fc8", "prefill": "3806801bc4a4887c",
         "insert": "47860a4b15fc27e3", "gather": "58895b3540c687ed",
         "prefill_prefix": "da9c8600e9ee52c6"},
 }
@@ -46,9 +53,10 @@ def lowered_programs(name: str) -> dict:
         return jax.ShapeDtypeStruct(shape, jnp.int32)
 
     pool = jax.eval_shape(lambda: eng.kv)
-    decode = [params, S(4), pool, S(4, eng.blocks_per_slot), S(4)]
-    if eng._ffn_counts is not None:
-        decode.append(S(*eng._ffn_counts[0].shape))
+    decode = [params, S(4), pool, S(4, eng.blocks_per_slot), S(4),
+              jax.ShapeDtypeStruct((4,), jnp.float32), S(4),
+              jax.eval_shape(lambda: jax.random.key(0)),
+              eng._ffn_counts and S(*eng._ffn_counts[0].shape)]
     prefix = jax.eval_shape(lambda: model.init_kv_cache(1, 32))
     return {
         "decode": eng._decode.lower(*decode),

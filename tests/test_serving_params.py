@@ -224,11 +224,11 @@ def n_matmul_weights(name) -> int:
 
 
 def lower_decode(eng, params):
-    args = [params, jnp.zeros((B,), I32), eng.kv,
-            jnp.zeros((B, eng.blocks_per_slot), I32), jnp.zeros((B,), I32)]
-    if eng._ffn_counts is not None:
-        args.append(eng._ffn_counts[0])
-    return eng._decode.lower(*args)
+    return eng._decode.lower(
+        params, jnp.zeros((B,), I32), eng.kv,
+        jnp.zeros((B, eng.blocks_per_slot), I32), jnp.zeros((B,), I32),
+        jnp.zeros((B,), jnp.float32), jnp.zeros((B,), I32),
+        jax.random.key(0), eng._ffn_counts and eng._ffn_counts[0])
 
 
 def test_lowered_decode_program_converts_no_weight(built):
