@@ -37,6 +37,12 @@ is the in-tree TPU-native equivalent (BASELINE.md config 5):
   is freed as the slot's window leaves it (``llm/paged_cache.py``,
   docs/serving.md). A model with one kind is served as it always was:
   the same pool, tables, counters and programs;
+- an EVA model (``model.eva``: an exact window that resets, chunk
+  summaries of everything before it) gets a pool and a table a PART:
+  the summary part grows one block per ``block_size * chunk`` positions,
+  the exact part holds the slot's current window and gives ALL its
+  blocks back when the slot crosses into the next (``llm/paged_cache.py``,
+  docs/serving.md);
 - the parameters are held in the dtype the programs compute in: the
   matmul weights cast once at construction (``model.serving_params``),
   not by every program that reads them; norms and an expert model's
@@ -64,9 +70,10 @@ import numpy as np
 
 from ray_tpu.llm.paged_cache import (BlockPool, SlotAllocation,
                                      WindowAllocation, allocate_slot,
-                                     ensure_capacity, first_window_block,
-                                     seal_prompt_blocks, seal_window_blocks,
-                                     slide_window, window_blocks_per_slot)
+                                     ensure_capacity, eva_window_block,
+                                     first_window_block, seal_prompt_blocks,
+                                     seal_window_blocks, slide_window,
+                                     window_blocks_per_slot)
 from ray_tpu.models.llama import FULL, SLIDING
 
 
@@ -215,11 +222,19 @@ class ContinuousBatchingEngine:
                     f"prefill bucket {b} not a multiple of "
                     f"block_size {block_size}")
         self.block_size = block_size
-        self.blocks_per_slot = (max_seq + block_size - 1) // block_size
+        # An EVA model (window, chunk): the slot's main allocation is its
+        # SUMMARY part, whose block of ``block_size`` rows covers that
+        # many chunks of positions; ``None`` for every other model, for
+        # which nothing here differs from what it always was
+        self.eva = getattr(model, "eva", None)
+        if self.eva is not None:
+            self._check_eva_tiles()
+        covers = block_size * (self.eva[1] if self.eva else 1)
+        self.blocks_per_slot = (max_seq + covers - 1) // covers
         if num_blocks is None:
             num_blocks = max_slots * self.blocks_per_slot
         self.num_blocks = num_blocks
-        self.pool = BlockPool(num_blocks, block_size)
+        self.pool = BlockPool(num_blocks, covers)
         # +1: physical block ``num_blocks`` is the SCRATCH block — every
         # padded table/scatter entry points there, so inactive slots and
         # bucket padding write garbage into scratch instead of a live
@@ -233,7 +248,22 @@ class ContinuousBatchingEngine:
         kinds = model.layer_kinds
         self.window: Optional[int] = (
             model.cfg.sliding_window if kinds and SLIDING in kinds else None)
-        if self.window is None:
+        table_width = self.blocks_per_slot
+        if self.eva is not None:
+            # the exact part: the blocks of one window a slot (and, as
+            # for a sliding layer, a tail block and one prefill chunk's),
+            # their table counted from the window's first block
+            self.window = self.eva[0]
+            self.num_window_blocks = max_slots * window_blocks_per_slot(
+                self.window, block_size, self.buckets[-1])
+            self.window_pool = BlockPool(self.num_window_blocks, block_size)
+            self.kv = model.init_kv_pools(
+                (num_blocks + 1, self.num_window_blocks + 1), block_size)
+            # one array for both tables: as wide as the wider
+            table_width = max(table_width, self.window // block_size + 1)
+            self._tables_win = np.full((max_slots, table_width),
+                                       self.num_window_blocks, np.int32)
+        elif self.window is None:
             self.window_pool = self._tables_win = None
             self.kv = model.init_kv_pool(num_blocks + 1, block_size)
         else:
@@ -250,8 +280,8 @@ class ContinuousBatchingEngine:
         self.slots: List[Optional[Request]] = [None] * max_slots
         self.allocs: List[Optional[SlotAllocation]] = [None] * max_slots
         self.offsets = np.zeros(max_slots, np.int32)   # tokens cached/slot
-        self._tables = np.full((max_slots, self.blocks_per_slot),
-                               num_blocks, np.int32)
+        self._tables = np.full((max_slots, table_width), num_blocks,
+                               np.int32)
         self._admit_order: List[int] = []   # oldest-first slot ids
         # The decode step's inputs live on the device from step to step
         # and are sent again only when the host's copy changed (``None``
@@ -304,10 +334,13 @@ class ContinuousBatchingEngine:
         self._prefill = jax.jit(self._prefill_impl)
         self._prefill_prefix = jax.jit(model.prefill_with_prefix)
         self._insert = jax.jit(
-            self._insert_impl if self.window is None
+            self._insert_eva_impl if self.eva is not None
+            else self._insert_impl if self.window is None
             else self._insert_kinds_impl, donate_argnums=(0,))
-        self._gather = jax.jit(self._gather_impl if self.window is None
-                               else self._gather_kinds_impl)
+        self._gather = jax.jit(
+            self._gather_eva_impl if self.eva is not None
+            else self._gather_impl if self.window is None
+            else self._gather_kinds_impl)
         # the prefill's first token: the decode program's sampler, alone
         self._sample = jax.jit(self._sample_impl)
 
@@ -337,10 +370,28 @@ class ContinuousBatchingEngine:
                       # each kind
                       "decode_kv_blocks_live_window": 0,
                       "kv_window_blocks_freed": 0,
-                      "kv_pool_blocks_full": num_blocks,
+                      "kv_pool_blocks_full": (
+                          0 if self.eva is not None else num_blocks),
                       "kv_pool_blocks_window": (
-                          0 if self.window is None
+                          0 if self.window is None or self.eva is not None
                           else self.num_window_blocks),
+                      # an EVA model (0 for every other): blocks of the
+                      # slots' SUMMARY tables a step's attention reads
+                      # (``decode_kv_blocks_live`` then counts both
+                      # parts'), blocks one row a position would read at
+                      # the same offsets, a layer's capacity of each
+                      # part, the times a slot crossed into its next
+                      # window and gave its exact blocks back, and the
+                      # summary rows written to stay (a prefill's whole
+                      # chunks, a decode step's at a chunk's last row)
+                      "decode_kv_blocks_live_summary": 0,
+                      "decode_kv_blocks_full_equivalent": 0,
+                      "kv_pool_blocks_exact": (
+                          0 if self.eva is None else self.num_window_blocks),
+                      "kv_pool_blocks_summary": (
+                          0 if self.eva is None else num_blocks),
+                      "kv_window_resets": 0,
+                      "kv_summary_rows_written": 0,
                       "t_step_s": 0.0, "t_schedule_s": 0.0,
                       "t_prefill_s": 0.0, "t_host_arrays_s": 0.0,
                       "t_enqueue_s": 0.0, "t_readback_s": 0.0,
@@ -369,6 +420,20 @@ class ContinuousBatchingEngine:
                                moe_assignments_expected=expected,
                                moe_expert_load=load.tolist())
         return self._stats
+
+    def _check_eva_tiles(self) -> None:
+        """An EVA model's window and chunk against the engine's sizes: a
+        chunk's rows lie in one block and a window starts on a block.
+        Every prefill (a bucket, or a chunk of the largest bucket) then
+        starts on a chunk: the buckets are multiples of the block."""
+        window, chunk = self.eva
+        bs = self.block_size
+        if bs % chunk:
+            raise ValueError(
+                f"block_size {bs} is no multiple of the EVA chunk {chunk}")
+        if window % bs:
+            raise ValueError(
+                f"the EVA window {window} is no multiple of block_size {bs}")
 
     # -- jitted internals --------------------------------------------------
     def _decode_step_paged(self, params, tokens, pool, block_tables, offsets,
@@ -454,6 +519,40 @@ class ContinuousBatchingEngine:
         L, N, Pb, bs = k.shape[:4]
         return (k.reshape(L, N, Pb * bs, *k.shape[4:]),
                 v.reshape(L, N, Pb * bs, *v.shape[4:]))
+
+    def _insert_eva_impl(self, pool, small, block_ids, sum_blocks, sum_rows):
+        """``_insert_impl`` for an EVA model's two parts: the exact rows
+        by blocks (``block_ids`` [N*nb], the exact part's ids), the
+        chunk summaries ``small["sk"/"sv"]`` [L, N, Tb/chunk, Hkv, D]
+        row by row at ``(sum_blocks, sum_rows)`` [N*Tb/chunk] of the
+        summary part (a bucket need not fill a summary block)."""
+        L, N, Tb = small["k"].shape[:3]
+        bs = self.block_size
+
+        def to_blocks(x):
+            return x.reshape(L, N * (Tb // bs), bs, *x.shape[3:])
+
+        def to_rows(x):
+            return x.reshape(L, -1, *x.shape[3:])
+
+        return {
+            "k": pool["k"].at[:, block_ids].set(to_blocks(small["k"])),
+            "v": pool["v"].at[:, block_ids].set(to_blocks(small["v"])),
+            "sk": pool["sk"].at[:, sum_blocks, sum_rows].set(
+                to_rows(small["sk"])),
+            "sv": pool["sv"].at[:, sum_blocks, sum_rows].set(
+                to_rows(small["sv"]))}
+
+    def _gather_eva_impl(self, pool, block_ids, sum_ids):
+        """An EVA model's prefix: the window's exact blocks [N, We] and
+        the slot's summary blocks [N, Ws], each dense [L, N, rows, Hkv,
+        D]. Both are as long as they ever get, whatever the prompt."""
+        def dense(x, ids):
+            x = x[:, ids]                       # [L, N, n, bs, Hkv, D]
+            return x.reshape(*x.shape[:2], -1, *x.shape[4:])
+
+        return (dense(pool["k"], block_ids), dense(pool["v"], block_ids),
+                dense(pool["sk"], sum_ids), dense(pool["sv"], sum_ids))
 
     def _sample_impl(self, logits, temps, top_ks, key):
         """logits [B, V] → (tokens [B], the next key), on the device.
@@ -555,8 +654,8 @@ class ContinuousBatchingEngine:
             self._admitting.append(req)
             toks = req.cache_tokens()
             n = len(toks)
-            never_fits = ((n + 1 + self.block_size - 1)
-                          // self.block_size > self.num_blocks)
+            covers = self.pool.block_size
+            never_fits = (n + 1 + covers - 1) // covers > self.num_blocks
             if n >= self.max_seq or never_fits:
                 req.finish_reason = ("length" if req.output
                                      else "prompt_too_long")
@@ -616,16 +715,28 @@ class ContinuousBatchingEngine:
             return False
         w = alloc.window
         before = (w.first, len(w.blocks))
-        self._stats["kv_window_blocks_freed"] += slide_window(
-            self.window_pool, w,
-            first_window_block(n_cached, self.window, self.block_size),
-            needed_tokens)
+        if self.eva is None:
+            self._stats["kv_window_blocks_freed"] += slide_window(
+                self.window_pool, w,
+                first_window_block(n_cached, self.window, self.block_size),
+                needed_tokens)
+        else:
+            # an EVA model's exact part: nothing goes inside a window,
+            # the whole window's blocks at its end
+            self._stats["kv_window_resets"] += bool(slide_window(
+                self.window_pool, w,
+                eva_window_block(n_cached, self.window, self.block_size),
+                needed_tokens))
         return before != (w.first, len(w.blocks))
 
     def _set_window_table(self, slot: int, alloc: SlotAllocation) -> None:
         w = alloc.window
         self._tables_win[slot] = self.num_window_blocks
-        self._tables_win[slot, w.first:w.first + len(w.blocks)] = w.blocks
+        if self.eva is None:
+            self._tables_win[slot, w.first:w.first + len(w.blocks)] = w.blocks
+        else:       # counted from the window's first block
+            held = w.blocks[:self._tables_win.shape[1]]
+            self._tables_win[slot, :len(held)] = held
         self._dev_tables = None
 
     def _follow_window(self, slot: int, alloc: SlotAllocation,
@@ -658,6 +769,36 @@ class ContinuousBatchingEngine:
                                                 self.num_window_blocks)
         return np.stack([ids, win])
 
+    def _scatter(self, small, rows: List[tuple], nb: int,
+                 n_rows: int) -> None:
+        """A prefill's K/V ``small`` [L, n_rows, nb*bs, ..] into the pool:
+        row ``r`` into the logical blocks ``[lo, lo + nb)`` of ``rows[r]``
+        = (allocation, lo, hi), what lies past ``hi`` (and the padding
+        rows) into the scratch block."""
+        if self.eva is None:
+            self.kv = self._insert(
+                self.kv, small,
+                self._flat(self._block_ids(rows, nb, n_rows)))
+            return
+        # an EVA model: the exact part by blocks, and each chunk's
+        # summary at its own row of the summary part (row j of the slot:
+        # row j % bs of its summary block j // bs)
+        bs = self.block_size
+        per_block = bs // self.eva[1]            # chunks in an exact block
+        exact = np.full((n_rows, nb), self.num_window_blocks, np.int32)
+        blocks = np.full((n_rows, nb * per_block), self.num_blocks, np.int32)
+        at = np.zeros((n_rows, nb * per_block), np.int32)
+        for r, (alloc, lo, hi) in enumerate(rows):
+            exact[r, :hi - lo] = alloc.window.ids(lo, hi,
+                                                  self.num_window_blocks)
+            j = lo * per_block + np.arange((hi - lo) * per_block)
+            held = j // bs < len(alloc.blocks)
+            blocks[r, :len(j)][held] = np.asarray(
+                alloc.blocks, np.int32)[j[held] // bs]
+            at[r, :len(j)] = j % bs
+        self.kv = self._insert(self.kv, small, *map(self._flat,
+                                                    (exact, blocks, at)))
+
     @staticmethod
     def _flat(ids: np.ndarray):
         """[.., rows, n] -> [.., rows*n] on the device: what the scatter
@@ -687,11 +828,10 @@ class ContinuousBatchingEngine:
                 toks[row, :len(seq)] = seq
                 self._slide(alloc, len(seq), len(seq) + 1)
                 self._stats["prefill_tokens"] += len(seq)
-            block_ids = self._block_ids(
-                [(alloc, 0, nb) for _, _, alloc in group], nb, n_pad)
             last_logits, small = self._prefill(
                 self.params, jnp.asarray(toks), jnp.asarray(lengths))
-            self.kv = self._insert(self.kv, small, self._flat(block_ids))
+            self._scatter(small, [(alloc, 0, nb) for _, _, alloc in group],
+                          nb, n_pad)
             self._stats["prefills"] += 1
             self._stats["prefill_padded_tokens"] += n_pad * bucket
             toks_out = self._sample_batch(
@@ -735,12 +875,11 @@ class ContinuousBatchingEngine:
                 self._stats["prefix_tokens_reused"] += shared
                 self._stats["prefill_tokens"] += len(suffix)
             ids = self._block_ids(prefix, pb_pad, n_pad, gather=True)
-            block_ids = self._block_ids(fresh, nb, n_pad)
             pk, pv = self._gather(self.kv, jnp.asarray(ids))
             last_logits, small = self._prefill_prefix(
                 self.params, jnp.asarray(toks), pk, pv,
                 jnp.asarray(plens), jnp.asarray(slens))
-            self.kv = self._insert(self.kv, small, self._flat(block_ids))
+            self._scatter(small, fresh, nb, n_pad)
             self._stats["prefills"] += 1
             self._stats["prefill_padded_tokens"] += n_pad * s_bucket
             toks_out = self._sample_batch(
@@ -769,18 +908,33 @@ class ContinuousBatchingEngine:
         # a sliding layer holds, and gathers, only the blocks the chunk's
         # first token still sees, and the chunk's own
         self._slide(alloc, pos, pos + len(chunk) + 1)
-        ids = self._block_ids([(alloc, 0, pb)], pb_pad, 1, gather=True)
-        pk, pv = self._gather(self.kv, jnp.asarray(ids))
         toks = np.zeros((1, s_bucket), np.int32)
         toks[0, :len(chunk)] = chunk
-        last_logits, small = self._prefill_prefix(
-            self.params, jnp.asarray(toks), pk, pv,
-            jnp.asarray([pos], np.int32),
-            jnp.asarray([len(chunk)], np.int32))
+        plen = jnp.asarray([pos], np.int32)
+        slen = jnp.asarray([len(chunk)], np.int32)
+        if self.eva is None:
+            ids = self._block_ids([(alloc, 0, pb)], pb_pad, 1, gather=True)
+            pk, pv = self._gather(self.kv, jnp.asarray(ids))
+            last_logits, small = self._prefill_prefix(
+                self.params, jnp.asarray(toks), pk, pv, plen, slen)
+        else:
+            # an EVA model gathers its window's exact rows before the
+            # chunk and all its summary rows: two shapes that do not
+            # grow with the prompt, so one program for every chunk
+            first = eva_window_block(pos, self.window, bs)
+            exact = alloc.window.ids(first, first + self.window // bs,
+                                     self.num_window_blocks)
+            summ = np.full(self.blocks_per_slot, self.num_blocks, np.int32)
+            summ[:len(alloc.blocks)] = alloc.blocks[:len(summ)]
+            pk, pv, sk, sv = self._gather(
+                self.kv, jnp.asarray([exact], np.int32),
+                jnp.asarray(summ[None]))
+            last_logits, small = self._prefill_prefix(
+                self.params, jnp.asarray(toks), pk, pv, plen, slen,
+                jnp.asarray([first * bs], np.int32), (sk, sv))
         nb = s_bucket // bs
-        block_ids = self._block_ids([(alloc, pb, pb + nb)], nb, 1)
         # chunk cache is [L, 1, Tb, ...]: reuse the batched scatter
-        self.kv = self._insert(self.kv, small, self._flat(block_ids))
+        self._scatter(small, [(alloc, pb, pb + nb)], nb, 1)
         self._stats["prefills"] += 1
         self._stats["prefill_tokens"] += len(chunk)
         self._stats["prefill_padded_tokens"] += s_bucket
@@ -816,7 +970,10 @@ class ContinuousBatchingEngine:
 
     def _activate(self, slot: int, req: Request, alloc: SlotAllocation,
                   n_cached: int, now: float) -> None:
-        seal_prompt_blocks(self.pool, alloc, req.cache_tokens())
+        if self.eva is None:
+            seal_prompt_blocks(self.pool, alloc, req.cache_tokens())
+        else:       # an EVA model's blocks are not hashed (paged_cache.py)
+            self._stats["kv_summary_rows_written"] += n_cached // self.eva[1]
         if self.window_pool is not None:
             # the prefill is in: what is left of it behind the window
             # goes, the prompt's blocks still held are indexed
@@ -1027,15 +1184,30 @@ class ContinuousBatchingEngine:
         # ceil((offset + 1) / bs) blocks a slot) of what its tables hold;
         # counted under the running program, in no phase's span
         bs = self.block_size
-        self._stats["decode_kv_blocks_live"] += int(
-            ((at[active] + bs) // bs).sum())
         self._stats["decode_kv_blocks_table"] += (
             len(active) * self.blocks_per_slot)
-        if self.window is not None:
-            # a sliding layer's kernel starts at its window's first block
-            first = np.maximum(at[active] - self.window + 1, 0) // bs
-            self._stats["decode_kv_blocks_live_window"] += int(
-                (at[active] // bs - first + 1).sum())
+        pos = at[active]
+        if self.eva is not None:
+            # an EVA layer reads its window's exact blocks so far and the
+            # summary blocks of the windows before it
+            window, chunk = self.eva
+            summary = -(-(pos // window * (window // chunk)) // bs)
+            self._stats["decode_kv_blocks_live"] += int(
+                (pos % window // bs + 1 + summary).sum())
+            self._stats["decode_kv_blocks_live_summary"] += int(summary.sum())
+            self._stats["decode_kv_blocks_full_equivalent"] += int(
+                ((pos + bs) // bs).sum())
+            self._stats["kv_summary_rows_written"] += int(
+                (pos % chunk == chunk - 1).sum())
+        else:
+            self._stats["decode_kv_blocks_live"] += int(
+                ((pos + bs) // bs).sum())
+            if self.window is not None:
+                # a sliding layer's kernel starts at its window's first
+                # block
+                first = np.maximum(pos - self.window + 1, 0) // bs
+                self._stats["decode_kv_blocks_live_window"] += int(
+                    (pos // bs - first + 1).sum())
         return self._dev_tokens, active
 
     def _emit(self, slot: int, tok: int) -> None:
@@ -1081,6 +1253,7 @@ class ContinuousBatchingEngine:
         bucket = self._bucket_for(n)
         if bucket is None:
             raise ValueError(f"prompt of {n} tokens exceeds buckets")
+        self._no_handoff_for_eva()
         toks = np.zeros((1, bucket), np.int32)
         toks[0, :n] = prompt_tokens
         last_logits, small = self._prefill(
@@ -1089,11 +1262,18 @@ class ContinuousBatchingEngine:
         self._stats["prefills"] += 1
         return kv, np.asarray(last_logits[0]), n
 
+    def _no_handoff_for_eva(self) -> None:
+        if self.eva is not None:
+            raise NotImplementedError(
+                "the prefill/decode handoff carries K/V rows only; an EVA "
+                "model's chunk summaries are not part of it yet")
+
     def submit_prefilled(self, prompt_tokens: List[int], kv: Dict,
                          last_logits, sampling: Optional[SamplingParams]
                          = None) -> Optional[Request]:
         """Admit a request whose prefill happened elsewhere. Returns None
         if no slot (or pool room) is free (caller retries)."""
+        self._no_handoff_for_eva()
         req = Request(prompt_tokens, sampling or SamplingParams())
         n = len(prompt_tokens)
         if n >= self.max_seq:
@@ -1117,9 +1297,8 @@ class ContinuousBatchingEngine:
                 blocks, 0, None if self.window_pool is None
                 else WindowAllocation(0, []))
             self._slide(alloc, n, n + 1)
-            block_ids = self._block_ids([(alloc, 0, nb)], nb, 1)
             small = {"k": jnp.asarray(kv["k"]), "v": jnp.asarray(kv["v"])}
-            self.kv = self._insert(self.kv, small, self._flat(block_ids))
+            self._scatter(small, [(alloc, 0, nb)], nb, 1)
             slot = free[0]
             toks_out = self._sample_batch(jnp.asarray(last_logits)[None],
                                           [req], 1)

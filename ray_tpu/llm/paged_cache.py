@@ -33,6 +33,20 @@ Design (vLLM-v1-shaped, TPU-adapted):
   A prefix hit needs BOTH kinds: ``allocate_slot`` takes the longest
   prefix whose blocks the full kind's index holds AND whose last
   window's worth of blocks the sliding kind's index still holds.
+- An EVA model (``models/llama.py``: an exact window that RESETS and
+  one summary row a chunk of everything before it) keeps a slot's K/V in
+  two PARTS, a ``BlockPool`` each. The SUMMARY part is the slot's main
+  allocation (``SlotAllocation.blocks``): a block of ``block_size``
+  summary rows covers ``block_size * chunk`` positions, that pool's
+  ``block_size`` is in POSITIONS, and ``allocate_slot`` /
+  ``ensure_capacity`` grow it as they grow any slot: one block every 512
+  positions at 32 rows of 16. The EXACT part is a ``WindowAllocation``
+  moved by ``slide_window`` to ``eva_window_block``: nothing goes while
+  the slot stays in its window, and at a window's end ALL its blocks go
+  back together. Neither part's blocks are hashed, so an EVA model's
+  prompts are never prefix hits: a hit would need the summary blocks of
+  every earlier window AND the exact blocks of the window it ends in,
+  and the exact ones are gone one window later.
 """
 
 from __future__ import annotations
@@ -206,6 +220,13 @@ def first_window_block(n_cached: int, window: int, block_size: int) -> int:
     """The logical block of the first position a sliding layer's query
     at position ``n_cached`` sees (key j is visible iff i - j < window)."""
     return max(0, n_cached - window + 1) // block_size
+
+
+def eva_window_block(n_cached: int, window: int, block_size: int) -> int:
+    """The logical block of the first position an EVA layer's query at
+    position ``n_cached`` sees exactly: its window's first (the window
+    resets at every multiple of ``window``, a multiple of the block)."""
+    return n_cached // window * (window // block_size)
 
 
 def window_blocks_per_slot(window: int, block_size: int, chunk: int) -> int:

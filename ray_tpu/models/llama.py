@@ -32,7 +32,18 @@ it is in-tree and TPU-first:
   table (``rope_scaling``: a kind may turn by a YaRN table) and the
   window that the program's ``attend`` applies. A model with no sliding
   layer has no kinds (``layer_kinds`` is ``None``) and lowers to the
-  programs it lowered to before kinds existed.
+  programs it lowered to before kinds existed;
+- a third kind, EVA ATTENTION (``"eva_attention"``, ``ops/eva.py``): an
+  exact window of ``eva_window`` positions that RESETS at its every
+  multiple, summaries of the chunks (``eva_chunk`` positions) of every
+  earlier window, one softmax over both. Its layers hold two more
+  leaves, ``eva_phi`` / ``eva_mu`` [L, Hkv, hd] (float32), the vectors a
+  chunk is pooled by, and a serving engine keeps their K/V in TWO parts:
+  exact rows of the current window and one summary row a chunk of
+  everything before it (``init_kv_pools``). An EVA model is EVA in
+  every layer (the kind is not mixed with the other two: its cache is
+  another shape); ``model.eva`` says so, and no other model's programs
+  differ for it.
 """
 
 from __future__ import annotations
@@ -44,6 +55,8 @@ import jax
 import jax.numpy as jnp
 
 from ray_tpu.ops.attention import attention, reference_attention
+from ray_tpu.ops.eva import (chunk_summaries, eva_attention,
+                             merge_softmax_parts, visible_summaries)
 from ray_tpu.ops.norms import rms_norm
 from ray_tpu.ops.ring_attention import ring_attention
 from ray_tpu.ops.rope import (YarnScaling, apply_rope, apply_rope_of_kind,
@@ -55,6 +68,8 @@ Params = Dict[str, Any]
 # (what the scans hand the layer body) is its place here
 LAYER_KINDS = ("full_attention", "sliding_attention")
 FULL, SLIDING = 0, 1
+# the third kind: a model of it is of it in every layer (module docstring)
+EVA_KIND = "eva_attention"
 # a full layer's window: past any position, so ``i - j < window`` holds
 NO_WINDOW = 2 ** 30
 
@@ -108,6 +123,19 @@ class LlamaConfig:
     # (kind, YarnScaling) pairs: the kinds whose rotary table is YaRN's;
     # a kind not named turns by the default table of ``rope_theta``
     rope_scaling: Tuple[Tuple[str, YarnScaling], ...] = ()
+    # the EVA kind's window (it resets at every multiple) and chunk (one
+    # summary row a chunk of every earlier window)
+    eva_window: Optional[int] = None
+    eva_chunk: Optional[int] = None
+    # RMSNorm scales stored as ``g`` and applied as ``1 + g``
+    norm_add_unit_offset: bool = False
+    # the residual stream in float32 (each block's input is normed into
+    # the compute dtype, its output added in float32)
+    fp32_residual: bool = False
+    # output heads: head ``p`` of ``lm_head`` [d, heads * vocab] predicts
+    # token ``t + 1 + p``. ``apply`` returns every head's logits; the
+    # serving programs compute head 0's, the next token's
+    num_pred_heads: int = 1
 
     def __post_init__(self):
         if self.attention_impl not in ("ring", "ulysses", "flash", "xla"):
@@ -126,11 +154,13 @@ class LlamaConfig:
             object.__setattr__(self, "head_dim", self.dim // self.n_heads)
         if self.layer_types is not None:
             object.__setattr__(self, "layer_types", tuple(self.layer_types))
-            unknown = set(self.layer_types) - set(LAYER_KINDS)
+            unknown = set(self.layer_types) - set(LAYER_KINDS) - {EVA_KIND}
             if unknown or len(self.layer_types) != self.n_layers:
                 raise ValueError(
                     f"layer_types must name one of {LAYER_KINDS} for each "
                     f"of the {self.n_layers} layers, got {self.layer_types}")
+            if EVA_KIND in self.layer_types:
+                self._check_eva()
             if (LAYER_KINDS[SLIDING] in self.layer_types
                     and not self.sliding_window):
                 raise ValueError("a sliding_attention layer needs a "
@@ -142,12 +172,34 @@ class LlamaConfig:
                 f"rope_scaling names kinds out of {LAYER_KINDS}, got "
                 f"{self.rope_scaling}")
 
+    def _check_eva(self) -> None:
+        if set(self.layer_types) != {EVA_KIND}:
+            raise ValueError(
+                f"{EVA_KIND} layers are not mixed with other kinds (their "
+                f"K/V cache is of another shape), got {self.layer_types}")
+        w, c = self.eva_window, self.eva_chunk
+        if not w or not c or w % c:
+            raise ValueError(
+                f"an {EVA_KIND} layer needs an eva_window that its "
+                f"eva_chunk divides, got window {w}, chunk {c}")
+        if self.rope_scaling:
+            raise ValueError(f"{EVA_KIND} layers turn by the default table")
+
+    @property
+    def eva(self) -> Optional[Tuple[int, int]]:
+        """(window, chunk) of a model of EVA layers, None for any other."""
+        if self.layer_types and EVA_KIND in self.layer_types:
+            return self.eva_window, self.eva_chunk
+        return None
+
     def num_params(self) -> int:
         d, f, v = self.dim, self.ffn_dim, self.vocab_size
         q = self.n_heads * self.head_dim
         kv = self.n_kv_heads * self.head_dim
         per_layer = d * q + 2 * d * kv + q * d + 3 * d * f + 2 * d
-        heads = 0 if self.tie_embeddings else v * d
+        if self.eva:
+            per_layer += 2 * kv                       # eva_phi, eva_mu
+        heads = 0 if self.tie_embeddings else v * d * self.num_pred_heads
         return v * d + self.n_layers * per_layer + d + heads
 
     # -- presets (sizes match the public Llama-3 family) --
@@ -195,6 +247,9 @@ def param_logical_axes(cfg: LlamaConfig) -> Params:
         },
         "norm_f": ("embed_in",),
     }
+    if cfg.eva:
+        axes["layers"]["eva_phi"] = (None, "kv_heads", None)
+        axes["layers"]["eva_mu"] = (None, "kv_heads", None)
     if not cfg.tie_embeddings:
         axes["lm_head"] = ("embed_in", "vocab")
     return axes
@@ -221,9 +276,21 @@ class LlamaModel:
         # None for the plain model (every layer full attention on the
         # default rotary table), which then carries none of what follows
         types = cfg.layer_types or (LAYER_KINDS[FULL],) * cfg.n_layers
+        # an EVA model: (window, chunk); its layers are all of that kind,
+        # so its scans hand the layer body no kind (None elsewhere)
+        self.eva: Optional[Tuple[int, int]] = cfg.eva
         yarn = dict(cfg.rope_scaling)
-        if LAYER_KINDS[SLIDING] not in types and not yarn:
+        if self.eva is not None:
+            # no table either (a constant of max_S * D/2 floats, twice, in
+            # every program that closes over it: 12.6 MB at 24,576
+            # positions): the angles come from the positions, as below
             self.layer_kinds: Optional[Tuple[int, ...]] = None
+            self._inv_freq = yarn_inv_freq(cfg.head_dim, cfg.rope_theta,
+                                           None)[None]
+            self._rope_scales = jnp.ones((1,), jnp.float32)
+            self._windows = None
+        elif LAYER_KINDS[SLIDING] not in types and not yarn:
+            self.layer_kinds = None
             self._angles = rope_frequencies(cfg.head_dim, cfg.max_seq_len,
                                             theta=cfg.rope_theta)
             self._rope_scales = self._windows = None
@@ -251,24 +318,34 @@ class LlamaModel:
             return (jax.random.normal(key, shape, jnp.float32)
                     * (fan_in ** -0.5))
 
+        def norm_scale(shape):      # a scale of 1 (stored as 0 under
+            return (jnp.zeros if cfg.norm_add_unit_offset   # ``1 + g``)
+                    else jnp.ones)(shape, jnp.float32)
+
         L = cfg.n_layers
         params: Params = {
             "embed": dense(next(k), (cfg.vocab_size, d), d),
             "layers": {
-                "attn_norm": jnp.ones((L, d), jnp.float32),
+                "attn_norm": norm_scale((L, d)),
                 "wq": dense(next(k), (L, d, cfg.n_heads, hd), d),
                 "wk": dense(next(k), (L, d, cfg.n_kv_heads, hd), d),
                 "wv": dense(next(k), (L, d, cfg.n_kv_heads, hd), d),
                 "wo": dense(next(k), (L, cfg.n_heads, hd, d), d),
-                "mlp_norm": jnp.ones((L, d), jnp.float32),
+                "mlp_norm": norm_scale((L, d)),
                 "w_gate": dense(next(k), (L, d, cfg.ffn_dim), d),
                 "w_up": dense(next(k), (L, d, cfg.ffn_dim), d),
                 "w_down": dense(next(k), (L, cfg.ffn_dim, d), cfg.ffn_dim),
             },
-            "norm_f": jnp.ones((d,), jnp.float32),
+            "norm_f": norm_scale((d,)),
         }
         if not cfg.tie_embeddings:
-            params["lm_head"] = dense(next(k), (d, cfg.vocab_size), d)
+            params["lm_head"] = dense(
+                next(k), (d, cfg.num_pred_heads * cfg.vocab_size), d)
+        if self.eva is not None:
+            # drawn, not zero: a program that drops them must differ
+            for name in ("eva_phi", "eva_mu"):
+                params["layers"][name] = jax.random.normal(
+                    next(k), (L, cfg.n_kv_heads, hd), jnp.float32)
         return params
 
     # -- the serving path's storage dtype -----------------------------------
@@ -438,8 +515,20 @@ class LlamaModel:
             down = jnp.einsum("bsf,fd->bsd", ff, layer["w_down"].astype(dt))
         return down, None
 
+    def _norm(self, x, weight):
+        """RMSNorm of a block's input: the stored scale (``1 + g`` under
+        ``norm_add_unit_offset``), and out of a float32 residual stream
+        into the compute dtype."""
+        cfg = self.cfg
+        if cfg.norm_add_unit_offset:
+            weight = 1.0 + weight
+        h = rms_norm(x, weight, eps=cfg.norm_eps)
+        return h.astype(cfg.dtype) if cfg.fp32_residual else h
+
     def _rope(self, x, positions, kind):
-        if kind is None:
+        if self.eva is not None:
+            kind = 0                   # its one row of frequencies
+        elif kind is None:
             return apply_rope(x, self._angles, positions)
         return apply_rope_of_kind(x, self._inv_freq, self._rope_scales,
                                   kind, positions)
@@ -464,7 +553,7 @@ class LlamaModel:
             return self._constrain(a, *names) if constrain else a
 
         with jax.named_scope("norm_residual"):
-            h = rms_norm(x, layer["attn_norm"], eps=cfg.norm_eps)
+            h = self._norm(x, layer["attn_norm"])
         with jax.named_scope("attention"):
             q = jnp.einsum("bsd,dhk->bshk", h, layer["wq"].astype(dt))
             k = jnp.einsum("bsd,dhk->bshk", h, layer["wk"].astype(dt))
@@ -478,7 +567,7 @@ class LlamaModel:
             o = jnp.einsum("bshk,hkd->bsd", o, layer["wo"].astype(dt))
         with jax.named_scope("norm_residual"):
             x = x + pin(o, "batch", "seq", "embed")
-            h = rms_norm(x, layer["mlp_norm"], eps=cfg.norm_eps)
+            h = self._norm(x, layer["mlp_norm"])
         down, extra = self._ffn(h, layer, live, constrain)
         with jax.named_scope("norm_residual"):
             return x + pin(down, "batch", "seq", "embed"), kv, extra
@@ -489,22 +578,29 @@ class LlamaModel:
         with jax.named_scope("embed"):
             x = self._embed_lookup(params["embed"].astype(self.cfg.dtype),
                                    tokens)
+            if self.cfg.fp32_residual:
+                x = x.astype(jnp.float32)
             return (self._constrain(x, "batch", "seq", "embed") if constrain
                     else x)
 
     def _head(self, params: Params, x: jax.Array,
               last: Optional[jax.Array] = None,
-              constrain: bool = False) -> jax.Array:
+              constrain: bool = False, every_head: bool = False
+              ) -> jax.Array:
         """Final norm and LM head: x [B, T, D] -> f32 logits [B, T, V];
         with ``last`` [B], of row ``last[b]`` of each sequence alone
-        ([B, 1, V]: the other rows never meet the head)."""
+        ([B, 1, V]: the other rows never meet the head). A model with
+        ``num_pred_heads`` > 1 gives head 0's V logits, the next
+        token's, unless ``every_head`` (``apply``: [B, T, heads * V])."""
         cfg = self.cfg
         with jax.named_scope("logits"):
-            x = rms_norm(x, params["norm_f"], eps=cfg.norm_eps)
+            x = self._norm(x, params["norm_f"])
             if last is not None:
                 x = jnp.take_along_axis(x, last[:, None, None], axis=1)
             head = (params["embed"].T if cfg.tie_embeddings
                     else params["lm_head"])
+            if cfg.num_pred_heads > 1 and not every_head:
+                head = head[:, :cfg.vocab_size]
             logits = jnp.einsum("bsd,dv->bsv", x, head.astype(cfg.dtype))
             if constrain:
                 logits = self._constrain(logits, "batch", "seq", "vocab")
@@ -525,6 +621,13 @@ class LlamaModel:
             layer, kind = layer_and_kind
 
             def attend(q, k, v):        # training keeps no K/V
+                if self.eva is not None:
+                    if positions is not None or self._sp > 1:
+                        raise NotImplementedError(
+                            "EVA layers take contiguous positions 0..T-1 "
+                            "on one sequence shard")
+                    return self._eva_over_rows(
+                        q, k, v, layer, jnp.arange(q.shape[1]))[0], None
                 with jax.named_scope("attention"):
                     return self._attention(q, k, v, positions,
                                            self._window(kind)), None
@@ -542,7 +645,24 @@ class LlamaModel:
         x = self._embed(params, tokens, constrain=True)
         x, extras = jax.lax.scan(layer_fn, x,
                                  (params["layers"], self._kinds_xs()))
-        return self._head(params, x, constrain=True), extras
+        return self._head(params, x, constrain=True, every_head=True), extras
+
+    def _eva_over_rows(self, q, k, v, layer: Params, positions_q):
+        """EVA attention of queries at ``positions_q`` over DENSE rows
+        k, v [B, S, Hkv, hd] that stand at positions 0..S-1 (a whole
+        sequence, a slot cache): the rows' whole chunks pooled as they
+        stand (a query sees only those of the windows before its own,
+        whose rows are all in; a tail chunk is summarised by no one),
+        then the dense masked form. -> (o, k~, v~)."""
+        window, chunk = self.eva
+        S = k.shape[1]
+        whole = S // chunk * chunk
+        ks, vs = chunk_summaries(k[:, :whole], v[:, :whole],
+                                 layer["eva_phi"], layer["eva_mu"], chunk)
+        o = eva_attention(q, k, v, ks, vs, positions_q, jnp.arange(S),
+                          jnp.arange(whole // chunk), window=window,
+                          chunk=chunk)
+        return o, ks, vs
 
     # -- KV-cache inference path (serving; BASELINE.md config 5) ----------
     def init_kv_cache(self, batch: int, max_seq: int) -> Params:
@@ -576,6 +696,10 @@ class LlamaModel:
                     # offsets
                     k_all = k_cache.at[batch_idx, q_pos].set(k_new)
                     v_all = v_cache.at[batch_idx, q_pos].set(v_new)
+                if self.eva is not None:
+                    o, ks, vs = self._eva_over_rows(q, k_all, v_all, layer,
+                                                    q_pos)
+                    return o, (k_all, v_all, ks, vs)
                 with jax.named_scope("attention"):
                     # attend over cache positions <= own position
                     o = reference_attention(q, k_all, v_all,
@@ -587,10 +711,16 @@ class LlamaModel:
             x, kv, _ = self._layer(x, layer, q_pos, attend, kind=kind)
             return x, kv
 
-        x, (k_out, v_out) = jax.lax.scan(
+        x, kv = jax.lax.scan(
             step, self._embed(params, tokens),
             (params["layers"], cache["k"], cache["v"], self._kinds_xs()))
-        return self._head(params, x), {"k": k_out, "v": v_out}
+        return self._head(params, x), self._kv_dict(kv)
+
+    @staticmethod
+    def _kv_dict(kv) -> Params:
+        """What a prefill's layer scan stacked up, by name: k and v, and
+        for an EVA model the chunk summaries of the same rows."""
+        return dict(zip(("k", "v", "sk", "sv"), kv))
 
     # -- paged KV-cache path (llm/engine.py + llm/paged_cache.py) ---------
     def init_kv_pool(self, num_blocks: int, block_size: int) -> Params:
@@ -615,6 +745,18 @@ class LlamaModel:
         uniform pool is the stack in which every window has NB blocks).
         """
         cfg = self.cfg
+        if self.eva is not None:
+            # an EVA model: ``num_blocks`` = (summary, exact). Every
+            # layer holds both PARTS: exact rows of a slot's current
+            # window in ``k``/``v``, one summary row a chunk of all
+            # before it in ``sk``/``sv``, blocks of ``block_size`` rows
+            # each, each part numbered from 0 by its own block table
+            shape = (block_size, cfg.n_kv_heads, cfg.head_dim)
+            summary, exact = ((cfg.n_layers, n) + shape for n in num_blocks)
+            return {"k": jnp.zeros(exact, cfg.dtype),
+                    "v": jnp.zeros(exact, cfg.dtype),
+                    "sk": jnp.zeros(summary, cfg.dtype),
+                    "sv": jnp.zeros(summary, cfg.dtype)}
         per_layer = [num_blocks[kind] for kind in self.layer_kinds]
         bases = [sum(per_layer[:i]) for i in range(len(per_layer))]
         shape = (sum(per_layer), block_size, cfg.n_kv_heads, cfg.head_dim)
@@ -688,7 +830,12 @@ class LlamaModel:
         the uniform one (one table: a sliding layer then keeps, and
         skips, the rows behind its window) or a pool a kind
         (``init_kv_pools``: the stack as it comes, ``bases`` beside it)
-        with a table a kind."""
+        with a table a kind.
+
+        An EVA model has a body of its own (``_decode_step_eva``)."""
+        if self.eva is not None:
+            return self._decode_step_eva(params, tokens, pool, block_tables,
+                                         offsets, live)
         if "bases" in pool:
             NB, stack = None, pool["k"].shape
         else:
@@ -751,10 +898,144 @@ class LlamaModel:
                     v=v_out.reshape(pool["v"].shape))
         return self._head(params, x)[:, 0], pool, extras
 
+    def _decode_step_eva(self, params: Params, tokens: jax.Array,
+                         pool: Params, block_tables: jax.Array,
+                         offsets: jax.Array, live=None):
+        """``decode_step_paged_counted`` of an EVA model: one softmax
+        over the exact rows of the slot's window and the summaries of
+        every chunk before it, each walked as its own page list
+        (``paged_decode_attention(stats=True)``, kernel or reference by
+        the one resolver) and joined (``merge_softmax_parts``). The
+        pools live through the step whole and in place, as ever.
+
+        A TWO-PART pool (``init_kv_pools``: ``sk``/``sv`` beside
+        ``k``/``v``) comes with ``block_tables`` [2, B, MAXB]: the
+        summary part's table, then the exact part's, which counts from
+        the WINDOW's first block (row ``r`` of the window is row ``r %
+        bs`` of entry ``r // bs``: at a window's end the engine hands
+        the slot a fresh table). The step writes its row into the exact
+        part, pools the chunk that row lies in (its earlier rows read
+        back from the same block) and writes that as summary row
+        ``offset // chunk``: EVERY slot EVERY step, no mask and no
+        conditional. Only the step at ``offset % chunk == chunk - 1``
+        writes the value that stays; the 15 before it write a partial
+        one over the same row, which no query can see (a window's
+        summaries are read from the next window on). That costs a step
+        one block gathered and one row written a slot-layer beside the
+        ~70 blocks its attention reads: under a tenth of the program on
+        the chip (PERF.md, PR 35).
+
+        A UNIFORM pool (``init_kv_pool``, one table, one row a position:
+        the harness's logits check) keeps every row, so the step reads
+        the window through ``starts`` and pools the earlier windows'
+        chunks as it goes: small batches only, and the same arithmetic.
+        """
+        window, chunk = self.eva
+        L, NB, bs = pool["k"].shape[:3]
+        if bs % chunk:
+            raise ValueError(
+                f"an EVA layer's chunk ({chunk}) has to divide the K/V "
+                f"block ({bs}): a chunk's rows are read from one block")
+        two_part = "sk" in pool
+        start = offsets // window * window        # the window's first
+        seen = visible_summaries(offsets, window, chunk)
+        impl = self.paged_decode_impl()
+        from ray_tpu.ops.paged_attention import (
+            paged_decode_attention, ragged_decode_attention_reference)
+
+        def entry(table, index):
+            return jnp.take_along_axis(table, index[:, None], axis=1)[:, 0]
+
+        if two_part:
+            NBs = pool["sk"].shape[1]
+            table_s, table = block_tables
+            row = offsets - start                 # within the window
+            dest_s = entry(table_s, offsets // chunk // bs)
+            off_s = offsets // chunk % bs
+        else:
+            table, row = block_tables, offsets
+        dest, off = entry(table, row // bs), row % bs
+
+        chunk_rows = ((off // chunk * chunk)[:, None]
+                      + jnp.arange(chunk))[:, :, None, None]
+
+        def chunk_of(rows, base):
+            """Each slot's current chunk [B, chunk, Hkv, D], out of the
+            block the step writes (whole blocks are gathered: B windows
+            of the stack, not B * chunk)."""
+            return jnp.take_along_axis(rows[base + dest], chunk_rows, axis=1)
+
+        def step(carry, layer_and_bases):
+            x, pools = carry
+            layer, base, base_s = layer_and_bases
+            phi, mu = layer["eva_phi"], layer["eva_mu"]
+
+            def attend(q, k_new, v_new):
+                k_all, v_all, *summaries = pools
+                with jax.named_scope("kv_update"):
+                    k_all = k_all.at[base + dest, off].set(k_new[:, 0])
+                    v_all = v_all.at[base + dest, off].set(v_new[:, 0])
+                if two_part:
+                    ks, vs = chunk_summaries(
+                        chunk_of(k_all, base), chunk_of(v_all, base),
+                        phi, mu, chunk)                    # [B, 1, Hkv, D]
+                    with jax.named_scope("kv_update"):
+                        sk_all, sv_all = (
+                            a.at[base_s + dest_s, off_s].set(new[:, 0])
+                            for a, new in zip(summaries, (ks, vs)))
+                    summaries = [sk_all, sv_all]
+                with jax.named_scope("eva_attention"):
+                    if two_part:
+                        parts = [
+                            paged_decode_attention(
+                                q[:, 0], k_all, v_all, table, row + 1,
+                                impl=impl, first_block=base, num_blocks=NB,
+                                stats=True),
+                            paged_decode_attention(
+                                q[:, 0], sk_all, sv_all, table_s, seen,
+                                impl=impl, first_block=base_s,
+                                num_blocks=NBs, stats=True)]
+                    else:
+                        held = base + table                # [B, MAXB]
+                        ks, vs = chunk_summaries(
+                            k_all[held].reshape(len(tokens), -1,
+                                                *k_all.shape[2:]),
+                            v_all[held].reshape(len(tokens), -1,
+                                                *v_all.shape[2:]),
+                            phi, mu, chunk)
+                        parts = [
+                            paged_decode_attention(
+                                q[:, 0], k_all, v_all, table, offsets + 1,
+                                impl=impl, starts=start, first_block=base,
+                                num_blocks=NB, stats=True),
+                            ragged_decode_attention_reference(
+                                q[:, 0], ks, vs, seen, stats=True)]
+                    o = merge_softmax_parts(parts).astype(q.dtype)
+                return o[:, None], (k_all, v_all, *summaries)
+
+            x, pools, extra = self._layer(x, layer, offsets[:, None], attend,
+                                          live=live)
+            return (x, pools), extra
+
+        names = ("k", "v", "sk", "sv") if two_part else ("k", "v")
+        layers = jnp.arange(L, dtype=jnp.int32)
+        (x, out), extras = jax.lax.scan(
+            step,
+            (self._embed(params, tokens[:, None]),
+             tuple(pool[n].reshape((-1,) + pool[n].shape[2:])
+                   for n in names)),
+            (params["layers"], layers * NB,
+             layers * (NBs if two_part else 0)))
+        pool = dict(pool, **{n: a.reshape(pool[n].shape)
+                             for n, a in zip(names, out)})
+        return self._head(params, x)[:, 0], pool, extras
+
     def prefill_with_prefix(self, params: Params, tokens: jax.Array,
                             prefix_k: jax.Array, prefix_v: jax.Array,
-                            prefix_len: jax.Array, lengths: jax.Array
-                            ) -> Tuple[jax.Array, Params]:
+                            prefix_len: jax.Array, lengths: jax.Array,
+                            prefix_start: Optional[jax.Array] = None,
+                            summaries: Optional[Tuple[jax.Array, jax.Array]]
+                            = None) -> Tuple[jax.Array, Params]:
         """Suffix prefill attending over a cached (shared) prefix.
 
         tokens   [N, Tb] suffix tokens (right-padded)
@@ -768,9 +1049,23 @@ class LlamaModel:
         their FLOPs entirely). A sliding layer masks the prefix rows
         behind each query's window, so its rows there may hold anything
         (the engine has freed their blocks and gathers none of them).
+
+        An EVA model's prefix is the exact rows from ``prefix_start``
+        [N] on (the first position of the window ``prefix_len`` lies
+        in: the gather never grows past a window) and ``summaries``,
+        (k~, v~) [L, N, Smax, Hkv, D], the slot's summary rows from
+        chunk 0 (those of chunks that began before ``prefix_len`` are
+        read). ``prefix_len`` is a multiple of the chunk. The suffix's
+        own chunks are pooled here and handed back as ``sk``/``sv``
+        [L, N, Tb/chunk, Hkv, D] beside its K/V (a suffix longer than a
+        window reads them itself).
         """
         Tb = tokens.shape[1]
         Pmax = prefix_k.shape[2]
+        if self.eva is not None:
+            return self._prefill_with_prefix_eva(
+                params, tokens, prefix_k, prefix_v, prefix_len, lengths,
+                prefix_start, *summaries)
         # absolute positions: suffix token t sits at prefix_len + t;
         # padded prefix rows get a position PAST every query so the
         # causal mask drops them
@@ -804,13 +1099,58 @@ class LlamaModel:
         return (self._head(params, x, last=lengths - 1)[:, 0],
                 {"k": k_out, "v": v_out})
 
+    def _prefill_with_prefix_eva(self, params, tokens, prefix_k, prefix_v,
+                                 prefix_len, lengths, prefix_start,
+                                 sum_k, sum_v):
+        window, chunk = self.eva
+        Tb, Pmax, Smax = tokens.shape[1], prefix_k.shape[2], sum_k.shape[2]
+        far = jnp.int32(2 ** 30)
+        pos_q = prefix_len[:, None] + jnp.arange(Tb)[None, :]       # [N,Tb]
+        at = prefix_start[:, None] + jnp.arange(Pmax)[None, :]
+        pos_k = jnp.concatenate(
+            [jnp.where(at < prefix_len[:, None], at, far), pos_q], axis=1)
+        # summary rows: the slot's own up to the suffix's first chunk,
+        # then the suffix's
+        first = prefix_len[:, None] // chunk
+        held = jnp.arange(Smax)[None, :]
+        index_s = jnp.concatenate(
+            [jnp.where(held < first, held, far),
+             first + jnp.arange(Tb // chunk)[None, :]], axis=1)
+
+        def step(x, layer_and_prefix):
+            layer, kp, vp, skp, svp = layer_and_prefix
+
+            def attend(q, k_new, v_new):
+                ks, vs = chunk_summaries(k_new, v_new, layer["eva_phi"],
+                                         layer["eva_mu"], chunk)
+
+                def join(old, new):
+                    return jnp.concatenate([old.astype(new.dtype), new],
+                                           axis=1)
+
+                o = eva_attention(q, join(kp, k_new), join(vp, v_new),
+                                  join(skp, ks), join(svp, vs), pos_q, pos_k,
+                                  index_s, window=window, chunk=chunk)
+                return o, (k_new, v_new, ks, vs)
+
+            x, kv, _ = self._layer(x, layer, pos_q, attend)
+            return x, kv
+
+        x, kv = jax.lax.scan(
+            step, self._embed(params, tokens),
+            (params["layers"], prefix_k, prefix_v, sum_k, sum_v))
+        return (self._head(params, x, last=lengths - 1)[:, 0],
+                self._kv_dict(kv))
+
     def loss(self, params: Params, tokens: jax.Array,
              targets: jax.Array,
              mask: Optional[jax.Array] = None) -> jax.Array:
         """Mean next-token cross-entropy."""
         # by class: ``PipelinedLlama`` borrows this method
-        return LlamaModel._cross_entropy(self.apply(params, tokens), targets,
-                                         mask)
+        logits = self.apply(params, tokens)
+        if self.cfg.num_pred_heads > 1:     # head 0: the next token's
+            logits = logits[..., :self.cfg.vocab_size]
+        return LlamaModel._cross_entropy(logits, targets, mask)
 
     @staticmethod
     def _cross_entropy(logits, targets, mask):

@@ -49,6 +49,13 @@ page's rows behind it, and reads nothing older (the table's entries
 before that page may point anywhere: the engine has freed their blocks);
 the reference masks the same rows of its gather. Without ``starts``
 both are the programs they were.
+
+An EVA layer (``ops/eva.py``) attends TWO page lists under one softmax,
+the exact rows of its window and the summary rows of everything before
+it: each list is one call with ``stats=True``, which hands back the
+softmax's output in float32 with its running max and sum (the kernel
+keeps both anyway), and ``ops.eva.merge_softmax_parts`` joins the parts.
+Without ``stats`` both sides are the programs they were.
 """
 
 from __future__ import annotations
@@ -68,12 +75,15 @@ logger = logging.getLogger(__name__)
 
 
 def ragged_decode_attention_reference(q, k, v, lengths, *, starts=None,
-                                      scale: Optional[float] = None):
+                                      scale: Optional[float] = None,
+                                      stats: bool = False):
     """One query token against a dense, length-bounded cache, masked
     past each row's length (and, with ``starts`` [B], before each row's
     first visible position): q [B, H, D] x k/v [B, S, Hkv, D], lengths
     [B] -> [B, H, D]. The arithmetic of the paged reference below, and
-    the tests' oracle."""
+    the tests' oracle. ``stats``: ``(o, m, l)`` instead, float32, the
+    softmax's running max and sum [B, H] beside its output (``NEG_INF``
+    and 0 where a row sees nothing), for ``ops.eva.merge_softmax_parts``."""
     head_dim = q.shape[-1]
     scale = scale if scale is not None else head_dim ** -0.5
     k = _repeat_kv(k, q.shape[1])
@@ -84,13 +94,21 @@ def ragged_decode_attention_reference(q, k, v, lengths, *, starts=None,
     if starts is not None:
         mask &= jnp.arange(k.shape[1])[None, :] >= starts[:, None]
     s = jnp.where(mask[:, None, :], s, NEG_INF)
+    if stats:
+        m = jnp.max(s, axis=-1)
+        p = jnp.where(mask[:, None, :], jnp.exp(s - m[..., None]), 0.0)
+        l = jnp.sum(p, axis=-1)
+        o = jnp.einsum("bhs,bshd->bhd", p.astype(v.dtype), v,
+                       preferred_element_type=jnp.float32)
+        return o / jnp.maximum(l, 1e-30)[..., None], m, l
     p = jax.nn.softmax(s, axis=-1)
     return jnp.einsum("bhs,bshd->bhd", p.astype(v.dtype), v)
 
 
 def paged_decode_attention_reference(q, k_pool, v_pool, block_tables,
                                      lengths, *, starts=None,
-                                     scale: Optional[float] = None):
+                                     scale: Optional[float] = None,
+                                     stats: bool = False):
     """XLA fallback: gather the slot's blocks into a dense view, then
     run the masked ragged reference. One extra HBM round-trip of the
     active context vs the Pallas path — correct everywhere, slower."""
@@ -101,11 +119,12 @@ def paged_decode_attention_reference(q, k_pool, v_pool, block_tables,
     k = k.reshape(B, maxb * bs, *k.shape[3:])
     v = v.reshape(B, maxb * bs, *v.shape[3:])
     return ragged_decode_attention_reference(q, k, v, lengths, starts=starts,
-                                             scale=scale)
+                                             scale=scale, stats=stats)
 
 
 def _paged_kernel(*refs, block_size: int, pages: int, max_blocks: int,
-                  scale: float, row_heads: int, windowed: bool):
+                  scale: float, row_heads: int, windowed: bool,
+                  stats: bool = False):
     import jax.experimental.pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
@@ -115,8 +134,13 @@ def _paged_kernel(*refs, block_size: int, pages: int, max_blocks: int,
         lens_ref, tables_ref, starts_ref, *refs = refs
     else:
         lens_ref, tables_ref, *refs = refs
-    (q_ref, bias_ref, k_hbm, v_hbm, o_ref, k_buf, v_buf, sems,
-     first_buf_ref, m_ref, l_ref, acc_ref) = refs
+    # ``stats``: two more outputs, the softmax's running max and sum
+    if stats:
+        (q_ref, bias_ref, k_hbm, v_hbm, o_ref, m_out_ref, l_out_ref,
+         *refs) = refs
+    else:
+        q_ref, bias_ref, k_hbm, v_hbm, o_ref, *refs = refs
+    k_buf, v_buf, sems, first_buf_ref, m_ref, l_ref, acc_ref = refs
 
     b = pl.program_id(0)
     # a page: rows (token, kv head), or (token, group of packed kv heads)
@@ -227,6 +251,12 @@ def _paged_kernel(*refs, block_size: int, pages: int, max_blocks: int,
     # length 0: every row was masked, and the output is 0, not their mean
     out = jnp.where(length > 0, acc_ref[...] / l_ref[:, :1], 0.0)
     o_ref[0] = out.astype(o_ref.dtype)
+    if stats:
+        # a slot that sees nothing: every row was masked, and the masked
+        # rows' own max and count are not its statistics
+        seen = length > (starts_ref[b] if windowed else 0)
+        m_out_ref[0] = jnp.where(seen, m_ref[...], NEG_INF)
+        l_out_ref[0] = jnp.where(seen, l_ref[...], 0.0)
 
 
 # Rows of a chunk (a page is bs * Hkv rows): what bounds the kernel's
@@ -252,13 +282,14 @@ def kernel_lowers(head_dim: int, kv_heads: int) -> bool:
     return _lane_pack(head_dim, kv_heads) * head_dim % LANES == 0
 
 
-@functools.partial(jax.jit,
-                   static_argnames=("num_blocks", "scale", "interpret"))
+@functools.partial(jax.jit, static_argnames=("num_blocks", "scale",
+                                             "interpret", "stats"))
 def paged_decode_attention_pallas(q, k_pool, v_pool, block_tables,
                                   lengths, starts=None, *, first_block=0,
                                   num_blocks: Optional[int] = None,
                                   scale: Optional[float] = None,
-                                  interpret: bool = False):
+                                  interpret: bool = False,
+                                  stats: bool = False):
     import jax.experimental.pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
@@ -313,7 +344,10 @@ def paged_decode_attention_pallas(q, k_pool, v_pool, block_tables,
             pl.BlockSpec(memory_space=pl.ANY),
             pl.BlockSpec(memory_space=pl.ANY),
         ],
-        out_specs=pl.BlockSpec((1, H, lanes), lambda b, *_: (b, 0, 0)),
+        out_specs=(
+            [pl.BlockSpec((1, H, lanes), lambda b, *_: (b, 0, 0))]
+            + [pl.BlockSpec((1, H, 128), lambda b, *_: (b, 0, 0))] * 2
+            if stats else pl.BlockSpec((1, H, lanes), lambda b, *_: (b, 0, 0))),
         scratch_shapes=[
             pltpu.VMEM((2, chunk_rows, lanes), k_pool.dtype),
             pltpu.VMEM((2, chunk_rows, lanes), v_pool.dtype),
@@ -334,9 +368,14 @@ def paged_decode_attention_pallas(q, k_pool, v_pool, block_tables,
     out = pl.pallas_call(
         functools.partial(_paged_kernel, block_size=bs, pages=pages,
                           max_blocks=maxb, scale=scale, row_heads=row_heads,
-                          windowed=windowed),
+                          windowed=windowed, stats=stats),
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((B, H, lanes), q.dtype),
+        # with its statistics the output stays float32: it is a PART of
+        # a softmax, weighed again by the caller
+        out_shape=(
+            [jax.ShapeDtypeStruct((B, H, lanes), jnp.float32)]
+            + [jax.ShapeDtypeStruct((B, H, 128), jnp.float32)] * 2
+            if stats else jax.ShapeDtypeStruct((B, H, lanes), q.dtype)),
         # slots run in order: each starts the next one's first copies
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",)),
@@ -344,9 +383,11 @@ def paged_decode_attention_pallas(q, k_pool, v_pool, block_tables,
     )(*scalars, q, bias,
       k_pool.reshape(NB, page_rows, lanes),
       v_pool.reshape(NB, page_rows, lanes))
+    if stats:
+        out, m, l = out
     if pack > 1:     # each q head's own lanes of its packed row
         out = out.reshape(B, H, pack, D)[:, jnp.arange(H), kv_head % pack]
-    return out
+    return (out, m[..., 0], l[..., 0]) if stats else out
 
 
 def default_impl(head_dim: int, kv_heads: int) -> str:
@@ -367,7 +408,8 @@ def default_impl(head_dim: int, kv_heads: int) -> str:
 def paged_decode_attention(q, k_pool, v_pool, block_tables, lengths, *,
                            impl: str, starts=None, first_block=0,
                            num_blocks: Optional[int] = None,
-                           scale: Optional[float] = None):
+                           scale: Optional[float] = None,
+                           stats: bool = False):
     """One algorithm, two implementations: ``impl`` is "pallas" (the
     kernel, interpreted where the backend is the CPU) or "xla" (its
     oracle).
@@ -380,14 +422,18 @@ def paged_decode_attention(q, k_pool, v_pool, block_tables, lengths, *,
     slice of the stack built; only the kernel's packed rows (D < 128),
     a copy in any case, copy the window alone. ``starts`` [B]: a
     sliding-window layer's first visible positions (the module's
-    docstring)."""
+    docstring). ``stats``: ``(o, m, l)`` in float32 instead of ``o``,
+    the softmax's output with its running max and sum [B, H]: ONE PART
+    of a softmax over several page lists, which the caller joins
+    (``ops.eva.merge_softmax_parts``: an EVA layer's exact pages and its
+    summary pages). Without it both sides are the programs they were."""
     if impl == "pallas":
         return paged_decode_attention_pallas(
             q, k_pool, v_pool, block_tables, lengths, starts,
             first_block=first_block, num_blocks=num_blocks, scale=scale,
-            interpret=pallas_interpret())
+            interpret=pallas_interpret(), stats=stats)
     if impl != "xla":
         raise ValueError(f"impl must be 'xla' or 'pallas', got {impl!r}")
     return paged_decode_attention_reference(
         q, k_pool, v_pool, first_block + block_tables, lengths,
-        starts=starts, scale=scale)
+        starts=starts, scale=scale, stats=stats)

@@ -365,6 +365,70 @@ def test_hybrid_cell_decode_program_fits_the_v5e(v5e, placed, monkeypatch):
     assert mem.temp_size_in_bytes < one_layer_experts * 1.05
 
 
+# evabyte-6.5b-d8.long_decode_eva: 16 slots x 24,576 at block 32; both
+# parts' tables are 65 entries a slot (the window's 64 blocks and one more;
+# the summary part needs 48), MHA 32/32 at 128
+EVA_CELL_SLOTS, EVA_CELL_TABLE, EVA_CELL_HEADS = 16, 65, (32, 32)
+
+
+def test_kernel_with_its_softmax_statistics_lowers_at_the_eva_cells_shape(v5e):
+    """``long_decode_eva``'s attention is the paged kernel twice a layer
+    (the window's pages, the summary pages), each call handing back its
+    softmax's running max and sum beside a float32 output."""
+    from ray_tpu.ops.paged_attention import paged_decode_attention_pallas
+
+    B, (H, Hkv), D, bs = EVA_CELL_SLOTS, EVA_CELL_HEADS, 128, CELL_BS
+    pool = v5e(B * 82 + 1, bs, Hkv, D)
+    lowered = paged_decode_attention_pallas.lower(
+        v5e(B, H, D), pool, pool, v5e(B, EVA_CELL_TABLE, dtype=jnp.int32),
+        v5e(B, dtype=jnp.int32), interpret=False, stats=True)
+    assert _mosaic(lowered)
+    out, m, l = lowered.out_info
+    assert out.dtype == m.dtype == l.dtype == jnp.float32
+    assert out.shape == (B, H, D) and m.shape == l.shape == (B, H)
+
+
+def test_eva_cell_decode_program_fits_the_v5e(v5e, placed, monkeypatch):
+    """``evabyte-6.5b-d8.long_decode_eva``'s decode program as the engine
+    jits it: 16 slots x 24,576, depth 8, a K/V pool a PART (82 exact
+    blocks and 48 summary blocks a slot-layer, + a scratch block each)
+    and a table a part. The v5e's compiler takes it at 11.17 GiB of 15.75
+    (3.04 of bf16 weights + 8.13 of pools) with both parts written in
+    place: the temporaries stay under one layer's smallest matmul
+    weight. ONE row a position would hold 48 GiB of K/V."""
+    from benchmark import run as harness
+    from benchmark.builders import evabyte
+    from ray_tpu.llm.paged_cache import window_blocks_per_slot
+
+    _as_on_the_chip(monkeypatch)
+    cfg = harness.load_json(harness.ROOT,
+                            "benchmark/configs/evabyte-6.5b-d8.json")
+    B, bs, max_seq = EVA_CELL_SLOTS, CELL_BS, 24_576
+    model = evabyte.build_model(cfg, max_seq)
+    window, chunk = model.eva
+    assert (window, chunk) == (2048, 16)
+    assert model.paged_decode_impl() == "pallas"
+    exact = B * window_blocks_per_slot(window, bs, 512)
+    summary = B * (max_seq // (bs * chunk))
+    assert (exact, summary) == (B * 82, B * 48)
+    assert max(window // bs + 1, max_seq // (bs * chunk)) == EVA_CELL_TABLE
+
+    pool = jax.eval_shape(lambda: model.init_kv_pools(
+        (summary + 1, exact + 1), bs))
+    assert pool["k"].shape[:2] == (8, exact + 1)
+    assert pool["sk"].shape[:2] == (8, summary + 1)
+    compiled = _engine_decode(model, summary).lower(
+        placed(_engine_params(model)), v5e(B, dtype=jnp.int32), placed(pool),
+        v5e(2, B, EVA_CELL_TABLE, dtype=jnp.int32), v5e(B, dtype=jnp.int32),
+        *_sampling(v5e, B), None).compile()
+    assert compiled.as_text().count("tpu_custom_call") >= 2
+    mem = compiled.memory_analysis()
+    total = (mem.argument_size_in_bytes + mem.temp_size_in_bytes
+             + mem.output_size_in_bytes - mem.alias_size_in_bytes)
+    assert 10 * 2**30 < total < 15.75 * 2**30
+    assert mem.temp_size_in_bytes < 4096 * 4096 * 2
+
+
 def test_smokes_decode_program_keeps_its_pool_in_place_on_the_v5e(
         v5e, placed, monkeypatch, smoke_sizes):
     """chip_smoke.py's pool phase, compiled here for the described v5e:

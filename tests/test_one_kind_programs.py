@@ -1,15 +1,20 @@
-"""A model with ONE kind of layer is served by the programs it was served
-by before layer kinds existed (PR 32): the StableHLO of an engine's five
-programs at the benchmark configurations' debug widths, hashed, against
-the hashes the parent of PR 32 (c95a537) gave with this container's jax.
+"""A model WITHOUT the layer kind a PR adds is served by the programs it
+was served by before: the StableHLO of an engine's five programs at the
+benchmark configurations' debug widths, hashed, against the hashes the
+parent gave with this container's jax.
+
+- The ONE-kind engines (``mistral-7b-v0.3-d6``, ``olmoe-1b-7b-d3``)
+  against the parent of PR 32 (c95a537), which brought layer kinds.
+- PR 35 (EVA attention: a third kind, two-part K/V): the one-kind hashes
+  stand, and the TWO-kind engine (``mellum2-12b-a2.5b-d8``: window and
+  full layers, a pool a kind) joins, against PR 35's parent (f9165a5).
 
 A PR that changes what these programs compute changes a hash, and says
 in ``CHANGES.md`` which operation and why, and writes the new hash here.
-A model with kinds has programs of its own and is not held to these.
 
-PR 33: the two ``decode`` hashes are its own. The sampler joined the
-decode program (an ``argmax`` over the logits; the key split, the top-k
-sort and the categorical draw inside conditionals of batch-level
+PR 33: the two one-kind ``decode`` hashes are its own. The sampler joined
+the decode program (an ``argmax`` over the logits; the key split, the
+top-k sort and the categorical draw inside conditionals of batch-level
 predicates) and the logits no longer leave it; the model's part of the
 program and the other four programs are the parent's.
 """
@@ -25,7 +30,7 @@ from benchmark import run as harness
 from ray_tpu.llm.engine import ContinuousBatchingEngine
 
 # sha256[:16] of ``lowered.as_text()``, computed on c95a537 (``decode``: on
-# PR 33's tree)
+# PR 33's tree; mellum2: on f9165a5)
 PARENT = {
     "mistral-7b-v0.3-d6": {
         "decode": "20fa90ecbf267886", "prefill": "8d7bc32d9dda3104",
@@ -35,6 +40,10 @@ PARENT = {
         "decode": "da59bfd928507fc8", "prefill": "3806801bc4a4887c",
         "insert": "47860a4b15fc27e3", "gather": "58895b3540c687ed",
         "prefill_prefix": "da9c8600e9ee52c6"},
+    "mellum2-12b-a2.5b-d8": {
+        "decode": "8da34f74c99fae38", "prefill": "de67ca28d69e5278",
+        "insert": "8b3a1532733cbdc8", "gather": "54aa86b2efd8fa61",
+        "prefill_prefix": "4bc563e68268b75f"},
 }
 
 
@@ -47,13 +56,16 @@ def lowered_programs(name: str) -> dict:
     eng = ContinuousBatchingEngine(
         model, jax.tree.map(lambda a: jnp.zeros(a.shape, a.dtype), params),
         max_slots=4, max_seq=128, prefill_buckets=(16, 32), block_size=8)
-    assert eng.window is None and model.layer_kinds is None
+    assert eng.eva is None and model.eva is None
+    # a table a kind, and ids a kind, where the model has two
+    kinds = () if model.layer_kinds is None else (2,)
+    assert (eng.window is None) == (model.layer_kinds is None)
 
     def S(*shape):
         return jax.ShapeDtypeStruct(shape, jnp.int32)
 
     pool = jax.eval_shape(lambda: eng.kv)
-    decode = [params, S(4), pool, S(4, eng.blocks_per_slot), S(4),
+    decode = [params, S(4), pool, S(*kinds, 4, eng.blocks_per_slot), S(4),
               jax.ShapeDtypeStruct((4,), jnp.float32), S(4),
               jax.eval_shape(lambda: jax.random.key(0)),
               eng._ffn_counts and S(*eng._ffn_counts[0].shape)]
@@ -62,8 +74,9 @@ def lowered_programs(name: str) -> dict:
         "decode": eng._decode.lower(*decode),
         "prefill": eng._prefill.lower(params, S(2, 32), S(2)),
         "insert": eng._insert.lower(
-            pool, jax.eval_shape(lambda: model.init_kv_cache(2, 32)), S(8)),
-        "gather": eng._gather.lower(pool, S(1, 4)),
+            pool, jax.eval_shape(lambda: model.init_kv_cache(2, 32)),
+            S(*kinds, 8)),
+        "gather": eng._gather.lower(pool, S(*kinds, 1, 4)),
         "prefill_prefix": eng._prefill_prefix.lower(
             params, S(1, 16), prefix["k"], prefix["v"], S(1), S(1)),
     }
@@ -80,5 +93,20 @@ def test_one_kind_program_is_the_parents(programs, program):
     text = lowered[program].as_text()
     assert hashlib.sha256(text.encode()).hexdigest()[:16] \
         == PARENT[name][program], (
-            f"{name}'s {program} program is no longer the one c95a537 "
+            f"{name}'s {program} program is no longer the one its parent "
             f"lowered: say in CHANGES.md which operation changed and why")
+
+
+def test_the_training_program_is_the_parents():
+    """``value_and_grad`` of the debug Llama's loss, the program the
+    train cells' model takes: PR 35's norm, residual and head options
+    leave it as f9165a5 lowered it."""
+    from ray_tpu.models.llama import LlamaConfig, LlamaModel
+
+    model = LlamaModel(LlamaConfig.debug())
+    params = jax.eval_shape(model.init, jax.random.key(0))
+    batch = jax.ShapeDtypeStruct((2, 64), jnp.int32)
+    text = jax.jit(jax.value_and_grad(model.loss)).lower(
+        params, batch, batch).as_text()
+    assert hashlib.sha256(text.encode()).hexdigest()[:16] \
+        == "36d429ac0c36f2f4"
