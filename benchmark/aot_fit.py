@@ -3,12 +3,17 @@
     JAX_PLATFORMS=cpu python3 -m benchmark.aot_fit <cell> [max_seq]
 
 Compiles the decode program of a ``serve_closed`` / ``serve_open`` cell
-as the engine jits it (the model's ``decode_step_paged`` with the pool
-donated; an expert model's counted step), at the published widths and
-the traffic file's engine shape, for a DESCRIBED v5e (the TPU's compiler
-is installed where no TPU is). Prints the compiler's memory analysis as
-one JSON line: GiB of arguments (weights + pool), of temporaries, and
-their sum against the chip's 15.75 GiB. A program that does not fit
+as the engine holds it: ``ContinuousBatchingEngine._decode_step_paged``
+(the model's step, counted for an expert model, and the sampler) with
+the pool donated, the weights as ``model.serving_params`` keeps them
+(bf16 matmul weights), and the K/V pools and tables of an engine that
+``ContinuousBatchingEngine.__init__`` itself laid out for the model's
+kind (``engine_of_shapes``: built on a model whose pools are shapes, so
+nothing is allocated and no layout is written down here), at the
+published widths and the traffic file's engine shape, for a DESCRIBED
+v5e (the TPU's compiler is installed where no TPU is). Prints the
+compiler's memory analysis as one JSON line: GiB of arguments (weights +
+pools), of temporaries, and their sum against the chip's 15.75 GiB. A program that does not fit
 raises what the chip's compiler would raise. Nothing runs: this is a
 size, never a time, and a size that passes here is still to be run on
 the chip (what else the process keeps there is not in it).
@@ -30,6 +35,47 @@ os.environ.setdefault("TPU_LOG_DIR", "disabled")
 CHIP_GIB = 15.75              # what a v5e's compiler has to place a program in
 
 
+class ShapesOnly:
+    """The model as an engine takes it, its K/V pools as shapes: an
+    engine built on it sizes pools and tables (its own ``__init__``)
+    and holds no array. The parameters go in as an empty tree."""
+
+    def __init__(self, model):
+        self._model = model
+
+    def __getattr__(self, name):
+        return getattr(self._model, name)
+
+    def serving_params(self, params):
+        return params
+
+    def init_kv_pool(self, *args):
+        import jax
+        return jax.eval_shape(lambda: self._model.init_kv_pool(*args))
+
+    def init_kv_pools(self, *args):
+        import jax
+        return jax.eval_shape(lambda: self._model.init_kv_pools(*args))
+
+
+def engine_of_shapes(model, slots: int, bs: int, max_seq: int):
+    """The engine ``LLMServer`` would build for this model and engine
+    shape (``ray_tpu/llm/serving.py``), with nothing on a device."""
+    from ray_tpu.llm.engine import ContinuousBatchingEngine
+
+    return ContinuousBatchingEngine(ShapesOnly(model), {}, max_slots=slots,
+                                    max_seq=max_seq, block_size=bs)
+
+
+def table_shape(eng) -> tuple:
+    """The block tables as ``_dispatch_decode`` sends them: one, or one
+    a kind (a part) stacked."""
+    import numpy as np
+
+    return (eng._tables if eng.window_pool is None
+            else np.stack([eng._tables, eng._tables_win])).shape
+
+
 def decode_memory(cell: str, max_seq: int | None = None) -> dict:
     import jax
     import jax.numpy as jnp
@@ -48,7 +94,6 @@ def decode_memory(cell: str, max_seq: int | None = None) -> dict:
                             w["traffic"] + ".json")["engine"]
     max_seq = int(max_seq or eng["max_seq"])
     B, bs = eng["max_slots"], eng["block_size"]
-    maxb = max_seq // bs
 
     paged_attention.on_chip = lambda: True
     paged_attention.pallas_interpret = lambda: False
@@ -65,22 +110,17 @@ def decode_memory(cell: str, max_seq: int | None = None) -> dict:
 
     model = importlib.import_module(
         "benchmark.builders." + cfg["builder"]).build_model(cfg, max_seq)
-    params = placed(jax.eval_shape(model.init, jax.random.key(0)))
-    pool = placed(jax.eval_shape(
-        lambda: model.init_kv_pool(B * maxb + 1, bs)))
-    args = [params, ints(B), pool, ints(B, maxb), ints(B)]
-    step = model.decode_step_paged
-    load_shape = model.ffn_load_shape()
-    if load_shape is not None:        # the engine's counted step
-
-        def step(params, tokens, pool, tables, offsets, load):
-            logits, pool, extras = model.decode_step_paged_counted(
-                params, tokens, pool, tables, offsets,
-                tables[:, 0] != B * maxb)
-            return logits, pool, load + extras["load"]
-
-        args.append(ints(*load_shape))
-    compiled = jax.jit(step, donate_argnums=(2,)).lower(*args).compile()
+    params = placed(jax.eval_shape(
+        lambda key: model.serving_params(model.init(key)),
+        jax.random.key(0)))
+    eng = engine_of_shapes(model, B, bs, max_seq)
+    pool = placed(eng.kv)
+    load = None if eng._ffn_counts is None else ints(*eng._ffn_counts[0].shape)
+    key = jax.eval_shape(lambda: jax.random.key(0))
+    compiled = jax.jit(eng._decode_step_paged, donate_argnums=(2,)).lower(
+        params, ints(B), pool, ints(*table_shape(eng)), ints(B),
+        jax.ShapeDtypeStruct((B,), jnp.float32, sharding=chip), ints(B),
+        key, load).compile()
     mem = compiled.memory_analysis()
 
     def gib(tree):

@@ -7,6 +7,12 @@ from __future__ import annotations
 import importlib
 
 
+class MisSized(RuntimeError):
+    """The cell's traffic does not fit the system it met (a closed-loop
+    window that would last a few seconds): the run fails by this
+    message and prints no result."""
+
+
 def load(kind: str):
     if not kind.replace("_", "").isalnum():
         raise ValueError(f"bad traffic kind {kind!r}")

@@ -3,15 +3,23 @@ its next request only when the last one ended.
 
 The window holds the same work in every run: all clients are admitted
 one at a time during set-up (one prefill shape: 1 x bucket) and have
-streamed a few tokens before the window opens, and ``max_tokens`` is
-sized so that no request ends inside it — no prefill, admission or
-refill inside the window — up to the step rate the run prints as its
-cap. Past the cap requests END inside the window: the clients resubmit,
-the engine prefills the resubmissions in groups no set-up ran (they
-compile there), and the window holds another cell's work. Such a run is
-reported as that (``requests_ended_in_window``, a line on stderr);
-``correct`` keeps its meaning. The rate is taken per client over WHOLE
-inter-token intervals (``lib.records.whole_interval_rate``) and summed.
+streamed a few tokens before the window opens, and NO REQUEST ENDS
+INSIDE IT — no prefill, admission or refill inside the window — at any
+step rate: the window closes at ``run_seconds`` OR just before the
+engine finishes its first request, whichever comes first
+(``watch_window``). ``max_tokens`` is sized so that at today's step
+rates the first never comes late: up to the rate the run prints as its
+cap every window lasts ``run_seconds``; past it the window SHRINKS (the
+tokens measured stay what the cell was sized for, the seconds fall) and
+the run says so (``counts.closed_early``, ``counts.window_s``, a line
+on stderr). A window that would come out under ``MIN_WINDOW_S`` is no
+measurement: the run fails and names the cell as mis-sized. What counts
+is the ENGINE's step count, read through its public ``stats``: a slot
+empties when the engine, not its client, reaches ``max_tokens``, and
+the clients lag the engine by up to thousands of tokens. The rate is
+taken per client over WHOLE inter-token intervals
+(``lib.records.whole_interval_rate``) between the open and the close as
+it really came, and summed.
 
 A traced run also reads the engine's ``stats`` at both edges of the
 traced span (``engine_trace_edges``): ``decode_program_roofline``
@@ -20,15 +28,27 @@ counts the K/V the timed program read from them.
 
 from __future__ import annotations
 
+import dataclasses
+import math
 import threading
 import time
 
+from benchmark import drivers
 from benchmark.lib import serving
 from benchmark.lib.records import RequestRecord, percentile
 
 STALL_FACTOR = 20        # a client silent for 20 median gaps has stalled
 STALL_DUMP_S = 0.5       # no token anywhere for this long: say where
 STALL_GRACE_S = 20.0     # a stream silent at the close may still resume
+MIN_WINDOW_S = 12.0      # a shorter window is no measurement (the trace
+                         # alone takes seconds 2-6): the cell is mis-sized
+LOOK_S = 1.0             # between two looks at the engine's steps near the cap
+LOOK_FAR_S = 6.0         # and while the cap is over two such gaps away: a look
+                         # reads an expert model's load off the device from
+                         # the replica's thread, so no more of them than the
+                         # close needs (PERF.md, PR 36); the second look
+                         # comes at 7 s, past the traced span (seconds 2-6)
+MIN_MARGIN_STEPS = 64    # the close keeps at least this far from the cap
 
 
 def watch_for_stalls(run, stamps, stop, limit: int = 3):
@@ -97,6 +117,71 @@ def resumed_by(stamps, last_stamp, silent, deadline: float,
     return [i for i in silent if i not in waiting]
 
 
+@dataclasses.dataclass
+class WindowClose:
+    t_closed: float
+    closed_early: bool      # before ``run_seconds``: the cap came first
+    steps_seen: int         # the engine's steps since the open, last look
+    margin_steps: int       # kept between the close and the cap
+    looks: int
+
+
+def watch_window(engine_steps, *, t_open: float, seconds: float,
+                 steps_open: int, steps_cap: int,
+                 clock=time.perf_counter, sleep=time.sleep) -> WindowClose:
+    """Wait for the window's close and say which it was: ``t_open +
+    seconds``, or the look at which the engine stood within a margin of
+    ``steps_cap``, the decode steps since the open after which its first
+    request ends (one token a slot a step).
+
+    ``engine_steps()`` reads the engine's ``decode_steps``: every
+    ``LOOK_S`` while no rate is known or the engine, at the highest rate
+    seen between two looks, could come within the margin of the cap in
+    two ``LOOK_FAR_S``; every ``LOOK_FAR_S`` while it is further off.
+    The margin is the steps of two ``LOOK_S`` at that rate (a stall in
+    one interval must not shrink it), and never under
+    ``MIN_MARGIN_STEPS``: a look that finds the engine just short of the
+    margin is followed by one within ``LOOK_S``, still a whole
+    ``LOOK_S`` of steps before any request ends. A look is the call the
+    traced span makes at both its edges; far from the cap none falls
+    inside that span, near it (every ``LOOK_S``) about four do."""
+    t_limit = t_open + seconds
+    t_prev, s_prev = t_open, steps_open
+    margin, looks, rate = MIN_MARGIN_STEPS, 0, 0.0
+    while True:
+        now = clock()
+        left = steps_cap - margin - (s_prev - steps_open)
+        far = rate > 0 and left > 2 * LOOK_FAR_S * rate
+        gap = LOOK_FAR_S if far else LOOK_S
+        if now + gap >= t_limit:
+            sleep(max(0.0, t_limit - now))
+            return WindowClose(clock(), False, s_prev - steps_open, margin,
+                               looks)
+        sleep(gap)
+        steps, now, looks = engine_steps(), clock(), looks + 1
+        rate = max(rate, (steps - s_prev) / max(now - t_prev, 1e-9))
+        margin = max(MIN_MARGIN_STEPS, math.ceil(2 * LOOK_S * rate))
+        if steps - steps_open >= steps_cap - margin:
+            return WindowClose(now, True, steps - steps_open, margin, looks)
+        t_prev, s_prev = now, steps
+
+
+def mis_sized(workload: str, close: WindowClose, t_open: float,
+              steps_cap: int, max_tokens: int):
+    """The message of a window that closed under ``MIN_WINDOW_S``, or
+    ``None``: a rate from so few seconds is not reported."""
+    window_s = close.t_closed - t_open
+    if not close.closed_early or window_s >= MIN_WINDOW_S:
+        return None
+    return (f"{workload} is MIS-SIZED for this engine: its first request "
+            f"of {max_tokens} tokens was {close.margin_steps} steps from "
+            f"its end {window_s:.1f} s after the window opened "
+            f"({close.steps_seen} of {steps_cap} decode steps), under the "
+            f"{MIN_WINDOW_S:.0f} s a window must last; no rate is "
+            f"reported. The cell needs longer requests, more slots or a "
+            f"deeper model: a benchmark PR's.")
+
+
 def requests_ended(records, lo: float, hi: float) -> int:
     """Requests whose finish chunk reached its client inside ``[lo, hi]``."""
     return sum(1 for r in records
@@ -147,9 +232,17 @@ def run(run) -> dict:
                 raise TimeoutError(f"client {i} got no token in time")
             time.sleep(0.002)
 
+    def engine_steps() -> int:
+        return serving.engine_stats(handle)["decode_steps"]
+
     for i, t in enumerate(threads):       # one admission at a time
         t.start()
         wait_tokens(i, 1)
+        if i == 0:
+            # the first request decodes in every step from here on: the
+            # engine's count of its tokens is one more than its steps
+            # since (read a step or two late, so a step or two short)
+            steps_first = engine_steps()
     for i in range(n_clients):
         wait_tokens(i, int(tr["min_streamed_before_window"]))
     run.phase("admit_clients")
@@ -158,13 +251,21 @@ def run(run) -> dict:
     counts0 = [len(s) for s in stamps]
     compiles0 = run.compiles.snapshot()["requests"]
     t_open = run.open_window()
-    t_close = t_open + run.seconds
+    # one token a slot a step: the window's steps may not outrun the
+    # tokens the longest-lived request had left when it opened, by the
+    # engine's count (its first request's) or its client's, whichever
+    # is further on
+    head_start = max(max(counts0), 1 + before["decode_steps"] - steps_first)
+    steps_cap = max_tokens - head_start
     watch_for_stalls(run, stamps, stop)
     run.trace_during(t_open, tr.get("trace_seconds", 4),
                      snapshot=lambda: serving.engine_stats(handle))
-    time.sleep(max(0.0, t_close - time.perf_counter()))
-    t_closed = time.perf_counter()
+    close = watch_window(engine_steps, t_open=t_open, seconds=run.seconds,
+                         steps_open=before["decode_steps"],
+                         steps_cap=steps_cap)
+    t_closed = close.t_closed
     counts1 = [len(s) for s in stamps]
+    stop.set()          # a request that ends from here on is not sent again
     # the clients are judged as the window closes: once the replica goes
     # down under them (below) every stream ends in an error that is the
     # shutdown's, not the system's
@@ -188,9 +289,12 @@ def run(run) -> dict:
 
     # the streams cannot be cancelled through the API and have minutes
     # to go: the replica goes down under them
-    stop.set()
     serve.shutdown()
     ray_tpu.shutdown()
+    if not run.tiny:            # a CPU run names no rate: nothing to refuse
+        message = mis_sized(run.workload, close, t_open, steps_cap, max_tokens)
+        if message:
+            raise drivers.MisSized(message)
 
     # how the window's inter-token gaps spread: a uniformly slow run and
     # one that stalled once read the same rate and differ here
@@ -200,21 +304,25 @@ def run(run) -> dict:
     failed = sum(bool(errored[i] or i in dead) for i in range(n_clients))
     requests = [r for rs in records for r in rs]
     ended = requests_ended(requests, t_open, t_closed)
-    # one token a slot a step: the window's steps may not outrun the
-    # tokens the shortest-lived request had left when it opened
     steps = after["decode_steps"] - before["decode_steps"]
-    steps_cap = max_tokens - max(counts0)
+    window_s = t_closed - t_open
     steps_per_s = cap = None            # a CPU run (--tiny-cpu) names no rate
     rates = ""
     if not run.tiny:
-        steps_per_s = steps / (t_closed - t_open)
-        cap = steps_cap / (t_closed - t_open)
-        rates = f" ({steps_per_s:.1f} against {cap:.1f} steps/s)"
-    run.log(f"{steps} decode steps in the window; a request of {max_tokens} "
-            f"tokens outlasts it up to {steps_cap}{rates}; {ended} "
-            f"request(s) ended inside it"
-            + (": PAST THE CAP, the window held refill and grouped prefill, "
-               "not this cell's work" if ended else ""))
+        steps_per_s = steps / window_s
+        # the rate past which the window begins to shrink
+        cap = (steps_cap - close.margin_steps) / run.seconds
+        rates = f" ({steps_per_s:.1f} steps/s; the window shrinks past {cap:.1f})"
+    run.log(f"window closed "
+            + (f"EARLY after {window_s:.1f} of {run.seconds:.0f} s, "
+               f"{close.margin_steps} steps before the engine's first "
+               f"request ends" if close.closed_early
+               else f"at its {run.seconds:.0f} s")
+            + f": {steps} decode steps of the {steps_cap} a request of "
+            f"{max_tokens} tokens had left{rates}; {close.looks} look(s) at "
+            f"the engine; {ended} request(s) ended inside it"
+            + (": the close came too late, the window held refill and "
+               "grouped prefill, not this cell's work" if ended else ""))
     return {
         "kind": "serve_closed", "correct": bool(checks["ok"]) and failed == 0,
         "attempted": n_clients, "failed": failed, "checks": checks,
@@ -228,6 +336,8 @@ def run(run) -> dict:
             if r.first_token_at and t_open <= r.first_token_at <= t_closed),
         "tokens_received_in_window": sum(counts1) - sum(counts0),
         "requests_ended_in_window": ended,
+        "window_s": window_s, "closed_early": close.closed_early,
+        "cap_steps": steps_cap, "margin_steps": close.margin_steps,
         "stalled_at_close": len(resumed),
         "decode_steps_per_s": steps_per_s, "cap_steps_per_s": cap,
         "token_gap_ms": gap_ms,

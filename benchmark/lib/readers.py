@@ -57,6 +57,46 @@ def program_ms_per_call(rec, needle: str) -> Optional[float]:
     return 1e3 * secs / calls if calls else None
 
 
+def streamed_tokens_per_s(rec) -> Optional[float]:
+    """Tokens the clients received per second: each client's rate over
+    the WHOLE inter-token intervals it saw between the window's open and
+    its close as it came, summed over the clients."""
+    total = 0.0
+    for stamps in rec.get("stamps", []):
+        rate, _, _ = whole_interval_rate(stamps, rec["t_open"], rec["t_close"])
+        total += rate or 0.0
+    return total or None
+
+
+def live_tokens_per_step(rec) -> Optional[float]:
+    """Tokens whose K/V one decode step of the traced span read, summed
+    over the slots (``d decode_kv_blocks_live x block_size / d
+    decode_steps`` between the engine's two snapshots inside the span);
+    ``None`` where the span has no two snapshots, no counter or no step."""
+    edges = rec.get("engine_trace_edges") or []
+    if len(edges) != 2 or "decode_kv_blocks_live" not in edges[0]:
+        return None
+    steps = edges[1]["decode_steps"] - edges[0]["decode_steps"]
+    blocks = (edges[1]["decode_kv_blocks_live"]
+              - edges[0]["decode_kv_blocks_live"])
+    if steps <= 0:
+        return None
+    return blocks * rec["traffic"]["engine"]["block_size"] / steps
+
+
+def decode_program_roofline(rec, needle: str) -> Optional[float]:
+    """Least time one decode step could take (the configuration's
+    ``costs.decode_step_bytes`` at the traced span's live K/V over the
+    chip's memory bandwidth) over the time the program took, %."""
+    ms = program_ms_per_call(rec, needle)
+    live = live_tokens_per_step(rec)
+    if ms is None or live is None or not rec.get("peaks"):
+        return None
+    least_s = rec["costs"].decode_step_bytes(
+        rec["config"], live) / rec["peaks"]["hbm_bytes_per_s"]
+    return 100.0 * least_s / (ms / 1e3)
+
+
 def ok_requests(rec):
     return [r for r in rec.get("requests", []) if r.ok]
 

@@ -11,7 +11,12 @@ slots together. Block-granular, as the paged kernel reads: up to
 ``block_size - 1`` tokens a slot over the cached ones (16 of ~800 on
 average at the cells' lengths: under 2 % of the K/V term, 0.3 % of the
 bytes). An untraced run, a driver that takes no such snapshots or a
-span in which no decode step ran reads nothing."""
+span in which no decode step ran reads nothing.
+
+The ``.stream`` twin of ``decode_program_roofline``: the same reading in
+the cell whose clients' rate the Serve stream path sets
+(``batch_decode``), where it moves ``serve_out_tokens_per_s.stream`` and
+that metric's wider bound."""
 
 from benchmark.lib import readers
 
@@ -21,10 +26,7 @@ LAYER = "Kernels"
 UNIT = "%"
 BETTER = "higher"
 SOURCE = "device_trace"
-MOVES = "serve_out_tokens_per_s"
-
-
-live_tokens_per_step = readers.live_tokens_per_step
+MOVES = "serve_out_tokens_per_s.stream"
 
 
 def read(rec):

@@ -9,12 +9,16 @@ driver in ``benchmark/drivers``; every metric the cell reports is read
 from the run's record by ``benchmark/metrics/<name>.py``. Set-up (load,
 init from the seed, warm-up, correctness check) ends when the window
 opens and is reported as ``setup_s``; then it measures for ``--seconds``
-and prints one JSON object as the last line of stdout.
+(a closed-loop cell: or until just before its first request would end,
+``drivers/serve_closed.py``) and prints one JSON object as the last
+line of stdout.
 
 No TPU, or fewer chips than the cell asks for: exit 3, nothing on
-stdout. ``--tiny-cpu`` is the explicit request for the CPU at debug
-widths (four virtual devices); it prints counts only, never a time, a
-rate or a share.
+stdout. A cell whose traffic no longer fits the system (a closed-loop
+window that its first request's end would cut to a few seconds): exit 5,
+the reason on stderr, nothing on stdout. ``--tiny-cpu`` is the explicit
+request for the CPU at debug widths (four virtual devices); it prints
+counts only, never a time, a rate or a share.
 """
 
 from __future__ import annotations
@@ -35,6 +39,7 @@ import threading
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 HERE = os.path.join(ROOT, "benchmark")
 EXIT_NO_CHIP = 3
+EXIT_MIS_SIZED = 5
 DEADLINE_S = 1150            # stacks are dumped and the run ends
 
 
@@ -87,6 +92,7 @@ class Run:
         self.compiles = None
         self.phases: list = []
         self._last = T_START
+        self.t_backend_up = None
         self.t_open = None
         self._trace_thread = None
         self.trace_edges: list = []
@@ -100,6 +106,13 @@ class Run:
         self.phases.append([name, now - self._last])
         self._last = now
         self.log(f"{name}: {self.phases[-1][1]:.2f} s")
+
+    def backend_up(self) -> None:
+        """``setup_s`` runs from here: what came before is the machine
+        starting its runtime, which varies by seconds from run to run
+        and holds no work of the program or of the benchmark."""
+        self.phase("backend_start")
+        self.t_backend_up = self._last
 
     def open_window(self, at: float | None = None) -> float:
         self.t_open = at if at is not None else time.perf_counter()
@@ -186,16 +199,21 @@ def start_backend(run: Run, trace: bool):
     compile cache, the compile counter and the tracer. Returns the
     device as JAX reports it, or ``None`` where the cell's chips are not
     there: no chip, no result."""
-    from ray_tpu._private.platform import (enable_compile_cache,
-                                           force_cpu_platform, on_chip)
     if run.tiny:
+        from ray_tpu._private.platform import force_cpu_platform
         force_cpu_platform(4)
+    # nothing of the repo is imported before the backend is up on the
+    # chip: what the program's imports cost counts as set-up
     import jax
+    run.phase("import_jax")
+    devices = jax.devices()          # a backend that cannot start raises
+    run.backend_up()
+
+    from ray_tpu._private.platform import enable_compile_cache, on_chip
 
     from benchmark.lib import device as devlib
     from benchmark.lib.trace import Tracer
 
-    devices = jax.devices()          # a backend that cannot start raises
     info = devlib.device_info()
     if not run.tiny and (not on_chip(devices[0])
                          or len(devices) < run.chips):
@@ -208,7 +226,7 @@ def start_backend(run: Run, trace: bool):
     run.compiles = devlib.CompileCounter()
     if trace:
         run.tracer = Tracer(os.path.join(ROOT, ".bench_out", "trace"))
-    run.phase("process_start_and_backend")
+    run.phase("program_imports_and_cache")
     run.log(f"{run.workload} seed={run.seed} seconds={run.seconds} "
             f"trace={int(trace)} on {info} cache={cache_dir}")
     return info
@@ -236,12 +254,18 @@ def main(argv=None) -> int:
     from benchmark.lib.peaks import peaks_for
 
     from benchmark import drivers
-    record = drivers.load(run.traffic["kind"]).run(run)
+    try:
+        record = drivers.load(run.traffic["kind"]).run(run)
+    except drivers.MisSized as e:
+        reap_children(run.log)
+        run.log(f"NO RESULT: {e}")
+        sys.stderr.flush()
+        os._exit(EXIT_MIS_SIZED)     # as below: threads may still block
 
     record.update(
         tiny=run.tiny, seconds=run.seconds, chips=run.chips,
         config=run.config, traffic=run.traffic, costs=run.costs,
-        setup_s=run.t_open - T_START,
+        setup_s=run.t_open - run.t_backend_up,
         peaks=None if run.tiny else peaks_for(info["kind"]),
         trace=run.tracer.reduced if run.tracer else None,
         memory_peak_bytes=devlib.memory_peak_bytes(run.chips))
@@ -271,8 +295,9 @@ def main(argv=None) -> int:
     line["counts"] = {k: record[k] for k in (
         "compiles_in_window", "tokens_received_in_window",
         "first_tokens_in_window", "requests_ended_in_window",
-        "stalled_at_close",
-        "decode_steps_per_s", "cap_steps_per_s", "token_gap_ms",
+        "window_s", "closed_early", "stalled_at_close",
+        "decode_steps_per_s", "cap_steps_per_s", "cap_steps",
+        "margin_steps", "token_gap_ms",
         "backlog_at_close", "warm",
         "offered_rate_per_s", "client_ms") if k in record}
     for k in ("engine_before", "engine_after", "engine_trace_edges"):

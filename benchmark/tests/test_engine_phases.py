@@ -35,18 +35,35 @@ NEW = [m for m in BENCH["per_layer"]
 OLD_KEYS = ("decode_steps", "tokens_generated", "preemptions")
 
 
-def test_the_sixteen_metrics_are_declared_for_their_cells():
-    assert len(NEW) == 16
-    traffic_of = {w["name"]: w["traffic"] for w in BENCH["workloads"]}
+def test_the_phase_metrics_are_declared_for_their_cells():
+    """A ``.decode`` or ``.stream`` reader is declared for every
+    closed-loop cell and a ``.chat`` reader for every open-loop one, by
+    the KIND of the cell's traffic (its driver), whatever the traffic or
+    the configuration is called: the suffix says which end-to-end metric
+    the reader moves, and a reader's cells are that metric's cells of
+    that kind. The two closed-loop metrics share no cell and leave none
+    out."""
+    assert len(NEW) == 16 + 7            # the seven ``.decode`` twins
+    kind_of = {w["name"]: harness.load_json(
+        harness.HERE, "traffic", w["traffic"] + ".json")["kind"]
+        for w in BENCH["workloads"]}
+    moved = {m["name"]: m for m in BENCH["end_to_end"]}
     for m in NEW:
         assert m["layer"] == "Engine scheduler"
         assert m["source"] == "program_counter"
-        prefix = {"decode": "batch_decode", "chat": "chat_mixed"}[
+        kind, moves = {
+            "decode": ("serve_closed", "serve_out_tokens_per_s"),
+            "stream": ("serve_closed", "serve_out_tokens_per_s.stream"),
+            "chat": ("serve_open", "tpot_p50_ms")}[
             m["name"].rsplit(".", 1)[1]]
-        # every cell of that kind of traffic, whatever its configuration
-        assert m["workloads"]
-        for cell in m["workloads"]:
-            assert traffic_of[cell].startswith(prefix)
+        assert m["moves"] == moves
+        # every cell of that kind that reports the metric, and no other
+        assert sorted(m["workloads"]) == sorted(
+            c for c in moved[moves]["workloads"] if kind_of[c] == kind)
+    closed = (moved["serve_out_tokens_per_s"]["workloads"]
+              + moved["serve_out_tokens_per_s.stream"]["workloads"])
+    assert sorted(closed) == sorted(
+        c for c, k in kind_of.items() if k == "serve_closed")
 
 
 @pytest.mark.parametrize("name", [m["name"] for m in NEW])
