@@ -377,6 +377,33 @@ class LlamaModel:
             for k, v in params["layers"].items()}
         return out
 
+    # The leaves of ``params["layers"]`` a SERVING program's layer scan
+    # never hands its body a layer's slice of: the body closes over the
+    # stack whole and addresses the layer's part of it (``_whole_leaves``).
+    # None here: every dense matmul reads its slice in place (XLA fuses
+    # the ``dynamic-slice`` into the dot). ``MoEModel`` names its expert
+    # stacks, whose grouped matmuls cannot.
+    WHOLE_LAYER_LEAVES: Tuple[str, ...] = ()
+
+    def _whole_leaves(self, layers: Params):
+        """What a serving program's layer scan does with
+        ``params["layers"]``: ``(xs, stacks)``. ``xs`` is what it slices
+        a layer at a time; ``stacks`` is what its body closes over and
+        hands ``_layer``: ``WHOLE_LAYER_LEAVES`` with their two leading
+        dimensions merged (``[L, E, ...]`` as ``[L*E, ...]``, the same
+        bytes), and then ``xs`` carries each layer's number under
+        ``"index"``, by which the body addresses the layer's part. A
+        model that names no such leaf, or has a mesh (merging L with a
+        sharded dimension would re-shard the stack), gets ``(layers,
+        None)``: its scans are what they were."""
+        names = () if self.mesh is not None else self.WHOLE_LAYER_LEAVES
+        if not names:
+            return layers, None
+        xs = {k: v for k, v in layers.items() if k not in names}
+        xs["index"] = jnp.arange(self.cfg.n_layers, dtype=jnp.int32)
+        return xs, {k: layers[k].reshape((-1,) + layers[k].shape[2:])
+                    for k in names}
+
     # -- sharding helpers ---------------------------------------------------
     def _constrain(self, x, *names):
         if self.mesh is None:
@@ -498,13 +525,15 @@ class LlamaModel:
         [B, T, Hkv, hd]; the dense layer does nothing here."""
         return q, k
 
-    def _ffn(self, h, layer: Params, live=None, constrain: bool = False):
+    def _ffn(self, h, layer: Params, live=None, constrain: bool = False,
+             stacks: Optional[Params] = None):
         """The feed-forward half: h [B, T, D] (already normed) ->
         ``(out [B, T, D], extra)``. ``extra`` is whatever the model
         wants carried out of the layer scan (``None`` here); ``live``
         [B] bool marks the rows it should count (all, if ``None``);
         ``constrain`` (the training program) pins the inner activation's
-        sharding to the mesh."""
+        sharding to the mesh; ``stacks`` is ``_whole_leaves``' (always
+        ``None`` here: the dense model names no whole leaf)."""
         dt = self.cfg.dtype
         with jax.named_scope("mlp"):
             gate = jnp.einsum("bsd,df->bsf", h, layer["w_gate"].astype(dt))
@@ -534,7 +563,8 @@ class LlamaModel:
                                   kind, positions)
 
     def _layer(self, x, layer: Params, positions, attend, live=None,
-               constrain: bool = False, kind=None):
+               constrain: bool = False, kind=None,
+               stacks: Optional[Params] = None):
         """One decoder layer. x [B, T, D]; ``positions`` what RoPE turns
         q and k by (``None``: 0..T-1), by the table of the layer's
         ``kind`` (its index, traced; None in the plain model);
@@ -542,9 +572,9 @@ class LlamaModel:
         with q/o [B, T, H, hd] and k/v [B, T, Hkv, hd], the calling
         program's own: it writes this call's K/V where the program keeps
         them, reads the earlier ones, and hands back as ``kv`` whatever
-        the program's layer scan carries on or stacks up. ``live`` is
-        ``_ffn``'s; ``constrain`` (the training program alone) pins the
-        activations' sharding to the mesh.
+        the program's layer scan carries on or stacks up. ``live`` and
+        ``stacks`` are ``_ffn``'s; ``constrain`` (the training program
+        alone) pins the activations' sharding to the mesh.
         -> (x, ``kv``, the layer's ``_ffn`` extra)."""
         cfg = self.cfg
         dt = cfg.dtype
@@ -568,7 +598,7 @@ class LlamaModel:
         with jax.named_scope("norm_residual"):
             x = x + pin(o, "batch", "seq", "embed")
             h = self._norm(x, layer["mlp_norm"])
-        down, extra = self._ffn(h, layer, live, constrain)
+        down, extra = self._ffn(h, layer, live, constrain, stacks)
         with jax.named_scope("norm_residual"):
             return x + pin(down, "batch", "seq", "embed"), kv, extra
 
@@ -708,12 +738,14 @@ class LlamaModel:
                                             window=self._window(kind))
                 return o, (k_all, v_all)
 
-            x, kv, _ = self._layer(x, layer, q_pos, attend, kind=kind)
+            x, kv, _ = self._layer(x, layer, q_pos, attend, kind=kind,
+                                   stacks=stacks)
             return x, kv
 
+        layers, stacks = self._whole_leaves(params["layers"])
         x, kv = jax.lax.scan(
             step, self._embed(params, tokens),
-            (params["layers"], cache["k"], cache["v"], self._kinds_xs()))
+            (layers, cache["k"], cache["v"], self._kinds_xs()))
         return self._head(params, x), self._kv_dict(kv)
 
     @staticmethod
@@ -832,6 +864,11 @@ class LlamaModel:
         (``init_kv_pools``: the stack as it comes, ``bases`` beside it)
         with a table a kind.
 
+        A model's ``WHOLE_LAYER_LEAVES`` (an expert model's stacks) live
+        through the step whole too: the body closes over them and
+        ``_ffn`` reads the layer's part in place (``_whole_leaves``), as
+        in ``forward_step`` and ``prefill_with_prefix``.
+
         An EVA model has a body of its own (``_decode_step_eva``)."""
         if self.eva is not None:
             return self._decode_step_eva(params, tokens, pool, block_tables,
@@ -883,14 +920,15 @@ class LlamaModel:
                 return o[:, None], (k_all, v_all)
 
             x, (k_pool, v_pool), extra = self._layer(
-                x, layer, q_pos, attend, live=live, kind=kind)
+                x, layer, q_pos, attend, live=live, kind=kind, stacks=stacks)
             return (x, k_pool, v_pool), extra
 
+        layers, stacks = self._whole_leaves(params["layers"])
         (x, k_out, v_out), extras = jax.lax.scan(
             step,
             (self._embed(params, tokens[:, None]),                 # [B,1,D]
              pool["k"].reshape(stack), pool["v"].reshape(stack)),
-            (params["layers"],
+            (layers,
              pool["bases"] if NB is None
              else jnp.arange(L, dtype=jnp.int32) * NB,
              kinds))
@@ -1090,12 +1128,14 @@ class LlamaModel:
                         window=self._window(kind))
                 return o, (k_new, v_new)
 
-            x, kv, _ = self._layer(x, layer, pos_q, attend, kind=kind)
+            x, kv, _ = self._layer(x, layer, pos_q, attend, kind=kind,
+                                   stacks=stacks)
             return x, kv
 
+        layers, stacks = self._whole_leaves(params["layers"])
         x, (k_out, v_out) = jax.lax.scan(
             step, self._embed(params, tokens),
-            (params["layers"], prefix_k, prefix_v, self._kinds_xs()))
+            (layers, prefix_k, prefix_v, self._kinds_xs()))
         return (self._head(params, x, last=lengths - 1)[:, 0],
                 {"k": k_out, "v": v_out})
 

@@ -15,6 +15,20 @@ dropless_expert_ffn``): every chosen expert is computed. Under ``ep`` > 1
 it is the capacity-bounded GShard dispatch (tokens beyond an expert's
 capacity are dropped), as one-hot einsums or as an explicit all-to-all
 (``moe_dispatch``); the mesh decides, not a flag.
+
+The serving programs hold the expert stacks WHOLE: their layer scans
+close over ``e_gate`` / ``e_up`` / ``e_down`` as ``[L*E, ...]`` and the
+grouped matmuls read layer ``l``'s experts in place, as groups ``l*E``
+onwards (``WHOLE_LAYER_LEAVES``, ``LlamaModel._whole_leaves``,
+``dropless_expert_ffn(first_expert=)``). Handed to a scan as ``xs`` each
+stack's slice was copied out a layer a step, since a grouped matmul's
+operand must be a buffer of its own: more time than the matmuls took
+(PERF.md, PR 37). What the caller hands in decides, not a flag, and two
+callers keep the slice, each for a reason the code can see: TRAINING
+(``apply``, under ``grad``: the transpose of a whole-stack operand is a
+stack-sized gradient a LAYER) and a model with a MESH (the ``ep``
+capacity paths' einsums slice for free, and merging ``L`` with a sharded
+``experts`` dimension would re-shard the stack).
 """
 
 from __future__ import annotations
@@ -109,6 +123,8 @@ class MoEModel(LlamaModel):
     # a bf16 router would choose other experts
     MATMUL_LAYER_LEAVES = ("wq", "wk", "wv", "wo",
                            "e_gate", "e_up", "e_down")
+    # the grouped matmuls' operands (the router is a dense matmul's)
+    WHOLE_LAYER_LEAVES = ("e_gate", "e_up", "e_down")
 
     def __init__(self, cfg: MoEConfig, mesh=None,
                  rules: Optional[Dict] = None):
@@ -161,20 +177,24 @@ class MoEModel(LlamaModel):
         with jax.named_scope("qk_norm"):
             return norm(q, layer["q_norm"]), norm(k, layer["k_norm"])
 
-    def _ffn(self, h, layer: Params, live=None, constrain: bool = False):
+    def _ffn(self, h, layer: Params, live=None, constrain: bool = False,
+             stacks: Optional[Params] = None):
         """(``constrain`` is the dense FFN's: the expert dispatches place
         their own.) h [B, T, D] -> (out, {"aux": training loss of the router,
         "load": [E] rows handed to each expert, of ``live`` slots,
         "experts": [B, T, K] each token's chosen experts}). Under an
         ``ep`` mesh axis the capacity dispatch runs and only "aux" is
-        there (``ffn_load_shape`` says so)."""
+        there (``ffn_load_shape`` says so). With ``stacks`` (a serving
+        program off a mesh) the expert weights are every layer's,
+        ``[L*E, ...]``, and this layer's begin at ``layer["index"] * E``."""
         cfg: MoEConfig = self.cfg
         shared = dict(top_k=cfg.expert_top_k, dtype=cfg.dtype,
                       norm_topk_prob=cfg.norm_topk_prob,
                       z_coef=cfg.router_z_loss,
                       lb_coef=cfg.load_balance_loss)
-        weights = (layer["router"], layer["e_gate"], layer["e_up"],
-                   layer["e_down"])
+        held = layer if stacks is None else stacks
+        weights = (layer["router"], held["e_gate"], held["e_up"],
+                   held["e_down"])
         if self._ep > 1:
             from ray_tpu.ops.moe_dispatch import (capacity_einsum_ffn,
                                                   expert_alltoall_ffn)
@@ -191,7 +211,9 @@ class MoEModel(LlamaModel):
         B, T, D = h.shape
         rows_live = None if live is None else jnp.repeat(live, T)
         out, load, experts, aux = dropless_expert_ffn(
-            h.reshape(B * T, D), *weights, live=rows_live, **shared)
+            h.reshape(B * T, D), *weights, live=rows_live,
+            first_expert=(None if stacks is None
+                          else layer["index"] * cfg.num_experts), **shared)
         return out.reshape(B, T, D), {
             "aux": aux, "load": load,
             "experts": experts.reshape(B, T, cfg.expert_top_k)}

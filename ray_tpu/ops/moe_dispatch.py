@@ -7,6 +7,11 @@
   kernel on the TPU, plain loops elsewhere), outputs un-sorted and
   summed with the router's weights. Every chosen expert is computed: no
   capacity, no drop, and nothing whose size grows faster than T * K.
+  The expert weights are ONE layer's ``[E, ...]`` or, with
+  ``first_expert``, every layer's stack ``[L*E, ...]`` of which the
+  layer's E groups alone hold rows (the serving programs: a grouped
+  matmul's operand is a buffer of its own, so a layer's SLICE of the
+  stack is a copy of it, 268 MB a stack a layer: PERF.md, PR 37).
 - the capacity-bounded GShard pair, kept for an ``ep`` mesh axis
   (ROADMAP R2 decides their future): ``capacity_einsum_ffn`` (dense
   one-hot ``[T, E, C]`` dispatch/combine einsums, XLA's partitioner
@@ -56,6 +61,7 @@ def router_aux_loss(logits, probs, z_coef: float, lb_coef: float):
 
 def dropless_expert_ffn(x, router, e_gate, e_up, e_down, *, top_k: int,
                         norm_topk_prob: bool, dtype, live=None,
+                        first_expert=None,
                         z_coef: float = 0.0, lb_coef: float = 0.0):
     """x [T, D] -> ``(out [T, D] in ``dtype``, load [E] int32, experts
     [T, K] int32, aux)``.
@@ -65,6 +71,14 @@ def dropless_expert_ffn(x, router, e_gate, e_up, e_down, *, top_k: int,
     the tokens ``live`` [T] bool marks (all, if ``None``): it sums to
     ``live.sum() * top_k`` because nothing is dropped. ``aux`` is the
     training loss of ``router_aux_loss``.
+
+    With ``first_expert`` (a traced int32 scalar) the weights are G >= E
+    groups, [G, D, F] and [G, F, D], a stack of several layers' experts
+    read in place: this layer's are groups ``first_expert ...
+    first_expert + E``, and every other group gets no row, so the
+    grouped matmul neither visits it nor fetches its weights. The rows,
+    their order and the E non-empty groups are the sliced call's, and
+    so is every bit of the result.
     """
     T, D = x.shape
     E = router.shape[-1]
@@ -82,6 +96,10 @@ def dropless_expert_ffn(x, router, e_gate, e_up, e_down, *, top_k: int,
         rows = x.astype(dtype)[order // top_k]        # [T*K, D]
         load = sizes if live is None else jnp.zeros((E,), jnp.int32).at[
             flat].add(jnp.repeat(live.astype(jnp.int32), top_k))
+        if first_expert is not None:
+            sizes = jax.lax.dynamic_update_slice(
+                jnp.zeros((e_gate.shape[0],), jnp.int32), sizes,
+                (first_expert,))
     with jax.named_scope("moe_experts"):
         def grouped(lhs, w):
             return jax.lax.ragged_dot(lhs, w.astype(dtype), sizes,

@@ -288,12 +288,14 @@ def test_olmoe_cell_decode_program_fits_the_v5e(v5e, placed, monkeypatch):
     """``olmoe-1b-7b-d3.batch_decode_moe``'s decode program as the engine
     jits it (the counted step: 32 slots x 4096, depth 3, all 64 experts,
     matmul weights in bf16 as the engine holds them): the v5e's compiler
-    takes it (6.49 GiB of 15.75; 11.56 on float32 weights, whose bf16
-    copies were 3.10 GiB of temporaries), with the paged kernel and the
-    grouped matmuls as Mosaic calls. The temporaries that remain (0.75
-    GiB) are ONE layer's three expert stacks, sliced out of the stacked
-    weights as operands of the grouped-matmul calls: under the bf16
-    weights' bytes, where a cast of every weight would equal them."""
+    takes it (5.74 GiB of 15.75: 5.73 of arguments, 2.73 weights + 3.00
+    pool; 11.56 on float32 weights, whose bf16 copies were 3.10 GiB of
+    temporaries), with the paged kernel and the grouped matmuls as
+    Mosaic calls. The temporaries that remain (0.014 GiB) hold nothing
+    stack-shaped: the grouped-matmul calls read the expert stacks whole
+    and in place (PR 37). Until then ONE layer's slice of a stack at a
+    time was copied out as their operand: 0.25 GiB of temporaries, 5.98
+    in all, and three copies a layer a step on the chip."""
     from ray_tpu.models import MoEConfig, model_for
 
     _as_on_the_chip(monkeypatch)
@@ -317,9 +319,10 @@ def test_olmoe_cell_decode_program_fits_the_v5e(v5e, placed, monkeypatch):
     total = (mem.argument_size_in_bytes + mem.temp_size_in_bytes
              + mem.output_size_in_bytes - mem.alias_size_in_bytes)
     assert total < 15.75 * 2**30
-    experts = sum(params["layers"][k].size * 2
-                  for k in ("e_gate", "e_up", "e_down"))
-    assert mem.temp_size_in_bytes < experts / L * 1.05
+    # the expert stacks are read in place (PR 37): not one layer's slice
+    # of one stack is copied out for the grouped-matmul calls
+    one_stack_a_layer = params["layers"]["e_gate"].size * 2 / L
+    assert mem.temp_size_in_bytes < one_stack_a_layer / 4
 
 
 def test_hybrid_cell_decode_program_fits_the_v5e(v5e, placed, monkeypatch):
@@ -327,11 +330,12 @@ def test_hybrid_cell_decode_program_fits_the_v5e(v5e, placed, monkeypatch):
     the engine jits it: 32 slots x 16,384, depth 8 (S S S F twice), all
     64 experts, a K/V pool a kind as one stack (2 full layers of 16,385
     blocks, 6 sliding layers of 1,601) and a table a kind. The v5e's
-    compiler takes it at 10.41 GiB of 15.75 (9.66 of arguments: 7.07
-    weights + 2.59 of pools; 0.74 of temporaries, one layer's three
-    expert stacks); ONE pool for all eight layers would hold 8.0 GiB
-    of K/V where the two hold 2.59, and with weights and temporaries
-    pass the chip's 15.75."""
+    compiler takes it at 9.68 GiB of 15.75 (9.66 of arguments: 7.07
+    weights + 2.59 of pools; 0.025 of temporaries, nothing of an expert
+    stack's shape among them: 0.25, one layer's slice of one stack at a
+    time, until PR 37); ONE pool for all eight layers would hold 8.0
+    GiB of K/V where the two hold 2.59, and with the weights pass the
+    chip's 15.75."""
     from benchmark import run as harness
     from benchmark.builders import mellum
     from ray_tpu.llm.paged_cache import window_blocks_per_slot
@@ -359,10 +363,11 @@ def test_hybrid_cell_decode_program_fits_the_v5e(v5e, placed, monkeypatch):
     total = (mem.argument_size_in_bytes + mem.temp_size_in_bytes
              + mem.output_size_in_bytes - mem.alias_size_in_bytes)
     assert total < 15.75 * 2**30
-    # the pool is written in place: nothing pool-sized among the
-    # temporaries, which are one layer's expert stacks and no more
-    one_layer_experts = 3 * E * 2304 * 896 * 2
-    assert mem.temp_size_in_bytes < one_layer_experts * 1.05
+    # the pool is written in place and the expert stacks are read in
+    # place (PR 37): nothing pool-sized among the temporaries, and not
+    # one layer's slice of one expert stack
+    one_stack_a_layer = E * 2304 * 896 * 2
+    assert mem.temp_size_in_bytes < one_stack_a_layer / 4
 
 
 # evabyte-6.5b-d8.long_decode_eva: 16 slots x 24,576 at block 32; both

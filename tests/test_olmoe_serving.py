@@ -197,9 +197,12 @@ def test_bf16_compute_with_the_reference_forced_to_its_routing(path, seed):
 
 # -- what the tolerance must refuse -----------------------------------------
 class _CapacityDrop(MoEModel):
-    """The FFN this PR replaced: 1.25 x T x K / E rows an expert."""
+    """The FFN this PR replaced: 1.25 x T x K / E rows an expert (its
+    einsums read a layer's slice of the expert stacks, as under ``ep``)."""
 
-    def _ffn(self, h, layer, live=None, constrain=False):
+    WHOLE_LAYER_LEAVES = ()
+
+    def _ffn(self, h, layer, live=None, constrain=False, stacks=None):
         c = self.cfg
         out, aux = moe_dispatch.capacity_einsum_ffn(
             h, layer["router"], layer["e_gate"], layer["e_up"],

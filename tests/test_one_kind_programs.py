@@ -17,6 +17,15 @@ the decode program (an ``argmax`` over the logits; the key split, the
 top-k sort and the categorical draw inside conditionals of batch-level
 predicates) and the logits no longer leave it; the model's part of the
 program and the other four programs are the parent's.
+
+PR 37: the two EXPERT models' ``decode``, ``prefill`` and
+``prefill_prefix`` hashes are its own. Their layer scans close over the
+expert stacks ``[L*E, ...]`` and hand the body the layer's index where
+they sliced ``e_gate`` / ``e_up`` / ``e_down`` a layer at a time, and
+``ragged_dot``'s ``group_sizes`` is the layer's E counts placed into a
+zero ``[L*E]`` vector (``dynamic_update_slice``); every other operation,
+``insert`` and ``gather``, and all five of the dense model's programs
+are the parent's.
 """
 
 import hashlib
@@ -30,20 +39,21 @@ from benchmark import run as harness
 from ray_tpu.llm.engine import ContinuousBatchingEngine
 
 # sha256[:16] of ``lowered.as_text()``, computed on c95a537 (``decode``: on
-# PR 33's tree; mellum2: on f9165a5)
+# PR 33's tree; mellum2: on f9165a5; the expert models' ``decode``,
+# ``prefill`` and ``prefill_prefix``: on PR 37's tree)
 PARENT = {
     "mistral-7b-v0.3-d6": {
         "decode": "20fa90ecbf267886", "prefill": "8d7bc32d9dda3104",
         "insert": "1b106dfa26607af4", "gather": "c3d4dde8973ad705",
         "prefill_prefix": "625968f268b7e03b"},
     "olmoe-1b-7b-d3": {
-        "decode": "da59bfd928507fc8", "prefill": "3806801bc4a4887c",
+        "decode": "2655b19927cbf11f", "prefill": "492cb54525c2b3ce",
         "insert": "47860a4b15fc27e3", "gather": "58895b3540c687ed",
-        "prefill_prefix": "da9c8600e9ee52c6"},
+        "prefill_prefix": "c8f08df0f7c5c35c"},
     "mellum2-12b-a2.5b-d8": {
-        "decode": "8da34f74c99fae38", "prefill": "de67ca28d69e5278",
+        "decode": "78510f869b7968a7", "prefill": "546ac83a2d1f6993",
         "insert": "8b3a1532733cbdc8", "gather": "54aa86b2efd8fa61",
-        "prefill_prefix": "4bc563e68268b75f"},
+        "prefill_prefix": "62933ba3014f7270"},
 }
 
 

@@ -208,13 +208,17 @@ CONVERT = re.compile(
 
 def weight_casts(model, lowered) -> list:
     """float32 -> bf16 converts in the lowered program whose operand has
-    a weight's shape (a layer's slice of a stack, or a whole leaf)."""
+    a weight's shape (a layer's slice of a stack, a whole leaf, or an
+    expert stack as the serving programs hold it, ``[L*E, ...]``)."""
     shapes = set()
     for leaf in jax.tree.leaves(jax.eval_shape(model.init,
                                                jax.random.key(0))):
         if leaf.ndim >= 2:
             shapes.add("x".join(map(str, leaf.shape)))
             shapes.add("x".join(map(str, leaf.shape[1:])))
+        if leaf.ndim == 4:
+            shapes.add("x".join(map(str, (leaf.shape[0] * leaf.shape[1],)
+                                    + leaf.shape[2:])))
     return [s for s in CONVERT.findall(lowered.as_text()) if s in shapes]
 
 

@@ -1,14 +1,32 @@
 """Time the candidates for the expert FFN's grouped matmuls on the chip.
 
-    chiprun -- python3 tools/moe_gmm_bench.py
+    chiprun -- python3 tools/moe_gmm_bench.py            # the whole stack
+    chiprun -- python3 tools/moe_gmm_bench.py candidates # PR 26's sweep
 
-OLMoE-1B-7B's shapes (64 experts of 2048 x 1024, top-8): rows T*K = 256
-(a 32-slot decode step) and 2,048 / 12,288 (prefill of 256 / 1,536
-tokens), rows sorted by expert. Candidates: ``jax.lax.ragged_dot`` and
-the Pallas ``megablox.gmm`` shipped with jax, each with the weights
-stored in bfloat16 and, as the program stores them, in float32 cast
-inside the timed program. Prints one JSON line per (rows, candidate);
-PERF.md (PR 26) holds the readings that chose ``ragged_dot``.
+**The whole stack (PR 37).** A decode step's 256 rows (32 slots, top-8)
+through one layer's three grouped matmuls, at both expert cells' shapes
+(OLMoE: L 3, 64 experts of 2048 x 1024; Mellum2: L 8, 2304 x 896), the
+weights in bfloat16 as an engine holds them:
+
+- ``layer_buffer``: the weights are one layer's ``[E, D, F]`` buffer: the
+  call a layer scan makes AFTER it has copied the layer's slice out;
+- ``whole_stack@first`` / ``@last``: the weights are the stack
+  ``[L*E, D, F]`` and ``group_sizes`` is zero but for the layer's E
+  entries at ``l*E`` (what ``dropless_expert_ffn(first_expert=l*E)``
+  hands ``ragged_dot``), at the first and the last layer's offset;
+- ``gmm_whole_stack@...``: jax's Pallas ``megablox.gmm`` on the same
+  operands (the fallback ISSUE 37 names);
+- ``scan_sliced`` / ``scan_whole``: a ``lax.scan`` over the L layers, the
+  stacks handed as ``xs`` (the copy a layer and stack included) or closed
+  over whole, in ms a LAYER: what the serving programs pay.
+
+**``candidates``**: OLMoE's shapes at rows 256 and 2,048 / 12,288
+(prefill of 256 / 1,536 tokens), ``jax.lax.ragged_dot`` against
+``megablox.gmm`` at three tilings, each with the weights stored in
+bfloat16 and in float32 cast inside the timed program. PERF.md (PR 26)
+holds the readings that chose ``ragged_dot``.
+
+Prints one JSON line per reading.
 """
 
 from __future__ import annotations
@@ -22,13 +40,21 @@ import jax.numpy as jnp
 import numpy as np
 
 E, D, F, K = 64, 2048, 1024, 8
+# the two expert cells: (name, layers, hidden, one expert's width, the
+# tiling the v5e compiler prints for ``ragged_dot`` at these shapes)
+STACKS = (("olmoe-1b-7b-d3", 3, 2048, 1024, (256, 512, 512)),
+          ("mellum2-12b-a2.5b-d8", 8, 2304, 896, (256, 256, 128)))
+
+
+def swiglu(grouped, xs, wg, wu, wd, sizes):
+    act = jax.nn.silu(grouped(xs, wg, sizes)) * grouped(xs, wu, sizes)
+    return grouped(act.astype(jnp.bfloat16), wd, sizes)
 
 
 def ffn(grouped):
     def run(xs, wg, wu, wd, sizes):
         wg, wu, wd = (w.astype(jnp.bfloat16) for w in (wg, wu, wd))
-        act = jax.nn.silu(grouped(xs, wg, sizes)) * grouped(xs, wu, sizes)
-        return grouped(act.astype(jnp.bfloat16), wd, sizes)
+        return swiglu(grouped, xs, wg, wu, wd, sizes)
     return jax.jit(run)
 
 
@@ -48,20 +74,84 @@ def megablox(tiling):
     return run
 
 
-def timed(fn, args, reps=20):
+def timed(fn, args, reps=200, rounds=5):
+    """ms a call with ``reps`` calls in the device's queue at once: a
+    half-millisecond call is shorter than a dispatch and its wake-up, so
+    a clock around ONE call (PR 26's readings) reads the host too."""
     jax.block_until_ready(fn(*args))
     out = []
-    for _ in range(reps):
+    for _ in range(rounds):
         t0 = time.perf_counter()
-        jax.block_until_ready(fn(*args))
-        out.append(time.perf_counter() - t0)
+        for _ in range(reps):
+            last = fn(*args)
+        jax.block_until_ready(last)
+        out.append((time.perf_counter() - t0) / reps)
     return 1e3 * float(np.median(out))
 
 
-def main():
-    dev = jax.devices()[0]
-    if dev.platform != "tpu":
-        sys.exit("moe_gmm_bench: no TPU; a CPU time is not a reading")
+def routed_sizes(rng, rows):
+    idx = np.stack([rng.permutation(E)[:K] for _ in range(rows // K)])
+    return np.bincount(idx.ravel(), minlength=E).astype(np.int32)
+
+
+def whole_stack(rows=256):
+    rng = np.random.default_rng(0)
+    for name, L, d, f, tiling in STACKS:
+        keys = jax.random.split(jax.random.key(0), 3)
+        # [L, E, ., .] as ``params["layers"]`` holds them
+        wg, wu, wd = (jax.random.normal(k, s, jnp.bfloat16) * 0.02
+                      for k, s in zip(keys, ((L, E, d, f), (L, E, d, f),
+                                             (L, E, f, d))))
+        merged = tuple(w.reshape((L * E,) + w.shape[2:])
+                       for w in (wg, wu, wd))
+        sizes = jnp.asarray(routed_sizes(rng, rows))
+        xs = jnp.asarray(rng.normal(size=(rows, d)), jnp.bfloat16)
+
+        def padded(first):
+            return jax.lax.dynamic_update_slice(
+                jnp.zeros((L * E,), jnp.int32), sizes, (first,))
+
+        def one_layer(grouped):
+            return jax.jit(lambda xs, wg, wu, wd, first: swiglu(
+                grouped, xs, wg, wu, wd, padded(first)))
+
+        def scan_sliced(xs, wg, wu, wd):
+            def body(x, w):
+                return swiglu(ragged, x, *w, sizes), None
+            return jax.lax.scan(body, xs, (wg, wu, wd))[0]
+
+        def scan_whole(xs, wg, wu, wd):
+            def body(x, l):
+                return swiglu(ragged, x, wg, wu, wd, padded(l * E)), None
+            return jax.lax.scan(body, xs, jnp.arange(L, dtype=jnp.int32))[0]
+
+        buffers = tuple(w[L - 1] for w in (wg, wu, wd))
+        want = np.asarray(ffn(ragged)(xs, *buffers, sizes), np.float32)
+        readings = [("layer_buffer", ffn(ragged), (xs, *buffers, sizes), 1)]
+        for impl, grouped in (("whole_stack", ragged),
+                              ("gmm_whole_stack", megablox(tiling))):
+            for at, l in (("first", 0), ("last", L - 1)):
+                readings.append((f"{impl}@{at}", one_layer(grouped),
+                                 (xs, *merged, jnp.int32(l * E)), 1))
+        readings += [("scan_sliced", jax.jit(scan_sliced),
+                      (xs, wg, wu, wd), L),
+                     ("scan_whole", jax.jit(scan_whole), (xs, *merged), L)]
+        for impl, fn, args, calls in readings:
+            line = {"shapes": name, "groups": L * E, "rows": rows,
+                    "impl": impl}
+            try:
+                line["ms"] = timed(fn, args) / calls
+                if impl.endswith("@last"):
+                    got = np.asarray(fn(*args), np.float32)
+                    line["max_abs_vs_layer_buffer"] = float(
+                        np.abs(got - want).max())
+            except Exception as e:     # noqa: BLE001 — a refusal is a reading
+                line["error"] = f"{type(e).__name__}: {str(e)[:200]}"
+            print(json.dumps(line), flush=True)
+        del wg, wu, wd, merged, buffers, readings
+
+
+def candidates():
     rng = np.random.default_rng(0)
     keys = jax.random.split(jax.random.key(0), 3)
     w32 = [jax.random.normal(k, s, jnp.float32) * 0.02 for k, s in
@@ -72,15 +162,14 @@ def main():
              "gmm_256_2048_512": megablox((256, 2048, 512)),
              "gmm_512_1024_1024": megablox((512, 1024, 1024))}
     for rows in (256, 2048, 12288):
-        idx = np.stack([rng.permutation(E)[:K] for _ in range(rows // K)])
-        sizes = jnp.asarray(np.bincount(idx.ravel(), minlength=E), jnp.int32)
+        sizes = jnp.asarray(routed_sizes(rng, rows))
         xs = jnp.asarray(rng.normal(size=(rows, D)), jnp.bfloat16)
         want = None
         for name, grouped in cands.items():
             for stored, ws in (("bf16", w16), ("f32", w32)):
                 try:
                     fn = ffn(grouped)
-                    ms = timed(fn, (xs, *ws, sizes))
+                    ms = timed(fn, (xs, *ws, sizes), reps=50)
                     got = np.asarray(fn(xs, *ws, sizes), np.float32)
                     want = got if want is None else want
                     err = float(np.abs(got - want).max())
@@ -90,6 +179,15 @@ def main():
                     line = {"rows": rows, "impl": name, "stored": stored,
                             "error": f"{type(e).__name__}: {str(e)[:200]}"}
                 print(json.dumps(line), flush=True)
+
+
+def main():
+    if jax.devices()[0].platform != "tpu":
+        sys.exit("moe_gmm_bench: no TPU; a CPU time is not a reading")
+    if sys.argv[1:] == ["candidates"]:
+        candidates()
+    else:
+        whole_stack()
 
 
 if __name__ == "__main__":
