@@ -1,0 +1,20 @@
+"""Phase ``engine.sample_readback`` (the engine thread waiting for a decode step's
+tokens to reach the host) per decode step: ``t_readback_s`` / ``decode_steps``,
+over the WHOLE window. A diagnosis more than a score: near the decode program's
+ms the cell is device-bound (the host waits for the device: the good case, hence
+``higher``), near 0 the host sets the step.
+
+The ``.chat`` twin of ``engine.readback_wait_ms_per_step.decode``: the same
+reading in ``chat_mixed``, where it moves ``tpot_p50_ms``."""
+
+from benchmark.lib import engine_phases
+
+LAYER = "Engine scheduler"
+UNIT = "ms"
+BETTER = "higher"
+SOURCE = "program_counter"
+MOVES = "tpot_p50_ms"
+
+
+def read(rec):
+    return engine_phases.ms_per_step(rec, "t_readback_s")

@@ -1,0 +1,22 @@
+"""Items handed to the clients (``stream_items_consumed``: every ref a consumer's
+``next`` was given) over items the engine put on its requests' streams
+(``stream_puts``: tokens and end markers) in the window: 100 where the clients
+keep up with the engine, under it where the Serve stream path sets their rate
+and a backlog grows behind them (the mark of a stream-bound cell).
+
+The ``.stream`` twin of ``serve.stream_consumed_share.decode``: the same
+reading in the cell whose clients' rate the Serve stream path sets
+(``batch_decode``), where it moves ``serve_out_tokens_per_s.stream`` and that
+metric's wider bound."""
+
+from benchmark.lib import stream_phases
+
+LAYER = "Serve ingress, router, replica"
+UNIT = "%"
+BETTER = "higher"
+SOURCE = "program_counter"
+MOVES = "serve_out_tokens_per_s.stream"
+
+
+def read(rec):
+    return stream_phases.consumed_share(rec)

@@ -80,11 +80,82 @@ class _InFlightTask:
         self.lock = threading.Lock()
 
 
+# A stream's items 0, 16, 32 ... BY THEIR INDEX are its sampled items:
+# the ones whose handling both ends time and at which each end's thread
+# publishes its CPU seconds (docs/serving.md, "The stream path")
+STREAM_SAMPLE_MASK = 15
+
+
+class _ThreadCpu:
+    """CPU time a stream's producing or consuming thread has spent on
+    it, as that thread published it: it reads its OWN
+    ``time.thread_time_ns()`` when it takes the stream up (the consumer:
+    at item 0), at every sampled item and when the stream ends, and
+    ``ns`` is the sum of the differences between its consecutive
+    readings (a reading by another thread, a retry's or a second
+    consumer's, starts anew). No other thread's clock is ever read: a
+    thread that has exited leaves a dangling handle."""
+
+    __slots__ = ("ns", "ident", "_last")
+
+    def __init__(self):
+        self.ns = 0
+        self.ident = None               # of the thread that read last
+
+    def publish(self) -> None:
+        now, me = time.thread_time_ns(), threading.get_ident()
+        if self.ident == me:
+            self.ns += now - self._last
+        self._last, self.ident = now, me
+
+
+class SampledItem:
+    """A sampled item on the thread that handles it: a replica's chunk,
+    from its token taken off the request's stream to the runtime having
+    reported it; a consumer's, from ``next_ref`` having returned to the
+    value being in hand. ONE pair of clock reads feeds ``span`` (the
+    caller's annotation for jax's profiler, on the device trace's clock
+    and a flag check while no profiler runs; None where the process has
+    not loaded jax) and the cells ``timed_ns`` / ``timed_items`` of
+    ``account``, which this thread alone writes (the engine's ``_Phase``
+    idiom). Never around a wait for an item: a device gap belongs to the
+    span that covers most of it (``benchmark/lib/trace.py``)."""
+
+    __slots__ = ("account", "span", "t0")
+
+    def __init__(self, account, span):
+        self.account = account
+        self.span = span
+
+    def __enter__(self):
+        if self.span is not None:
+            self.span.__enter__()
+        self.t0 = time.perf_counter_ns()
+        return self
+
+    def __exit__(self, *exc):
+        account = self.account
+        account.timed_ns += time.perf_counter_ns() - self.t0
+        account.timed_items += 1
+        if self.span is not None:
+            self.span.__exit__(*exc)
+        return False
+
+
 class GeneratorState:
     """Producer/consumer state for a streaming-generator task.
 
     Reference: ``ReportGeneratorItemReturns`` proactive item reporting +
     ``GeneratorBackpressureWaiter`` (core_worker/generator_waiter.h).
+
+    It is also the stream's account, which ``Runtime.generator_stats``
+    sums over all streams: ``produced`` (items reported) and
+    ``handed_out`` (items ``next_ref`` returned; ``consumed`` is the
+    high-water mark of the index, for back-pressure), each end's CPU
+    time, and what the consumer timed of its sampled items. Every cell
+    has ONE writing thread or is written under ``cond``, and is an
+    integer (times in ns): sums of them are exact in any order, so a
+    reading never goes down when a stream moves into the totals.
     """
 
     def __init__(self, backpressure_num_objects: int = -1):
@@ -92,9 +163,27 @@ class GeneratorState:
         self.items: List[ObjectRef] = []
         self.produced = 0
         self.consumed = 0
+        self.handed_out = 0
         self.finished = False
         self.error: Optional[BaseException] = None
         self.backpressure = backpressure_num_objects
+        self.producer_cpu = _ThreadCpu()
+        self.consumer_cpu = _ThreadCpu()
+        self.timed_ns = 0               # the consumer's ``SampledItem``s
+        self.timed_items = 0
+        # the consumer was told the stream's end; ``generator_stats``
+        # has folded the account into the runtime's totals
+        self.read_to_end = False
+        self.folded = False
+
+    def account(self) -> Dict[str, Any]:
+        """The cells ``Runtime.generator_stats`` sums."""
+        return {"stream_items_reported": self.produced,
+                "stream_items_consumed": self.handed_out,
+                "stream_producer_cpu_ns": self.producer_cpu.ns,
+                "stream_consumer_cpu_ns": self.consumer_cpu.ns,
+                "stream_consume_ns": self.timed_ns,
+                "stream_items_timed_consume": self.timed_items}
 
     def report_item(self, ref: ObjectRef) -> None:
         with self.cond:
@@ -119,9 +208,12 @@ class GeneratorState:
                 if index < len(self.items):
                     ref = self.items[index]
                     self.consumed = max(self.consumed, index + 1)
+                    self.handed_out += 1
                     self.cond.notify_all()
-                    return ref
+                    break
                 if self.finished:
+                    self.consumer_cpu.publish()
+                    self.read_to_end = True
                     if self.error is not None:
                         raise self.error
                     raise StopIteration
@@ -131,6 +223,21 @@ class GeneratorState:
                     if remaining <= 0:
                         raise exc.GetTimeoutError("generator item timeout")
                 self.cond.wait(remaining)
+        if not index & STREAM_SAMPLE_MASK:
+            # outside the stream's lock; the consumer's first reading is
+            # item 0's (the wait for it cost no CPU)
+            self.consumer_cpu.publish()
+        return ref
+
+
+def _ns_as_seconds(sums: Dict[str, int]) -> Dict[str, Any]:
+    """``stats``' form of summed cells: a ``*_ns`` key as ``*_s``."""
+    return {(k[:-2] + "s" if k.endswith("_ns") else k):
+            (v * 1e-9 if k.endswith("_ns") else v) for k, v in sums.items()}
+
+
+# ``Runtime.generator_stats`` of a runtime that has seen no stream
+NO_STREAMS = _ns_as_seconds(dict(GeneratorState().account(), streams_live=0))
 
 
 class Runtime:
@@ -233,6 +340,10 @@ class Runtime:
         self._remote_actors: Dict[ActorID, Any] = {}
 
         self._generators: Dict[TaskID, GeneratorState] = {}
+        # what the streams that ended and were read to their end
+        # counted (``generator_stats`` folds them in)
+        self._generator_totals = GeneratorState().account()
+        self._generator_stats_lock = threading.Lock()
 
         # ICI-topology-aware gang scheduling: when a slice topology is
         # declared, TPU placement-group bundles claim contiguous
@@ -1709,6 +1820,8 @@ class Runtime:
         # (streams are assumed deterministic, as in lineage reconstruction).
         skip = len(state.items)
         from ray_tpu._private import failpoints as _fp
+        cpu = state.producer_cpu
+        cpu.publish()                   # this thread takes the stream up
         try:
             for item in gen:
                 if _fp.ENABLED:
@@ -1724,8 +1837,12 @@ class Runtime:
                 self.futures.complete(oid)
                 ref = ObjectRef(oid, owner_hex=self.worker_id.hex(),
                                 task_name=spec.name)
+                sampled = not state.produced & STREAM_SAMPLE_MASK
                 state.report_item(ref)
+                if sampled:
+                    cpu.publish()
         except BaseException as e:  # noqa: BLE001
+            cpu.publish()
             from ray_tpu._private.worker_process import WorkerCrashed
             if isinstance(e, WorkerCrashed):
                 # System failure mid-stream (worker process died): retry
@@ -1739,6 +1856,7 @@ class Runtime:
             state.finish(te.as_instanceof_cause())
             self._fail_task(spec, te)
             return
+        cpu.publish()
         state.finish()
         # The task's own return value is the generator handle sentinel.
         for oid in spec.return_ids:
@@ -1747,7 +1865,36 @@ class Runtime:
         self._on_task_done(spec, TaskState.FINISHED)
 
     def generator_state(self, task_id: TaskID) -> GeneratorState:
-        return self._generators.setdefault(task_id, GeneratorState())
+        # every ``next`` of a consumer comes through here: no throwaway
+        # state (a lock, a deque, the account's cells) for ``setdefault``
+        state = self._generators.get(task_id)
+        if state is None:
+            state = self._generators.setdefault(task_id, GeneratorState())
+        return state
+
+    def generator_stats(self) -> Dict[str, Any]:
+        """What this process's runtime has seen of its streaming
+        generators, Serve's or not (docs/serving.md, "The stream path"):
+        the sums of every stream's account, and ``streams_live``, the
+        streams begun and not ended. A stream that ended and was read
+        to its end is folded into running totals here, so the sums no
+        longer depend on its state (the states themselves stay in
+        ``_generators`` for the life of the runtime)."""
+        with self._generator_stats_lock:
+            totals = self._generator_totals
+            out = dict(totals, streams_live=0)
+            for state in list(self._generators.values()):
+                if state.folded:
+                    continue
+                # before the cells: an ended stream's are final
+                ended = state.finished and state.read_to_end
+                out["streams_live"] += not state.finished
+                for key, value in state.account().items():
+                    out[key] += value
+                    if ended:
+                        totals[key] += value
+                state.folded = ended
+            return _ns_as_seconds(out)
 
     # ------------------------------------------------------------------
     # actors

@@ -94,12 +94,26 @@ class SamplingParams:
 class Request:
     _ids = itertools.count()
 
-    def __init__(self, prompt_tokens: List[int], sampling: SamplingParams):
+    def __init__(self, prompt_tokens: List[int], sampling: SamplingParams,
+                 readers: Optional["_StreamReaders"] = None):
         self.id = next(Request._ids)
         self.prompt = list(prompt_tokens)
         self.sampling = sampling
         self.output: List[int] = []
+        # ``(token, decode step that delivered it)``; ``(None, step)``
+        # ends the stream
         self.stream: "queue.Queue" = queue.Queue()
+        # the reading thread's account of this stream (docs/serving.md,
+        # "The stream path"), written by that ONE thread and summed by
+        # the engine's ``stats`` while ``readers`` holds the request:
+        # items taken off ``stream``, the step that delivered the newest
+        # of them, and what ``LLMServer.stream`` timed of its sampled
+        # items (integer ns: the engine's sums are exact in any order)
+        self._readers = readers
+        self.takes = 0
+        self.step = 0
+        self.timed_ns = 0
+        self.timed_items = 0
         self.submitted_at = time.perf_counter()
         self.queued_at = self.submitted_at   # reset when a preemption requeues
         self.first_token_at: Optional[float] = None
@@ -123,27 +137,76 @@ class Request:
 
     def iter_tokens(self):
         """Stream tokens as they are generated; raises if the engine
-        died under the request."""
-        while True:
-            tok = self.stream.get()
-            if tok is None:
-                self.raise_if_failed()
-                return
-            yield tok
+        died under the request. The request is in the engine's
+        ``stream_takes`` sum from here until the stream has been READ to
+        its end (or the reader gives up), which with a backlog is long
+        after its slot was released."""
+        readers = self._readers
+        if readers is not None:
+            readers.begin(self)
+        try:
+            while True:
+                tok, self.step = self.stream.get()
+                self.takes += 1
+                if tok is None:
+                    self.raise_if_failed()
+                    return
+                yield tok
+        finally:
+            if readers is not None:
+                readers.end(self)
 
-    def fail(self, err: BaseException) -> None:
+    def fail(self, err: BaseException, step: int = 0) -> bool:
+        """Fail the request; True if that put the stream's end marker."""
         if self.done.is_set():
-            return
+            return False
         self.error = err
         self.finish_reason = "error"
         self.finished_at = time.perf_counter()
-        self.stream.put(None)
+        self.stream.put((None, step))
         self.done.set()
+        return True
 
     def raise_if_failed(self) -> None:
         if self.error is not None:
             raise EngineDeadError(
                 f"engine loop died: {self.error!r}") from self.error
+
+
+class _StreamReaders:
+    """The requests whose streams are being read, and what the readers
+    of the ended ones counted. A reading thread writes its cells on the
+    ``Request`` with no lock and takes this one twice a stream; ``sums``
+    adds the live cells to the ended streams' totals when ``stats`` is
+    asked."""
+
+    def __init__(self):
+        self._lock = threading.Lock()
+        self._live: set = set()
+        self._takes = self._timed_ns = self._timed_items = 0
+
+    def begin(self, req: Request) -> None:
+        with self._lock:
+            self._live.add(req)
+
+    def end(self, req: Request) -> None:
+        with self._lock:
+            self._live.discard(req)
+            self._takes += req.takes
+            self._timed_ns += req.timed_ns
+            self._timed_items += req.timed_items
+            # a second reading of the same request starts from nothing
+            req.takes = req.timed_ns = req.timed_items = 0
+
+    def sums(self) -> Dict[str, Any]:
+        with self._lock:
+            live = list(self._live)
+            return {
+                "stream_takes": self._takes + sum(r.takes for r in live),
+                "stream_produce_s": 1e-9 * (self._timed_ns + sum(
+                    r.timed_ns for r in live)),
+                "stream_items_timed_produce": self._timed_items + sum(
+                    r.timed_items for r in live)}
 
 
 class _Phase:
@@ -308,6 +371,7 @@ class ContinuousBatchingEngine:
         # dispatched and not read yet, between two ``step()``s
         self._in_flight: Optional[tuple] = None
         self._lock = threading.Lock()
+        self._readers = _StreamReaders()
         self._rng_key = jax.random.key(0)
         self.error: Optional[BaseException] = None   # set once, by run_forever
 
@@ -397,6 +461,18 @@ class ContinuousBatchingEngine:
                       "t_enqueue_s": 0.0, "t_readback_s": 0.0,
                       "t_emit_s": 0.0, "t_deliver_s": 0.0, "t_idle_s": 0.0,
                       "cpu_host_s": 0.0,
+                      # the stream path (docs/serving.md, "The stream
+                      # path"): items put on the requests' streams
+                      # (tokens and end markers; this thread, or under
+                      # the lock), items their readers took off them,
+                      # what ``LLMServer.stream`` timed of its sampled
+                      # items (the readers' cells, summed when ``stats``
+                      # is asked), and the CPU seconds of the thread(s)
+                      # in ``step()``, waiting phases included
+                      "stream_puts": 0, "stream_takes": 0,
+                      "stream_produce_s": 0.0,
+                      "stream_items_timed_produce": 0,
+                      "engine_thread_cpu_s": 0.0,
                       # expert models (0 / empty for a dense one): rows
                       # the expert FFN processed for live slots in decode
                       # steps, what a dropless FFN must have processed
@@ -412,7 +488,9 @@ class ContinuousBatchingEngine:
     @property
     def stats(self) -> Dict[str, Any]:
         """The counters (one dict, updated in place). Asking for them is
-        what reads an expert model's load back from the device."""
+        what reads an expert model's load back from the device and sums
+        the stream readers' cells."""
+        self._stats.update(self._readers.sums())
         if self._ffn_counts is not None:
             load, expected = self._ffn_counts       # one pair, one step
             load = np.asarray(load)
@@ -588,7 +666,8 @@ class ContinuousBatchingEngine:
     # -- public API --------------------------------------------------------
     def submit(self, prompt_tokens: List[int],
                sampling: Optional[SamplingParams] = None) -> Request:
-        req = Request(prompt_tokens, sampling or SamplingParams())
+        req = Request(prompt_tokens, sampling or SamplingParams(),
+                      self._readers)
         self._stats["requests"] += 1
         # deque.append is atomic — submitters never contend on the
         # engine-step lock (a step can span a whole prefill+decode)
@@ -611,8 +690,9 @@ class ContinuousBatchingEngine:
             self._cpu_waiting = 0.0
             self._admit()
             active = self._decode_step()
-            self._stats["cpu_host_s"] += (time.thread_time() - c0
-                                         - self._cpu_waiting)
+            cpu = time.thread_time() - c0
+            self._stats["cpu_host_s"] += cpu - self._cpu_waiting
+            self._stats["engine_thread_cpu_s"] += cpu
         self._stats["t_step_s"] += time.perf_counter() - t0
         return active
 
@@ -661,7 +741,7 @@ class ContinuousBatchingEngine:
                                      else "prompt_too_long")
                 req.finished_at = time.perf_counter()
                 req.done.set()
-                req.stream.put(None)
+                self._put_end(req)
                 continue
             # +1 so the first decode write never needs a growth step
             alloc = allocate_slot(self.pool, toks, n + 1,
@@ -1228,10 +1308,20 @@ class ContinuousBatchingEngine:
             self._undelivered.append((req, None))
             self._release(slot)
 
+    def _put_end(self, req: Request) -> None:
+        """End a stream that never ran (a prompt refused): the marker,
+        numbered and counted as ``_deliver`` does. By the engine thread,
+        or under the lock."""
+        req.stream.put((None, self._stats["decode_steps"]))
+        self._stats["stream_puts"] += 1
+
     def _deliver(self) -> None:
         """Hand the streams what ``_emit`` booked, in its order."""
+        step = self._stats["decode_steps"]
+        # counted first: no snapshot reads an item taken that was not put
+        self._stats["stream_puts"] += len(self._undelivered)
         for req, tok in self._undelivered:
-            req.stream.put(tok)
+            req.stream.put((tok, step))
             if tok is None:
                 req.done.set()
         self._undelivered.clear()
@@ -1240,7 +1330,8 @@ class ContinuousBatchingEngine:
         """A decode step's tokens, delivered after the step: as a rule
         under the next step's program (``_decode_step``)."""
         if self._undelivered:
-            with _Phase(self, "engine.deliver", "t_deliver_s", waits=True):
+            with _Phase(self, "engine.deliver", "t_deliver_s", waits=True,
+                        step=self._stats["decode_steps"]):
                 self._deliver()
 
     # -- prefill/decode disaggregation handoff -----------------------------
@@ -1274,13 +1365,15 @@ class ContinuousBatchingEngine:
         """Admit a request whose prefill happened elsewhere. Returns None
         if no slot (or pool room) is free (caller retries)."""
         self._no_handoff_for_eva()
-        req = Request(prompt_tokens, sampling or SamplingParams())
+        req = Request(prompt_tokens, sampling or SamplingParams(),
+                      self._readers)
         n = len(prompt_tokens)
         if n >= self.max_seq:
             req.finish_reason = "prompt_too_long"
             req.finished_at = time.perf_counter()
             req.done.set()
-            req.stream.put(None)
+            with self._lock:
+                self._put_end(req)
             return req
         with self._lock:
             free = [i for i, s in enumerate(self.slots) if s is None]
@@ -1336,15 +1429,20 @@ class ContinuousBatchingEngine:
             raise
 
     def _fail_all(self, err: BaseException) -> None:
-        self._deliver()             # what was generated before the failure
-        for req in self._admitting:
-            req.fail(err)
-        for slot, req in enumerate(self.slots):
-            if req is not None:
-                self.slots[slot] = None
-                req.fail(err)
-        while self.waiting:
-            try:
-                self.waiting.popleft().fail(err)
-            except IndexError:      # a concurrent sweep drained it
-                break
+        # under the lock: the loop is dead, and submitters that find it
+        # so sweep from their own threads
+        with self._lock:
+            self._deliver()         # what was generated before the failure
+            step = self._stats["decode_steps"]
+            for req in self._admitting:
+                self._stats["stream_puts"] += req.fail(err, step)
+            for slot, req in enumerate(self.slots):
+                if req is not None:
+                    self.slots[slot] = None
+                    self._stats["stream_puts"] += req.fail(err, step)
+            while self.waiting:
+                try:
+                    req = self.waiting.popleft()
+                except IndexError:  # a concurrent sweep drained it
+                    break
+                self._stats["stream_puts"] += req.fail(err, step)

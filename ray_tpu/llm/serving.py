@@ -9,16 +9,31 @@ background thread and requests stream through per-request queues.
 
 from __future__ import annotations
 
+import contextlib
 import dataclasses
 import threading
 from typing import Any, Dict, List, Optional
 
+import jax
+
 from ray_tpu import serve
+from ray_tpu._private import worker
 from ray_tpu.llm.engine import (ContinuousBatchingEngine, SamplingParams)
 from ray_tpu.llm.tokenizer import ByteTokenizer, load_tokenizer
 
 
 REQUEST_TIMEOUT_S = 300.0    # a unary request's whole generation
+
+
+def _produced(req, index: int) -> worker.SampledItem:
+    """A sampled chunk on its replica thread: the span
+    ``serve.stream.produce`` and the request's cells. ``step`` is the
+    decode step that delivered the token: the number on its
+    ``engine.deliver`` span. The span lies between two waits for the
+    engine and around none."""
+    span = jax.profiler.TraceAnnotation("serve.stream.produce", request=req.id,
+                                        index=index, step=req.step)
+    return worker.SampledItem(req, span)
 
 
 @dataclasses.dataclass
@@ -44,8 +59,6 @@ class LLMServer:
     """Serve deployment class hosting one engine per replica."""
 
     def __init__(self, config: LLMConfig):
-        import jax
-
         from ray_tpu.models import LlamaConfig, model_for
 
         self.config = config
@@ -64,6 +77,7 @@ class LLMServer:
             self.model, params, max_slots=config.max_slots,
             max_seq=config.max_seq, block_size=config.block_size,
             num_blocks=config.num_blocks)
+        self._stats_lock = threading.Lock()
         self._stop = threading.Event()
         self._thread = threading.Thread(
             target=self.engine.run_forever, args=(self._stop,), daemon=True)
@@ -85,20 +99,33 @@ class LLMServer:
         """Streaming completions: one chunk per generated token as the
         engine produces it (reference: ray.llm streaming through Serve;
         the TTFT the serving bench measures is only real if the first
-        token can leave the replica before generation completes)."""
+        token can leave the replica before generation completes).
+
+        Chunks 0, 16, 32 ... by their index are the stream's sampled
+        items (docs/serving.md, "The stream path"): each is timed from
+        its token taken off the request's stream to the consumer of this
+        generator asking for the next chunk, which is the runtime having
+        stored and reported this one."""
         ids, sampling = self._parse(request)
         req = self.engine.submit(ids, sampling)
+        head = {"id": f"cmpl-{req.id}", "model": self.config.model_id}
+        decode, mask = self.tokenizer.decode, worker.STREAM_SAMPLE_MASK
         index = 0
         for tok in req.iter_tokens():
-            yield {"id": f"cmpl-{req.id}", "model": self.config.model_id,
-                   "delta": self.tokenizer.decode([tok]),
-                   "token_id": int(tok), "index": index}
+            if index & mask:        # an unsampled chunk: no clock, no span
+                yield {**head, "delta": decode([tok]),
+                       "token_id": int(tok), "index": index}
+            else:
+                with _produced(req, index):
+                    yield {**head, "delta": decode([tok]),
+                           "token_id": int(tok), "index": index}
             index += 1
-        yield {"id": f"cmpl-{req.id}", "model": self.config.model_id,
-               "finish_reason": req.finish_reason, "done": True,
-               "usage": {"prompt_tokens": len(ids),
-                         "completion_tokens": len(req.output)},
-               "ttft_s": req.ttft_s}
+        with (_produced(req, index) if not index & mask
+              else contextlib.nullcontext()):
+            yield {**head, "finish_reason": req.finish_reason, "done": True,
+                   "usage": {"prompt_tokens": len(ids),
+                             "completion_tokens": len(req.output)},
+                   "ttft_s": req.ttft_s}
 
     def __call__(self, request: Dict[str, Any]):
         """OpenAI-completions-shaped request/response; ``stream: true``
@@ -125,7 +152,18 @@ class LLMServer:
         }
 
     def stats(self) -> Dict[str, Any]:
-        return dict(self.engine.stats)
+        """The engine's counters and, merged into them, what this
+        process's runtime counts of its streaming generators
+        (``Runtime.generator_stats``; zeros where the process hosts no
+        stream's state): one flat dict, every key there from
+        construction (docs/serving.md). Asking reads an expert model's
+        load back from the device and sums the streams' cells; one
+        asker at a time, so no snapshot reads a count going down."""
+        rt = worker.global_runtime()
+        with self._stats_lock:
+            streams = (rt.generator_stats() if isinstance(rt, worker.Runtime)
+                       else worker.NO_STREAMS)
+            return {**self.engine.stats, **streams}
 
     def queue_depth(self) -> int:
         """Engine backlog beyond the decode slots: requests submitted

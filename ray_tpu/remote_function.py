@@ -4,6 +4,7 @@ from __future__ import annotations
 
 import functools
 import inspect
+import sys
 from typing import Any, Dict, List, Optional, Union
 
 from ray_tpu._private import worker
@@ -60,7 +61,29 @@ class ObjectRefGenerator:
         """``next(gen)`` with a deadline: raises ``GetTimeoutError``
         after ``timeout`` seconds; the claimed index returns to the
         hole set so a retry (or another consumer) re-claims it."""
+        return self._next(worker.global_worker(), timeout)[2]
+
+    def next_value(self, timeout: Optional[float] = None) -> Any:
+        """``get(gen.next(timeout))``, each under the deadline. A
+        sampled item's ``get`` (the wait for the item is over by then) is
+        timed into the stream's account (``Runtime.generator_stats``)
+        where that lives in this process, not with a worker's host, and
+        is the span ``serve.stream.consume`` where the process has
+        loaded jax (never imported for a span's sake)."""
         rt = worker.global_worker()
+        state, index, ref = self._next(rt, timeout)
+        if (index & worker.STREAM_SAMPLE_MASK
+                or not isinstance(state, worker.GeneratorState)):
+            return rt.get([ref], timeout=timeout)[0]
+        jax = sys.modules.get("jax")
+        span = jax and jax.profiler.TraceAnnotation("serve.stream.consume",
+                                                    task=self._task_id.hex(),
+                                                    index=index)
+        with worker.SampledItem(state, span):
+            return rt.get([ref], timeout=timeout)[0]
+
+    def _next(self, rt, timeout: Optional[float]):
+        """Claim an index and wait for its item: ``(state, index, ref)``."""
         state = rt.generator_state(self._task_id)
         with self._lock:
             if self._holes:
@@ -70,7 +93,7 @@ class ObjectRefGenerator:
                 index = self._index
                 self._index += 1
         try:
-            return state.next_ref(index, timeout=timeout)
+            return state, index, state.next_ref(index, timeout=timeout)
         except BaseException:
             with self._lock:
                 self._holes.add(index)

@@ -61,19 +61,10 @@ class DeploymentResponseGenerator:
         ``timeout`` seconds (the response is finished locally — an
         abandoning client must not leak router in-flight counts)."""
         try:
-            if timeout is not None and hasattr(self._ref_gen, "next"):
-                ref = self._ref_gen.next(timeout=timeout)
-            else:
-                ref = next(self._ref_gen)
-        except StopIteration:
-            self._finish()
-            raise
-        except Exception:
-            self._finish()
-            raise
-        try:
-            return ray_tpu.get(ref, timeout=timeout)
-        except Exception:
+            # ``next`` and ``get``; the stream's account times the
+            # sampled chunks' ``get`` (docs/serving.md, "The stream path")
+            return self._ref_gen.next_value(timeout=timeout)
+        except Exception:           # StopIteration too: the stream is over
             self._finish()
             raise
 

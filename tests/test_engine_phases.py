@@ -114,7 +114,13 @@ def test_spans_are_the_eight_phases_siblings_on_one_thread(tiny_model,
     attrs = [a for n, a, _, _ in driven if n == "engine.prefill"]
     assert {"bucket": 64, "n": 3, "n_pad": 4} in attrs
     assert all(set(a) == {"bucket", "n", "n_pad"} for a in attrs)
-    assert all(not a for n, a, _, _ in driven if n != "engine.prefill")
+    # (``engine.deliver`` names the decode step it delivers: what a
+    # streamed token's ``serve.stream.produce`` span carries too,
+    # tests/test_stream_phases.py)
+    assert all(set(a) == {"step"} for n, a, _, _ in driven
+               if n == "engine.deliver")
+    assert all(not a for n, a, _, _ in driven
+               if n not in ("engine.prefill", "engine.deliver"))
 
 
 def test_streams_get_every_token_in_order_then_their_end(tiny_model):
