@@ -11,7 +11,9 @@ in ONE process (the one that owns the chip):
           LlamaConfig.llama3_1b() (max_seq_len cut to 1024), once per
           decode-attention implementation ("xla", then "pallas"); then
           once with an expert model (OLMoE's block: MoEConfig with 16 =
-          16 heads of 128, experts of 2048 x 1024, few of them).
+          16 heads of 128, few experts, of 2048 x 896: a width XLA's
+          ragged_dot tiles by 128, so on the chip the grouped matmuls
+          are the Pallas kernel at the repo's tiling).
   pool    the engine's decode program compiled at a shape whose KV pool
           outweighs its weights: its temporaries must stay under one
           pool's bytes (the pool is written in place, never copied).
@@ -217,10 +219,12 @@ def sizes() -> dict:
     return dict(
         serve_cfg=dataclasses.replace(llama, max_seq_len=MAX_SEQ),
         # OLMoE's block at its published attention (16 = 16 heads of
-        # 128) and expert widths; few experts, layers and vocabulary rows
+        # 128) and hidden width; few experts, layers and vocabulary rows;
+        # experts of 896 (OLMoE's 1024 is tiled 512 wide by XLA and keeps
+        # ragged_dot: ops.moe_dispatch.grouped_matmul_impl)
         moe_cfg=MoEConfig.debug_olmoe(
             vocab_size=4096, max_seq_len=MAX_SEQ, dim=2048, n_heads=16,
-            n_kv_heads=16, ffn_dim=1024, num_experts=16, expert_top_k=4),
+            n_kv_heads=16, ffn_dim=896, num_experts=16, expert_top_k=4),
         # the serve cells' attention (8 KV heads of 128) over narrow
         # layers: 0.5 GiB of pool at POOL_SLOTS x MAX_SEQ against 0.14
         # GiB of bf16 weights, of which the temporaries hold no copy
@@ -629,6 +633,12 @@ def serve_moe_phase(rep: Report, sz: dict) -> None:
         rep.check("decode attention is the platform's",
                   eng.decode_attention_impl == impl,
                   f"engine built with {eng.decode_attention_impl!r}")
+        grouped = "pallas_gmm" if on_chip() else "ragged_dot"
+        rep.check("the expert FFN's grouped matmuls are the platform's",
+                  st["moe_grouped_impl"] == grouped,
+                  f"{st['moe_grouped_impl']!r}, tilings gate/up/down "
+                  + "/".join(repr(st[f"moe_gmm_tiling_{c}"])
+                             for c in ("gate", "up", "down")))
         alive = weakref.ref(eng)
         del server, eng
     finally:

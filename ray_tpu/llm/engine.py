@@ -480,6 +480,11 @@ class ContinuousBatchingEngine:
                       # host), and the rows per [layer][expert]
                       "moe_assignments": 0, "moe_assignments_expected": 0,
                       "moe_expert_load": [],
+                      # what implements the decode step's three grouped
+                      # matmuls ("pallas_gmm" / "ragged_dot") and their
+                      # (rows, k, n) tilings, as the model resolves them
+                      # from the platform and the step's shapes
+                      **model.grouped_matmul_plan(max_slots),
                       # bytes of the parameters as the engine holds them
                       "param_bytes": sum(
                           a.nbytes for a in jax.tree.leaves(self.params))}

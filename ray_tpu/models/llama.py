@@ -835,6 +835,13 @@ class LlamaModel:
         counts nothing, as the dense one."""
         return None
 
+    def grouped_matmul_plan(self, tokens: int) -> Dict[str, str]:
+        """What implements the FFN's grouped matmuls in a program of
+        ``tokens`` tokens, for an engine's ``stats``: empty strings for
+        the dense FFN, which has none (``MoEModel`` answers)."""
+        return {"moe_grouped_impl": "", "moe_gmm_tiling_gate": "",
+                "moe_gmm_tiling_up": "", "moe_gmm_tiling_down": ""}
+
     def decode_step_paged_counted(self, params: Params, tokens: jax.Array,
                                   pool: Params, block_tables: jax.Array,
                                   offsets: jax.Array,

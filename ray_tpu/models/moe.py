@@ -16,6 +16,14 @@ it is the capacity-bounded GShard dispatch (tokens beyond an expert's
 capacity are dropped), as one-hot einsums or as an explicit all-to-all
 (``moe_dispatch``); the mesh decides, not a flag.
 
+The three expert matmuls are ``ops.moe_dispatch.grouped_matmul``: on a
+TPU backend, at widths XLA's ``ragged_dot`` tiles narrower than 512 x
+512, jax's Pallas grouped matmul at a tiling chosen from the call's
+shapes (rows, k, n); ``jax.lax.ragged_dot`` elsewhere and where no
+tiling is legal; ``grouped_matmul_plan`` says which for an engine's
+``stats`` (``moe_grouped_impl``). Training runs the same forward, with
+``ragged_dot``'s backward.
+
 The serving programs hold the expert stacks WHOLE: their layer scans
 close over ``e_gate`` / ``e_up`` / ``e_down`` as ``[L*E, ...]`` and the
 grouped matmuls read layer ``l``'s experts in place, as groups ``l*E``
@@ -223,6 +231,31 @@ class MoEModel(LlamaModel):
         if self._ep > 1:
             return None
         return self.cfg.n_layers, self.cfg.num_experts
+
+    def grouped_matmul_plan(self, tokens: int) -> Dict[str, str]:
+        """Which implementation the three grouped matmuls of a program of
+        ``tokens`` tokens take and at what (rows, k, n) tiling, as
+        ``ops.moe_dispatch.grouped_matmul_impl`` resolves them from the
+        platform and the shapes (``tokens * expert_top_k`` rows; gate and
+        up ``[D, F]``, down ``[F, D]``). A tiling is "" where the call is
+        ``ragged_dot``; all are "" under an ``ep`` mesh axis, where the
+        capacity dispatch runs and no grouped matmul does."""
+        plan = super().grouped_matmul_plan(tokens)
+        if self._ep > 1:
+            return plan
+        from ray_tpu.ops.moe_dispatch import grouped_matmul_impl
+        cfg: MoEConfig = self.cfg
+        m, d, f = tokens * cfg.expert_top_k, cfg.dim, cfg.ffn_dim
+        itemsize = jnp.dtype(cfg.dtype).itemsize
+        calls = {"gate": (d, f), "up": (d, f), "down": (f, d)}
+        chosen = {name: grouped_matmul_impl(m, k, n, itemsize)
+                  for name, (k, n) in calls.items()}
+        plan["moe_grouped_impl"] = "+".join(sorted(
+            {impl for impl, _ in chosen.values()}))
+        for name, (_, tiling) in chosen.items():
+            if tiling is not None:
+                plan[f"moe_gmm_tiling_{name}"] = "x".join(map(str, tiling))
+        return plan
 
     def apply_with_aux(self, params: Params, tokens: jax.Array,
                        positions=None):

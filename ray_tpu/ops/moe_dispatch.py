@@ -3,15 +3,29 @@
 - ``dropless_expert_ffn``: what every model runs off a mesh, serving and
   training alike. Router in float32, top-k, the (token, choice)
   assignments sorted by expert, the three expert matmuls as grouped
-  matmuls over the sorted rows (``jax.lax.ragged_dot``: a native grouped
-  kernel on the TPU, plain loops elsewhere), outputs un-sorted and
-  summed with the router's weights. Every chosen expert is computed: no
-  capacity, no drop, and nothing whose size grows faster than T * K.
+  matmuls over the sorted rows (``grouped_matmul``, below), outputs
+  un-sorted and summed with the router's weights. Every chosen expert is
+  computed: no capacity, no drop, and nothing whose size grows faster
+  than T * K.
   The expert weights are ONE layer's ``[E, ...]`` or, with
   ``first_expert``, every layer's stack ``[L*E, ...]`` of which the
   layer's E groups alone hold rows (the serving programs: a grouped
   matmul's operand is a buffer of its own, so a layer's SLICE of the
   stack is a copy of it, 268 MB a stack a layer: PERF.md, PR 37).
+- ``grouped_matmul``: one algorithm, two implementations, ONE resolver
+  (``grouped_matmul_impl``, by the platform and the call's shapes, the
+  way ``ops.paged_attention.default_impl`` resolves decode attention: no
+  flag, no environment variable, no configuration key). On a TPU backend,
+  at widths where XLA's heuristic for ``ragged_dot`` falls to small
+  weight tiles (Mellum2's 2304 x 896: 256 x 128, 63 grid steps a group,
+  three times OLMoE's time a byte: PERF.md, PR 40), jax's Pallas grouped
+  matmul (``megablox.gmm``: bf16 operands, float32 accumulator) at the
+  tiling ``gmm_tiling(m, k, n, itemsize)`` gives; everywhere else, at
+  widths XLA tiles 512 x 512 (OLMoE's 2048 x 1024: ``xla_tiles_wide``)
+  and for a shape with no legal tiling, ``jax.lax.ragged_dot`` (the
+  kernel's reference in the tests). TRAINING on a TPU runs the same
+  kernel forward; its backward is ``ragged_dot``'s transposes
+  (``pallas_grouped_matmul``'s ``custom_vjp``), which no cell measures.
 - the capacity-bounded GShard pair, kept for an ``ep`` mesh axis
   (ROADMAP R2 decides their future): ``capacity_einsum_ffn`` (dense
   one-hot ``[T, E, C]`` dispatch/combine einsums, XLA's partitioner
@@ -27,10 +41,15 @@
 from __future__ import annotations
 
 import functools
-from typing import Tuple
+import logging
+from typing import Optional, Tuple
 
 import jax
 import jax.numpy as jnp
+
+from ray_tpu._private.platform import on_chip, pallas_interpret
+
+logger = logging.getLogger(__name__)
 
 
 def route_topk(xf, router, top_k: int, norm_topk_prob: bool,
@@ -57,6 +76,145 @@ def router_aux_loss(logits, probs, z_coef: float, lb_coef: float):
                   axis=0)
     return (jnp.mean(z ** 2) * z_coef
             + lb_coef * num_experts * jnp.sum(me * ce))
+
+
+# What a grouped-matmul call may hold of the v5e's 16 MiB of scoped VMEM
+# by ``gmm_vmem_bytes``' reckoning: the compiler's own temporaries (the
+# dot's float32 result, the store's mask) come on top of it.
+GMM_VMEM_BUDGET = 12 * 2**20
+
+
+def gmm_vmem_bytes(tm: int, tk: int, tn: int, itemsize: int) -> int:
+    """VMEM a grouped-matmul grid step holds at tiling (tm, tk, tn): the
+    weight tile, the row tile and the output tile double-buffered, and
+    the float32 accumulator."""
+    return (2 * tk * tn * itemsize + 2 * tm * tk * itemsize
+            + 2 * tm * tn * itemsize + 4 * tm * tn)
+
+
+def lane_divisors(x: int):
+    """Multiples of 128 that divide ``x``, largest first."""
+    return [t for t in range(x - x % 128, 0, -128) if x % t == 0]
+
+
+def gmm_tiling(m: int, k: int, n: int, itemsize: int = 2
+               ) -> Optional[Tuple[int, int, int]]:
+    """The (rows, k, n) tiling of the Pallas grouped matmul for ``lhs
+    [m, k] @ rhs [G, k, n]``, from the shapes alone; ``None`` where no
+    legal tiling exists (the call then stays ``jax.lax.ragged_dot``).
+
+    The kernel's grid is (n tiles, (group, row tile) pairs that hold a
+    row, k tiles) and every step fetches a (tk, tn) weight tile and
+    multiplies ALL tm rows of its row tile by it. What the v5e read
+    (``tools/moe_gmm_bench.py tilings``, PERF.md PR 40):
+
+    - (tk, tn): multiples of 128 that DIVIDE k and n, as few to a group
+      as ``GMM_VMEM_BUDGET`` allows, the whole expert where it fits (one
+      grid step a group: 2304 x 896 in bf16 is 4.1 MB, 8.3 double-
+      buffered). Where a group needs more than one, n is split before k:
+      a k tile shorter than k costs a pass over the float32 accumulator
+      a step. Small tiles are what made these calls slow: a fixed cost a
+      grid step, 63 steps a group at 256 x 128.
+    - tm: 128 rows, 256 from 2,048 rows on, and the largest power of two
+      under that which divides m (megablox requires it; 16 at least, a
+      bf16 tile's sublanes). A step's multiply takes as long as its
+      weights' fetch at ~240 rows (197 TFLOP/s over 819 GB/s, bf16), so
+      256 is the largest row tile that is not compute-bound. The
+      winners read: 128 up to 1,024 rows (32-64 a few percent behind at
+      a decode step's 256), 256 at 2,048 and 4,096 (by 2 and 8 %), 512
+      1.6 x slower there; at 12,288 rows, which no cell runs, 128 read
+      12 % ahead again at 2304 x 896.
+    """
+    target = 256 if m >= 2048 else 128
+    tm = next((t for t in (256, 128, 64, 32, 16)
+               if t <= target and m % t == 0), None)
+    if tm is None:
+        return None
+    fits = [(tk, tn) for tk in lane_divisors(k) for tn in lane_divisors(n)
+            if gmm_vmem_bytes(tm, tk, tn, itemsize) <= GMM_VMEM_BUDGET]
+    if not fits:
+        return None
+    tk, tn = min(fits, key=lambda t: ((k // t[0]) * (n // t[1]), -t[0]))
+    return tm, tk, tn
+
+
+def xla_tiles_wide(k: int, n: int) -> bool:
+    """Does XLA's own heuristic for ``ragged_dot`` reach its widest (k,
+    n) tile, 512 x 512? It tiles a width by its largest power-of-two
+    factor up to 512 (the v5e compiler prints ``ragged_dot_tiling=
+    "256,512,512"`` at 2048 x 1024 and ``"256,256,128"`` at 2304 x 896)."""
+    return k % 512 == 0 and n % 512 == 0
+
+
+def grouped_matmul_impl(m: int, k: int, n: int, itemsize: int
+                        ) -> Tuple[str, Optional[Tuple[int, int, int]]]:
+    """``("pallas_gmm", tiling)`` or ``("ragged_dot", None)`` for ``lhs
+    [m, k] @ rhs [G, k, n]``: the one place that decides, by the
+    platform and the shapes (as ``ops.paged_attention.default_impl``
+    does for decode attention). The Pallas kernel on a TPU backend where
+    XLA's own tiling falls short of 512 x 512 and ``gmm_tiling`` has a
+    tiling; ``jax.lax.ragged_dot`` everywhere else.
+
+    Why widths XLA tiles well keep ``ragged_dot`` (PERF.md, PR 40): at
+    2048 x 1024 the kernel reads a third faster a call (0.37 for 0.56
+    ms at 256 rows) where at 2304 x 896 it reads 4.4 times faster (0.39
+    for 1.72), and every program that holds it pays ~0.1 s of Mosaic
+    lowering before the compile cache is asked, ~2 s of a process's
+    set-up. The one cell with such widths is bound by the Serve stream
+    path, where a faster engine thread takes the interpreter lock from
+    the stream threads: with the kernel its clients received 7 % FEWER
+    tokens a second and its set-up grew 17 %. Revisit when S2 (b) lands.
+    """
+    if not on_chip() or xla_tiles_wide(k, n):
+        return "ragged_dot", None
+    tiling = gmm_tiling(m, k, n, itemsize)
+    if tiling is None:
+        logger.warning(
+            "grouped matmul [%d, %d] @ [G, %d, %d] keeps ragged_dot on the "
+            "chip at XLA's small tiles: no row tile divides %d rows or no "
+            "multiple of 128 divides a width", m, k, k, n, m)
+        return "ragged_dot", None
+    return "pallas_gmm", tiling
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5))
+def pallas_grouped_matmul(lhs, rhs, group_sizes, dtype, tiling,
+                          interpret: bool = False):
+    """jax's Pallas grouped matmul (``megablox.gmm``: bf16 operands,
+    float32 accumulator, a grid of (n tiles, non-empty (group, row tile)
+    pairs, k tiles)) at ``tiling``. Its gradient is ``ragged_dot``'s
+    (XLA's transposes): no training cell measures a tiled backward."""
+    from jax.experimental.pallas.ops.tpu.megablox.gmm import gmm
+    return gmm(lhs, rhs, group_sizes, dtype, tiling, interpret=interpret)
+
+
+def _pallas_gmm_fwd(lhs, rhs, group_sizes, dtype, tiling, interpret):
+    out = pallas_grouped_matmul(lhs, rhs, group_sizes, dtype, tiling,
+                                interpret)
+    return out, (lhs, rhs, group_sizes)
+
+
+def _pallas_gmm_bwd(dtype, tiling, interpret, residuals, grad):
+    lhs, rhs, group_sizes = residuals
+    _, vjp = jax.vjp(lambda l, r: jax.lax.ragged_dot(
+        l, r, group_sizes, preferred_element_type=dtype), lhs, rhs)
+    return (*vjp(grad), None)
+
+
+pallas_grouped_matmul.defvjp(_pallas_gmm_fwd, _pallas_gmm_bwd)
+
+
+def grouped_matmul(lhs, rhs, group_sizes, dtype):
+    """``lhs [m, k]``'s rows, sorted by group, each times its group's
+    ``rhs [G, k, n]``: ``[m, n]`` in ``dtype``, float32 sums. Groups of
+    no rows are neither visited nor fetched."""
+    impl, tiling = grouped_matmul_impl(
+        lhs.shape[0], rhs.shape[1], rhs.shape[2], jnp.dtype(dtype).itemsize)
+    if impl == "ragged_dot":
+        return jax.lax.ragged_dot(lhs, rhs, group_sizes,
+                                  preferred_element_type=dtype)
+    return pallas_grouped_matmul(lhs, rhs, group_sizes, dtype, tiling,
+                                 pallas_interpret())
 
 
 def dropless_expert_ffn(x, router, e_gate, e_up, e_down, *, top_k: int,
@@ -102,8 +260,7 @@ def dropless_expert_ffn(x, router, e_gate, e_up, e_down, *, top_k: int,
                 (first_expert,))
     with jax.named_scope("moe_experts"):
         def grouped(lhs, w):
-            return jax.lax.ragged_dot(lhs, w.astype(dtype), sizes,
-                                      preferred_element_type=dtype)
+            return grouped_matmul(lhs, w.astype(dtype), sizes, dtype)
         act = jax.nn.silu(grouped(rows, e_gate)) * grouped(rows, e_up)
         rows = grouped(act, e_down)                   # [T*K, D]
     with jax.named_scope("moe_combine"):

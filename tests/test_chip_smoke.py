@@ -225,10 +225,11 @@ def _as_on_the_chip(monkeypatch):
     """This process's backend is the CPU, where the model rightly takes
     the reference: tell it what it would see on the chip (the compile
     is the v5e's)."""
-    from ray_tpu.ops import paged_attention
+    from ray_tpu.ops import moe_dispatch, paged_attention
 
-    monkeypatch.setattr(paged_attention, "on_chip", lambda: True)
-    monkeypatch.setattr(paged_attention, "pallas_interpret", lambda: False)
+    for module in (paged_attention, moe_dispatch):
+        monkeypatch.setattr(module, "on_chip", lambda: True)
+        monkeypatch.setattr(module, "pallas_interpret", lambda: False)
 
 
 @pytest.mark.parametrize("maxb", CELL_TABLES)
@@ -284,6 +285,48 @@ def test_llama3_1b_decode_program_holds_the_kernel(v5e, placed, monkeypatch,
         v5e(B, maxb, dtype=jnp.int32), v5e(B, dtype=jnp.int32)))
 
 
+def _holds_the_tiled_grouped_matmuls(text, model, slots):
+    """The compiled decode program's three grouped matmuls are the Pallas
+    kernel at ``gmm_tiling``'s choice (PR 40; that it compiled says the
+    tiles fit the scoped VMEM), not XLA's ``ragged_dot`` at the tiling
+    its heuristic picks (256 x 128 weight tiles at Mellum2's widths: 63
+    grid steps a group)."""
+    plan = model.grouped_matmul_plan(slots)
+    assert plan["moe_grouped_impl"] == "pallas_gmm"
+    assert all(plan[f"moe_gmm_tiling_{c}"] for c in ("gate", "up", "down"))
+    # one attention kernel + three grouped matmuls in the layer scan
+    assert text.count("tpu_custom_call") >= 4
+    assert "ragged_dot_tiling" not in text and "ragged-dot" not in text
+    assert text.count(" custom-call(") and "gmm" in text
+
+
+@pytest.mark.parametrize("k,n", [(2304, 896), (896, 2304), (2048, 1024),
+                                 (1024, 2048)])
+def test_grouped_matmul_lowers_at_its_own_tilings(v5e, k, n):
+    """The Pallas grouped matmul at ``gmm_tiling``'s choice for both
+    expert cells' calls, from a two-slot decode step's 16 rows to a
+    1,536-token prefill's 12,288, on a whole stack of 8 x 64 groups:
+    Mosaic takes every one (what ``gmm_vmem_bytes`` reckons under its
+    budget fits the v5e's scoped VMEM), forward and under ``grad``."""
+    from ray_tpu.ops.moe_dispatch import gmm_tiling, pallas_grouped_matmul
+
+    G = 512
+    for m in (16, 256, 2048, 4096, 12288):
+        tiling = gmm_tiling(m, k, n, 2)
+        assert tiling is not None, m
+        assert _mosaic(jax.jit(
+            lambda a, b, s, t=tiling: pallas_grouped_matmul(
+                a, b, s, jnp.bfloat16, t)).lower(
+            v5e(m, k), v5e(G, k, n), v5e(G, dtype=jnp.int32))), (m, tiling)
+    # training: the kernel forward, ragged_dot's transposes backward
+    tiling = gmm_tiling(2048, k, n, 2)
+    text = jax.jit(jax.grad(lambda a, b, s: jnp.sum(pallas_grouped_matmul(
+        a, b, s, jnp.bfloat16, tiling).astype(jnp.float32)),
+        (0, 1))).lower(v5e(2048, k), v5e(64, k, n),
+                       v5e(64, dtype=jnp.int32)).compile().as_text()
+    assert "ragged_dot_tiling" in text
+
+
 def test_olmoe_cell_decode_program_fits_the_v5e(v5e, placed, monkeypatch):
     """``olmoe-1b-7b-d3.batch_decode_moe``'s decode program as the engine
     jits it (the counted step: 32 slots x 4096, depth 3, all 64 experts,
@@ -291,7 +334,8 @@ def test_olmoe_cell_decode_program_fits_the_v5e(v5e, placed, monkeypatch):
     takes it (5.74 GiB of 15.75: 5.73 of arguments, 2.73 weights + 3.00
     pool; 11.56 on float32 weights, whose bf16 copies were 3.10 GiB of
     temporaries), with the paged kernel and the grouped matmuls as
-    Mosaic calls. The temporaries that remain (0.014 GiB) hold nothing
+    Mosaic calls (the matmuls XLA's ``ragged_dot`` at its own 256 x 512
+    x 512: these widths keep it, PR 40). The temporaries that remain (0.014 GiB) hold nothing
     stack-shaped: the grouped-matmul calls read the expert stacks whole
     and in place (PR 37). Until then ONE layer's slice of a stack at a
     time was copied out as their operand: 0.25 GiB of temporaries, 5.98
@@ -313,8 +357,13 @@ def test_olmoe_cell_decode_program_fits_the_v5e(v5e, placed, monkeypatch):
         placed(jax.eval_shape(lambda: model.init_kv_pool(B * maxb + 1, bs))),
         v5e(B, maxb, dtype=jnp.int32), v5e(B, dtype=jnp.int32),
         *_sampling(v5e, B), v5e(L, E, dtype=jnp.int32)).compile()
-    # one attention kernel + three grouped matmuls in the layer scan
-    assert compiled.as_text().count("tpu_custom_call") >= 4
+    # one attention kernel + three grouped matmuls in the layer scan:
+    # XLA's own ragged_dot, which tiles these widths 512 x 512 (PR 40:
+    # the Pallas kernel is for widths it tiles narrower)
+    text = compiled.as_text()
+    assert text.count("tpu_custom_call") >= 4
+    assert 'ragged_dot_tiling="256,512,512"' in text
+    assert model.grouped_matmul_plan(B)["moe_grouped_impl"] == "ragged_dot"
     mem = compiled.memory_analysis()
     total = (mem.argument_size_in_bytes + mem.temp_size_in_bytes
              + mem.output_size_in_bytes - mem.alias_size_in_bytes)
@@ -335,7 +384,9 @@ def test_hybrid_cell_decode_program_fits_the_v5e(v5e, placed, monkeypatch):
     stack's shape among them: 0.25, one layer's slice of one stack at a
     time, until PR 37); ONE pool for all eight layers would hold 8.0
     GiB of K/V where the two hold 2.59, and with the weights pass the
-    chip's 15.75."""
+    chip's 15.75. Its 24 grouped matmuls are the Pallas kernel at
+    ``gmm_tiling``'s (128, whole expert) (PR 40), not ``ragged_dot`` at
+    the 256 x 128 weight tiles XLA's heuristic falls to at 2304 x 896."""
     from benchmark import run as harness
     from benchmark.builders import mellum
     from ray_tpu.llm.paged_cache import window_blocks_per_slot
@@ -358,7 +409,7 @@ def test_hybrid_cell_decode_program_fits_the_v5e(v5e, placed, monkeypatch):
         placed(_engine_params(model)), v5e(B, dtype=jnp.int32), placed(pool),
         v5e(2, B, maxb, dtype=jnp.int32), v5e(B, dtype=jnp.int32),
         *_sampling(v5e, B), v5e(L, E, dtype=jnp.int32)).compile()
-    assert compiled.as_text().count("tpu_custom_call") >= 4
+    _holds_the_tiled_grouped_matmuls(compiled.as_text(), model, B)
     mem = compiled.memory_analysis()
     total = (mem.argument_size_in_bytes + mem.temp_size_in_bytes
              + mem.output_size_in_bytes - mem.alias_size_in_bytes)

@@ -2,6 +2,8 @@
 
     chiprun -- python3 tools/moe_gmm_bench.py            # the whole stack
     chiprun -- python3 tools/moe_gmm_bench.py candidates # PR 26's sweep
+    chiprun -- python3 tools/moe_gmm_bench.py tilings    # PR 40's sweep
+    chiprun -- python3 tools/moe_gmm_bench.py tilings 1024 12288  # row tiles
 
 **The whole stack (PR 37).** A decode step's 256 rows (32 slots, top-8)
 through one layer's three grouped matmuls, at both expert cells' shapes
@@ -26,12 +28,29 @@ weights in bfloat16 as an engine holds them:
 bfloat16 and in float32 cast inside the timed program. PERF.md (PR 26)
 holds the readings that chose ``ragged_dot``.
 
+**``tilings`` (PR 40)**: the Pallas grouped matmul at a sweep of (rows,
+k, n) tiles, ONE call at a time (``gate_up``: ``[m, D] @ [L*E, D, F]``;
+``down``: ``[m, F] @ [L*E, F, D]``) on the whole stack with the layer's
+groups at the LAST layer's offset, at both cells' shapes, m = 256 (a
+decode step) and the cell's prefill rows (Mellum2's 512-token chunk =
+4,096; OLMoE's 256-token prompt = 2,048); then the layer's three calls
+together (``ffn``) at the first and the last layer, on ``ragged_dot``,
+on the compiler's own tiling and on ``ops.moe_dispatch.gmm_tiling``'s
+choice. Every line carries the grid steps the routed sizes give at that
+tiling and ``gmm_vmem_bytes``' reckoning; ``chosen`` marks the rows at
+``gmm_tiling``'s own choice. A tiling the compiler refuses is a line
+with ``error``. With rows named after ``tilings`` (multiples of 256),
+those rows in place of the cells', and the ROW tiles alone at
+``gmm_tiling``'s (k, n). The lines go to
+``chiprun_out/moe_gmm_tilings.jsonl`` too.
+
 Prints one JSON line per reading.
 """
 
 from __future__ import annotations
 
 import json
+import os
 import sys
 import time
 
@@ -87,6 +106,17 @@ def timed(fn, args, reps=200, rounds=5):
         jax.block_until_ready(last)
         out.append((time.perf_counter() - t0) / reps)
     return 1e3 * float(np.median(out))
+
+
+def grid_steps(sizes, tiling, k, n):
+    """Grid steps of a grouped-matmul call: n tiles x k tiles x the
+    (group, row tile) pairs that hold a row."""
+    tm, tk, tn = tiling
+    ends = np.cumsum(sizes)
+    starts = ends - sizes
+    visits = int(sum((e - 1) // tm - s // tm + 1
+                     for s, e in zip(starts, ends) if e > s))
+    return -(-n // tn) * -(-k // tk) * visits
 
 
 def routed_sizes(rng, rows):
@@ -181,11 +211,101 @@ def candidates():
                 print(json.dumps(line), flush=True)
 
 
+# the cells' prefill rows: tokens x top-8
+PREFILL_ROWS = {"olmoe-1b-7b-d3": 2048, "mellum2-12b-a2.5b-d8": 4096}
+
+
+def tilings(rows_asked=(), out_path="chiprun_out/moe_gmm_tilings.jsonl"):
+    os.makedirs(os.path.dirname(out_path), exist_ok=True)
+    with open(out_path, "w") as out:
+        sweep_tilings(rows_asked, out)
+
+
+def sweep_tilings(rows_asked, out):
+    sys.path.insert(0, os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))))
+    from ray_tpu.ops.moe_dispatch import (GMM_VMEM_BUDGET, gmm_tiling,
+                                          gmm_vmem_bytes, lane_divisors)
+
+    def reading(line, fn, args, reps):
+        try:
+            line["ms"] = timed(fn, args, reps=reps, rounds=3)
+        except Exception as e:     # noqa: BLE001 — a refusal is a reading
+            line["error"] = f"{type(e).__name__}: {str(e)[:160]}"
+        text = json.dumps(line)
+        print(text, flush=True)
+        out.write(text + "\n")
+        out.flush()
+
+    rng = np.random.default_rng(0)
+    for name, L, d, f, compilers in STACKS:
+        keys = jax.random.split(jax.random.key(0), 3)
+        merged = tuple(jax.random.normal(key, shape, jnp.bfloat16) * 0.02
+                       for key, shape in zip(keys, ((L * E, d, f),
+                                                    (L * E, d, f),
+                                                    (L * E, f, d))))
+        for rows in rows_asked or (256, PREFILL_ROWS[name]):
+            sizes = routed_sizes(rng, rows)
+            reps = 200 if rows <= 512 else 50
+
+            def padded(first, sizes=sizes):
+                return jax.lax.dynamic_update_slice(
+                    jnp.zeros((L * E,), jnp.int32), jnp.asarray(sizes),
+                    (first,))
+
+            last = jnp.int32((L - 1) * E)
+            tms = ((32, 64, 128, 256) if rows <= 512 else (128, 256, 512))
+            for call, k, n, w in (("gate_up", d, f, merged[0]),
+                                  ("down", f, d, merged[2])):
+                xs = jnp.asarray(rng.normal(size=(rows, k)), jnp.bfloat16)
+                chosen = gmm_tiling(rows, k, n, 2)
+                if rows_asked:      # the row tiles alone, at the chosen (k, n)
+                    sweep = {(tm, *chosen[1:]) for tm in tms if rows % tm == 0}
+                else:
+                    sweep = {(tm, tk, tn) for tm in tms
+                             for tk in lane_divisors(k) if tk >= 256
+                             for tn in lane_divisors(n) if tn >= 256
+                             if gmm_vmem_bytes(tm, tk, tn, 2)
+                             <= GMM_VMEM_BUDGET + 2 * 2**20}
+                sweep |= {compilers, chosen}
+                base = {"shapes": name, "groups": L * E, "rows": rows,
+                        "call": call, "k": k, "n": n, "at": "last"}
+                reading({**base, "impl": "ragged_dot"},
+                        jax.jit(lambda xs, w, first: ragged(
+                            xs, w, padded(first))), (xs, w, last), reps)
+                for tiling in sorted(sweep):
+                    reading({**base, "impl": "gmm", "tiling": list(tiling),
+                             "grid_steps": grid_steps(sizes, tiling, k, n),
+                             "vmem_bytes": gmm_vmem_bytes(*tiling, 2),
+                             "chosen": tiling == chosen},
+                            jax.jit(lambda xs, w, first, t=tiling: megablox(t)(
+                                xs, w, padded(first))), (xs, w, last), reps)
+            # the layer's three calls together, at both ends of the stack
+            xs = jnp.asarray(rng.normal(size=(rows, d)), jnp.bfloat16)
+
+            def by_shape(xs, w, sizes):
+                return megablox(gmm_tiling(xs.shape[0], *w.shape[1:], 2))(
+                    xs, w, sizes)
+
+            for impl, grouped in (("ragged_dot", ragged),
+                                  ("gmm_compilers", megablox(compilers)),
+                                  ("gmm_chosen", by_shape)):
+                fn = jax.jit(lambda xs, wg, wu, wd, first, g=grouped: swiglu(
+                    g, xs, wg, wu, wd, padded(first)))
+                for at, l in (("first", 0), ("last", L - 1)):
+                    reading({"shapes": name, "groups": L * E, "rows": rows,
+                             "call": "ffn", "at": at, "impl": impl},
+                            fn, (xs, *merged, jnp.int32(l * E)), reps)
+        del merged
+
+
 def main():
     if jax.devices()[0].platform != "tpu":
         sys.exit("moe_gmm_bench: no TPU; a CPU time is not a reading")
     if sys.argv[1:] == ["candidates"]:
         candidates()
+    elif sys.argv[1:2] == ["tilings"]:
+        tilings(tuple(int(r) for r in sys.argv[2:]))
     else:
         whole_stack()
 
