@@ -58,6 +58,7 @@ from __future__ import annotations
 
 import dataclasses
 import itertools
+import math
 import queue
 import threading
 import time
@@ -391,8 +392,9 @@ class ContinuousBatchingEngine:
             self._ffn_counts = None
         else:
             self._ffn_counts = (jnp.zeros(load_shape, jnp.int32), 0)
+            # the EXPERT layers' (a model may lead with dense ones)
             self._ffn_rows_per_slot = (model.cfg.expert_top_k
-                                       * model.cfg.n_layers)
+                                       * load_shape[0])
         # ONE program a decode step: the model's step and the sampler
         self._decode = jax.jit(self._decode_step_paged, donate_argnums=(2,))
         self._prefill = jax.jit(self._prefill_impl)
@@ -485,6 +487,19 @@ class ContinuousBatchingEngine:
                       # (rows, k, n) tilings, as the model resolves them
                       # from the platform and the step's shapes
                       **model.grouped_matmul_plan(max_slots),
+                      # the router of an expert model's FFN ("softmax" /
+                      # "sigmoid"; "" for a dense model)
+                      "moe_router_kind": getattr(model.cfg, "router_kind",
+                                                 ""),
+                      # the K/V pool as it is held: one position's bytes
+                      # a layer (both parts of a row, padding and all:
+                      # ``model.kv_row_shapes``) and all its arrays' bytes
+                      "kv_row_bytes": sum(
+                          math.prod(row) for row in model.kv_row_shapes()
+                      ) * jnp.dtype(model.cfg.dtype).itemsize,
+                      "kv_pool_bytes": sum(
+                          math.prod(a.shape) * a.dtype.itemsize
+                          for name, a in self.kv.items() if name != "bases"),
                       # bytes of the parameters as the engine holds them
                       "param_bytes": sum(
                           a.nbytes for a in jax.tree.leaves(self.params))}

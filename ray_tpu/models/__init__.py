@@ -11,15 +11,17 @@ GPT-2, MLP (Fashion-MNIST baseline), ViT (ImageNet streaming).
 
 from ray_tpu.models.gpt2 import GPT2Config, GPT2Model
 from ray_tpu.models.llama import LlamaConfig, LlamaModel
+from ray_tpu.models.mla import MLAConfig, MLAModel
 from ray_tpu.models.mlp import MLPConfig, MLPModel
 from ray_tpu.models.moe import MoEConfig, MoEModel
 from ray_tpu.models.vit import ViTConfig, ViTModel
 
 __all__ = ["LlamaConfig", "LlamaModel", "MLPConfig", "MLPModel",
            "GPT2Config", "GPT2Model", "ViTConfig", "ViTModel",
-           "MoEConfig", "MoEModel", "model_for"]
+           "MoEConfig", "MoEModel", "MLAConfig", "MLAModel", "model_for"]
 
 _MODEL_OF = {LlamaConfig: LlamaModel, MoEConfig: MoEModel,
+             MLAConfig: MLAModel,
              GPT2Config: GPT2Model, MLPConfig: MLPModel,
              ViTConfig: ViTModel}
 

@@ -309,33 +309,50 @@ class LlamaModel:
                 [NO_WINDOW, cfg.sliding_window or NO_WINDOW], jnp.int32)
 
     # -- init ---------------------------------------------------------------
+    @property
+    def _main_layers(self) -> int:
+        """Layers in ``params["layers"]``: all, but for a model that
+        holds leading layers of another tree apart (``_scan_layers``)."""
+        return self.cfg.n_layers
+
+    @staticmethod
+    def _dense(key, shape, fan_in):
+        return jax.random.normal(key, shape, jnp.float32) * fan_in ** -0.5
+
+    def _norm_scale(self, shape):   # a scale of 1 (stored as 0 under
+        return (jnp.zeros if self.cfg.norm_add_unit_offset  # ``1 + g``)
+                else jnp.ones)(shape, jnp.float32)
+
+    def _init_attention(self, k, L: int) -> Params:
+        """A stack of ``L`` layers' attention weights, keys drawn from
+        the iterator ``k``."""
+        cfg, dense = self.cfg, self._dense
+        d, hd = cfg.dim, cfg.head_dim
+        return {"wq": dense(next(k), (L, d, cfg.n_heads, hd), d),
+                "wk": dense(next(k), (L, d, cfg.n_kv_heads, hd), d),
+                "wv": dense(next(k), (L, d, cfg.n_kv_heads, hd), d),
+                "wo": dense(next(k), (L, cfg.n_heads, hd, d), d)}
+
+    def _init_layers(self, k, L: int, ffn_dim: int) -> Params:
+        """A stack of ``L`` dense decoder layers, SwiGLU ``ffn_dim``."""
+        d, dense = self.cfg.dim, self._dense
+        return {"attn_norm": self._norm_scale((L, d)),
+                **self._init_attention(k, L),
+                "mlp_norm": self._norm_scale((L, d)),
+                "w_gate": dense(next(k), (L, d, ffn_dim), d),
+                "w_up": dense(next(k), (L, d, ffn_dim), d),
+                "w_down": dense(next(k), (L, ffn_dim, d), ffn_dim)}
+
     def init(self, rng: jax.Array) -> Params:
         cfg = self.cfg
         d, hd = cfg.dim, cfg.head_dim
         k = iter(jax.random.split(rng, 16))
+        dense, norm_scale = self._dense, self._norm_scale
 
-        def dense(key, shape, fan_in):
-            return (jax.random.normal(key, shape, jnp.float32)
-                    * (fan_in ** -0.5))
-
-        def norm_scale(shape):      # a scale of 1 (stored as 0 under
-            return (jnp.zeros if cfg.norm_add_unit_offset   # ``1 + g``)
-                    else jnp.ones)(shape, jnp.float32)
-
-        L = cfg.n_layers
+        L = self._main_layers
         params: Params = {
             "embed": dense(next(k), (cfg.vocab_size, d), d),
-            "layers": {
-                "attn_norm": norm_scale((L, d)),
-                "wq": dense(next(k), (L, d, cfg.n_heads, hd), d),
-                "wk": dense(next(k), (L, d, cfg.n_kv_heads, hd), d),
-                "wv": dense(next(k), (L, d, cfg.n_kv_heads, hd), d),
-                "wo": dense(next(k), (L, cfg.n_heads, hd, d), d),
-                "mlp_norm": norm_scale((L, d)),
-                "w_gate": dense(next(k), (L, d, cfg.ffn_dim), d),
-                "w_up": dense(next(k), (L, d, cfg.ffn_dim), d),
-                "w_down": dense(next(k), (L, cfg.ffn_dim, d), cfg.ffn_dim),
-            },
+            "layers": self._init_layers(k, L, cfg.ffn_dim),
             "norm_f": norm_scale((d,)),
         }
         if not cfg.tie_embeddings:
@@ -372,9 +389,11 @@ class LlamaModel:
 
         out = {k: cast(v) if k in ("embed", "lm_head") else v
                for k, v in params.items()}
-        out["layers"] = {
-            k: cast(v) if k in self.MATMUL_LAYER_LEAVES else v
-            for k, v in params["layers"].items()}
+        for stack in ("layers", "leading_layers"):
+            if stack in params:
+                out[stack] = {
+                    k: cast(v) if k in self.MATMUL_LAYER_LEAVES else v
+                    for k, v in params[stack].items()}
         return out
 
     # The leaves of ``params["layers"]`` a SERVING program's layer scan
@@ -400,7 +419,9 @@ class LlamaModel:
         if not names:
             return layers, None
         xs = {k: v for k, v in layers.items() if k not in names}
-        xs["index"] = jnp.arange(self.cfg.n_layers, dtype=jnp.int32)
+        # the layer's number IN ITS STACK (a model with leading layers of
+        # another tree holds them apart: ``_scan_layers``)
+        xs["index"] = jnp.arange(len(layers[names[0]]), dtype=jnp.int32)
         return xs, {k: layers[k].reshape((-1,) + layers[k].shape[2:])
                     for k in names}
 
@@ -477,7 +498,9 @@ class LlamaModel:
         for the plain model."""
         return None if kind is None else self._windows[kind]
 
-    def _attention(self, q, k, v, positions, window=None):
+    def _attention(self, q, k, v, positions, window=None, layer=None):
+        """The training program's attention over this call's own rows
+        (``layer``: for a model whose rows need its weights to be read)."""
         if window is not None:
             # a layer of a model with kinds (training and the tests'
             # oracle; no cell trains one): the masked reference
@@ -562,6 +585,41 @@ class LlamaModel:
         return apply_rope_of_kind(x, self._inv_freq, self._rope_scales,
                                   kind, positions)
 
+    def _qkv(self, h, layer: Params, positions, kind, pin):
+        """The layer's projections of h [B, T, D] (normed), up to what
+        ``attend`` takes: q [B, T, H, hd] and this call's K/V ROWS as the
+        model's cache holds them, here k/v [B, T, Hkv, hd], q and k
+        turned by RoPE. A model whose cache rows are something else
+        (``MLAModel``: a latent row and one rotary key part) overrides
+        this with ``kv_row_shapes``, ``_attend_rows`` and
+        ``_attend_pages``; ``pin`` is ``_layer``'s."""
+        dt = self.cfg.dtype
+        q = jnp.einsum("bsd,dhk->bshk", h, layer["wq"].astype(dt))
+        k = jnp.einsum("bsd,dhk->bshk", h, layer["wk"].astype(dt))
+        v = jnp.einsum("bsd,dhk->bshk", h, layer["wv"].astype(dt))
+        q, k = self._qk_norm(q, k, layer)
+        q = pin(q, "batch", "seq", "heads", None)
+        q = self._rope(q, positions, kind)
+        k = self._rope(k, positions, kind)
+        return q, k, v
+
+    def _attend_rows(self, q, k, v, layer: Params, positions_q, positions_k,
+                     window=None):
+        """A prefill's attention of q [B, T, H, hd] over DENSE rows k, v
+        [B, S, ...] (a slot cache, or a gathered prefix and the call's
+        own rows) at ``positions_k``: -> o [B, T, H, hd]."""
+        return reference_attention(q, k, v, positions_q=positions_q,
+                                   positions_k=positions_k, window=window)
+
+    def _attend_pages(self, q, k_pool, v_pool, layer: Params, block_tables,
+                      lengths, **window):
+        """A decode step's attention of q [B, H, hd] over the slots'
+        pages of the pools (``ops.paged_attention.paged_decode_attention``
+        and its keywords): -> o [B, H, hd]."""
+        from ray_tpu.ops.paged_attention import paged_decode_attention
+        return paged_decode_attention(q, k_pool, v_pool, block_tables,
+                                      lengths, **window)
+
     def _layer(self, x, layer: Params, positions, attend, live=None,
                constrain: bool = False, kind=None,
                stacks: Optional[Params] = None):
@@ -569,8 +627,8 @@ class LlamaModel:
         q and k by (``None``: 0..T-1), by the table of the layer's
         ``kind`` (its index, traced; None in the plain model);
         ``attend(q, k, v) -> (o, kv)``
-        with q/o [B, T, H, hd] and k/v [B, T, Hkv, hd], the calling
-        program's own: it writes this call's K/V where the program keeps
+        with q/o [B, T, H, hd] and k/v ``_qkv``'s rows ([B, T, Hkv, hd]),
+        the calling program's own: it writes this call's K/V where it keeps
         them, reads the earlier ones, and hands back as ``kv`` whatever
         the program's layer scan carries on or stacks up. ``live`` and
         ``stacks`` are ``_ffn``'s; ``constrain`` (the training program
@@ -585,13 +643,7 @@ class LlamaModel:
         with jax.named_scope("norm_residual"):
             h = self._norm(x, layer["attn_norm"])
         with jax.named_scope("attention"):
-            q = jnp.einsum("bsd,dhk->bshk", h, layer["wq"].astype(dt))
-            k = jnp.einsum("bsd,dhk->bshk", h, layer["wk"].astype(dt))
-            v = jnp.einsum("bsd,dhk->bshk", h, layer["wv"].astype(dt))
-            q, k = self._qk_norm(q, k, layer)
-            q = pin(q, "batch", "seq", "heads", None)
-            q = self._rope(q, positions, kind)
-            k = self._rope(k, positions, kind)
+            q, k, v = self._qkv(h, layer, positions, kind, pin)
         o, kv = attend(q, k, v)
         with jax.named_scope("attention"):
             o = jnp.einsum("bshk,hkd->bsd", o, layer["wo"].astype(dt))
@@ -647,7 +699,7 @@ class LlamaModel:
         extra (``None`` for the dense layer)."""
         cfg = self.cfg
 
-        def layer_fn(x, layer_and_kind):
+        def layer_fn(x, layer_and_kind, stacks=None):
             layer, kind = layer_and_kind
 
             def attend(q, k, v):        # training keeps no K/V
@@ -660,7 +712,7 @@ class LlamaModel:
                         q, k, v, layer, jnp.arange(q.shape[1]))[0], None
                 with jax.named_scope("attention"):
                     return self._attention(q, k, v, positions,
-                                           self._window(kind)), None
+                                           self._window(kind), layer), None
 
             x, _, extra = self._layer(x, layer, positions, attend,
                                       constrain=True, kind=kind)
@@ -672,9 +724,10 @@ class LlamaModel:
                     layer_fn, policy=jax.checkpoint_policies.dots_saveable)
             else:
                 layer_fn = jax.checkpoint(layer_fn)
-        x = self._embed(params, tokens, constrain=True)
-        x, extras = jax.lax.scan(layer_fn, x,
-                                 (params["layers"], self._kinds_xs()))
+        # training slices the layer's weights (``stacks`` None: moe.py)
+        x, extras = self._scan_layers(
+            layer_fn, self._embed(params, tokens, constrain=True), params,
+            (params["layers"], None), (self._kinds_xs(),))
         return self._head(params, x, constrain=True, every_head=True), extras
 
     def _eva_over_rows(self, q, k, v, layer: Params, positions_q):
@@ -694,13 +747,55 @@ class LlamaModel:
                           chunk=chunk)
         return o, ks, vs
 
+    def _scan_layers(self, step, carry, params: Params, main, xs: tuple,
+                     join: bool = False):
+        """``jax.lax.scan`` of a program's layer body over the model's
+        layers, A STACK AFTER ANOTHER: ``step(carry, (layer, *xs), stacks)
+        -> (carry, ys)``. ``main`` is ``(layers, stacks)``: what the scan
+        slices of ``params["layers"]`` and what its body closes over
+        whole (``_whole_leaves``; training hands ``(params["layers"],
+        None)``); ``xs`` what the program hands each layer beside its
+        parameters (arrays over ALL the layers, or None).
+
+        A model has one stack and this is one scan. A model whose first
+        ``cfg.leading_layers`` layers are of ANOTHER PARAMETER TREE (dense
+        layers before expert layers) holds them as a stack of their own,
+        ``params["leading_layers"]``, scanned first by the same body: the
+        one decoder layer asks the tree what it holds (``_ffn``). The K/V
+        rows, bases and kinds in ``xs`` count through both. ``ys`` are
+        the main stack's; with ``join`` both stacks', in layer order
+        (a prefill's K/V rows)."""
+        layers, stacks = main
+        lead, ys_lead = params.get("leading_layers"), None
+        if lead is not None:
+            n = len(lead["attn_norm"])
+            carry, ys_lead = jax.lax.scan(
+                lambda c, x: step(c, x, None), carry,
+                (lead, *jax.tree.map(lambda a: a[:n], xs)))
+            xs = jax.tree.map(lambda a: a[n:], xs)
+        carry, ys = jax.lax.scan(lambda c, x: step(c, x, stacks), carry,
+                                 (layers, *xs))
+        if join and ys_lead is not None:
+            ys = jax.tree.map(lambda a, b: jnp.concatenate([a, b]),
+                              ys_lead, ys)
+        return carry, ys
+
     # -- KV-cache inference path (serving; BASELINE.md config 5) ----------
+    def kv_row_shapes(self) -> Tuple[Tuple[int, ...], Tuple[int, ...]]:
+        """One position's row in each of the cache's two parts, ``"k"``
+        and ``"v"``: what follows ``[L, B, S]`` in a slot cache and
+        ``[L, NB, bs]`` in a pool. The engine and the harness move rows by
+        these names whatever they hold (``llm/engine.py:_insert_impl``)."""
+        row = (self.cfg.n_kv_heads, self.cfg.head_dim)
+        return row, row
+
+    def _kv_zeros(self, *leading: int) -> Params:
+        return {name: jnp.zeros(leading + row, self.cfg.dtype)
+                for name, row in zip(("k", "v"), self.kv_row_shapes())}
+
     def init_kv_cache(self, batch: int, max_seq: int) -> Params:
         """Slot-major cache: [L, B, S, Hkv, D] per k/v, bf16 in HBM."""
-        cfg = self.cfg
-        shape = (cfg.n_layers, batch, max_seq, cfg.n_kv_heads, cfg.head_dim)
-        return {"k": jnp.zeros(shape, cfg.dtype),
-                "v": jnp.zeros(shape, cfg.dtype)}
+        return self._kv_zeros(self.cfg.n_layers, batch, max_seq)
 
     def forward_step(self, params: Params, tokens: jax.Array,
                      cache: Params, offsets: jax.Array
@@ -717,7 +812,7 @@ class LlamaModel:
         q_pos = offsets[:, None] + jnp.arange(T)[None, :]        # [B, T]
         batch_idx = jnp.arange(B)[:, None]
 
-        def step(x, layer_and_cache):
+        def step(x, layer_and_cache, stacks):
             layer, k_cache, v_cache, kind = layer_and_cache
 
             def attend(q, k_new, v_new):
@@ -732,20 +827,18 @@ class LlamaModel:
                     return o, (k_all, v_all, ks, vs)
                 with jax.named_scope("attention"):
                     # attend over cache positions <= own position
-                    o = reference_attention(q, k_all, v_all,
-                                            positions_q=q_pos,
-                                            positions_k=jnp.arange(S),
-                                            window=self._window(kind))
+                    o = self._attend_rows(q, k_all, v_all, layer, q_pos,
+                                          jnp.arange(S), self._window(kind))
                 return o, (k_all, v_all)
 
             x, kv, _ = self._layer(x, layer, q_pos, attend, kind=kind,
                                    stacks=stacks)
             return x, kv
 
-        layers, stacks = self._whole_leaves(params["layers"])
-        x, kv = jax.lax.scan(
-            step, self._embed(params, tokens),
-            (layers, cache["k"], cache["v"], self._kinds_xs()))
+        main = self._whole_leaves(params["layers"])
+        x, kv = self._scan_layers(
+            step, self._embed(params, tokens), params, main,
+            (cache["k"], cache["v"], self._kinds_xs()), join=True)
         return self._head(params, x), self._kv_dict(kv)
 
     @staticmethod
@@ -758,11 +851,7 @@ class LlamaModel:
     def init_kv_pool(self, num_blocks: int, block_size: int) -> Params:
         """Block-pool cache: k/v [L, num_blocks, block_size, Hkv, D],
         bf16 in HBM, shared by every slot via per-slot block tables."""
-        cfg = self.cfg
-        shape = (cfg.n_layers, num_blocks, block_size, cfg.n_kv_heads,
-                 cfg.head_dim)
-        return {"k": jnp.zeros(shape, cfg.dtype),
-                "v": jnp.zeros(shape, cfg.dtype)}
+        return self._kv_zeros(self.cfg.n_layers, num_blocks, block_size)
 
     def init_kv_pools(self, num_blocks: Tuple[int, ...],
                       block_size: int) -> Params:
@@ -791,10 +880,8 @@ class LlamaModel:
                     "sv": jnp.zeros(summary, cfg.dtype)}
         per_layer = [num_blocks[kind] for kind in self.layer_kinds]
         bases = [sum(per_layer[:i]) for i in range(len(per_layer))]
-        shape = (sum(per_layer), block_size, cfg.n_kv_heads, cfg.head_dim)
-        return {"k": jnp.zeros(shape, cfg.dtype),
-                "v": jnp.zeros(shape, cfg.dtype),
-                "bases": jnp.asarray(bases, jnp.int32)}
+        return dict(self._kv_zeros(sum(per_layer), block_size),
+                    bases=jnp.asarray(bases, jnp.int32))
 
     def paged_decode_impl(self) -> str:
         """The attention ``decode_step_paged`` is built with, and the one
@@ -881,11 +968,13 @@ class LlamaModel:
             return self._decode_step_eva(params, tokens, pool, block_tables,
                                          offsets, live)
         if "bases" in pool:
-            NB, stack = None, pool["k"].shape
+            NB, bs = None, pool["k"].shape[1]
         else:
-            L, NB = pool["k"].shape[:2]
-            stack = (L * NB,) + pool["k"].shape[2:]
-        bs = stack[1]
+            L, NB, bs = pool["k"].shape[:3]
+
+        def whole(a):           # [L, NB, bs, ...] as the stack [L*NB, ...]
+            return a if NB is None else a.reshape((-1,) + a.shape[2:])
+
         kinds = self._kinds_xs()
         if kinds is not None and block_tables.ndim == 2:
             block_tables = jnp.broadcast_to(
@@ -901,9 +990,8 @@ class LlamaModel:
             lengths - self._windows[:, None], 0)
         q_pos = offsets[:, None]                                   # [B, 1]
         impl = self.paged_decode_impl()
-        from ray_tpu.ops.paged_attention import paged_decode_attention
 
-        def step(carry, layer_base_kind):
+        def step(carry, layer_base_kind, stacks):
             x, k_pool, v_pool = carry
             # ``base``: where this layer's blocks start in the stack
             layer, base, kind = layer_base_kind
@@ -920,23 +1008,23 @@ class LlamaModel:
                     v_all = v_pool.at[base + own(dest_block), dest_off].set(
                         v_new[:, 0])
                 with jax.named_scope("attention"):
-                    o = paged_decode_attention(
-                        q[:, 0], k_all, v_all, own(block_tables), lengths,
-                        impl=impl, starts=own(starts), first_block=base,
-                        num_blocks=NB)
+                    o = self._attend_pages(
+                        q[:, 0], k_all, v_all, layer, own(block_tables),
+                        lengths, impl=impl, starts=own(starts),
+                        first_block=base, num_blocks=NB)
                 return o[:, None], (k_all, v_all)
 
             x, (k_pool, v_pool), extra = self._layer(
                 x, layer, q_pos, attend, live=live, kind=kind, stacks=stacks)
             return (x, k_pool, v_pool), extra
 
-        layers, stacks = self._whole_leaves(params["layers"])
-        (x, k_out, v_out), extras = jax.lax.scan(
+        main = self._whole_leaves(params["layers"])
+        (x, k_out, v_out), extras = self._scan_layers(
             step,
             (self._embed(params, tokens[:, None]),                 # [B,1,D]
-             pool["k"].reshape(stack), pool["v"].reshape(stack)),
-            (layers,
-             pool["bases"] if NB is None
+             whole(pool["k"]), whole(pool["v"])),
+            params, main,
+            (pool["bases"] if NB is None
              else jnp.arange(L, dtype=jnp.int32) * NB,
              kinds))
         pool = dict(pool, k=k_out.reshape(pool["k"].shape),
@@ -1121,28 +1209,27 @@ class LlamaModel:
             jnp.arange(Pmax)[None, :], far)                          # [N,Pmax]
         pos_k = jnp.concatenate([pos_prefix, pos_q], axis=1)      # [N,P+Tb]
 
-        def step(x, layer_and_prefix):
+        def step(x, layer_and_prefix, stacks):
             layer, kp, vp, kind = layer_and_prefix  # kp/vp [N, Pmax, Hkv, D]
 
             def attend(q, k_new, v_new):
                 with jax.named_scope("attention"):
-                    o = reference_attention(
+                    o = self._attend_rows(
                         q, jnp.concatenate([kp.astype(k_new.dtype), k_new],
                                            axis=1),
                         jnp.concatenate([vp.astype(v_new.dtype), v_new],
                                         axis=1),
-                        positions_q=pos_q, positions_k=pos_k,
-                        window=self._window(kind))
+                        layer, pos_q, pos_k, self._window(kind))
                 return o, (k_new, v_new)
 
             x, kv, _ = self._layer(x, layer, pos_q, attend, kind=kind,
                                    stacks=stacks)
             return x, kv
 
-        layers, stacks = self._whole_leaves(params["layers"])
-        x, (k_out, v_out) = jax.lax.scan(
-            step, self._embed(params, tokens),
-            (layers, prefix_k, prefix_v, self._kinds_xs()))
+        main = self._whole_leaves(params["layers"])
+        x, (k_out, v_out) = self._scan_layers(
+            step, self._embed(params, tokens), params, main,
+            (prefix_k, prefix_v, self._kinds_xs()), join=True)
         return (self._head(params, x, last=lengths - 1)[:, 0],
                 {"k": k_out, "v": v_out})
 

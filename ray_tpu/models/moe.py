@@ -71,6 +71,24 @@ class MoEConfig(LlamaConfig):
     # inside shard_map (ops/moe_dispatch.py).
     capacity_factor: float = 1.25
     moe_dispatch: str = "einsum"
+    # The router. "softmax": the top-k of a softmax over all experts.
+    # "sigmoid" (``topk_method: noaux_tc``, one group): scores
+    # ``sigmoid(logits)``; chosen by ``scores + router_bias`` [E], a
+    # float32 leaf that is no part of the weights (its values come from
+    # training; ``init`` draws them N(0, ``router_bias_init_std``) so that
+    # a program that drops it differs); weights the chosen scores, under
+    # ``norm_topk_prob`` over their sum, times ``routed_scaling_factor``
+    router_kind: str = "softmax"
+    routed_scaling_factor: float = 1.0
+    router_bias_init_std: float = 0.0
+    # a dense SwiGLU this wide on EVERY token beside the routed sum (the
+    # shared experts, side by side: n_shared x one's width); 0: none
+    shared_ffn_dim: int = 0
+    # the first ``leading_layers`` layers are DENSE (SwiGLU
+    # ``leading_ffn_dim``): another parameter tree, a stack of its own
+    # (``params["leading_layers"]``, ``LlamaModel._scan_layers``)
+    leading_layers: int = 0
+    leading_ffn_dim: Optional[int] = None
 
     def __post_init__(self):
         super().__post_init__()
@@ -78,15 +96,38 @@ class MoEConfig(LlamaConfig):
             raise ValueError(
                 f"moe_dispatch must be 'einsum' or 'alltoall', "
                 f"got {self.moe_dispatch!r}")
+        if self.router_kind not in ("softmax", "sigmoid"):
+            raise ValueError(
+                f"router_kind must be 'softmax' or 'sigmoid', "
+                f"got {self.router_kind!r}")
+        if not 0 <= self.leading_layers < self.n_layers or (
+                self.leading_layers and not self.leading_ffn_dim):
+            raise ValueError(
+                f"leading_layers ({self.leading_layers}) dense layers of "
+                f"width leading_ffn_dim ({self.leading_ffn_dim}) come "
+                f"before at least one of the {self.n_layers} layers")
+        if self.leading_layers and self.layer_types is not None:
+            raise ValueError("leading layers of another tree are not "
+                             "combined with layer kinds")
+
+    def attention_params(self) -> int:
+        """One layer's attention weights (and QK-norm scales)."""
+        d = self.dim
+        q = self.n_heads * self.head_dim
+        kv = self.n_kv_heads * self.head_dim
+        return 2 * d * q + 2 * d * kv + (q + kv if self.qk_norm else 0)
 
     def num_params(self) -> int:
         d, f, v, E = self.dim, self.ffn_dim, self.vocab_size, self.num_experts
-        q = self.n_heads * self.head_dim
-        kv = self.n_kv_heads * self.head_dim
-        per_layer = (2 * d * q + 2 * d * kv + 2 * d + d * E + 3 * E * d * f
-                     + (q + kv if self.qk_norm else 0))
+        attn = self.attention_params() + 2 * d            # and two norms
+        expert_layer = (attn + d * E + 3 * E * d * f
+                        + 3 * d * self.shared_ffn_dim
+                        + (E if self.router_kind == "sigmoid" else 0))
+        lead = self.leading_layers
+        dense_layer = attn + 3 * d * (self.leading_ffn_dim or 0)
         heads = 0 if self.tie_embeddings else v * d
-        return v * d + self.n_layers * per_layer + d + heads
+        return (v * d + lead * dense_layer
+                + (self.n_layers - lead) * expert_layer + d + heads)
 
     @staticmethod
     def debug_moe(num_experts: int = 4) -> "MoEConfig":
@@ -116,6 +157,13 @@ def moe_param_logical_axes(cfg: MoEConfig) -> Params:
     layers["e_gate"] = (None, "experts", "embed_in", "mlp")
     layers["e_up"] = (None, "experts", "embed_in", "mlp")
     layers["e_down"] = (None, "experts", "mlp", "embed_in")
+    if cfg.router_kind == "sigmoid":
+        layers["router_bias"] = (None, "experts")
+    if cfg.shared_ffn_dim:
+        layers["s_gate"] = layers["s_up"] = (None, "embed_in", "mlp")
+        layers["s_down"] = (None, "mlp", "embed_in")
+    if cfg.leading_layers:
+        axes["leading_layers"] = param_logical_axes(cfg)["layers"]
     if cfg.qk_norm:
         layers["q_norm"] = (None, "heads", None)
         layers["k_norm"] = (None, "kv_heads", None)
@@ -130,7 +178,12 @@ class MoEModel(LlamaModel):
     # among them: its logits are computed in float32 (``route_topk``) and
     # a bf16 router would choose other experts
     MATMUL_LAYER_LEAVES = ("wq", "wk", "wv", "wo",
-                           "e_gate", "e_up", "e_down")
+                           "e_gate", "e_up", "e_down",
+                           # the shared expert's, and a leading dense
+                           # layer's FFN (the router's BIAS, like the
+                           # router, stays float32)
+                           "s_gate", "s_up", "s_down",
+                           "w_gate", "w_up", "w_down")
     # the grouped matmuls' operands (the router is a dense matmul's)
     WHOLE_LAYER_LEAVES = ("e_gate", "e_up", "e_down")
 
@@ -138,11 +191,20 @@ class MoEModel(LlamaModel):
                  rules: Optional[Dict] = None):
         super().__init__(cfg, mesh=mesh, rules=rules)
         self._ep = 1 if mesh is None else mesh.shape.get("ep", 1)
+        if self._ep > 1 and (cfg.router_kind != "softmax"
+                             or cfg.shared_ffn_dim):
+            raise NotImplementedError(
+                "the capacity dispatches under an ep mesh axis have the "
+                "softmax router and no shared expert")
+
+    @property
+    def _main_layers(self) -> int:
+        return self.cfg.n_layers - self.cfg.leading_layers
 
     def init(self, rng: jax.Array) -> Params:
         params = super().init(rng)
         cfg: MoEConfig = self.cfg
-        d, f, E, L = cfg.dim, cfg.ffn_dim, cfg.num_experts, cfg.n_layers
+        d, f, E, L = cfg.dim, cfg.ffn_dim, cfg.num_experts, self._main_layers
         keys = jax.random.split(jax.random.fold_in(rng, 1), 4)
         layers = params["layers"]
         for key in ("w_gate", "w_up", "w_down"):
@@ -160,6 +222,18 @@ class MoEModel(LlamaModel):
                 (L, cfg.n_heads, cfg.head_dim), jnp.float32)
             layers["k_norm"] = jnp.ones(
                 (L, cfg.n_kv_heads, cfg.head_dim), jnp.float32)
+        more = iter(jax.random.split(jax.random.fold_in(rng, 2), 16))
+        if cfg.router_kind == "sigmoid":
+            layers["router_bias"] = jax.random.normal(
+                next(more), (L, E), jnp.float32) * cfg.router_bias_init_std
+        if cfg.shared_ffn_dim:
+            fs = cfg.shared_ffn_dim
+            layers["s_gate"] = self._dense(next(more), (L, d, fs), d)
+            layers["s_up"] = self._dense(next(more), (L, d, fs), d)
+            layers["s_down"] = self._dense(next(more), (L, fs, d), fs)
+        if cfg.leading_layers:
+            params["leading_layers"] = self._init_layers(
+                more, cfg.leading_layers, cfg.leading_ffn_dim)
         return params
 
     def param_shardings(self):
@@ -194,8 +268,16 @@ class MoEModel(LlamaModel):
         ``ep`` mesh axis the capacity dispatch runs and only "aux" is
         there (``ffn_load_shape`` says so). With ``stacks`` (a serving
         program off a mesh) the expert weights are every layer's,
-        ``[L*E, ...]``, and this layer's begin at ``layer["index"] * E``."""
+        ``[L*E, ...]``, and this layer's begin at ``layer["index"] * E``.
+
+        The layer's own tree says what it is: one with no router is a
+        LEADING DENSE layer (``cfg.leading_layers``), the parent's SwiGLU
+        and no extra. A ``shared_ffn_dim`` adds the shared expert's dense
+        SwiGLU of every token to the routed sum."""
         cfg: MoEConfig = self.cfg
+        if "router" not in layer:
+            with jax.named_scope("dense_ffn_leading"):
+                return super()._ffn(h, layer, live, constrain)
         shared = dict(top_k=cfg.expert_top_k, dtype=cfg.dtype,
                       norm_topk_prob=cfg.norm_topk_prob,
                       z_coef=cfg.router_z_loss,
@@ -218,11 +300,23 @@ class MoEModel(LlamaModel):
         from ray_tpu.ops.moe_dispatch import dropless_expert_ffn
         B, T, D = h.shape
         rows_live = None if live is None else jnp.repeat(live, T)
+        if cfg.router_kind == "sigmoid":
+            shared.update(sigmoid_bias=layer["router_bias"],
+                          weight_scale=cfg.routed_scaling_factor)
         out, load, experts, aux = dropless_expert_ffn(
             h.reshape(B * T, D), *weights, live=rows_live,
             first_expert=(None if stacks is None
                           else layer["index"] * cfg.num_experts), **shared)
-        return out.reshape(B, T, D), {
+        out = out.reshape(B, T, D)
+        if cfg.shared_ffn_dim:
+            with jax.named_scope("moe_shared_expert"):
+                dt = cfg.dtype
+                act = (jax.nn.silu(jnp.einsum(
+                    "bsd,df->bsf", h, layer["s_gate"].astype(dt)))
+                    * jnp.einsum("bsd,df->bsf", h, layer["s_up"].astype(dt)))
+                out = out + jnp.einsum("bsf,fd->bsd", act,
+                                       layer["s_down"].astype(dt))
+        return out, {
             "aux": aux, "load": load,
             "experts": experts.reshape(B, T, cfg.expert_top_k)}
 
@@ -230,7 +324,7 @@ class MoEModel(LlamaModel):
         """[layers, experts]; nothing under an ``ep`` mesh axis."""
         if self._ep > 1:
             return None
-        return self.cfg.n_layers, self.cfg.num_experts
+        return self._main_layers, self.cfg.num_experts
 
     def grouped_matmul_plan(self, tokens: int) -> Dict[str, str]:
         """Which implementation the three grouped matmuls of a program of

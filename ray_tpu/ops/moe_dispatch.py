@@ -53,12 +53,27 @@ logger = logging.getLogger(__name__)
 
 
 def route_topk(xf, router, top_k: int, norm_topk_prob: bool,
-               precision=None):
+               precision=None, sigmoid_bias=None, weight_scale: float = 1.0):
     """Router in float32: ``(logits [T, E], probs [T, E], weights [T, K],
     experts [T, K])``. The top-k softmax weights are used as they are
-    unless ``norm_topk_prob`` (then they sum to 1)."""
+    unless ``norm_topk_prob`` (then they sum to 1).
+
+    With ``sigmoid_bias`` [E] (float32) the SIGMOID form (``noaux_tc``,
+    one group): scores ``s = sigmoid(logits)``; the experts are the
+    top-k of ``s + bias``, a selection bias that is no part of the
+    weights; the weights are ``s`` of the chosen, divided by their sum
+    (+ 1e-20) under ``norm_topk_prob``, times ``weight_scale``
+    (``routed_scaling_factor``). ``probs`` is then ``s``."""
     logits = jnp.einsum("td,de->te", xf.astype(jnp.float32),
                         router.astype(jnp.float32), precision=precision)
+    if sigmoid_bias is not None:
+        scores = jax.nn.sigmoid(logits)
+        _, gate_idx = jax.lax.top_k(scores + sigmoid_bias, top_k)
+        gate_vals = jnp.take_along_axis(scores, gate_idx, axis=-1)
+        if norm_topk_prob:
+            gate_vals = gate_vals / (
+                jnp.sum(gate_vals, -1, keepdims=True) + 1e-20)
+        return logits, scores, gate_vals * weight_scale, gate_idx
     probs = jax.nn.softmax(logits, axis=-1)
     gate_vals, gate_idx = jax.lax.top_k(probs, top_k)
     if norm_topk_prob:
@@ -220,7 +235,8 @@ def grouped_matmul(lhs, rhs, group_sizes, dtype):
 def dropless_expert_ffn(x, router, e_gate, e_up, e_down, *, top_k: int,
                         norm_topk_prob: bool, dtype, live=None,
                         first_expert=None,
-                        z_coef: float = 0.0, lb_coef: float = 0.0):
+                        z_coef: float = 0.0, lb_coef: float = 0.0,
+                        sigmoid_bias=None, weight_scale: float = 1.0):
     """x [T, D] -> ``(out [T, D] in ``dtype``, load [E] int32, experts
     [T, K] int32, aux)``.
 
@@ -228,7 +244,8 @@ def dropless_expert_ffn(x, router, e_gate, e_up, e_down, *, top_k: int,
     is the number of rows handed to expert ``e``'s grouped matmuls, of
     the tokens ``live`` [T] bool marks (all, if ``None``): it sums to
     ``live.sum() * top_k`` because nothing is dropped. ``aux`` is the
-    training loss of ``router_aux_loss``.
+    training loss of ``router_aux_loss``. ``sigmoid_bias`` and
+    ``weight_scale`` are ``route_topk``'s: the sigmoid router.
 
     With ``first_expert`` (a traced int32 scalar) the weights are G >= E
     groups, [G, D, F] and [G, F, D], a stack of several layers' experts
@@ -245,7 +262,8 @@ def dropless_expert_ffn(x, router, e_gate, e_up, e_down, *, top_k: int,
         # bf16 pass of the logits would swap more near-tied experts
         logits, probs, weights, experts = route_topk(
             x, router, top_k, norm_topk_prob,
-            precision=jax.lax.Precision.HIGHEST)
+            precision=jax.lax.Precision.HIGHEST, sigmoid_bias=sigmoid_bias,
+            weight_scale=weight_scale)
         aux = router_aux_loss(logits, probs, z_coef, lb_coef)
     with jax.named_scope("moe_dispatch"):
         flat = experts.reshape(T * top_k)

@@ -421,6 +421,73 @@ def test_hybrid_cell_decode_program_fits_the_v5e(v5e, placed, monkeypatch):
     assert mem.temp_size_in_bytes < one_stack_a_layer / 4
 
 
+# kanana-2-30b-a3b-d5.long_decode_mla: 32 slots x 24,576 at block 32 (768
+# table entries a slot), a latent row of 512 + 128 lanes, 32 heads
+MLA_CELL_TABLE = 768
+
+
+def test_mla_decode_kernel_lowers_at_the_mla_cells_shape(v5e):
+    """The absorbed latent-attention kernel (``ops/mla_attention.py``) for
+    the v5e, no chip: pages ``[32, 512]`` and ``[32, 128]`` copied as the
+    2-D tiles they are out of a stack of five layers' windows, the 32
+    heads as the rows of its dots. The pools are what the arguments
+    hold: 4.69 GiB, a row's 1,280 B and nothing a sublane tile pads."""
+    from ray_tpu.ops.mla_attention import (PE_LANES,
+                                           mla_decode_attention_pallas)
+
+    B, H, R, bs, maxb, L = CELL_SLOTS, 32, 512, CELL_BS, MLA_CELL_TABLE, 5
+    blocks = L * (B * maxb + 1)
+    compiled = mla_decode_attention_pallas.lower(
+        v5e(B, H, R), v5e(B, H, PE_LANES), v5e(blocks, bs, R),
+        v5e(blocks, bs, PE_LANES), v5e(B, maxb, dtype=jnp.int32),
+        v5e(B, dtype=jnp.int32), scale=192 ** -0.5,
+        interpret=False).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+    held = compiled.memory_analysis().argument_size_in_bytes
+    assert held == pytest.approx(blocks * bs * (R + PE_LANES) * 2, rel=0.001)
+    assert 4.2 < held / 2**30 < 4.7
+
+
+def test_mla_cell_decode_program_fits_the_v5e(v5e, placed, monkeypatch):
+    """``kanana-2-30b-a3b-d5.long_decode_mla``'s decode program as the
+    engine jits it: 32 slots x 24,576, one leading dense layer and four
+    expert layers (two layer scans, one body), all 128 experts, latent
+    pool rows. The v5e's compiler takes it at 10.57 GiB of 15.75 (10.56
+    of arguments: 5.87 weights + 4.69 pool; 0.01 of temporaries), with
+    the latent kernel of both scans and the three grouped matmuls as
+    Mosaic calls (2048 x 768 is no width XLA tiles 512 x 512, so
+    ``grouped_matmul_impl`` takes the Pallas kernel), and nothing of an
+    expert stack's shape among the temporaries."""
+    from benchmark import run as harness
+    from benchmark.builders import kanana
+
+    _as_on_the_chip(monkeypatch)
+    cfg = harness.load_json(harness.ROOT,
+                            "benchmark/configs/kanana-2-30b-a3b-d5.json")
+    B, bs, maxb, E = CELL_SLOTS, CELL_BS, MLA_CELL_TABLE, 128
+    model = kanana.build_model(cfg, maxb * bs)
+    assert model.paged_decode_impl() == "mla_pallas"
+    assert model.ffn_load_shape() == (4, E)
+    pool = jax.eval_shape(lambda: model.init_kv_pool(B * maxb + 1, bs))
+    assert pool["k"].shape == (5, B * maxb + 1, bs, 512)
+    assert pool["v"].shape == (5, B * maxb + 1, bs, 128)
+    compiled = _engine_decode(model, B * maxb).lower(
+        placed(_engine_params(model)), v5e(B, dtype=jnp.int32), placed(pool),
+        v5e(B, maxb, dtype=jnp.int32), v5e(B, dtype=jnp.int32),
+        *_sampling(v5e, B), v5e(4, E, dtype=jnp.int32)).compile()
+    text = compiled.as_text()
+    _holds_the_tiled_grouped_matmuls(text, model, B)
+    # the latent kernel in each of the two scans, and the matmuls
+    assert text.count("tpu_custom_call") >= 5
+    mem = compiled.memory_analysis()
+    total = (mem.argument_size_in_bytes + mem.temp_size_in_bytes
+             + mem.output_size_in_bytes - mem.alias_size_in_bytes)
+    assert total < 15.75 * 2**30
+    assert 4.2 < sum(a.size * 2 for a in pool.values()) / 2**30 < 4.7
+    one_stack_a_layer = E * 2048 * 768 * 2
+    assert mem.temp_size_in_bytes < one_stack_a_layer / 4
+
+
 # evabyte-6.5b-d8.long_decode_eva: 16 slots x 24,576 at block 32; both
 # parts' tables are 65 entries a slot (the window's 64 blocks and one more;
 # the summary part needs 48), MHA 32/32 at 128

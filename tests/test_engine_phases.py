@@ -294,11 +294,13 @@ def test_stats_keys_are_fixed_plain_monotone_and_the_phases_sum_to_the_step():
             "prefill_tokens", "prefill_padded_tokens"} <= set(first)
         # numbers, but for an expert model's per-expert rows (a list,
         # empty for this dense one: tests/test_olmoe_serving.py) and the
-        # names of what implements its grouped matmuls (strings, empty
-        # for this dense one: tests/test_moe_grouped_matmul.py)
+        # names of what implements its grouped matmuls and of its router
+        # (strings, empty for this dense one:
+        # tests/test_moe_grouped_matmul.py, tests/test_kanana_serving.py)
         assert first["moe_expert_load"] == []
         named = {"moe_grouped_impl", "moe_gmm_tiling_gate",
-                 "moe_gmm_tiling_up", "moe_gmm_tiling_down"}
+                 "moe_gmm_tiling_up", "moe_gmm_tiling_down",
+                 "moe_router_kind"}
         assert all(first[k] == "" for k in named)
         assert all(type(v) in (int, float) for k, v in first.items()
                    if k != "moe_expert_load" and k not in named)

@@ -4,6 +4,7 @@
     chiprun -- python3 tools/moe_gmm_bench.py candidates # PR 26's sweep
     chiprun -- python3 tools/moe_gmm_bench.py tilings    # PR 40's sweep
     chiprun -- python3 tools/moe_gmm_bench.py tilings 1024 12288  # row tiles
+    chiprun -- python3 tools/moe_gmm_bench.py chosen     # PR 41's reading
 
 **The whole stack (PR 37).** A decode step's 256 rows (32 slots, top-8)
 through one layer's three grouped matmuls, at both expert cells' shapes
@@ -43,6 +44,13 @@ with ``error``. With rows named after ``tilings`` (multiples of 256),
 those rows in place of the cells', and the ROW tiles alone at
 ``gmm_tiling``'s (k, n). The lines go to
 ``chiprun_out/moe_gmm_tilings.jsonl`` too.
+
+**``chosen`` (PR 41)**: what ``ops.moe_dispatch.grouped_matmul_impl``
+picks at a width it was not tuned on, as a reading and no more: 128
+experts of 2048 x 768 (kanana-2-30b-a3b-d5: 4 expert layers, top-6), a
+decode step's 192 rows and a 512-token chunk's 3,072, the layer's three
+calls on the whole stack at the last layer's offset, on the resolver's
+choice and on ``ragged_dot``.
 
 Prints one JSON line per reading.
 """
@@ -299,6 +307,42 @@ def sweep_tilings(rows_asked, out):
         del merged
 
 
+def chosen(name="kanana-2-30b-a3b-d5", L=4, experts=128, d=2048, f=768,
+           top_k=6, rows_asked=(192, 3072)):
+    sys.path.insert(0, os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))))    # the one mode that asks the program
+    from ray_tpu.ops.moe_dispatch import (gmm_vmem_bytes, grouped_matmul,
+                                          grouped_matmul_impl)
+
+    rng = np.random.default_rng(0)
+    keys = jax.random.split(jax.random.key(0), 3)
+    wg, wu, wd = (jax.random.normal(k, (L * experts,) + shape, jnp.bfloat16)
+                  * 0.02 for k, shape in zip(keys, ((d, f), (d, f), (f, d))))
+    for rows in rows_asked:
+        idx = np.stack([rng.permutation(experts)[:top_k]
+                        for _ in range(rows // top_k)])
+        sizes = np.zeros(L * experts, np.int32)
+        sizes[(L - 1) * experts:] = np.bincount(idx.ravel(),
+                                                minlength=experts)
+        xs = jax.random.normal(jax.random.key(rows), (rows, d), jnp.bfloat16)
+        picks = {call: grouped_matmul_impl(rows, k, n, 2)
+                 for call, (k, n) in (("gate_up", (d, f)), ("down", (f, d)))}
+        line = {"shapes": name, "groups": L * experts, "rows": rows,
+                "experts_with_rows": int((sizes > 0).sum()), "call": "ffn",
+                "at": "last"}
+        for call, (impl, tiling) in picks.items():
+            line[call] = {"impl": impl, "tiling": tiling, "vmem_bytes": (
+                tiling and gmm_vmem_bytes(*tiling, 2))}
+        reps = 200 if rows <= 256 else 50
+        for impl, grouped in (
+                ("resolver", lambda a, w, s: grouped_matmul(
+                    a, w, s, jnp.bfloat16)), ("ragged_dot", ragged)):
+            line["ms_" + impl] = timed(ffn(grouped),
+                                       (xs, wg, wu, wd, jnp.asarray(sizes)),
+                                       reps)
+        print(json.dumps(line), flush=True)
+
+
 def main():
     if jax.devices()[0].platform != "tpu":
         sys.exit("moe_gmm_bench: no TPU; a CPU time is not a reading")
@@ -306,6 +350,8 @@ def main():
         candidates()
     elif sys.argv[1:2] == ["tilings"]:
         tilings(tuple(int(r) for r in sys.argv[2:]))
+    elif sys.argv[1:] == ["chosen"]:
+        chosen()
     else:
         whole_stack()
 
