@@ -149,6 +149,10 @@ class FutureTable:
         with self._lock:
             return object_id in self._done
 
+    def all_done(self, object_ids: List[ObjectID]) -> bool:
+        with self._lock:
+            return self._done.issuperset(object_ids)
+
     def add_done_callback(self, object_id: ObjectID,
                           cb: Callable[[ObjectID], None]) -> None:
         with self._lock:

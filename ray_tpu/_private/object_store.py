@@ -25,7 +25,7 @@ import threading
 import weakref
 from collections import OrderedDict
 from dataclasses import dataclass
-from typing import Any, Dict, Optional
+from typing import Any, Dict, List, Optional
 
 from ray_tpu._private.ids import NodeID, ObjectID
 from ray_tpu.exceptions import OutOfMemoryError
@@ -244,6 +244,20 @@ class LocalObjectStore:
                 weakref.finalize(arr, self._release_native_view, key)
                 return arr
             return entry.value
+
+    def get_many(self, object_ids: List[ObjectID]) -> Optional[List[Any]]:
+        """``get`` of several entries under ONE acquisition of the lock,
+        where every one is a value held in memory; None where one is
+        missing, spilled or in the native tier (``get`` reads those)."""
+        with self._lock:
+            entries = [self._entries.get(oid) for oid in object_ids]
+            if any(entry is None or entry.spilled_path is not None
+                   or entry.native_meta is not None for entry in entries):
+                return None
+            for oid in object_ids:
+                self._entries.move_to_end(oid)
+            self.stats["gets"] += len(entries)
+            return [entry.value for entry in entries]
 
     def _release_native_view(self, key: bytes) -> None:
         """Finalizer for zero-copy native-tier arrays."""

@@ -164,7 +164,10 @@ class TaskSpec:
     pg_capture: bool = False  # propagate the PG to child tasks
     # lineage/retry accounting
     attempt_number: int = 0
-    # generator backpressure
+    # generator backpressure: the producer of a streaming task waits
+    # while this many OBJECTS it reported are beyond the newest one a
+    # consumer has been handed an item of (a ``ChunkRun`` of several
+    # items is one object; ``worker.GeneratorState``); -1: never
     backpressure_num_objects: int = -1
     enable_task_events: bool = True
     # TPU-first placement: force execution in the mesh-owning host

@@ -16,11 +16,13 @@ the coordination service in later rounds).
 
 from __future__ import annotations
 
+import bisect
 import inspect
 import os
 import threading
 import time
-from typing import Any, Callable, Dict, List, Optional, Sequence, Set, Tuple
+from typing import (Any, Callable, Dict, List, NamedTuple, Optional, Sequence,
+                    Set, Tuple)
 
 from ray_tpu import exceptions as exc
 from ray_tpu._private import events as trace_events
@@ -86,6 +88,38 @@ class _InFlightTask:
 STREAM_SAMPLE_MASK = 15
 
 
+def sampled_items(first: int, count: int) -> int:
+    """How many of the items ``first`` ... ``first + count - 1`` are
+    sampled ones."""
+    step = STREAM_SAMPLE_MASK + 1
+    return (first + count + STREAM_SAMPLE_MASK) // step - (
+        first + STREAM_SAMPLE_MASK) // step
+
+
+def first_sampled(index: int) -> int:
+    """The first sampled item at or after ``index``."""
+    return index + (-index & STREAM_SAMPLE_MASK)
+
+
+class ChunkRun(list):
+    """Several consecutive items of a stream, carried as ONE object
+    (``serve.ChunkRun``): a streaming generator that has fallen behind
+    its source yields the items that wait together, the runtime stores
+    and reports the run once and counts its items, and the consumer
+    still reads one item a ``next`` (docs/serving.md, "The stream
+    path"). An empty run carries nothing and is not reported."""
+
+    __slots__ = ()
+
+
+class RunItem(NamedTuple):
+    """What ``GeneratorState.next_ref`` hands out for an item that
+    travelled in a run: the run's ref and the item's place in it."""
+
+    ref: ObjectRef
+    offset: int
+
+
 class _ThreadCpu:
     """CPU time a stream's producing or consuming thread has spent on
     it, as that thread published it: it reads its OWN
@@ -118,14 +152,17 @@ class SampledItem:
     and a flag check while no profiler runs; None where the process has
     not loaded jax) and the cells ``timed_ns`` / ``timed_items`` of
     ``account``, which this thread alone writes (the engine's ``_Phase``
-    idiom). Never around a wait for an item: a device gap belongs to the
-    span that covers most of it (``benchmark/lib/trace.py``)."""
+    idiom). A run of items is handled, and so timed, ONCE: ``items`` is
+    how many sampled ones it holds. Never around a wait for an item: a
+    device gap belongs to the span that covers most of it
+    (``benchmark/lib/trace.py``)."""
 
-    __slots__ = ("account", "span", "t0")
+    __slots__ = ("account", "span", "items", "t0")
 
-    def __init__(self, account, span):
+    def __init__(self, account, span, items: int = 1):
         self.account = account
         self.span = span
+        self.items = items
 
     def __enter__(self):
         if self.span is not None:
@@ -136,7 +173,7 @@ class SampledItem:
     def __exit__(self, *exc):
         account = self.account
         account.timed_ns += time.perf_counter_ns() - self.t0
-        account.timed_items += 1
+        account.timed_items += self.items
         if self.span is not None:
             self.span.__exit__(*exc)
         return False
@@ -148,19 +185,28 @@ class GeneratorState:
     Reference: ``ReportGeneratorItemReturns`` proactive item reporting +
     ``GeneratorBackpressureWaiter`` (core_worker/generator_waiter.h).
 
+    ``items`` holds one ref an OBJECT the producer reported: an item, or
+    a ``ChunkRun`` of them, ``starts`` the index of each object's first
+    item. A consumer asks by ITEM (``next_ref``), and while no run was
+    reported an item's index is its object's. Back-pressure
+    (``backpressure_num_objects``) counts OBJECTS, a run as one: the
+    producer waits while that many were reported beyond the newest one
+    a consumer has been handed an item of (``consumed``).
+
     It is also the stream's account, which ``Runtime.generator_stats``
-    sums over all streams: ``produced`` (items reported) and
-    ``handed_out`` (items ``next_ref`` returned; ``consumed`` is the
-    high-water mark of the index, for back-pressure), each end's CPU
-    time, and what the consumer timed of its sampled items. Every cell
-    has ONE writing thread or is written under ``cond``, and is an
-    integer (times in ns): sums of them are exact in any order, so a
-    reading never goes down when a stream moves into the totals.
+    sums over all streams, in ITEMS but for the objects reported:
+    ``produced`` (items reported) and ``handed_out`` (items ``next_ref``
+    and ``take_waiting`` handed to a consumer), each end's CPU time, and
+    what the consumer timed of its sampled items. Every cell has ONE
+    writing thread or is written under ``cond``, and is an integer
+    (times in ns): sums of them are exact in any order, so a reading
+    never goes down when a stream moves into the totals.
     """
 
     def __init__(self, backpressure_num_objects: int = -1):
         self.cond = threading.Condition()
         self.items: List[ObjectRef] = []
+        self.starts: List[int] = []
         self.produced = 0
         self.consumed = 0
         self.handed_out = 0
@@ -179,20 +225,23 @@ class GeneratorState:
     def account(self) -> Dict[str, Any]:
         """The cells ``Runtime.generator_stats`` sums."""
         return {"stream_items_reported": self.produced,
+                "stream_objects_reported": len(self.items),
                 "stream_items_consumed": self.handed_out,
                 "stream_producer_cpu_ns": self.producer_cpu.ns,
                 "stream_consumer_cpu_ns": self.consumer_cpu.ns,
                 "stream_consume_ns": self.timed_ns,
                 "stream_items_timed_consume": self.timed_items}
 
-    def report_item(self, ref: ObjectRef) -> None:
+    def report_item(self, ref: ObjectRef, count: int = 1) -> None:
+        """One object: an item, or a run of ``count`` of them."""
         with self.cond:
             self.items.append(ref)
-            self.produced += 1
+            self.starts.append(self.produced)
+            self.produced += count
             self.cond.notify_all()
             if self.backpressure > 0:
-                while (not self.finished
-                       and self.produced - self.consumed >= self.backpressure):
+                while (not self.finished and len(self.items) - self.consumed
+                       >= self.backpressure):
                     self.cond.wait(1.0)
 
     def finish(self, error: Optional[BaseException] = None) -> None:
@@ -201,13 +250,29 @@ class GeneratorState:
             self.error = error
             self.cond.notify_all()
 
+    def _object_of(self, index: int) -> int:
+        """Which of ``items`` holds the reported item ``index``; under
+        ``cond``. While no run was reported an item is an object."""
+        if self.produced == len(self.items):
+            return index
+        return bisect.bisect_right(self.starts, index) - 1
+
     def next_ref(self, index: int, timeout: Optional[float] = None):
+        """Wait for item ``index`` and hand it out: its ``ObjectRef``,
+        or, where it travelled in a run, a ``RunItem`` (a reader of
+        values takes what waits beyond the item with it,
+        ``take_waiting``; one that wants a ref an item splits the run,
+        ``Runtime.run_item_ref``)."""
         deadline = None if timeout is None else time.monotonic() + timeout
         with self.cond:
             while True:
-                if index < len(self.items):
-                    ref = self.items[index]
-                    self.consumed = max(self.consumed, index + 1)
+                if index < self.produced:
+                    at = self._object_of(index)
+                    out, starts = self.items[at], self.starts
+                    if (starts[at + 1] if at + 1 < len(starts)
+                            else self.produced) - starts[at] > 1:
+                        out = RunItem(out, index - starts[at])
+                    self.consumed = max(self.consumed, at + 1)
                     self.handed_out += 1
                     self.cond.notify_all()
                     break
@@ -227,7 +292,22 @@ class GeneratorState:
             # outside the stream's lock; the consumer's first reading is
             # item 0's (the wait for it cost no CPU)
             self.consumer_cpu.publish()
-        return ref
+        return out
+
+    def take_waiting(self, index: int) -> Tuple[List[ObjectRef], int]:
+        """The reader that was handed item ``index`` takes with it
+        everything reported beyond it, the rest of its run and the
+        objects after: their refs, the first the object that holds
+        ``index``, and how many further items they carry."""
+        with self.cond:
+            count = self.produced - index - 1
+            refs = self.items[self._object_of(index):]
+            self.handed_out += count
+            self.consumed = len(self.items)
+            self.cond.notify_all()
+        if sampled_items(index + 1, count):
+            self.consumer_cpu.publish()
+        return refs, count
 
 
 def _ns_as_seconds(sums: Dict[str, int]) -> Dict[str, Any]:
@@ -1152,6 +1232,10 @@ class Runtime:
 
     def get(self, refs: Sequence[ObjectRef],
             timeout: Optional[float] = None) -> List[Any]:
+        if len(refs) > 1:
+            ready = self._get_ready(refs)
+            if ready is not None:
+                return ready
         deadline = None if timeout is None else time.monotonic() + timeout
         out: List[Any] = []
         for ref in refs:
@@ -1169,6 +1253,26 @@ class Runtime:
                 raise value
             out.append(value)
         return out
+
+    def _get_ready(self, refs: Sequence[ObjectRef]) -> Optional[List[Any]]:
+        """``get`` of refs whose objects are all complete, none lost,
+        none an error, all in the owner's memory store: each table's
+        lock is taken ONCE for all of them, where the loop in ``get``
+        takes five a ref (among ~65 stream threads every one of them is
+        a chance to stand in line for the interpreter lock again: a
+        reader that took a backlog of a stream's objects paid a turn of
+        it an OBJECT, PERF.md section 6, PR 42). None where any of that
+        does not hold: ``get`` then takes them one by one."""
+        if self._lost:
+            return None
+        ids = [ref.id for ref in refs]
+        if not self.futures.all_done(ids):
+            return None
+        values = self.memory_store.get_many(ids)
+        if values is None or any(isinstance(value, exc.RayTpuError)
+                                 for value in values):
+            return None
+        return values
 
     def _get_one(self, ref: ObjectRef, deadline: Optional[float],
                  _depth: int = 0) -> Any:
@@ -1816,20 +1920,34 @@ class Runtime:
     def _drain_generator(self, spec: TaskSpec, node: Node, gen) -> None:
         state = self._generators.setdefault(
             spec.task_id, GeneratorState(spec.backpressure_num_objects))
-        # On a retry, skip items already reported by the previous attempt
-        # (streams are assumed deterministic, as in lineage reconstruction).
-        skip = len(state.items)
+        # On a retry, skip the ITEMS the previous attempt reported
+        # (streams are assumed deterministic, as in lineage
+        # reconstruction; where its runs begin and end is not).
+        skip = state.produced
         from ray_tpu._private import failpoints as _fp
         cpu = state.producer_cpu
         cpu.publish()                   # this thread takes the stream up
         try:
             for item in gen:
                 if _fp.ENABLED:
-                    # per-item seam: error arm kills the stream mid-way
+                    # per-object seam: error arm kills the stream mid-way
                     # (consumer sees a typed error); delay arm throttles
                     _fp.fire("worker.generator_stream",
                              task=spec.task_id.hex())
-                if skip > 0:
+                count = 1
+                if isinstance(item, ChunkRun):
+                    # what the transport costs, it costs an OBJECT: a
+                    # run is stored and reported once, counted in items
+                    if skip:
+                        replayed = min(skip, len(item))
+                        skip -= replayed
+                        item = ChunkRun(item[replayed:])
+                    count = len(item)
+                    if count == 1:
+                        item = item[0]
+                    elif not count:
+                        continue
+                elif skip:
                     skip -= 1
                     continue
                 oid = ObjectID.from_random()
@@ -1837,8 +1955,8 @@ class Runtime:
                 self.futures.complete(oid)
                 ref = ObjectRef(oid, owner_hex=self.worker_id.hex(),
                                 task_name=spec.name)
-                sampled = not state.produced & STREAM_SAMPLE_MASK
-                state.report_item(ref)
+                sampled = sampled_items(state.produced, count)
+                state.report_item(ref, count)
                 if sampled:
                     cpu.publish()
         except BaseException as e:  # noqa: BLE001
@@ -1871,6 +1989,13 @@ class Runtime:
         if state is None:
             state = self._generators.setdefault(task_id, GeneratorState())
         return state
+
+    def run_item_ref(self, item: RunItem) -> ObjectRef:
+        """A ref of its own for ONE item of a run, for a reader that
+        wants refs (``ObjectRefGenerator.next``, a worker process's
+        ``gen_next``): the run is read and the item stored again. The
+        slow path; Serve reads values (``next_value``)."""
+        return self.put(self.get([item.ref])[0][item.offset])
 
     def generator_stats(self) -> Dict[str, Any]:
         """What this process's runtime has seen of its streaming

@@ -1267,6 +1267,8 @@ def dispatch_core_op(rt, holder, call: str, kw: Dict[str, Any],
         state = rt.generator_state(kw["task_id"])
         try:
             ref = state.next_ref(kw["index"], timeout=kw.get("timeout"))
+            if isinstance(ref, tuple):      # a ``RunItem``
+                ref = rt.run_item_ref(ref)
             holder._hold(task_rid, ref)
             return ref
         except StopIteration:

@@ -136,26 +136,49 @@ class Request:
         only after a preemption re-admission)."""
         return self.prompt + self.output
 
-    def iter_tokens(self):
-        """Stream tokens as they are generated; raises if the engine
-        died under the request. The request is in the engine's
-        ``stream_takes`` sum from here until the stream has been READ to
-        its end (or the reader gives up), which with a backlog is long
-        after its slot was released."""
+    def iter_runs(self):
+        """Stream tokens as they are generated, a RUN at a time:
+        ``(tokens, ended)``. The reader waits for one item and takes
+        with it, under the one acquisition of the stream's lock,
+        everything else that waits: a reader that keeps up gets runs of
+        one, a reader that has fallen behind gets what it is behind by.
+        ``ended`` says the run took the stream's end marker (``tokens``
+        may then be empty); an engine that died under the request ends
+        the stream by raising, after the tokens that came before. The
+        request is in the engine's ``stream_takes`` sum from here until
+        the stream has been READ to its end (or the reader gives up),
+        which with a backlog is long after its slot was released."""
         readers = self._readers
         if readers is not None:
             readers.begin(self)
+        stream = self.stream
         try:
             while True:
-                tok, self.step = self.stream.get()
-                self.takes += 1
-                if tok is None:
-                    self.raise_if_failed()
-                    return
-                yield tok
+                with stream.not_empty:
+                    while not stream.queue:
+                        stream.not_empty.wait()
+                    run = list(stream.queue)
+                    stream.queue.clear()
+                self.takes += len(run)
+                self.step = run[-1][1]
+                tokens = [tok for tok, _ in run]
+                if tokens[-1] is not None:
+                    yield tokens, False
+                    continue
+                tokens.pop()
+                if tokens and self.error is not None:
+                    yield tokens, False
+                self.raise_if_failed()
+                yield tokens, True
+                return
         finally:
             if readers is not None:
                 readers.end(self)
+
+    def iter_tokens(self):
+        """``iter_runs``, a token at a time."""
+        for tokens, _ in self.iter_runs():
+            yield from tokens
 
     def fail(self, err: BaseException, step: int = 0) -> bool:
         """Fail the request; True if that put the stream's end marker."""

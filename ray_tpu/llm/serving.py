@@ -25,15 +25,19 @@ from ray_tpu.llm.tokenizer import ByteTokenizer, load_tokenizer
 REQUEST_TIMEOUT_S = 300.0    # a unary request's whole generation
 
 
-def _produced(req, index: int) -> worker.SampledItem:
-    """A sampled chunk on its replica thread: the span
+def _produced(req, index: int, sampled: int) -> worker.SampledItem:
+    """A chunk, or a run of them, that holds ``sampled`` sampled items
+    (the first at ``index``) on its replica thread: the span
     ``serve.stream.produce`` and the request's cells. ``step`` is the
-    decode step that delivered the token: the number on its
-    ``engine.deliver`` span. The span lies between two waits for the
-    engine and around none."""
+    decode step that delivered the newest of its tokens: the number on
+    that step's ``engine.deliver`` span. The span lies between two waits
+    for the engine and around none."""
     span = jax.profiler.TraceAnnotation("serve.stream.produce", request=req.id,
                                         index=index, step=req.step)
-    return worker.SampledItem(req, span)
+    return worker.SampledItem(req, span, sampled)
+
+
+_UNSAMPLED = contextlib.nullcontext()   # no clock, no span
 
 
 @dataclasses.dataclass
@@ -101,31 +105,40 @@ class LLMServer:
         the TTFT the serving bench measures is only real if the first
         token can leave the replica before generation completes).
 
+        A replica thread that has fallen behind its request takes every
+        token that waits in one go (``Request.iter_runs``) and yields
+        their chunks, the same dicts, together as ONE ``serve.ChunkRun``:
+        what the path to the client costs, it costs an object. A token
+        that waited alone is the bare dict, and the handle's caller
+        reads one chunk a ``next`` either way.
+
         Chunks 0, 16, 32 ... by their index are the stream's sampled
-        items (docs/serving.md, "The stream path"): each is timed from
-        its token taken off the request's stream to the consumer of this
-        generator asking for the next chunk, which is the runtime having
-        stored and reported this one."""
+        items (docs/serving.md, "The stream path"): each, or the run
+        that holds it, is timed from its tokens taken off the request's
+        stream to the consumer of this generator asking for the next,
+        which is the runtime having stored and reported this one."""
         ids, sampling = self._parse(request)
         req = self.engine.submit(ids, sampling)
         head = {"id": f"cmpl-{req.id}", "model": self.config.model_id}
-        decode, mask = self.tokenizer.decode, worker.STREAM_SAMPLE_MASK
+        decode = self.tokenizer.decode
         index = 0
-        for tok in req.iter_tokens():
-            if index & mask:        # an unsampled chunk: no clock, no span
-                yield {**head, "delta": decode([tok]),
-                       "token_id": int(tok), "index": index}
-            else:
-                with _produced(req, index):
-                    yield {**head, "delta": decode([tok]),
-                           "token_id": int(tok), "index": index}
-            index += 1
-        with (_produced(req, index) if not index & mask
-              else contextlib.nullcontext()):
-            yield {**head, "finish_reason": req.finish_reason, "done": True,
-                   "usage": {"prompt_tokens": len(ids),
-                             "completion_tokens": len(req.output)},
-                   "ttft_s": req.ttft_s}
+        for tokens, ended in req.iter_runs():
+            count = len(tokens) + ended
+            sampled = worker.sampled_items(index, count)
+            with (_produced(req, worker.first_sampled(index), sampled)
+                  if sampled else _UNSAMPLED):
+                chunks = [{**head, "delta": decode([tok]),
+                           "token_id": int(tok), "index": i}
+                          for i, tok in enumerate(tokens, index)]
+                if ended:
+                    chunks.append({
+                        **head, "finish_reason": req.finish_reason,
+                        "done": True,
+                        "usage": {"prompt_tokens": len(ids),
+                                  "completion_tokens": len(req.output)},
+                        "ttft_s": req.ttft_s})
+                yield chunks[0] if count == 1 else serve.ChunkRun(chunks)
+            index += count
 
     def __call__(self, request: Dict[str, Any]):
         """OpenAI-completions-shaped request/response; ``stream: true``

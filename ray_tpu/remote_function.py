@@ -2,6 +2,7 @@
 
 from __future__ import annotations
 
+import collections
 import functools
 import inspect
 import sys
@@ -18,12 +19,21 @@ from ray_tpu._private.task_spec import (DEFAULT_TASK_OPTIONS, TaskKind,
                                         validate_options)
 
 
+_NOTHING = object()         # ``ObjectRefGenerator._pop_taken``: none in hand
+
+
 class ObjectRefGenerator:
     """Iterator over the streamed returns of a generator task.
 
     Each `next()` yields an ObjectRef as soon as the producer reports the
     item — before the task finishes (reference: ``_raylet.pyx``
     ObjectRefGenerator, proto ``ReportGeneratorItemReturns``).
+
+    A producer may report several items as one object (a
+    ``worker.ChunkRun``), and a reader of values that is behind takes
+    every object that waits in one go. A reader still gets one item a
+    call, in order: ``next_value`` hands what it took out of ``_taken``;
+    ``next`` has the runtime split a run (the slow path).
     """
 
     def __init__(self, task_id: TaskID):
@@ -38,10 +48,13 @@ class ObjectRefGenerator:
         # when consumers fail interleaved
         self._lock = threading.Lock()
         self._holes: set = set()
+        # the values ``next_value`` claimed with an item (what waited
+        # beyond it) and has not handed out yet
+        self._taken: collections.deque = collections.deque()
 
     def __getstate__(self):
         return {"_task_id": self._task_id, "_index": self._index,
-                "_holes": set(self._holes)}
+                "_holes": set(self._holes), "_taken": list(self._taken)}
 
     def __setstate__(self, d):
         import threading
@@ -49,6 +62,7 @@ class ObjectRefGenerator:
         self._task_id = d["_task_id"]
         self._index = d["_index"]
         self._holes = set(d.get("_holes", ()))
+        self._taken = collections.deque(d.get("_taken", ()))
         self._lock = threading.Lock()
 
     def __iter__(self) -> "ObjectRefGenerator":
@@ -60,27 +74,70 @@ class ObjectRefGenerator:
     def next(self, timeout: Optional[float] = None) -> ObjectRef:
         """``next(gen)`` with a deadline: raises ``GetTimeoutError``
         after ``timeout`` seconds; the claimed index returns to the
-        hole set so a retry (or another consumer) re-claims it."""
-        return self._next(worker.global_worker(), timeout)[2]
+        hole set so a retry (or another consumer) re-claims it. One ref
+        an ITEM, also where the item travelled in a run."""
+        rt = worker.global_worker()
+        value = self._pop_taken()   # claimed by ``next_value``: in hand
+        if value is not _NOTHING:
+            return rt.put(value)
+        item = self._next(rt, timeout)[2]
+        return item if isinstance(item, ObjectRef) else rt.run_item_ref(item)
 
     def next_value(self, timeout: Optional[float] = None) -> Any:
-        """``get(gen.next(timeout))``, each under the deadline. A
-        sampled item's ``get`` (the wait for the item is over by then) is
-        timed into the stream's account (``Runtime.generator_stats``)
-        where that lives in this process, not with a worker's host, and
-        is the span ``serve.stream.consume`` where the process has
-        loaded jax (never imported for a span's sake)."""
+        """``get(gen.next(timeout))``, each under the deadline. A reader
+        that is behind waits for ONE item and takes with it everything
+        that was reported beyond it, the rest of the item's run and the
+        objects after (one ``next_ref``, one ``take_waiting``, one
+        ``get``): their values wait in ``_taken`` and the next calls
+        pop them. A sampled item's ``get`` (the wait for the item is
+        over by then), or that of a take that holds sampled items, is
+        timed into the stream's account (``Runtime.generator_stats``) where that
+        lives in this process, not with a worker's host, and is the span
+        ``serve.stream.consume`` where the process has loaded jax (never
+        imported for a span's sake)."""
+        value = self._pop_taken()
+        if value is not _NOTHING:
+            return value
         rt = worker.global_worker()
-        state, index, ref = self._next(rt, timeout)
-        if (index & worker.STREAM_SAMPLE_MASK
-                or not isinstance(state, worker.GeneratorState)):
-            return rt.get([ref], timeout=timeout)[0]
-        jax = sys.modules.get("jax")
-        span = jax and jax.profiler.TraceAnnotation("serve.stream.consume",
-                                                    task=self._task_id.hex(),
-                                                    index=index)
-        with worker.SampledItem(state, span):
-            return rt.get([ref], timeout=timeout)[0]
+        state, index, item = self._next(rt, timeout)
+        if not isinstance(state, worker.GeneratorState):
+            return rt.get([item], timeout=timeout)[0]
+        in_run = not isinstance(item, ObjectRef)
+        refs, more = [item.ref if in_run else item], 0
+        if state.produced > index + 1:
+            with self._lock:
+                # unless another reader of this generator has claimed
+                # past this item
+                if self._index == index + 1:
+                    refs, more = state.take_waiting(index)
+                    self._index += more
+        sampled = worker.sampled_items(index, 1 + more)
+        if not sampled:
+            values = rt.get(refs, timeout=timeout)
+        else:
+            jax = sys.modules.get("jax")
+            span = jax and jax.profiler.TraceAnnotation(
+                "serve.stream.consume", task=self._task_id.hex(),
+                index=worker.first_sampled(index))
+            with worker.SampledItem(state, span, sampled):
+                values = rt.get(refs, timeout=timeout)
+        taken = (values[0][item.offset:item.offset + 1 + more] if in_run
+                 else values[:1])
+        for value in values[1:]:
+            if isinstance(value, worker.ChunkRun):
+                taken.extend(value)
+            else:
+                taken.append(value)
+        self._taken.extend(taken[1:])
+        return taken[0]
+
+    def _pop_taken(self) -> Any:
+        if self._taken:
+            try:
+                return self._taken.popleft()
+            except IndexError:          # another reader took the last
+                pass
+        return _NOTHING
 
     def _next(self, rt, timeout: Optional[float]):
         """Claim an index and wait for its item: ``(state, index, ref)``."""

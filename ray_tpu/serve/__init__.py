@@ -6,6 +6,7 @@ planes — controller actor (deploy/reconcile/autoscale), proxies
 dynamic batching and model composition via deployment handles.
 """
 
+from ray_tpu._private.worker import ChunkRun
 from ray_tpu.serve.api import (delete, get_app_handle,
                                get_deployment_handle, run, shutdown,
                                start_http_proxy, status)
@@ -21,5 +22,5 @@ __all__ = [
     "run", "shutdown", "status", "delete", "get_deployment_handle",
     "get_app_handle", "start_http_proxy",
     "batch", "DeploymentHandle", "DeploymentResponse",
-    "multiplexed", "get_multiplexed_model_id", "run_config",
+    "multiplexed", "get_multiplexed_model_id", "run_config", "ChunkRun",
 ]

@@ -385,8 +385,11 @@ def test_llm_server_serves_the_expert_model_its_config_describes():
         assert "e_gate" in server.engine.params["layers"]
         out = server({"prompt": [3, 4, 5, 6], "max_tokens": 5})
         assert len(out["token_ids"]) == 5
-        chunks = list(server.stream({"prompt": [3, 4, 5, 6],
-                                     "max_tokens": 5}))
+        # the generator itself, not a handle: chunks that waited
+        # together come as one ``serve.ChunkRun`` (a list of them)
+        chunks = [c for obj in server.stream({"prompt": [3, 4, 5, 6],
+                                              "max_tokens": 5})
+                  for c in (obj if isinstance(obj, list) else [obj])]
         assert [c["token_id"] for c in chunks[:-1]] == out["token_ids"]
         stats = server.stats()
         json.dumps(stats)                                    # JSON-plain

@@ -304,6 +304,38 @@ def test_streaming_non_generator_degrades_to_single_chunk(serve_cluster):
     assert chunks == [{"just": "one"}]
 
 
+def test_streaming_a_run_of_chunks_reaches_the_caller_chunk_by_chunk(
+        serve_cluster):
+    """A deployment may hand several chunks at once as ONE
+    ``serve.ChunkRun`` (one object on the way): a handle's caller and an
+    SSE client still read one chunk a step, in order."""
+
+    @serve.deployment
+    class Runs:
+        def __call__(self, payload):
+            yield {"i": 0}
+            yield serve.ChunkRun([{"i": 1}, {"i": 2}, {"i": 3}])
+            yield serve.ChunkRun([{"i": 4}])
+            yield {"i": 5}
+
+    handle = serve.run(Runs.bind())
+    gen = handle.options(stream=True).remote({})
+    assert gen.next(timeout=30) == {"i": 0}
+    assert gen.next(timeout=30) == {"i": 1}
+    assert [c["i"] for c in gen] == [2, 3, 4, 5]
+    port = serve.start_http_proxy(port=0)
+    req = urllib.request.Request(
+        f"http://127.0.0.1:{port}/", data=json.dumps({}).encode(),
+        headers={"Content-Type": "application/json",
+                 "Accept": "text/event-stream"})
+    with urllib.request.urlopen(req, timeout=30) as resp:
+        events = [raw.decode().strip()[len("data: "):] for raw in resp
+                  if raw.decode().startswith("data: ")]
+    assert events[-1] == "[DONE]"
+    assert [json.loads(e) for e in events[:-1]] == [{"i": i}
+                                                    for i in range(6)]
+
+
 def test_http_proxy_sse_streaming(serve_cluster):
     """SSE through the HTTP proxy: first data: event readable before the
     generator completes (a real TTFT)."""

@@ -2,11 +2,15 @@
 stream path"): counts at its four boundaries (the engine's put, the
 replica thread's take, the runtime's report, the consumer's ref), each
 end's CPU seconds as its own thread published them, and sampled spans on
-both ends of the same items. CPU, debug widths, no timing thresholds:
-what is checked is names, attributes, exact counts, which thread, and
-that no reading ever goes down."""
+both ends of the same items. A stream that has fallen behind is carried
+in runs (several items, one object): the account stays in items, and
+``stream_objects_reported`` beside it says how many objects carried
+them. CPU, debug widths, no timing thresholds: what is checked is names,
+attributes, exact counts, which thread, and that no reading ever goes
+down."""
 
 import json
+import os
 import sys
 import threading
 import time
@@ -22,8 +26,9 @@ from ray_tpu.llm import LLMConfig, build_llm_app
 COUNTS = ("stream_puts", "stream_takes", "stream_items_reported",
           "stream_items_consumed")
 STREAM_KEYS = set(COUNTS) | {
-    "streams_live", "stream_producer_cpu_s", "stream_consumer_cpu_s",
-    "engine_thread_cpu_s", "stream_produce_s", "stream_consume_s",
+    "stream_objects_reported", "streams_live", "stream_producer_cpu_s",
+    "stream_consumer_cpu_s", "engine_thread_cpu_s", "stream_produce_s",
+    "stream_consume_s",
     "stream_items_timed_produce", "stream_items_timed_consume"}
 WAIT_S = 120
 
@@ -72,6 +77,35 @@ def slow_down(server, monkeypatch, seconds):
         time.sleep(seconds)
         return step()
     monkeypatch.setattr(server.engine, "step", slow_step)
+
+
+def hold_reader(server, monkeypatch, tokens=None):
+    """The replica thread is handed its request only once the engine
+    has put ``tokens`` of it on the stream (None: all of it and the end
+    marker): a reader that starts behind."""
+    submit = server.engine.submit
+
+    def submit_and_wait(ids, sampling):
+        req = submit(ids, sampling)
+        until(lambda: req.done.is_set() or (
+            tokens is not None and req.stream.qsize() >= tokens),
+            "the engine to get ahead")
+        return req
+    monkeypatch.setattr(server.engine, "submit", submit_and_wait)
+
+
+def in_step_with_the_readers(server, monkeypatch):
+    """The engine makes a step only once every item it has put was
+    taken: readers that keep up, whatever the machine is busy with."""
+    step = server.engine.step
+
+    def step_when_read():
+        def read():
+            stats = server.engine.stats
+            return stats["stream_puts"] == stats["stream_takes"]
+        until(read, "the readers")
+        return step()
+    monkeypatch.setattr(server.engine, "step", step_when_read)
 
 
 def until(cond, what):
@@ -161,6 +195,88 @@ def test_a_consumer_that_does_not_read_is_a_backlog_before_the_clients(llm):
             == before["stream_items_reported"]
             - before["stream_items_consumed"])
     assert after["streams_live"] == 0
+
+
+def test_a_reader_that_starts_behind_is_carried_in_fewer_objects_than_items(
+        llm, monkeypatch):
+    """A replica thread that finds n tokens and the end marker waiting
+    reports them as ONE object; the caller still reads n token chunks,
+    ``index`` 0 ... n-1, then ``done``, and the account is in items."""
+    handle, server = llm
+    read_all(open_stream(handle, 3))                    # compile
+    hold_reader(server, monkeypatch)
+    before = server.stats()
+    chunks = read_all(open_stream(handle, 21))
+    assert all(type(c) is dict and "token_id" in c for c in chunks[:-1])
+    assert chunks[-1]["usage"]["completion_tokens"] == len(chunks) - 1 > 1
+    until(lambda: server.stats()["streams_live"] == 0, "the stream's end")
+    after = server.stats()
+    for key in COUNTS:
+        assert after[key] - before[key] == len(chunks), key
+    assert (after["stream_objects_reported"]
+            - before["stream_objects_reported"]) == 1
+
+
+def test_a_stream_that_keeps_up_is_carried_an_item_an_object(
+        llm, monkeypatch):
+    """A token that waited alone travels as the parent's bare dict:
+    ``LLMServer.stream`` yields nothing else, and through the handle the
+    objects reported are the items. The one run a reader that keeps up
+    meets is the stream's end: the engine puts its last token (or two:
+    it runs a step ahead) and the end marker in one go, so those are
+    taken together."""
+    handle, server = llm
+    read_all(open_stream(handle, 3))                    # compile
+    in_step_with_the_readers(server, monkeypatch)
+    request = {"prompt": [5, 6, 7, 8, 9], "max_tokens": 20, "stream": True}
+    *yielded, end = server.stream(request)
+    assert len(yielded) > 2 and all(type(c) is dict for c in yielded)
+    assert type(end) is serve.ChunkRun and 2 <= len(end) <= 3
+    assert end[-1]["done"] and [c["index"] for c in yielded + end[:-1]] == (
+        list(range(len(yielded) + len(end) - 1)))
+    before = server.stats()
+    chunks = read_all(open_stream(handle, 20))
+    assert [c.get("token_id") for c in chunks] == [
+        c.get("token_id") for c in yielded + list(end)]
+    until(lambda: server.stats()["streams_live"] == 0, "the stream's end")
+    after = server.stats()
+    for key in COUNTS:
+        assert after[key] - before[key] == len(chunks), key
+    assert len(chunks) - 2 <= (
+        after["stream_objects_reported"]
+        - before["stream_objects_reported"]) <= len(chunks) - 1
+
+
+@pytest.mark.parametrize("ahead", [None, 20], ids=["all", "20_tokens"])
+def test_a_run_is_timed_once_for_the_sampled_items_it_holds_on_both_ends(
+        llm, monkeypatch, ahead):
+    """Items 0, 16, 32 ... stay the sampled ones when they travel in
+    runs: what an end handles at once (the replica a run, the reader
+    everything it finds waiting) is ONE span, at the first sampled index
+    it holds, and the timed items are counted by index, so the two ends'
+    denominators agree."""
+    handle, server = llm
+    read_all(open_stream(handle, 3))                    # compile
+    hold_reader(server, monkeypatch, ahead)
+    before = server.stats()
+    Recorder.log, Recorder.open_now = [], 0
+    monkeypatch.setattr(jax.profiler, "TraceAnnotation", Recorder)
+    chunks = read_all(open_stream(handle, 40))
+    until(lambda: server.stats()["streams_live"] == 0, "the stream's end")
+    after = server.stats()
+    objects = (after["stream_objects_reported"]
+               - before["stream_objects_reported"])
+    assert objects < len(chunks) > 33
+    for key in ("stream_items_timed_produce", "stream_items_timed_consume"):
+        assert after[key] - before[key] == timed_items([chunks]) == 3, key
+    for name in ("serve.stream.produce", "serve.stream.consume"):
+        spans = [a["index"] for n, a, _, _ in Recorder.log if n == name]
+        assert spans[0] == 0 and spans == sorted(set(spans)) and set(
+            spans) <= {0, 16, 32}, (name, spans)
+        assert len(spans) <= objects
+        if ahead is None:                               # the one run
+            assert spans == [0] and objects == 1
+    assert Recorder.open_now == 0
 
 
 def test_spans_are_on_the_sampled_items_of_both_ends_and_on_no_wait(
@@ -490,3 +606,219 @@ def test_the_engine_counts_a_stream_nobody_reads_and_one_read_twice():
     assert eng.stats["stream_puts"] == 13
     assert list(long.iter_tokens()) == [] and eng.stats["stream_takes"] == 7
     assert unread.stream.qsize() == 6
+
+
+def queued(tokens):
+    """A request with ``tokens`` on its stream, as decode steps 0, 1, ...
+    delivered them."""
+    from ray_tpu.llm import SamplingParams
+    from ray_tpu.llm.engine import Request
+
+    req = Request([3, 4, 5], SamplingParams())
+    for step, tok in enumerate(tokens):
+        req.stream.put((tok, step))
+    return req
+
+
+@pytest.mark.parametrize("queue", ["tokens", "end", "end_alone", "fail",
+                                   "fail_alone"])
+def test_a_request_is_read_a_run_at_a_time(queue):
+    """``Request.iter_runs``: the reader waits for ONE item and takes
+    everything that waits with it, in order, up to and including the
+    end marker; what came before an end or a failure is delivered."""
+    from ray_tpu.llm.engine import EngineDeadError
+
+    tokens = [] if queue.endswith("_alone") else [10, 11, 12, 13, 14]
+    req = queued(tokens)
+    if queue.startswith("end"):
+        req.finish_reason = "length"
+        req.stream.put((None, 7))
+        req.done.set()
+    elif queue.startswith("fail"):
+        assert req.fail(RuntimeError("device lost"), 7)
+    runs = req.iter_runs()
+    if queue == "tokens":
+        assert next(runs) == (tokens, False)
+        assert (req.takes, req.step) == (5, 4)
+        req.stream.put((15, 5))         # one token waiting: a run of one
+        assert next(runs) == ([15], False)
+        assert (req.takes, req.step) == (6, 5)
+        return
+    if queue.startswith("end"):
+        assert next(runs) == (tokens, True)
+    else:
+        if tokens:
+            assert next(runs) == (tokens, False)
+        with pytest.raises(EngineDeadError, match="device lost"):
+            next(runs)
+    assert (req.takes, req.step) == (len(tokens) + 1, 7)
+    assert list(runs) == [] and req.stream.qsize() == 0
+    # a token at a time, the same stream reads the same
+    again = queued(tokens)
+    again.stream.put((None, 7))
+    assert list(again.iter_tokens()) == tokens and again.takes == 6 - (
+        5 - len(tokens))
+
+
+ATTEMPTS = []
+
+
+@ray_tpu.remote(num_returns="streaming", max_retries=1, _in_process=True)
+def runs_then_a_crash():
+    """Items 0 ... 7, their runs cut differently by each attempt; the
+    first attempt dies after four."""
+    from ray_tpu._private.worker_process import WorkerCrashed
+
+    ATTEMPTS.append(len(ATTEMPTS))
+    if len(ATTEMPTS) == 1:
+        yield serve.ChunkRun([0, 1, 2])
+        yield 3
+        raise WorkerCrashed("the worker died under the stream")
+    yield 0
+    yield serve.ChunkRun([1, 2, 3, 4])
+    yield serve.ChunkRun([5, 6])
+    yield serve.ChunkRun([])
+    yield 7
+
+
+def test_a_replay_skips_the_items_reported_not_the_objects(ray_start_regular):
+    """A retried stream has reported two objects and FOUR items: the
+    replay drops four items, out of whatever runs it yields them in."""
+    rt = worker.global_runtime()
+    del ATTEMPTS[:]
+    gen = runs_then_a_crash.remote()
+    assert [gen.next_value(timeout=WAIT_S) for _ in range(8)] == list(range(8))
+    with pytest.raises(StopIteration):
+        gen.next_value(timeout=WAIT_S)
+    assert ATTEMPTS == [0, 1]
+    stats = rt.generator_stats()
+    assert stats["stream_items_reported"] == 8
+    assert stats["stream_items_consumed"] == 8
+    # [0, 1, 2], 3 | 4 (what the replay left of its run), [5, 6], 7
+    assert stats["stream_objects_reported"] == 5
+    assert stats["streams_live"] == 0
+
+
+@ray_tpu.remote(num_returns="streaming", _in_process=True)
+def in_runs(runs):
+    for run in runs:
+        yield serve.ChunkRun(run) if isinstance(run, list) else run
+
+
+@pytest.mark.parametrize("values_first", [0, 2, 4])
+def test_a_reader_of_refs_gets_one_ref_an_item_of_a_run(ray_start_regular,
+                                                        values_first):
+    """``ObjectRefGenerator.next()`` on a stream that travelled in runs:
+    one ref an item, in order (the runtime splits the run), also after
+    ``next_value`` has claimed a run's items; the account is in items."""
+    rt = worker.global_runtime()
+    gen = in_runs.remote([0, [1, 2, 3], 4, [5, 6]])
+    values = [gen.next_value(timeout=WAIT_S) for _ in range(values_first)]
+    refs = list(gen)
+    assert all(isinstance(r, ray_tpu.ObjectRef) for r in refs)
+    assert len({r.id for r in refs}) == len(refs) == 7 - values_first
+    assert values + ray_tpu.get(refs) == list(range(7))
+    stats = rt.generator_stats()
+    assert stats["stream_items_reported"] == 7
+    assert stats["stream_items_consumed"] == 7
+    assert stats["stream_objects_reported"] == 4
+
+
+def test_two_readers_of_one_run_get_each_item_once(ray_start_regular):
+    """Readers that share a generator and race for a run's items: every
+    item reaches exactly one of them, by value or by ref."""
+    n_runs, width = 60, 5
+    gen = in_runs.remote([list(range(i * width, (i + 1) * width))
+                          for i in range(n_runs)])
+    got = [[] for _ in range(4)]
+
+    def read(k):
+        try:
+            while True:
+                got[k].append(gen.next_value(timeout=WAIT_S) if k % 2
+                              else ray_tpu.get(gen.next(timeout=WAIT_S)))
+        except StopIteration:
+            pass
+    threads = [threading.Thread(target=read, args=(k,)) for k in range(4)]
+    interval = sys.getswitchinterval()
+    sys.setswitchinterval(1e-5)
+    try:
+        for t in threads:
+            t.start()
+        for t in threads:
+            t.join(WAIT_S)
+    finally:
+        sys.setswitchinterval(interval)
+    assert not any(t.is_alive() for t in threads)
+    assert sorted(sum(got, [])) == list(range(n_runs * width))
+    stats = worker.global_runtime().generator_stats()
+    assert stats["stream_items_consumed"] == n_runs * width
+    assert stats["stream_objects_reported"] == n_runs
+
+
+def test_back_pressure_counts_objects_a_run_as_one(ray_start_regular):
+    """``_generator_backpressure_num_objects`` = 2: the producer waits
+    with two OBJECTS (here six items) beyond what the consumer has
+    touched; a reader takes what waits, and lets as many through."""
+    rt = worker.global_runtime()
+    gen = in_runs.options(_generator_backpressure_num_objects=2).remote(
+        [[3 * i, 3 * i + 1, 3 * i + 2] for i in range(5)])
+
+    def held_at(objects):
+        def reported():
+            stats = rt.generator_stats()
+            return (stats["stream_objects_reported"],
+                    stats["stream_items_reported"])
+        until(lambda: reported() == (objects, 3 * objects),
+              f"the producer to be held at {objects} objects")
+        time.sleep(0.05)
+        assert reported() == (objects, 3 * objects)
+    held_at(2)
+    assert gen.next_value(timeout=WAIT_S) == 0  # took both: two more come
+    held_at(4)
+    assert [gen.next_value(timeout=WAIT_S) for _ in range(5)] == list(
+        range(1, 6))                            # out of the reader's hand
+    held_at(4)
+    assert [gen.next_value(timeout=WAIT_S) for _ in range(9)] == list(
+        range(6, 15))
+    held_at(5)
+    assert rt.generator_stats()["stream_items_consumed"] == 15
+
+
+@ray_tpu.remote
+def late(gate):
+    while not os.path.exists(gate):
+        time.sleep(0.01)
+    return "late"
+
+
+@ray_tpu.remote(max_retries=0)
+def broken():
+    raise ValueError("no value")
+
+
+def test_a_get_of_many_ready_refs_is_one_read_and_else_one_by_one(
+        ray_start_regular, tmp_path):
+    """What a reader's take of a backlog rides on: ``get`` of several
+    refs that are all complete and in the owner's store reads them under
+    one acquisition of each lock; one that is not complete yet, or an
+    error, sends it back to the loop, which waits and raises as ever."""
+    rt = worker.global_runtime()
+    refs = [ray_tpu.put({"i": i}) for i in range(5)]
+    gets = rt.memory_store.stats["gets"]
+    assert rt._get_ready(refs) == [{"i": i} for i in range(5)]
+    assert rt.memory_store.stats["gets"] == gets + 5
+    assert ray_tpu.get(refs) == [{"i": i} for i in range(5)]
+    gate = str(tmp_path / "gate")
+    waiting = late.remote(gate)
+    assert rt._get_ready(refs + [waiting]) is None
+    with pytest.raises(ray_tpu.exceptions.GetTimeoutError):
+        ray_tpu.get(refs + [waiting], timeout=0.05)
+    open(gate, "w").close()
+    assert ray_tpu.get(refs + [waiting], timeout=WAIT_S)[-1] == "late"
+    assert rt._get_ready(refs + [waiting])[-1] == "late"
+    failed = broken.remote()
+    ray_tpu.wait([failed], timeout=WAIT_S)
+    assert rt._get_ready(refs + [failed]) is None
+    with pytest.raises(ValueError, match="no value"):
+        ray_tpu.get(refs + [failed], timeout=WAIT_S)
