@@ -439,6 +439,8 @@ class ContinuousBatchingEngine:
         # seconds (``_Phase``); ``t_step_s`` is all of ``step()`` from
         # before it takes the lock; ``cpu_host_s`` is this thread's CPU
         # time in ``step()`` outside the phases that wait for the device.
+        sparse = model.sparse_decode_plan()
+        self._index_topk = sparse["index_topk"]
         self._stats = {"requests": 0, "tokens_generated": 0,
                       "decode_steps": 0,
                       # decode steps whose batch held a row with
@@ -505,6 +507,27 @@ class ContinuousBatchingEngine:
                       # host), and the rows per [layer][expert]
                       "moe_assignments": 0, "moe_assignments_expected": 0,
                       "moe_expert_load": [],
+                      # a layer that holds A SHARE of its router's experts
+                      # (``MoEConfig.experts_held``): how many (0 for a
+                      # dense model; all of them otherwise), the rows of
+                      # ``moe_assignments`` that went to them, which are
+                      # the rows computed here, and the router's groups
+                      "moe_experts_held": (
+                          model.cfg.held[1] if load_shape else 0),
+                      "moe_assignments_held": 0,
+                      "moe_router_groups": getattr(
+                          model.cfg, "router_n_group", 0),
+                      # learned sparse attention (0 / "" for every other
+                      # model): the rows a query's attention keeps, the
+                      # rows the decode steps' attention read (each live
+                      # slot's ``min(length, index_topk)``, a layer;
+                      # counted on the host like the live blocks), a
+                      # row's index key in bytes, and what implements
+                      # the decode step's attention, index scores and
+                      # selection
+                      "decode_kv_rows_selected": 0,
+                      **sparse,
+                      "decode_attention_impl": self.decode_attention_impl,
                       # what implements the decode step's three grouped
                       # matmuls ("pallas_gmm" / "ragged_dot") and their
                       # (rows, k, n) tilings, as the model resolves them
@@ -519,7 +542,7 @@ class ContinuousBatchingEngine:
                       # ``model.kv_row_shapes``) and all its arrays' bytes
                       "kv_row_bytes": sum(
                           math.prod(row) for row in model.kv_row_shapes()
-                      ) * jnp.dtype(model.cfg.dtype).itemsize,
+                      ) * jnp.dtype(model.kv_dtype).itemsize,
                       "kv_pool_bytes": sum(
                           math.prod(a.shape) * a.dtype.itemsize
                           for name, a in self.kv.items() if name != "bases"),
@@ -537,9 +560,13 @@ class ContinuousBatchingEngine:
         if self._ffn_counts is not None:
             load, expected = self._ffn_counts       # one pair, one step
             load = np.asarray(load)
-            self._stats.update(moe_assignments=int(load.sum()),
-                               moe_assignments_expected=expected,
-                               moe_expert_load=load.tolist())
+            first, n_held = self.model.cfg.held
+            self._stats.update(
+                moe_assignments=int(load.sum()),
+                moe_assignments_expected=expected,
+                moe_assignments_held=int(
+                    load[:, first:first + n_held].sum()),
+                moe_expert_load=load.tolist())
         return self._stats
 
     def _check_eva_tiles(self) -> None:
@@ -1325,6 +1352,9 @@ class ContinuousBatchingEngine:
         else:
             self._stats["decode_kv_blocks_live"] += int(
                 ((pos + bs) // bs).sum())
+            if self._index_topk:
+                self._stats["decode_kv_rows_selected"] += int(
+                    np.minimum(pos + 1, self._index_topk).sum())
             if self.window is not None:
                 # a sliding layer's kernel starts at its window's first
                 # block

@@ -592,7 +592,10 @@ class LlamaModel:
         turned by RoPE. A model whose cache rows are something else
         (``MLAModel``: a latent row and one rotary key part) overrides
         this with ``kv_row_shapes``, ``_attend_rows`` and
-        ``_attend_pages``; ``pin`` is ``_layer``'s."""
+        ``_attend_pages``; ``pin`` is ``_layer``'s. Its ``q`` may be a
+        TREE of per-token arrays ``[B, T, ...]`` (an indexer's query
+        beside the attention's): the layer hands it to ``attend`` as it
+        is and the decode step takes row 0 of every leaf."""
         dt = self.cfg.dtype
         q = jnp.einsum("bsd,dhk->bshk", h, layer["wq"].astype(dt))
         k = jnp.einsum("bsd,dhk->bshk", h, layer["wk"].astype(dt))
@@ -789,8 +792,14 @@ class LlamaModel:
         row = (self.cfg.n_kv_heads, self.cfg.head_dim)
         return row, row
 
+    @property
+    def kv_dtype(self):
+        """What the cache holds a row in: the compute dtype, but for a
+        model that packs its rows into words (``MLAModel.word_rows``)."""
+        return self.cfg.dtype
+
     def _kv_zeros(self, *leading: int) -> Params:
-        return {name: jnp.zeros(leading + row, self.cfg.dtype)
+        return {name: jnp.zeros(leading + row, self.kv_dtype)
                 for name, row in zip(("k", "v"), self.kv_row_shapes())}
 
     def init_kv_cache(self, batch: int, max_seq: int) -> Params:
@@ -929,6 +938,15 @@ class LlamaModel:
         return {"moe_grouped_impl": "", "moe_gmm_tiling_gate": "",
                 "moe_gmm_tiling_up": "", "moe_gmm_tiling_down": ""}
 
+    def sparse_decode_plan(self) -> Dict:
+        """What a model whose decode attention reads a SELECTION of a
+        slot's rows reports to an engine's ``stats`` (``MLAModel`` with
+        an indexer answers): the rows a query keeps, the bytes of a
+        row's index key, and what implements the index scores and the
+        selection. 0 and empty strings for every other model."""
+        return {"index_topk": 0, "kv_index_row_bytes": 0,
+                "decode_indexer_impl": "", "decode_select_impl": ""}
+
     def decode_step_paged_counted(self, params: Params, tokens: jax.Array,
                                   pool: Params, block_tables: jax.Array,
                                   offsets: jax.Array,
@@ -1009,7 +1027,8 @@ class LlamaModel:
                         v_new[:, 0])
                 with jax.named_scope("attention"):
                     o = self._attend_pages(
-                        q[:, 0], k_all, v_all, layer, own(block_tables),
+                        jax.tree.map(lambda a: a[:, 0], q), k_all, v_all,
+                        layer, own(block_tables),
                         lengths, impl=impl, starts=own(starts),
                         first_block=base, num_blocks=NB)
                 return o[:, None], (k_all, v_all)

@@ -81,6 +81,21 @@ class MoEConfig(LlamaConfig):
     router_kind: str = "softmax"
     routed_scaling_factor: float = 1.0
     router_bias_init_std: float = 0.0
+    # the sigmoid router's GROUP LIMIT (``n_group``, ``topk_group``): the
+    # experts are ``router_n_group`` groups of neighbours, scored by the
+    # sum of their two best; the top-k is taken in the
+    # ``router_topk_group`` best groups alone (``route_topk``). 1: none
+    router_n_group: int = 1
+    router_topk_group: int = 1
+    # ONE CHIP'S SHARE of a layer that several chips share by experts:
+    # the layer HOLDS ``experts_held`` of the router's ``num_experts``,
+    # from ``first_expert_held`` on (None: all of them). The router
+    # keeps its width and its top-k; an assignment to an expert that is
+    # not held is computed by nobody here and its part of the sum is
+    # left out (``dropless_expert_ffn(held=)``); nothing stands in for
+    # the other chips or their exchange
+    experts_held: Optional[int] = None
+    first_expert_held: int = 0
     # a dense SwiGLU this wide on EVERY token beside the routed sum (the
     # shared experts, side by side: n_shared x one's width); 0: none
     shared_ffn_dim: int = 0
@@ -109,6 +124,31 @@ class MoEConfig(LlamaConfig):
         if self.leading_layers and self.layer_types is not None:
             raise ValueError("leading layers of another tree are not "
                              "combined with layer kinds")
+        groups, kept = self.router_n_group, self.router_topk_group
+        if groups > 1 and (self.router_kind != "sigmoid"
+                           or self.num_experts % groups
+                           or not 1 <= kept <= groups
+                           or self.num_experts // groups < 2
+                           or kept * (self.num_experts // groups)
+                           < self.expert_top_k):
+            raise ValueError(
+                f"a group limit is the sigmoid router's: {groups} groups "
+                f"that divide the {self.num_experts} experts into two or "
+                f"more each, of which {kept} hold the top "
+                f"{self.expert_top_k}")
+        if self.held != (0, self.num_experts) and not (
+                0 <= self.held[0] and self.held[1] >= 1
+                and sum(self.held) <= self.num_experts):
+            raise ValueError(
+                f"experts {self.held[0]} to {sum(self.held)} are not among "
+                f"the router's {self.num_experts}")
+
+    @property
+    def held(self) -> Tuple[int, int]:
+        """(first, count) of the experts this layer holds."""
+        if self.experts_held is None:
+            return 0, self.num_experts
+        return self.first_expert_held, self.experts_held
 
     def attention_params(self) -> int:
         """One layer's attention weights (and QK-norm scales)."""
@@ -120,7 +160,7 @@ class MoEConfig(LlamaConfig):
     def num_params(self) -> int:
         d, f, v, E = self.dim, self.ffn_dim, self.vocab_size, self.num_experts
         attn = self.attention_params() + 2 * d            # and two norms
-        expert_layer = (attn + d * E + 3 * E * d * f
+        expert_layer = (attn + d * E + 3 * self.held[1] * d * f
                         + 3 * d * self.shared_ffn_dim
                         + (E if self.router_kind == "sigmoid" else 0))
         lead = self.leading_layers
@@ -192,10 +232,12 @@ class MoEModel(LlamaModel):
         super().__init__(cfg, mesh=mesh, rules=rules)
         self._ep = 1 if mesh is None else mesh.shape.get("ep", 1)
         if self._ep > 1 and (cfg.router_kind != "softmax"
-                             or cfg.shared_ffn_dim):
+                             or cfg.shared_ffn_dim
+                             or cfg.experts_held is not None):
             raise NotImplementedError(
                 "the capacity dispatches under an ep mesh axis have the "
-                "softmax router and no shared expert")
+                "softmax router, no shared expert and every expert (the "
+                "mesh shares them out, not ``experts_held``)")
 
     @property
     def _main_layers(self) -> int:
@@ -205,6 +247,7 @@ class MoEModel(LlamaModel):
         params = super().init(rng)
         cfg: MoEConfig = self.cfg
         d, f, E, L = cfg.dim, cfg.ffn_dim, cfg.num_experts, self._main_layers
+        H = cfg.held[1]              # the stacks hold the layer's share
         keys = jax.random.split(jax.random.fold_in(rng, 1), 4)
         layers = params["layers"]
         for key in ("w_gate", "w_up", "w_down"):
@@ -212,11 +255,11 @@ class MoEModel(LlamaModel):
         layers["router"] = jax.random.normal(
             keys[0], (L, d, E), jnp.float32) * 0.02
         layers["e_gate"] = jax.random.normal(
-            keys[1], (L, E, d, f), jnp.float32) * d ** -0.5
+            keys[1], (L, H, d, f), jnp.float32) * d ** -0.5
         layers["e_up"] = jax.random.normal(
-            keys[2], (L, E, d, f), jnp.float32) * d ** -0.5
+            keys[2], (L, H, d, f), jnp.float32) * d ** -0.5
         layers["e_down"] = jax.random.normal(
-            keys[3], (L, E, f, d), jnp.float32) * f ** -0.5
+            keys[3], (L, H, f, d), jnp.float32) * f ** -0.5
         if cfg.qk_norm:
             layers["q_norm"] = jnp.ones(
                 (L, cfg.n_heads, cfg.head_dim), jnp.float32)
@@ -303,10 +346,14 @@ class MoEModel(LlamaModel):
         if cfg.router_kind == "sigmoid":
             shared.update(sigmoid_bias=layer["router_bias"],
                           weight_scale=cfg.routed_scaling_factor)
+        if cfg.router_n_group > 1:
+            shared.update(groups=(cfg.router_n_group, cfg.router_topk_group))
+        if cfg.experts_held is not None:
+            shared.update(held=cfg.held)
         out, load, experts, aux = dropless_expert_ffn(
             h.reshape(B * T, D), *weights, live=rows_live,
             first_expert=(None if stacks is None
-                          else layer["index"] * cfg.num_experts), **shared)
+                          else layer["index"] * cfg.held[1]), **shared)
         out = out.reshape(B, T, D)
         if cfg.shared_ffn_dim:
             with jax.named_scope("moe_shared_expert"):

@@ -53,7 +53,8 @@ logger = logging.getLogger(__name__)
 
 
 def route_topk(xf, router, top_k: int, norm_topk_prob: bool,
-               precision=None, sigmoid_bias=None, weight_scale: float = 1.0):
+               precision=None, sigmoid_bias=None, weight_scale: float = 1.0,
+               groups=(1, 1)):
     """Router in float32: ``(logits [T, E], probs [T, E], weights [T, K],
     experts [T, K])``. The top-k softmax weights are used as they are
     unless ``norm_topk_prob`` (then they sum to 1).
@@ -63,12 +64,30 @@ def route_topk(xf, router, top_k: int, norm_topk_prob: bool,
     top-k of ``s + bias``, a selection bias that is no part of the
     weights; the weights are ``s`` of the chosen, divided by their sum
     (+ 1e-20) under ``norm_topk_prob``, times ``weight_scale``
-    (``routed_scaling_factor``). ``probs`` is then ``s``."""
+    (``routed_scaling_factor``). ``probs`` is then ``s``.
+
+    ``groups`` = (``n_group``, ``topk_group``), the sigmoid form's GROUP
+    LIMIT: the E experts are ``n_group`` groups of neighbours; a group's
+    score is the sum of its TWO largest ``s + bias``; the ``topk_group``
+    best groups stay and ``s + bias`` of every other expert is set to 0
+    before the top-k (the weights are still ``s`` of the chosen). (1, 1)
+    is no limit."""
     logits = jnp.einsum("td,de->te", xf.astype(jnp.float32),
                         router.astype(jnp.float32), precision=precision)
     if sigmoid_bias is not None:
         scores = jax.nn.sigmoid(logits)
-        _, gate_idx = jax.lax.top_k(scores + sigmoid_bias, top_k)
+        biased = scores + sigmoid_bias
+        n_group, topk_group = groups
+        if n_group > 1:
+            with jax.named_scope("moe_group_limit"):
+                grouped = biased.reshape(biased.shape[0], n_group, -1)
+                group_score = jnp.sum(jax.lax.top_k(grouped, 2)[0], axis=-1)
+                _, kept = jax.lax.top_k(group_score, topk_group)
+                stays = jnp.any(jax.nn.one_hot(kept, n_group, dtype=bool),
+                                axis=1)                       # [T, groups]
+                biased = jnp.where(stays[:, :, None], grouped,
+                                   0.0).reshape(biased.shape)
+        _, gate_idx = jax.lax.top_k(biased, top_k)
         gate_vals = jnp.take_along_axis(scores, gate_idx, axis=-1)
         if norm_topk_prob:
             gate_vals = gate_vals / (
@@ -236,7 +255,8 @@ def dropless_expert_ffn(x, router, e_gate, e_up, e_down, *, top_k: int,
                         norm_topk_prob: bool, dtype, live=None,
                         first_expert=None,
                         z_coef: float = 0.0, lb_coef: float = 0.0,
-                        sigmoid_bias=None, weight_scale: float = 1.0):
+                        sigmoid_bias=None, weight_scale: float = 1.0,
+                        groups=(1, 1), held=None):
     """x [T, D] -> ``(out [T, D] in ``dtype``, load [E] int32, experts
     [T, K] int32, aux)``.
 
@@ -244,8 +264,18 @@ def dropless_expert_ffn(x, router, e_gate, e_up, e_down, *, top_k: int,
     is the number of rows handed to expert ``e``'s grouped matmuls, of
     the tokens ``live`` [T] bool marks (all, if ``None``): it sums to
     ``live.sum() * top_k`` because nothing is dropped. ``aux`` is the
-    training loss of ``router_aux_loss``. ``sigmoid_bias`` and
-    ``weight_scale`` are ``route_topk``'s: the sigmoid router.
+    training loss of ``router_aux_loss``. ``sigmoid_bias``,
+    ``weight_scale`` and ``groups`` are ``route_topk``'s: the sigmoid
+    router.
+
+    With ``held`` = (first, H) the layer HOLDS A SHARE of the router's E
+    experts, ``first ... first + H``, and the weights are those H alone
+    ([H, D, F]; a stack's layer is H groups): one chip's part of a layer
+    that several share by experts. The router is E wide and chooses as
+    ever (``load`` and ``experts`` count every choice); an assignment to
+    an expert that is not held is sorted behind the held ones' rows and
+    belongs to NO group, so no weight is fetched for it, and its part of
+    the sum is left out: ``out`` is what the held experts add.
 
     With ``first_expert`` (a traced int32 scalar) the weights are G >= E
     groups, [G, D, F] and [G, F, D], a stack of several layers' experts
@@ -263,15 +293,24 @@ def dropless_expert_ffn(x, router, e_gate, e_up, e_down, *, top_k: int,
         logits, probs, weights, experts = route_topk(
             x, router, top_k, norm_topk_prob,
             precision=jax.lax.Precision.HIGHEST, sigmoid_bias=sigmoid_bias,
-            weight_scale=weight_scale)
+            weight_scale=weight_scale, groups=groups)
         aux = router_aux_loss(logits, probs, z_coef, lb_coef)
     with jax.named_scope("moe_dispatch"):
-        flat = experts.reshape(T * top_k)
-        order = jnp.argsort(flat, stable=True)        # rows by expert
+        flat = by = experts.reshape(T * top_k)
+        if held is not None:
+            # the held experts' rows first, by expert; the others' last,
+            # in no group
+            first_held, n_held = held
+            here = (flat >= first_held) & (flat < first_held + n_held)
+            by = jnp.where(here, flat - first_held, n_held)
+        order = jnp.argsort(by, stable=True)          # rows by expert
         sizes = jnp.zeros((E,), jnp.int32).at[flat].add(1)
         rows = x.astype(dtype)[order // top_k]        # [T*K, D]
         load = sizes if live is None else jnp.zeros((E,), jnp.int32).at[
             flat].add(jnp.repeat(live.astype(jnp.int32), top_k))
+        if held is not None:
+            sizes = jnp.zeros((n_held + 1,), jnp.int32).at[by].add(
+                1)[:n_held]
         if first_expert is not None:
             sizes = jax.lax.dynamic_update_slice(
                 jnp.zeros((e_gate.shape[0],), jnp.int32), sizes,
@@ -284,6 +323,9 @@ def dropless_expert_ffn(x, router, e_gate, e_up, e_down, *, top_k: int,
     with jax.named_scope("moe_combine"):
         back = jnp.argsort(order)                     # un-sort
         rows = rows[back].reshape(T, top_k, D)
+        if held is not None:
+            # a row of no group is whatever the grouped matmul left there
+            rows = jnp.where(here.reshape(T, top_k, 1), rows, 0)
         # elementwise, so the weights keep their float32 (a dot would
         # round them to bf16 on the TPU)
         out = jnp.sum(rows.astype(jnp.float32) * weights[:, :, None], axis=1)

@@ -488,6 +488,90 @@ def test_mla_cell_decode_program_fits_the_v5e(v5e, placed, monkeypatch):
     assert mem.temp_size_in_bytes < one_stack_a_layer / 4
 
 
+# deepseek-v3.2-d5.long_decode_dsa: 16 slots x 22,528 at block 32 (704
+# table entries a slot), rows held as words, 2,048 rows selected
+DSA_CELL_SLOTS, DSA_CELL_TABLE, DSA_TOPK = 16, 704, 2048
+
+
+def test_dsa_kernels_lower_at_the_dsa_cells_shape(v5e):
+    """The two Mosaic kernels of ``ops/dsa.py`` for the v5e, no chip: the
+    index scores through the block table (pages of "v", [32, 128] words)
+    and the absorbed attention that copies 2,048 selected rows a slot by
+    their numbers (a row of "k" [2, 128] words and one of "v" [128] a
+    DMA each, out of stacks of five layers' windows). The pools are what
+    the arguments hold: 1,536 B a row and nothing padded. The selection
+    between them is XLA's: ``top_k`` at 2,048 of 22,528 lowers to a
+    ``sort``, the name ``dsa.indexer_roofline.decode`` reads."""
+    from ray_tpu.ops import dsa
+
+    B, bs, maxb, L = DSA_CELL_SLOTS, CELL_BS, DSA_CELL_TABLE, 5
+    blocks = L * (B * maxb + 1)
+    u32, i32 = jnp.uint32, jnp.int32
+    indexer = dsa.indexer_scores_pallas.lower(
+        v5e(B, 64, 128), v5e(B, 64, dtype=jnp.float32),
+        v5e(blocks, bs, 128, dtype=u32), v5e(B, maxb, dtype=i32),
+        v5e(B, dtype=i32), first_block=v5e(dtype=i32)).compile()
+    assert "tpu_custom_call" in indexer.as_text()
+    assert indexer.memory_analysis().argument_size_in_bytes \
+        == pytest.approx(blocks * bs * 512, rel=0.001)
+    attention = dsa.selected_attention_pallas.lower(
+        v5e(B, 128, 512), v5e(B, 128, 128),
+        v5e(blocks, bs, 2, 128, dtype=u32), v5e(blocks, bs, 128, dtype=u32),
+        v5e(B, DSA_TOPK, dtype=i32), v5e(B, dtype=i32),
+        scale=0.13523).compile()
+    text = attention.as_text()
+    assert "tpu_custom_call" in text
+    # the pools (the uint32 operands) reach the kernel as they lie: no copy
+    # into another layout
+    assert not [line for line in text.splitlines()
+                if " copy(" in line and "u32[" in line]
+    assert attention.memory_analysis().argument_size_in_bytes \
+        == pytest.approx(blocks * bs * 1536, rel=0.001)
+    select = jax.jit(lambda s, n: dsa.select_topk(s, n, DSA_TOPK)).lower(
+        v5e(B, maxb * bs, dtype=jnp.float32), v5e(B, dtype=i32)).compile()
+    assert " sort(" in select.as_text()
+
+
+def test_dsa_cell_decode_program_fits_the_v5e(v5e, placed, monkeypatch):
+    """``deepseek-v3.2-d5.long_decode_dsa``'s decode program as the engine
+    jits it: 16 slots x 22,528, one leading dense layer and four expert
+    layers that hold 16 of the router's 256 experts. The v5e's compiler
+    takes it at 11.23 GiB of 15.75 (8.65 of weights, 2.58 of pool, 0.005
+    of temporaries: ``benchmark/aot_fit.py``), the two kernels of
+    ``ops/dsa.py`` in both scans, ``ragged_dot`` for the experts (7168 x
+    2048 is a width XLA tiles 512 x 512), and nothing of an expert
+    stack's shape among the temporaries."""
+    from benchmark import run as harness
+    from benchmark.builders import deepseek_v32
+
+    _as_on_the_chip(monkeypatch)
+    cfg = harness.load_json(harness.ROOT,
+                            "benchmark/configs/deepseek-v3.2-d5.json")
+    B, bs, maxb = DSA_CELL_SLOTS, CELL_BS, DSA_CELL_TABLE
+    model = deepseek_v32.build_model(cfg, maxb * bs)
+    assert model.paged_decode_impl() == "dsa_pallas"
+    assert model.ffn_load_shape() == (4, 256)
+    assert model.grouped_matmul_plan(B)["moe_grouped_impl"] == "ragged_dot"
+    pool = jax.eval_shape(lambda: model.init_kv_pool(B * maxb + 1, bs))
+    assert pool["k"].shape == (5, B * maxb + 1, bs, 2, 128)
+    assert pool["v"].shape == (5, B * maxb + 1, bs, 128)
+    compiled = _engine_decode(model, B * maxb).lower(
+        placed(_engine_params(model)), v5e(B, dtype=jnp.int32), placed(pool),
+        v5e(B, maxb, dtype=jnp.int32), v5e(B, dtype=jnp.int32),
+        *_sampling(v5e, B), v5e(4, 256, dtype=jnp.int32)).compile()
+    text = compiled.as_text()
+    # both kernels in each of the two scans
+    assert text.count("tpu_custom_call") >= 4
+    mem = compiled.memory_analysis()
+    total = (mem.argument_size_in_bytes + mem.temp_size_in_bytes
+             + mem.output_size_in_bytes - mem.alias_size_in_bytes)
+    assert total < 15.75 * 2**30
+    assert sum(a.size * 4 for a in pool.values()) \
+        == 5 * (B * maxb + 1) * bs * 1536
+    one_layers_slice_of_a_stack = 16 * 7168 * 2048 * 2
+    assert mem.temp_size_in_bytes < one_layers_slice_of_a_stack / 4
+
+
 # evabyte-6.5b-d8.long_decode_eva: 16 slots x 24,576 at block 32; both
 # parts' tables are 65 entries a slot (the window's 64 blocks and one more;
 # the summary part needs 48), MHA 32/32 at 128

@@ -1,0 +1,566 @@
+"""DeepSeek-V3.2's block on the serving path, at debug widths with seeded
+weights, against ``benchmark/reference/deepseek_v32.py``: learned sparse
+attention (a lightning indexer with a cache of its own, the top-k rows as
+a mask in the prefills and as row numbers in the decode step, the
+absorbed latent attention over the selected rows), a compressed query,
+YaRN with its scale on the softmax, the group-limited sigmoid router, and
+an expert layer that holds a share of its router's experts.
+
+Under bf16 compute TWO things swap on rounding: the router's near-tied
+experts (as for kanana) and the indexer's near-tied rows at the
+``index_topk``-th place. So the system is compared in float32 compute,
+where nothing swaps, to 1e-4, logits AND selected sets; and in bf16 with
+the reference FORCED to the system's experts and rows.
+"""
+
+import dataclasses
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from benchmark.builders import deepseek_v32 as builder
+from benchmark.reference import deepseek_v32 as reference
+from ray_tpu.models import MLAConfig, model_for
+from ray_tpu.ops import dsa
+from ray_tpu.ops.moe_dispatch import route_topk
+
+F32_TOL = 1e-4          # max |logit difference|, logits of RMS ~1
+# bf16 compute against the float32 reference forced to the system's
+# experts and rows, relative RMS of the logits (kanana's block reads
+# 0.011-0.014 at these widths; the readings here are 0.012-0.016)
+BF16_REL_RMS = 0.025
+I32 = jnp.int32
+HELD = (4, 8)           # experts 4..11 of the router's 16
+
+
+def make(dtype=jnp.float32, seed=1, held=HELD, **overrides):
+    cfg = MLAConfig.debug_deepseek_v32(
+        dtype=dtype, first_expert_held=held[0], experts_held=held[1],
+        **overrides)
+    model = model_for(cfg)
+    params = jax.jit(model.init)(jax.random.key(seed))
+    key = jax.random.key(seed + 100)
+    for stack in ("layers", "leading_layers"):
+        layers = params[stack]
+        for name in ("kv_norm", "q_norm", "attn_norm", "mlp_norm",
+                     "idx_k_norm"):
+            key, sub = jax.random.split(key)
+            # scales about 1, the LayerNorm's bias about 0
+            layers[name] = layers[name] + 0.3 * jax.random.normal(
+                sub, layers[name].shape)
+    # router logits of sigma 0.9, as the init gives at the published width
+    params["layers"]["router"] *= (7168 / cfg.dim) ** 0.5
+    return cfg, model, params
+
+
+def ref_kwargs(cfg):
+    y = cfg.yarn
+    return dict(
+        qk_nope_head_dim=cfg.qk_nope_head_dim,
+        qk_rope_head_dim=cfg.qk_rope_head_dim, kv_lora_rank=cfg.kv_lora_rank,
+        rope_theta=cfg.rope_theta,
+        yarn=(y.factor, y.original_max_position, y.beta_fast, y.beta_slow),
+        mscale_all_dim=cfg.yarn_mscale_all_dim, rms_norm_eps=cfg.norm_eps,
+        index_topk=cfg.index_topk, top_k=cfg.expert_top_k,
+        n_group=cfg.router_n_group, topk_group=cfg.router_topk_group,
+        routed_scaling_factor=cfg.routed_scaling_factor,
+        norm_topk_prob=cfg.norm_topk_prob, experts_held=cfg.held)
+
+
+def ref_forward(cfg, params, tokens, **kw):
+    return reference.forward(
+        builder.reference_params({"tie_word_embeddings": False}, params),
+        tokens, **{**ref_kwargs(cfg), **kw})
+
+
+def seqs(cfg, shape=(2, 48), seed=0):
+    return jnp.asarray(np.random.default_rng(seed).integers(
+        1, cfg.vocab_size, shape), I32)
+
+
+def rel_rms(got, want):
+    return float(jnp.sqrt(jnp.mean((got - want) ** 2) / jnp.mean(want ** 2)))
+
+
+class Selections:
+    """What the system selected, recorded as it ran: the prefills' masks
+    (``ops.dsa.topk_mask``) and the decode steps' row numbers
+    (``select_topk``), in the order the layers ran."""
+
+    def __init__(self, monkeypatch):
+        self.masks, self.rows = [], []
+        real_mask, real_rows = dsa.topk_mask, dsa.select_topk
+
+        def mask(scores, seen, k):
+            out = real_mask(scores, seen, k)
+            jax.debug.callback(
+                lambda m: self.masks.append(np.asarray(m)), out,
+                ordered=True)
+            return out
+
+        def rows(scores, lengths, k):
+            out = real_rows(scores, lengths, k)
+            jax.debug.callback(
+                lambda r, n: self.rows.append((np.asarray(r),
+                                               np.asarray(n))),
+                *out, ordered=True)
+            return out
+
+        monkeypatch.setattr(dsa, "topk_mask", mask)
+        monkeypatch.setattr(dsa, "select_topk", rows)
+
+    def prefill(self, layers: int):
+        """[L, B, T, S] of one prefill call: its blocks joined."""
+        blocks = len(self.masks) // layers
+        out = np.stack([np.concatenate(self.masks[l * blocks:(l + 1) * blocks],
+                                       axis=1) for l in range(layers)])
+        self.masks.clear()
+        return out
+
+    def decode(self, layers: int, width: int):
+        """[L, B, width] bool of one decode step."""
+        out = np.zeros((layers, len(self.rows[0][1]), width), bool)
+        for l, (rows, count) in enumerate(self.rows[:layers]):
+            for b, n in enumerate(count):
+                out[l, b, rows[b, :n]] = True
+        del self.rows[:layers]
+        return out
+
+
+def full_forward(model, params, toks, sel):
+    logits = jax.jit(model.apply)(params, toks)
+    return logits, sel.prefill(model.cfg.n_layers)
+
+
+def prefill_then_paged_decode(model, params, toks, sel, prompt=24, bs=8):
+    """``check_logits``'s route: a prefill (EXPANDED, the selection as a
+    mask) into a slot-major cache, scattered into pool blocks by the
+    names ``"k"`` / ``"v"``, then paged decode steps (index scores over
+    the pages, row numbers, the absorbed form over the selected rows).
+    24 rows are prefilled, so the decode steps select 12 of 25..48, and
+    cross block edges at 32 and 40."""
+    B, total = toks.shape
+    L = model.cfg.n_layers
+    nb = -(-total // bs)
+    cache = model.init_kv_cache(B, nb * bs)
+    padded = jnp.zeros((B, nb * bs), I32).at[:, :prompt].set(toks[:, :prompt])
+    pre, cache = jax.jit(model.forward_step)(params, padded, cache,
+                                             jnp.zeros((B,), I32))
+    chosen = [sel.prefill(L)[:, :, :prompt, :total]]
+    pool = model.init_kv_pool(B * nb + 1, bs)
+    ids = jnp.arange(B * nb)
+    pool = {k: pool[k].at[:, ids].set(
+        cache[k].reshape(L, B * nb, bs, *cache[k].shape[3:]))
+        for k in ("k", "v")}
+    tables = ids.astype(I32).reshape(B, nb)
+    out = [pre[:, :prompt]]
+    step = jax.jit(model.decode_step_paged)
+    for pos in range(prompt, total):
+        logits, pool = step(
+            params, toks[:, pos], pool, tables, jnp.full((B,), pos, I32))
+        out.append(logits[:, None])
+        chosen.append(sel.decode(L, total)[:, :, None])
+    return jnp.concatenate(out, axis=1), np.concatenate(chosen, axis=2)
+
+
+def prefix_prefill(model, params, toks, sel, prefix=32):
+    """A suffix prefill of 16 rows over a cached prefix of 32 (from a
+    plain prefill), padded as the engine pads: the chunk's queries score
+    the gathered prefix's index keys and their own."""
+    B, total = toks.shape
+    L = model.cfg.n_layers
+    cache = model.init_kv_cache(B, prefix)
+    _, cache = jax.jit(model.forward_step)(params, toks[:, :prefix], cache,
+                                           jnp.zeros((B,), I32))
+    sel.masks.clear()
+    padded = {n: jnp.pad(a, ((0, 0), (0, 0), (0, 8)) + ((0, 0),) * (
+        a.ndim - 3)) for n, a in cache.items()}
+    suffix = jnp.zeros((B, 32), I32).at[:, :total - prefix].set(
+        toks[:, prefix:])
+    logits, rows = jax.jit(model.prefill_with_prefix)(
+        params, suffix, padded["k"], padded["v"], jnp.full((B,), prefix, I32),
+        jnp.full((B,), total - prefix, I32))
+    assert rows["k"].shape[:3] == (L, B, 32)
+    # the keys: 40 of the padded prefix, then the suffix's own
+    masks = sel.prefill(L)[:, :, :total - prefix]
+    chosen = np.concatenate([masks[..., :prefix],
+                             masks[..., 40:40 + total - prefix]], axis=-1)
+    return logits[:, None], chosen       # the position total - 1's logits
+
+
+PATHS = {"full_forward": full_forward,
+         "prefill_then_paged_decode": prefill_then_paged_decode,
+         "prefix_prefill": prefix_prefill}
+
+
+@pytest.mark.parametrize("path", sorted(PATHS))
+def test_float32_compute_matches_the_reference_logits_and_rows(
+        path, monkeypatch):
+    cfg, model, params = make()
+    assert not model.word_rows and model.paged_decode_impl() == "dsa_xla"
+    toks = seqs(cfg)
+    sel = Selections(monkeypatch)
+    with jax.default_matmul_precision("highest"):
+        got, chosen = PATHS[path](model, params, toks, sel)
+    want, choices = ref_forward(cfg, params, toks, with_choices=True)
+    want_rows = np.asarray(choices["selection"])
+    if path == "prefix_prefill":
+        want, want_rows = want[:, -1:], want_rows[:, :, 32:]
+        np.testing.assert_allclose(got, want, atol=F32_TOL)
+    else:
+        np.testing.assert_allclose(got, want, atol=F32_TOL)
+    # the selected SETS, every layer, every position: 12 rows once there
+    # are so many, every row before
+    assert chosen.shape == want_rows.shape
+    assert (chosen == want_rows).all()
+    counts = want_rows.sum(-1)
+    first = 32 if path == "prefix_prefill" else 0
+    assert (counts[0, 0] == np.minimum(np.arange(first, 48) + 1,
+                                       cfg.index_topk)).all()
+
+
+def make_word_rows(dtype=jnp.bfloat16, **kw):
+    """Widths that fill lane tiles: the row is held as words and the
+    Mosaic kernels (interpreted here) read it."""
+    return make(dtype, kv_lora_rank=256, index_head_dim=128, **kw)
+
+
+def bf16_full_forward(model, params, toks, sel):
+    logits, extras = jax.jit(model._apply_with_extras)(params, toks)
+    return logits, extras["experts"], sel.prefill(model.cfg.n_layers)
+
+
+def bf16_paged_decode_from_empty(model, params, toks, sel, bs=8):
+    """Every position by a paged decode step (index scores over the
+    pages, row numbers, the absorbed form over the selected rows), with
+    the experts and the rows each step chose."""
+    B, total = toks.shape
+    L = model.cfg.n_layers
+    nb = -(-total // bs)
+    pool = model.init_kv_pool(B * nb + 1, bs)
+    tables = jnp.arange(B * nb, dtype=I32).reshape(B, nb)
+    logits, experts, chosen = [], [], []
+    step = jax.jit(model.decode_step_paged_counted)
+    for pos in range(total):
+        out, pool, extras = step(
+            params, toks[:, pos], pool, tables, jnp.full((B,), pos, I32))
+        logits.append(out[:, None])
+        experts.append(extras["experts"])
+        chosen.append(sel.decode(L, total)[:, :, None])
+    return (jnp.concatenate(logits, 1), jnp.concatenate(experts, 2),
+            np.concatenate(chosen, 2))
+
+
+@pytest.mark.parametrize("seed", [1])
+@pytest.mark.parametrize("path,impl", [("full_forward", None),
+                                       ("paged_decode", "xla"),
+                                       ("paged_decode", "pallas")])
+def test_bf16_compute_with_the_reference_forced_to_its_experts_and_rows(
+        path, impl, seed, monkeypatch):
+    """Rows held as words; the decode step's kernels interpreted."""
+    cfg, model, params = make_word_rows(seed=seed)
+    if impl is not None:
+        model = model_for(dataclasses.replace(cfg, decode_attention=impl))
+        assert model.word_rows
+        assert model.paged_decode_impl() == "dsa_" + impl
+    toks = seqs(cfg, (2, 40), seed=seed)
+    sel = Selections(monkeypatch)
+    run = bf16_full_forward if path == "full_forward" \
+        else bf16_paged_decode_from_empty
+    got, experts, chosen = run(model, model.serving_params(params), toks, sel)
+    want = ref_forward(cfg, params, toks, forced_experts=experts,
+                       forced_selection=jnp.asarray(chosen))
+    assert rel_rms(got.astype(jnp.float32), want) < BF16_REL_RMS
+    # free, the same logits stand far off: both kinds of near-tie swap
+    assert (chosen.sum(-1)[0, 0] == np.minimum(np.arange(40) + 1, 12)).all()
+
+
+def test_bf16_word_rows_hold_what_the_plain_rows_hold():
+    """The same prefill through the word-row cache and read back: the
+    packed rows unpack to the bf16 values bit for bit."""
+    cfg, model, params = make_word_rows()
+    assert model.kv_row_shapes() == ((1, 128), (128,))
+    assert model.kv_dtype == jnp.uint32
+    x = jax.random.normal(jax.random.key(0), (3, 5, 256), jnp.bfloat16)
+    assert bool(jnp.all(dsa.unpack_words(dsa.pack_words(x)) == x))
+    served = model.serving_params(params)
+    toks = seqs(cfg, (1, 16))
+    cache = model.init_kv_cache(1, 16)
+    _, cache = model.forward_step(served, toks, cache, jnp.zeros((1,), I32))
+    assert cache["k"].dtype == cache["v"].dtype == jnp.uint32
+    c, k_pe, k_idx = model._row_parts(cache["k"], cache["v"])
+    assert c.shape == (3, 1, 16, 256) and k_idx.shape == (3, 1, 16, 128)
+    assert k_pe.shape == (3, 1, 16, 128)
+    assert float(jnp.abs(k_pe[..., cfg.qk_rope_head_dim:]).max()) == 0.0
+
+
+def test_the_published_row_is_1536_bytes_and_unpadded():
+    from benchmark import run as harness
+    pub = harness.load_json(harness.ROOT,
+                            "benchmark/configs/deepseek-v3.2-d5.json")
+    model = builder.build_model(pub, 64)
+    assert model.word_rows
+    assert model.kv_row_shapes() == ((2, 128), (128,))
+    pool = jax.eval_shape(lambda: model.init_kv_pool(4, 32))
+    assert pool["k"].shape == (5, 4, 32, 2, 128)
+    assert pool["v"].shape == (5, 4, 32, 128)
+    assert sum(a.size * 4 for a in pool.values()) == 5 * 4 * 32 * 1536
+    assert model.cfg.num_params() == pub["parameters"] == 4_635_518_208
+    assert model.cfg.softmax_scale == pytest.approx(0.13523, abs=1e-5)
+
+
+# -- the selection ----------------------------------------------------------
+@pytest.mark.parametrize("k", [1, 5, 12])
+def test_topk_mask_is_exact_and_ties_go_to_the_earlier_row(k):
+    rng = np.random.default_rng(k)
+    # few distinct values: ties everywhere, the k-th place among them
+    scores = jnp.asarray(rng.integers(-2, 3, (3, 7, 40)), jnp.float32)
+    seen = jnp.asarray(rng.random((3, 7, 40)) < 0.7)
+    got = np.asarray(dsa.topk_mask(scores, seen, k))
+    _, rows = jax.lax.top_k(jnp.where(seen, scores, -jnp.inf), k)
+    want = np.zeros_like(got)
+    np.put_along_axis(want, np.asarray(rows), True, axis=-1)
+    want &= np.asarray(seen)
+    assert (got == want).all()
+    assert (got.sum(-1) == np.minimum(np.asarray(seen).sum(-1), k)).all()
+    assert (np.asarray(reference.topk_rows(scores, seen, k)) == got).all()
+
+
+def test_a_short_slot_selects_every_row_and_no_row_past_its_length():
+    scores = jnp.asarray(np.random.default_rng(0).normal(size=(3, 32)),
+                         jnp.float32)
+    # rows past the length hold anything, even the largest scores
+    scores = scores.at[:, 20:].set(100.0)
+    lengths = jnp.asarray([1, 7, 20], I32)
+    rows, count = dsa.select_topk(scores, lengths, 12)
+    assert count.tolist() == [1, 7, 12]
+    for b, n in enumerate(count.tolist()):
+        assert set(np.asarray(rows[b, :n]).tolist()) <= set(
+            range(int(lengths[b])))
+    assert set(np.asarray(rows[1, :7]).tolist()) == set(range(7))
+    # a table narrower than index_topk: every row at most
+    rows, count = dsa.select_topk(scores[:, :8], lengths, 12)
+    assert rows.shape == (3, 8) and count.tolist() == [1, 7, 8]
+
+
+# -- the kernels, interpreted, against their twins --------------------------
+def word_pools(seed, B, maxb, bs, R=256, Di=128, rope=16):
+    ks = jax.random.split(jax.random.key(seed), 8)
+    NB = B * maxb + 1
+    c = jax.random.normal(ks[0], (NB, bs, R), jnp.bfloat16)
+    pe = jnp.pad(jax.random.normal(ks[1], (NB, bs, rope), jnp.bfloat16),
+                 ((0, 0), (0, 0), (0, 128 - rope)))
+    ki = jax.random.normal(ks[2], (NB, bs, Di), jnp.bfloat16)
+    k_pool = dsa.pack_words(c).reshape(NB, bs, R // 256, 128)
+    v_pool = jnp.concatenate([dsa.pack_words(pe), dsa.pack_words(ki)], -1)
+    tables = jnp.asarray(np.random.default_rng(seed).permutation(
+        B * maxb).reshape(B, maxb), I32)
+    return ks[3:], k_pool, v_pool, tables
+
+
+def unpacked(k_rows, v_rows):
+    return (dsa.unpack_words(k_rows.reshape(*k_rows.shape[:-2], -1)),
+            dsa.unpack_words(v_rows[..., :64]),
+            dsa.unpack_words(v_rows[..., 64:]))
+
+
+KERNEL_LENGTHS = {"ragged": [1, 17, 48], "one_row": [1, 1, 1],
+                  "a_block_edge": [8, 9, 16], "full": [48, 48, 47]}
+
+
+@pytest.mark.parametrize("lengths", sorted(KERNEL_LENGTHS))
+@pytest.mark.parametrize("first_block", [0, 19])
+def test_kernels_in_interpret_mode_are_their_xla_twins(lengths, first_block):
+    B, maxb, bs, H, Hi = 3, 6, 8, 4, 4
+    ks, k_pool, v_pool, tables = word_pools(7, B, maxb, bs)
+    # the pools as one layer's window of a stack that starts elsewhere
+    pad = lambda a: jnp.concatenate([jnp.zeros_like(a)[:first_block], a])
+    k_pool, v_pool = pad(k_pool), pad(v_pool)
+    lens = jnp.asarray(KERNEL_LENGTHS[lengths], I32)
+    q_idx = jax.random.normal(ks[0], (B, Hi, 128), jnp.bfloat16)
+    w = jax.random.normal(ks[1], (B, Hi), jnp.float32)
+    common = dict(first_block=jnp.int32(first_block))
+    twin = dsa.indexer_scores(
+        q_idx, w, v_pool, tables, lens, impl="xla",
+        key_of=lambda v: unpacked(v[..., None, :], v)[2], **common)
+    kernel = dsa.indexer_scores(q_idx, w, v_pool, tables, lens,
+                                impl="pallas", key_of=None, **common)
+    live = np.arange(maxb * bs)[None] < np.asarray(lens)[:, None]
+    assert ((np.asarray(twin) > -1e29) == live).all()
+    assert ((np.asarray(kernel) > -1e29) == live).all()
+    np.testing.assert_allclose(np.asarray(kernel)[live],
+                               np.asarray(twin)[live], atol=1e-4)
+
+    rows, count = dsa.select_topk(twin, lens, 12)
+    q_lat = jax.random.normal(ks[2], (B, H, 256), jnp.bfloat16)
+    q_pe = jnp.pad(jax.random.normal(ks[3], (B, H, 16), jnp.bfloat16),
+                   ((0, 0), (0, 0), (0, 112)))
+    outs = [dsa.sparse_decode_attention(
+        q_lat, q_pe, k_pool, v_pool, tables, rows, count, impl=impl,
+        scale=0.1, parts_of=lambda k, v: unpacked(k, v)[:2], **common
+    ).astype(jnp.float32) for impl in ("xla", "pallas")]
+    assert float(jnp.abs(outs[0]).max()) > 0.5
+    np.testing.assert_allclose(outs[1], outs[0], atol=0.03)
+
+
+def test_the_resolver_names_what_runs(monkeypatch):
+    from ray_tpu.ops import paged_attention
+    cfg, model, _ = make()
+    assert model.paged_decode_impl() == "dsa_xla"
+    plan = model.sparse_decode_plan()
+    assert plan == {"index_topk": 12, "kv_index_row_bytes": 64,
+                    "decode_indexer_impl": "dsa_indexer_xla",
+                    "decode_select_impl": "xla_top_k"}
+    wide = make_word_rows()[1]
+    monkeypatch.setattr(paged_attention, "on_chip", lambda: True)
+    assert wide.paged_decode_impl() == "dsa_pallas"
+    assert wide.sparse_decode_plan()["decode_indexer_impl"] \
+        == "dsa_indexer_pallas"
+    # a row that is not words has no kernel to read it, whatever is forced
+    forced = model_for(dataclasses.replace(cfg, decode_attention="pallas"))
+    assert forced.paged_decode_impl() == "dsa_xla"
+    # kanana's fields: no indexer, the dense kernel's names
+    plain = model_for(MLAConfig.debug_kanana())
+    assert plain.paged_decode_impl() == "mla_pallas"
+    assert plain.sparse_decode_plan()["index_topk"] == 0
+
+
+# -- the router and the share -----------------------------------------------
+def test_route_topk_is_the_references_group_limited_choice():
+    rng = np.random.default_rng(3)
+    x = jnp.asarray(rng.normal(size=(64, 32)), jnp.float32)
+    router = jnp.asarray(rng.normal(size=(32, 16)), jnp.float32)
+    bias = jnp.asarray(rng.normal(size=(16,)) * 0.3, jnp.float32)
+    _, scores, weights, experts = route_topk(
+        x, router, 4, True, sigmoid_bias=bias, weight_scale=2.5,
+        groups=(4, 2))
+    want = reference.grouped_sigmoid_topk(
+        scores, bias, top_k=4, n_group=4, topk_group=2)
+    assert (np.sort(np.asarray(experts)) == np.sort(np.asarray(want))).all()
+    # at most 2 of the 4 groups of 4 neighbours, and the limit bites
+    assert (np.asarray([len(set(row // 4)) for row in np.asarray(experts)])
+            <= 2).all()
+    free = route_topk(x, router, 4, True, sigmoid_bias=bias)[3]
+    assert (np.sort(np.asarray(free)) != np.sort(np.asarray(experts))).any()
+    # the weights are s of the chosen (not s + b), renormalised, x 2.5
+    s = np.take_along_axis(np.asarray(scores), np.asarray(experts), -1)
+    np.testing.assert_allclose(weights, 2.5 * s / s.sum(-1, keepdims=True),
+                               rtol=1e-5)
+
+
+def test_the_shares_of_a_layer_add_up_to_the_uncut_layer():
+    """The guide's section 4: the routed parts that the shares (0, 4),
+    (4, 4), (8, 4), (12, 4) compute, plus the shared expert ONCE, are the
+    uncut layer's output, by the program and by the reference."""
+    cfg, whole, params = make(held=(0, 16))
+    layer = {k: v[0] for k, v in params["layers"].items()}
+    h = jax.random.normal(jax.random.key(9), (2, 24, cfg.dim), jnp.float32)
+    with jax.default_matmul_precision("highest"):
+        uncut, extra = whole._ffn(h, layer)
+        total, ref_total = jnp.zeros_like(uncut), jnp.zeros_like(uncut)
+        for first in (0, 4, 8, 12):
+            part = model_for(dataclasses.replace(
+                cfg, first_expert_held=first, experts_held=4))
+            cut = {**layer, **{n: layer[n][first:first + 4]
+                               for n in ("e_gate", "e_up", "e_down")}}
+            out, ex = part._ffn(h, cut)
+            assert (np.asarray(ex["experts"])
+                    == np.asarray(extra["experts"])).all()
+            total = total + out
+            ref_out, _ = reference._expert_block(
+                h, cut, top_k=4, n_group=4, topk_group=2,
+                norm_topk_prob=True, routed_scale=2.5, held=(first, 4),
+                forced=None, fault=None)
+            ref_total = ref_total + ref_out
+        # every share added the shared expert: count it once
+        dense = reference._swiglu(h, layer["s_gate"], layer["s_up"],
+                                  layer["s_down"])
+    np.testing.assert_allclose(total - 3 * dense, uncut, atol=1e-4)
+    np.testing.assert_allclose(ref_total - 3 * dense, uncut, atol=1e-4)
+    # a token none of whose experts is held gets the shared expert alone
+    part = model_for(dataclasses.replace(cfg, first_expert_held=0,
+                                         experts_held=4))
+    cut = {**layer, **{n: layer[n][:4]
+                       for n in ("e_gate", "e_up", "e_down")}}
+    with jax.default_matmul_precision("highest"):
+        out, ex = part._ffn(h, cut)
+    none_held = np.asarray((ex["experts"] >= 4).all(-1))
+    assert none_held.any() and not none_held.all()
+    np.testing.assert_allclose(np.asarray(out)[none_held],
+                               np.asarray(dense)[none_held], atol=1e-4)
+    assert int(ex["load"].sum()) == 2 * 24 * 4      # every choice counted
+
+
+# -- controls: each must fail ----------------------------------------------
+@pytest.fixture(scope="module")
+def served_logits():
+    cfg, model, params = make()
+    toks = seqs(cfg)
+    with jax.default_matmul_precision("highest"):
+        got = model.apply(params, toks)
+    return cfg, params, toks, got
+
+
+@pytest.mark.parametrize("fault", reference.FAULTS)
+def test_a_faulty_block_is_refused(fault, served_logits):
+    cfg, params, toks, got = served_logits
+    want = ref_forward(cfg, params, toks, fault=fault)
+    assert float(jnp.max(jnp.abs(got - want))) > 100 * F32_TOL
+    assert rel_rms(got, want) > 0.05
+
+
+# -- the parameters ---------------------------------------------------------
+def test_serving_params_keep_the_float32_leaves():
+    cfg, model, params = make(jnp.bfloat16)
+    served = model.serving_params(params)
+    f32 = {"attn_norm", "mlp_norm", "kv_norm", "q_norm", "idx_k_norm",
+           "router", "router_bias"}
+    for stack in ("layers", "leading_layers"):
+        for name, a in served[stack].items():
+            assert a.dtype == (jnp.float32 if name in f32
+                               else jnp.bfloat16), (stack, name)
+    assert served["layers"]["e_gate"].shape[:2] == (2, HELD[1])
+    assert served["layers"]["router"].shape == (2, cfg.dim, 16)
+    assert float(jnp.std(served["layers"]["router_bias"])) > 0   # drawn
+    assert cfg.num_params() == sum(a.size for a in jax.tree.leaves(params))
+
+
+@pytest.mark.parametrize("method,scopes", [
+    ("forward_step", ("mla_q_down", "mla_q_up", "dsa_indexer_q",
+                      "dsa_indexer_k", "dsa_indexer_scores", "dsa_select",
+                      "dsa_masked_attention", "mla_kv_down", "mla_kv_up",
+                      "moe_group_limit", "moe_router", "moe_shared_expert",
+                      "dense_ffn_leading")),
+    ("decode_step_paged", ("mla_q_down", "mla_q_up", "dsa_indexer_q",
+                           "dsa_indexer_k", "dsa_indexer_scores",
+                           "dsa_select", "dsa_attention", "mla_q_absorb",
+                           "mla_v_up", "moe_group_limit", "moe_router"))])
+def test_scopes_are_in_the_lowered_programs_metadata(method, scopes):
+    cfg, model, params = make()
+    two = jnp.zeros((2,), I32)
+    args = {"forward_step": (params, jnp.ones((2, 16), I32),
+                             model.init_kv_cache(2, 16), two),
+            "decode_step_paged": (params, two, model.init_kv_pool(9, 8),
+                                  jnp.zeros((2, 4), I32), two)}[method]
+    text = jax.jit(getattr(model, method)).lower(*args).as_text(
+        debug_info=True)
+    for scope in scopes:
+        assert scope in text, scope
+    assert "mla_q_proj" not in text
+
+
+def test_the_config_refuses_what_it_cannot_be():
+    with pytest.raises(ValueError, match="indexer"):
+        MLAConfig.debug_deepseek_v32(q_lora_rank=None)
+    with pytest.raises(ValueError, match="group limit"):
+        MLAConfig.debug_deepseek_v32(router_n_group=3)
+    with pytest.raises(ValueError, match="group limit"):
+        MLAConfig.debug_deepseek_v32(router_topk_group=1, expert_top_k=6)
+    with pytest.raises(ValueError, match="router's"):
+        MLAConfig.debug_deepseek_v32(first_expert_held=12, experts_held=8)
+    from ray_tpu.ops.rope import YarnScaling
+    with pytest.raises(ValueError, match="yarn"):
+        MLAConfig.debug_deepseek_v32(rope_scaling=(
+            ("full_attention", YarnScaling(8.0, 16)),))

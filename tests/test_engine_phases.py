@@ -296,12 +296,19 @@ def test_stats_keys_are_fixed_plain_monotone_and_the_phases_sum_to_the_step():
         # empty for this dense one: tests/test_olmoe_serving.py) and the
         # names of what implements its grouped matmuls and of its router
         # (strings, empty for this dense one:
-        # tests/test_moe_grouped_matmul.py, tests/test_kanana_serving.py)
+        # tests/test_moe_grouped_matmul.py, tests/test_kanana_serving.py),
+        # and since PR 43 the names of what implements the decode step's
+        # attention (every engine's), index scores and selection (empty
+        # but for learned sparse attention:
+        # tests/test_deepseek_v32_engine.py)
         assert first["moe_expert_load"] == []
         named = {"moe_grouped_impl", "moe_gmm_tiling_gate",
                  "moe_gmm_tiling_up", "moe_gmm_tiling_down",
-                 "moe_router_kind"}
+                 "moe_router_kind", "decode_indexer_impl",
+                 "decode_select_impl"}
         assert all(first[k] == "" for k in named)
+        assert first["decode_attention_impl"] == eng.decode_attention_impl
+        named.add("decode_attention_impl")
         assert all(type(v) in (int, float) for k, v in first.items()
                    if k != "moe_expert_load" and k not in named)
         snaps = [first]

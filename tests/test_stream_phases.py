@@ -376,11 +376,14 @@ def test_stats_keys_are_there_from_construction_and_json_plain():
     for snap in snaps:
         assert set(snap) == set(first)
         assert json.loads(json.dumps(snap)) == snap
-        # numbers, but for an expert model's rows (a list) and the names
-        # of what implements its grouped matmuls and of its router
-        # (strings): all empty for this dense model
+        # numbers, but for an expert model's rows (a list), the names of
+        # what implements its grouped matmuls and of its router (strings:
+        # all empty for this dense model) and, since PR 43, the names of
+        # what implements the decode step's attention, index scores and
+        # selection (``decode_*_impl``)
         assert all(type(v) in (int, float) for k, v in snap.items()
                    if k != "moe_expert_load"
+                   and not k.endswith("_impl")
                    and not k.startswith(("moe_grouped_", "moe_gmm_",
                                          "moe_router_")))
     assert snaps[1]["stream_puts"] - snaps[0]["stream_puts"] == len(chunks)

@@ -52,6 +52,15 @@ decode step's 192 rows and a 512-token chunk's 3,072, the layer's three
 calls on the whole stack at the last layer's offset, on the resolver's
 choice and on ``ragged_dot``.
 
+**``held`` (PR 43)**: an expert layer that HOLDS A SHARE of its router's
+experts (deepseek-v3.2-d5: 16 of 256 experts of 7168 x 2048, top-8, 4
+expert layers): a decode step's 128 assignments (16 slots), of which ~8
+land on held experts and the rest belong to no group, and a 512-token
+chunk's 4,096, of which ~256 land; the layer's three calls on the whole
+stack of 4 x 16 groups at the last layer's offset, on the resolver's
+choice and on ``ragged_dot``: what ``grouped_matmul_impl`` picks at this
+width and this few rows, as a reading and no more.
+
 Prints one JSON line per reading.
 """
 
@@ -343,6 +352,40 @@ def chosen(name="kanana-2-30b-a3b-d5", L=4, experts=128, d=2048, f=768,
         print(json.dumps(line), flush=True)
 
 
+def held(name="deepseek-v3.2-d5", L=4, router=256, n_held=16, d=7168, f=2048,
+         top_k=8, rows_asked=(128, 4096)):
+    sys.path.insert(0, os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))))
+    from ray_tpu.ops.moe_dispatch import grouped_matmul, grouped_matmul_impl
+
+    rng = np.random.default_rng(0)
+    keys = jax.random.split(jax.random.key(0), 3)
+    wg, wu, wd = (jax.random.normal(k, (L * n_held,) + shape, jnp.bfloat16)
+                  * 0.02 for k, shape in zip(keys, ((d, f), (d, f), (f, d))))
+    for rows in rows_asked:
+        idx = np.stack([rng.permutation(router)[:top_k]
+                        for _ in range(rows // top_k)]).ravel()
+        sizes = np.zeros(L * n_held, np.int32)
+        sizes[(L - 1) * n_held:] = np.bincount(idx[idx < n_held],
+                                               minlength=n_held)
+        xs = jax.random.normal(jax.random.key(rows), (rows, d), jnp.bfloat16)
+        line = {"shapes": name, "groups": L * n_held, "rows": rows,
+                "rows_in_a_group": int(sizes.sum()),
+                "experts_with_rows": int((sizes > 0).sum()), "call": "ffn",
+                "at": "last"}
+        for call, (k, n) in (("gate_up", (d, f)), ("down", (f, d))):
+            impl, tiling = grouped_matmul_impl(rows, k, n, 2)
+            line[call] = {"impl": impl, "tiling": tiling}
+        reps = 200 if rows <= 256 else 50
+        for impl, grouped in (
+                ("resolver", lambda a, w, s: grouped_matmul(
+                    a, w, s, jnp.bfloat16)), ("ragged_dot", ragged)):
+            line["ms_" + impl] = timed(ffn(grouped),
+                                       (xs, wg, wu, wd, jnp.asarray(sizes)),
+                                       reps)
+        print(json.dumps(line), flush=True)
+
+
 def main():
     if jax.devices()[0].platform != "tpu":
         sys.exit("moe_gmm_bench: no TPU; a CPU time is not a reading")
@@ -352,6 +395,8 @@ def main():
         tilings(tuple(int(r) for r in sys.argv[2:]))
     elif sys.argv[1:] == ["chosen"]:
         chosen()
+    elif sys.argv[1:] == ["held"]:
+        held()
     else:
         whole_stack()
 
