@@ -991,7 +991,8 @@ class LlamaModel:
             L, NB, bs = pool["k"].shape[:3]
 
         def whole(a):           # [L, NB, bs, ...] as the stack [L*NB, ...]
-            return a if NB is None else a.reshape((-1,) + a.shape[2:])
+            # (the size spelled out: a row may be empty, ``MLAModel``'s "v")
+            return a if NB is None else a.reshape((L * NB,) + a.shape[2:])
 
         kinds = self._kinds_xs()
         if kinds is not None and block_tables.ndim == 2:

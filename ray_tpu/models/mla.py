@@ -49,9 +49,13 @@ none changing a program of a model without it:
   no more). THE CACHE ROW gains a third part: ``"v"`` holds ``k_pe`` in
   its first lane tile and ``k_I`` in the lanes after it. In bf16 at
   widths that fill lane tiles (the chip's) the row is held AS 32-BIT
-  WORDS, two numbers a word, ``"k"`` [2, 128] and ``"v"`` [128] uint32
-  (``word_rows``; ``ops/dsa.py`` says why: a kernel can then copy ONE
-  row by its number); the bytes are the same 1,536. The prefills
+  WORDS, two numbers a word, and ALL OF IT UNDER ``"k"``: [4, 128]
+  uint32 = the sub-row of ``k_pe | k_I``, ``c``'s two, one spare;
+  ``"v"`` is a zero-width row (``word_rows``; ``ops/dsa.py`` says why:
+  a kernel can then copy what the attention reads of ONE row by its
+  number with ONE copy, and a copy's cost is its start, not its bytes;
+  XLA lays a run of sub-rows out token by token only where they are a
+  power of two, hence the spare: 2,048 B a token for 1,536). The prefills
   select as a MASK over the expanded form's rows (a block of queries at
   a time: index scores, the exact k-th largest as a threshold, the
   masked softmax); the decode step computes ``I`` over each slot's pages,
@@ -249,11 +253,13 @@ class MLAModel(MoEModel):
     def kv_row_shapes(self) -> Tuple[Tuple[int, ...], Tuple[int, ...]]:
         """``"k"``: the latent row ``c``; ``"v"``: ``k_pe`` in the first
         lanes of a zero lane tile and, with an indexer, the index key
-        ``k_I`` in the lanes after it."""
+        ``k_I`` in the lanes after it. As words the row is ONE run of
+        sub-rows under ``"k"`` (``k_pe | k_I``'s, then ``c``'s, zeros up
+        to a power of two) and ``"v"`` holds nothing."""
         cfg: MLAConfig = self.cfg
         if self.word_rows:
-            return ((cfg.kv_lora_rank // (2 * dsa.WORD_LANES),
-                     dsa.WORD_LANES), (dsa.WORD_LANES,))
+            return ((dsa.word_row_subrows(cfg.kv_lora_rank), dsa.WORD_LANES),
+                    (0,))
         idx = cfg.index_head_dim if self.indexed else 0
         return (cfg.kv_lora_rank,), (self.pe_lanes + idx,)
 
@@ -263,35 +269,42 @@ class MLAModel(MoEModel):
 
     def _rows_of(self, c, k_pe, k_idx):
         """An indexed model's cache rows ``("k", "v")`` of what they
-        hold: the third part rides in ``"v"``, behind ``k_pe``'s lane
-        tile; as words where the row is held so."""
+        hold: the third part rides behind ``k_pe``'s lane tile, in ``"v"``
+        or, where the row is held as words, in the first sub-row of
+        ``"k"``."""
         if not self.word_rows:
             return c, jnp.concatenate([k_pe, k_idx], axis=-1)
-        return (dsa.pack_words(c).reshape(*c.shape[:-1],
-                                          *self.kv_row_shapes()[0]),
-                jnp.concatenate([dsa.pack_words(k_pe),
-                                 dsa.pack_words(k_idx)], axis=-1))
+        lead, (row, _) = c.shape[:-1], self.kv_row_shapes()
+        words = jnp.concatenate([dsa.pack_words(k_pe), dsa.pack_words(k_idx),
+                                 dsa.pack_words(c)], axis=-1)
+        spare = math.prod(row) - words.shape[-1]
+        return (jnp.pad(words, [(0, 0)] * len(lead) + [(0, spare)]
+                        ).reshape(*lead, *row),
+                jnp.zeros((*lead, 0), jnp.uint32))
 
     def _c_of(self, k_rows):
         """``c`` [..., R] out of rows of ``"k"``."""
         if not self.word_rows:
             return k_rows
-        return dsa.unpack_words(k_rows.reshape(*k_rows.shape[:-2], -1),
-                                self.cfg.dtype)
+        n_sub = self.cfg.kv_lora_rank // (2 * dsa.WORD_LANES)
+        c = k_rows[..., 1:1 + n_sub, :]
+        return dsa.unpack_words(c.reshape(*c.shape[:-2], -1), self.cfg.dtype)
 
-    def _keys_of(self, v_rows):
-        """``(k_pe [..., pe_lanes], k_I [..., Di])`` out of rows of
+    def _keys_of(self, rows):
+        """``(k_pe [..., pe_lanes], k_I [..., Di])`` out of rows of the
+        array that holds them: ``"k"`` where the row is words, else
         ``"v"``."""
         if not self.word_rows:
-            return v_rows[..., :self.pe_lanes], v_rows[..., self.pe_lanes:]
-        dt = self.cfg.dtype
-        return (dsa.unpack_words(v_rows[..., :dsa.PE_WORDS], dt),
-                dsa.unpack_words(v_rows[..., dsa.PE_WORDS:], dt))
+            return rows[..., :self.pe_lanes], rows[..., self.pe_lanes:]
+        dt, keys = self.cfg.dtype, rows[..., 0, :]
+        return (dsa.unpack_words(keys[..., :dsa.PE_WORDS], dt),
+                dsa.unpack_words(keys[..., dsa.PE_WORDS:], dt))
 
     def _row_parts(self, k_rows, v_rows):
         """``_rows_of``'s inverse: ``(c, k_pe, k_I)`` in the compute
         dtype."""
-        return (self._c_of(k_rows), *self._keys_of(v_rows))
+        return (self._c_of(k_rows),
+                *self._keys_of(k_rows if self.word_rows else v_rows))
 
     def _rope_pe(self, x, positions):
         """The rotary part x [B, T, heads, rope] turned by its
@@ -491,8 +504,9 @@ class MLAModel(MoEModel):
             side = impl.removeprefix("dsa_")
             with jax.named_scope("dsa_indexer_scores"):
                 scores = dsa.indexer_scores(
-                    q_idx, w_idx, pe_pool, block_tables, lengths, impl=side,
-                    key_of=lambda v: self._keys_of(v)[1],
+                    q_idx, w_idx, c_pool if self.word_rows else pe_pool,
+                    block_tables, lengths, impl=side,
+                    key_of=lambda rows: self._keys_of(rows)[1],
                     first_block=first_block)
             with jax.named_scope("dsa_select"):
                 rows, count = dsa.select_topk(scores, lengths,
@@ -501,7 +515,7 @@ class MLAModel(MoEModel):
                 o_lat = dsa.sparse_decode_attention(
                     q_lat, q_pe, c_pool, pe_pool, block_tables, rows, count,
                     impl=side, scale=cfg.softmax_scale,
-                    parts_of=lambda k, v: (self._c_of(k), self._keys_of(v)[0]),
+                    parts_of=lambda k, v: self._row_parts(k, v)[:2],
                     first_block=first_block)
             with jax.named_scope("mla_v_up"):
                 return jnp.einsum("bhr,hrv->bhv", o_lat,

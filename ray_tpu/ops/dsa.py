@@ -14,33 +14,49 @@ anywhere; ``models/mla.py`` resolves which runs (``paged_decode_impl``):
 
 - THE ROW ON THE CHIP is 32-bit WORDS, two bf16 numbers a word
   (``pack_words``: element ``j`` of a part in the low half of word ``j``,
-  element ``j + n/2`` in the high half), in sub-rows of 128 words:
-  ``"k"`` [..., 2, 128] holds ``c`` (1 KB a token, contiguous), ``"v"``
-  [..., 128] holds ``k_pe`` with its padding (64 words: ``k_pe`` is
-  their low halves) and ``k_I`` (64 words). Why: a kernel that reads ROWS BY NUMBER copies one row a DMA,
-  and Mosaic slices an HBM array one row at a time only where its rows
-  are contiguous, which on the chip's tiled layouts is 32-bit rows of
-  exactly 128 lanes ("Slice shape along dimension 0 must be aligned to
-  tiling (8)" for [N, 512] bf16 and for [N, 256] uint32 alike); XLA's own
-  row gather took 59 ms a layer at the cell's shape, 1.8 us a row
-  (PERF.md, PR 43). A kernel unpacks a word with a shift and a mask and
-  never shuffles lanes: the QUERY is laid out to match (zeros where a
-  word holds another part). Off the chip's dtype and widths the row is
-  the plain ``c`` and ``k_pe | k_I`` and the XLA forms run.
+  element ``j + n/2`` in the high half), in sub-rows of 128 words, ALL
+  UNDER ``"k"`` [..., 4, 128]: sub-row 0 holds ``k_pe`` with its padding
+  (64 words: ``k_pe`` is their low halves) and ``k_I`` (64 words),
+  sub-rows 1 and 2 hold ``c``, sub-row 3 is spare; ``"v"`` is a
+  zero-width row. Why words: a kernel that reads ROWS BY NUMBER copies
+  one row a DMA, and Mosaic slices an HBM array one row at a time only
+  where its rows are contiguous, which on the chip's tiled layouts is
+  32-bit rows of exactly 128 lanes, or a run of such sub-rows seen as
+  ``[tokens, sub, 1, 128]`` ("Slice shape along dimension 0 must be
+  aligned to tiling (8)" for [N, 512] bf16 and for [N, 256] uint32
+  alike); XLA's own row gather took 59 ms a layer at the cell's shape,
+  1.8 us a row (PERF.md, PR 43). Why ONE array (PR 44): a copy costs ~17
+  ns to START whatever its bytes (512 B and 2,048 B alike; a wait ~3 ns;
+  ``tools/dsa_row_copy_bench.py``), so what the attention reads of a
+  token is one contiguous run and one copy; PR 43 held ``c`` under "k"
+  and ``k_pe | k_I`` under "v", two copies and two waits a row. Why the
+  spare sub-row, 2,048 B a token for 1,536: XLA lays ``[..., n, 128]``
+  uint32 out token by token, in tiles of (n, 128), only where ``n`` is a
+  power of two (``word_row_subrows``); three sub-rows it holds
+  sub-row-major as a parameter and pads to four for the decode step's
+  scatter, with two copies of the whole pool a step (so do ``[..., 3, 1,
+  128]`` and ``[..., 1, 384]``: PERF.md, PR 44). A kernel unpacks a word
+  with a shift and a mask and never shuffles lanes: the QUERY is laid
+  out to match (zeros where a word holds another part). Off the chip's
+  dtype and widths the row is the plain ``c`` and ``k_pe | k_I`` and the
+  XLA forms run.
 - ``indexer_scores`` (decode): ``I[slot, 0..len)`` through the block
-  table: the Mosaic kernel walks a slot's LIVE pages of ``"v"`` as
-  ``ops/mla_attention.py``'s walks its pages (512 B a row of the 1,536);
-  the XLA twin gathers every table entry.
+  table: the Mosaic kernel walks a slot's LIVE pages as
+  ``ops/mla_attention.py``'s walks its pages, copying of every token of
+  a page the keys' sub-row alone, ONE strided copy a page (32 runs of
+  512 B, 2,048 B apart: as fast as one run of 16 KB, same tool); the XLA
+  twin gathers every table entry.
 - the selection. ``topk_mask`` (prefill: a mask over rows for a block of
   queries, from the exact k-th largest score found by a radix search over
   the float's bits, 32 counting passes and no sort) and ``select_topk``
   (decode: row indices, ``jax.lax.top_k``). TIES go to the EARLIER
   position in both, so the two agree row for row.
 - ``sparse_decode_attention``: the absorbed attention over the selected
-  rows ALONE. The Mosaic kernel copies each selected row out of the pools
-  by its number, two DMAs a row (``c``'s two sub-rows as one, ``"v"``'s
-  one), into VMEM, then scores, softmax and values of all heads in one
-  pass; the XLA twin gathers the same rows.
+  rows ALONE. The Mosaic kernel copies each selected row out of the pool
+  by its number, ONE DMA a row (the keys' sub-row and ``c``'s two), into
+  VMEM, waits ONCE for the whole buffer (a DMA semaphore counts bytes),
+  then scores, softmax and values of all heads in one pass; the XLA twin
+  gathers the same rows.
 """
 
 from __future__ import annotations
@@ -61,7 +77,13 @@ CHUNK_ROWS = 2048
 
 # -- the row as words -------------------------------------------------------
 WORD_LANES = 128        # words of a sub-row: one lane tile of 32-bit lanes
-PE_WORDS = 64           # words of "v" before the index key's (k_pe, zeros)
+PE_WORDS = 64           # words of the keys' sub-row before k_I's (k_pe, zeros)
+
+
+def word_row_subrows(latent: int) -> int:
+    """Sub-rows of a token's row of words: the keys' one and ``latent /
+    256`` of ``c``, rounded up to a power of two (module docstring)."""
+    return 1 << (latent // (2 * WORD_LANES)).bit_length()
 
 
 def pack_words(x):
@@ -122,7 +144,7 @@ def indexer_scores_reference(q_idx, w, v_pool, block_tables, lengths, *,
     return jnp.where(live, scores, NEG_INF)
 
 
-def _indexer_kernel(lens_ref, tables_ref, q_ref, w_ref, v_hbm, o_ref, k_buf,
+def _indexer_kernel(lens_ref, tables_ref, q_ref, w_ref, k_hbm, o_ref, k_buf,
                     sems, first_buf_ref, *, block_size: int, pages: int,
                     max_blocks: int):
     import jax.experimental.pallas as pl
@@ -137,7 +159,9 @@ def _indexer_kernel(lens_ref, tables_ref, q_ref, w_ref, v_hbm, o_ref, k_buf,
                         max_blocks)
 
     def chunk_copies(slot, chunk, buf, act: str):
-        """``act`` ("start" or "wait") on one chunk's page copies."""
+        """``act`` ("start" or "wait") on one chunk's page copies: of
+        every token of a page its FIRST sub-row, the keys', as ONE
+        strided copy (a page's 32 runs of 512 B, a row's bytes apart)."""
         n_live = live_pages(slot)
         for i in range(pages):
             j = chunk * pages + i
@@ -145,7 +169,7 @@ def _indexer_kernel(lens_ref, tables_ref, q_ref, w_ref, v_hbm, o_ref, k_buf,
             @pl.when(j < n_live)
             def _():
                 getattr(pltpu.make_async_copy(
-                    v_hbm.at[tables_ref[slot, j]],
+                    k_hbm.at[tables_ref[slot, j], :, 0, 0],
                     k_buf.at[buf, pl.ds(i * block_size, block_size)],
                     sems.at[buf]), act)()
 
@@ -191,22 +215,24 @@ def _indexer_kernel(lens_ref, tables_ref, q_ref, w_ref, v_hbm, o_ref, k_buf,
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
-def indexer_scores_pallas(q_idx, w, v_pool, block_tables, lengths, *,
+def indexer_scores_pallas(q_idx, w, k_pool, block_tables, lengths, *,
                           first_block=0, interpret: bool = False):
-    """``v_pool`` [NB, bs, 128] uint32, the rows as words."""
+    """``k_pool`` [NB, bs, sub-rows, 128] uint32, the rows as words."""
     import jax.experimental.pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
     B, Hi, Di = q_idx.shape
-    bs = v_pool.shape[1]
+    bs = k_pool.shape[1]
     half = Di // 2
-    if (v_pool.dtype != jnp.uint32 or v_pool.shape[2:] != (WORD_LANES,)
+    if (k_pool.dtype != jnp.uint32 or k_pool.ndim != 4
+            or k_pool.shape[3] != WORD_LANES
             or PE_WORDS + half != WORD_LANES):
         raise ValueError(
-            f"the indexer kernel reads rows of {WORD_LANES} words whose "
-            f"last {WORD_LANES - PE_WORDS} are an index key of "
-            f"{2 * (WORD_LANES - PE_WORDS)} numbers, got {v_pool.dtype}"
-            f"{v_pool.shape[2:]} and keys of {Di}; use the XLA twin")
+            f"the indexer kernel reads rows of sub-rows of {WORD_LANES} "
+            f"words, the last {WORD_LANES - PE_WORDS} words of the first an "
+            f"index key of {2 * (WORD_LANES - PE_WORDS)} numbers, got "
+            f"{k_pool.dtype}{k_pool.shape[2:]} and keys of {Di}; use the "
+            f"XLA twin")
     block_tables = block_tables.astype(jnp.int32) + first_block
     maxb = block_tables.shape[1]
     pages = max(1, min(maxb, CHUNK_ROWS // bs))
@@ -240,24 +266,29 @@ def indexer_scores_pallas(q_idx, w, v_pool, block_tables, lengths, *,
             dimension_semantics=("arbitrary",)),
         interpret=interpret,
     )(lengths.astype(jnp.int32), block_tables, q_parts.astype(jnp.bfloat16),
-      w.astype(jnp.float32)[..., None], v_pool)
+      w.astype(jnp.float32)[..., None],
+      # a token's sub-rows, each a row of its own: [NB, bs, sub, 1, 128]
+      k_pool.reshape(*k_pool.shape[:3], 1, WORD_LANES))
     return out.reshape(B, n_chunks * chunk_rows)[:, :maxb * bs]
 
 
-def indexer_scores(q_idx, w, v_pool, block_tables, lengths, *, impl: str,
+def indexer_scores(q_idx, w, key_pool, block_tables, lengths, *, impl: str,
                    key_of, first_block=0):
     """``I`` [B, MAXB * bs] float32 of one query a slot against the slot's
-    cached index keys; ``NEG_INF`` at and past its length. ``impl`` is
-    "pallas" (rows as words) or "xla" (any row, read by ``key_of``);
-    ``first_block`` as in ``mla_decode_attention``."""
+    cached index keys; ``NEG_INF`` at and past its length. ``key_pool`` is
+    the pool that holds the keys; ``impl`` is "pallas" (rows as words) or
+    "xla" (any row, read by ``key_of``); ``first_block`` as in
+    ``mla_decode_attention``."""
     if impl == "pallas":
         return indexer_scores_pallas(
-            q_idx, w, v_pool, block_tables, lengths, first_block=first_block,
+            q_idx, w, key_pool, block_tables, lengths,
+            first_block=first_block,
             interpret=paged_attention.pallas_interpret())
     if impl != "xla":
         raise ValueError(f"impl must be 'xla' or 'pallas', got {impl!r}")
     return indexer_scores_reference(
-        q_idx, w, v_pool, first_block + block_tables, lengths, key_of=key_of)
+        q_idx, w, key_pool, first_block + block_tables, lengths,
+        key_of=key_of)
 
 
 # -- the selection ----------------------------------------------------------
@@ -319,9 +350,11 @@ def selected_attention_reference(q_lat, q_pe, k_pool, v_pool, flat, count, *,
     ``parts_of`` (the model's: rows of "k", rows of "v" -> c [B, K, R],
     k_pe [B, K, P]); the first ``count`` [B] of them are real. q_lat [B,
     H, R], q_pe [B, H, P] -> o_lat [B, H, R]."""
-    c, pe = parts_of(
-        k_pool.reshape(-1, *k_pool.shape[2:])[flat],
-        v_pool.reshape(-1, *v_pool.shape[2:])[flat])
+    def rows(pool):           # (the size spelled out: "v" may be empty)
+        return pool.reshape(pool.shape[0] * pool.shape[1],
+                            *pool.shape[2:])[flat]
+
+    c, pe = parts_of(rows(k_pool), rows(v_pool))
     s = (jnp.einsum("bhr,bkr->bhk", q_lat, c,
                     preferred_element_type=jnp.float32)
          + jnp.einsum("bhp,bkp->bhk", q_pe, pe,
@@ -332,24 +365,14 @@ def selected_attention_reference(q_lat, q_pe, k_pool, v_pool, flat, count, *,
                       preferred_element_type=jnp.float32).astype(q_lat.dtype)
 
 
-def _selected_kernel(count_ref, rows_ref, q_ref, k_hbm, v_hbm, o_ref, c_buf,
-                     v_buf, sems, *, scale: float, n_sub: int):
+def _selected_kernel(count_ref, rows_ref, q_ref, k_hbm, o_ref, buf, sem, *,
+                     scale: float, n_sub: int):
     import jax.experimental.pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
     b = pl.program_id(0)
     n = count_ref[b]
-    K = v_buf.shape[0]
-
-    def copies(i, row):
-        """Row ``row`` of the pools to place ``i`` of the buffers: the
-        token's ``n_sub`` sub-rows of ``c`` as one copy (they are
-        contiguous), its row of "v" as another."""
-        return (pltpu.make_async_copy(k_hbm.at[row],
-                                      c_buf.at[:, pl.ds(i, 1), :],
-                                      sems.at[0]),
-                pltpu.make_async_copy(v_hbm.at[pl.ds(row, 1), :],
-                                      v_buf.at[pl.ds(i, 1), :], sems.at[1]))
+    K = buf.shape[1]
 
     # ``group`` rows an iteration (Mosaic unrolls a loop wholly or not
     # at all): fewer branches between the copies' descriptors
@@ -357,28 +380,31 @@ def _selected_kernel(count_ref, rows_ref, q_ref, k_hbm, v_hbm, o_ref, c_buf,
 
     def start(i, carry):
         for j in range(group):
-            for copy in copies(i * group + j, rows_ref[b, i * group + j]):
-                copy.start()
-        return carry
-
-    def wait(i, carry):
-        for _ in range(group):
-            for copy in copies(0, 0):
-                copy.wait()
+            at = i * group + j
+            # what the attention reads of a token, the keys' sub-row and
+            # ``c``'s, is one run of the pool: ONE copy, each sub-row to
+            # row ``at`` of its plane of the buffer
+            pltpu.make_async_copy(
+                k_hbm.at[rows_ref[b, at], pl.ds(0, n_sub + 1)],
+                buf.at[:, pl.ds(at, 1), :], sem).start()
         return carry
 
     jax.lax.fori_loop(0, K // group, start, 0)
-    jax.lax.fori_loop(0, K // group, wait, 0)
+    # all K rows are in flight, whatever ``n`` (rows past it are copied and
+    # masked), and a DMA semaphore counts BYTES: ONE wait, on a descriptor
+    # of the buffer's own size, returns when every row is in
+    pltpu.make_async_copy(buf, buf, sem).wait()
 
     contract_lanes = (((1,), (1,)), ((), ()))
     # planes of c in the order of its lanes: (half, sub-row)
     planes = [None] * (2 * n_sub)
     for sub in range(n_sub):
-        planes[sub], planes[n_sub + sub] = _planes(c_buf[sub])
-    # k_pe is the low halves of "v"'s first words (their high halves are
-    # its zero padding); the query is zero at the index key's lanes
+        planes[sub], planes[n_sub + sub] = _planes(buf[1 + sub])
+    # k_pe is the low halves of the keys' sub-row's first words (their
+    # high halves are its zero padding); the query is zero at the index
+    # key's lanes
     s = jnp.zeros((q_ref.shape[2], K), jnp.float32)
-    for p, plane in enumerate(planes + [_planes(v_buf[...])[0]]):
+    for p, plane in enumerate(planes + [_planes(buf[0])[0]]):
         s = s + jax.lax.dot_general(q_ref[0, p], plane, contract_lanes,
                                     preferred_element_type=jnp.float32)
     at = jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
@@ -394,10 +420,10 @@ def _selected_kernel(count_ref, rows_ref, q_ref, k_hbm, v_hbm, o_ref, c_buf,
 
 
 @functools.partial(jax.jit, static_argnames=("scale", "interpret"))
-def selected_attention_pallas(q_lat, q_pe, k_pool, v_pool, flat, count, *,
+def selected_attention_pallas(q_lat, q_pe, k_pool, flat, count, *,
                               scale: float, interpret: bool = False):
-    """``k_pool`` [NB, bs, n_sub, 128] and ``v_pool`` [NB, bs, 128]
-    uint32, the rows as words; ``flat`` [B, K] row numbers."""
+    """``k_pool`` [NB, bs, sub-rows, 128] uint32, the rows as words;
+    ``flat`` [B, K] row numbers."""
     import jax.experimental.pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
@@ -405,14 +431,13 @@ def selected_attention_pallas(q_lat, q_pe, k_pool, v_pool, flat, count, *,
     K = flat.shape[1]
     n_sub = R // (2 * WORD_LANES)
     if (k_pool.dtype != jnp.uint32
-            or k_pool.shape[2:] != (n_sub, WORD_LANES)
-            or v_pool.shape[2:] != (WORD_LANES,)
+            or k_pool.shape[2:] != (word_row_subrows(R), WORD_LANES)
             or q_pe.shape[-1] != 2 * PE_WORDS):
         raise ValueError(
-            f"the sparse attention kernel reads rows of words, c in "
-            f"sub-rows of {WORD_LANES}, got {k_pool.dtype}"
-            f"{k_pool.shape[2:]} and {v_pool.shape[2:]} for a latent of "
-            f"{R}; use the XLA twin")
+            f"the sparse attention kernel reads rows of words, "
+            f"{word_row_subrows(R)} sub-rows of {WORD_LANES} for a latent "
+            f"of {R}, got {k_pool.dtype}{k_pool.shape[2:]}; use the XLA "
+            f"twin")
     # the query in the planes' order and lanes: c's 2 * n_sub, then
     # k_pe's (zeros where a word of "v" holds the index key)
     q_parts = jnp.concatenate([
@@ -425,13 +450,11 @@ def selected_attention_pallas(q_lat, q_pe, k_pool, v_pool, flat, count, *,
         grid=(B,),
         in_specs=[pl.BlockSpec((1, n_parts, H, WORD_LANES),
                                lambda b, *_: (b, 0, 0, 0)),
-                  pl.BlockSpec(memory_space=pl.ANY),
                   pl.BlockSpec(memory_space=pl.ANY)],
         out_specs=pl.BlockSpec((1, 2 * n_sub, H, WORD_LANES),
                                lambda b, *_: (b, 0, 0, 0)),
-        scratch_shapes=[pltpu.VMEM((n_sub, K, WORD_LANES), jnp.uint32),
-                        pltpu.VMEM((K, WORD_LANES), jnp.uint32),
-                        pltpu.SemaphoreType.DMA((2,))],
+        scratch_shapes=[pltpu.VMEM((n_sub + 1, K, WORD_LANES), jnp.uint32),
+                        pltpu.SemaphoreType.DMA(())],
     )
     out = pl.pallas_call(
         functools.partial(_selected_kernel, scale=scale, n_sub=n_sub),
@@ -443,9 +466,8 @@ def selected_attention_pallas(q_lat, q_pe, k_pool, v_pool, flat, count, *,
         interpret=interpret,
     )(count.astype(jnp.int32), flat.astype(jnp.int32),
       q_parts.astype(jnp.bfloat16),
-      # a token's sub-rows, each a row of its own: [tokens, n_sub, 1, 128]
-      k_pool.reshape(-1, n_sub, 1, WORD_LANES),
-      v_pool.reshape(-1, WORD_LANES))
+      # a token's sub-rows, each a row of its own: [tokens, sub, 1, 128]
+      k_pool.reshape(-1, k_pool.shape[2], 1, WORD_LANES))
     return jnp.moveaxis(out, 1, 2).reshape(B, H, R)
 
 
@@ -456,9 +478,9 @@ def sparse_decode_attention(q_lat, q_pe, k_pool, v_pool, block_tables, rows,
     ``select_topk`` chose: q_lat [B, H, R], q_pe [B, H, P] -> o_lat [B,
     H, R]. No row that was not selected is read."""
     flat = flat_rows(block_tables, rows, k_pool.shape[1], first_block)
-    if impl == "pallas":
+    if impl == "pallas":                # rows as words: "k" holds all
         return selected_attention_pallas(
-            q_lat, q_pe, k_pool, v_pool, flat, count, scale=scale,
+            q_lat, q_pe, k_pool, flat, count, scale=scale,
             interpret=paged_attention.pallas_interpret())
     if impl != "xla":
         raise ValueError(f"impl must be 'xla' or 'pallas', got {impl!r}")

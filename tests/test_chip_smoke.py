@@ -495,38 +495,41 @@ DSA_CELL_SLOTS, DSA_CELL_TABLE, DSA_TOPK = 16, 704, 2048
 
 def test_dsa_kernels_lower_at_the_dsa_cells_shape(v5e):
     """The two Mosaic kernels of ``ops/dsa.py`` for the v5e, no chip: the
-    index scores through the block table (pages of "v", [32, 128] words)
-    and the absorbed attention that copies 2,048 selected rows a slot by
-    their numbers (a row of "k" [2, 128] words and one of "v" [128] a
-    DMA each, out of stacks of five layers' windows). The pools are what
-    the arguments hold: 1,536 B a row and nothing padded. The selection
-    between them is XLA's: ``top_k`` at 2,048 of 22,528 lowers to a
-    ``sort``, the name ``dsa.indexer_roofline.decode`` reads."""
+    index scores through the block table (of every token of a page the
+    keys' sub-row, [32, 128] words: ONE strided copy a page) and the
+    absorbed attention that copies 2,048 selected rows a slot by their
+    numbers (the first three of a row's [4, 128] words, ONE DMA, out of a
+    stack of five layers' windows). The pool is what the arguments hold:
+    2,048 B a row, the layout's own spare sub-row and no padding beyond
+    it, and it reaches both kernels as it lies. The selection between them is XLA's: ``top_k`` at 2,048 of
+    22,528 lowers to a ``sort``, the name ``dsa.indexer_roofline.decode``
+    reads."""
     from ray_tpu.ops import dsa
 
     B, bs, maxb, L = DSA_CELL_SLOTS, CELL_BS, DSA_CELL_TABLE, 5
     blocks = L * (B * maxb + 1)
     u32, i32 = jnp.uint32, jnp.int32
+    pool = v5e(blocks, bs, 4, 128, dtype=u32)
+
+    def moved(compiled):
+        """The uint32 operands copied into another layout on the way."""
+        return [line for line in compiled.as_text().splitlines()
+                if " copy(" in line and "u32[" in line]
+
     indexer = dsa.indexer_scores_pallas.lower(
-        v5e(B, 64, 128), v5e(B, 64, dtype=jnp.float32),
-        v5e(blocks, bs, 128, dtype=u32), v5e(B, maxb, dtype=i32),
-        v5e(B, dtype=i32), first_block=v5e(dtype=i32)).compile()
-    assert "tpu_custom_call" in indexer.as_text()
+        v5e(B, 64, 128), v5e(B, 64, dtype=jnp.float32), pool,
+        v5e(B, maxb, dtype=i32), v5e(B, dtype=i32),
+        first_block=v5e(dtype=i32)).compile()
+    assert "tpu_custom_call" in indexer.as_text() and not moved(indexer)
     assert indexer.memory_analysis().argument_size_in_bytes \
-        == pytest.approx(blocks * bs * 512, rel=0.001)
+        == pytest.approx(blocks * bs * 2048, rel=0.001)
     attention = dsa.selected_attention_pallas.lower(
-        v5e(B, 128, 512), v5e(B, 128, 128),
-        v5e(blocks, bs, 2, 128, dtype=u32), v5e(blocks, bs, 128, dtype=u32),
+        v5e(B, 128, 512), v5e(B, 128, 128), pool,
         v5e(B, DSA_TOPK, dtype=i32), v5e(B, dtype=i32),
         scale=0.13523).compile()
-    text = attention.as_text()
-    assert "tpu_custom_call" in text
-    # the pools (the uint32 operands) reach the kernel as they lie: no copy
-    # into another layout
-    assert not [line for line in text.splitlines()
-                if " copy(" in line and "u32[" in line]
+    assert "tpu_custom_call" in attention.as_text() and not moved(attention)
     assert attention.memory_analysis().argument_size_in_bytes \
-        == pytest.approx(blocks * bs * 1536, rel=0.001)
+        == pytest.approx(blocks * bs * 2048, rel=0.001)
     select = jax.jit(lambda s, n: dsa.select_topk(s, n, DSA_TOPK)).lower(
         v5e(B, maxb * bs, dtype=jnp.float32), v5e(B, dtype=i32)).compile()
     assert " sort(" in select.as_text()
@@ -536,7 +539,7 @@ def test_dsa_cell_decode_program_fits_the_v5e(v5e, placed, monkeypatch):
     """``deepseek-v3.2-d5.long_decode_dsa``'s decode program as the engine
     jits it: 16 slots x 22,528, one leading dense layer and four expert
     layers that hold 16 of the router's 256 experts. The v5e's compiler
-    takes it at 11.23 GiB of 15.75 (8.65 of weights, 2.58 of pool, 0.005
+    takes it at 12.09 GiB of 15.75 (8.65 of weights, 3.44 of pool, 0.005
     of temporaries: ``benchmark/aot_fit.py``), the two kernels of
     ``ops/dsa.py`` in both scans, ``ragged_dot`` for the experts (7168 x
     2048 is a width XLA tiles 512 x 512), and nothing of an expert
@@ -553,8 +556,8 @@ def test_dsa_cell_decode_program_fits_the_v5e(v5e, placed, monkeypatch):
     assert model.ffn_load_shape() == (4, 256)
     assert model.grouped_matmul_plan(B)["moe_grouped_impl"] == "ragged_dot"
     pool = jax.eval_shape(lambda: model.init_kv_pool(B * maxb + 1, bs))
-    assert pool["k"].shape == (5, B * maxb + 1, bs, 2, 128)
-    assert pool["v"].shape == (5, B * maxb + 1, bs, 128)
+    assert pool["k"].shape == (5, B * maxb + 1, bs, 4, 128)
+    assert pool["v"].shape == (5, B * maxb + 1, bs, 0)
     compiled = _engine_decode(model, B * maxb).lower(
         placed(_engine_params(model)), v5e(B, dtype=jnp.int32), placed(pool),
         v5e(B, maxb, dtype=jnp.int32), v5e(B, dtype=jnp.int32),
@@ -567,7 +570,7 @@ def test_dsa_cell_decode_program_fits_the_v5e(v5e, placed, monkeypatch):
              + mem.output_size_in_bytes - mem.alias_size_in_bytes)
     assert total < 15.75 * 2**30
     assert sum(a.size * 4 for a in pool.values()) \
-        == 5 * (B * maxb + 1) * bs * 1536
+        == 5 * (B * maxb + 1) * bs * 2048
     one_layers_slice_of_a_stack = 16 * 7168 * 2048 * 2
     assert mem.temp_size_in_bytes < one_layers_slice_of_a_stack / 4
 

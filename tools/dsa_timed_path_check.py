@@ -43,7 +43,9 @@ Readings (chip, PR 43, 16 slots live, 16,431 rows a slot, 47 decode steps):
 honest, selection forced: first chunk (no selection yet) 0.0121, prefix
 chunks 0.0130-0.0138, decode rows 0.0132-0.0158, worst 0.0158; selection
 FREE: prefix chunks 0.087-0.088, decode rows 0.098-0.128; faulty 1.08
-(prefix chunks) and 1.28-1.29 (decode rows).
+(prefix chunks) and 1.28-1.29 (decode rows). PR 44 (the row one run under
+"k", one copy a selected row): honest the same to the digits shown, worst
+0.0158; faulty 1.08 and 1.28-1.30.
 """
 import argparse
 import json
@@ -124,9 +126,9 @@ def main(argv=None) -> int:
         S = tokens.shape[1]
         layer = {n: a[0] for n, a in params["leading_layers"].items()}
         h = model._norm(model._embed(params, tokens), layer["attn_norm"])
-        (_, q_idx, w), _, v_rows = model._qkv(
+        (_, q_idx, w), k_rows, v_rows = model._qkv(
             h, layer, jnp.arange(S)[None], None, lambda a, *names: a)
-        k_idx = model._keys_of(v_rows)[1]
+        k_idx = model._row_parts(k_rows, v_rows)[2]
         block = 128
         pad = -S % block
         rows = jnp.arange(S + pad).reshape(-1, block)
@@ -203,8 +205,10 @@ def main(argv=None) -> int:
         S = len(toks)
         ids = jnp.asarray(eng._tables[slot, :-(-S // bs)])
         # layer 1's rows, as what they hold (unpacked where they are words)
+        # (the size spelled out: a row of words leaves "v" empty)
         parts = model._row_parts(*(
-            eng.kv[name][1][ids].reshape(-1, *eng.kv[name].shape[3:])[:S]
+            eng.kv[name][1][ids].reshape(len(ids) * bs,
+                                         *eng.kv[name].shape[3:])[:S]
             for name in ("k", "v")))
         c, pe, ki = (np.asarray(a.astype(jnp.float32)) for a in parts)
         pe = pe[:, :rope]
