@@ -13,14 +13,22 @@ normed residual stream:
   ADJACENT pairs of lanes (``rope_interleave``: the published weights are
   laid out so); q and k are de-interleaved the same way before the
   rotate-half the family uses, which leaves every score as it was.
-- THE CACHE ROW is ``c`` and ``k_pe``: ``kv_row_shapes`` puts ``c``
-  ``[kv_lora_rank]`` under ``"k"`` and ``k_pe``, zero-padded to a lane
-  tile (``ops.mla_attention.PE_LANES``), under ``"v"``. No head axis:
-  ``[..., bs, 512]`` and ``[..., bs, 128]`` tile as they are, where
-  ``[..., bs, 1, 512]`` would pad every row to a sublane tile. (The pad
-  is for the kernel's page copies; a latent width that fills no lane
-  tile, which the kernel cannot copy on the chip anyway, is not padded:
-  the debug widths' rows are ``c`` and ``k_pe`` as they are.)
+- THE CACHE ROW is ``c | k_pe``, ONE row: ``kv_row_shapes`` puts ``c``
+  in lanes ``[0, kv_lora_rank)`` of ``"k"`` and ``k_pe``, zero-padded to
+  a lane tile (``ops.mla_attention.PE_LANES``), in the lanes after it;
+  ``"v"`` is a zero-width row, which the engine, the harness and the
+  prefix gather carry like any other (since PR 45; ``c`` under ``"k"``
+  and ``k_pe`` under ``"v"`` before: the same 1,280 B a token at the
+  published widths). Why one row: the decode kernel then fetches a page
+  with ONE copy, and a copy costs its kernel the descriptor's start, not
+  the bytes (``ops/mla_attention.py`` has the readings: 1.362 -> 0.987
+  ms a layer at the cell's shape). ``_rows_of`` / ``_row_parts`` are the
+  only places that know the split. No head axis: ``[..., bs, 640]``
+  tiles as it is, where ``[..., bs, 1, 640]`` would pad every row to a
+  sublane tile. (The pad is for the kernel's page copies; a latent width
+  that fills no lane tile, which the kernel cannot copy on the chip
+  anyway, is not padded: the debug widths' row is ``c | k_pe`` as they
+  are, 48 lanes.)
 - TWO FORMS, one set of weights. ``W_uk`` [H, nope, R] and ``W_uv`` [H, R,
   v] are the two halves of the published ``kv_b_proj`` a head, held once.
   The prefills and training (``_attend_rows``) EXPAND: keys ``[c W_uk |
@@ -46,8 +54,9 @@ none changing a program of a model without it:
   sqrt(heads x width)``, the first rotary-width lanes of ``q_I`` and
   ``k_I`` turned in the HALF-SPLIT form, and the attention reads the
   ``index_topk`` rows of largest ``I`` alone (every row while there are
-  no more). THE CACHE ROW gains a third part: ``"v"`` holds ``k_pe`` in
-  its first lane tile and ``k_I`` in the lanes after it. In bf16 at
+  no more). THE CACHE ROW gains a third part and is laid out by its
+  own rule: ``"k"`` holds ``c``, ``"v"`` holds ``k_pe`` in its first
+  lane tile and ``k_I`` in the lanes after it. In bf16 at
   widths that fill lane tiles (the chip's) the row is held AS 32-BIT
   WORDS, two numbers a word, and ALL OF IT UNDER ``"k"``: [4, 128]
   uint32 = the sub-row of ``k_pe | k_I``, ``c``'s two, one spare;
@@ -251,30 +260,37 @@ class MLAModel(MoEModel):
 
     # -- the cache row -------------------------------------------------------
     def kv_row_shapes(self) -> Tuple[Tuple[int, ...], Tuple[int, ...]]:
-        """``"k"``: the latent row ``c``; ``"v"``: ``k_pe`` in the first
-        lanes of a zero lane tile and, with an indexer, the index key
-        ``k_I`` in the lanes after it. As words the row is ONE run of
-        sub-rows under ``"k"`` (``k_pe | k_I``'s, then ``c``'s, zeros up
-        to a power of two) and ``"v"`` holds nothing."""
+        """Without an indexer ONE row under ``"k"``, ``c`` then ``k_pe``
+        in its (padded) lanes, and ``"v"`` holds nothing. With one,
+        ``"k"``: the latent row ``c``; ``"v"``: ``k_pe`` in the first
+        lanes of a zero lane tile and the index key ``k_I`` in the lanes
+        after it; as words the row is ONE run of sub-rows under ``"k"``
+        (``k_pe | k_I``'s, then ``c``'s, zeros up to a power of two) and
+        ``"v"`` holds nothing."""
         cfg: MLAConfig = self.cfg
+        if not self.indexed:
+            return (cfg.kv_lora_rank + self.pe_lanes,), (0,)
         if self.word_rows:
             return ((dsa.word_row_subrows(cfg.kv_lora_rank), dsa.WORD_LANES),
                     (0,))
-        idx = cfg.index_head_dim if self.indexed else 0
-        return (cfg.kv_lora_rank,), (self.pe_lanes + idx,)
+        return (cfg.kv_lora_rank,), (self.pe_lanes + cfg.index_head_dim,)
 
     @property
     def kv_dtype(self):
         return jnp.uint32 if self.word_rows else self.cfg.dtype
 
-    def _rows_of(self, c, k_pe, k_idx):
-        """An indexed model's cache rows ``("k", "v")`` of what they
-        hold: the third part rides behind ``k_pe``'s lane tile, in ``"v"``
-        or, where the row is held as words, in the first sub-row of
-        ``"k"``."""
+    def _rows_of(self, c, k_pe, k_idx=None):
+        """The cache rows ``("k", "v")`` of what they hold: ``c | k_pe``
+        as one row of ``"k"``; with an indexer the third part rides
+        behind ``k_pe``'s lane tile, in ``"v"`` or, where the row is
+        held as words, in the first sub-row of ``"k"``."""
+        lead = c.shape[:-1]
+        if not self.indexed:
+            return (jnp.concatenate([c, k_pe], axis=-1),
+                    jnp.zeros((*lead, 0), c.dtype))
         if not self.word_rows:
             return c, jnp.concatenate([k_pe, k_idx], axis=-1)
-        lead, (row, _) = c.shape[:-1], self.kv_row_shapes()
+        row, _ = self.kv_row_shapes()
         words = jnp.concatenate([dsa.pack_words(k_pe), dsa.pack_words(k_idx),
                                  dsa.pack_words(c)], axis=-1)
         spare = math.prod(row) - words.shape[-1]
@@ -302,7 +318,10 @@ class MLAModel(MoEModel):
 
     def _row_parts(self, k_rows, v_rows):
         """``_rows_of``'s inverse: ``(c, k_pe, k_I)`` in the compute
-        dtype."""
+        dtype (``k_I`` None without an indexer)."""
+        if not self.indexed:
+            R = self.cfg.kv_lora_rank
+            return k_rows[..., :R], k_rows[..., R:], None
         return (self._c_of(k_rows),
                 *self._keys_of(k_rows if self.word_rows else v_rows))
 
@@ -354,7 +373,7 @@ class MLAModel(MoEModel):
             k_pe = jnp.pad(k_pe, ((0, 0), (0, 0),
                                   (0, self.pe_lanes - cfg.qk_rope_head_dim)))
         if not self.indexed:
-            return q, c, k_pe
+            return q, *self._rows_of(c, k_pe)
         Hi, Di = cfg.index_n_heads, cfg.index_head_dim
         with jax.named_scope("dsa_indexer_q"):
             q_idx = self._rope_idx(jnp.einsum(
@@ -370,17 +389,18 @@ class MLAModel(MoEModel):
             k_idx = self._rope_idx(k_idx[..., None, :], positions)[..., 0, :]
         return (q, q_idx, w), *self._rows_of(c, k_pe, k_idx)
 
-    def _attend_rows(self, q, c, k_pe, layer: Params, positions_q,
+    def _attend_rows(self, q, k_rows, v_rows, layer: Params, positions_q,
                      positions_k, window=None):
-        """The EXPANDED form: every row's keys and values up-projected
-        from its latent part, then the family's masked attention."""
+        """The EXPANDED form: every cache row's keys and values
+        up-projected from its latent part, then the family's masked
+        attention."""
         cfg: MLAConfig = self.cfg
         dt = cfg.dtype
         # None where the configuration leaves the scale as it was
         scale = cfg.softmax_scale if cfg.yarn_mscale_all_dim else None
         if self.indexed:
             q, q_idx, w_idx = q
-            c, k_pe, k_idx = self._row_parts(c, k_pe)
+        c, k_pe, k_idx = self._row_parts(k_rows, v_rows)
         with jax.named_scope("mla_kv_up"):
             k_nope = jnp.einsum("bsr,hnr->bshn", c, layer["w_uk"].astype(dt))
             v = jnp.einsum("bsr,hrv->bshv", c, layer["w_uv"].astype(dt))
@@ -457,9 +477,10 @@ class MLAModel(MoEModel):
             blocks(jnp.broadcast_to(positions_q, (B, T)))))
         return jnp.moveaxis(o, 0, 1).reshape(B, T, *o.shape[3:])
 
-    def _attention(self, q, c, k_pe, positions, window=None, layer=None):
-        return self._attend_rows(q, c, k_pe, layer, positions, positions,
-                                 window)
+    def _attention(self, q, k_rows, v_rows, positions, window=None,
+                   layer=None):
+        return self._attend_rows(q, k_rows, v_rows, layer, positions,
+                                 positions, window)
 
     def paged_decode_impl(self) -> str:
         """"mla_pallas" (the Mosaic kernel of ``ops/mla_attention.py``) or
@@ -486,11 +507,12 @@ class MLAModel(MoEModel):
                 # the selection is XLA's top-k on every platform
                 "decode_select_impl": "xla_top_k"}
 
-    def _attend_pages(self, q, c_pool, pe_pool, layer: Params, block_tables,
+    def _attend_pages(self, q, k_pool, v_pool, layer: Params, block_tables,
                       lengths, *, impl, starts=None, first_block=0,
                       num_blocks=None):
         """The ABSORBED form: q [B, H, nope + rope] against the latent
-        pages; no row of the cache is up-projected."""
+        pages (the pools of ``"k"`` and ``"v"``); no row of the cache is
+        up-projected."""
         cfg: MLAConfig = self.cfg
         dt, nope = cfg.dtype, cfg.qk_nope_head_dim
         if self.indexed:
@@ -504,7 +526,7 @@ class MLAModel(MoEModel):
             side = impl.removeprefix("dsa_")
             with jax.named_scope("dsa_indexer_scores"):
                 scores = dsa.indexer_scores(
-                    q_idx, w_idx, c_pool if self.word_rows else pe_pool,
+                    q_idx, w_idx, k_pool if self.word_rows else v_pool,
                     block_tables, lengths, impl=side,
                     key_of=lambda rows: self._keys_of(rows)[1],
                     first_block=first_block)
@@ -513,7 +535,7 @@ class MLAModel(MoEModel):
                                               cfg.index_topk)
             with jax.named_scope("dsa_attention"):
                 o_lat = dsa.sparse_decode_attention(
-                    q_lat, q_pe, c_pool, pe_pool, block_tables, rows, count,
+                    q_lat, q_pe, k_pool, v_pool, block_tables, rows, count,
                     impl=side, scale=cfg.softmax_scale,
                     parts_of=lambda k, v: self._row_parts(k, v)[:2],
                     first_block=first_block)
@@ -522,7 +544,7 @@ class MLAModel(MoEModel):
                                   layer["w_uv"].astype(dt))
         with jax.named_scope("mla_attention"):
             o_lat = mla_decode_attention(
-                q_lat, q_pe, c_pool, pe_pool, block_tables, lengths,
+                q_lat, q_pe, k_pool, block_tables, lengths,
                 impl=impl.removeprefix("mla_"), scale=cfg.softmax_scale,
                 first_block=first_block)
         with jax.named_scope("mla_v_up"):

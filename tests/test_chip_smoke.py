@@ -428,20 +428,20 @@ MLA_CELL_TABLE = 768
 
 def test_mla_decode_kernel_lowers_at_the_mla_cells_shape(v5e):
     """The absorbed latent-attention kernel (``ops/mla_attention.py``) for
-    the v5e, no chip: pages ``[32, 512]`` and ``[32, 128]`` copied as the
-    2-D tiles they are out of a stack of five layers' windows, the 32
-    heads as the rows of its dots. The pools are what the arguments
-    hold: 4.69 GiB, a row's 1,280 B and nothing a sublane tile pads."""
+    the v5e, no chip: pages ``[32, 640]`` (``c | k_pe``, ONE pool row since
+    PR 45) copied as the 2-D tiles they are out of a stack of five layers'
+    windows, the row's two parts lane ranges of the one buffer, the 32
+    heads as the rows of its dots. The pool is what the arguments hold:
+    4.69 GiB, a row's 1,280 B and nothing a sublane tile pads."""
     from ray_tpu.ops.mla_attention import (PE_LANES,
                                            mla_decode_attention_pallas)
 
     B, H, R, bs, maxb, L = CELL_SLOTS, 32, 512, CELL_BS, MLA_CELL_TABLE, 5
     blocks = L * (B * maxb + 1)
     compiled = mla_decode_attention_pallas.lower(
-        v5e(B, H, R), v5e(B, H, PE_LANES), v5e(blocks, bs, R),
-        v5e(blocks, bs, PE_LANES), v5e(B, maxb, dtype=jnp.int32),
-        v5e(B, dtype=jnp.int32), scale=192 ** -0.5,
-        interpret=False).compile()
+        v5e(B, H, R), v5e(B, H, PE_LANES), v5e(blocks, bs, R + PE_LANES),
+        v5e(B, maxb, dtype=jnp.int32), v5e(B, dtype=jnp.int32),
+        scale=192 ** -0.5, interpret=False).compile()
     assert "tpu_custom_call" in compiled.as_text()
     held = compiled.memory_analysis().argument_size_in_bytes
     assert held == pytest.approx(blocks * bs * (R + PE_LANES) * 2, rel=0.001)
@@ -469,8 +469,8 @@ def test_mla_cell_decode_program_fits_the_v5e(v5e, placed, monkeypatch):
     assert model.paged_decode_impl() == "mla_pallas"
     assert model.ffn_load_shape() == (4, E)
     pool = jax.eval_shape(lambda: model.init_kv_pool(B * maxb + 1, bs))
-    assert pool["k"].shape == (5, B * maxb + 1, bs, 512)
-    assert pool["v"].shape == (5, B * maxb + 1, bs, 128)
+    assert pool["k"].shape == (5, B * maxb + 1, bs, 512 + 128)
+    assert pool["v"].shape == (5, B * maxb + 1, bs, 0)
     compiled = _engine_decode(model, B * maxb).lower(
         placed(_engine_params(model)), v5e(B, dtype=jnp.int32), placed(pool),
         v5e(B, maxb, dtype=jnp.int32), v5e(B, dtype=jnp.int32),

@@ -127,7 +127,7 @@ def prefix_prefill(model, params, toks, prefix=8):
         params, suffix, padded["k"], padded["v"], jnp.full((B,), prefix, I32),
         jnp.full((B,), total - prefix, I32))
     assert rows["k"].shape == (model.cfg.n_layers, B, 32,
-                               model.cfg.kv_lora_rank)
+                               *model.kv_row_shapes()[0])
     return logits[:, None]                       # position total - 1
 
 
@@ -137,16 +137,16 @@ PATHS = {"full_forward": (full_forward, 0),
          "prefix_prefill": (prefix_prefill, -1)}
 
 
-# 32: the debug row, ``c`` and ``k_pe`` as they are; 128: a latent width
-# that fills a lane tile, so ``k_pe`` is zero-padded to ``PE_LANES`` in the
+# 32: the debug row, ``c | k_pe`` as they are; 128: a latent width that
+# fills a lane tile, so ``k_pe`` is zero-padded to ``PE_LANES`` in the
 # cache, the layout of the published widths (512 + 128) that the cell times
 @pytest.mark.parametrize("kv_lora_rank", [32, 128])
 @pytest.mark.parametrize("path", sorted(PATHS))
 def test_float32_compute_matches_the_reference(path, kv_lora_rank):
     cfg, model, params = make(kv_lora_rank=kv_lora_rank)
-    assert model.kv_row_shapes()[1] == (
-        (mla_attention.PE_LANES,) if kv_lora_rank == 128
-        else (cfg.qk_rope_head_dim,))
+    assert model.kv_row_shapes() == ((
+        kv_lora_rank + (mla_attention.PE_LANES if kv_lora_rank == 128
+                        else cfg.qk_rope_head_dim),), (0,))
     toks = seqs(cfg)
     run, first = PATHS[path]
     with jax.default_matmul_precision("highest"):
@@ -166,11 +166,12 @@ def test_absorbed_and_expanded_forms_agree_on_the_same_rows():
     key = jax.random.split(jax.random.key(3), 3)
     h = jax.random.normal(key[0], (B, S, cfg.dim))
     with jax.default_matmul_precision("highest"):
-        q, c, k_pe = model._qkv(h, layer, None, None, lambda a, *_: a)
-        expanded = model._attend_rows(q, c, k_pe, layer, jnp.arange(S),
-                                      jnp.arange(S))[:, -1]
-        pool = (c.reshape(B * S // bs, bs, -1), k_pe.reshape(B * S // bs, bs,
-                                                             -1))
+        q, k_rows, v_rows = model._qkv(h, layer, None, None,
+                                       lambda a, *_: a)
+        expanded = model._attend_rows(q, k_rows, v_rows, layer,
+                                      jnp.arange(S), jnp.arange(S))[:, -1]
+        pool = (k_rows.reshape(B * S // bs, bs, k_rows.shape[-1]),
+                v_rows.reshape(B * S // bs, bs, 0))
         tables = jnp.arange(B * S // bs, dtype=I32).reshape(B, -1)
         for impl in ("mla_xla", "mla_pallas"):
             absorbed = model._attend_pages(
@@ -274,38 +275,62 @@ def test_rows_of_logits_are_the_logits_rows():
 
 
 # -- the kernel against its twin -----------------------------------------------
-@pytest.mark.parametrize("lengths", [
-    (1, 8, 9, 37), (64, 63, 17, 0), (5, 5, 5, 5)],
-    ids=["one_row_and_block_edges", "full_table_and_empty", "same"])
-def test_kernel_in_interpret_mode_is_its_xla_twin(lengths, monkeypatch):
+# 2 pages a chunk: a slot of 37 rows walks three chunks (the third of ONE
+# live page: the one wait must count that page's bytes alone), 64 rows four
+# full ones, 17 two (the second of one page of two), 0 rows none
+@pytest.mark.parametrize("lengths,first_block", [
+    ((1, 8, 9, 37), 40), ((64, 63, 17, 0), 40), ((5, 5, 5, 5), 40),
+    ((33, 16, 0, 41), 0), ((24, 40, 56, 48), 40)],
+    ids=["one_row_and_block_edges", "full_table_and_empty", "same",
+         "first_block_0_and_odd_last_chunks", "whole_pages_whole_chunks"])
+def test_kernel_in_interpret_mode_is_its_xla_twin(lengths, first_block,
+                                                  monkeypatch):
     """Ragged lengths, a slot with one row, slots at a block's edge and one
-    past it, a slot with none; the table's entries past a slot's length
-    point at garbage (a block of NaNs) or out of the layer's window."""
+    past it, a slot with none, last chunks with dead pages; the table's
+    entries past a slot's length point at garbage (a block of NaNs) or out
+    of the layer's window."""
     monkeypatch.setattr(mla_attention, "CHUNK_ROWS", 16)    # 2 pages a chunk
     B, H, R, P, bs, maxb, NB = 4, 4, 32, 16, 8, 8, 40
-    k = jax.random.split(jax.random.key(0), 4)
-    c_pool = jax.random.normal(k[0], (2 * NB, bs, R))
-    pe_pool = jax.random.normal(k[1], (2 * NB, bs, P))
-    c_pool = c_pool.at[NB + 39].set(jnp.nan)     # the layer's garbage block
-    q_lat = jax.random.normal(k[2], (B, H, R))
-    q_pe = jax.random.normal(k[3], (B, H, P))
+    k = jax.random.split(jax.random.key(0), 3)
+    pool = jax.random.normal(k[0], (2 * NB, bs, R + P))
+    garbage = first_block + 39                   # the layer's garbage block
+    q_lat = jax.random.normal(k[1], (B, H, R))
+    q_pe = jax.random.normal(k[2], (B, H, P))
     lengths = jnp.asarray(lengths, I32)
     live = -(-lengths // bs)
     tables = np.random.default_rng(1).permutation(39)[:B * maxb].reshape(
         B, maxb)
     tables = jnp.where(jnp.arange(maxb)[None] < live[:, None], tables, 39)
-    args = (q_lat, q_pe, c_pool, pe_pool, tables.astype(I32), lengths)
     got = mla_attention.mla_decode_attention(
-        *args, impl="pallas", scale=0.2, first_block=NB)
+        q_lat, q_pe, pool.at[garbage].set(jnp.nan), tables.astype(I32),
+        lengths, impl="pallas", scale=0.2, first_block=first_block)
     # the twin on the live rows alone (it gathers whole tables, garbage
     # and all, and a NaN row it masks is still 0 * NaN in its value dot)
-    clean = c_pool.at[NB + 39].set(0.0)
     want = mla_attention.mla_decode_attention(
-        q_lat, q_pe, clean, pe_pool, tables.astype(I32), lengths,
-        impl="xla", scale=0.2, first_block=NB)
+        q_lat, q_pe, pool.at[garbage].set(0.0), tables.astype(I32), lengths,
+        impl="xla", scale=0.2, first_block=first_block)
     want = jnp.where(lengths[:, None, None] > 0, want, 0.0)
     assert bool(jnp.all(jnp.isfinite(got)))
     np.testing.assert_allclose(got, want, atol=2e-5)
+
+
+def test_kernel_starts_one_copy_a_page_and_waits_by_powers_of_two(
+        monkeypatch):
+    """The mechanism, read off the program: a chunk of 8 pages is 8
+    ``dma_start`` (one a page: ``c | k_pe`` is one pool row) where it is
+    started (the call's first chunk, the slot's next, the next slot's
+    first), and its live pages are waited for by 8 + 4 + 2 + 1: four
+    ``dma_wait``, of which a full chunk takes one."""
+    B, H, R, P, bs, maxb = 2, 4, 128, 128, 8, 16
+    monkeypatch.setattr(mla_attention, "CHUNK_ROWS", 8 * bs)
+    program = str(jax.make_jaxpr(
+        lambda *a: mla_attention.mla_decode_attention_pallas.__wrapped__(
+            *a, scale=0.1, interpret=True))(
+        jnp.zeros((B, H, R)), jnp.zeros((B, H, P)),
+        jnp.zeros((40, bs, R + P)), jnp.zeros((B, maxb), I32),
+        jnp.zeros((B,), I32)))
+    assert program.count("dma_start") == 3 * 8
+    assert program.count("dma_wait") == 4
 
 
 def test_the_resolver_names_the_implementation(monkeypatch):
@@ -323,14 +348,32 @@ def test_cache_rows_have_no_head_axis_and_count_every_layer():
     cfg, model, _ = make()
     cache = model.init_kv_cache(2, 16)
     pool = model.init_kv_pool(5, 8)
-    assert cache["k"].shape == (3, 2, 16, cfg.kv_lora_rank)
-    assert cache["v"].shape == (3, 2, 16, cfg.qk_rope_head_dim)
-    assert pool["k"].shape == (3, 5, 8, cfg.kv_lora_rank)
-    assert pool["v"].shape == (3, 5, 8, cfg.qk_rope_head_dim)
+    row = cfg.kv_lora_rank + cfg.qk_rope_head_dim     # ONE row: c | k_pe
+    assert cache["k"].shape == (3, 2, 16, row)
+    assert cache["v"].shape == (3, 2, 16, 0)
+    assert pool["k"].shape == (3, 5, 8, row)
+    assert pool["v"].shape == (3, 5, 8, 0)
     # at widths the kernel copies as lane tiles the rotary part is padded
     # to one, and still no head axis
     wide = model_for(dataclasses.replace(cfg, kv_lora_rank=128))
-    assert wide.kv_row_shapes() == ((128,), (mla_attention.PE_LANES,))
+    assert wide.kv_row_shapes() == ((128 + mla_attention.PE_LANES,), (0,))
+
+
+@pytest.mark.parametrize("kv_lora_rank", [32, 128])
+def test_rows_of_and_row_parts_are_inverses(kv_lora_rank):
+    cfg = MLAConfig.debug_kanana(kv_lora_rank=kv_lora_rank)
+    model = model_for(cfg)
+    k = jax.random.split(jax.random.key(3), 2)
+    c = jax.random.normal(k[0], (2, 5, kv_lora_rank))
+    k_pe = jnp.pad(jax.random.normal(k[1], (2, 5, cfg.qk_rope_head_dim)),
+                   ((0, 0), (0, 0),
+                    (0, model.pe_lanes - cfg.qk_rope_head_dim)))
+    k_rows, v_rows = model._rows_of(c, k_pe)
+    assert (k_rows.shape[2:], v_rows.shape[2:]) == model.kv_row_shapes()
+    got_c, got_pe, none = model._row_parts(k_rows, v_rows)
+    assert none is None
+    np.testing.assert_array_equal(got_c, c)
+    np.testing.assert_array_equal(got_pe, k_pe)
 
 
 def test_serving_params_keep_the_float32_leaves():
@@ -440,12 +483,17 @@ def _prompt(cfg, n, seed):
 
 
 ENGINE_CASES = {
-    # name: (prompt lengths, engine kwargs, the stats key that must move)
-    "bucket_prefill": ((5, 12, 20), {}, "prefills"),
-    "chunked_prefill": ((40, 9), {}, "prefills"),
-    "prefix_prefill": ("shared", {}, "prefix_prefills"),
+    # name: (prompt lengths, engine kwargs, the stats key that must move,
+    # the model's overrides)
+    "bucket_prefill": ((5, 12, 20), {}, "prefills", {}),
+    "chunked_prefill": ((40, 9), {}, "prefills", {}),
+    # at the published widths' layout, k_pe padded to a lane tile: the
+    # second request's prefill gathers the first's rows and decodes over
+    # them (the other cases insert, gather and decode the 48-lane row)
+    "prefix_prefill": ("shared", {}, "prefix_prefills",
+                       {"kv_lora_rank": 128}),
     "preemption_by_recompute": ((20, 21, 22), {"num_blocks": 10},
-                                "preemptions"),
+                                "preemptions", {}),
 }
 
 
@@ -455,9 +503,11 @@ def test_engine_greedy_tokens_are_the_references_argmax(case):
     generated token is the reference's first choice given the prompt and
     the tokens before it (teacher forced) unless the reference has its
     first two within 1e-3, and the expert FFN processed exactly what a
-    dropless FFN must, over the EXPERT layers alone."""
-    cfg, model, params = make()
-    lens, kwargs, moved = ENGINE_CASES[case]
+    dropless FFN must, over the EXPERT layers alone. The shared
+    prompts' second request reads the first's rows back: insert, prefix
+    gather, decode over the ONE row."""
+    lens, kwargs, moved, overrides = ENGINE_CASES[case]
+    cfg, model, params = make(**overrides)
     if lens == "shared":
         head = _prompt(cfg, 16, 50)
         prompts = [head + _prompt(cfg, n, i) for i, n in enumerate((3, 7))]
@@ -484,8 +534,9 @@ def test_engine_greedy_tokens_are_the_references_argmax(case):
     assert stats[moved] > 0
     assert stats["moe_router_kind"] == "sigmoid"
     assert eng.decode_attention_impl == "mla_xla"
-    assert stats["kv_row_bytes"] == 4 * (cfg.kv_lora_rank
-                                         + cfg.qk_rope_head_dim)
+    assert stats["kv_row_bytes"] == 4 * (cfg.kv_lora_rank + model.pe_lanes)
+    assert eng.kv["k"].shape[2:] == (8, cfg.kv_lora_rank + model.pe_lanes)
+    assert eng.kv["v"].shape[2:] == (8, 0)
     assert stats["kv_pool_bytes"] == sum(a.nbytes for a in eng.kv.values())
     assert stats["moe_assignments"] == stats["moe_assignments_expected"] > 0
     load = np.asarray(stats["moe_expert_load"])

@@ -9,7 +9,8 @@ Why it exists. The cell's own ``correct`` cannot hold its timed path tightly
 (PERF.md section 7 (z), ROADMAP Y2): with seeded weights this block's routing
 cascades, the logits check's floor is 0.15-0.27 and the greedy margin stands
 where a fault of the whole attention passes two to five runs of nine. But the FIRST
-EXPERT LAYER'S LATENT CACHE ROWS (layer 1: ``c`` and ``k_pe``) are a function
+EXPERT LAYER'S LATENT CACHE ROWS (layer 1: ``c | k_pe``, one row of ``"k"``
+since PR 45) are a function
 of layer 0 alone: the embedding, layer 0's latent attention over every earlier
 row, and the DENSE FFN. No router stands before them, nothing cascades, and
 bf16 against float32 reads under 0.01.
@@ -36,7 +37,8 @@ do not. It sees layer 0's attention only (the expert layers run the same
 body at another ``first_block``), and neither the router nor the experts.
 
 Readings (chip, PR 41): honest layer-1 ``c`` 0.0073 (prefix chunks), 0.0074-
-0.0075 (decode rows), worst row 0.0115; faulty 0.74 and 0.81-0.84.
+0.0075 (decode rows), worst row 0.0115; faulty 0.74 and 0.81-0.84. PR 45 (the
+one row, one copy a page): PERF.md section 6.
 """
 import argparse
 import json
@@ -92,10 +94,10 @@ def main(argv=None) -> int:
         jax.random.key(args.seed % (2**31 - 1)))
 
     class Faulty(type(model)):
-        def _attend_pages(self, q, c_pool, pe_pool, layer, block_tables,
+        def _attend_pages(self, q, k_pool, v_pool, layer, block_tables,
                           lengths, **kw):
             return super()._attend_pages(
-                q, c_pool, pe_pool, layer, block_tables,
+                q, k_pool, v_pool, layer, block_tables,
                 jnp.minimum(lengths, cut), **kw)
 
         def prefill_with_prefix(self, params, tokens, prefix_k, prefix_v,
@@ -146,12 +148,12 @@ def main(argv=None) -> int:
         S = len(toks)
         ids = jnp.asarray(eng._tables[slot, :-(-S // bs)])
 
-        def part(name, lanes):
-            rows = eng.kv[name][1][ids]                  # layer 1
-            return np.asarray(rows.reshape(-1, rows.shape[-1])[:S, :lanes]
-                              .astype(jnp.float32))
-
-        c, pe = part("k", rank), part("v", rope)
+        # layer 1's rows, c | k_pe (padded) in ONE row of "k" ("v" holds
+        # nothing: every size spelled out, a width of 0 infers no -1)
+        rows = eng.kv["k"][1][ids]
+        rows = np.asarray(rows.reshape(ids.shape[0] * bs, rows.shape[-1])[:S]
+                          .astype(jnp.float32))
+        c, pe = rows[:, :rank], rows[:, rank:rank + rope]
         wc, wpe = jax.device_get(
             layer1_rows(params, jnp.asarray([toks], jnp.int32)))
         wc, wpe = wc[0], wpe[0]
