@@ -339,7 +339,6 @@ def main() -> int:
     else:
         result = _run_train(tiny_cpu)
     if os.environ.get("BENCH_CONTROL_PLANE", "1") != "0":
-        from ray_tpu._private.config import cfg as _cfg
         # host-side sections; each carries the platform stamp so a
         # partial json consumer knows which machine it describes
         stamp = {"platform": result["platform"]}
@@ -347,9 +346,6 @@ def main() -> int:
         window = 0.3 if tiny_cpu else 1.5
         result["control_plane"] = {
             **_control_plane_probe(window, 400 if tiny_cpu else 2000),
-            # which wire/dispatch core produced these rows — A/B runs
-            # flip RAY_TPU_ASYNC_CORE and diff the same json key
-            "async_core": bool(_cfg().async_core),
             # spans-on vs spans-off delta, paired + median-of-ratios in
             # ONE cluster (sequential unpaired probes are a noise
             # lottery on shared hosts — see tools/perf_smoke.sh probe 4)

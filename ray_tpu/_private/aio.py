@@ -1,30 +1,29 @@
-"""Asyncio control-plane wire: protocols on the per-process event loop.
+"""The control-plane wire: asyncio protocols on the per-process event loop.
 
-The async twin of ``rpc.py``, selected by ``cfg().async_core`` through
-``rpc.serve()`` / ``rpc.connect()``. Reference model: the C++ runtime's
-asio cores (``daemon_core.cc``) — ONE event loop per process owns every
-peer socket, frame parse -> handler -> reply runs pipelined on the loop,
-and writes are deferred and coalesced per peer per loop iteration (the
-one-sendmsg-per-peer discipline). The threaded core's per-connection
-reader threads and per-frame cross-thread wakeups disappear; blocking
-handlers still leave the loop (``@concurrent`` thread, FIFO lane on the
-shared pool) exactly as before.
+The server and client that ``rpc.serve()`` / ``rpc.connect()`` build.
+Reference model: the C++ runtime's asio cores (``daemon_core.cc``) —
+ONE event loop per process owns every peer socket, frame parse ->
+handler -> reply runs pipelined on the loop, and writes are deferred and
+coalesced per peer per loop iteration (the one-sendmsg-per-peer
+discipline). No per-connection reader thread, no per-frame cross-thread
+wakeup; blocking handlers leave the loop (``@concurrent`` thread, FIFO
+lane on the shared pool).
 
-Wire parity is the contract, not an aspiration:
+The wire's contract:
 
-- Frames are byte-identical (``u32 len | msgpack map``) — async and
-  threaded peers interoperate on the same socket; the ``async_core``
-  hello bit only advertises the local core, it never changes framing.
-- The same ``_WIRE`` counters back ``wire_metric_entries`` (imported
-  from rpc, not duplicated), so dashboards don't fork per core.
-- Every failpoint seam fires at the same layer: ``rpc.client.send`` /
-  ``rpc.client.recv`` above the frame layer, ``rpc.server.recv`` before
-  dispatch.
-- netchaos sits BELOW the frame layer, but the loop must never sleep:
+- Frames are ``u32 len | msgpack map``, the format every release of
+  this runtime has spoken, so peers of different releases interoperate
+  on the same socket.
+- The ``_WIRE`` counters that back ``wire_metric_entries`` live in
+  ``rpc.py`` with the schemas; this module only bumps them.
+- The failpoint seams sit above the frame layer: ``rpc.client.send`` /
+  ``rpc.client.recv`` around a client's frames, ``rpc.server.recv``
+  before dispatch.
+- netchaos sits BELOW the frame layer, and the loop must never sleep:
   the ``*_decide`` variants return ``(verdict, delay_s)`` and delays are
   served by per-connection ``call_later`` FIFO queues — a delayed frame
-  holds back later frames on ITS link only, matching the threaded
-  sleep's per-connection serialization without stalling other peers.
+  holds back later frames on ITS link only, without stalling other
+  peers.
 
 Thread-affinity: everything the loop calls is ``#: loop-only``
 (raylint's loop-affinity pass + ``eventloop.assert_loop`` under the
@@ -47,11 +46,9 @@ import msgpack
 from ray_tpu._private import eventloop
 from ray_tpu._private import failpoints as _fp
 from ray_tpu._private import netchaos as _nc
-from ray_tpu._private.rpc import (  # shared wire state: ONE set of
-    # counters/schemas for both cores, so exposition and validation
-    # cannot drift between them
+from ray_tpu._private.rpc import (
     _LEN, _WIRE, _WIRE_LOCK, _WIRE_SERVER_REQS, _WIRE_CLIENT_REQS,
-    MAX_FRAME, SEND_CONCAT_MAX, RpcError, HOLD, _validate)
+    MAX_FRAME, SEND_CONCAT_MAX, RemoteError, RpcError, HOLD, _validate)
 
 
 def _raw_sock(transport) -> Any:
@@ -170,8 +167,7 @@ class _FrameProtocol(asyncio.Protocol):
     AsyncConnection) supplies ``_attached`` / ``_on_frame`` /
     ``_on_lost`` and a ``sock`` attribute for chaos-link identity.
     Inbound chaos delays re-schedule delivery via ``call_later`` — the
-    loop never sleeps — preserving per-link FIFO like the threaded
-    reader's in-line sleep did."""
+    loop never sleeps — preserving per-link FIFO."""
 
     def __init__(self, owner) -> None:
         self._owner = owner
@@ -233,9 +229,8 @@ class _FrameProtocol(asyncio.Protocol):
         try:
             msg = msgpack.unpackb(blob, raw=False)
         except Exception:
-            # protocol violation == connection death (the threaded
-            # reader thread dies the same way); abort tears down via
-            # connection_lost
+            # protocol violation == connection death; abort tears down
+            # via connection_lost
             if self.transport is not None:
                 self.transport.abort()
             return
@@ -253,10 +248,11 @@ class _FrameProtocol(asyncio.Protocol):
 # ---------------------------------------------------------------------------
 
 class AsyncClient:
-    """Duck-types ``rpc.Client``: blocking thread-side ``call`` /
-    ``notify`` against a connection owned by the event loop. The socket
-    is connected synchronously (constructor failure parity with the
-    threaded client), then handed to the loop."""
+    """One TCP connection to a server; thread-safe request/reply:
+    blocking thread-side ``call`` / ``notify`` against a connection
+    owned by the event loop. The socket is connected synchronously (a
+    refused dial raises from the constructor), then handed to the
+    loop."""
 
     def __init__(self, addr: Tuple[str, int], timeout: float = 30.0,
                  on_push: Optional[Callable[[str, Dict[str, Any]], None]]
@@ -292,18 +288,12 @@ class AsyncClient:
         self._batcher = _WriteBatcher(self._loop, transport, self._sock)
 
     def _on_frame(self, msg: Dict[str, Any]) -> None:  #: loop-only
-        # Deliberately the threaded core's seam NAME: chaos schedules
-        # and failpoint tests target "rpc.client.recv" and must hit
-        # whichever core the process runs — one seam, two cores, so
-        # the registry's one-site rule is suppressed here (and at the
-        # other alternate-core sites below) rather than forking names.
-        if _fp.ENABLED and _fp.fire(  # raylint: disable=failpoint-registry
+        if _fp.ENABLED and _fp.fire(
                 "rpc.client.recv", method=msg.get("m", "")) is _fp.DROP:
             return      # reply/push lost in transit
         rid = msg.get("i")
         if rid is None:
-            # server push (no correlation id) — inline on the loop, the
-            # async analogue of the threaded reader running it inline
+            # server push (no correlation id) — inline on the loop
             if self._on_push is not None:
                 try:
                     self._on_push(msg.get("m", ""), msg)
@@ -361,13 +351,17 @@ class AsyncClient:
 
     def _call_counted(self, method: str, timeout: Optional[float],
                       kw: Dict[str, Any]) -> Dict[str, Any]:
-        # same seam discipline as the threaded client: the failpoint
-        # fires BEFORE the pending slot exists, and a deadline-less
-        # caller surfaces a dropped send as transport death
-        dropped = (_fp.ENABLED and _fp.fire(  # raylint: disable=failpoint-registry
+        # failpoint BEFORE the pending slot exists: an error arm must
+        # not leak a slot; a DROP arm skips the send so the caller times
+        # out exactly like real frame loss
+        dropped = (_fp.ENABLED and _fp.fire(
             "rpc.client.send", method=method) is _fp.DROP)
         if dropped and (timeout if timeout is not None
                         else self._timeout) is None:
+            # a deadline-less caller (long-poll subscribers) can never
+            # observe a lost frame as a timeout — surface the drop as
+            # transport failure instead of wedging the waiter forever
+            # (on healthy TCP, silent frame loss IS connection death)
             self._fail_all()
             raise RpcError(f"send to {self.addr} dropped by failpoint")
         with self._id_lock:
@@ -395,14 +389,13 @@ class AsyncClient:
             raise RpcError(f"connection to {self.addr} died during "
                            f"{method}")
         if reply.get("e"):
-            from ray_tpu._private.rpc import RemoteError
             raise RemoteError(reply["e"])
         return reply
 
     def notify(self, method: str, **kw) -> None:
         """Fire-and-forget (no reply expected)."""
         _validate(method, kw)
-        if (_fp.ENABLED and _fp.fire("rpc.client.send",  # raylint: disable=failpoint-registry
+        if (_fp.ENABLED and _fp.fire("rpc.client.send",
                                      method=method) is _fp.DROP):
             return              # notification lost in transit
         msg = dict(kw)
@@ -433,10 +426,11 @@ class AsyncClient:
 # ---------------------------------------------------------------------------
 
 class AsyncConnection:
-    """Duck-types ``rpc.Connection`` for services: ``sock`` / ``peer`` /
-    ``meta`` / ``closed``, ``link()``, ``reply()``, ``reply_error()``,
-    ``push()``. Replies may come from any thread (lane, @concurrent,
-    pump); they re-enter the loop and join this peer's write batch."""
+    """Server-side handle to one client connection, as services see
+    it: ``sock`` / ``peer`` / ``meta`` / ``closed``, ``link()``,
+    ``reply()``, ``reply_error()``, ``push()``. Replies may come from
+    any thread (lane, @concurrent, pump); they re-enter the loop and
+    join this peer's write batch."""
 
     def __init__(self, server: "AsyncServer"):
         self._server = server
@@ -447,9 +441,9 @@ class AsyncConnection:
         self.closed = False
         self._proto = _FrameProtocol(self)
         self._batcher: Optional[_WriteBatcher] = None
-        # FIFO lane: identical semantics to the threaded server — from
-        # one peer, ordered handlers run one at a time in arrival order
-        # on the shared pool, off the loop
+        # FIFO lane: from one peer, ordered handlers run one at a time
+        # in arrival order on the shared pool, off the loop, so decoding
+        # (and @concurrent handlers) pipeline ahead of a slow handler
         self._lane: deque = deque()
         self._lane_lock = threading.Lock()
         self._lane_busy = False
@@ -480,7 +474,7 @@ class AsyncConnection:
     # -- any-thread reply surface ------------------------------------
     def _send(self, msg: Dict[str, Any]) -> None:
         if self.closed or self._batcher is None:
-            return      # threaded parity: send-after-death marks closed
+            return      # the peer is gone: nobody to tell
         blob = msgpack.packb(msg, use_bin_type=True)
         if eventloop.on_loop():
             self._batcher.send(blob)  # raylint: disable=loop-affinity
@@ -503,14 +497,15 @@ class AsyncConnection:
 
 
 class AsyncServer:
-    """Duck-types ``rpc.Server``. The listening socket is bound
-    synchronously (``addr`` valid immediately, like the threaded
-    server); ``start()`` hands it to the loop. Dispatch runs on the
-    loop: ``@loop_safe`` handlers inline (parse -> handler -> reply
-    with zero hand-offs), ``@concurrent`` on a dedicated thread,
-    everything else through the per-connection FIFO lane on the shared
-    pool — the same three-tier discipline as the threaded core, minus
-    the per-connection reader threads."""
+    """RPC server. ``service`` exposes ``handle_<method>`` callables
+    with signature (conn, rid, msg) -> reply dict | HOLD. Optional
+    ``on_disconnect(conn)`` on the service is called when a client
+    connection drops (daemon death detection hook). The listening
+    socket is bound synchronously (``addr`` valid immediately);
+    ``start()`` hands it to the loop. Dispatch runs on the loop:
+    ``@loop_safe`` handlers inline (parse -> handler -> reply with zero
+    hand-offs), ``@concurrent`` on a dedicated thread, everything else
+    through the per-connection FIFO lane on the shared pool."""
 
     def __init__(self, service: Any, host: str = "127.0.0.1",
                  port: int = 0):
@@ -558,8 +553,10 @@ class AsyncServer:
             self._run_handler(conn, handler, rid, msg)
             return
         if getattr(handler, "_rpc_concurrent", False):
-            # dedicated thread, NOT the shared pool (threaded parity):
-            # may block for minutes without starving lane drains
+            # dedicated thread, NOT the shared pool: @concurrent
+            # handlers may block for minutes (object pulls), and enough
+            # of them would exhaust the pool and stall every
+            # connection's lane drain
             threading.Thread(
                 target=self._run_handler,
                 args=(conn, handler, rid, msg), daemon=True,
@@ -613,7 +610,7 @@ class AsyncServer:
         cb = getattr(self.service, "on_disconnect", None)
         if cb is not None and not self._stop:
             # service disconnect hooks may block (reclaim, persist):
-            # run them off-loop, like the dying reader thread used to
+            # run them off-loop
             self._pool.submit(lambda: self._safe_disconnect(cb, conn))
 
     @staticmethod

@@ -70,16 +70,6 @@ CAPABILITY_FLAGS = {
         "doc": "this driver understands ext-slot object grants "
                "(self-describing: reflects the sender's own ability)",
     },
-    "async_core": {
-        "kind": "hello",
-        "guard": "_async_core_remote",
-        "doc": "daemon runs the single-threaded asyncio wire+dispatch "
-               "core (cfg().async_core). Frames are byte-identical "
-               "across cores, so this bit gates NOTHING on the wire — "
-               "it exists so mixed clusters are observable (driver "
-               "stats name which peers run which core) and so a future "
-               "release can retire the threaded fallback knowingly",
-    },
     "fence": {
         "kind": "hello",
         "guard": "_fence_supported",

@@ -41,7 +41,7 @@ from ray_tpu._private import daemon as _daemon_schemas  # noqa: F401 — declare
 from ray_tpu._private.head import HeadClient
 from ray_tpu._private.ids import NodeID
 from ray_tpu._private.lock_sanitizer import tracked_lock
-from ray_tpu._private.rpc import HOLD, Client, Server, declare
+from ray_tpu._private.rpc import HOLD, declare
 
 declare("core_op", "call", "payload", "task")
 
@@ -475,7 +475,6 @@ class DaemonHandle:
         # waiters (docs/fault_tolerance.md "Partitions, epochs & fencing")
         self.epoch = 0
         self._fence_supported = False       # daemon advertises in hello
-        self._async_core_remote = False     # which core the daemon runs
         # zero-copy object plane (set from the hello reply)
         self.objectplane = False
         self.arena_name: Optional[str] = None
@@ -768,9 +767,6 @@ class DaemonHandle:
         self._tenancy_supported = bool(out.get("tenancy"))
         # partition fencing: epoch/attempt stamps on result frames
         self._fence_supported = bool(out.get("fence"))
-        # observational only (frames are core-agnostic): lets cluster
-        # stats name which peers run the asyncio core in a mixed fleet
-        self._async_core_remote = bool(out.get("async_core"))
         self.epoch = int(out.get("epoch") or 0)
         self._job_id = job_id
         return out
@@ -1782,18 +1778,13 @@ class ClusterBackend:
         return self
 
     def describe_peers(self) -> List[str]:
-        """One line per connected daemon for debug_state dumps: which
-        control-plane core the peer advertised in hello (the async_core
-        capability bit), plus liveness. Mixed clusters — a rolling
-        restart flipping ``async_core``, or an old daemon behind a new
-        driver — are invisible on the wire (frames are byte-identical),
-        so this is the one place an operator can SEE the mix."""
+        """One line per connected daemon for debug_state dumps: its
+        liveness as this driver sees it."""
         out = []
         with self._lock:
             handles = list(self.daemons.values())
         for h in handles:
-            core = "async" if h._async_core_remote else "threaded"
-            out.append(f"daemon {h.node_id.hex()[:8]}: core={core} "
+            out.append(f"daemon {h.node_id.hex()[:8]}: "
                        f"alive={not h.dead}")
         return out
 

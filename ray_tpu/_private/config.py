@@ -235,11 +235,6 @@ FLAG_DEFS = [
     Flag("admission_queue_max", int, 4096, "bounded per-job pending "
          "queue: tasks over quota beyond this many outstanding get a "
          "REJECTED verdict (AdmissionRejectedError) instead of QUEUED"),
-    Flag("async_core", bool, True, "single-threaded asyncio control "
-         "plane: one event loop per process owns every peer socket "
-         "(wire, reply pump, dispatch pass); off falls back to the "
-         "thread-per-connection core (kept for one release; mixed "
-         "clusters interoperate via the async_core hello bit)"),
     Flag("loop_lag_probe_s", float, 0.25, "interval of the event-loop "
          "lag probe behind ray_tpu_event_loop_lag_seconds (a repeating "
          "call_later measuring scheduled-vs-ran skew); 0 disarms"),

@@ -45,11 +45,12 @@ from ray_tpu._private import events as _events
 from ray_tpu._private import eventloop
 from ray_tpu._private import failpoints as _fp
 from ray_tpu._private import rpc
+from ray_tpu._private.aio import AsyncClient, AsyncConnection
 from ray_tpu._private.head import HeadClient, _hb_interval
 from ray_tpu._private.ids import ActorID, NodeID, TaskID
 from ray_tpu._private.lock_sanitizer import tracked_lock
 from ray_tpu._private.task_spec import TaskKind, TaskSpec
-from ray_tpu._private.rpc import Client, Connection, Server, declare
+from ray_tpu._private.rpc import declare
 from ray_tpu.util import metrics as _metrics
 from ray_tpu.util import profiling as _profiling
 
@@ -1007,7 +1008,7 @@ class PullManager:
     def __init__(self, objects: ObjectTable, peer_fn, num_workers: int = 2,
                  chunk: Optional[int] = None):
         self.objects = objects
-        self._peer = peer_fn        # addr -> rpc.Client
+        self._peer = peer_fn        # addr -> AsyncClient
         self.chunk = chunk if chunk is not None else _pull_chunk()
         self._cv = threading.Condition()
         self._heap: list = []
@@ -1121,7 +1122,7 @@ class _BatchTaskConn:
     __slots__ = ("service", "conn", "task_hex", "key", "trace",
                  "term_pump")
 
-    def __init__(self, service: "DaemonService", conn: Connection,
+    def __init__(self, service: "DaemonService", conn: AsyncConnection,
                  task_hex: str, key: tuple, trace=None,
                  term_pump: bool = False):
         self.service = service
@@ -1193,28 +1194,21 @@ class _BatchReplyPump:
         # result_flush span sink (daemon lane); None in bare-pump tests
         self.task_events = task_events
         self.node_hex = node_hex
-        self._cv = threading.Condition()
+        self._lock = threading.Lock()
         # conn -> [(outcome, t_add)]: t_add is perf_counter at buffering
         # for traced outcomes (0.0 untraced — no clock read)
-        self._buf: Dict[Connection, list] = {}  #: guarded by self._cv
-        # async core: the pump is a call_later chain on the event loop —
-        # one cross-thread wake per linger WINDOW (the arming hop), not
-        # one per completion, and the flush runs where the write batcher
+        self._buf: Dict[AsyncConnection, list] = {}  #: guarded by self._lock
+        # The pump is a call_later chain on the event loop — one
+        # cross-thread wake per linger WINDOW (the arming hop), not one
+        # per completion, and the flush runs where the write batcher
         # lives, so a chunk's push coalesces with other loop writes.
-        # Threaded core: the dedicated cv-wait thread, as before.
-        self._aloop = eventloop.get_loop() if cfg().async_core else None
-        self._armed = False     #: guarded by self._cv (loop mode)
-        if self._aloop is None:
-            threading.Thread(target=self._loop, daemon=True,
-                             name="batch-reply-pump").start()
+        self._aloop = eventloop.get_loop()
+        self._armed = False     #: guarded by self._lock
 
-    def add(self, conn: Connection, out: Dict[str, Any]) -> None:
+    def add(self, conn: AsyncConnection, out: Dict[str, Any]) -> None:
         t_add = time.perf_counter() if "tr" in out else 0.0
-        with self._cv:
+        with self._lock:
             self._buf.setdefault(conn, []).append((out, t_add))
-            if self._aloop is None:
-                self._cv.notify()
-                return
             if self._armed:
                 return      # a flush is already scheduled: coalesce
             self._armed = True
@@ -1231,7 +1225,7 @@ class _BatchReplyPump:
             self._aloop.call_soon(self._flush_on_loop)
 
     def _flush_on_loop(self) -> None:  #: loop-only
-        with self._cv:
+        with self._lock:
             buf, self._buf = self._buf, {}
             self._armed = False
         failed = False
@@ -1246,59 +1240,22 @@ class _BatchReplyPump:
                     # resend is idempotent at the driver); concurrent
                     # add()s may have re-armed already — checked below
                     failed = True
-                    with self._cv:
+                    with self._lock:
                         self._buf.setdefault(conn, [])[:0] = entries[i:]
                     break
                 i += self.max_per_frame
         if failed:
-            with self._cv:
+            with self._lock:
                 re_arm = not self._armed and bool(self._buf)
                 if re_arm:
                     self._armed = True
             if re_arm:
-                # the 1ms floor is the same retry backoff the threaded
-                # pump applies after a failed pass (no busy-spin at
-                # linger 0 against a failing-but-open connection)
+                # the linger acts as retry backoff too, floored at 1ms
+                # so a linger of 0 cannot busy-spin the pump against a
+                # persistently failing (but not yet closed) connection
                 self._arm_flush(backoff=0.001)
 
-    def _loop(self) -> None:
-        failed_last_pass = False
-        while True:
-            with self._cv:
-                while not self._buf:
-                    self._cv.wait()
-            # short linger: completions that land together leave
-            # together. After a failed pass the linger acts as retry
-            # backoff too — floored so a linger of 0 cannot busy-spin
-            # the pump against a persistently failing (but not yet
-            # closed) connection.
-            linger = self.linger_s
-            if failed_last_pass:
-                linger = max(linger, 0.001)
-            if linger:
-                time.sleep(linger)
-            with self._cv:
-                buf, self._buf = self._buf, {}
-            failed_last_pass = False
-            for conn, entries in buf.items():
-                if conn.closed:
-                    continue
-                i = 0
-                while i < len(entries):
-                    chunk = entries[i:i + self.max_per_frame]
-                    if not self._send_chunk(conn, chunk):
-                        # lost in transit: requeue this chunk AND the
-                        # rest, preserving order — the resend is
-                        # idempotent at the driver. A dead connection
-                        # drops out at the next pass's closed check.
-                        failed_last_pass = True
-                        with self._cv:
-                            self._buf.setdefault(conn, [])[:0] = \
-                                entries[i:]
-                        break
-                    i += self.max_per_frame
-
-    def _send_chunk(self, conn: Connection, chunk) -> bool:
+    def _send_chunk(self, conn: AsyncConnection, chunk) -> bool:
         if _fp.ENABLED:
             try:
                 # drop/error arm = the frame is lost in transit; the
@@ -1430,8 +1387,8 @@ class DaemonService:
             from ray_tpu._private import worker_process as _wp
             _wp.set_arena_info(self.objects.arena_name,
                                self.objects._shm.capacity())
-        self.owner: Optional[Client] = None
-        self.driver_conn: Optional[Connection] = None
+        self.owner: Optional[AsyncClient] = None
+        self.driver_conn: Optional[AsyncConnection] = None
         # fencing epoch minted by the head at register_node (0 =
         # standalone / never registered); stamped into heartbeats,
         # hello replies, and every result/stream frame so drivers can
@@ -1468,7 +1425,7 @@ class DaemonService:
         self._batch_pump = _BatchReplyPump(
             task_events=self.task_events, node_hex=self.node_id.hex())
         self._bundles: Dict[Tuple[str, int], Dict[str, Any]] = {}  #: guarded by self._lock
-        self._peers: Dict[Tuple[str, int], Client] = {}  #: guarded by self._lock
+        self._peers: Dict[Tuple[str, int], AsyncClient] = {}  #: guarded by self._lock
         # cross-language actors: name -> [actor_id, seqno]
         self._xlang_actors: Dict[str, list] = {}   #: guarded by self._lock
         self.head_addr = None            # set by main() in daemon mode
@@ -1599,7 +1556,7 @@ class DaemonService:
         self.notify_driver("worker_log", pid=pid, stream=stream,
                            line=line, node=self.node_id.hex()[:8])
 
-    def _peer(self, addr: Tuple[str, int]) -> Client:
+    def _peer(self, addr: Tuple[str, int]) -> AsyncClient:
         # dial OUTSIDE the lock: holding it across a TCP connect
         # stalled every other peer lookup for the dial's duration.
         # Losing a dial race just closes the extra connection.
@@ -1686,10 +1643,6 @@ class DaemonService:
                 # incarnation (old daemons advertise neither and the
                 # driver accepts frames unfenced)
                 "fence": True,
-                # which wire+dispatch core this daemon runs (frames are
-                # identical either way — purely observational, see
-                # capabilities.py)
-                "async_core": self._batch_pump._aloop is not None,
                 "epoch": self.epoch,
                 # zero-copy object plane: same-host clients attach this
                 # arena by name for direct puts / slot-ref'd gets
@@ -1707,7 +1660,7 @@ class DaemonService:
         if conn is not None and not conn.closed:
             conn.push(kind, **kw)
 
-    def on_disconnect(self, conn: Connection) -> None:
+    def on_disconnect(self, conn: AsyncConnection) -> None:
         cid = None
         try:
             cid = conn.meta.get("arena_client_id")
@@ -2013,7 +1966,7 @@ class DaemonService:
         RPC round trip is gone: the frame is acked once, and completions
         return batched on task_batch_done push frames.
 
-        loop_safe: on the async core this runs inline on the event loop
+        loop_safe: this runs inline on the event loop
         (dedupe is dict ops under a short lock hold; nothing blocks),
         so frame parse -> admission -> ack has zero thread hand-offs.
         The per-task pool submits — which may cold-SPAWN pool threads —

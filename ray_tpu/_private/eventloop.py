@@ -6,7 +6,7 @@ event loop per process owns every peer socket, handlers run inline on
 the loop, and anything blocking is handed to an executor. This module
 is the Python analogue: ONE lazily-started loop thread per process
 (``get_loop``), shared by the rpc wire (``aio.py``), the daemon's reply
-pump, and the node dispatch pass when ``cfg().async_core`` is on.
+pump, and the node dispatch pass.
 
 Instrumentation (docs/observability.md):
 

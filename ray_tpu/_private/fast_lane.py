@@ -431,7 +431,7 @@ class FastLaneClient:
         # Same loop-affinity contract as AsyncClient.call: the lane
         # reply arrives on a reader thread, but blocking the process
         # event loop here would stall every peer the loop serves —
-        # fail loudly instead of deadlocking quietly (async core).
+        # fail loudly instead of deadlocking quietly.
         from ray_tpu._private import eventloop
         if eventloop.on_loop():
             raise RuntimeError(

@@ -51,8 +51,9 @@ import msgpack
 
 from ray_tpu._private import failpoints as _fp
 from ray_tpu._private import rpc
+from ray_tpu._private.aio import AsyncClient, AsyncConnection
 from ray_tpu._private.lock_sanitizer import tracked_lock
-from ray_tpu._private.rpc import HOLD, Client, Connection, Server, declare
+from ray_tpu._private.rpc import HOLD, declare
 
 def _hb_interval() -> float:
     from ray_tpu._private.config import cfg
@@ -256,7 +257,7 @@ class HeadService:
         #: guarded by self._lock
         self._bases: Dict[str, int] = {}   # trimmed-channel log offsets
         #: guarded by self._lock
-        self._parked: Dict[str, List[Tuple[Connection, int, int]]] = {}
+        self._parked: Dict[str, List[Tuple[AsyncConnection, int, int]]] = {}
         self._store: Optional[_HeadStore] = None
         # task-event store: sqlite when persistent, bounded ring in
         # memory otherwise (reference: gcs_task_manager.h:94)
@@ -541,7 +542,7 @@ class HeadService:
                 self._mark_dead(node_id, "drain deadline expired",
                                 drain_expired=True)
 
-    def on_disconnect(self, conn: Connection) -> None:
+    def on_disconnect(self, conn: AsyncConnection) -> None:
         node_id = conn.meta.get("node_id")
         if node_id:
             self._mark_dead(node_id, "connection lost")
@@ -743,7 +744,7 @@ class HeadClient:
         # live per-channel subscriber connections, tracked so close()
         # can actually close them (a parked long-poll otherwise holds
         # its socket open forever)
-        self._sub_clients: List[Client] = []  #: guarded by self._sub_lock
+        self._sub_clients: List[AsyncClient] = []  #: guarded by self._sub_lock
         self._sub_lock = tracked_lock("head_client.subs",
                                       reentrant=False)
         self._retry_policy = None   # built lazily; immutable once made
@@ -867,8 +868,8 @@ class HeadClient:
         return self._call("kv_keys", prefix=prefix, ns=namespace)["keys"]
 
     # pubsub
-    def _sub_swap(self, old: Optional[Client],
-                  new: Optional[Client]) -> None:
+    def _sub_swap(self, old: Optional[AsyncClient],
+                  new: Optional[AsyncClient]) -> None:
         """Track the live subscriber connection for close(). If close()
         already ran, the fresh client is closed on the spot (the dial
         won the race with stop)."""

@@ -259,9 +259,9 @@ class Registry:
     def apply(self, src: str, dst: str, link: str, nbytes: int,
               defer: bool = False):
         """Consult policies for one frame. With ``defer=False`` (the
-        threaded wire) latency/bandwidth delays are slept here and the
-        verdict alone is returned. With ``defer=True`` (the asyncio
-        wire, which must never sleep on the loop) the return is a
+        fast lane's blocking sockets) latency/bandwidth delays are slept
+        here and the verdict alone is returned. With ``defer=True`` (the
+        rpc wire, which must never sleep on the loop) the return is a
         ``(verdict, delay_s)`` pair and the CALLER owes the delay —
         typically a per-connection ``call_later`` chain so delayed
         frames still serialize per link but not across links."""
@@ -443,15 +443,15 @@ def on_recv(sock, nbytes: int) -> Optional[_Verdict]:
 
 
 def on_send_decide(sock, nbytes: int) -> Tuple[Optional[_Verdict], float]:
-    """``on_send`` for the asyncio wire: returns (verdict, delay_s)
+    """``on_send`` for the rpc wire: returns (verdict, delay_s)
     WITHOUT sleeping — the event loop owes the delay via call_later."""
     src, dst, lid = _edge(sock, outbound=True)
     return _registry.apply(src, dst, lid, nbytes, defer=True)
 
 
 def on_recv_decide(sock, nbytes: int) -> Tuple[Optional[_Verdict], float]:
-    """``on_recv`` for the asyncio wire: no sleep, dup suppressed (dup
-    is a send-side effect, matching the threaded path)."""
+    """``on_recv`` for the rpc wire: no sleep, dup suppressed (dup
+    is a send-side effect, as in ``on_recv``)."""
     src, dst, lid = _edge(sock, outbound=False)
     v, delay_s = _registry.apply(src, dst, lid, nbytes, defer=True)
     return (DROP_FRAME if v is DROP_FRAME else None), delay_s

@@ -17,7 +17,6 @@ resource model is process-agnostic so a subprocess worker pool can slot in.
 from __future__ import annotations
 
 import heapq
-import queue
 import threading
 import time
 from collections import OrderedDict, deque
@@ -30,11 +29,6 @@ from ray_tpu._private.object_store import LocalObjectStore
 from ray_tpu._private.task_spec import TaskKind, TaskSpec
 from ray_tpu.util import metrics as _metrics
 
-_DISPATCH_POLL_S = 5.0
-
-# Queue sentinel that only wakes the dispatch loop (None means exit).
-_WAKE = object()
-
 
 def _bump_cluster_epoch() -> None:
     # lazy import: scheduler.py imports this module at top level
@@ -43,17 +37,15 @@ def _bump_cluster_epoch() -> None:
 
 
 class ResourceLedger:
-    """Tracks total/available resources with blocking acquire."""
+    """Tracks total/available resources; acquires never block."""
 
     def __init__(self, total: Dict[str, float]):
         self.total = dict(total)
         self._available = dict(total)
-        self._cond = threading.Condition()
-        # availability-grew hook (async dispatch): fired OUTSIDE the
-        # condition lock after release/release_many/add_total so a
-        # loop-hosted dispatch pass wakes immediately instead of
-        # polling wait_for_change. The threaded dispatch loop keeps
-        # using the condition and never sets this.
+        self._lock = threading.Lock()
+        # availability-grew hook: fired OUTSIDE the lock after
+        # release/release_many/add_total so the node's dispatch pass
+        # wakes immediately instead of waiting for its retry timer.
         self.on_change: Optional[Callable[[], None]] = None
 
     def _fire_on_change(self) -> None:
@@ -68,7 +60,7 @@ class ResourceLedger:
         return all(self.total.get(k, 0.0) >= v for k, v in demand.items())
 
     def try_acquire(self, demand: Dict[str, float]) -> bool:
-        with self._cond:
+        with self._lock:
             if all(self._available.get(k, 0.0) >= v - 1e-9
                    for k, v in demand.items()):
                 for k, v in demand.items():
@@ -77,41 +69,30 @@ class ResourceLedger:
             return False
 
     def release(self, demand: Dict[str, float]) -> None:
-        with self._cond:
+        with self._lock:
             for k, v in demand.items():
                 self._available[k] = min(
                     self._available.get(k, 0.0) + v, self.total.get(k, 0.0))
-            self._cond.notify_all()
         self._fire_on_change()
 
-    def wait_for_change(self, timeout: float) -> None:
-        with self._cond:
-            self._cond.wait(timeout)
-
-    def notify(self) -> None:
-        with self._cond:
-            self._cond.notify_all()
-
     def available(self) -> Dict[str, float]:
-        with self._cond:
+        with self._lock:
             return dict(self._available)
 
     def add_total(self, extra: Dict[str, float]) -> None:
         """Grow capacity in place (placement-group bundle resources)."""
-        with self._cond:
+        with self._lock:
             for k, v in extra.items():
                 self.total[k] = self.total.get(k, 0.0) + v
                 self._available[k] = self._available.get(k, 0.0) + v
-            self._cond.notify_all()
         self._fire_on_change()
         _bump_cluster_epoch()   # can_fit_total answers changed
 
     def remove_total(self, extra: Dict[str, float]) -> None:
-        with self._cond:
+        with self._lock:
             for k, v in extra.items():
                 self.total[k] = max(self.total.get(k, 0.0) - v, 0.0)
                 self._available[k] = max(self._available.get(k, 0.0) - v, 0.0)
-            self._cond.notify_all()
         _bump_cluster_epoch()
 
     def try_acquire_many(self, demand: Dict[str, float],
@@ -122,7 +103,7 @@ class ResourceLedger:
         per queued task)."""
         if max_n <= 0:
             return 0
-        with self._cond:
+        with self._lock:
             n = max_n
             for k, v in demand.items():
                 if v <= 0:
@@ -137,19 +118,16 @@ class ResourceLedger:
 
     def release_many(self, groups) -> None:
         """Release a batch of completions' demands under ONE lock
-        acquisition and ONE notify — the drain-side sibling of
-        :meth:`try_acquire_many`. ``groups`` is an iterable of
+        acquisition and ONE ``on_change`` wake — the drain-side sibling
+        of :meth:`try_acquire_many`. ``groups`` is an iterable of
         ``(demand, count)`` pairs (same-shape completions pre-grouped
-        by the caller); per-task release paid a lock round-trip plus a
-        notify_all — and therefore a dispatch-thread wakeup — per
-        completed task."""
-        with self._cond:
+        by the caller)."""
+        with self._lock:
             for demand, count in groups:
                 for k, v in demand.items():
                     self._available[k] = min(
                         self._available.get(k, 0.0) + v * count,
                         self.total.get(k, 0.0))
-            self._cond.notify_all()
         self._fire_on_change()
 
 
@@ -498,7 +476,6 @@ class Node:
         self.pressure_level = "ok"
         self.actors: Dict[ActorID, ActorExecutor] = {}  #: guarded by self._actors_lock
         self._actors_lock = tracked_lock("node.actors", reentrant=False)
-        self._queue: "queue.Queue[Optional[TaskSpec]]" = queue.Queue()
         # Backlog bucketed by exact resource shape: one dispatch pass
         # is O(#shapes), not O(#queued tasks) — with a deep uniform
         # backlog (the reference's 1M+ queued-task envelope) a flat
@@ -516,14 +493,11 @@ class Node:
                                           reentrant=False)
         self._running: set = set()      #: guarded by self._running_lock
         self._running_lock = tracked_lock("node.running", reentrant=False)
-        # Coalesced ledger-release staging (flat combining): completing
-        # tasks append here; whichever thread finds no flush in
-        # progress drains the whole batch with ONE release_many call.
-        # Uncontended completions flush inline (no added latency);
-        # under a drain storm hundreds of releases share one ledger
-        # lock acquisition and one dispatch-thread wakeup.
+        # Coalesced ledger-release staging: completing tasks append
+        # here and the dispatch pass drains the whole batch with ONE
+        # release_many call, so under a drain storm hundreds of
+        # releases share one ledger lock acquisition.
         self._release_stage: List[Dict[str, float]] = []  #: guarded by self._stage_lock
-        self._stage_flushing = False    #: guarded by self._stage_lock
         self._stage_lock = tracked_lock("node.release_stage",
                                         reentrant=False)
         from ray_tpu._private.config import cfg
@@ -535,29 +509,19 @@ class Node:
         # queue lag surfaced in debug_state dumps).
         self.loop_stats = {"dispatch_iterations": 0, "tasks_launched": 0,
                            "max_queue_lag_ms": 0.0, "launch_ms_total": 0.0}
-        # async core: the dispatch pass is a callback on the process
-        # event loop — submit, release and dispatch share one thread,
-        # so the cross-thread convoys (queue.Queue futex wake per
-        # enqueue, ledger condition notify per completion, dispatch
-        # thread wakeup per release) disappear. Producers stage on
-        # plain deques and arm ONE call_soon_threadsafe per burst
-        # behind a dirty flag. Threaded core: the dedicated dispatcher
-        # thread below, unchanged.
-        if cfg().async_core:
-            from ray_tpu._private import eventloop
-            self._aloop = eventloop.get_loop()
-            self._inbox: deque = deque()     # GIL-atomic append/popleft
-            self._wake_armed = False         # dirty flag (benign races)
-            self._stopped = False            #: loop-only
-            self._retry_timer = None         #: loop-only
-            self._dispatcher = None
-            self.ledger.on_change = self._wake_loop
-        else:
-            self._aloop = None
-            self._dispatcher = threading.Thread(
-                target=self._dispatch_loop, daemon=True,
-                name=f"dispatch-{node_id.hex()[:8]}")
-            self._dispatcher.start()
+        # The dispatch pass is a callback on the process event loop —
+        # submit, release and dispatch share one thread, so there is no
+        # cross-thread convoy (a futex wake per enqueue, a condition
+        # notify per completion, a dispatcher wakeup per release).
+        # Producers stage on plain deques and arm ONE
+        # call_soon_threadsafe per burst behind a dirty flag.
+        from ray_tpu._private import eventloop
+        self._aloop = eventloop.get_loop()
+        self._inbox: deque = deque()     # GIL-atomic append/popleft
+        self._wake_armed = False         # dirty flag (benign races)
+        self._stopped = False            #: loop-only
+        self._retry_timer = None         #: loop-only
+        self.ledger.on_change = self._wake_loop
 
     def info(self) -> NodeInfo:
         return NodeInfo(node_id=self.node_id, alive=self.alive,
@@ -580,14 +544,10 @@ class Node:
         self._post(spec)
 
     def _post(self, item) -> None:
-        """Dispatch-input hand-off. Threaded core: the blocking queue
-        (one futex wake per item). Async core: stage on a plain deque
-        and coalesce wakes behind the dirty flag — one
-        call_soon_threadsafe per BURST of submissions, not one per
-        task."""
-        if self._aloop is None:
-            self._queue.put(item)
-            return
+        """Dispatch-input hand-off: stage on a plain deque and coalesce
+        wakes behind the dirty flag — one call_soon_threadsafe per
+        BURST of submissions, not one per task. ``None`` stops the
+        pass for good."""
         self._inbox.append(item)
         self._wake_loop()
 
@@ -596,7 +556,7 @@ class Node:
         # second pass finds empty stages and returns; a producer that
         # loses the other way (flag already True) is covered by the
         # armed pass, which drains AFTER clearing the flag
-        if self._wake_armed or self._aloop is None:
+        if self._wake_armed:
             return
         self._wake_armed = True
         try:
@@ -628,32 +588,10 @@ class Node:
                 avail[k] = avail.get(k, 0.0) - v
         return avail
 
-    def _dispatch_loop(self) -> None:
-        while True:
-            # Move newly queued tasks into the backlog buckets.
-            try:
-                timeout = 0.0 if self._backlog_n else _DISPATCH_POLL_S
-                while True:
-                    spec = self._queue.get(timeout=timeout)
-                    if spec is None:
-                        return
-                    if spec is _WAKE:
-                        timeout = 0.0
-                        continue
-                    self._ingest(spec)
-                    timeout = 0.0
-            except queue.Empty:
-                pass
-            progressed = self._dispatch_pass()
-            if self._backlog_n and not progressed:
-                self.ledger.wait_for_change(0.05)
-
-    def _ingest(self, spec: TaskSpec) -> None:
-        """Bucket one submitted spec into the backlog (dispatch thread
-        or event loop — whichever owns the backlog in this mode)."""
+    def _ingest(self, spec: TaskSpec) -> None:  #: loop-only
+        """Bucket one submitted spec into the backlog."""
         # re-read per spec: the runtime attaches the tenancy manager
-        # right after construction, but the dispatcher may have
-        # captured a stale None before the first enqueue
+        # right after construction
         ten = self.tenancy
         key = tuple(sorted(spec.resources.items()))
         if ten is not None:
@@ -666,7 +604,7 @@ class Node:
         self._backlog_n += 1
 
     def _loop_pass(self) -> None:  #: loop-only
-        """One dispatch round on the process event loop (async core).
+        """One dispatch round on the process event loop.
 
         Producers (submit handlers, completing workers, ledger
         releases) stage work on plain deques and arm at most one of
@@ -691,16 +629,13 @@ class Node:
             if item is None:
                 self._stopped = True
                 return
-            if item is _WAKE:
-                continue
             self._ingest(item)
         if self._stopped:
             return
         progressed = self._dispatch_pass()
         if self._backlog_n and not progressed and not self._stopped:
             # blocked on resources/quota with no release in flight —
-            # poll-retry, mirroring the threaded loop's
-            # wait_for_change(0.05); a real release cancels this timer
+            # poll-retry every 50 ms; a real release cancels this timer
             # via the ledger's on_change wake
             self._retry_timer = self._aloop.call_later(
                 0.05, self._retry_pass)
@@ -710,10 +645,9 @@ class Node:
         self._loop_pass()
 
     def _dispatch_pass(self) -> bool:
-        """One admission pass over the backlog buckets (shared by the
-        threaded dispatcher and the loop-hosted async pass). Returns
-        whether any bucket made progress; the caller decides how to
-        wait when blocked (condition poll vs call_later retry)."""
+        """One admission pass over the backlog buckets. Returns whether
+        any bucket made progress; ``_loop_pass`` arms a retry timer
+        when none did."""
         ten = self.tenancy
         if not self.alive:
             self._fail_backlog()
@@ -784,7 +718,7 @@ class Node:
                             # queue phase: backlog enqueue ->
                             # dispatch-loop admission. t0 is reused
                             # as the span end: zero extra clock
-                            # reads on the dispatch thread.
+                            # reads in the dispatch pass.
                             from ray_tpu._private import events as _ev
                             _ev.record_phase_rt(
                                 spec, "queue", lag_ms / 1000.0,
@@ -847,45 +781,14 @@ class Node:
     # -- coalesced ledger release (flat combining) -----------------------
     def stage_release(self, resources: Dict[str, float]) -> None:
         """Release ledger resources, coalescing concurrent completions:
-        if another thread is already flushing, this release rides its
-        drain (one ledger acquisition + one notify for the whole
-        batch); otherwise this thread flushes inline — the uncontended
-        single-task case keeps the old release latency.
-
-        Async core: every release stages and the LOOP drains the whole
-        batch at the top of its next pass — the completing worker
-        thread never touches the ledger lock, and a drain storm
-        collapses to one release_many + zero cross-thread dispatch
-        wakeups (the pass it woke is already the one dispatching)."""
-        if self._aloop is not None:
-            with self._stage_lock:
-                self._release_stage.append(resources)
-            self._wake_loop()
-            return
+        every release stages and the LOOP drains the whole batch at the
+        top of its next pass — the completing worker thread never
+        touches the ledger lock, and a drain storm collapses to one
+        release_many + zero cross-thread dispatch wakeups (the pass it
+        woke is already the one dispatching)."""
         with self._stage_lock:
             self._release_stage.append(resources)
-            if self._stage_flushing:
-                return      # the in-flight flusher drains us too
-            self._stage_flushing = True
-        self._drain_release_stage()
-
-    def _drain_release_stage(self) -> None:
-        while True:
-            with self._stage_lock:
-                batch = self._release_stage
-                if not batch:
-                    self._stage_flushing = False
-                    return
-                self._release_stage = []
-            try:
-                self._release_batch(batch)
-            except BaseException:
-                # never leave the flusher flag stuck: staged entries
-                # appended meanwhile drain on the NEXT stage_release
-                # call (it sees _stage_flushing False and flushes)
-                with self._stage_lock:
-                    self._stage_flushing = False
-                raise
+        self._wake_loop()
 
     def _release_batch(self, batch) -> None:
         if len(batch) == 1:
@@ -907,8 +810,8 @@ class Node:
         """Run runtime notifications off the event loop. The lost/
         drained callbacks resubmit through the scheduler and may do
         blocking RPC (AsyncClient.call raises on the loop by design),
-        so a loop-hosted dispatch pass ships them to a helper thread;
-        a plain caller (threaded core, shutdown path) runs inline."""
+        so the dispatch pass ships them to a helper thread; a caller
+        off the loop (the shutdown path) runs inline."""
         from ray_tpu._private import eventloop
         if eventloop.on_loop():
             threading.Thread(target=fn, daemon=True,
@@ -934,15 +837,15 @@ class Node:
         """Enter the DRAINING state: running tasks finish, the dispatch
         loop returns queued work to the runtime, the scheduler stops
         placing here. Runs on any thread; the backlog itself is only
-        touched by the dispatch thread (woken via the sentinel)."""
+        touched by the dispatch pass, woken here."""
         self.draining = True
         # DRAINING must leave cached pick_node candidate sets NOW, not
         # at the next natural invalidation
         _bump_cluster_epoch()
-        self._post(_WAKE)
+        self._wake_loop()
 
     def _resubmit_backlog(self) -> None:
-        """Graceful-drain pass (dispatch thread only): queued tasks that
+        """Graceful-drain pass (dispatch pass only): queued tasks that
         have not been bounced before go back to the cluster scheduler;
         a task the scheduler sent BACK here (nothing else fits) keeps
         its spot and dispatches locally — no resubmit ping-pong."""

@@ -15,14 +15,17 @@ Design choices, TPU-first rationale:
 - Framing: ``u32 length | msgpack map``. msgpack handles bytes natively,
   so serialized task payloads embed without base64.
 
-Server model: one decode thread per connection feeding a shared handler
-pool — requests PIPELINE (the reference multiplexes gRPC streams the
+Server model (``aio.py``): ONE event loop per process owns every peer
+socket; requests PIPELINE (the reference multiplexes gRPC streams the
 same way). Per-connection arrival order is preserved for ordinary
-handlers via a FIFO lane; handlers that may block mark themselves
-``@concurrent`` to run outside the lane. Dispatch is by method name to
-a service object (``handle_<method>``). A handler may return ``HOLD``
-to park the request (long-poll; reference ``pubsub/publisher.h:300``)
-and complete it later via ``Connection.reply``.
+handlers via a FIFO lane on a shared pool; handlers that may block mark
+themselves ``@concurrent`` to run outside the lane, handlers that never
+block mark themselves ``@loop_safe`` to run inline on the loop. Dispatch
+is by method name to a service object (``handle_<method>``). A handler
+may return ``HOLD`` to park the request (long-poll; reference
+``pubsub/publisher.h:300``) and complete it later via the connection's
+``reply``. This module holds the wire's shared state (schemas, errors,
+markers, counters) and the two factories, ``serve`` and ``connect``.
 """
 
 from __future__ import annotations
@@ -30,13 +33,8 @@ from __future__ import annotations
 import socket
 import struct
 import threading
-import time
-from collections import deque
 from typing import Any, Callable, Dict, Optional, Tuple
 
-import msgpack
-
-from ray_tpu._private import failpoints as _fp
 from ray_tpu._private import netchaos as _nc
 
 _LEN = struct.Struct("!I")
@@ -70,7 +68,7 @@ def concurrent(handler):
 
 
 def loop_safe(handler):
-    """Mark a handler as non-blocking: on the async core it runs INLINE
+    """Mark a handler as non-blocking: it runs INLINE
     on the event loop (parse -> handler -> reply with zero thread
     hand-offs; the reply joins the peer's coalesced write batch). The
     contract is strict — no lock that a non-loop thread holds across
@@ -78,7 +76,7 @@ def loop_safe(handler):
     must be staged to an executor by the handler itself. Ordering note:
     loop_safe frames keep arrival order among THEMSELVES (loop FIFO)
     but may run ahead of earlier lane-queued methods from the same
-    peer. The threaded core ignores the marker (lane semantics)."""
+    peer."""
     handler._rpc_loop_safe = True
     return handler
 
@@ -156,53 +154,9 @@ def wire_metric_entries() -> list:
 
 
 # Above this size the `len + blob` concatenation copy costs more than a
-# second syscall: send header and payload as two sendalls under the lock
-# (zero extra copy); below it, one small concat + one syscall wins.
+# second write: header and payload go out as two writes (zero extra
+# copy); below it, one small concat + one write wins.
 SEND_CONCAT_MAX = 64 * 1024
-
-
-def send_frame_bytes(sock: socket.socket, blob, wlock) -> None:
-    """Length-prefixed frame write, shared by rpc and the fast lane.
-    ``blob`` is any bytes-like; large payloads are never copied into a
-    `len + blob` concatenation. ``wlock`` is the connection's
-    write-serialization lock — holding it across the sendall is the
-    contract (frames must not interleave), which is why it must never
-    double as a ledger lock."""
-    n = len(blob)
-    if n > MAX_FRAME:
-        raise RpcError(f"frame too large: {n}")
-    if _nc.ENABLED:
-        # chaos sits BELOW the frame layer: a drop suppresses the WHOLE
-        # frame (never a byte prefix, so framing stays intact); a dup
-        # delivers the same complete frame twice back-to-back
-        verdict = _nc.on_send(sock, n + 4)
-        if verdict is _nc.DROP_FRAME:
-            return
-        if verdict is _nc.DUP_FRAME:
-            _WIRE["bytes_sent"] += n + 4
-            _WIRE["frames_sent"] += 1
-            with wlock:
-                if n <= SEND_CONCAT_MAX:
-                    sock.sendall(_LEN.pack(n) + blob)
-                else:
-                    sock.sendall(_LEN.pack(n))
-                    sock.sendall(blob)
-    _WIRE["bytes_sent"] += n + 4    # lossy-tolerant plain add (hot path)
-    _WIRE["frames_sent"] += 1
-    if n <= SEND_CONCAT_MAX:
-        with wlock:
-            sock.sendall(_LEN.pack(n) + blob)
-        return
-    with wlock:
-        # two-phase write under the SAME lock hold: the header and its
-        # payload must stay adjacent on the stream
-        sock.sendall(_LEN.pack(n))
-        sock.sendall(blob)
-
-
-def _send_frame(sock: socket.socket, obj: Dict[str, Any],
-                wlock: threading.Lock) -> None:
-    send_frame_bytes(sock, msgpack.packb(obj, use_bin_type=True), wlock)
 
 
 def recv_exact(sock: socket.socket, n: int) -> bytearray:
@@ -222,376 +176,18 @@ def recv_exact(sock: socket.socket, n: int) -> bytearray:
     return buf
 
 
-def _recv_frame(sock: socket.socket) -> Dict[str, Any]:
-    while True:
-        (n,) = _LEN.unpack(recv_exact(sock, 4))
-        _WIRE["bytes_recv"] += n + 4    # lossy-tolerant plain add (hot path)
-        _WIRE["frames_recv"] += 1
-        blob = recv_exact(sock, n)
-        if _nc.ENABLED and _nc.on_recv(sock, n + 4) is _nc.DROP_FRAME:
-            continue        # inbound frame lost on the simulated link
-        return msgpack.unpackb(blob, raw=False)
-
-
-# ---------------------------------------------------------------------------
-# client
-# ---------------------------------------------------------------------------
-
-class Client:
-    """One TCP connection to a Server; thread-safe request/reply."""
-
-    def __init__(self, addr: Tuple[str, int], timeout: float = 30.0,
-                 on_push: Optional[Callable[[str, Dict[str, Any]], None]]
-                 = None):
-        self.addr = addr
-        self._sock = socket.create_connection(addr, timeout=10.0)
-        self._sock.settimeout(None)
-        self._sock.setsockopt(socket.IPPROTO_TCP, socket.TCP_NODELAY, 1)
-        self._wlock = threading.Lock()
-        self._id = 0                        #: guarded by self._id_lock
-        self._id_lock = threading.Lock()
-        self._pending: Dict[int, list] = {}  #: guarded by self._plock
-        self._plock = threading.Lock()
-        self._timeout = timeout
-        self._on_push = on_push
-        self.dead = False
-        self._reader = threading.Thread(target=self._read_loop, daemon=True,
-                                        name=f"rpc-client-{addr[1]}")
-        self._reader.start()
-
-    def link(self, peer_role: str, link_id: str = "") -> "Client":
-        """Tag this connection's socket with the peer's chaos-link
-        identity (cold path; chainable: ``Client(addr).link("head")``)."""
-        _nc.register_link(self._sock, peer_role, link_id)
-        return self
-
-    def _read_loop(self) -> None:
-        try:
-            while True:
-                msg = _recv_frame(self._sock)
-                if _fp.ENABLED and _fp.fire(
-                        "rpc.client.recv",
-                        method=msg.get("m", "")) is _fp.DROP:
-                    continue    # reply/push lost in transit
-                rid = msg.get("i")
-                if rid is None:
-                    # server push (no correlation id)
-                    if self._on_push is not None:
-                        try:
-                            self._on_push(msg.get("m", ""), msg)
-                        except Exception:
-                            pass
-                    continue
-                with self._plock:
-                    slot = self._pending.pop(rid, None)
-                if slot is not None:
-                    slot[1] = msg
-                    slot[0].set()
-        except Exception:   # transport death AND injected faults: any
-            # reader exit must fail pending slots, or timeout=None
-            # callers hang forever on a zombie connection
-            self._fail_all()
-
-    def _fail_all(self) -> None:
-        self.dead = True
-        with self._plock:
-            pending, self._pending = self._pending, {}
-        for slot in pending.values():
-            slot[1] = None
-            slot[0].set()
-
-    def call(self, method: str, timeout: Optional[float] = None,
-             **kw) -> Dict[str, Any]:
-        """Blocking request/reply. Raises RemoteError on handler error,
-        RpcError on transport failure."""
-        _validate(method, kw)
-        if self.dead:
-            raise RpcError(f"connection to {self.addr} is dead")
-        with _WIRE_LOCK:
-            _WIRE_CLIENT_REQS[method] = \
-                _WIRE_CLIENT_REQS.get(method, 0) + 1
-            _WIRE["inflight"] += 1
-        try:
-            return self._call_counted(method, timeout, kw)
-        finally:
-            with _WIRE_LOCK:
-                _WIRE["inflight"] -= 1
-
-    def _call_counted(self, method: str, timeout: Optional[float],
-                      kw: Dict[str, Any]) -> Dict[str, Any]:
-        # failpoint BEFORE the pending slot exists: an error arm must
-        # not leak a slot; a DROP arm skips the send so the caller times
-        # out exactly like real frame loss
-        dropped = (_fp.ENABLED and _fp.fire(
-            "rpc.client.send", method=method) is _fp.DROP)
-        if dropped and (timeout if timeout is not None
-                        else self._timeout) is None:
-            # a deadline-less caller (long-poll subscribers) can never
-            # observe a lost frame as a timeout — surface the drop as
-            # transport failure instead of wedging the waiter forever
-            # (on healthy TCP, silent frame loss IS connection death)
-            self._fail_all()
-            raise RpcError(f"send to {self.addr} dropped by failpoint")
-        with self._id_lock:
-            self._id += 1
-            rid = self._id
-        slot = [threading.Event(), None]
-        with self._plock:
-            self._pending[rid] = slot
-        msg = dict(kw)
-        msg["m"] = method
-        msg["i"] = rid
-        try:
-            if not dropped:
-                _send_frame(self._sock, msg, self._wlock)
-        except (OSError, RpcError):
-            self._fail_all()
-            raise RpcError(f"send to {self.addr} failed")
-        if not slot[0].wait(timeout if timeout is not None
-                            else self._timeout):
-            with self._plock:
-                self._pending.pop(rid, None)
-            raise RpcError(f"{method} to {self.addr} timed out")
-        reply = slot[1]
-        if reply is None:
-            raise RpcError(f"connection to {self.addr} died during "
-                           f"{method}")
-        if reply.get("e"):
-            raise RemoteError(reply["e"])
-        return reply
-
-    def notify(self, method: str, **kw) -> None:
-        """Fire-and-forget (no reply expected)."""
-        _validate(method, kw)
-        if (_fp.ENABLED and _fp.fire("rpc.client.send",
-                                     method=method) is _fp.DROP):
-            return              # notification lost in transit
-        msg = dict(kw)
-        msg["m"] = method
-        try:
-            _send_frame(self._sock, msg, self._wlock)
-        except (OSError, RpcError):
-            self._fail_all()
-            raise RpcError(f"send to {self.addr} failed")
-
-    def close(self) -> None:
-        self.dead = True
-        try:
-            # a bare close() does NOT wake a reader blocked in recv()
-            # (the fd may even be reused); shutdown() delivers EOF so
-            # the reader exits and deadline-less callers unblock
-            self._sock.shutdown(socket.SHUT_RDWR)
-        except OSError:
-            pass
-        try:
-            self._sock.close()
-        except OSError:
-            pass
-        self._fail_all()    # idempotent: close() means dead for callers
-
-
-# ---------------------------------------------------------------------------
-# server
-# ---------------------------------------------------------------------------
-
-class Connection:
-    """Server-side handle to one client connection."""
-
-    def __init__(self, sock: socket.socket, peer):
-        self.sock = sock
-        self.peer = peer
-        self.wlock = threading.Lock()
-        self.meta: Dict[str, Any] = {}   # services stash identity here
-        self.closed = False
-        # FIFO lane: ordered handlers from this peer execute one at a
-        # time in arrival order, but OFF the read thread, so decoding
-        # (and @concurrent handlers) pipeline ahead of a slow handler.
-        self._lane: deque = deque()
-        self._lane_lock = threading.Lock()
-        self._lane_busy = False
-
-    def link(self, peer_role: str, link_id: str = "") -> "Connection":
-        """Tag the accepted socket's chaos-link identity — services
-        call this once the peer identifies itself (hello/register)."""
-        _nc.register_link(self.sock, peer_role, link_id)
-        return self
-
-    def reply(self, rid: int, **kw) -> None:
-        msg = dict(kw)
-        msg["i"] = rid
-        try:
-            _send_frame(self.sock, msg, self.wlock)
-        except (OSError, RpcError):
-            self.closed = True
-
-    def reply_error(self, rid: int, err: str) -> None:
-        self.reply(rid, e=err)
-
-    def push(self, method: str, **kw) -> None:
-        """Server-initiated message (no correlation id)."""
-        msg = dict(kw)
-        msg["m"] = method
-        try:
-            _send_frame(self.sock, msg, self.wlock)
-        except (OSError, RpcError):
-            self.closed = True
-
-
-class Server:
-    """Threaded RPC server. ``service`` exposes ``handle_<method>``
-    callables with signature (conn, rid, msg) -> reply dict | HOLD.
-    Optional ``on_disconnect(conn)`` on the service is called when a
-    client connection drops (daemon death detection hook)."""
-
-    def __init__(self, service: Any, host: str = "127.0.0.1",
-                 port: int = 0):
-        self.service = service
-        self._srv = socket.create_server((host, port))
-        self.addr = self._srv.getsockname()
-        self._stop = False
-        self._conns: list = []
-        from ray_tpu._private.thread_pool import DaemonThreadPool
-        self._pool = DaemonThreadPool(128, name=f"rpc-{self.addr[1]}")
-        self._accept_thread = threading.Thread(
-            target=self._accept_loop, daemon=True,
-            name=f"rpc-server-{self.addr[1]}")
-
-    def _run_handler(self, conn: Connection, handler, rid, msg) -> None:
-        try:
-            out = handler(conn, rid, msg)
-            if out is HOLD or rid is None:
-                return
-            conn.reply(rid, **(out or {}))
-        except Exception as e:  # noqa: BLE001 — shipped back; the reply
-            # is inside the try because an unserializable handler return
-            # raises in msgpack, not in the handler
-            if rid is not None:
-                conn.reply_error(rid, f"{type(e).__name__}: {e}")
-
-    def _drain_lane(self, conn: Connection) -> None:
-        while True:
-            with conn._lane_lock:
-                if not conn._lane:
-                    conn._lane_busy = False
-                    return
-                handler, rid, msg, t_enq = conn._lane.popleft()
-            try:    # lane dwell: time queued behind same-peer requests
-                from ray_tpu.util.metrics import note_queue_dwell
-                note_queue_dwell("rpc.lane",
-                                 time.perf_counter() - t_enq)
-            except Exception:
-                pass
-            try:
-                self._run_handler(conn, handler, rid, msg)
-            except BaseException:   # never wedge the lane
-                with conn._lane_lock:
-                    conn._lane_busy = False
-                raise
-
-    def start(self) -> "Server":
-        self._accept_thread.start()
-        return self
-
-    def _accept_loop(self) -> None:
-        while not self._stop:
-            try:
-                sock, peer = self._srv.accept()
-            except OSError:
-                return
-            sock.setsockopt(socket.IPPROTO_TCP, socket.TCP_NODELAY, 1)
-            conn = Connection(sock, peer)
-            self._conns.append(conn)
-            threading.Thread(target=self._serve_conn, args=(conn,),
-                             daemon=True,
-                             name=f"rpc-conn-{peer[1]}").start()
-
-    def _serve_conn(self, conn: Connection) -> None:
-        try:
-            while not self._stop:
-                msg = _recv_frame(conn.sock)
-                method = msg.get("m", "")
-                if _fp.ENABLED and _fp.fire(
-                        "rpc.server.recv", method=method) is _fp.DROP:
-                    continue    # request lost before dispatch
-                rid = msg.get("i")
-                with _WIRE_LOCK:
-                    _WIRE_SERVER_REQS[method] = \
-                        _WIRE_SERVER_REQS.get(method, 0) + 1
-                handler = getattr(self.service, f"handle_{method}", None)
-                if handler is None:
-                    if rid is not None:
-                        conn.reply_error(rid, f"no such method {method!r}")
-                    continue
-                if getattr(handler, "_rpc_concurrent", False):
-                    # Dedicated thread, NOT the shared pool: @concurrent
-                    # handlers may block for minutes (object pulls), and
-                    # enough of them would exhaust the pool and stall
-                    # every connection's lane drain.
-                    threading.Thread(
-                        target=self._run_handler,
-                        args=(conn, handler, rid, msg), daemon=True,
-                        name=f"rpc-conc-{method}").start()
-                    continue
-                with conn._lane_lock:
-                    conn._lane.append((handler, rid, msg,
-                                       time.perf_counter()))
-                    if conn._lane_busy:
-                        continue
-                    conn._lane_busy = True
-                self._pool.submit(lambda: self._drain_lane(conn))
-        except (RpcError, OSError):
-            pass
-        finally:
-            conn.closed = True
-            try:
-                conn.sock.close()
-            except OSError:
-                pass
-            cb = getattr(self.service, "on_disconnect", None)
-            if cb is not None and not self._stop:
-                try:
-                    cb(conn)
-                except Exception:
-                    pass
-
-    def stop(self) -> None:
-        self._stop = True
-        try:
-            self._srv.close()
-        except OSError:
-            pass
-        for conn in self._conns:
-            try:
-                conn.sock.close()
-            except OSError:
-                pass
-
-
-# ---------------------------------------------------------------------------
-# core selection: ONE pair of factories gates the async rebuild. Both
-# cores speak identical frames, so a threaded peer and an async peer
-# interoperate on the same socket — cfg().async_core is a per-process
-# choice (advertised via the async_core hello bit), not a wire version.
-# ---------------------------------------------------------------------------
-
 def serve(service: Any, host: str = "127.0.0.1", port: int = 0):
-    """Build the configured server core (NOT started — call .start())."""
-    from ray_tpu._private.config import cfg
-    if cfg().async_core:
-        from ray_tpu._private.aio import AsyncServer
-        return AsyncServer(service, host=host, port=port)
-    return Server(service, host=host, port=port)
+    """Build a server (NOT started — call .start())."""
+    from ray_tpu._private.aio import AsyncServer
+    return AsyncServer(service, host=host, port=port)
 
 
 def connect(addr: Tuple[str, int], timeout: float = 30.0,
             on_push: Optional[Callable[[str, Dict[str, Any]], None]]
             = None):
-    """Dial with the configured client core."""
-    from ray_tpu._private.config import cfg
-    if cfg().async_core:
-        from ray_tpu._private.aio import AsyncClient
-        return AsyncClient(addr, timeout=timeout, on_push=on_push)
-    return Client(addr, timeout=timeout, on_push=on_push)
+    """Dial a server: one TCP connection, thread-safe request/reply."""
+    from ray_tpu._private.aio import AsyncClient
+    return AsyncClient(addr, timeout=timeout, on_push=on_push)
 
 
 def wait_for_server(addr: Tuple[str, int], timeout: float = 15.0) -> None:

@@ -937,7 +937,7 @@ class Runtime:
         while time.monotonic() < deadline:
             with node._running_lock:
                 busy = bool(node._running)
-            if not busy and node._backlog_n == 0 and node._queue.empty():
+            if not busy and node._backlog_n == 0 and not node._inbox:
                 # Clean drain: sweep again — results stored (and actors
                 # created) WHILE draining live on this node too — then
                 # leave the cluster with zero reconstruction debt.

@@ -57,7 +57,7 @@ class PushManager:
     def __init__(self, objects, peer_fn, locate_fn=None,
                  chunk: Optional[int] = None, num_workers: int = 2):
         self.objects = objects
-        self._peer = peer_fn            # addr -> rpc.Client
+        self._peer = peer_fn            # addr -> AsyncClient
         self._locate = locate_fn        # oid -> [addr] holding a copy
         self.chunk = chunk if chunk is not None else _push_chunk_size()
         self._cv = threading.Condition()

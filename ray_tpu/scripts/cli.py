@@ -202,7 +202,6 @@ def cmd_stop(args) -> int:
     host, port = address.rsplit(":", 1)
     from ray_tpu._private import rpc
     from ray_tpu._private.head import HeadClient
-    from ray_tpu._private.rpc import Client
 
     stopped = 0
     try:
@@ -211,7 +210,7 @@ def cmd_stop(args) -> int:
             if not info["alive"]:
                 continue
             try:
-                Client(tuple(info["addr"]), timeout=5.0).call(
+                rpc.connect(tuple(info["addr"]), timeout=5.0).call(
                     "daemon_stop", timeout=2.0)
                 stopped += 1
             except (rpc.RpcError, OSError):

@@ -91,7 +91,7 @@ class TenancyManager:
         #: dispatched. The submit-time verdict folds this in so a BURST
         #: of submits sees its own outstanding demand: usage alone made
         #: the QUEUED verdict a race against the dispatch pass (the
-        #: async core coalesces dispatch wakes, so a tight submit loop
+        #: node coalesces dispatch wakes, so a tight submit loop
         #: can finish before the first task is ever marked running).
         self._inflight: Dict[str, Dict[str, float]] = {}
         #: guarded by self._lock — quota/weight records awaiting head sync

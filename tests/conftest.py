@@ -39,6 +39,15 @@ def _cleanup_runtime():
         ray_tpu.shutdown()
 
 
+@pytest.hookimpl(tryfirst=True)
+def pytest_collection_modifyitems(config, items):
+    """Every collected test's marker names, taken before ``-m``
+    deselects any of them (tests/test_tiers.py reads it)."""
+    config.collected_marks = {
+        item.nodeid: {m.name for m in item.iter_markers()}
+        for item in items}
+
+
 def pytest_sessionfinish(session, exitstatus):
     """Lock-sanitizer gate (tools/run_chaos.sh sanitized stage): the
     -W error escalation only fails tests whose inversion fires on the

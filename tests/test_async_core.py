@@ -1,19 +1,21 @@
-"""Asyncio control-plane core (cfg().async_core): tier-1 units.
+"""The control-plane core (``aio.py`` on ``eventloop.py``): tier-1 units.
 
-Pins the contracts the async rewrite introduced:
+Pins the core's contracts:
 
+- one core: ``rpc.serve`` / ``rpc.connect`` build the ``aio`` classes
+  and nothing else, no flag selects another, no dispatcher or pump
+  thread exists, and no hello key names a core;
 - loop-affinity sanitizer: ``eventloop.assert_loop`` is armed by
   ``lock_sanitizer`` and catches loop-only code running on a plain
   thread (the runtime leg of raylint's static loop-affinity pass);
 - coalesced writes: a burst of frames staged on the loop leaves in ONE
   ``transport.write`` (the ``daemon_core.cc`` one-sendmsg-per-peer
   model), with large payloads skipping the join copy;
-- failpoint + netchaos parity: the async wire honors the SAME seam
-  names and frame-level chaos semantics as the threaded core, so chaos
-  schedules and fault-injection tests are core-agnostic;
-- mixed-cluster interop: daemons advertise their core in the hello
-  ``async_core`` bit; frames are byte-identical so a threaded daemon
-  under an async driver (and vice versa) just works;
+- failpoints and netchaos on the wire: the seams of
+  ``docs/fault_tolerance.md`` fire, and a chaos delay is a
+  ``call_later`` chain on its link, never a sleep on the shared loop
+  (the frame-level cases live in ``tests/test_failpoints.py`` and
+  ``tests/test_netchaos.py``);
 - loop-lag watchdog: a blocked loop shows up in
   ``ray_tpu_event_loop_lag_seconds`` and the slow-callback counter;
 - metric-registry pollution pin: a ``clear_registry()`` in one test
@@ -140,7 +142,7 @@ def test_write_batcher_big_payload_skips_join_copy():
 
 
 # ---------------------------------------------------------------------------
-# failpoint parity on the async wire (same seam names as the threaded core)
+# failpoints and netchaos on the wire
 # ---------------------------------------------------------------------------
 
 class _EchoSvc:
@@ -164,22 +166,7 @@ def _async_pair(svc, timeout=0.5, chaos_roles=None):
     return server, client
 
 
-def test_failpoint_server_recv_drop_parity():
-    svc = _EchoSvc()
-    server, client = _async_pair(svc, timeout=0.3)
-    try:
-        assert client.call("ac_echo", v=1)["v"] == 1
-        fp.activate("rpc.server.recv=drop:max=1")
-        with pytest.raises(rpc.RpcError):
-            client.call("ac_echo", v=2)
-        assert client.call("ac_echo", v=3)["v"] == 3
-        assert fp.fire_count("rpc.server.recv") == 1
-    finally:
-        client.close()
-        server.stop()
-
-
-def test_failpoint_client_send_drop_parity():
+def test_failpoint_client_send_drop():
     svc = _EchoSvc()
     server, client = _async_pair(svc, timeout=0.2)
     try:
@@ -188,45 +175,6 @@ def test_failpoint_client_send_drop_parity():
             client.call("ac_echo", v=1)
         assert client.call("ac_echo", v=2)["v"] == 2
         assert fp.fire_count("rpc.client.send") == 1
-    finally:
-        client.close()
-        server.stop()
-
-
-# ---------------------------------------------------------------------------
-# netchaos parity on the async wire (below the frame layer, loop never
-# sleeps — delays ride call_later chains)
-# ---------------------------------------------------------------------------
-
-def test_netchaos_partition_and_heal_parity():
-    svc = _EchoSvc()
-    server, client = _async_pair(svc, chaos_roles=("t", "svc"))
-    try:
-        assert client.call("ac_echo", v=1)["v"] == 1
-        nc.activate("t>svc=partition")
-        with pytest.raises(rpc.RpcError):
-            client.call("ac_echo", v=2)
-        assert svc.calls == 1           # request never arrived
-        assert nc.injected_count("drop") >= 1
-        nc.reset()
-        assert client.call("ac_echo", v=3)["v"] == 3    # link healed
-    finally:
-        client.close()
-        server.stop()
-
-
-def test_netchaos_duplicate_suppressed_at_caller_parity():
-    svc = _EchoSvc()
-    server, client = _async_pair(svc, timeout=2.0,
-                                 chaos_roles=("t", "svc"))
-    try:
-        nc.activate("t>svc=dup=1.0")
-        assert client.call("ac_echo", v=7)["v"] == 7
-        deadline = time.monotonic() + 2.0
-        while svc.calls < 2 and time.monotonic() < deadline:
-            time.sleep(0.01)
-        assert svc.calls == 2           # the wire really duplicated
-        assert nc.injected_count("dup") >= 1
     finally:
         client.close()
         server.stop()
@@ -266,29 +214,90 @@ def test_netchaos_latency_delays_without_blocking_loop():
 
 
 # ---------------------------------------------------------------------------
-# mixed-cluster interop via the hello async_core capability bit
+# one core: no second implementation, no flag, no thread, no hello key
 # ---------------------------------------------------------------------------
 
-@pytest.mark.parametrize("daemon_core", ["0", "1"])
-def test_mixed_cluster_hello_bit(monkeypatch, daemon_core):
-    """Daemon processes inherit RAY_TPU_ASYNC_CORE from the driver's
-    environment; the driver's own core is pinned the opposite way via
-    _system_config (which wins locally but is NOT inherited). Both
-    mixes must execute tasks — frames are byte-identical across cores —
-    and the hello bit must report the daemon's actual core."""
-    import ray_tpu
-    monkeypatch.setenv("RAY_TPU_ASYNC_CORE", daemon_core)
-    driver_async = daemon_core == "0"   # always the opposite core
-    rt = ray_tpu.init(num_nodes=1, resources={"CPU": 2},
-                      cluster="daemons",
-                      _system_config={"async_core": driver_async})
+def test_rpc_factories_build_the_one_implementation():
+    svc = _EchoSvc()
+    server = rpc.serve(svc).start()
+    client = rpc.connect(server.addr, timeout=2.0)
     try:
-        handles = list(rt.cluster_backend.daemons.values())
-        assert len(handles) == 1
-        assert handles[0]._async_core_remote is (daemon_core == "1")
-        want = "async" if daemon_core == "1" else "threaded"
+        assert type(server) is AsyncServer
+        assert type(client) is AsyncClient
+        assert client.call("ac_echo", v=4)["v"] == 4
+    finally:
+        client.close()
+        server.stop()
+    for name in ("Server", "Client", "Connection"):
+        assert not hasattr(rpc, name), f"rpc.{name} is back"
+
+
+def test_no_flag_selects_a_core():
+    from ray_tpu._private import config
+    assert "async_core" not in {f.name for f in config.FLAG_DEFS}
+    try:
+        with pytest.raises(ValueError, match="async_core"):
+            config.apply_system_config({"async_core": False})
+    finally:
+        config.reset()
+
+
+def _core_threads():
+    return [t.name for t in threading.enumerate()
+            if t.name.startswith("dispatch-")
+            or t.name == "batch-reply-pump"]
+
+
+def test_no_dispatcher_and_no_pump_thread(monkeypatch):
+    """The environment name that once asked for the thread-per-
+    connection core is read by nothing: dispatch and the reply pump
+    stay on the loop, and a task runs."""
+    import ray_tpu
+    from ray_tpu._private import config
+    from ray_tpu._private.daemon import _BatchReplyPump
+    monkeypatch.setenv("RAY_TPU_ASYNC_CORE", "0")
+    config.reset()
+    ray_tpu.init(num_nodes=2, resources={"CPU": 2})
+    try:
+        _BatchReplyPump()
+
+        @ray_tpu.remote
+        def f(x):
+            return x + 1
+
+        assert ray_tpu.get([f.remote(i) for i in range(8)],
+                           timeout=60) == list(range(1, 9))
+        assert _core_threads() == []
+    finally:
+        ray_tpu.shutdown()
+        config.reset()
+
+
+def test_hello_names_no_core_and_an_old_daemons_key_is_ignored(
+        monkeypatch):
+    """A daemon's hello reply carries no ``async_core`` key; a reply
+    from an older daemon that still does is read as any unknown key
+    is, and the driver connects and runs tasks."""
+    import ray_tpu
+    from ray_tpu._private.cluster import DaemonHandle
+    sent = []
+    real_call = DaemonHandle._call
+
+    def call(self, method, **kw):
+        out = real_call(self, method, **kw)
+        if method == "hello_driver":
+            sent.append(dict(out))
+            out["async_core"] = False   # what an older daemon added
+        return out
+
+    monkeypatch.setattr(DaemonHandle, "_call", call)
+    rt = ray_tpu.init(num_nodes=1, resources={"CPU": 2},
+                      cluster="daemons")
+    try:
+        assert sent and all("async_core" not in out for out in sent)
         peers = rt.cluster_backend.describe_peers()
-        assert any(f"core={want}" in line for line in peers)
+        assert len(peers) == 1 and "alive=True" in peers[0]
+        assert "core=" not in peers[0]
 
         @ray_tpu.remote
         def f(x):

@@ -603,44 +603,36 @@ def test_release_many_clamps_at_total_like_release():
     assert led.available() == single.available()
 
 
-def test_release_many_wakes_dispatch_waiter():
-    """release_many must notify the ledger condition — a dispatch loop
-    parked in wait_for_change wakes when a batch of completions lands."""
-    import threading
+def test_release_many_wakes_the_dispatch_pass():
+    """release_many must fire the ledger's on_change hook, once for the
+    batch and outside the ledger's lock — the node's dispatch pass,
+    blocked on resources, wakes when a batch of completions lands."""
     from ray_tpu._private.node import ResourceLedger
-    led = ResourceLedger({"CPU": 1.0})
-    assert led.try_acquire_many({"CPU": 1.0}, 1) == 1
-    woke = threading.Event()
-
-    def waiter():
-        led.wait_for_change(5.0)
-        woke.set()
-
-    t = threading.Thread(target=waiter)
-    t.start()
-    time.sleep(0.1)
-    led.release_many([({"CPU": 1.0}, 1)])
-    assert woke.wait(2.0), "release_many never notified the condition"
-    t.join()
+    led = ResourceLedger({"CPU": 2.0})
+    assert led.try_acquire_many({"CPU": 1.0}, 2) == 2
+    woke = []
+    # reading the ledger from the hook deadlocks if the lock is held
+    led.on_change = lambda: woke.append(led.available())
+    led.release_many([({"CPU": 1.0}, 2)])
+    assert woke == [{"CPU": 2.0}], "release_many never woke the pass"
 
 
 def test_recv_exact_shared_implementation():
-    """One recv helper for rpc + fast_lane; recv_into semantics and the
-    two-phase large-frame send survive a round trip."""
+    """One recv helper for rpc + fast_lane; recv_into semantics
+    survive a round trip of a small and a large frame."""
     import socket
+    import struct
     import threading
 
     from ray_tpu._private import fast_lane, rpc
     assert fast_lane._recv_exact is rpc.recv_exact
 
     a, b = socket.socketpair()
-    lock = threading.Lock()
     big = b"q" * (rpc.SEND_CONCAT_MAX + 1000)
     sender = threading.Thread(
-        target=lambda: (rpc.send_frame_bytes(a, b"small", lock),
-                        rpc.send_frame_bytes(a, big, lock)))
+        target=lambda: [a.sendall(struct.pack("!I", len(blob)) + blob)
+                        for blob in (b"small", big)])
     sender.start()
-    import struct
     (n1,) = struct.unpack("!I", rpc.recv_exact(b, 4))
     assert bytes(rpc.recv_exact(b, n1)) == b"small"
     (n2,) = struct.unpack("!I", rpc.recv_exact(b, 4))

@@ -54,8 +54,8 @@ def test_chaos_every_nth_rpc_drop_converges(seed):
 
     rpc.declare("bump", "k")
     svc = Svc()
-    server = rpc.Server(svc).start()
-    client = rpc.Client(server.addr, timeout=0.25)
+    server = rpc.serve(svc).start()
+    client = rpc.connect(server.addr, timeout=0.25)
     fp.activate("rpc.server.recv=drop:every=3", seed=seed)
     policy = RetryPolicy(max_attempts=6, base_s=0.005,
                          max_backoff_s=0.02)
@@ -668,7 +668,7 @@ host, port, oid_hex = sys.argv[1], int(sys.argv[2]), sys.argv[3]
 from ray_tpu._private import rpc
 rpc.declare("get_object", "oid", "prefer_shm")
 rpc.declare("create_object", "oid", "size")
-c = rpc.Client((host, port), timeout=10)
+c = rpc.connect((host, port), timeout=10)
 out = c.call("get_object", oid=bytes.fromhex(oid_hex),
              prefer_shm=True, slot_ok=True)
 assert out.get("slot") is not None, out

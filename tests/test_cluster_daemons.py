@@ -298,13 +298,13 @@ def test_concurrent_pull_dedup(monkeypatch):
         blob = bytes(bytearray(8 * 1024 * 1024))
         a.put_object_blob(b"oid-dedup", blob)
         results = []
-        from ray_tpu._private.rpc import Client
+        from ray_tpu._private import rpc
 
         def pull():
             # separate connection per puller: the server processes one
             # connection's requests sequentially, so sharing one would
             # serialize the pulls instead of racing them
-            cli = Client(b.addr, timeout=120.0)
+            cli = rpc.connect(b.addr, timeout=120.0)
             try:
                 out = cli.call("pull_object", oid=b"oid-dedup",
                                from_addr=list(a.addr), priority=2)

@@ -14,6 +14,7 @@ import time
 import pytest
 
 import ray_tpu
+from ray_tpu._private import aio
 from ray_tpu._private import failpoints as fp
 from ray_tpu._private import rpc
 from ray_tpu._private.retry import RetryPolicy, record_retry
@@ -266,17 +267,21 @@ def test_record_retry_exports_prometheus_text():
 # wired seams (cheap: no daemon cluster)
 # ---------------------------------------------------------------------------
 
-def test_rpc_server_recv_drop_times_out_then_recovers():
+@pytest.mark.parametrize("build", ["rpc.serve", "aio"])
+def test_rpc_server_recv_drop_times_out_then_recovers(build):
     """A dropped request vanishes on the wire: the caller times out,
-    a retry goes through, and the hit log shows exactly one drop."""
+    a retry goes through, and the hit log shows exactly one drop —
+    through the factories and through the classes they build."""
 
     class Svc:
         def handle_echo(self, conn, rid, msg):
             return {"v": msg["v"]}
 
     rpc.declare("echo", "v")
-    server = rpc.Server(Svc()).start()
-    client = rpc.Client(server.addr, timeout=0.3)
+    serve, connect = {"rpc.serve": (rpc.serve, rpc.connect),
+                      "aio": (aio.AsyncServer, aio.AsyncClient)}[build]
+    server = serve(Svc()).start()
+    client = connect(server.addr, timeout=0.3)
     try:
         assert client.call("echo", v=1)["v"] == 1
         fp.activate("rpc.server.recv=drop:max=1")
@@ -296,8 +301,8 @@ def test_rpc_client_send_drop_with_retry_policy_converges():
             return {"v": msg["v"]}
 
     rpc.declare("echo", "v")
-    server = rpc.Server(Svc()).start()
-    client = rpc.Client(server.addr, timeout=0.2)
+    server = rpc.serve(Svc()).start()
+    client = rpc.connect(server.addr, timeout=0.2)
     try:
         fp.activate("rpc.client.send=drop:max=2")
         policy = RetryPolicy(max_attempts=5, base_s=0.0)
@@ -483,8 +488,8 @@ def test_rpc_client_recv_drop_loses_reply_then_recovers():
             return {"v": msg["v"]}
 
     rpc.declare("echo2", "v")
-    server = rpc.Server(Svc()).start()
-    client = rpc.Client(server.addr, timeout=0.3)
+    server = rpc.serve(Svc()).start()
+    client = rpc.connect(server.addr, timeout=0.3)
     try:
         assert client.call("echo2", v=1)["v"] == 1
         fp.activate("rpc.client.recv=drop:max=1")
@@ -548,9 +553,9 @@ def test_drain_announce_drop_loses_the_notice():
     import types
 
     svc = HeadService()
-    server = rpc.Server(svc).start()
+    server = rpc.serve(svc).start()
     try:
-        # register through the handler directly: an rpc.Client would
+        # register through the handler directly: a real client would
         # mark the node dead on disconnect (conn.meta fencing)
         svc.handle_register_node(
             types.SimpleNamespace(meta={}, link=lambda *a: None), 1,

@@ -333,7 +333,7 @@ def test_head_client_publish_survives_head_restart():
     from ray_tpu._private.head import HeadClient, HeadService
 
     svc = HeadService()
-    server = rpc.Server(svc, host="127.0.0.1", port=0).start()
+    server = rpc.serve(svc, host="127.0.0.1", port=0).start()
     port = server.addr[1]
     client = HeadClient(("127.0.0.1", port), reconnect_window=10.0)
     try:
@@ -346,7 +346,7 @@ def test_head_client_publish_survives_head_restart():
 
         def restart():
             time.sleep(0.4)
-            holder["server"] = rpc.Server(
+            holder["server"] = rpc.serve(
                 svc2, host="127.0.0.1", port=port).start()
 
         t = threading.Thread(target=restart, daemon=True)
@@ -367,7 +367,7 @@ def test_head_client_close_joins_subscriber_threads():
     from ray_tpu._private.head import HeadClient, HeadService
 
     svc = HeadService()
-    server = rpc.Server(svc, host="127.0.0.1", port=0).start()
+    server = rpc.serve(svc, host="127.0.0.1", port=0).start()
     client = HeadClient(server.addr, reconnect_window=5.0)
     try:
         seen = []

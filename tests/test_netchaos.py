@@ -22,6 +22,7 @@ import time
 
 import pytest
 
+from ray_tpu._private import aio
 from ray_tpu._private import failpoints as fp
 from ray_tpu._private import netchaos as nc
 from ray_tpu._private import rpc
@@ -57,8 +58,8 @@ def test_disarmed_by_default_and_zero_overhead(monkeypatch):
             return {"v": msg["v"]}
 
     rpc.declare("nc_echo", "v")
-    server = rpc.Server(Svc()).start()
-    client = rpc.Client(server.addr, timeout=2.0).link("daemon")
+    server = rpc.serve(Svc()).start()
+    client = rpc.connect(server.addr, timeout=2.0).link("daemon")
     try:
         assert client.call("nc_echo", v=5)["v"] == 5
     finally:
@@ -218,19 +219,28 @@ class _CountingSvc:
 rpc.declare("nc_count", "v")
 
 
-def _pair(svc, timeout=0.5, local_role="t", peer_role="svc"):
-    server = rpc.Server(svc).start()
-    client = rpc.Client(server.addr, timeout=timeout)
+# the two ways a pair is built: the factories every caller of the
+# runtime uses, and the classes they build (tests about the classes)
+BUILDS = {"rpc.serve": (rpc.serve, rpc.connect),
+          "aio": (aio.AsyncServer, aio.AsyncClient)}
+
+
+def _pair(svc, timeout=0.5, local_role="t", peer_role="svc",
+          build="rpc.serve"):
+    serve, connect = BUILDS[build]
+    server = serve(svc).start()
+    client = connect(server.addr, timeout=timeout)
     # per-socket role override: this test process plays role ``t``
     nc.register_link(client._sock, peer_role, local_role=local_role)
     return server, client
 
 
-def test_one_way_partition_request_direction():
+@pytest.mark.parametrize("build", BUILDS)
+def test_one_way_partition_request_direction(build):
     """t>svc partition: requests vanish, the handler never runs, the
     caller gets a TYPED timeout (never a wedged thread)."""
     svc = _CountingSvc()
-    server, client = _pair(svc)
+    server, client = _pair(svc, build=build)
     try:
         assert client.call("nc_count", v=1)["v"] == 1
         nc.activate("t>svc=partition")
@@ -277,12 +287,13 @@ def test_symmetric_partition_blocks_both_directions():
         server.stop()
 
 
-def test_duplicate_delivery_is_suppressed_at_the_caller():
+@pytest.mark.parametrize("build", BUILDS)
+def test_duplicate_delivery_is_suppressed_at_the_caller(build):
     """dup=1.0 delivers every request frame twice: the handler runs
     twice (the wire really duplicated), but the caller observes exactly
     one reply — the second reply's rid finds no pending slot."""
     svc = _CountingSvc()
-    server, client = _pair(svc, timeout=2.0)
+    server, client = _pair(svc, timeout=2.0, build=build)
     try:
         nc.activate("t>svc=dup=1.0")
         assert client.call("nc_count", v=7)["v"] == 7
@@ -324,7 +335,7 @@ rpc.declare("nc_never")
 def _fresh_handle():
     from ray_tpu._private.cluster import ArenaCache, DaemonHandle
     from ray_tpu._private.ids import NodeID
-    server = rpc.Server(_NullSvc()).start()
+    server = rpc.serve(_NullSvc()).start()
     handle = DaemonHandle(NodeID.from_random(), server.addr, None,
                           ArenaCache())
     handle._fence_supported = True

@@ -123,6 +123,21 @@ def test_locality_preference(ray_start_cluster):
             holder = node
             break
     assert holder is not None
+
+    def wait_idle():
+        # get() returns when the result is stored; the task's CPU goes
+        # back to the ledger later, in the node's next dispatch pass.
+        # A CPU still held there outweighs the locality bias (0.25 of
+        # utilization against 0.1), so submit only to an idle cluster.
+        deadline = time.monotonic() + 30
+        while any(n.effective_available() != n.ledger.total
+                  for n in rt.nodes()):
+            assert time.monotonic() < deadline, "cluster never went idle"
+            time.sleep(0.005)
+
     # Sequential submissions (idle cluster each time): locality bias wins.
-    consumer_nodes = [ray_tpu.get(consume.remote(data)) for _ in range(6)]
+    consumer_nodes = []
+    for _ in range(6):
+        wait_idle()
+        consumer_nodes.append(ray_tpu.get(consume.remote(data)))
     assert Histogram(consumer_nodes)[holder.node_id.hex()] >= 5

@@ -220,10 +220,13 @@ def test_many_object_args_one_task(ray_start_regular):
 # ---------------------------------------------------------------------------
 # scale-envelope tier (VERDICT r4 #4): the committed single-host slices
 # of release/benchmarks/README.md:5-31. Marked `envelope` — run via
-# `pytest -m envelope` (tools/run_ci.sh runs them as their own stage).
+# `pytest -m envelope` (tools/run_ci.sh runs them as their own stage) —
+# and `slow`, which is what keeps them out of the tier-1 sweep: its
+# `-m 'not slow'` replaces pytest.ini's default `-m`.
 # ---------------------------------------------------------------------------
 
 @pytest.mark.envelope
+@pytest.mark.slow
 def test_queued_task_backlog_100k(ray_start_regular):
     """100,000 no-op tasks queued before any get, fully drained, with
     drain-rate parity vs a 10k run — the flat-degradation evidence for
@@ -252,6 +255,7 @@ def test_queued_task_backlog_100k(ray_start_regular):
 
 
 @pytest.mark.envelope
+@pytest.mark.slow
 def test_many_actors_5000(ray_start_regular):
     """5,000 live actors all answering (reference envelope: 40k
     cluster-wide on 64 hosts; this is the one-host slice)."""
@@ -272,6 +276,7 @@ def test_many_actors_5000(ray_start_regular):
 
 
 @pytest.mark.envelope
+@pytest.mark.slow
 def test_64_virtual_node_scheduling():
     """64 virtual nodes: spread tasks land on >= 32 distinct nodes and
     a STRICT_SPREAD placement group claims 16 distinct nodes (the

@@ -104,8 +104,7 @@ results["burst_batched_per_s"] = round(burst_batched(), 1)
 # probe 4: tracing overhead — the same burst with spans ON vs OFF.
 # MUST run before the object-plane probe: a put/get phase leaves the
 # driver-side object bookkeeping in a state where traced bursts pay a
-# consistent ~20% (measured on BOTH wire cores, so it predates the
-# async rebuild — see ROADMAP). This row measures the documented ~1%
+# consistent ~20% (see ROADMAP). This row measures the documented ~1%
 # steady-state tracing tax on the burst path, not that interaction.
 # Methodology: 5 PAIRED bursts in one cluster with BALANCED ordering
 # (on-first on even rounds, off-first on odd) and the MEDIAN of the
@@ -282,31 +281,21 @@ fs_consistent = max(on_rates) < min(off_rates)
 results["fairshare_overhead_pct"] = round(fs_overhead, 1)
 results["fairshare_overhead_consistent"] = bool(fs_consistent)
 
-# probe 9: async-core A/B on the queued submit→drain burst — the
-# event-loop core's headline row (docs/performance.md "Asyncio core").
-# Same fresh-cluster alternating methodology as probe 8: each arm gets
-# its own cluster and warm-up; the env var carries the core choice
-# into daemon processes (daemons mode) while apply_system_config pins
-# the driver side. async_submit_drain_ratio is the end-to-end burst
-# rate (submit + drain of the SAME n tasks) async ÷ threaded, so box
-# speed cancels; its floor lives in tools/perf_floor*.json. While the
-# arms run, a sampler thread records the PEAK driver-loop lag gauge —
-# loop_lag_max_s is a budget row (lower is better) gated as a ceiling.
+# probe 9: event-loop lag under the queued submit→drain burst
+# (docs/performance.md "Asyncio core"). Three fresh clusters, each with
+# its own warm-up, submit and drain n tasks; while they run, a sampler
+# thread records the PEAK driver-loop lag gauge — loop_lag_max_s is a
+# budget row (lower is better) gated as a ceiling.
 import threading  # noqa: E402
 
 from ray_tpu.util import metrics as _metrics  # noqa: E402
 
 
-def core_burst(async_on: bool, n=2000) -> float:
-    os.environ["RAY_TPU_ASYNC_CORE"] = "1" if async_on else "0"
-    apply_system_config({"async_core": async_on})
+def core_burst(n=2000) -> None:
     ray_tpu.init(num_nodes=1, resources={"CPU": 8})
     try:
         ray_tpu.get([noop.remote() for _ in range(300)])    # warm pools
-        t0 = time.perf_counter()
-        refs = [noop.remote() for _ in range(n)]
-        ray_tpu.get(refs)
-        return n / (time.perf_counter() - t0)
+        ray_tpu.get([noop.remote() for _ in range(n)])
     finally:
         ray_tpu.shutdown()
 
@@ -330,20 +319,10 @@ def _sample_lag() -> None:
 _sampler = threading.Thread(target=_sample_lag, daemon=True,
                             name="perf-smoke-lag-sampler")
 _sampler.start()
-ab_on, ab_off = [], []
-for i in range(3):
-    if i % 2 == 0:
-        ab_on.append(core_burst(True))
-        ab_off.append(core_burst(False))
-    else:
-        ab_off.append(core_burst(False))
-        ab_on.append(core_burst(True))
+for _ in range(3):
+    core_burst()
 _lag_stop.set()
 _sampler.join(timeout=5.0)
-os.environ.pop("RAY_TPU_ASYNC_CORE", None)
-apply_system_config(None)
-results["async_submit_drain_ratio"] = round(
-    statistics.median(ab_on) / statistics.median(ab_off), 3)
 results["loop_lag_max_s"] = round(_lag_peak[0], 3)
 
 print(json.dumps(results, indent=2))
