@@ -43,6 +43,16 @@ is the in-tree TPU-native equivalent (BASELINE.md config 5):
   the exact part holds the slot's current window and gives ALL its
   blocks back when the slot crosses into the next (``llm/paged_cache.py``,
   docs/serving.md);
+- a model with a RECURRENT STATE (``model.recurrent``: state-space
+  layers among attention layers) gets, beside the block pool and the
+  tables of its attention layers, a state ROW A SLOT of fixed size
+  (``model.state_row_shapes``), in the same tree as the pool: a prefill
+  stops each row's recurrence at that row's own length, a chunked
+  prefill starts each chunk from the state the chunk before left,
+  activation writes the slot's row, the decode step rewrites every
+  slot's row in place, preemption drops it (the re-prefill rebuilds it)
+  and a prefix hit is REFUSED: the shared pages would come without the
+  state at their end (docs/serving.md, "Recurrent state");
 - the parameters are held in the dtype the programs compute in: the
   matmul weights cast once at construction (``model.serving_params``),
   not by every program that reads them; norms and an expert model's
@@ -316,6 +326,12 @@ class ContinuousBatchingEngine:
         self.eva = getattr(model, "eva", None)
         if self.eva is not None:
             self._check_eva_tiles()
+        # A model with a recurrent state: the names of the state's parts
+        # in the cache tree (() for every other model, for which nothing
+        # here differs from what it always was)
+        self.recurrent = bool(getattr(model, "recurrent", False))
+        self._state_names = (tuple(model.state_row_shapes())
+                             if self.recurrent else ())
         covers = block_size * (self.eva[1] if self.eva else 1)
         self.blocks_per_slot = (max_seq + covers - 1) // covers
         if num_blocks is None:
@@ -333,6 +349,9 @@ class ContinuousBatchingEngine:
         # (``model.init_kv_pools``). ``window`` None: one kind, and
         # nothing below this line differs from what it always was.
         kinds = model.layer_kinds
+        if self.recurrent and (kinds or self.eva is not None):
+            raise NotImplementedError(
+                "a recurrent state beside a pool a kind or a part")
         self.window: Optional[int] = (
             model.cfg.sliding_window if kinds and SLIDING in kinds else None)
         table_width = self.blocks_per_slot
@@ -352,7 +371,10 @@ class ContinuousBatchingEngine:
                                        self.num_window_blocks, np.int32)
         elif self.window is None:
             self.window_pool = self._tables_win = None
-            self.kv = model.init_kv_pool(num_blocks + 1, block_size)
+            # (a recurrent model: the state rows, one a slot, in the tree)
+            self.kv = model.init_kv_pool(
+                num_blocks + 1, block_size,
+                *((max_slots,) if self.recurrent else ()))
         else:
             self._layer_kinds = np.asarray(kinds, np.int32)
             self.num_window_blocks = max_slots * window_blocks_per_slot(
@@ -404,6 +426,9 @@ class ContinuousBatchingEngine:
         # or "xla" (the gather over every table): chosen by the model
         # from its configuration and the platform when the engine is built
         self.decode_attention_impl = model.paged_decode_impl()
+        if self.recurrent:      # the state update's kernel or its twin
+            self.decode_attention_impl += (
+                f"+ssm_{self.decode_attention_impl}")
         # An expert model's FFN reports, each decode step, the rows it
         # handed to each expert: summed ON THE DEVICE by the decode
         # program itself and read only when ``stats`` is asked for, so
@@ -432,6 +457,13 @@ class ContinuousBatchingEngine:
             else self._gather_kinds_impl)
         # the prefill's first token: the decode program's sampler, alone
         self._sample = jax.jit(self._sample_impl)
+        if self.recurrent:
+            self._write_state = jax.jit(self._write_state_impl,
+                                        donate_argnums=(0,))
+            # a prompt's first chunk starts from this; the later ones
+            # from what the chunk before left (``_prefill_chunk``)
+            self._zero_state = model.init_state(1)
+            self._chunk_state = self._zero_state
 
         # Every key exists from here on (another thread copies the dict
         # while the loop writes it), flat and JSON-plain; units and
@@ -441,6 +473,9 @@ class ContinuousBatchingEngine:
         # time in ``step()`` outside the phases that wait for the device.
         sparse = model.sparse_decode_plan()
         self._index_topk = sparse["index_topk"]
+        state_bytes = sum(math.prod(self.kv[name].shape)
+                          * self.kv[name].dtype.itemsize
+                          for name in self._state_names)
         self._stats = {"requests": 0, "tokens_generated": 0,
                       "decode_steps": 0,
                       # decode steps whose batch held a row with
@@ -545,7 +580,24 @@ class ContinuousBatchingEngine:
                       ) * jnp.dtype(model.kv_dtype).itemsize,
                       "kv_pool_bytes": sum(
                           math.prod(a.shape) * a.dtype.itemsize
-                          for name, a in self.kv.items() if name != "bases"),
+                          for name, a in self.kv.items()
+                          if name != "bases"
+                          and name not in self._state_names),
+                      # a model with a recurrent state (0 for every
+                      # other): the layers that carry one, a slot's row
+                      # over all of them and all slots' rows in bytes,
+                      # rows written at activation, chunks of a chunked
+                      # prefill that started from the state the chunk
+                      # before left, and prefix hits not taken because
+                      # the shared pages come without a state
+                      "state_layers": len(
+                          self.kv[self._state_names[0]])
+                      if self.recurrent else 0,
+                      "state_row_bytes": state_bytes // max_slots,
+                      "state_bytes": state_bytes,
+                      "state_rows_written": 0,
+                      "state_chunks_carried": 0,
+                      "prefix_hits_refused_recurrent": 0,
                       # bytes of the parameters as the engine holds them
                       "param_bytes": sum(
                           a.nbytes for a in jax.tree.leaves(self.params))}
@@ -611,8 +663,12 @@ class ContinuousBatchingEngine:
         [L, N, Tb, Hkv, D] that admission scatters into pool blocks."""
         N, Tb = tokens.shape
         small = self.model.init_kv_cache(N, Tb)
+        # a recurrence must stop at each row's own length: the padding
+        # behind it would advance the state (attention does not mind:
+        # the rows behind the prompt are overwritten)
         logits, small = self.model.forward_step(
-            params, tokens, small, jnp.zeros((N,), jnp.int32))
+            params, tokens, small, jnp.zeros((N,), jnp.int32),
+            *((lengths,) if self.recurrent else ()))
         last = jnp.take_along_axis(
             logits, (lengths - 1)[:, None, None], axis=1)[:, 0]
         return last, small
@@ -631,7 +687,17 @@ class ContinuousBatchingEngine:
 
         k = pool["k"].at[:, block_ids].set(to_blocks(small["k"]))
         v = pool["v"].at[:, block_ids].set(to_blocks(small["v"]))
-        return {"k": k, "v": v}
+        return dict(pool, k=k, v=v)
+
+    def _write_state_impl(self, pool, state, slots):
+        """A prefill's recurrent state ``state[name]`` [Lm, N, ...] into
+        the slots' rows: row ``r`` into slot ``slots[r]`` of every
+        layer, a padding row (``slots[r]`` = ``max_slots``, out of
+        bounds) nowhere."""
+        return dict(pool, **{
+            name: pool[name].at[:, slots].set(
+                state[name].astype(pool[name].dtype), mode="drop")
+            for name in self._state_names})
 
     def _gather_impl(self, pool, block_ids):
         """Gather prefix blocks [N, Pb] -> dense [L, N, Pb*bs, Hkv, D]."""
@@ -813,10 +879,18 @@ class ContinuousBatchingEngine:
                 req.done.set()
                 self._put_end(req)
                 continue
+            if self.recurrent:
+                # shared pages would come without the state at their
+                # end: the hit is not taken, and counted
+                first = self.pool.chain_hashes(toks[:min(covers, n - 1)],
+                                               covers)
+                self._stats["prefix_hits_refused_recurrent"] += bool(
+                    first and first[0] in self.pool._by_hash)
             # +1 so the first decode write never needs a growth step
             alloc = allocate_slot(self.pool, toks, n + 1,
                                   window_pool=self.window_pool,
-                                  window=self.window)
+                                  window=self.window,
+                                  share=not self.recurrent)
             if alloc is None:
                 # pool can't host it right now — put it back, stop
                 self.waiting.appendleft(req)
@@ -982,6 +1056,7 @@ class ContinuousBatchingEngine:
                 self.params, jnp.asarray(toks), jnp.asarray(lengths))
             self._scatter(small, [(alloc, 0, nb) for _, _, alloc in group],
                           nb, n_pad)
+            self._set_state_rows(small, [slot for slot, _, _ in group], n_pad)
             self._stats["prefills"] += 1
             self._stats["prefill_padded_tokens"] += n_pad * bucket
             toks_out = self._sample_batch(
@@ -992,6 +1067,20 @@ class ContinuousBatchingEngine:
                 self._activate(slot, req, alloc, int(lengths[row]), now)
                 self._emit(slot, int(toks_out[row]))
             self._deliver()
+
+    def _set_state_rows(self, state, slots: List[int], n_pad: int) -> None:
+        """Activation of a model with a recurrent state: what the
+        prefill left, row ``r`` of ``state``, becomes slot ``slots[r]``'s
+        row, so a slot never sees its last tenant's state. Nothing for
+        every other model."""
+        if not self.recurrent:
+            return
+        at = np.full(n_pad, self.max_slots, np.int32)
+        at[:len(slots)] = slots
+        self.kv = self._write_state(
+            self.kv, {name: state[name] for name in self._state_names},
+            jnp.asarray(at))
+        self._stats["state_rows_written"] += len(slots)
 
     def _prefill_phase(self, bucket: int, n: int, n_pad: int) -> _Phase:
         """One admitted group's prefill, host work and device wait alike:
@@ -1065,8 +1154,17 @@ class ContinuousBatchingEngine:
         if self.eva is None:
             ids = self._block_ids([(alloc, 0, pb)], pb_pad, 1, gather=True)
             pk, pv = self._gather(self.kv, jnp.asarray(ids))
-            last_logits, small = self._prefill_prefix(
-                self.params, jnp.asarray(toks), pk, pv, plen, slen)
+            if self.recurrent:
+                # the chunk starts from the state the chunk before left
+                last_logits, small = self._prefill_prefix(
+                    self.params, jnp.asarray(toks), pk, pv, plen, slen,
+                    self._chunk_state)
+                self._chunk_state = {name: small[name]
+                                     for name in self._state_names}
+                self._stats["state_chunks_carried"] += pos > 0
+            else:
+                last_logits, small = self._prefill_prefix(
+                    self.params, jnp.asarray(toks), pk, pv, plen, slen)
         else:
             # an EVA model gathers its window's exact rows before the
             # chunk and all its summary rows: two shapes that do not
@@ -1107,11 +1205,15 @@ class ContinuousBatchingEngine:
         last_logits = None
         # one phase for all its chunks; ``bucket`` is the first chunk's
         with self._prefill_phase(self._bucket_for(min(big, n - pos)), 1, 1):
+            if self.recurrent:
+                self._chunk_state = self._zero_state
             while pos < n:
                 chunk_len = min(big, n - pos)
                 last_logits = self._prefill_chunk(alloc, seq, pos,
                                                   chunk_len)
                 pos += chunk_len
+            if self.recurrent:
+                self._set_state_rows(self._chunk_state, [slot], 1)
             toks_out = self._sample_batch(last_logits, [req], 1)
         with _Phase(self, "engine.emit", "t_emit_s"):
             self._activate(slot, req, alloc, n, time.perf_counter())
@@ -1427,6 +1529,10 @@ class ContinuousBatchingEngine:
         return kv, np.asarray(last_logits[0]), n
 
     def _no_handoff_for_eva(self) -> None:
+        if self.recurrent:
+            raise NotImplementedError(
+                "the prefill/decode handoff carries K/V rows only; a "
+                "model's recurrent state is not part of it yet")
         if self.eva is not None:
             raise NotImplementedError(
                 "the prefill/decode handoff carries K/V rows only; an EVA "

@@ -280,7 +280,7 @@ def allocate_slot(pool: BlockPool, prompt: Sequence[int],
                   reserve_tokens: Optional[int] = None,
                   extra_key: Optional[Tuple] = None,
                   window_pool: Optional[BlockPool] = None,
-                  window: Optional[int] = None
+                  window: Optional[int] = None, share: bool = True
                   ) -> Optional[Tuple[SlotAllocation, int]]:
     """Allocate blocks for a request: longest shared prefix from the
     pool's index + fresh blocks covering the rest of ``reserve_tokens``
@@ -297,13 +297,17 @@ def allocate_slot(pool: BlockPool, prompt: Sequence[int],
     those blocks; the rest of the sliding kind's come with
     ``slide_window`` as the prefill and the decode go.
 
+    ``share`` false: no prefix is taken from the index, whatever it
+    holds (a model with a recurrent state: the shared pages would come
+    without the state at their end); every block is fresh.
+
     Returns (allocation, shared_token_count) or None if the pool cannot
     cover the non-shared remainder right now.
     """
     bs = pool.block_size
     reserve_tokens = max(reserve_tokens or 0, len(prompt))
     hashes = pool.chain_hashes(prompt, bs, extra_key)
-    shared = pool.match_prefix(hashes)
+    shared = pool.match_prefix(hashes) if share else []
     # never share the block holding the LAST prompt token: a FULL-prompt
     # hit would skip prefill entirely and the engine still needs the
     # last-token logits — keep >=1 token of real prefill.

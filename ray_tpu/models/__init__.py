@@ -14,14 +14,16 @@ from ray_tpu.models.llama import LlamaConfig, LlamaModel
 from ray_tpu.models.mla import MLAConfig, MLAModel
 from ray_tpu.models.mlp import MLPConfig, MLPModel
 from ray_tpu.models.moe import MoEConfig, MoEModel
+from ray_tpu.models.nemotron_h import NemotronHConfig, NemotronHModel
 from ray_tpu.models.vit import ViTConfig, ViTModel
 
 __all__ = ["LlamaConfig", "LlamaModel", "MLPConfig", "MLPModel",
            "GPT2Config", "GPT2Model", "ViTConfig", "ViTModel",
-           "MoEConfig", "MoEModel", "MLAConfig", "MLAModel", "model_for"]
+           "MoEConfig", "MoEModel", "MLAConfig", "MLAModel", "NemotronHConfig",
+           "NemotronHModel", "model_for"]
 
 _MODEL_OF = {LlamaConfig: LlamaModel, MoEConfig: MoEModel,
-             MLAConfig: MLAModel,
+             MLAConfig: MLAModel, NemotronHConfig: NemotronHModel,
              GPT2Config: GPT2Model, MLPConfig: MLPModel,
              ViTConfig: ViTModel}
 

@@ -256,9 +256,15 @@ def dropless_expert_ffn(x, router, e_gate, e_up, e_down, *, top_k: int,
                         first_expert=None,
                         z_coef: float = 0.0, lb_coef: float = 0.0,
                         sigmoid_bias=None, weight_scale: float = 1.0,
-                        groups=(1, 1), held=None):
+                        groups=(1, 1), held=None, expert_input=None):
     """x [T, D] -> ``(out [T, D] in ``dtype``, load [E] int32, experts
     [T, K] int32, aux)``.
+
+    ``e_gate`` None: an expert WITHOUT a gate matrix, ``relu(u W1)^2 W2``
+    (``e_up`` is W1, ``e_down`` W2) where the gated one is ``silu(u
+    W_gate) * (u W_up)``. ``expert_input`` [T, Dl]: the experts read THESE
+    rows (experts in a latent: ``e_up`` [E, Dl, F], ``e_down`` [E, F, Dl],
+    ``out`` [T, Dl]) while the router reads ``x``.
 
     router [D, E]; e_gate/e_up [E, D, F]; e_down [E, F, D]. ``load[e]``
     is the number of rows handed to expert ``e``'s grouped matmuls, of
@@ -305,7 +311,8 @@ def dropless_expert_ffn(x, router, e_gate, e_up, e_down, *, top_k: int,
             by = jnp.where(here, flat - first_held, n_held)
         order = jnp.argsort(by, stable=True)          # rows by expert
         sizes = jnp.zeros((E,), jnp.int32).at[flat].add(1)
-        rows = x.astype(dtype)[order // top_k]        # [T*K, D]
+        rows = (x if expert_input is None else expert_input).astype(
+            dtype)[order // top_k]                    # [T*K, D]
         load = sizes if live is None else jnp.zeros((E,), jnp.int32).at[
             flat].add(jnp.repeat(live.astype(jnp.int32), top_k))
         if held is not None:
@@ -313,16 +320,19 @@ def dropless_expert_ffn(x, router, e_gate, e_up, e_down, *, top_k: int,
                 1)[:n_held]
         if first_expert is not None:
             sizes = jax.lax.dynamic_update_slice(
-                jnp.zeros((e_gate.shape[0],), jnp.int32), sizes,
+                jnp.zeros((e_up.shape[0],), jnp.int32), sizes,
                 (first_expert,))
     with jax.named_scope("moe_experts"):
         def grouped(lhs, w):
             return grouped_matmul(lhs, w.astype(dtype), sizes, dtype)
-        act = jax.nn.silu(grouped(rows, e_gate)) * grouped(rows, e_up)
+        if e_gate is None:
+            act = jnp.square(jax.nn.relu(grouped(rows, e_up)))
+        else:
+            act = jax.nn.silu(grouped(rows, e_gate)) * grouped(rows, e_up)
         rows = grouped(act, e_down)                   # [T*K, D]
     with jax.named_scope("moe_combine"):
         back = jnp.argsort(order)                     # un-sort
-        rows = rows[back].reshape(T, top_k, D)
+        rows = rows[back].reshape(T, top_k, rows.shape[-1])
         if held is not None:
             # a row of no group is whatever the grouped matmul left there
             rows = jnp.where(here.reshape(T, top_k, 1), rows, 0)

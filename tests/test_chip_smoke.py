@@ -725,3 +725,57 @@ def test_flash_forward_kernel_lowers_for_v5e(v5e, smoke_sizes):
         assert _mosaic(_flash_forward.lower(
             v5e(1, S, H, D), kv, kv, causal=True, block_q=128, block_k=128,
             interpret=False))
+
+
+# nemotron-3-super-d11.long_decode_ssm: 64 slots x 14,336 at block 32 (448
+# entries a slot), one period MEMEMEMEM*E at the published widths
+SSM_CELL_SLOTS, SSM_CELL_TABLE = 64, 448
+
+
+def test_ssm_cell_decode_program_fits_the_v5e(v5e, placed, monkeypatch):
+    """``nemotron-3-super-d11.long_decode_ssm``'s decode program as the
+    engine jits it: 64 slots x 14,336, five Mamba-2 layers whose state
+    rows (``S`` float32, 4 MiB a slot a layer) the Mosaic state-update
+    kernel rewrites IN PLACE, one attention layer on the paged kernel at
+    GQA 32 / 2, five latent-expert layers that hold 128 of the router's
+    512 experts on the Pallas grouped matmul at 1,024 x 2,688. The v5e's
+    compiler takes it at 10.86 GiB of 15.75 (8.68 of weights, 1.27 of
+    state, 0.875 of pool, 0.03 of temporaries), and nothing of a state
+    stack's, an expert stack's or a projection's shape is among the
+    temporaries: no layer's slice of a stack is copied."""
+    from benchmark import run as harness
+    from benchmark.builders import nemotron_h
+
+    _as_on_the_chip(monkeypatch)
+    cfg = harness.load_json(harness.ROOT,
+                            "benchmark/configs/nemotron-3-super-d11.json")
+    B, bs, maxb = SSM_CELL_SLOTS, CELL_BS, SSM_CELL_TABLE
+    model = nemotron_h.build_model(cfg, maxb * bs)
+    assert model.recurrent and model.paged_decode_impl() == "pallas"
+    assert model.ffn_load_shape() == (5, 512)
+    plan = model.grouped_matmul_plan(B)
+    assert plan["moe_grouped_impl"] == "pallas_gmm"
+    assert plan["moe_gmm_tiling_up"] == "128x512x2688"
+    assert plan["moe_gmm_tiling_down"] == "128x2688x512"
+    pool = jax.eval_shape(lambda: model.init_kv_pool(B * maxb + 1, bs, B))
+    assert pool["k"].shape == (1, B * maxb + 1, bs, 2, 128)
+    assert pool["ssm"].shape == (5, B, 8, 128, 1024)
+    assert pool["ssm"].dtype == jnp.float32
+    assert pool["conv"].shape == (5, B, 3, 10240)
+    params = _engine_params(model)
+    assert sum(a.size for a in jax.tree.leaves(params)) == 4_648_163_712
+    compiled = _engine_decode(model, B * maxb).lower(
+        placed(params), v5e(B, dtype=jnp.int32), placed(pool),
+        v5e(B, maxb, dtype=jnp.int32), v5e(B, dtype=jnp.int32),
+        *_sampling(v5e, B), v5e(5, 512, dtype=jnp.int32)).compile()
+    text = compiled.as_text()
+    # the state kernel a Mamba layer, the attention kernel, two grouped
+    # matmuls an expert layer
+    assert text.count("ssm_state_update_pallas") >= 5
+    assert text.count("tpu_custom_call") >= 16
+    mem = compiled.memory_analysis()
+    total = (mem.argument_size_in_bytes + mem.temp_size_in_bytes
+             + mem.output_size_in_bytes - mem.alias_size_in_bytes)
+    assert total < 11.0 * 2**30
+    one_slots_state = 5 * 8 * 128 * 1024 * 4
+    assert mem.temp_size_in_bytes < 4 * one_slots_state

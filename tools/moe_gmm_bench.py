@@ -386,6 +386,63 @@ def held(name="deepseek-v3.2-d5", L=4, router=256, n_held=16, d=7168, f=2048,
         print(json.dumps(line), flush=True)
 
 
+def latent(name="nemotron-3-super-d11", L=5, router=512, n_held=128,
+           latent_dim=1024, f=2688, top_k=22, rows_asked=(1408, 11264)):
+    """**``latent``**: the gate-less relu^2 experts in a latent
+    (``nemotron-3-super-d11``: 5 x 128 held groups, 1,024 x 2,688 =
+    21 x 128 and its transpose, top-22 of 512) at a decode step's 1,408
+    rows and a prefill chunk's 11,264, groups at the LAST layer's
+    offset: the resolver's tiling (k is split where the whole expert
+    does not fit: 128 x 512 x 2688) against an n-split of the same
+    VMEM class and against ``ragged_dot``."""
+    sys.path.insert(0, os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))))
+    from ray_tpu.ops.moe_dispatch import grouped_matmul, grouped_matmul_impl
+
+    rng = np.random.default_rng(0)
+    keys = jax.random.split(jax.random.key(0), 2)
+    w1, w2 = (jax.random.normal(k, (L * n_held,) + shape, jnp.bfloat16)
+              * 0.02 for k, shape in zip(keys, ((latent_dim, f),
+                                                (f, latent_dim))))
+
+    def pair(grouped):
+        def run(xs, w1, w2, sizes):
+            act = jnp.square(jax.nn.relu(grouped(xs, w1, sizes)))
+            return grouped(act.astype(jnp.bfloat16), w2, sizes)
+        return jax.jit(run)
+
+    for rows in rows_asked:
+        idx = np.stack([rng.permutation(router)[:top_k]
+                        for _ in range(rows // top_k)]).ravel()
+        sizes = np.zeros(L * n_held, np.int32)
+        sizes[(L - 1) * n_held:] = np.bincount(idx[idx < n_held],
+                                               minlength=n_held)
+        xs = jax.random.normal(jax.random.key(rows), (rows, latent_dim),
+                               jnp.bfloat16)
+        line = {"shapes": name, "groups": L * n_held, "rows": rows,
+                "rows_in_a_group": int(sizes.sum()),
+                "experts_with_rows": int((sizes > 0).sum()), "call": "pair",
+                "at": "last"}
+        for call, (k, n) in (("up", (latent_dim, f)), ("down", (f, latent_dim))):
+            impl, tiling = grouped_matmul_impl(rows, k, n, 2)
+            line[call] = {"impl": impl, "tiling": tiling}
+        tm = line["up"]["tiling"][0] if line["up"]["tiling"] else 128
+        reps = 200 if rows <= 2048 else 30
+        for impl, grouped in (
+                ("resolver", lambda a, w, s: grouped_matmul(
+                    a, w, s, jnp.bfloat16)),
+                ("n_split", lambda a, w, s: megablox(
+                    (tm, 1024, 896) if w.shape[1] == latent_dim
+                    else (tm, 896, 1024))(a, w, s)),
+                ("ragged_dot", ragged)):
+            try:
+                line["ms_" + impl] = timed(
+                    pair(grouped), (xs, w1, w2, jnp.asarray(sizes)), reps)
+            except Exception as e:          # noqa: BLE001 - a reading
+                line["ms_" + impl] = f"{type(e).__name__}: {str(e)[:160]}"
+        print(json.dumps(line), flush=True)
+
+
 def main():
     if jax.devices()[0].platform != "tpu":
         sys.exit("moe_gmm_bench: no TPU; a CPU time is not a reading")
@@ -397,6 +454,8 @@ def main():
         chosen()
     elif sys.argv[1:] == ["held"]:
         held()
+    elif sys.argv[1:] == ["latent"]:
+        latent()
     else:
         whole_stack()
 
