@@ -80,11 +80,6 @@ def _run_train(tiny_cpu: bool) -> dict:
         if os.environ.get("BENCH_ATTN"):
             cfg = dataclasses.replace(
                 cfg, attention_impl=os.environ["BENCH_ATTN"])
-        if os.environ.get("BENCH_FBQ"):
-            cfg = dataclasses.replace(
-                cfg, flash_block_q=int(os.environ["BENCH_FBQ"]),
-                flash_block_k=int(os.environ.get(
-                    "BENCH_FBK", os.environ["BENCH_FBQ"])))
     else:
         cfg = LlamaConfig.debug(vocab_size=512, max_seq_len=256)
         batch, seq = 2, 256

@@ -214,7 +214,8 @@ def sizes() -> dict:
                 vocab_size=512, dim=64, n_layers=2, n_heads=4,
                 n_kv_heads=2, ffn_dim=128, max_seq_len=128, remat=False),
             sharded_batch=(8, 128),
-            kernel_shapes=((2, 8, 4, 64),), kernel_seq=256)
+            kernel_shapes=((2, 8, 4, 64),), kernel_seq=256,
+            attention_cases=((256, 8, 4, 64, True), (197, 4, 4, 64, False)))
     llama = LlamaConfig.llama3_1b()
     return dict(
         serve_cfg=dataclasses.replace(llama, max_seq_len=MAX_SEQ),
@@ -240,7 +241,16 @@ def sizes() -> dict:
         # and OLMoE's 16 = 16 heads of 128
         kernel_shapes=((MAX_SLOTS, 32, 8, 64), (MAX_SLOTS, 8, 4, 128),
                        (32, 32, 8, 128), (32, 16, 16, 128)),
-        kernel_seq=2048)
+        kernel_seq=2048,
+        # (S, H, Hkv, D, causal) of the fused training attention: the
+        # kernel shapes' widths at 2,048 (a row block's spans split into
+        # tiles there), the longest sequence that stays in VMEM, ViT-B's
+        # 197 patches (not causal, ending inside a block), and one past
+        # residency, which takes the scan
+        attention_cases=((2048, 32, 8, 64, True), (2048, 8, 4, 128, True),
+                         (2048, 32, 8, 128, True), (2048, 16, 16, 128, True),
+                         (4096, 16, 16, 64, True), (2816, 8, 4, 128, False),
+                         (197, 12, 12, 64, False), (8192, 8, 8, 128, True)))
 
 
 # ---------------------------------------------------------------------------
@@ -267,7 +277,8 @@ def kernels_phase(rep: Report, sz: dict) -> None:
     import numpy as np
 
     from ray_tpu._private.platform import on_chip, pallas_interpret
-    from ray_tpu.ops.attention import _flash_forward, reference_attention
+    from ray_tpu.ops.attention import (_stays_resident, flash_attention,
+                                       reference_attention)
     from ray_tpu.ops.paged_attention import (
         default_impl, paged_decode_attention_pallas,
         paged_decode_attention_reference)
@@ -312,18 +323,45 @@ def kernels_phase(rep: Report, sz: dict) -> None:
                   f"unless interpreted", facts["mosaic"] != interpret,
                   f"tpu_custom_call in compiled text: {facts['mosaic']}")
 
+    # the fused training attention, forward AND backward: o, dq, dk, dv
+    # against the reference's own, at every kernel shape's head width and
+    # at the gate's edges
+    for S, H, Hkv, D, causal in sz["attention_cases"]:
+        shape = (f"S{S}/H{H}/Hkv{Hkv}/D{D}/"
+                 f"{'causal' if causal else 'full'}")
         qkv = (normal(1, S, H, D), normal(1, S, Hkv, D),
                normal(1, S, Hkv, D))
-        flash = dict(causal=True, block_q=128, block_k=128,
-                     interpret=interpret)
-        err = max_diff(_flash_forward(*qkv, **flash),
-                       reference_attention(*qkv, causal=True))
-        rep.check(f"flash forward kernel {shape} matches its reference",
-                  err <= KERNEL_TOL, f"max |diff| {err:.4f} <= {KERNEL_TOL}")
-        facts = program_facts(_flash_forward, *qkv, **flash)
-        rep.check(f"flash forward kernel {shape} is a Mosaic kernel "
-                  f"unless interpreted", facts["mosaic"] != interpret,
-                  f"tpu_custom_call in compiled text: {facts['mosaic']}")
+        weigh = normal(1, S, H, D)
+
+        def o_and_grads(attn):
+            def weighed(q, k, v):
+                o = attn(q, k, v)
+                return (o * weigh).astype(jnp.float32).sum(), o
+            (_, o), grads = jax.value_and_grad(
+                weighed, argnums=(0, 1, 2), has_aux=True)(*qkv)
+            return (o, *grads)
+
+        fused = jax.jit(lambda: o_and_grads(
+            lambda q, k, v: flash_attention(q, k, v, causal)))
+        want = o_and_grads(
+            lambda q, k, v: reference_attention(q, k, v, causal=causal))
+        errs = {name: (max_diff(a, b), float(jnp.max(jnp.abs(b))))
+                for name, a, b in zip(("o", "dq", "dk", "dv"), fused(), want)}
+        rep.check(f"fused attention {shape} matches its reference, forward "
+                  f"and backward",
+                  all(err <= KERNEL_TOL * max(1.0, peak)
+                      for err, peak in errs.values()),
+                  "max |diff| (of a peak of) " + ", ".join(
+                      f"{name} {err:.4f} ({peak:.2f})"
+                      for name, (err, peak) in errs.items())
+                  + f" <= {KERNEL_TOL} x max(1, peak)")
+        # a head's sequence that does not stay in VMEM takes the scan
+        kernels = 2 if _stays_resident(S, S, D, jnp.bfloat16, causal) else 0
+        mosaic = fused.lower().compile().as_text().count("tpu_custom_call")
+        rep.check(f"fused attention {shape} is {kernels} Mosaic kernels "
+                  f"unless interpreted",
+                  mosaic == (0 if interpret else kernels),
+                  f"tpu_custom_call in compiled text: {mosaic} times")
 
 
 # ---------------------------------------------------------------------------
@@ -712,10 +750,11 @@ def train_phase(rep: Report, sz: dict) -> None:
                  {"CPU": 1, "TPU": 1} if on_chip() else {"CPU": 1})
     check_losses(rep, result, cfg.vocab_size)
     facts = result.metrics_history[0]["metrics"]["facts"]
-    rep.info(f"program train_step[gpt2 {batch_shape[0]}x{batch_shape[1]}]: "
-             f"Mosaic kernel {'yes' if facts['mosaic'] else 'no'} "
-             f"(head_dim {cfg.head_dim}: ops.attention picks flash only at "
-             f"head_dim % 128 == 0)")
+    rep.check("the one-chip train step holds the fused attention kernels "
+              "exactly on the chip", facts["mosaic"] == on_chip(),
+              f"train_step[gpt2 {batch_shape[0]}x{batch_shape[1]}, head_dim "
+              f"{cfg.head_dim}, no mesh]: tpu_custom_call in compiled text: "
+              f"{facts['mosaic']}, on chip: {on_chip()}")
 
 
 # ---------------------------------------------------------------------------

@@ -13,7 +13,7 @@ from typing import Any, Dict, Optional
 import jax
 import jax.numpy as jnp
 
-from ray_tpu.ops.attention import attention
+from ray_tpu.ops.attention import attention, use_flash_on
 from ray_tpu.ops.norms import layer_norm
 
 Params = Dict[str, Any]
@@ -79,6 +79,7 @@ class GPT2Model:
         self.cfg = cfg
         self.mesh = mesh
         self.rules = rules
+        self._use_flash = use_flash_on(mesh)
 
     def init(self, rng: jax.Array) -> Params:
         cfg = self.cfg
@@ -125,7 +126,10 @@ class GPT2Model:
             qkv = jnp.einsum("bsd,dthk->bsthk", h, layer["wqkv"].astype(dt))
             qkv = qkv + layer["bqkv"].astype(dt)
             q, kk, vv = qkv[:, :, 0], qkv[:, :, 1], qkv[:, :, 2]
-            o = attention(q, kk, vv, causal=True)
+            # a Mosaic call carries no partitioning rule: under a mesh
+            # the reference, off one what the dispatcher finds tiles
+            o = attention(q, kk, vv, causal=True,
+                          use_flash=self._use_flash)
             o = jnp.einsum("bshk,hkd->bsd", o, layer["wo"].astype(dt))
         with jax.named_scope("norm_residual"):
             x = x + o + layer["bo"].astype(dt)

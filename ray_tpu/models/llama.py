@@ -54,7 +54,8 @@ from typing import Any, Dict, Optional, Tuple
 import jax
 import jax.numpy as jnp
 
-from ray_tpu.ops.attention import attention, reference_attention
+from ray_tpu.ops.attention import (attention, reference_attention,
+                                   use_flash_on)
 from ray_tpu.ops.eva import (chunk_summaries, eva_attention,
                              merge_softmax_parts, visible_summaries)
 from ray_tpu.ops.norms import rms_norm
@@ -101,9 +102,6 @@ class LlamaConfig:
     # "xla" = blockwise online-softmax in pure XLA (O(S·block) memory)
     # — the A/B baseline the Pallas kernel must beat.
     attention_impl: str = "ring"
-    # Pallas flash tile sizes (the per-grid-step overhead vs VMEM dial)
-    flash_block_q: int = 128
-    flash_block_k: int = 128
     # KV-cache decode attention. None (the default): the paged decode
     # program takes the Mosaic kernel on a TPU backend and the XLA
     # reference elsewhere (``LlamaModel.paged_decode_impl``,
@@ -267,6 +265,7 @@ class LlamaModel:
         self.cfg = cfg
         self.mesh = mesh
         self.rules = rules
+        self._use_flash = use_flash_on(mesh)
         self._sp = 1 if mesh is None else mesh.shape.get("sp", 1)
         if self._sp > 1 and cfg.attention_impl == "flash":
             raise ValueError(
@@ -523,21 +522,23 @@ class LlamaModel:
                                                  causal=True)
             from ray_tpu.ops.ring_attention import ring_attention_sharded
             return ring_attention_sharded(q, k, v, self.mesh, causal=True)
-        # sp==1: "flash" forces the Pallas kernel (interpret-mode
-        # off-TPU) with the config's tile sizes; "xla" forces the
-        # blockwise online-softmax fallback; otherwise the dispatcher
-        # auto-selects by platform/shape.
+        # sp==1: "flash" forces ``flash_attention`` (the Pallas kernels,
+        # interpreted off a TPU, with blocks by shape; the scan where a
+        # head's sequence outgrows VMEM); "xla" forces the blockwise
+        # online-softmax scan; otherwise the dispatcher selects by
+        # platform and shape.
         cfg = self.cfg
         if cfg.attention_impl == "flash" and positions is None:
             from ray_tpu.ops.attention import flash_attention
-            # positional: custom_vjp functions reject keyword args
-            return flash_attention(q, k, v, True, cfg.flash_block_q,
-                                   cfg.flash_block_k)
+            return flash_attention(q, k, v, True)
         if cfg.attention_impl == "xla" and positions is None:
             from ray_tpu.ops.attention import blockwise_attention
             return blockwise_attention(q, k, v, causal=True)
+        # under a mesh the reference: a Mosaic call carries no
+        # partitioning rule (``paged_decode_impl`` decides the same way)
         return attention(q, k, v, causal=True, positions_q=positions,
-                         positions_k=positions, use_flash=None)
+                         positions_k=positions,
+                         use_flash=self._use_flash)
 
     # -- the decoder layer: ONE body (``_layer``) for every program. A
     # MODEL of the family differs in ``_qk_norm`` and ``_ffn`` (``MoEModel``

@@ -13,7 +13,7 @@ from typing import Any, Dict, Optional
 import jax
 import jax.numpy as jnp
 
-from ray_tpu.ops.attention import attention
+from ray_tpu.ops.attention import attention, use_flash_on
 from ray_tpu.ops.norms import layer_norm
 
 Params = Dict[str, Any]
@@ -57,6 +57,7 @@ class ViTModel:
         self.cfg = cfg
         self.mesh = mesh
         self.rules = rules
+        self._use_flash = use_flash_on(mesh)
 
     def init(self, rng: jax.Array) -> Params:
         cfg = self.cfg
@@ -103,7 +104,9 @@ class ViTModel:
         h = layer_norm(x, layer["ln1_w"], layer["ln1_b"], eps=cfg.norm_eps)
         qkv = jnp.einsum("bsd,dthk->bsthk", h, layer["wqkv"].astype(dt))
         q, kk, vv = qkv[:, :, 0], qkv[:, :, 1], qkv[:, :, 2]
-        o = attention(q, kk, vv, causal=False)
+        # under a mesh the reference (a Mosaic call cannot be partitioned)
+        o = attention(q, kk, vv, causal=False,
+                      use_flash=self._use_flash)
         x = x + jnp.einsum("bshk,hkd->bsd", o, layer["wo"].astype(dt))
         h = layer_norm(x, layer["ln2_w"], layer["ln2_b"], eps=cfg.norm_eps)
         up = jax.nn.gelu(

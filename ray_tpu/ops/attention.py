@@ -1,12 +1,22 @@
-"""Attention: reference JAX implementation + Pallas TPU flash kernel.
+"""Attention: reference JAX implementation + Pallas TPU flash kernels.
 
 Reference capability: the reference repo delegates attention to vLLM /
 flash-attn CUDA kernels (outside its tree). Here it is in-tree and
 TPU-native:
 
-- ``attention``      — dispatcher; GQA-aware, causal, autodiff-friendly.
-- ``flash_attention``— Pallas online-softmax kernel (HBM→VMEM tiled,
-  MXU matmuls, O(S) memory). Forward kernel + recompute-based VJP.
+- ``attention``      — dispatcher; GQA-aware, causal or not,
+  differentiable. Takes ``flash_attention`` on a TPU where ``kernels_tile``
+  says the shapes tile, the reference everywhere else.
+- ``flash_attention``— a fused pair of Pallas kernels behind one
+  ``custom_vjp``: the forward keeps ``o`` and a row of log-sum-exp, the
+  backward rebuilds ``p`` from q, k and that row a tile at a time and
+  gives dq, dk and dv from one pass. No S x S (nor S x block) array
+  reaches HBM in either direction; tiles above the causal diagonal are
+  never visited. The kernels keep a head's whole sequence in VMEM: a
+  longer one (``_stays_resident``) takes ``blockwise_attention``.
+- ``blockwise_attention`` — the XLA online-softmax scan, O(S x block)
+  memory at any length: what a mesh runs (a Mosaic call cannot be
+  partitioned), what Ulysses runs, and the long sequences' path.
 
 Shapes follow the JAX convention [batch, seq, heads, head_dim].
 """
@@ -125,153 +135,379 @@ def blockwise_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
 
 
 # ---------------------------------------------------------------------------
-# Pallas flash-attention forward kernel
+# Pallas flash attention: ONE fused pair, a forward and a backward kernel
 # ---------------------------------------------------------------------------
+# A grid step holds one head whole in VMEM (q, K, V; in the backward do
+# and the two rows of statistics besides) and walks the score matrix in
+# row blocks whose tiles are STATIC slices: a block's tiles stop at the
+# causal diagonal, only the tile that crosses the mask's edge pays for a
+# mask, and the rest of the row is taken in tiles up to ``_WIDE`` columns.
+# The loops are unrolled as Python, so the kernel is straight-line code
+# the scheduler can overlap (square tiles under ``fori_loop`` with traced
+# bounds ran 1.45x slower on the v5e, PERF.md PR 48). Nothing of
+# S x S or S x block size is written to HBM in either direction; what the
+# forward keeps for the backward is ``o`` and a row of log-sum-exp.
 
-def _flash_fwd_kernel(q_ref, k_ref, v_ref, o_ref, acc_ref, m_ref, l_ref, *,
-                      scale: float, block_q: int, block_k: int, causal: bool,
-                      num_k_blocks: int, seq_k: int):
+_NT = (((1,), (1,)), ((), ()))      # a @ b.T
+_TN = (((0,), (0,)), ((), ()))      # a.T @ b
+# block sizes by shape, measured at 16 x 1,024 x 16 x 64 and x 8 x 128
+# on the v5e: query rows of a forward block, key rows of a backward block
+# (never more than the sequence), and the widest tile of either
+_BLOCK_Q, _BLOCK_K, _WIDE = 512, 256, 1024
+_LANES = 128
+# the kernels are straight-line code, and Mosaic gives every unrolled
+# tile's temporaries VMEM of their own: past this many (query, key) pairs
+# a head (4,096 causal positions, some 50 tiles in the backward and 15-40
+# s of compiling; 2,896 where every key is seen) the forward no longer
+# compiles inside ``_vmem_limit`` on the v5e
+_MAX_UNROLLED = 4096 * 4096 // 2
+# the live tiles of one step of a block's walk (scores, p, dp, ds in
+# float32 at [_BLOCK_K, _WIDE] and their casts), beside what is resident
+_TILES_BYTES = 8 << 20
+# the v5e's (and v6e's) VMEM: what a program that is interpreted, or
+# compiled with no chip, is sized for
+_V5E_VMEM_BYTES = 128 << 20
+
+
+def _dot(a, b, dims=(((1,), (0,)), ((), ()))):
+    """MXU matmul: operands in their own dtype, float32 accumulation."""
+    return jax.lax.dot_general(a, b, dims,
+                               preferred_element_type=jnp.float32)
+
+
+def _visible(q0, k0, shape, *, keys_axis, causal, seq_k):
+    """The mask of one tile: a key past ``seq_k`` is padding, and under
+    ``causal`` a query sees the keys at or before its own position.
+    ``q0``/``k0``: the tile's first query and key; ``keys_axis``: the
+    axis of ``shape`` the keys lie along."""
+    keys = k0 + jax.lax.broadcasted_iota(jnp.int32, shape, keys_axis)
+    mask = keys < seq_k
+    if causal:
+        mask &= q0 + jax.lax.broadcasted_iota(
+            jnp.int32, shape, 1 - keys_axis) >= keys
+    return mask
+
+
+def _tiles(*spans):
+    """A row block's tiles as (start, stop, masked), from its spans
+    (start, stop, whether the span crosses the mask's edge): no tile
+    wider than ``_WIDE``; a span that is empty gives none."""
+    return [(a, min(a + _WIDE, stop), masked)
+            for start, stop, masked in spans
+            for a in range(start, stop, _WIDE)]
+
+
+def _flash_fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *, scale: float,
+                      block: int, causal: bool, seq_k: int):
+    """One head: q [Sq, D] against K and V [Sk, D], a block of ``block``
+    query rows at a time, online softmax over the block's key tiles."""
+    pad_q, pad_k = q_ref.shape[0], k_ref.shape[0]
+    for q0 in range(0, pad_q, block):
+        rows = min(block, pad_q - q0)
+        q = q_ref[q0:q0 + rows, :]
+        # keys [0, edge) are seen by every row of the block (whole
+        # ``block``s of them: the tiles stay aligned), [edge, end) by some
+        edge, end = seq_k // block * block, pad_k
+        if causal:
+            edge = min(edge, (q0 + 1) // block * block)
+            end = min(end, q0 + rows)
+        acc = None
+        for a, b, masked in _tiles((0, edge, False), (edge, end, True)):
+            v = v_ref[a:b, :]
+            s = _dot(q, k_ref[a:b, :], _NT) * scale           # [rows, b-a]
+            if masked:
+                s = jnp.where(_visible(q0, a, s.shape, keys_axis=1,
+                                       causal=causal, seq_k=seq_k),
+                              s, NEG_INF)
+            m_tile = jnp.max(s, axis=-1, keepdims=True)
+            if acc is None:
+                m = m_tile
+                p = jnp.exp(s - m)
+                l = jnp.sum(p, axis=-1, keepdims=True)
+                acc = _dot(p.astype(v.dtype), v)
+            else:
+                m_new = jnp.maximum(m, m_tile)
+                alpha = jnp.exp(m - m_new)
+                p = jnp.exp(s - m_new)
+                l = alpha * l + jnp.sum(p, axis=-1, keepdims=True)
+                acc = acc * alpha + _dot(p.astype(v.dtype), v)
+                m = m_new
+        o_ref[q0:q0 + rows, :] = (acc * (1.0 / l)).astype(o_ref.dtype)
+        # a ROW of the array, positions along the lanes: what the
+        # backward's transposed tiles broadcast, and S floats a head in
+        # HBM (a column would be padded to 128 lanes there)
+        lse = jnp.broadcast_to(m + jnp.log(l), (rows, _LANES))
+        lse_ref[:, q0:q0 + rows] = jnp.transpose(lse)[:1]
+
+
+def _flash_bwd_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
+                      dq_ref, dk_ref, dv_ref, dq_acc, dk_acc, dv_acc, *,
+                      scale: float, block: int, causal: bool, seq_k: int):
+    """One q head's dq and its share of its kv head's dk and dv, a block
+    of ``block`` keys at a time. The tiles are the TRANSPOSED scores
+    [keys, queries]: ``p`` is rebuilt from q, k and the log-sum-exp row
+    and feeds dv, dk and dq from one pass. The q heads of a GQA group are
+    consecutive grid steps that add into one dk and dv."""
     import jax.experimental.pallas as pl
 
-    qi = pl.program_id(1)
-    ki = pl.program_id(2)
-
-    @pl.when(ki == 0)
-    def _init():
-        acc_ref[...] = jnp.zeros_like(acc_ref)
-        m_ref[...] = jnp.full_like(m_ref, NEG_INF)
-        l_ref[...] = jnp.zeros_like(l_ref)
-
-    # Fully-masked blocks (k strictly above the causal diagonal) are skipped.
-    should_run = True
-    if causal:
-        should_run = ki * block_k < (qi + 1) * block_q
-
-    @pl.when(should_run)
-    def _compute():
-        # feed the MXU native dtypes (bf16 in, f32 accumulate) — no
-        # explicit f32 casts of the operands
-        q = q_ref[0]                               # [bq, D]
-        k = k_ref[0]                               # [bk, D]
-        v = v_ref[0]
-        s = jax.lax.dot_general(
-            q, k, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32) * scale     # [bq, bk]
-        cols = ki * block_k + jax.lax.broadcasted_iota(
-            jnp.int32, (block_q, block_k), 1)
-        mask = cols < seq_k  # tail block: don't attend to padding keys
-        # Zero padded V rows: their p weights are exp(NEG_INF)≈0, but
-        # 0 * <uninitialized> is NaN when the pad is NaN (interpret mode),
-        # and garbage-dependent on hardware — make the product exact 0.
-        kvalid = ki * block_k + jax.lax.broadcasted_iota(
-            jnp.int32, (block_k, 1), 0) < seq_k
-        v = jnp.where(kvalid, v, 0)
+    g, group = pl.program_id(1), pl.num_programs(1)
+    pad_q, pad_k = q_ref.shape[0], k_ref.shape[0]
+    for k0 in range(0, pad_k, block):
+        ks = slice(k0, min(k0 + block, pad_k))
+        k, v = k_ref[ks, :], v_ref[ks, :]
+        # queries [first, edge) see some of these keys, [edge, Sq) all
+        first, edge = 0, pad_q if ks.stop > seq_k else 0
         if causal:
-            rows = qi * block_q + jax.lax.broadcasted_iota(
-                jnp.int32, (block_q, block_k), 0)
-            mask = mask & (rows >= cols)
-        s = jnp.where(mask, s, NEG_INF)
-        m_prev = m_ref[:, :1]                      # [bq, 1]
-        l_prev = l_ref[:, :1]
-        m_curr = jnp.max(s, axis=-1, keepdims=True)
-        m_new = jnp.maximum(m_prev, m_curr)
-        alpha = jnp.exp(m_prev - m_new)
-        p = jnp.exp(s - m_new)
-        l_new = alpha * l_prev + jnp.sum(p, axis=-1, keepdims=True)
-        acc_ref[...] = acc_ref[...] * alpha + jax.lax.dot_general(
-            p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
-        m_ref[...] = jnp.broadcast_to(m_new, m_ref.shape)
-        l_ref[...] = jnp.broadcast_to(l_new, l_ref.shape)
+            first = k0
+            edge = max(edge, ks.stop)
+        edge = min(edge, pad_q)
+        dk = dv = jnp.zeros(k.shape, jnp.float32)
+        for a, b, masked in _tiles((first, edge, True),
+                                   (edge, pad_q, False)):
+            q, do = q_ref[a:b, :], do_ref[a:b, :]
+            st = _dot(k, q, _NT) * scale                      # [keys, b-a]
+            if masked:
+                st = jnp.where(_visible(a, k0, st.shape, keys_axis=0,
+                                        causal=causal, seq_k=seq_k),
+                               st, NEG_INF)
+            pt = jnp.exp(st - lse_ref[:, a:b])
+            dv += _dot(pt.astype(do.dtype), do)
+            dpt = _dot(v, do, _NT)
+            dst = (pt * (dpt - delta_ref[:, a:b]) * scale).astype(q.dtype)
+            dk += _dot(dst, q)
+            dq = _dot(dst, k, _TN)                            # [b-a, D]
+            if k0 == 0:         # the first key block is seen by every row
+                dq_acc[a:b, :] = dq
+            else:
+                dq_acc[a:b, :] += dq
 
-    @pl.when(ki == num_k_blocks - 1)
-    def _finalize():
-        l = l_ref[:, :1]
-        l = jnp.where(l == 0.0, 1.0, l)
-        o_ref[0] = (acc_ref[...] / l).astype(o_ref.dtype)
+        @pl.when(g == 0)
+        def _first_of_group():
+            dk_acc[ks, :] = dk
+            dv_acc[ks, :] = dv
+
+        @pl.when(g > 0)
+        def _rest_of_group():
+            dk_acc[ks, :] += dk
+            dv_acc[ks, :] += dv
+
+    dq_ref[...] = dq_acc[...].astype(dq_ref.dtype)
+
+    @pl.when(g == group - 1)
+    def _last_of_group():
+        dk_ref[...] = dk_acc[...].astype(dk_ref.dtype)
+        dv_ref[...] = dv_acc[...].astype(dv_ref.dtype)
 
 
-@functools.partial(jax.jit,
-                   static_argnames=("causal", "block_q", "block_k",
-                                    "interpret"))
-def _flash_forward(q, k, v, *, causal: bool, block_q: int, block_k: int,
+def _vmem_limit() -> int:
+    """What a kernel may take of VMEM: half of the chip's (the backend's
+    TPU, as Pallas describes it: 128 MiB on a v5e or v6e, 64 on a v5p,
+    16 before), so that Mosaic's own buffers and the next grid step's
+    fetches have the rest. The blocks and ``_MAX_UNROLLED`` were measured
+    on the v5e alone (PERF.md PR 48)."""
+    # the backend itself, not ``on_chip``: a program compiled for a
+    # topology with no chip behind it is the v5e's
+    if jax.default_backend() != "tpu":
+        return _V5E_VMEM_BYTES // 2
+    from jax.experimental.pallas import tpu as pltpu
+    return pltpu.get_tpu_info().vmem_capacity_bytes // 2
+
+
+def _stays_resident(seq_q: int, seq_k: int, head_dim: int, dtype,
+                    causal: bool) -> bool:
+    """Whether the kernels take these sequences: no more pairs than they
+    are unrolled for, and the backward's VMEM (the larger of the two)
+    inside ``_vmem_limit``: q, do, k, v in and dq, dk, dv out, each
+    buffered twice, the two rows of statistics (8 sublanes of float32)
+    twice, and three float32 accumulators; a tile's lanes are padded to
+    128."""
+    hidden = min(seq_q, seq_k) if causal else 0     # above the diagonal
+    pairs = seq_q * seq_k - hidden * hidden // 2
+    pad_q, pad_k = (s + -s % _LANES for s in (seq_q, seq_k))
+    width, item = max(head_dim, _LANES), jnp.dtype(dtype).itemsize
+    resident = (2 * item * width * (3 * pad_q + 4 * pad_k)
+                + 2 * 2 * 8 * 4 * pad_q
+                + 4 * width * (pad_q + 2 * pad_k))
+    return (pairs <= _MAX_UNROLLED
+            and resident + _TILES_BYTES <= _vmem_limit())
+
+
+def _by_head(x: jax.Array, num_kv: int) -> jax.Array:
+    """[B, S, H, D] -> [B * Hkv, H // Hkv, S', D]: a kv head's q heads
+    side by side (``_repeat_kv``'s order), S padded with zeros to whole
+    ``_LANES`` (a block's last tile may be short, a row of log-sum-exp
+    is stored in whole lanes)."""
+    batch, seq, heads, head_dim = x.shape
+    x = jnp.pad(x, ((0, 0), (0, -seq % _LANES), (0, 0), (0, 0)))
+    return x.transpose(0, 2, 1, 3).reshape(
+        batch * num_kv, heads // num_kv, x.shape[1], head_dim)
+
+
+def _from_heads(x: jax.Array, batch: int, seq: int) -> jax.Array:
+    """``_by_head``'s inverse, the padding dropped."""
+    return x.reshape(batch, -1, *x.shape[2:])[:, :, :seq] \
+        .transpose(0, 2, 1, 3)
+
+
+def _head_specs(pad_q: int, pad_k: int, head_dim: int):
+    """Block specs over the grid (kv head, q head of its group): a q
+    head's [Sq, D], its row of statistics [1, Sq], a kv head's [Sk, D]
+    (the same block for the whole group: fetched once)."""
+    import jax.experimental.pallas as pl
+    return (pl.BlockSpec((None, None, pad_q, head_dim),
+                         lambda i, g: (i, g, 0, 0)),
+            pl.BlockSpec((None, None, 1, pad_q), lambda i, g: (i, g, 0, 0)),
+            pl.BlockSpec((None, pad_k, head_dim), lambda i, g: (i, 0, 0)))
+
+
+def _compiler_params(*semantics: str):
+    from jax.experimental.pallas import tpu as pltpu
+    return pltpu.CompilerParams(dimension_semantics=semantics,
+                                vmem_limit_bytes=_vmem_limit())
+
+
+@functools.partial(jax.jit, static_argnames=("causal", "block", "interpret"))
+def _flash_forward(q, k, v, *, causal: bool, block: Optional[int],
                    interpret: bool):
+    """-> (o [B, S, H, D], log-sum-exp [B * Hkv, H // Hkv, 1, S']).
+    ``block``: query rows a block."""
+    import jax.experimental.pallas as pl
+
+    batch, seq_q, num_heads, head_dim = q.shape
+    seq_k, num_kv = k.shape[1], k.shape[2]
+    qh = _by_head(q, num_kv)
+    kh, vh = (_by_head(x, num_kv)[:, 0] for x in (k, v))
+    q_spec, row_spec, kv_spec = _head_specs(qh.shape[2], kh.shape[1],
+                                            head_dim)
+    o, lse = pl.pallas_call(
+        functools.partial(_flash_fwd_kernel, scale=head_dim ** -0.5,
+                          block=block or _BLOCK_Q, causal=causal,
+                          seq_k=seq_k),
+        grid=(batch * num_kv, num_heads // num_kv),
+        in_specs=[q_spec, kv_spec, kv_spec],
+        out_specs=[q_spec, row_spec],
+        out_shape=[jax.ShapeDtypeStruct(qh.shape, q.dtype),
+                   jax.ShapeDtypeStruct((*qh.shape[:2], 1, qh.shape[2]),
+                                        jnp.float32)],
+        compiler_params=_compiler_params("parallel", "parallel"),
+        name="flash_attention_fwd_pallas", interpret=interpret,
+    )(qh, kh, vh)
+    return _from_heads(o, batch, seq_q), lse
+
+
+@functools.partial(jax.jit, static_argnames=("causal", "block", "interpret"))
+def _flash_backward(q, k, v, o, lse, do, *, causal: bool,
+                    block: Optional[int], interpret: bool):
+    """-> (dq, dk, dv), shaped as q, k, v. ``block``: key rows a block."""
     import jax.experimental.pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
     batch, seq_q, num_heads, head_dim = q.shape
-    seq_k = k.shape[1]
-    num_kv = k.shape[2]
-    group = num_heads // num_kv
-    scale = head_dim ** -0.5
-
-    bq = min(block_q, seq_q)
-    bk = min(block_k, seq_k)
-    nq = pl.cdiv(seq_q, bq)
-    nk = pl.cdiv(seq_k, bk)
-
-    # Layout [B*H, S, D]: one grid row per (batch, head) pair.
-    qt = q.transpose(0, 2, 1, 3).reshape(batch * num_heads, seq_q, head_dim)
-    kt = k.transpose(0, 2, 1, 3).reshape(batch * num_kv, seq_k, head_dim)
-    vt = v.transpose(0, 2, 1, 3).reshape(batch * num_kv, seq_k, head_dim)
-
-    def kv_index(bh, qi, ki):
-        return (bh // num_heads) * num_kv + (bh % num_heads) // group, ki, 0
-
-    out = pl.pallas_call(
-        functools.partial(_flash_fwd_kernel, scale=scale, block_q=bq,
-                          block_k=bk, causal=causal, num_k_blocks=nk,
+    seq_k, num_kv = k.shape[1], k.shape[2]
+    qh, doh = (_by_head(x, num_kv) for x in (q, do))
+    kh, vh = (_by_head(x, num_kv)[:, 0] for x in (k, v))
+    # delta = rowsum(o * do): what softmax's backward subtracts from dp
+    delta = jnp.sum(o.astype(jnp.float32) * do.astype(jnp.float32), axis=-1,
+                    keepdims=True)
+    delta = _by_head(delta, num_kv).reshape(lse.shape)
+    q_spec, row_spec, kv_spec = _head_specs(qh.shape[2], kh.shape[1],
+                                            head_dim)
+    dq, dk, dv = pl.pallas_call(
+        functools.partial(_flash_bwd_kernel, scale=head_dim ** -0.5,
+                          block=block or _BLOCK_K, causal=causal,
                           seq_k=seq_k),
-        grid=(batch * num_heads, nq, nk),
-        in_specs=[
-            pl.BlockSpec((1, bq, head_dim), lambda bh, qi, ki: (bh, qi, 0)),
-            pl.BlockSpec((1, bk, head_dim), kv_index),
-            pl.BlockSpec((1, bk, head_dim), kv_index),
-        ],
-        out_specs=pl.BlockSpec((1, bq, head_dim),
-                               lambda bh, qi, ki: (bh, qi, 0)),
-        out_shape=jax.ShapeDtypeStruct((batch * num_heads, seq_q, head_dim),
-                                       q.dtype),
-        scratch_shapes=[
-            pltpu.VMEM((bq, head_dim), jnp.float32),
-            pltpu.VMEM((bq, 128), jnp.float32),
-            pltpu.VMEM((bq, 128), jnp.float32),
-        ],
-        compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "parallel", "arbitrary")),
-        interpret=interpret,
-    )(qt, kt, vt)
-    return out.reshape(batch, num_heads, seq_q, head_dim).transpose(0, 2, 1, 3)
+        grid=(batch * num_kv, num_heads // num_kv),
+        in_specs=[q_spec, kv_spec, kv_spec, q_spec, row_spec, row_spec],
+        out_specs=[q_spec, kv_spec, kv_spec],
+        out_shape=[jax.ShapeDtypeStruct(x.shape, x.dtype)
+                   for x in (qh, kh, vh)],
+        scratch_shapes=[pltpu.VMEM(x.shape[-2:], jnp.float32)
+                        for x in (qh, kh, vh)],
+        compiler_params=_compiler_params("parallel", "arbitrary"),
+        name="flash_attention_bwd_pallas", interpret=interpret,
+    )(qh, kh, vh, doh, lse, delta)
+    return (_from_heads(dq, batch, seq_q),
+            _from_heads(dk[:, None], batch, seq_k),
+            _from_heads(dv[:, None], batch, seq_k))
+
+
+def flash_attention(q, k, v, causal: bool = True, block_q=None,
+                    block_k=None, interpret: bool | None = None):
+    """Flash attention, forward and backward, at any length: no S x S
+    array in either. The fused Pallas kernels where a
+    head's sequence stays in VMEM (``_stays_resident``: up to 4,096
+    causal positions on the v5e), the same online softmax as an XLA scan
+    (``blockwise_attention``) beyond. ``block_q`` (the query rows of a
+    forward block), ``block_k`` (the key rows of a backward block) and
+    ``interpret`` are the tests': by shape and by backend when None."""
+    if not _stays_resident(q.shape[1], k.shape[1], q.shape[-1], q.dtype,
+                           causal):
+        return blockwise_attention(q, k, v, causal=causal)
+    return _flash_kernels(q, k, v, causal, block_q, block_k, interpret)
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6))
-def flash_attention(q, k, v, causal: bool = True, block_q: int = 128,
-                    block_k: int = 128, interpret: bool | None = None):
-    """Pallas TPU flash attention. O(S) memory forward; backward recomputes
-    blockwise (remat scan), so training memory stays O(S·block) too."""
-    if interpret is None:
-        interpret = pallas_interpret()
-    return _flash_forward(q, k, v, causal=causal, block_q=block_q,
-                          block_k=block_k, interpret=interpret)
+def _flash_kernels(q, k, v, causal, block_q, block_k, interpret):
+    return _flash_fwd_rule(q, k, v, causal, block_q, block_k, interpret)[0]
+
+
+def _interpreted(interpret: Optional[bool]) -> bool:
+    # decided OUTSIDE the jitted calls: it is part of their cache key
+    return pallas_interpret() if interpret is None else interpret
 
 
 def _flash_fwd_rule(q, k, v, causal, block_q, block_k, interpret):
-    if interpret is None:
-        interpret = pallas_interpret()
-    out = _flash_forward(q, k, v, causal=causal, block_q=block_q,
-                         block_k=block_k, interpret=interpret)
-    return out, (q, k, v)
+    o, lse = _flash_forward(q, k, v, causal=causal, block=block_q,
+                            interpret=_interpreted(interpret))
+    return o, (q, k, v, o, lse)
 
 
 def _flash_bwd_rule(causal, block_q, block_k, interpret, res, g):
-    q, k, v = res
-    _, vjp = jax.vjp(
-        lambda q_, k_, v_: blockwise_attention(q_, k_, v_, causal=causal),
-        q, k, v)
-    return vjp(g)
+    return _flash_backward(*res, g, causal=causal, block=block_k,
+                           interpret=_interpreted(interpret))
 
 
-flash_attention.defvjp(_flash_fwd_rule, _flash_bwd_rule)
+_flash_kernels.defvjp(_flash_fwd_rule, _flash_bwd_rule)
+
+
+def kernels_tile(q: jax.Array, k: jax.Array) -> bool:
+    """Where ``flash_attention`` is what to run, read from the arguments'
+    shapes: a head width the MXU contracts whole (64: half its depth,
+    128, 256), q heads in whole GQA groups, and at least 256 queries.
+    The kernels take shorter sequences too (padded to 128, masked both
+    ways), but there the reference's S x S arrays are small and XLA's
+    fusions win: at 16,384 tokens of head_dim 64 on the v5e the
+    reference's forward + backward takes 0.39 of the kernels' time at
+    128 positions, 0.81 at ViT's 197, 1.01 at 256, 1.99 at 512 (at
+    head_dim 128: 0.63, 1.16, 1.41, 2.86; PERF.md PR 48). Causal or not,
+    a sequence that ends inside a block, and one too long to stay in
+    VMEM (it takes the scan) are all inside."""
+    (_, seq_q, heads, head_dim), num_kv = q.shape, k.shape[2]
+    return (head_dim in (64, 128, 256) and heads % num_kv == 0
+            and seq_q >= 256)
+
+
+def use_flash_on(mesh) -> Optional[bool]:
+    """``attention``'s ``use_flash`` for a model built on ``mesh``, asked
+    once by its constructor. Under a mesh False: the reference, because a
+    Mosaic call carries no partitioning rule. Off one None: the dispatcher
+    reads the shapes; on a TPU it will most likely find kernels to trace,
+    so the import of Pallas, a second of Python that pulls the GPU
+    dialects in, starts here on a daemon thread and runs under the
+    weights' init instead of inside the first trace. Whoever imports
+    Pallas meanwhile waits on the module's import lock for this thread."""
+    if mesh is not None:
+        return False
+    if on_chip():
+        import threading
+
+        def load():
+            import jax.experimental.pallas      # noqa: F401
+            import jax.experimental.pallas.tpu  # noqa: F401
+
+        threading.Thread(target=load, name="pallas-import",
+                         daemon=True).start()
+    return None
 
 
 def attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
@@ -279,12 +515,14 @@ def attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
               positions_q: Optional[jax.Array] = None,
               positions_k: Optional[jax.Array] = None,
               use_flash: Optional[bool] = None) -> jax.Array:
-    """Dispatcher: Pallas flash kernel on TPU when shapes tile cleanly,
-    reference otherwise. Explicit position vectors force the reference path
-    (the kernel assumes contiguous 0..S-1 positions)."""
+    """Dispatcher: ``flash_attention`` on a TPU where the shapes tile
+    (``kernels_tile``), the reference otherwise. Explicit position vectors
+    force the reference path (the kernels assume contiguous 0..S-1
+    positions), and so does a caller whose mesh would partition the call
+    (``use_flash=False``, from ``use_flash_on``)."""
     if use_flash is None:
         use_flash = (on_chip() and positions_q is None and positions_k is None
-                     and q.shape[-1] % 128 == 0 and q.shape[1] >= 128)
+                     and kernels_tile(q, k))
     if use_flash:
         return flash_attention(q, k, v, causal)
     return reference_attention(q, k, v, causal=causal,

@@ -80,6 +80,44 @@ def test_compile_cache_defaults_to_the_checkout(monkeypatch):
             jax.config.update(k, v)
 
 
+def test_a_model_asks_once_whether_its_mesh_allows_the_kernels(monkeypatch):
+    """``use_flash_on``: False under a mesh, None off one, and the import
+    of Pallas starts on a thread only where the backend is a TPU (the
+    fused attention's second of imports then runs under the weights'
+    init). The models' constructors are its callers."""
+    import threading
+
+    from ray_tpu.models.gpt2 import GPT2Config, GPT2Model
+    from ray_tpu.parallel.mesh import MeshSpec, build_mesh
+
+    kernels = importlib.import_module("ray_tpu.ops.attention")
+    started = []
+    monkeypatch.setattr(threading.Thread, "start",
+                        lambda self: started.append(self.name))
+    mesh = build_mesh(MeshSpec(fsdp=1), jax.devices()[:1])
+    assert GPT2Model(GPT2Config.debug(), mesh=mesh)._use_flash is False
+    assert GPT2Model(GPT2Config.debug())._use_flash is None
+    assert started == []
+    monkeypatch.setattr(kernels, "on_chip", lambda: True)
+    assert GPT2Model(GPT2Config.debug(), mesh=mesh)._use_flash is False
+    assert started == []
+    assert GPT2Model(GPT2Config.debug())._use_flash is None
+    assert started == ["pallas-import"]
+
+
+def test_the_import_thread_loads_pallas(monkeypatch):
+    import threading
+
+    kernels = importlib.import_module("ray_tpu.ops.attention")
+    monkeypatch.setattr(kernels, "on_chip", lambda: True)
+    assert kernels.use_flash_on(None) is None
+    for t in threading.enumerate():
+        if t.name == "pallas-import":
+            t.join(120)
+    assert "jax._src.pallas.pallas_call" in sys.modules
+    assert "jax.experimental.pallas.tpu" in sys.modules
+
+
 # ---------------------------------------------------------------------------
 # Mosaic lowering for the v5e, no chip needed
 # ---------------------------------------------------------------------------
@@ -227,7 +265,9 @@ def _as_on_the_chip(monkeypatch):
     is the v5e's)."""
     from ray_tpu.ops import moe_dispatch, paged_attention
 
-    for module in (paged_attention, moe_dispatch):
+    # ``ray_tpu.ops.attention`` the NAME is the dispatcher, not its module
+    attention = importlib.import_module("ray_tpu.ops.attention")
+    for module in (attention, paged_attention, moe_dispatch):
         monkeypatch.setattr(module, "on_chip", lambda: True)
         monkeypatch.setattr(module, "pallas_interpret", lambda: False)
 
@@ -723,8 +763,129 @@ def test_flash_forward_kernel_lowers_for_v5e(v5e, smoke_sizes):
     for _, H, Hkv, D in smoke_sizes["kernel_shapes"]:
         kv = v5e(1, S, Hkv, D)
         assert _mosaic(_flash_forward.lower(
-            v5e(1, S, H, D), kv, kv, causal=True, block_q=128, block_k=128,
+            v5e(1, S, H, D), kv, kv, causal=True, block=None,
             interpret=False))
+
+
+# gpt2-medium.train_1chip's attention, and a GQA one at head_dim 128
+@pytest.mark.parametrize("B,S,H,Hkv,D", [(16, 1024, 16, 16, 64),
+                                         (2, 2048, 32, 8, 128)])
+def test_flash_backward_kernel_lowers_for_v5e(v5e, B, S, H, Hkv, D):
+    from ray_tpu.ops.attention import _flash_backward
+
+    q, kv = v5e(B, S, H, D), v5e(B, S, Hkv, D)
+    lse = v5e(B * Hkv, H // Hkv, 1, S, dtype=jnp.float32)
+    assert _mosaic(_flash_backward.lower(
+        q, kv, kv, q, lse, q, causal=True, block=None, interpret=False))
+
+
+# LlamaConfig.max_seq_len's default, at bench_400m's and at GPT-2's width
+@pytest.mark.parametrize("B,S,H,Hkv,D", [(1, 8192, 8, 8, 128),
+                                         (1, 8192, 16, 16, 64)])
+def test_flash_past_residency_compiles_to_the_scan(v5e, monkeypatch,
+                                                   B, S, H, Hkv, D):
+    """A sequence the kernels do not keep in VMEM: ``attention`` on the
+    chip and a forced ``flash_attention`` both compile, forward and
+    backward, to ``blockwise_attention``'s scan: no Mosaic call, no array
+    of the score matrix's size, temporaries of a few key blocks."""
+    kernels = importlib.import_module("ray_tpu.ops.attention")
+    _as_on_the_chip(monkeypatch)
+    q, kv = v5e(B, S, H, D), v5e(B, S, Hkv, D)
+    assert kernels.kernels_tile(q, kv)
+
+    def loss(attn):
+        return lambda q, k, v: attn(q, k, v).astype(jnp.float32).sum()
+
+    for attn in (lambda q, k, v: kernels.attention(q, k, v, causal=True),
+                 lambda q, k, v: kernels.flash_attention(q, k, v, True)):
+        compiled = jax.jit(jax.grad(loss(attn), argnums=(0, 1, 2))).lower(
+            q, kv, kv).compile()
+        text = compiled.as_text()
+        assert "tpu_custom_call" not in text
+        assert f"{S},{S}" not in text
+        temporaries = compiled.memory_analysis().temp_size_in_bytes
+        print(f"temporaries {temporaries / 2**20:.0f} MiB")
+        # one array of float32 scores would be B * H * S * S * 4 bytes
+        # and the reference's backward holds three; the scan keeps a key
+        # block's scores and one float32 accumulator a key block
+        assert temporaries < B * H * S * S * 4 * 0.6, temporaries / 2**20
+
+
+def _train_program(model, placed, v5e, batch, seq):
+    """``value_and_grad(model.loss)``, lowered for the chip ``placed``
+    puts the parameters on."""
+    tokens = v5e(batch, seq, dtype=jnp.int32)
+    return jax.jit(jax.value_and_grad(model.loss)).lower(
+        placed(jax.eval_shape(model.init, jax.random.key(0))),
+        tokens, tokens)
+
+
+def test_train_cell_attention_is_the_fused_kernels(v5e, placed, monkeypatch):
+    """``gpt2-medium.train_1chip``'s loss and gradient, 16 x 1,024 at the
+    published widths (head_dim 64): both fused attention kernels are in
+    the program, forward twice (``remat``), no array of the score
+    matrix's shape is, and the compiler's temporaries are 4.61 GiB
+    (4.68 with the S x S reference, PR 48's issue: what is left is the
+    float32 logits over 50,257, 3.07 GiB an array) beside 1.32 GiB of
+    float32 parameters and as much of gradients."""
+    from benchmark import run as harness
+    from benchmark.builders import gpt2
+
+    _as_on_the_chip(monkeypatch)
+    cfg = harness.load_json(harness.ROOT, "benchmark/configs/gpt2-medium.json")
+    traffic = harness.load_json(harness.ROOT,
+                                "benchmark/traffic/train_1chip.json")
+    B, S = traffic["batch"], traffic["seq_len"]
+    model = gpt2.build_model(cfg, S)
+    assert (model.cfg.head_dim, model.cfg.remat) == (64, True)
+    compiled = _train_program(model, placed, v5e, B, S).compile()
+    text = compiled.as_text()
+    calls = [line for line in text.splitlines() if "tpu_custom_call" in line]
+    assert sum("flash_attention_fwd_pallas" in c for c in calls) == 2
+    assert sum("flash_attention_bwd_pallas" in c for c in calls) == 1
+    assert len(calls) == 3
+    H = model.cfg.n_heads
+    assert f"{B},{H},{S},{S}" not in text and f"{B},{S},{H},{S}" not in text
+    temporaries = compiled.memory_analysis().temp_size_in_bytes / 2**30
+    assert temporaries < 4.65, temporaries
+
+
+def test_train_attention_under_a_mesh_keeps_the_reference(v5e_topo,
+                                                          monkeypatch):
+    """The sibling of ``test_decode_under_a_mesh_keeps_the_reference``:
+    a model that is given a mesh asks the dispatcher for the reference
+    (XLA refuses to partition a Mosaic call), whatever the shapes tile."""
+    from jax.sharding import NamedSharding, PartitionSpec as P
+
+    from ray_tpu.models.gpt2 import GPT2Config, GPT2Model
+    from ray_tpu.models.llama import LlamaConfig, LlamaModel
+    from ray_tpu.parallel.mesh import MeshSpec, build_mesh
+
+    _as_on_the_chip(monkeypatch)
+    mesh = build_mesh(MeshSpec(fsdp=2, tp=2), v5e_topo.devices)
+    B, S = 8, 1024
+
+    def lower(model):
+        shardings = model.param_shardings()
+        params = jax.tree.map(
+            lambda a, s: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=s),
+            jax.eval_shape(model.init, jax.random.key(0)), shardings)
+        tokens = jax.ShapeDtypeStruct(
+            (B, S), jnp.int32, sharding=NamedSharding(mesh, P("fsdp")))
+        return jax.jit(jax.value_and_grad(model.loss)).lower(
+            params, tokens, tokens)
+
+    gpt2 = GPT2Config(vocab_size=4096, dim=1024, n_layers=1, n_heads=16,
+                      max_seq_len=S)
+    llama = LlamaConfig(vocab_size=4096, dim=1024, n_layers=1, n_heads=8,
+                        n_kv_heads=4, ffn_dim=2048, max_seq_len=S)
+    assert (gpt2.head_dim, llama.head_dim) == (64, 128)
+    for model in (GPT2Model(gpt2, mesh=mesh), LlamaModel(llama, mesh=mesh)):
+        assert not _mosaic(lower(model))
+    forced = LlamaModel(dataclasses.replace(llama, attention_impl="flash"),
+                        mesh=mesh)
+    with pytest.raises(NotImplementedError, match="partitioned"):
+        lower(forced).compile()
 
 
 # nemotron-3-super-d11.long_decode_ssm: 64 slots x 14,336 at block 32 (448

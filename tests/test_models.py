@@ -142,8 +142,7 @@ def test_llama_attention_impl_parity():
     tgts = jnp.roll(toks, -1, axis=1)
     losses = {}
     for impl in ("ring", "xla", "flash"):
-        cfg = dataclasses.replace(base, attention_impl=impl,
-                                  flash_block_q=32, flash_block_k=32)
+        cfg = dataclasses.replace(base, attention_impl=impl)
         m = LlamaModel(cfg)
         p = m.init(jax.random.key(0))
         losses[impl] = float(m.loss(p, toks, tgts))

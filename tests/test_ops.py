@@ -342,26 +342,165 @@ def test_flash_attention_ragged_seq():
                                rtol=2e-4, atol=2e-5)
 
 
-def test_flash_attention_gradients_match_reference():
+def _flash_grad_cases():
+    """head_dim 64 and 128 x causal and not x kv heads 1, 2 and = heads,
+    each at a sequence that ends inside a block; and the small square case
+    this test began as (PR 48: it held the XLA-scan backward then)."""
+    cases = [pytest.param(1, 128, 2, 2, 16, True, 64, id="d16-causal-mha-s128")]
+    for D in (64, 128):
+        for causal in (True, False):
+            for Hkv in (1, 2, 4):
+                cases.append(pytest.param(
+                    1, 200, 4, Hkv, D, causal, 128,
+                    id=f"d{D}-{'causal' if causal else 'full'}-kv{Hkv}-s200"))
+    return cases
+
+
+@pytest.mark.parametrize("B,S,H,Hkv,D,causal,block", _flash_grad_cases())
+def test_flash_attention_gradients_match_reference(B, S, H, Hkv, D, causal,
+                                                   block):
+    """The fused kernels (interpret mode) against ``reference_attention``:
+    the output AND dq, dk, dv, under a cotangent that weighs every
+    element differently."""
     from ray_tpu.ops.attention import flash_attention
 
     rng = np.random.default_rng(2)
-    B, S, H, D = 1, 128, 2, 16
     q = jnp.asarray(rng.normal(size=(B, S, H, D)), jnp.float32)
-    k = jnp.asarray(rng.normal(size=(B, S, H, D)), jnp.float32)
-    v = jnp.asarray(rng.normal(size=(B, S, H, D)), jnp.float32)
+    k = jnp.asarray(rng.normal(size=(B, S, Hkv, D)), jnp.float32)
+    v = jnp.asarray(rng.normal(size=(B, S, Hkv, D)), jnp.float32)
+    w = jnp.asarray(rng.normal(size=(B, S, H, D)), jnp.float32)
 
-    def f_flash(q, k, v):
-        return (flash_attention(q, k, v, True, 64, 64, True) ** 2).sum()
-
-    def f_ref(q, k, v):
-        return (reference_attention(q, k, v, causal=True) ** 2).sum()
-
-    g_flash = jax.grad(f_flash, argnums=(0, 1, 2))(q, k, v)
-    g_ref = jax.grad(f_ref, argnums=(0, 1, 2))(q, k, v)
-    for a, b in zip(g_flash, g_ref):
+    got = _o_and_grads(lambda q, k, v: flash_attention(
+        q, k, v, causal, block, block, True), q, k, v, w)
+    want = _o_and_grads(lambda q, k, v: reference_attention(
+        q, k, v, causal=causal), q, k, v, w)
+    for name, a, b in zip(("o", "dq", "dk", "dv"), got, want):
         np.testing.assert_allclose(np.asarray(a), np.asarray(b),
-                                   rtol=5e-3, atol=5e-4)
+                                   rtol=2e-4, atol=2e-5, err_msg=name)
+
+
+def _o_and_grads(attn, q, k, v, w):
+    (_, o), grads = jax.value_and_grad(
+        lambda q, k, v: (lambda o: ((o * w).sum(), o))(attn(q, k, v)),
+        argnums=(0, 1, 2), has_aux=True)(q, k, v)
+    return (o, *grads)
+
+
+@pytest.mark.parametrize("causal", [True, False], ids=["causal", "full"])
+def test_flash_attention_spans_split_into_tiles(monkeypatch, causal):
+    """A row block's spans wider than ``_WIDE`` split into several tiles
+    (on the chip: a sequence past 1,024): here ``_WIDE`` and both blocks
+    are 128, so at 400 positions a forward block walks up to three
+    unmasked tiles and a masked one, a backward block as many."""
+    import importlib
+    kernels = importlib.import_module("ray_tpu.ops.attention")
+
+    monkeypatch.setattr(kernels, "_WIDE", 128)
+    assert len(kernels._tiles((0, 512, False), (512, 640, True))) == 5
+    rng = np.random.default_rng(3)
+    B, S, H, Hkv, D = 1, 400, 2, 1, 64
+    q, w = (jnp.asarray(rng.normal(size=(B, S, H, D)), jnp.float32)
+            for _ in range(2))
+    k, v = (jnp.asarray(rng.normal(size=(B, S, Hkv, D)), jnp.float32)
+            for _ in range(2))
+    # the jitted calls' cache does not know ``_WIDE``
+    for call in (kernels._flash_forward, kernels._flash_backward):
+        call.clear_cache()
+    try:
+        got = _o_and_grads(lambda q, k, v: kernels.flash_attention(
+            q, k, v, causal, 128, 128, True), q, k, v, w)
+    finally:
+        for call in (kernels._flash_forward, kernels._flash_backward):
+            call.clear_cache()
+    want = _o_and_grads(lambda q, k, v: reference_attention(
+        q, k, v, causal=causal), q, k, v, w)
+    for name, a, b in zip(("o", "dq", "dk", "dv"), got, want):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b),
+                                   rtol=2e-4, atol=2e-5, err_msg=name)
+
+
+def test_flash_attention_past_residency_takes_the_scan(monkeypatch):
+    """A head's sequence the kernels do not keep in VMEM runs
+    ``blockwise_attention``: same values and gradients, no Pallas call,
+    at any length (here the limit is patched down to 256 causal
+    positions)."""
+    import importlib
+    kernels = importlib.import_module("ray_tpu.ops.attention")
+
+    monkeypatch.setattr(kernels, "_MAX_UNROLLED", 256 * 256 // 2)
+    rng = np.random.default_rng(4)
+    B, S, H, Hkv, D = 1, 384, 2, 1, 64
+    q, w = (jnp.asarray(rng.normal(size=(B, S, H, D)), jnp.float32)
+            for _ in range(2))
+    k, v = (jnp.asarray(rng.normal(size=(B, S, Hkv, D)), jnp.float32)
+            for _ in range(2))
+
+    def flash(q, k, v):
+        return kernels.flash_attention(q, k, v, True)
+
+    assert "pallas_call" not in str(jax.make_jaxpr(flash)(q, k, v))
+    assert "pallas_call" in str(jax.make_jaxpr(flash)(
+        q[:, :256], k[:, :256], v[:, :256]))
+    got = _o_and_grads(flash, q, k, v, w)
+    want = _o_and_grads(lambda q, k, v: reference_attention(
+        q, k, v, causal=True), q, k, v, w)
+    for name, a, b in zip(("o", "dq", "dk", "dv"), got, want):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b),
+                                   rtol=2e-4, atol=2e-5, err_msg=name)
+
+
+@pytest.mark.parametrize("S,D,dtype,causal,resident", [
+    (1024, 64, jnp.bfloat16, True, True),   # gpt2-medium.train_1chip
+    (4096, 128, jnp.bfloat16, True, True),  # 21 MiB resident + the tiles
+    (4097, 128, jnp.bfloat16, True, False),     # more than is unrolled
+    (8192, 128, jnp.bfloat16, True, False),     # LlamaConfig.max_seq_len
+    (2816, 64, jnp.bfloat16, False, True),
+    (4096, 128, jnp.bfloat16, False, False),    # twice the causal tiles
+    (3328, 256, jnp.float32, True, True),       # 56 + 8 MiB: half of 128
+    (3456, 256, jnp.float32, True, False),
+])
+def test_flash_residency_by_shape(S, D, dtype, causal, resident):
+    """What stays in VMEM, sized for the v5e where no chip is: every
+    admitted corner here compiles for the v5e chip-less (PERF.md PR 48)."""
+    from ray_tpu.ops.attention import _stays_resident
+
+    assert _stays_resident(S, S, D, dtype, causal) is resident
+
+
+@pytest.mark.parametrize("S,H,Hkv,D,tiles,kernels", [
+    (1024, 16, 16, 64, True, True),      # gpt2-medium.train_1chip
+    (256, 8, 2, 128, True, True),
+    (197, 12, 12, 64, False, False),     # ViT-B: the reference is faster
+    (8192, 8, 8, 128, True, False),      # the scan inside flash_attention
+    (1024, 16, 16, 80, False, False),    # a head width that does not tile
+    (128, 8, 8, 128, False, False),      # short: the reference is faster
+])
+def test_dispatcher_reads_the_shapes_on_a_tpu(monkeypatch, S, H, Hkv, D,
+                                              tiles, kernels):
+    """``attention`` on a TPU backend with no mesh: the kernels where the
+    shapes tile and stay in VMEM, the scan where they tile and do not,
+    the reference elsewhere, under a mesh (``use_flash=False``) and with
+    explicit positions."""
+    import importlib
+    attn = importlib.import_module("ray_tpu.ops.attention")
+
+    monkeypatch.setattr(attn, "on_chip", lambda: True)
+    monkeypatch.setattr(attn, "pallas_interpret", lambda: False)
+    q = jax.ShapeDtypeStruct((1, S, H, D), jnp.bfloat16)
+    kv = jax.ShapeDtypeStruct((1, S, Hkv, D), jnp.bfloat16)
+    assert attn.kernels_tile(q, kv) is tiles
+
+    def program(**kw):
+        return str(jax.make_jaxpr(
+            lambda q, k, v: attn.attention(q, k, v, **kw))(q, kv, kv))
+
+    text = program()
+    assert ("pallas_call" in text) is kernels
+    assert ("scan" in text) is (tiles and not kernels)
+    for kw in (dict(use_flash=False),
+               dict(positions_q=jnp.arange(S), positions_k=jnp.arange(S))):
+        text = program(**kw)
+        assert "pallas_call" not in text and "scan" not in text
 
 
 def test_llama_flash_impl_matches_ring_default():
