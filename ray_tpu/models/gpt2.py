@@ -3,20 +3,85 @@
 Reference capability: trained via TorchTrainer+DDP in the reference's
 release tests; here a pjit data/tensor-parallel functional model (pre-LN,
 learned positions, tied embeddings, GELU MLP).
+
+``GPT2Config.remat``: False keeps every intermediate of every block for
+the backward; True (the default) remakes what does not fit. The layer
+scan then keeps, besides its carry, the longest prefix of ``RESIDUALS``
+that ``residuals_that_fit`` the device: the attention kernels' ``o`` and
+log-sum-exp (the forward kernel runs once a step, not twice), then the
+qkv product, the attention block's output, and w_up's product; the layer
+norms, the GELU and whatever was not kept are remade in the backward.
+The choice is arithmetic on ``tokens.shape``, ``cfg`` and the device's
+``memory_stats()["bytes_limit"]``, made once a trace; it is logged, and
+exported as the gauge ``train.kept_residual_bytes`` (one series a
+candidate, 0 where it did not fit). On a CPU, which reports no limit,
+and under a mesh nothing is kept. No flag selects it.
 """
 
 from __future__ import annotations
 
 import dataclasses
-from typing import Any, Dict, Optional
+import functools
+import itertools
+import logging
+from typing import Any, Dict, Optional, Sequence
 
 import jax
 import jax.numpy as jnp
+from jax.ad_checkpoint import checkpoint_name
 
-from ray_tpu.ops.attention import attention, use_flash_on
+from ray_tpu.ops.attention import FLASH_RESIDUALS, attention, use_flash_on
 from ray_tpu.ops.norms import layer_norm
+from ray_tpu.util.metrics import Gauge
+
+logger = logging.getLogger(__name__)
 
 Params = Dict[str, Any]
+
+# What the layer scan may keep of a block for its backward, as
+# ``checkpoint_name``s by the tag ``train.kept_residual_bytes`` gives them,
+# in the order they are taken: the attention kernel's outputs first (they
+# save its second run, and stand alone), then the matmul products by what
+# completes a chain from the block's input with nothing remade: qkv, the
+# attention block's output, w_up's (what each bought on the v5e: PERF.md
+# PR 49)
+RESIDUALS = {
+    "attention": FLASH_RESIDUALS,
+    "qkv": ("gpt2.qkv",),
+    "wo": ("gpt2.wo",),
+    "w_up": ("gpt2.w_up",),
+}
+
+
+# Device bytes the layer scan's budget leaves alone: what the compiler
+# reserves of a chip (0.26 GiB on a v5e) and what a training process
+# holds outside its step program (0.15 GiB: PERF.md PR 49)
+_RESERVE_BYTES = 420 << 20
+
+
+def residuals_that_fit(candidates: Sequence[int], held_bytes: int,
+                       logits_bytes: int, capacity: Optional[int]) -> int:
+    """How many of ``candidates`` (the bytes of each of ``RESIDUALS``
+    over the whole layer stack, in its order) the layer scan keeps: the
+    longest prefix that fits ``capacity`` beside ``held_bytes`` (the
+    train state, its gradients and the scan's carry, held whatever is
+    kept), the loss head's float32 ``logits_bytes`` and
+    ``_RESERVE_BYTES``. Arithmetic, never a trial compile, and on the
+    side of keeping less: a step remade costs a few percent, a program
+    the compiler refuses costs the run (the v5e compiler's frontier for
+    GPT-2 medium, batch by batch: PERF.md PR 49). None where the device
+    does not say what it holds (``capacity`` None: a CPU)."""
+    if capacity is None:
+        return 0
+    room = capacity - _RESERVE_BYTES - held_bytes - logits_bytes
+    return sum(total <= room for total in itertools.accumulate(candidates))
+
+
+def _chip_bytes() -> Optional[int]:
+    """What the first device may allocate; None where the backend does
+    not say (a CPU)."""
+    stats = jax.devices()[0].memory_stats()
+    return stats.get("bytes_limit") if stats else None
 
 
 @dataclasses.dataclass(frozen=True)
@@ -124,7 +189,7 @@ class GPT2Model:
                            eps=cfg.norm_eps)
         with jax.named_scope("attention"):
             qkv = jnp.einsum("bsd,dthk->bsthk", h, layer["wqkv"].astype(dt))
-            qkv = qkv + layer["bqkv"].astype(dt)
+            qkv = checkpoint_name(qkv + layer["bqkv"].astype(dt), "gpt2.qkv")
             q, kk, vv = qkv[:, :, 0], qkv[:, :, 1], qkv[:, :, 2]
             # a Mosaic call carries no partitioning rule: under a mesh
             # the reference, off one what the dispatcher finds tiles
@@ -132,15 +197,65 @@ class GPT2Model:
                           use_flash=self._use_flash)
             o = jnp.einsum("bshk,hkd->bsd", o, layer["wo"].astype(dt))
         with jax.named_scope("norm_residual"):
-            x = x + o + layer["bo"].astype(dt)
+            x = checkpoint_name(x + o + layer["bo"].astype(dt), "gpt2.wo")
             h = layer_norm(x, layer["ln2_w"], layer["ln2_b"],
                            eps=cfg.norm_eps)
         with jax.named_scope("mlp"):
             up = jnp.einsum("bsd,df->bsf", h, layer["w_up"].astype(dt))
-            up = jax.nn.gelu(up + layer["b_up"].astype(dt))
+            up = checkpoint_name(up + layer["b_up"].astype(dt), "gpt2.w_up")
+            up = jax.nn.gelu(up)
             down = jnp.einsum("bsf,fd->bsd", up, layer["w_down"].astype(dt))
         with jax.named_scope("norm_residual"):
             return x + down + layer["b_down"].astype(dt)
+
+    def _keeping(self, batch: int, seq: int):
+        """The layer scan's ``jax.checkpoint`` policy at this batch: the
+        prefix of ``RESIDUALS`` that ``residuals_that_fit`` the device,
+        read once a trace from the shapes, ``cfg`` and the device. Under
+        a mesh a chip's share of each term is the shardings' to say, not
+        this model's: nothing is kept, as on a CPU. Where the dispatcher
+        does not take the fused kernels (under 256 positions, a head past
+        VMEM) the first candidate's names are in no program and its bytes
+        are set aside for nothing."""
+        cfg = self.cfg
+        GiB = 2 ** 30
+        # [batch, seq, 1] of activations over the stack, and the kernels'
+        # row of float32 log-sum-exp a head (padded to whole lanes)
+        act = batch * seq * jnp.dtype(cfg.dtype).itemsize * cfg.n_layers
+        lse = batch * cfg.n_heads * (seq + -seq % 128) * 4 * cfg.n_layers
+        sizes = {"attention": act * cfg.dim + lse, "qkv": 3 * act * cfg.dim,
+                 "wo": act * cfg.dim, "w_up": act * cfg.ffn_dim}
+        # float32 parameters, two AdamW moments and gradients, the bf16
+        # copy of the weights the compiler makes outside the scan, and
+        # the scan's carry
+        held = 18 * cfg.num_params() + act * cfg.dim
+        logits = 4 * batch * seq * cfg.vocab_size
+        capacity = None if self.mesh is not None else _chip_bytes()
+        kept = list(RESIDUALS)[:residuals_that_fit(
+            [sizes[tag] for tag in RESIDUALS], held, logits, capacity)]
+        keep = jax.checkpoint_policies.save_only_these_names(
+            *(name for tag in kept for name in RESIDUALS[tag]))
+
+        @functools.cache
+        def report():
+            gauge = Gauge(
+                "train.kept_residual_bytes",
+                "what GPT-2's layer scan keeps for its backward", ("name",))
+            for tag, size in sizes.items():
+                gauge.set(size if tag in kept else 0, {"name": tag})
+            logger.info(
+                "gpt2 layer scan at %d x %d keeps %s of %s GiB beside %.2f "
+                "GiB of state and carry, %.2f of float32 logits and %.2f "
+                "of reserve: capacity %s GiB", batch, seq, kept or "nothing",
+                {t: round(b / GiB, 2) for t, b in sizes.items()},
+                held / GiB, logits / GiB, _RESERVE_BYTES / GiB,
+                capacity and round(capacity / GiB, 2))
+
+        def policy(*args, **kwargs):
+            report()        # asked only where the block is differentiated
+            return keep(*args, **kwargs)
+
+        return policy
 
     def apply(self, params: Params, tokens: jax.Array) -> jax.Array:
         cfg = self.cfg
@@ -151,7 +266,7 @@ class GPT2Model:
 
         block = self._block
         if cfg.remat:
-            block = jax.checkpoint(block)
+            block = jax.checkpoint(block, policy=self._keeping(B, S))
 
         def scan_body(x, layer):
             return block(x, layer), None

@@ -28,6 +28,7 @@ from typing import Optional
 
 import jax
 import jax.numpy as jnp
+from jax.ad_checkpoint import checkpoint_name
 
 from ray_tpu._private.platform import on_chip, pallas_interpret
 
@@ -456,9 +457,17 @@ def _interpreted(interpret: Optional[bool]) -> bool:
     return pallas_interpret() if interpret is None else interpret
 
 
+# ``checkpoint_name``s of what the forward kernel makes, named where the
+# backward rule takes them as residuals: a ``jax.checkpoint`` policy that
+# keeps both runs the forward kernel once; under every other policy they
+# are inert
+FLASH_RESIDUALS = ("flash_attention.o", "flash_attention.lse")
+
+
 def _flash_fwd_rule(q, k, v, causal, block_q, block_k, interpret):
     o, lse = _flash_forward(q, k, v, causal=causal, block=block_q,
                             interpret=_interpreted(interpret))
+    o, lse = map(checkpoint_name, (o, lse), FLASH_RESIDUALS)
     return o, (q, k, v, o, lse)
 
 

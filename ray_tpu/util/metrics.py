@@ -4,6 +4,7 @@ Counter/Gauge/Histogram over the C++ OpenCensus registry,
 
 from __future__ import annotations
 
+import re
 import threading
 from typing import Dict, List, Optional, Sequence, Tuple
 
@@ -378,6 +379,9 @@ def render_prometheus(parts: List[Tuple[Dict[str, str], List[Dict]]]
     lines: List[str] = []
     for name in sorted(merged):
         slot = merged[name]
+        # the exposition's alphabet: ``train.kept_residual_bytes`` is
+        # scraped as ``train_kept_residual_bytes``
+        name = re.sub(r"[^a-zA-Z0-9_:]", "_", name)
         lines.append(f"# HELP {name} {_esc_help(slot['description'])}")
         lines.append(f"# TYPE {name} {slot['kind']}")
         if slot["kind"] == "histogram":

@@ -206,6 +206,24 @@ def test_render_prometheus_federated_labels():
         metrics.clear_registry()
 
 
+def test_render_prometheus_keeps_to_the_expositions_alphabet():
+    """A name with a dot (``train.kept_residual_bytes``) is scraped with
+    an underscore; the JSON view keeps the name as it was given."""
+    from ray_tpu.util import metrics
+
+    metrics.clear_registry()
+    try:
+        metrics.Gauge("train.kept_residual_bytes", "", ("name",)).set(
+            7, {"name": "qkv"})
+        text = metrics.prometheus_text()
+        assert 'train_kept_residual_bytes{name="qkv"} 7' in text
+        assert "train.kept" not in text
+        assert any(row["name"] == "train.kept_residual_bytes"
+                   for row in metrics.cluster_metrics_json()["metrics"])
+    finally:
+        metrics.clear_registry()
+
+
 def test_worker_metrics_flow_to_driver(ray_start_regular):
     """User metrics created inside pool workers surface on the driver's
     Prometheus endpoint (reference: worker -> agent -> exporter flow);
