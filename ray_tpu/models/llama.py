@@ -43,7 +43,16 @@ it is in-tree and TPU-first:
   everything before it (``init_kv_pools``). An EVA model is EVA in
   every layer (the kind is not mixed with the other two: its cache is
   another shape); ``model.eva`` says so, and no other model's programs
-  differ for it.
+  differ for it;
+- a model may carry MORE THAN ONE RESIDUAL STREAM (``LlamaConfig.hc_mult``
+  > 1: manifold-constrained hyper-connections, ``ops/mhc.py``). What its
+  layer scans carry is then not x [B, T, D] but X [B, T, n D], the streams
+  side by side in the lanes; a sublayer reads its input out of them and
+  writes its output back through ``_read_streams`` / ``_write_streams``,
+  which for one stream ARE ``x`` and ``x + y``. Each sublayer has three more
+  leaves (``attn_hc`` / ``mlp_hc``: ``phi``, ``alpha``, ``bias``,
+  float32). The streams live and die inside a program: no cache, no
+  engine and no slot knows of them.
 """
 
 from __future__ import annotations
@@ -54,6 +63,7 @@ from typing import Any, Dict, Optional, Tuple
 import jax
 import jax.numpy as jnp
 
+from ray_tpu.ops import mhc
 from ray_tpu.ops.attention import (attention, reference_attention,
                                    use_flash_on)
 from ray_tpu.ops.eva import (chunk_summaries, eva_attention,
@@ -134,6 +144,14 @@ class LlamaConfig:
     # token ``t + 1 + p``. ``apply`` returns every head's logits; the
     # serving programs compute head 0's, the next token's
     num_pred_heads: int = 1
+    # residual streams a token (1: the plain residual) and, where there
+    # are more, how a sublayer's maps are made (``ops/mhc.py``): Sinkhorn
+    # rounds, the term in their denominators, the clamp on ``m_res``
+    # before ``exp``
+    hc_mult: int = 1
+    hc_sinkhorn_iters: int = 20
+    hc_eps: float = 1e-6
+    hc_res_clamp: Tuple[float, float] = (-30.0, 30.0)
 
     def __post_init__(self):
         if self.attention_impl not in ("ring", "ulysses", "flash", "xla"):
@@ -169,6 +187,13 @@ class LlamaConfig:
             raise ValueError(
                 f"rope_scaling names kinds out of {LAYER_KINDS}, got "
                 f"{self.rope_scaling}")
+        object.__setattr__(self, "hc_res_clamp",
+                           tuple(map(float, self.hc_res_clamp)))
+        if self.hc_mult < 1 or self.hc_sinkhorn_iters < 0:
+            raise ValueError(
+                f"hc_mult ({self.hc_mult}) streams, at least one, and "
+                f"hc_sinkhorn_iters ({self.hc_sinkhorn_iters}) rounds, "
+                f"none or more")
 
     def _check_eva(self) -> None:
         if set(self.layer_types) != {EVA_KIND}:
@@ -190,11 +215,26 @@ class LlamaConfig:
             return self.eva_window, self.eva_chunk
         return None
 
+    @property
+    def hc_map_width(self) -> int:
+        """Numbers in a sublayer's three maps a token: ``H_pre`` and
+        ``H_post`` [n], ``H_res`` [n, n]."""
+        return 2 * self.hc_mult + self.hc_mult ** 2
+
+    def hc_params(self) -> int:
+        """One layer's stream maps: ``phi``, ``alpha`` and ``bias`` of its
+        two sublayers (none with one stream)."""
+        if self.hc_mult == 1:
+            return 0
+        width = self.hc_map_width
+        return 2 * (width * self.hc_mult * self.dim + 3 + width)
+
     def num_params(self) -> int:
         d, f, v = self.dim, self.ffn_dim, self.vocab_size
         q = self.n_heads * self.head_dim
         kv = self.n_kv_heads * self.head_dim
-        per_layer = d * q + 2 * d * kv + q * d + 3 * d * f + 2 * d
+        per_layer = (d * q + 2 * d * kv + q * d + 3 * d * f + 2 * d
+                     + self.hc_params())
         if self.eva:
             per_layer += 2 * kv                       # eva_phi, eva_mu
         heads = 0 if self.tie_embeddings else v * d * self.num_pred_heads
@@ -267,6 +307,10 @@ class LlamaModel:
         self.rules = rules
         self._use_flash = use_flash_on(mesh)
         self._sp = 1 if mesh is None else mesh.shape.get("sp", 1)
+        if cfg.hc_mult > 1 and mesh is not None:
+            raise NotImplementedError(
+                "more than one residual stream carries no partitioning "
+                "rules yet (the streams' axis has no logical name)")
         if self._sp > 1 and cfg.attention_impl == "flash":
             raise ValueError(
                 "attention_impl='flash' is a single-device kernel; with an "
@@ -335,12 +379,37 @@ class LlamaModel:
     def _init_layers(self, k, L: int, ffn_dim: int) -> Params:
         """A stack of ``L`` dense decoder layers, SwiGLU ``ffn_dim``."""
         d, dense = self.cfg.dim, self._dense
-        return {"attn_norm": self._norm_scale((L, d)),
-                **self._init_attention(k, L),
-                "mlp_norm": self._norm_scale((L, d)),
-                "w_gate": dense(next(k), (L, d, ffn_dim), d),
-                "w_up": dense(next(k), (L, d, ffn_dim), d),
-                "w_down": dense(next(k), (L, ffn_dim, d), ffn_dim)}
+        layers = {"attn_norm": self._norm_scale((L, d)),
+                  **self._init_attention(k, L),
+                  "mlp_norm": self._norm_scale((L, d)),
+                  "w_gate": dense(next(k), (L, d, ffn_dim), d),
+                  "w_up": dense(next(k), (L, d, ffn_dim), d)}
+        down = next(k)
+        layers["w_down"] = dense(down, (L, ffn_dim, d), ffn_dim)
+        if self.cfg.hc_mult > 1:
+            # (keys folded out of one the stack draws anyway: a model with
+            # one stream draws what it drew before)
+            for i, name in enumerate(("attn_hc", "mlp_hc")):
+                layers[name] = self._init_stream_maps(
+                    jax.random.fold_in(down, 1 + i), L)
+        return layers
+
+    def _init_stream_maps(self, key, L: int) -> Params:
+        """One sublayer's map parameters a layer, float32: ``phi`` [L, 2n +
+        n^2, n d] (``Phi`` transposed: ``ops/mhc.py``) normal and fan-in
+        scaled, so that the dynamic part of ``m`` has unit variance;
+        ``alpha`` ones; ``bias`` zero but for 3 on the diagonal of its
+        ``H_res`` part (a trained model's values are its own, as a
+        router's bias is): ``H_res`` starts near the identity and not at
+        it, so the streams stay distinct through the layers."""
+        cfg = self.cfg
+        n, width = cfg.hc_mult, cfg.hc_map_width
+        bias = jnp.concatenate([
+            jnp.zeros((2 * n,), jnp.float32),
+            3.0 * jnp.eye(n, dtype=jnp.float32).reshape(-1)])
+        return {"phi": self._dense(key, (L, width, n * cfg.dim), n * cfg.dim),
+                "alpha": jnp.ones((L, 3), jnp.float32),
+                "bias": jnp.broadcast_to(bias, (L, width))}
 
     def init(self, rng: jax.Array) -> Params:
         cfg = self.cfg
@@ -645,27 +714,63 @@ class LlamaModel:
             return self._constrain(a, *names) if constrain else a
 
         with jax.named_scope("norm_residual"):
-            h = self._norm(x, layer["attn_norm"])
+            h, maps = self._read_streams(x, layer.get("attn_hc"))
+            h = self._norm(h, layer["attn_norm"])
         with jax.named_scope("attention"):
             q, k, v = self._qkv(h, layer, positions, kind, pin)
         o, kv = attend(q, k, v)
         with jax.named_scope("attention"):
             o = jnp.einsum("bshk,hkd->bsd", o, layer["wo"].astype(dt))
         with jax.named_scope("norm_residual"):
-            x = x + pin(o, "batch", "seq", "embed")
-            h = self._norm(x, layer["mlp_norm"])
+            x = self._write_streams(x, pin(o, "batch", "seq", "embed"), maps)
+            h, maps = self._read_streams(x, layer.get("mlp_hc"))
+            h = self._norm(h, layer["mlp_norm"])
         down, extra = self._ffn(h, layer, live, constrain, stacks)
         with jax.named_scope("norm_residual"):
-            return x + pin(down, "batch", "seq", "embed"), kv, extra
+            return (self._write_streams(
+                x, pin(down, "batch", "seq", "embed"), maps), kv, extra)
+
+    # -- the residual path: one vector a token, or ``hc_mult`` streams ------
+    def _read_streams(self, x, hc: Optional[Params]):
+        """A sublayer's input out of the residual: ``(h, maps)``. The
+        plain residual IS its sublayers' input (``maps`` None). With
+        streams (``hc``: the sublayer's ``phi``, ``alpha``, ``bias``) x is
+        X [B, T, n D]: the sublayer's three maps are made from the streams
+        as they stand, ``h = sum_i H_pre[i] X[i]``, and ``(H_post, H_res)``
+        go on to ``_write_streams``."""
+        if hc is None:
+            return x, None
+        cfg = self.cfg
+        with jax.named_scope("mhc_maps"):
+            h_pre, h_post, h_res = mhc.stream_maps(
+                x, cfg.hc_mult, hc["phi"], hc["alpha"], hc["bias"],
+                iters=cfg.hc_sinkhorn_iters, eps=cfg.hc_eps,
+                norm_eps=cfg.norm_eps, clamp=cfg.hc_res_clamp)
+        with jax.named_scope("mhc_mix"):
+            return mhc.mix_in(x, h_pre), (h_post, h_res)
+
+    def _write_streams(self, x, y, maps):
+        """A sublayer's output ``y`` back into the residual: ``x + y``,
+        or, with streams, ``X'[i] = sum_j H_res[i, j] X[j] + H_post[i] y``
+        by the maps its ``_read_streams`` made."""
+        if maps is None:
+            return x + y
+        with jax.named_scope("mhc_mix"):
+            return mhc.mix_out(x, y, *maps)
 
     def _embed(self, params: Params, tokens: jax.Array,
                constrain: bool = False) -> jax.Array:
-        """tokens [B, T] -> x [B, T, D] in the compute dtype."""
+        """tokens [B, T] -> x [B, T, D] in the compute dtype; for a model
+        with streams X [B, T, n D]."""
         with jax.named_scope("embed"):
             x = self._embed_lookup(params["embed"].astype(self.cfg.dtype),
                                    tokens)
             if self.cfg.fp32_residual:
                 x = x.astype(jnp.float32)
+            if self.cfg.hc_mult > 1:
+                # every stream starts as the embedding (Hyper-Connections,
+                # Alg. 2)
+                return jnp.tile(x, (1, 1, self.cfg.hc_mult))
             return (self._constrain(x, "batch", "seq", "embed") if constrain
                     else x)
 
@@ -673,12 +778,16 @@ class LlamaModel:
               last: Optional[jax.Array] = None,
               constrain: bool = False, every_head: bool = False
               ) -> jax.Array:
-        """Final norm and LM head: x [B, T, D] -> f32 logits [B, T, V];
+        """Final norm and LM head: x [B, T, D] (or a model's streams [B, T,
+        n D]: they leave as their sum) -> f32 logits [B, T, V];
         with ``last`` [B], of row ``last[b]`` of each sequence alone
         ([B, 1, V]: the other rows never meet the head). A model with
         ``num_pred_heads`` > 1 gives head 0's V logits, the next
         token's, unless ``every_head`` (``apply``: [B, T, heads * V])."""
         cfg = self.cfg
+        if cfg.hc_mult > 1:
+            with jax.named_scope("mhc_mix"):
+                x = mhc.sum_streams(x, cfg.hc_mult)
         with jax.named_scope("logits"):
             x = self._norm(x, params["norm_f"])
             if last is not None:

@@ -197,6 +197,20 @@ class MLAConfig(MoEConfig):
                 beta_slow=1.0, attention_factor=1.0),
             yarn_mscale_all_dim=1.0), **overrides})
 
+    @staticmethod
+    def debug_xing(vocab_size: int = 512, max_seq_len: int = 128,
+                   **overrides) -> "MLAConfig":
+        """Xing4.0's block at debug widths: FOUR residual streams mixed by
+        manifold-constrained hyper-connections (20 Sinkhorn rounds)
+        around ``debug_deepseek_v32``'s attention WITHOUT the indexer (a
+        compressed query, YaRN with its scale on the softmax, the dense
+        absorbed kernel), 8 experts top-3 with no group limit, one
+        shared expert."""
+        return MLAConfig.debug_deepseek_v32(**{**dict(
+            vocab_size=vocab_size, max_seq_len=max_seq_len, num_experts=8,
+            expert_top_k=3, routed_scaling_factor=2.0, router_n_group=1,
+            router_topk_group=1, index_n_heads=0, hc_mult=4), **overrides})
+
 
 class MLAModel(MoEModel):
     """The expert model with latent attention in every layer."""

@@ -159,7 +159,8 @@ class MoEConfig(LlamaConfig):
 
     def num_params(self) -> int:
         d, f, v, E = self.dim, self.ffn_dim, self.vocab_size, self.num_experts
-        attn = self.attention_params() + 2 * d            # and two norms
+        # and two norms, and the stream maps where there are streams
+        attn = self.attention_params() + 2 * d + self.hc_params()
         expert_layer = (attn + d * E + 3 * self.held[1] * d * f
                         + 3 * d * self.shared_ffn_dim
                         + (E if self.router_kind == "sigmoid" else 0))

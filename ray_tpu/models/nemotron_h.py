@@ -75,11 +75,13 @@ class NemotronHConfig(MoEConfig):
                 f"pattern is a string over {sorted(KINDS)}, one letter a "
                 f"layer, got {self.pattern!r}")
         if (self.mamba_heads % self.ssm_groups or self.conv_kernel < 2
-                or self.layer_types is not None or self.leading_layers):
+                or self.layer_types is not None or self.leading_layers
+                or self.hc_mult != 1):
             raise ValueError(
                 f"{self.mamba_heads} heads in {self.ssm_groups} groups, a "
                 f"convolution over {self.conv_kernel} positions, no layer "
-                "types and no leading layers")
+                "types, no leading layers and one residual stream (the "
+                "walk adds a block's output to x itself)")
 
     @property
     def mamba_inner(self) -> int:

@@ -20,12 +20,14 @@
   weight tiles (Mellum2's 2304 x 896: 256 x 128, 63 grid steps a group,
   three times OLMoE's time a byte: PERF.md, PR 40), jax's Pallas grouped
   matmul (``megablox.gmm``: bf16 operands, float32 accumulator) at the
-  tiling ``gmm_tiling(m, k, n, itemsize)`` gives; everywhere else, at
-  widths XLA tiles 512 x 512 (OLMoE's 2048 x 1024: ``xla_tiles_wide``)
-  and for a shape with no legal tiling, ``jax.lax.ragged_dot`` (the
-  kernel's reference in the tests). TRAINING on a TPU runs the same
-  kernel forward; its backward is ``ragged_dot``'s transposes
-  (``pallas_grouped_matmul``'s ``custom_vjp``), which no cell measures.
+  tiling ``gmm_tiling(m, k, n, itemsize)`` gives, and where an expert is
+  more than eight of XLA's 512 x 512 tiles (3584 x 1024, 7168 x 2048);
+  everywhere else, for a small expert XLA tiles 512 x 512 (OLMoE's 2048
+  x 1024: ``xla_tiles_wide``) and for a shape with no legal tiling,
+  ``jax.lax.ragged_dot`` (the kernel's reference in the tests).
+  TRAINING on a TPU runs the same kernel forward; its backward is
+  ``ragged_dot``'s transposes (``pallas_grouped_matmul``'s
+  ``custom_vjp``), which no cell measures.
 - the capacity-bounded GShard pair, kept for an ``ep`` mesh axis
   (ROADMAP R2 decides their future): ``capacity_einsum_ffn`` (dense
   one-hot ``[T, E, C]`` dispatch/combine einsums, XLA's partitioner
@@ -172,11 +174,17 @@ def gmm_tiling(m: int, k: int, n: int, itemsize: int = 2
     return tm, tk, tn
 
 
+# An expert of at most this many of XLA's widest (512 x 512) tiles keeps
+# ``ragged_dot``: ``grouped_matmul_impl`` says what was measured either side
+XLA_WIDE_TILES = 8
+
+
 def xla_tiles_wide(k: int, n: int) -> bool:
     """Does XLA's own heuristic for ``ragged_dot`` reach its widest (k,
     n) tile, 512 x 512? It tiles a width by its largest power-of-two
     factor up to 512 (the v5e compiler prints ``ragged_dot_tiling=
-    "256,512,512"`` at 2048 x 1024 and ``"256,256,128"`` at 2304 x 896)."""
+    "256,512,512"`` at 2048 x 1024, ``"128,512,512"`` at 3584 x 1024 and
+    7168 x 2048, and ``"256,256,128"`` at 2304 x 896)."""
     return k % 512 == 0 and n % 512 == 0
 
 
@@ -186,20 +194,24 @@ def grouped_matmul_impl(m: int, k: int, n: int, itemsize: int
     [m, k] @ rhs [G, k, n]``: the one place that decides, by the
     platform and the shapes (as ``ops.paged_attention.default_impl``
     does for decode attention). The Pallas kernel on a TPU backend where
-    XLA's own tiling falls short of 512 x 512 and ``gmm_tiling`` has a
-    tiling; ``jax.lax.ragged_dot`` everywhere else.
+    ``gmm_tiling`` has a tiling and XLA's own falls short of 512 x 512
+    or an expert is more than ``XLA_WIDE_TILES`` such tiles;
+    ``jax.lax.ragged_dot`` everywhere else.
 
-    Why widths XLA tiles well keep ``ragged_dot`` (PERF.md, PR 40): at
-    2048 x 1024 the kernel reads a third faster a call (0.37 for 0.56
-    ms at 256 rows) where at 2304 x 896 it reads 4.4 times faster (0.39
-    for 1.72), and every program that holds it pays ~0.1 s of Mosaic
-    lowering before the compile cache is asked, ~2 s of a process's
-    set-up. The one cell with such widths is bound by the Serve stream
-    path, where a faster engine thread takes the interpreter lock from
-    the stream threads: with the kernel its clients received 7 % FEWER
-    tokens a second and its set-up grew 17 %. Revisit when S2 (b) lands.
+    Why a small expert that XLA tiles well keeps ``ragged_dot`` (PERF.md,
+    PR 40): at 2048 x 1024 (8 tiles) the kernel reads a third faster a
+    call (0.37 for 0.56 ms at 256 rows) where at 2304 x 896 it reads 4.4
+    times faster (0.39 for 1.72), and every program that holds it pays
+    ~0.1 s of Mosaic lowering before the compile cache is asked, ~2 s of
+    a process's set-up: a fifth of the set-up of the one cell with such
+    widths, whose step the host sets, so that the kernel gave it nothing
+    back (its clients received 7 % FEWER tokens a second then). A larger
+    expert pays the same lowering once and saves more a step: at 3584 x
+    1024 (14 tiles, 64 experts, four layers) the kernel's two tiles an
+    expert took 1.3 ms off a 13.0 ms decode program (PERF.md, PR 50).
     """
-    if not on_chip() or xla_tiles_wide(k, n):
+    if not on_chip() or (xla_tiles_wide(k, n)
+                         and (k // 512) * (n // 512) <= XLA_WIDE_TILES):
         return "ragged_dot", None
     tiling = gmm_tiling(m, k, n, itemsize)
     if tiling is None:

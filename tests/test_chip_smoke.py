@@ -528,6 +528,55 @@ def test_mla_cell_decode_program_fits_the_v5e(v5e, placed, monkeypatch):
     assert mem.temp_size_in_bytes < one_stack_a_layer / 4
 
 
+# xing4.0-29b-a4b-d5.long_decode_mhc: 32 slots x 16,384 at block 32 (512
+# table entries a slot: ``CELL_TABLES`` holds the width), four residual
+# streams of 3,584 lanes, the same latent row and 32 heads as the MLA cell
+MHC_CELL_TABLE = CELL_TABLES[3]
+
+
+def test_mhc_cell_decode_program_fits_the_v5e(v5e, placed, monkeypatch):
+    """``xing4.0-29b-a4b-d5.long_decode_mhc``'s decode program as the
+    engine jits it (``python3 -m benchmark.aot_fit``'s compile): 32 slots
+    x 16,384, one leading dense layer and four expert layers, all 64
+    experts, the whole vocabulary. The v5e's compiler takes it at 10.71
+    GiB of 15.75 (7.55 of weights, 3.13 of pool, 0.03 of temporaries),
+    with the latent kernel in each of its two scans; the streams' maps and
+    mixing are XLA's fusions (``ops/mhc.py`` says why no kernel) and the
+    grouped matmuls are the Pallas kernel at ``gmm_tiling``'s two tiles an
+    expert (XLA's own 512 x 512 would make fourteen of 3,584 x 1,024)."""
+    from benchmark import run as harness
+    from benchmark.builders import xing
+
+    _as_on_the_chip(monkeypatch)
+    cfg = harness.load_json(harness.ROOT,
+                            "benchmark/configs/xing4.0-29b-a4b-d5.json")
+    assert harness.load_json(harness.HERE, "traffic", "long_decode_mhc.json")[
+        "engine"] == {"max_slots": CELL_SLOTS, "max_seq": MHC_CELL_TABLE * 32,
+                      "block_size": CELL_BS, "max_ongoing_requests": 64}
+    B, bs, maxb, E = CELL_SLOTS, CELL_BS, MHC_CELL_TABLE, 64
+    model = xing.build_model(cfg, maxb * bs)
+    assert model.paged_decode_impl() == "mla_pallas"
+    assert model.ffn_load_shape() == (4, E)
+    assert model.grouped_matmul_plan(B)["moe_grouped_impl"] == "pallas_gmm"
+    pool = jax.eval_shape(lambda: model.init_kv_pool(B * maxb + 1, bs))
+    assert pool["k"].shape == (5, B * maxb + 1, bs, 512 + 128)
+    params = _engine_params(model)
+    assert sum(a.size for a in jax.tree.leaves(params)) == cfg["parameters"]
+    compiled = _engine_decode(model, B * maxb).lower(
+        placed(params), v5e(B, dtype=jnp.int32), placed(pool),
+        v5e(B, maxb, dtype=jnp.int32), v5e(B, dtype=jnp.int32),
+        *_sampling(v5e, B), v5e(4, E, dtype=jnp.int32)).compile()
+    text = compiled.as_text()
+    assert text.count("mla_decode_attention_pallas") >= 2
+    assert "ragged_dot_tiling" not in text and "ragged-dot" not in text
+    mem = compiled.memory_analysis()
+    total = (mem.argument_size_in_bytes + mem.temp_size_in_bytes
+             + mem.output_size_in_bytes - mem.alias_size_in_bytes)
+    assert 10.5 * 2**30 < total < 10.9 * 2**30
+    one_stack_a_layer = E * 3584 * 1024 * 2
+    assert mem.temp_size_in_bytes < one_stack_a_layer / 4
+
+
 # deepseek-v3.2-d5.long_decode_dsa: 16 slots x 22,528 at block 32 (704
 # table entries a slot), rows held as words, 2,048 rows selected
 DSA_CELL_SLOTS, DSA_CELL_TABLE, DSA_TOPK = 16, 704, 2048
@@ -581,9 +630,10 @@ def test_dsa_cell_decode_program_fits_the_v5e(v5e, placed, monkeypatch):
     layers that hold 16 of the router's 256 experts. The v5e's compiler
     takes it at 12.09 GiB of 15.75 (8.65 of weights, 3.44 of pool, 0.005
     of temporaries: ``benchmark/aot_fit.py``), the two kernels of
-    ``ops/dsa.py`` in both scans, ``ragged_dot`` for the experts (7168 x
-    2048 is a width XLA tiles 512 x 512), and nothing of an expert
-    stack's shape among the temporaries."""
+    ``ops/dsa.py`` in both scans, the Pallas grouped matmul for the
+    experts (7168 x 2048 is 56 of the 512 x 512 tiles XLA would make:
+    ``grouped_matmul_impl``, PR 50), and nothing of an expert stack's
+    shape among the temporaries."""
     from benchmark import run as harness
     from benchmark.builders import deepseek_v32
 
@@ -594,7 +644,7 @@ def test_dsa_cell_decode_program_fits_the_v5e(v5e, placed, monkeypatch):
     model = deepseek_v32.build_model(cfg, maxb * bs)
     assert model.paged_decode_impl() == "dsa_pallas"
     assert model.ffn_load_shape() == (4, 256)
-    assert model.grouped_matmul_plan(B)["moe_grouped_impl"] == "ragged_dot"
+    assert model.grouped_matmul_plan(B)["moe_grouped_impl"] == "pallas_gmm"
     pool = jax.eval_shape(lambda: model.init_kv_pool(B * maxb + 1, bs))
     assert pool["k"].shape == (5, B * maxb + 1, bs, 4, 128)
     assert pool["v"].shape == (5, B * maxb + 1, bs, 0)

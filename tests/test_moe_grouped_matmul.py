@@ -82,12 +82,16 @@ def test_the_cpu_keeps_ragged_dot():
 @pytest.mark.parametrize("k,n,impl", [
     (2304, 896, "pallas_gmm"), (896, 2304, "pallas_gmm"),   # XLA: 256 x 128
     (2048, 896, "pallas_gmm"),                  # one narrow width is enough
-    (2048, 1024, "ragged_dot"), (1024, 2048, "ragged_dot")])  # XLA: 512 x 512
+    (2048, 1024, "ragged_dot"), (1024, 2048, "ragged_dot"),  # XLA: 512 x 512
+    (3584, 1024, "pallas_gmm"), (1024, 3584, "pallas_gmm"),  # 14 such tiles
+    (7168, 2048, "pallas_gmm")])                             # 56
 def test_a_tpu_backend_takes_the_kernel_where_xla_tiles_narrow(
         k, n, impl, m, monkeypatch):
-    """One resolver, by the platform and the shapes: widths XLA's own
-    heuristic tiles 512 x 512 keep ``ragged_dot`` (a third to gain a
-    call, ~2 s of Mosaic lowering a process to pay: PERF.md, PR 40)."""
+    """One resolver, by the platform and the shapes: an expert of at most
+    eight of the 512 x 512 tiles XLA's own heuristic reaches keeps
+    ``ragged_dot`` (a third to gain a call, ~2 s of Mosaic lowering a
+    process to pay: PERF.md, PR 40); a larger one takes the kernel
+    (PERF.md, PR 50)."""
     monkeypatch.setattr(moe_dispatch, "on_chip", lambda: True)
     got, tiling = grouped_matmul_impl(m, k, n, 2)
     assert got == impl

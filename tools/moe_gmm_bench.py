@@ -5,6 +5,7 @@
     chiprun -- python3 tools/moe_gmm_bench.py tilings    # PR 40's sweep
     chiprun -- python3 tools/moe_gmm_bench.py tilings 1024 12288  # row tiles
     chiprun -- python3 tools/moe_gmm_bench.py chosen     # PR 41's reading
+    chiprun -- python3 tools/moe_gmm_bench.py wide       # PR 50's reading
 
 **The whole stack (PR 37).** A decode step's 256 rows (32 slots, top-8)
 through one layer's three grouped matmuls, at both expert cells' shapes
@@ -51,6 +52,16 @@ experts of 2048 x 768 (kanana-2-30b-a3b-d5: 4 expert layers, top-6), a
 decode step's 192 rows and a 512-token chunk's 3,072, the layer's three
 calls on the whole stack at the last layer's offset, on the resolver's
 choice and on ``ragged_dot``.
+
+**``wide`` (PR 50)**: ``chosen`` at 64 experts of 3584 x 1024
+(xing4.0-29b-a4b-d5: 4 expert layers, top-4), a decode step's 128 rows
+and a 512-token chunk's 2,048. 3,584 = 7 x 512, so XLA's own tiling is
+512 x 512 (``xla_tiles_wide``), fourteen tiles an expert; the resolver
+takes the Pallas kernel at ``gmm_tiling``'s two ((128, 3584, 512) and
+(128, 1024, 1792) at 128 rows: legal though 28 lane tiles are no power of
+two). The line also times the kernel at ``gmm_tiling``'s choice whatever
+the resolver says (``ms_gmm_tiling``): it was the reading that moved the
+rule (1.689 for 2.074 ms at 128 rows, 2.374 for 4.912 at 2,048).
 
 **``held`` (PR 43)**: an expert layer that HOLDS A SHARE of its router's
 experts (deepseek-v3.2-d5: 16 of 256 experts of 7168 x 2048, top-8, 4
@@ -317,10 +328,11 @@ def sweep_tilings(rows_asked, out):
 
 
 def chosen(name="kanana-2-30b-a3b-d5", L=4, experts=128, d=2048, f=768,
-           top_k=6, rows_asked=(192, 3072)):
+           top_k=6, rows_asked=(192, 3072), with_gmm_tiling=False):
     sys.path.insert(0, os.path.dirname(os.path.dirname(
         os.path.abspath(__file__))))    # the one mode that asks the program
-    from ray_tpu.ops.moe_dispatch import (gmm_vmem_bytes, grouped_matmul,
+    from ray_tpu.ops.moe_dispatch import (gmm_tiling, gmm_vmem_bytes,
+                                          grouped_matmul,
                                           grouped_matmul_impl)
 
     rng = np.random.default_rng(0)
@@ -343,9 +355,12 @@ def chosen(name="kanana-2-30b-a3b-d5", L=4, experts=128, d=2048, f=768,
             line[call] = {"impl": impl, "tiling": tiling, "vmem_bytes": (
                 tiling and gmm_vmem_bytes(*tiling, 2))}
         reps = 200 if rows <= 256 else 50
-        for impl, grouped in (
-                ("resolver", lambda a, w, s: grouped_matmul(
-                    a, w, s, jnp.bfloat16)), ("ragged_dot", ragged)):
+        impls = [("resolver", lambda a, w, s: grouped_matmul(
+            a, w, s, jnp.bfloat16)), ("ragged_dot", ragged)]
+        if with_gmm_tiling:
+            impls.append(("gmm_tiling", lambda a, w, s: megablox(
+                gmm_tiling(a.shape[0], *w.shape[1:]))(a, w, s)))
+        for impl, grouped in impls:
             line["ms_" + impl] = timed(ffn(grouped),
                                        (xs, wg, wu, wd, jnp.asarray(sizes)),
                                        reps)
@@ -452,6 +467,9 @@ def main():
         tilings(tuple(int(r) for r in sys.argv[2:]))
     elif sys.argv[1:] == ["chosen"]:
         chosen()
+    elif sys.argv[1:] == ["wide"]:
+        chosen("xing4.0-29b-a4b-d5", 4, 64, 3584, 1024, 4, (128, 2048),
+               with_gmm_tiling=True)
     elif sys.argv[1:] == ["held"]:
         held()
     elif sys.argv[1:] == ["latent"]:
