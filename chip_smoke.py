@@ -242,12 +242,13 @@ def sizes() -> dict:
         kernel_shapes=((MAX_SLOTS, 32, 8, 64), (MAX_SLOTS, 8, 4, 128),
                        (32, 32, 8, 128), (32, 16, 16, 128)),
         kernel_seq=2048,
-        # (S, H, Hkv, D, causal) of the fused training attention: the
-        # kernel shapes' widths at 2,048 (a row block's spans split into
+        # (S, H, Hkv, D, causal) of the fused training attention:
+        # gpt2-medium.train_1chip's, the kernel shapes' widths at 2,048 (a row block's spans split into
         # tiles there), the longest sequence that stays in VMEM, ViT-B's
         # 197 patches (not causal, ending inside a block), and one past
         # residency, which takes the scan
-        attention_cases=((2048, 32, 8, 64, True), (2048, 8, 4, 128, True),
+        attention_cases=((1024, 16, 16, 64, True), (2048, 32, 8, 64, True),
+                         (2048, 8, 4, 128, True),
                          (2048, 32, 8, 128, True), (2048, 16, 16, 128, True),
                          (4096, 16, 16, 64, True), (2816, 8, 4, 128, False),
                          (197, 12, 12, 64, False), (8192, 8, 8, 128, True)))
@@ -278,7 +279,7 @@ def kernels_phase(rep: Report, sz: dict) -> None:
 
     from ray_tpu._private.platform import on_chip, pallas_interpret
     from ray_tpu.ops.attention import (_stays_resident, flash_attention,
-                                       reference_attention)
+                                       packed_attention, reference_attention)
     from ray_tpu.ops.paged_attention import (
         default_impl, paged_decode_attention_pallas,
         paged_decode_attention_reference)
@@ -325,7 +326,10 @@ def kernels_phase(rep: Report, sz: dict) -> None:
 
     # the fused training attention, forward AND backward: o, dq, dk, dv
     # against the reference's own, at every kernel shape's head width and
-    # at the gate's edges
+    # at the gate's edges, in the projections' layout (two 64-wide heads
+    # a lane tile, a GQA group's kv head in either slot), timed; where
+    # every head has its kv head and the kernels run, the packed entry
+    # too, whose o and ONE gradient are the three arrays' to the bit
     for S, H, Hkv, D, causal in sz["attention_cases"]:
         shape = (f"S{S}/H{H}/Hkv{Hkv}/D{D}/"
                  f"{'causal' if causal else 'full'}")
@@ -345,8 +349,12 @@ def kernels_phase(rep: Report, sz: dict) -> None:
             lambda q, k, v: flash_attention(q, k, v, causal)))
         want = o_and_grads(
             lambda q, k, v: reference_attention(q, k, v, causal=causal))
+        got = jax.block_until_ready(fused())
+        t0 = time.perf_counter()
+        jax.block_until_ready(fused())
+        ms = (time.perf_counter() - t0) * 1e3
         errs = {name: (max_diff(a, b), float(jnp.max(jnp.abs(b))))
-                for name, a, b in zip(("o", "dq", "dk", "dv"), fused(), want)}
+                for name, a, b in zip(("o", "dq", "dk", "dv"), got, want)}
         rep.check(f"fused attention {shape} matches its reference, forward "
                   f"and backward",
                   all(err <= KERNEL_TOL * max(1.0, peak)
@@ -354,9 +362,23 @@ def kernels_phase(rep: Report, sz: dict) -> None:
                   "max |diff| (of a peak of) " + ", ".join(
                       f"{name} {err:.4f} ({peak:.2f})"
                       for name, (err, peak) in errs.items())
-                  + f" <= {KERNEL_TOL} x max(1, peak)")
+                  + f" <= {KERNEL_TOL} x max(1, peak); forward + backward "
+                  f"{ms:.2f} ms")
         # a head's sequence that does not stay in VMEM takes the scan
         kernels = 2 if _stays_resident(S, S, D, jnp.bfloat16, causal) else 0
+        if H == Hkv and kernels:
+            def packed(x):
+                o = packed_attention(x, H, causal=causal, use_flash=True)
+                return (o.reshape(weigh.shape) * weigh).astype(
+                    jnp.float32).sum(), o
+            (_, o), grad = jax.jit(jax.value_and_grad(packed, has_aux=True))(
+                jnp.stack(qkv, 2).reshape(1, S, -1))
+            grad = grad.reshape(1, S, 3, H, D)
+            diffs = [max_diff(o.reshape(weigh.shape), got[0]),
+                     *(max_diff(grad[:, :, n], got[1 + n]) for n in range(3))]
+            rep.check(f"packed attention {shape} gives the three arrays' o "
+                      f"and gradient", max(diffs) == 0.0,
+                      f"max |diff| of o, dq, dk, dv: {diffs}")
         mosaic = fused.lower().compile().as_text().count("tpu_custom_call")
         rep.check(f"fused attention {shape} is {kernels} Mosaic kernels "
                   f"unless interpreted",

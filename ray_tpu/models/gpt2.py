@@ -30,7 +30,8 @@ import jax
 import jax.numpy as jnp
 from jax.ad_checkpoint import checkpoint_name
 
-from ray_tpu.ops.attention import FLASH_RESIDUALS, attention, use_flash_on
+from ray_tpu.ops.attention import (FLASH_RESIDUALS, attention,
+                                   packed_attention, use_flash_on)
 from ray_tpu.ops.norms import layer_norm
 from ray_tpu.util.metrics import Gauge
 
@@ -188,14 +189,27 @@ class GPT2Model:
             h = layer_norm(x, layer["ln1_w"], layer["ln1_b"],
                            eps=cfg.norm_eps)
         with jax.named_scope("attention"):
-            qkv = jnp.einsum("bsd,dthk->bsthk", h, layer["wqkv"].astype(dt))
-            qkv = checkpoint_name(qkv + layer["bqkv"].astype(dt), "gpt2.qkv")
-            q, kk, vv = qkv[:, :, 0], qkv[:, :, 1], qkv[:, :, 2]
-            # a Mosaic call carries no partitioning rule: under a mesh
-            # the reference, off one what the dispatcher finds tiles
-            o = attention(q, kk, vv, causal=True,
-                          use_flash=self._use_flash)
-            o = jnp.einsum("bshk,hkd->bsd", o, layer["wo"].astype(dt))
+            wqkv, bqkv, wo = (layer[name].astype(dt)
+                              for name in ("wqkv", "bqkv", "wo"))
+            if self.mesh is not None:
+                # the heads are sharded: the product keeps them an axis,
+                # and is cut for the reference (a Mosaic call carries no
+                # partitioning rule)
+                qkv = jnp.einsum("bsd,dthk->bsthk", h, wqkv) + bqkv
+                qkv = checkpoint_name(qkv, "gpt2.qkv")
+                o = attention(*(qkv[:, :, n] for n in range(3)), causal=True,
+                              use_flash=False)
+                o = jnp.einsum("bshk,hkd->bsd", o, wo)
+            else:
+                # off a mesh the heads stay side by side in the lanes from
+                # the product to wo: what the dispatcher finds tiles, and
+                # the kernels, read and write that as it lies
+                qkv = jnp.einsum("bsd,df->bsf", h,
+                                 wqkv.reshape(cfg.dim, -1)) + bqkv.reshape(-1)
+                qkv = checkpoint_name(qkv, "gpt2.qkv")
+                o = packed_attention(qkv, cfg.n_heads, causal=True,
+                                     use_flash=self._use_flash)
+                o = jnp.einsum("bsf,fd->bsd", o, wo.reshape(-1, cfg.dim))
         with jax.named_scope("norm_residual"):
             x = checkpoint_name(x + o + layer["bo"].astype(dt), "gpt2.wo")
             h = layer_norm(x, layer["ln2_w"], layer["ln2_b"],

@@ -9,10 +9,11 @@ native requirement for this build).
 
 from ray_tpu.ops.norms import rms_norm, layer_norm
 from ray_tpu.ops.rope import apply_rope, rope_frequencies
-from ray_tpu.ops.attention import attention, flash_attention
+from ray_tpu.ops.attention import (attention, flash_attention,
+                                   packed_attention)
 from ray_tpu.ops.ring_attention import ring_attention
 
 __all__ = [
     "rms_norm", "layer_norm", "apply_rope", "rope_frequencies",
-    "attention", "flash_attention", "ring_attention",
+    "attention", "flash_attention", "packed_attention", "ring_attention",
 ]

@@ -7,13 +7,17 @@ TPU-native:
 - ``attention``      — dispatcher; GQA-aware, causal or not,
   differentiable. Takes ``flash_attention`` on a TPU where ``kernels_tile``
   says the shapes tile, the reference everywhere else.
+  ``packed_attention`` is the same for a qkv projection's one product.
 - ``flash_attention``— a fused pair of Pallas kernels behind one
   ``custom_vjp``: the forward keeps ``o`` and a row of log-sum-exp, the
   backward rebuilds ``p`` from q, k and that row a tile at a time and
   gives dq, dk and dv from one pass. No S x S (nor S x block) array
   reaches HBM in either direction; tiles above the causal diagonal are
-  never visited. The kernels keep a head's whole sequence in VMEM: a
-  longer one (``_stays_resident``) takes ``blockwise_attention``.
+  never visited. q, k, v, dO, O, dq, dk and dv are read and written as
+  the projections hold them, ``[B, S, H * D]`` a lane tile at a time (two
+  64-wide heads a tile): no transpose or copy stands around the calls.
+  The kernels keep a head's whole sequence in VMEM: a longer one
+  (``_stays_resident``) takes ``blockwise_attention``.
 - ``blockwise_attention`` — the XLA online-softmax scan, O(S x block)
   memory at any length: what a mesh runs (a Mosaic call cannot be
   partitioned), what Ulysses runs, and the long sequences' path.
@@ -24,7 +28,8 @@ Shapes follow the JAX convention [batch, seq, heads, head_dim].
 from __future__ import annotations
 
 import functools
-from typing import Optional
+import operator
+from typing import NamedTuple, Optional
 
 import jax
 import jax.numpy as jnp
@@ -138,8 +143,20 @@ def blockwise_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
 # ---------------------------------------------------------------------------
 # Pallas flash attention: ONE fused pair, a forward and a backward kernel
 # ---------------------------------------------------------------------------
-# A grid step holds one head whole in VMEM (q, K, V; in the backward do
-# and the two rows of statistics besides) and walks the score matrix in
+# The kernels read q, k, v and dO and write O, dq, dk and dv where the
+# projections keep them: ``[B, S, H * D]``, a block the whole sequence of
+# one LANE TILE of it. A head of 128 or 256 lanes is a tile; narrower
+# heads lie side by side in one (two of 64), each in its SLOT, and are
+# never cut apart: a matmul contracts the tile's 128 lanes with every lane
+# outside the head's slot zeroed, which costs the 128-deep MXU what a
+# 64-deep contraction does, and a product that comes out 128 lanes wide
+# keeps the slot that is the head's. Under GQA a q head and its kv head
+# may lie in different slots of their tiles: the one is rolled to the
+# other's. Nothing is transposed, padded or copied on the way in or out
+# where S is whole lanes and the heads fill their tiles (``_lane_tiles``).
+#
+# A grid step holds a tile whole in VMEM (q, K, V; in the backward do
+# and the rows of statistics besides) and walks the score matrix in
 # row blocks whose tiles are STATIC slices: a block's tiles stop at the
 # causal diagonal, only the tile that crosses the mask's edge pays for a
 # mask, and the rest of the row is taken in tiles up to ``_WIDE`` columns.
@@ -147,7 +164,7 @@ def blockwise_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
 # the scheduler can overlap (square tiles under ``fori_loop`` with traced
 # bounds ran 1.45x slower on the v5e, PERF.md PR 48). Nothing of
 # S x S or S x block size is written to HBM in either direction; what the
-# forward keeps for the backward is ``o`` and a row of log-sum-exp.
+# forward keeps for the backward is ``o`` and a row of log-sum-exp a head.
 
 _NT = (((1,), (1,)), ((), ()))      # a @ b.T
 _TN = (((0,), (0,)), ((), ()))      # a.T @ b
@@ -198,103 +215,240 @@ def _tiles(*spans):
             for a in range(start, stop, _WIDE)]
 
 
+def _lane_tiles(head_dim: int) -> tuple[int, int]:
+    """(heads a lane tile, lanes a head): a width that divides ``_LANES``
+    shares its tile (64: two heads side by side), every other is padded
+    to whole tiles (128 and 256 are one and two as they are)."""
+    if _LANES % head_dim == 0:
+        return _LANES // head_dim, head_dim
+    return 1, head_dim + -head_dim % _LANES
+
+
+def _kv_slot(tile, slot, per_tile: int, group: int):
+    """The slot, in its kv tile, of the kv head of the q head in ``slot``
+    of q tile ``tile``: ``slot`` itself where every head has its own."""
+    if group == 1 or per_tile == 1:
+        return slot
+    return (tile * per_tile + slot) // group % per_tile
+
+
+def _in_slot(shape, slot, width: int):
+    """[rows, lanes] of bool: the lanes of ``slot``, ``width`` a slot."""
+    lane = jax.lax.broadcasted_iota(jnp.int32, shape, 1)
+    return (lane >= slot * width) & (lane < (slot + 1) * width)
+
+
+def _to_slot(x, src, dst, width: int, beside=None):
+    """[rows, lanes]: the head in slot ``src`` moved to slot ``dst``,
+    the other lanes zeros, or ``beside``'s (a slot is ``width`` lanes,
+    and may be traced). A tile of one head is returned as it is."""
+    from jax.experimental.pallas import tpu as pltpu
+
+    lanes = x.shape[1]
+    if lanes == width:
+        return x
+    if src is not dst:
+        # Mosaic rotates 32-bit lanes only
+        x = pltpu.roll(x.astype(jnp.float32), (dst - src) * width % lanes,
+                       1).astype(x.dtype)
+    return jnp.where(_in_slot(x.shape, dst, width), x,
+                     jnp.zeros_like(x) if beside is None else beside)
+
+
+def _q_tile(group: int):
+    """This grid step's q tile, over the grid (batch, kv tile, q tile of
+    the kv tile's ``group``, ...): asked at a kernel's top level."""
+    import jax.experimental.pallas as pl
+    return pl.program_id(1) * group + pl.program_id(2)
+
+
+def _each_head(tile, per_tile: int, group: int, head) -> None:
+    """``head(slot, kv_slot)`` for every q head of q tile ``tile``. The
+    heads of a shared tile run under ONE loop: Mosaic gives every
+    unrolled tile of the walk VMEM of its own, and a loop's body is laid
+    out once."""
+    def body(slot, carry=None):
+        head(slot, _kv_slot(tile, slot, per_tile, group))
+
+    if per_tile == 1:
+        body(0)
+    else:
+        jax.lax.fori_loop(0, per_tile, body, None)
+
+
 def _flash_fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *, scale: float,
-                      block: int, causal: bool, seq_k: int):
-    """One head: q [Sq, D] against K and V [Sk, D], a block of ``block``
-    query rows at a time, online softmax over the block's key tiles."""
+                      block: int, causal: bool, seq_k: int, width: int,
+                      group: int):
+    """One lane tile of q heads ([Sq, lanes], a head ``width`` lanes)
+    against its kv tile's K and V [Sk, lanes]: a head at a time, a block
+    of ``block`` query rows at a time, online softmax over the block's
+    key tiles."""
     pad_q, pad_k = q_ref.shape[0], k_ref.shape[0]
-    for q0 in range(0, pad_q, block):
-        rows = min(block, pad_q - q0)
-        q = q_ref[q0:q0 + rows, :]
-        # keys [0, edge) are seen by every row of the block (whole
-        # ``block``s of them: the tiles stay aligned), [edge, end) by some
-        edge, end = seq_k // block * block, pad_k
-        if causal:
-            edge = min(edge, (q0 + 1) // block * block)
-            end = min(end, q0 + rows)
-        acc = None
-        for a, b, masked in _tiles((0, edge, False), (edge, end, True)):
-            v = v_ref[a:b, :]
-            s = _dot(q, k_ref[a:b, :], _NT) * scale           # [rows, b-a]
-            if masked:
-                s = jnp.where(_visible(q0, a, s.shape, keys_axis=1,
-                                       causal=causal, seq_k=seq_k),
-                              s, NEG_INF)
-            m_tile = jnp.max(s, axis=-1, keepdims=True)
-            if acc is None:
-                m = m_tile
-                p = jnp.exp(s - m)
-                l = jnp.sum(p, axis=-1, keepdims=True)
-                acc = _dot(p.astype(v.dtype), v)
-            else:
-                m_new = jnp.maximum(m, m_tile)
-                alpha = jnp.exp(m - m_new)
-                p = jnp.exp(s - m_new)
-                l = alpha * l + jnp.sum(p, axis=-1, keepdims=True)
-                acc = acc * alpha + _dot(p.astype(v.dtype), v)
-                m = m_new
-        o_ref[q0:q0 + rows, :] = (acc * (1.0 / l)).astype(o_ref.dtype)
-        # a ROW of the array, positions along the lanes: what the
-        # backward's transposed tiles broadcast, and S floats a head in
-        # HBM (a column would be padded to 128 lanes there)
-        lse = jnp.broadcast_to(m + jnp.log(l), (rows, _LANES))
-        lse_ref[:, q0:q0 + rows] = jnp.transpose(lse)[:1]
+    tile, per_tile = _q_tile(group), q_ref.shape[1] // width
+
+    def head(slot, kv_slot):
+        for q0 in range(0, pad_q, block):
+            rows = min(block, pad_q - q0)
+            # the head of q where its kv head lies, alone in the tile:
+            # the contraction over all the lanes is the head's own
+            q = _to_slot(q_ref[q0:q0 + rows, :], slot, kv_slot, width)
+            # keys [0, edge) are seen by every row of the block (whole
+            # ``block``s of them: the tiles stay aligned), [edge, end) by
+            # some
+            edge, end = seq_k // block * block, pad_k
+            if causal:
+                edge = min(edge, (q0 + 1) // block * block)
+                end = min(end, q0 + rows)
+            acc = None
+            for a, b, masked in _tiles((0, edge, False), (edge, end, True)):
+                v = v_ref[a:b, :]
+                s = _dot(q, k_ref[a:b, :], _NT) * scale       # [rows, b-a]
+                if masked:
+                    s = jnp.where(_visible(q0, a, s.shape, keys_axis=1,
+                                           causal=causal, seq_k=seq_k),
+                                  s, NEG_INF)
+                m_tile = jnp.max(s, axis=-1, keepdims=True)
+                if acc is None:
+                    m = m_tile
+                    p = jnp.exp(s - m)
+                    l = jnp.sum(p, axis=-1, keepdims=True)
+                    acc = _dot(p.astype(v.dtype), v)
+                else:
+                    m_new = jnp.maximum(m, m_tile)
+                    alpha = jnp.exp(m - m_new)
+                    p = jnp.exp(s - m_new)
+                    l = alpha * l + jnp.sum(p, axis=-1, keepdims=True)
+                    acc = acc * alpha + _dot(p.astype(v.dtype), v)
+                    m = m_new
+            # p @ v is the tile's lanes wide: the kv head's slot of it is
+            # this head's, and goes to the slot the head has in q's tile,
+            # beside what the tile's other heads wrote
+            o_ref[q0:q0 + rows, :] = _to_slot(
+                (acc * (1.0 / l)).astype(o_ref.dtype), kv_slot, slot, width,
+                beside=o_ref[q0:q0 + rows, :])
+            # a ROW of the array, positions along the lanes: what the
+            # backward's transposed tiles broadcast, and S floats a head
+            # in HBM (a column would be padded to 128 lanes there)
+            lse = jnp.broadcast_to(m + jnp.log(l), (rows, _LANES))
+            lse_ref[slot, :, q0:q0 + rows] = jnp.transpose(lse)[:1]
+
+    _each_head(tile, per_tile, group, head)
 
 
-def _flash_bwd_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
-                      dq_ref, dk_ref, dv_ref, dq_acc, dk_acc, dv_acc, *,
-                      scale: float, block: int, causal: bool, seq_k: int):
-    """One q head's dq and its share of its kv head's dk and dv, a block
-    of ``block`` keys at a time. The tiles are the TRANSPOSED scores
-    [keys, queries]: ``p`` is rebuilt from q, k and the log-sum-exp row
-    and feeds dv, dk and dq from one pass. The q heads of a GQA group are
-    consecutive grid steps that add into one dk and dv."""
+def _flash_bwd_kernel(q_ref, k_ref, v_ref, do_ref, o_ref, lse_ref, *refs,
+                      scale: float, block: int, causal: bool, seq_k: int,
+                      width: int, group: int):
+    """One lane tile of q heads' dq and its share of its kv tile's dk and
+    dv, a block of ``block`` keys at a time. The tiles are the TRANSPOSED
+    scores [keys, queries]: ``p`` is rebuilt from q, k and the log-sum-exp
+    row and feeds dv, dk and dq from one pass, a head of the tile after
+    the other over each tile's q and dO (unrolled, unlike the forward's:
+    this stack fits, and a tile's loads, its mask and its dq are the
+    heads' together: 1.60 against 1.75 ms a layer at the cell's shape,
+    PERF.md PR 51). The q tiles of a kv tile are consecutive grid steps
+    that add into one dk and dv.
+
+    ``refs``: the outputs, three float32 accumulators and delta's rows
+    (8 sublanes a head, as a row of log-sum-exp is held). Three outputs
+    are dq, dk and dv; ONE is the packed gradient, whose lane tiles of
+    dq, dk and dv are three more grid steps' blocks (the grid's last
+    axis: the first computes, each writes the accumulator it names)."""
     import jax.experimental.pallas as pl
 
-    g, group = pl.program_id(1), pl.num_programs(1)
+    *outs, dq_acc, dk_acc, dv_acc, delta_rows = refs
+    g = pl.program_id(2)
     pad_q, pad_k = q_ref.shape[0], k_ref.shape[0]
-    for k0 in range(0, pad_k, block):
-        ks = slice(k0, min(k0 + block, pad_k))
-        k, v = k_ref[ks, :], v_ref[ks, :]
-        # queries [first, edge) see some of these keys, [edge, Sq) all
-        first, edge = 0, pad_q if ks.stop > seq_k else 0
-        if causal:
-            first = k0
-            edge = max(edge, ks.stop)
-        edge = min(edge, pad_q)
-        dk = dv = jnp.zeros(k.shape, jnp.float32)
-        for a, b, masked in _tiles((first, edge, True),
-                                   (edge, pad_q, False)):
-            q, do = q_ref[a:b, :], do_ref[a:b, :]
-            st = _dot(k, q, _NT) * scale                      # [keys, b-a]
-            if masked:
-                st = jnp.where(_visible(a, k0, st.shape, keys_axis=0,
-                                        causal=causal, seq_k=seq_k),
-                               st, NEG_INF)
-            pt = jnp.exp(st - lse_ref[:, a:b])
-            dv += _dot(pt.astype(do.dtype), do)
-            dpt = _dot(v, do, _NT)
-            dst = (pt * (dpt - delta_ref[:, a:b]) * scale).astype(q.dtype)
-            dk += _dot(dst, q)
-            dq = _dot(dst, k, _TN)                            # [b-a, D]
-            if k0 == 0:         # the first key block is seen by every row
-                dq_acc[a:b, :] = dq
-            else:
-                dq_acc[a:b, :] += dq
+    tile, per_tile = _q_tile(group), q_ref.shape[1] // width
+    kv_slots = [_kv_slot(tile, slot, per_tile, group)
+                for slot in range(per_tile)]
 
-        @pl.when(g == 0)
-        def _first_of_group():
-            dk_acc[ks, :] = dk
-            dv_acc[ks, :] = dv
+    def accumulate():
+        # delta = rowsum(o * do), what softmax's backward subtracts from
+        # dp: a ROW a head, as the log-sum-exp is. Ones over a head's
+        # lanes against o * do sum the head's lanes and lay the sums out
+        # along the lanes in one matmul; the float32 product goes in as
+        # two bfloat16 halves, which hold the 16 bits it has
+        for a in range(0, pad_q, _BLOCK_Q):
+            b = min(a + _BLOCK_Q, pad_q)
+            product = (o_ref[a:b, :].astype(jnp.float32)
+                       * do_ref[a:b, :].astype(jnp.float32))
+            high = product.astype(jnp.bfloat16)
+            low = (product - high.astype(jnp.float32)).astype(jnp.bfloat16)
+            for slot in range(per_tile):
+                ones = _in_slot((8, q_ref.shape[1]), slot,
+                                width).astype(jnp.bfloat16)
+                delta_rows[slot, :, a:b] = (_dot(ones, high, _NT)
+                                            + _dot(ones, low, _NT))
+        for k0 in range(0, pad_k, block):
+            ks = slice(k0, min(k0 + block, pad_k))
+            # a kv head where its q head lies, alone in the tile: the
+            # scores contract the head's lanes only, and dq comes out in
+            # the q head's slot with zeros beside it
+            kvs = [(_to_slot(k_ref[ks, :], kv_slot, slot, width),
+                    _to_slot(v_ref[ks, :], kv_slot, slot, width))
+                   for slot, kv_slot in enumerate(kv_slots)]
+            # queries [first, edge) see some of these keys, [edge, Sq) all
+            first, edge = 0, pad_q if ks.stop > seq_k else 0
+            if causal:
+                first = k0
+                edge = max(edge, ks.stop)
+            edge = min(edge, pad_q)
+            zeros = jnp.zeros((ks.stop - k0, q_ref.shape[1]), jnp.float32)
+            dks, dvs = [zeros] * per_tile, [zeros] * per_tile
+            for a, b, masked in _tiles((first, edge, True),
+                                       (edge, pad_q, False)):
+                q, do = q_ref[a:b, :], do_ref[a:b, :]
+                if masked:
+                    visible = _visible(a, k0, (ks.stop - k0, b - a),
+                                       keys_axis=0, causal=causal,
+                                       seq_k=seq_k)
+                dq = None
+                for slot, (k, v) in enumerate(kvs):
+                    st = _dot(k, q, _NT) * scale              # [keys, b-a]
+                    if masked:
+                        st = jnp.where(visible, st, NEG_INF)
+                    pt = jnp.exp(st - lse_ref[slot, :, a:b])
+                    dvs[slot] += _dot(pt.astype(do.dtype), do)
+                    dpt = _dot(v, do, _NT)
+                    dst = (pt * (dpt - delta_rows[slot, :1, a:b])
+                           * scale).astype(q.dtype)
+                    dks[slot] += _dot(dst, q)
+                    part = _dot(dst, k, _TN)                  # [b-a, lanes]
+                    dq = part if dq is None else dq + part
+                if k0 == 0:     # the first key block is seen by every row
+                    dq_acc[a:b, :] = dq
+                else:
+                    dq_acc[a:b, :] += dq
+            # dst.T @ q and p.T @ do are the tile's lanes wide: the q
+            # head's slot of each is its kv head's share, and goes to the
+            # kv head's slot
+            dk, dv = (functools.reduce(operator.add, (
+                _to_slot(d, slot, kv_slot, width)
+                for slot, (kv_slot, d) in enumerate(zip(kv_slots, ds))))
+                for ds in (dks, dvs))
 
-        @pl.when(g > 0)
-        def _rest_of_group():
-            dk_acc[ks, :] += dk
-            dv_acc[ks, :] += dv
+            @pl.when(g == 0)
+            def _first_of_group():
+                dk_acc[ks, :] = dk
+                dv_acc[ks, :] = dv
 
+            @pl.when(g > 0)
+            def _rest_of_group():
+                dk_acc[ks, :] += dk
+                dv_acc[ks, :] += dv
+
+    if len(outs) == 1:
+        pl.when(pl.program_id(3) == 0)(accumulate)
+        for step, acc in enumerate((dq_acc, dk_acc, dv_acc)):
+            @pl.when(pl.program_id(3) == step)
+            def _write(acc=acc):
+                outs[0][...] = acc[...].astype(outs[0].dtype)
+        return
+    accumulate()
+    dq_ref, dk_ref, dv_ref = outs
     dq_ref[...] = dq_acc[...].astype(dq_ref.dtype)
 
-    @pl.when(g == group - 1)
+    @pl.when(g == pl.num_programs(2) - 1)
     def _last_of_group():
         dk_ref[...] = dk_acc[...].astype(dk_ref.dtype)
         dv_ref[...] = dv_acc[...].astype(dv_ref.dtype)
@@ -318,47 +472,113 @@ def _stays_resident(seq_q: int, seq_k: int, head_dim: int, dtype,
                     causal: bool) -> bool:
     """Whether the kernels take these sequences: no more pairs than they
     are unrolled for, and the backward's VMEM (the larger of the two)
-    inside ``_vmem_limit``: q, do, k, v in and dq, dk, dv out, each
-    buffered twice, the two rows of statistics (8 sublanes of float32)
-    twice, and three float32 accumulators; a tile's lanes are padded to
-    128."""
+    inside ``_vmem_limit``: q, do, o, k, v in and dq, dk, dv out, each
+    buffered twice, the row of log-sum-exp twice and delta's once (8
+    sublanes of float32), and three float32 accumulators; a tile is 128
+    lanes or the head's."""
     hidden = min(seq_q, seq_k) if causal else 0     # above the diagonal
     pairs = seq_q * seq_k - hidden * hidden // 2
     pad_q, pad_k = (s + -s % _LANES for s in (seq_q, seq_k))
     width, item = max(head_dim, _LANES), jnp.dtype(dtype).itemsize
-    resident = (2 * item * width * (3 * pad_q + 4 * pad_k)
-                + 2 * 2 * 8 * 4 * pad_q
+    resident = (2 * item * width * (4 * pad_q + 4 * pad_k)
+                + 3 * 8 * 4 * pad_q
                 + 4 * width * (pad_q + 2 * pad_k))
     return (pairs <= _MAX_UNROLLED
             and resident + _TILES_BYTES <= _vmem_limit())
 
 
-def _by_head(x: jax.Array, num_kv: int) -> jax.Array:
-    """[B, S, H, D] -> [B * Hkv, H // Hkv, S', D]: a kv head's q heads
-    side by side (``_repeat_kv``'s order), S padded with zeros to whole
-    ``_LANES`` (a block's last tile may be short, a row of log-sum-exp
-    is stored in whole lanes)."""
-    batch, seq, heads, head_dim = x.shape
-    x = jnp.pad(x, ((0, 0), (0, -seq % _LANES), (0, 0), (0, 0)))
-    return x.transpose(0, 2, 1, 3).reshape(
-        batch * num_kv, heads // num_kv, x.shape[1], head_dim)
+class _Layout(NamedTuple):
+    """Where a call's lane tiles are, read from its operands' shapes."""
+    width: int          # lanes a head
+    per_tile: int       # heads a lane tile
+    kv_tiles: int       # lane tiles of k, and of v
+    group: int          # q tiles a kv tile: q heads a kv head
+    # the lane tile at which q, k and v begin in their arrays: three
+    # arrays, or the qkv projection's one
+    first: tuple = (0, 0, 0)
+
+    @property
+    def lanes(self) -> int:
+        return self.width * self.per_tile
+
+    # heads in whole tiles: kv heads up to the next tile and q heads with
+    # them (heads of zeros, and none where ``kernels_tile``)
+    @property
+    def kv_heads(self) -> int:
+        return self.kv_tiles * self.per_tile
+
+    @property
+    def q_heads(self) -> int:
+        return self.kv_heads * self.group
+
+    def specs(self, pad_q: int, pad_k: int, held=lambda b, i, *step: (b, i)):
+        """Block specs over the grid (batch, kv tile, q tile of the kv
+        tile's group, ...): ``of_q(first)``, a q tile's [Sq', lanes] of
+        an array whose q tiles begin at lane tile ``first``; ``of_kv``,
+        a kv tile's [Sk', lanes] (the same block for the whole group:
+        fetched once); the q tile's heads' rows [heads a tile, 1, Sq'].
+        ``held(batch, kv tile, ...)``: whose blocks a grid step holds."""
+        import jax.experimental.pallas as pl
+
+        def of_q(first=0):
+            def index(b, i, g, *step):
+                b, i = held(b, i, *step)
+                return b, 0, first + i * self.group + g
+            return pl.BlockSpec((None, pad_q, self.lanes), index)
+
+        def of_kv(first=0):
+            def index(b, i, g, *step):
+                b, i = held(b, i, *step)
+                return b, 0, first + i
+            return pl.BlockSpec((None, pad_k, self.lanes), index)
+
+        def rows_index(b, i, g, *step):
+            b, i = held(b, i, *step)
+            return b, i * self.group + g, 0, 0
+
+        return of_q, of_kv, pl.BlockSpec((None, self.per_tile, 1, pad_q),
+                                         rows_index)
 
 
-def _from_heads(x: jax.Array, batch: int, seq: int) -> jax.Array:
-    """``_by_head``'s inverse, the padding dropped."""
-    return x.reshape(batch, -1, *x.shape[2:])[:, :, :seq] \
-        .transpose(0, 2, 1, 3)
+def _in_lanes(x: jax.Array, heads: int, width: int) -> jax.Array:
+    """[B, S, ..., h, D] -> [B, S', ... * heads * width]: the array as
+    the projection wrote it, reshaped, where S is whole ``_LANES`` (a
+    block's last tile may be short, a row of log-sum-exp is stored in
+    whole lanes), h is ``heads`` and D is ``width``; padded with zeros
+    where not."""
+    batch, seq, *_, h, head_dim = x.shape
+    x = jnp.pad(x, ((0, 0), (0, -seq % _LANES), *[(0, 0)] * (x.ndim - 4),
+                    (0, heads - h), (0, width - head_dim)))
+    return x.reshape(batch, x.shape[1], -1)
 
 
-def _head_specs(pad_q: int, pad_k: int, head_dim: int):
-    """Block specs over the grid (kv head, q head of its group): a q
-    head's [Sq, D], its row of statistics [1, Sq], a kv head's [Sk, D]
-    (the same block for the whole group: fetched once)."""
-    import jax.experimental.pallas as pl
-    return (pl.BlockSpec((None, None, pad_q, head_dim),
-                         lambda i, g: (i, g, 0, 0)),
-            pl.BlockSpec((None, None, 1, pad_q), lambda i, g: (i, g, 0, 0)),
-            pl.BlockSpec((None, pad_k, head_dim), lambda i, g: (i, 0, 0)))
+def _from_lanes(x: jax.Array, width: int, shape) -> jax.Array:
+    """``_in_lanes``'s inverse, to an array of ``shape``."""
+    (_, seq, *packed, heads, head_dim) = shape
+    return x.reshape(*x.shape[:2], *packed, -1, width)[
+        :, :seq, ..., :heads, :head_dim]
+
+
+def _operands(qkv, heads: Optional[int]):
+    """((q, k, v) in lanes, their ``_Layout``, o's shape) of q
+    [B, S, H, D], k and v [B, S, Hkv, D], or of ONE qkv projection's
+    [B, S, 3 * heads * D]: then q, k and v are the same array, begun at
+    three lane tiles, and o is [B, S, heads * D]."""
+    if len(qkv) == 1:
+        batch, seq, _ = qkv[0].shape
+        qkv = (qkv[0].reshape(batch, seq, 3, heads, -1),)
+    q_heads, kv_heads, head_dim = (qkv[0].shape[-2], qkv[-1].shape[-2],
+                                   qkv[0].shape[-1])
+    per_tile, width = _lane_tiles(head_dim)
+    lay = _Layout(width, per_tile, -(-kv_heads // per_tile),
+                  q_heads // kv_heads)
+    shape = (*qkv[0].shape[:2], q_heads, head_dim)
+    if len(qkv) == 3:
+        return tuple(_in_lanes(x, n, width) for x, n in zip(
+            qkv, (lay.q_heads, lay.kv_heads, lay.kv_heads))), lay, shape
+    packed = _in_lanes(qkv[0], lay.kv_heads, width)
+    return (packed,) * 3, lay._replace(
+        first=(0, lay.kv_tiles, 2 * lay.kv_tiles)), shape
 
 
 def _compiler_params(*semantics: str):
@@ -367,69 +587,102 @@ def _compiler_params(*semantics: str):
                                 vmem_limit_bytes=_vmem_limit())
 
 
-@functools.partial(jax.jit, static_argnames=("causal", "block", "interpret"))
-def _flash_forward(q, k, v, *, causal: bool, block: Optional[int],
-                   interpret: bool):
-    """-> (o [B, S, H, D], log-sum-exp [B * Hkv, H // Hkv, 1, S']).
-    ``block``: query rows a block."""
+_STATIC = ("heads", "causal", "block", "interpret")
+
+
+@functools.partial(jax.jit, static_argnames=_STATIC)
+def _flash_forward(*qkv, heads: Optional[int] = None, causal: bool,
+                   block: Optional[int], interpret: bool):
+    """``_operands``'s q, k, v, or packed qkv of ``heads`` -> (o
+    [B, S, H, D], or [B, S, H * D] of a packed qkv; log-sum-exp
+    [B, H', 1, S']), over the grid (batch, kv tile, q tile of the kv
+    tile's group): a q tile's [Sq', lanes] and its heads' rows, a kv
+    tile's [Sk', lanes] (the same block for the whole group: fetched
+    once). ``block``: query rows a block."""
     import jax.experimental.pallas as pl
 
-    batch, seq_q, num_heads, head_dim = q.shape
-    seq_k, num_kv = k.shape[1], k.shape[2]
-    qh = _by_head(q, num_kv)
-    kh, vh = (_by_head(x, num_kv)[:, 0] for x in (k, v))
-    q_spec, row_spec, kv_spec = _head_specs(qh.shape[2], kh.shape[1],
-                                            head_dim)
+    (q, k, v), lay, shape = _operands(qkv, heads)
+    batch, pad_q, pad_k = q.shape[0], q.shape[1], k.shape[1]
+    seq_k, head_dim = qkv[-1].shape[1], shape[-1]
+    of_q, of_kv, rows = lay.specs(pad_q, pad_k)
     o, lse = pl.pallas_call(
         functools.partial(_flash_fwd_kernel, scale=head_dim ** -0.5,
                           block=block or _BLOCK_Q, causal=causal,
-                          seq_k=seq_k),
-        grid=(batch * num_kv, num_heads // num_kv),
-        in_specs=[q_spec, kv_spec, kv_spec],
-        out_specs=[q_spec, row_spec],
-        out_shape=[jax.ShapeDtypeStruct(qh.shape, q.dtype),
-                   jax.ShapeDtypeStruct((*qh.shape[:2], 1, qh.shape[2]),
+                          seq_k=seq_k, width=lay.width, group=lay.group),
+        grid=(batch, lay.kv_tiles, lay.group),
+        in_specs=[of_q(lay.first[0]), of_kv(lay.first[1]),
+                  of_kv(lay.first[2])],
+        out_specs=[of_q(), rows],
+        out_shape=[jax.ShapeDtypeStruct(
+                       (batch, pad_q, lay.q_heads * lay.width), q.dtype),
+                   jax.ShapeDtypeStruct((batch, lay.q_heads, 1, pad_q),
                                         jnp.float32)],
-        compiler_params=_compiler_params("parallel", "parallel"),
+        compiler_params=_compiler_params("parallel", "parallel", "parallel"),
         name="flash_attention_fwd_pallas", interpret=interpret,
-    )(qh, kh, vh)
-    return _from_heads(o, batch, seq_q), lse
+    )(q, k, v)
+    o = _from_lanes(o, lay.width, shape)
+    return o.reshape(*shape[:2], -1) if len(qkv) == 1 else o, lse
 
 
-@functools.partial(jax.jit, static_argnames=("causal", "block", "interpret"))
-def _flash_backward(q, k, v, o, lse, do, *, causal: bool,
-                    block: Optional[int], interpret: bool):
-    """-> (dq, dk, dv), shaped as q, k, v. ``block``: key rows a block."""
+@functools.partial(jax.jit, static_argnames=_STATIC)
+def _flash_backward(qkv, o, lse, do, *, heads: Optional[int] = None,
+                    causal: bool, block: Optional[int], interpret: bool):
+    """-> the gradients of ``qkv`` (``_operands``'s three arrays or one),
+    shaped as they are, over the forward's grid. The ONE gradient of a
+    packed qkv takes three grid steps a tile: the first computes and
+    writes dq's lane tile, the two after it dk's and dv's, and while
+    those two run the NEXT tile's operands are what is fetched.
+    ``block``: key rows a block."""
     import jax.experimental.pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
-    batch, seq_q, num_heads, head_dim = q.shape
-    seq_k, num_kv = k.shape[1], k.shape[2]
-    qh, doh = (_by_head(x, num_kv) for x in (q, do))
-    kh, vh = (_by_head(x, num_kv)[:, 0] for x in (k, v))
-    # delta = rowsum(o * do): what softmax's backward subtracts from dp
-    delta = jnp.sum(o.astype(jnp.float32) * do.astype(jnp.float32), axis=-1,
-                    keepdims=True)
-    delta = _by_head(delta, num_kv).reshape(lse.shape)
-    q_spec, row_spec, kv_spec = _head_specs(qh.shape[2], kh.shape[1],
-                                            head_dim)
-    dq, dk, dv = pl.pallas_call(
+    (q, k, v), lay, shape = _operands(qkv, heads)
+    batch, pad_q, pad_k = q.shape[0], q.shape[1], k.shape[1]
+    tiles, packed = lay.kv_tiles, len(qkv) == 1
+    seq_k, head_dim = qkv[-1].shape[1], shape[-1]
+    o, do = (_in_lanes(x.reshape(shape), lay.q_heads, lay.width)
+             for x in (o, do))
+
+    def ahead(b, i, step):
+        """While a tile's dk and dv are written, the NEXT tile's blocks
+        are held: fetched beside the work, not after it."""
+        nxt = jnp.minimum(b * tiles + i + jnp.minimum(step, 1),
+                          batch * tiles - 1)
+        return nxt // tiles, nxt % tiles
+
+    if packed:
+        of_q, of_kv, rows = lay.specs(pad_q, pad_k, ahead)
+        grid = (batch, tiles, lay.group, 3)
+        out_specs = pl.BlockSpec(
+            (None, pad_q, lay.lanes),
+            lambda b, i, g, step: (b, 0, step * tiles + i))
+        out_shape = jax.ShapeDtypeStruct(q.shape, q.dtype)
+    else:
+        of_q, of_kv, rows = lay.specs(pad_q, pad_k)
+        grid = (batch, tiles, lay.group)
+        out_specs = [of_q(), of_kv(), of_kv()]
+        out_shape = [jax.ShapeDtypeStruct(x.shape, x.dtype)
+                     for x in (q, k, v)]
+    grads = pl.pallas_call(
         functools.partial(_flash_bwd_kernel, scale=head_dim ** -0.5,
                           block=block or _BLOCK_K, causal=causal,
-                          seq_k=seq_k),
-        grid=(batch * num_kv, num_heads // num_kv),
-        in_specs=[q_spec, kv_spec, kv_spec, q_spec, row_spec, row_spec],
-        out_specs=[q_spec, kv_spec, kv_spec],
-        out_shape=[jax.ShapeDtypeStruct(x.shape, x.dtype)
-                   for x in (qh, kh, vh)],
-        scratch_shapes=[pltpu.VMEM(x.shape[-2:], jnp.float32)
-                        for x in (qh, kh, vh)],
-        compiler_params=_compiler_params("parallel", "arbitrary"),
+                          seq_k=seq_k, width=lay.width, group=lay.group),
+        grid=grid,
+        in_specs=[of_q(lay.first[0]), of_kv(lay.first[1]),
+                  of_kv(lay.first[2]), of_q(), of_q(), rows],
+        out_specs=out_specs, out_shape=out_shape,
+        scratch_shapes=[*(pltpu.VMEM((pad, lay.lanes), jnp.float32)
+                          for pad in (pad_q, pad_k, pad_k)),
+                        pltpu.VMEM((lay.per_tile, 8, pad_q), jnp.float32)],
+        compiler_params=_compiler_params(
+            "parallel", "parallel", *["arbitrary"] * (len(grid) - 2)),
         name="flash_attention_bwd_pallas", interpret=interpret,
-    )(qh, kh, vh, doh, lse, delta)
-    return (_from_heads(dq, batch, seq_q),
-            _from_heads(dk[:, None], batch, seq_k),
-            _from_heads(dv[:, None], batch, seq_k))
+    )(q, k, v, do, o, lse)
+    if packed:
+        grad = _from_lanes(grads, lay.width, (*shape[:2], 3, *shape[2:]))
+        return (grad.reshape(qkv[0].shape),)
+    return tuple(_from_lanes(g, lay.width, x.shape)
+                 for g, x in zip(grads, qkv))
 
 
 def flash_attention(q, k, v, causal: bool = True, block_q=None,
@@ -444,12 +697,16 @@ def flash_attention(q, k, v, causal: bool = True, block_q=None,
     if not _stays_resident(q.shape[1], k.shape[1], q.shape[-1], q.dtype,
                            causal):
         return blockwise_attention(q, k, v, causal=causal)
-    return _flash_kernels(q, k, v, causal, block_q, block_k, interpret)
+    return _flash_kernels((q, k, v), None, causal, block_q, block_k,
+                          interpret)
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6))
-def _flash_kernels(q, k, v, causal, block_q, block_k, interpret):
-    return _flash_fwd_rule(q, k, v, causal, block_q, block_k, interpret)[0]
+@functools.partial(jax.custom_vjp, nondiff_argnums=(1, 2, 3, 4, 5))
+def _flash_kernels(qkv, heads, causal, block_q, block_k, interpret):
+    """``_operands``'s ``qkv`` (a tuple of three, or of the packed one
+    and its ``heads``) -> o."""
+    return _flash_fwd_rule(qkv, heads, causal, block_q, block_k,
+                           interpret)[0]
 
 
 def _interpreted(interpret: Optional[bool]) -> bool:
@@ -464,16 +721,17 @@ def _interpreted(interpret: Optional[bool]) -> bool:
 FLASH_RESIDUALS = ("flash_attention.o", "flash_attention.lse")
 
 
-def _flash_fwd_rule(q, k, v, causal, block_q, block_k, interpret):
-    o, lse = _flash_forward(q, k, v, causal=causal, block=block_q,
+def _flash_fwd_rule(qkv, heads, causal, block_q, block_k, interpret):
+    o, lse = _flash_forward(*qkv, heads=heads, causal=causal, block=block_q,
                             interpret=_interpreted(interpret))
     o, lse = map(checkpoint_name, (o, lse), FLASH_RESIDUALS)
-    return o, (q, k, v, o, lse)
+    return o, (qkv, o, lse)
 
 
-def _flash_bwd_rule(causal, block_q, block_k, interpret, res, g):
-    return _flash_backward(*res, g, causal=causal, block=block_k,
-                           interpret=_interpreted(interpret))
+def _flash_bwd_rule(heads, causal, block_q, block_k, interpret, res, g):
+    return (_flash_backward(*res, g, heads=heads, causal=causal,
+                            block=block_k,
+                            interpret=_interpreted(interpret)),)
 
 
 _flash_kernels.defvjp(_flash_fwd_rule, _flash_bwd_rule)
@@ -481,8 +739,11 @@ _flash_kernels.defvjp(_flash_fwd_rule, _flash_bwd_rule)
 
 def kernels_tile(q: jax.Array, k: jax.Array) -> bool:
     """Where ``flash_attention`` is what to run, read from the arguments'
-    shapes: a head width the MXU contracts whole (64: half its depth,
-    128, 256), q heads in whole GQA groups, and at least 256 queries.
+    shapes: a head width the MXU contracts whole (64: half its depth, two
+    heads a lane tile; 128; 256), q heads in whole GQA groups, kv heads
+    that fill their lane tiles (an odd number of 64-wide heads would be
+    padded with one of zeros, a copy and a head's work for nothing: it
+    takes the reference), and at least 256 queries.
     The kernels take shorter sequences too (padded to 128, masked both
     ways), but there the reference's S x S arrays are small and XLA's
     fusions win: at 16,384 tokens of head_dim 64 on the v5e the
@@ -493,7 +754,7 @@ def kernels_tile(q: jax.Array, k: jax.Array) -> bool:
     VMEM (it takes the scan) are all inside."""
     (_, seq_q, heads, head_dim), num_kv = q.shape, k.shape[2]
     return (head_dim in (64, 128, 256) and heads % num_kv == 0
-            and seq_q >= 256)
+            and num_kv % _lane_tiles(head_dim)[0] == 0 and seq_q >= 256)
 
 
 def use_flash_on(mesh) -> Optional[bool]:
@@ -537,3 +798,29 @@ def attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
     return reference_attention(q, k, v, causal=causal,
                                positions_q=positions_q,
                                positions_k=positions_k)
+
+
+def packed_attention(qkv: jax.Array, num_heads: int, *, causal: bool = True,
+                     use_flash: Optional[bool] = None) -> jax.Array:
+    """``attention`` of a qkv projection's ONE product
+    [B, S, 3 * H * D] -> o [B, S, H * D], the heads side by side in the
+    lanes on both sides: the form in which XLA holds the two row-major,
+    as the kernels read and write them (an array [..., H, 64] it lays out
+    S-minor, because 64 lanes of a tile's 128 would be padding, and
+    re-lays for every Mosaic call). Where the dispatcher takes the kernels
+    they read q, k and v out of the product where it lies and give its
+    gradient whole, as the projection's two gradient matmuls take it:
+    nothing is cut out of the product or put together for it. Everywhere
+    else the product is cut and ``attention`` runs."""
+    batch, seq, lanes = qkv.shape
+    head_dim = lanes // (3 * num_heads)
+    shapes = [jax.ShapeDtypeStruct((batch, seq, num_heads, head_dim),
+                                   qkv.dtype)] * 2
+    if use_flash is None:
+        use_flash = on_chip() and kernels_tile(*shapes)
+    if use_flash and _stays_resident(seq, seq, head_dim, qkv.dtype, causal):
+        return _flash_kernels((qkv,), num_heads, causal, None, None, None)
+    q, k, v = (qkv.reshape(batch, seq, 3, num_heads, head_dim)[:, :, n]
+               for n in range(3))
+    return attention(q, k, v, causal=causal,
+                     use_flash=use_flash).reshape(batch, seq, -1)

@@ -10,7 +10,9 @@ for a described topology with no chip present).
 import dataclasses
 import importlib.util
 import json
+import math
 import os
+import re
 import subprocess
 import sys
 import time
@@ -817,16 +819,18 @@ def test_flash_forward_kernel_lowers_for_v5e(v5e, smoke_sizes):
             interpret=False))
 
 
-# gpt2-medium.train_1chip's attention, and a GQA one at head_dim 128
+# gpt2-medium.train_1chip's attention, a GQA one at head_dim 128, and
+# llama3_1b's: two q heads a lane tile whose kv head is in either slot
 @pytest.mark.parametrize("B,S,H,Hkv,D", [(16, 1024, 16, 16, 64),
-                                         (2, 2048, 32, 8, 128)])
+                                         (2, 2048, 32, 8, 128),
+                                         (2, 2048, 32, 8, 64)])
 def test_flash_backward_kernel_lowers_for_v5e(v5e, B, S, H, Hkv, D):
     from ray_tpu.ops.attention import _flash_backward
 
     q, kv = v5e(B, S, H, D), v5e(B, S, Hkv, D)
-    lse = v5e(B * Hkv, H // Hkv, 1, S, dtype=jnp.float32)
+    lse = v5e(B, H, 1, S, dtype=jnp.float32)
     assert _mosaic(_flash_backward.lower(
-        q, kv, kv, q, lse, q, causal=True, block=None, interpret=False))
+        (q, kv, kv), q, lse, q, causal=True, block=None, interpret=False))
 
 
 # LlamaConfig.max_seq_len's default, at bench_400m's and at GPT-2's width
@@ -913,6 +917,27 @@ def test_train_cell_attention_is_the_fused_kernels(v5e, placed, monkeypatch,
     assert len(calls) == 2
     H = model.cfg.n_heads
     assert f"{B},{H},{S},{S}" not in text and f"{B},{S},{H},{S}" not in text
+    # the kernels read and write the projections' own layout (PR 51):
+    # nothing is re-laid out under either call's scope, no operand has a
+    # 64-wide minor dimension (padded to 128 lanes in HBM), and the qkv
+    # product, kept or remade, reaches both calls without a copy
+    relayouts = [line for line in text.splitlines()
+                 if re.search(r"= \S+ (copy|transpose)\(", line)]
+    assert relayouts        # the pattern still finds the program's copies
+    assert not [line for line in relayouts
+                if re.search(r'op_name="[^"]*jit\(_flash_(for|back)ward\)',
+                             line)]
+    for call in calls:
+        operands = re.search(r"operand_layout_constraints=\{(.*?)\}, \w+=",
+                             call).group(1)
+        shapes = re.findall(r"\[([\d,]+)\]\{(\d+)", operands)
+        assert len(shapes) >= 3
+        for dims, minor in shapes:
+            assert int(dims.split(",")[int(minor)]) % 128 == 0, operands
+    product = B * S * 3 * model.cfg.dim
+    for line in relayouts:
+        dims = re.search(r"= \w+\[([\d,]*)\]", line).group(1)
+        assert math.prod(map(int, dims.split(",") if dims else [])) != product
 
 
 def test_train_attention_under_a_mesh_keeps_the_reference(v5e_topo,

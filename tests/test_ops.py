@@ -353,6 +353,17 @@ def _flash_grad_cases():
                 cases.append(pytest.param(
                     1, 200, 4, Hkv, D, causal, 128,
                     id=f"d{D}-{'causal' if causal else 'full'}-kv{Hkv}-s200"))
+    # two 64-wide heads a lane tile (PR 51): a GQA group of three, where
+    # a tile's two q heads have kv heads in different slots of one kv
+    # tile; a group of four over two kv tiles, where both q heads read
+    # the slot that is not theirs; and odd numbers of heads and kv heads,
+    # which ``flash_attention`` pads with heads of zeros to whole tiles
+    # (``kernels_tile`` refuses them: test_dispatcher_reads_the_shapes)
+    for H, Hkv, causal in ((6, 2, True), (16, 4, False), (3, 3, True),
+                           (6, 3, False)):
+        cases.append(pytest.param(
+            2, 200, H, Hkv, 64, causal, 128,
+            id=f"d64-{'causal' if causal else 'full'}-h{H}-kv{Hkv}-s200"))
     return cases
 
 
@@ -384,6 +395,49 @@ def _o_and_grads(attn, q, k, v, w):
         lambda q, k, v: (lambda o: ((o * w).sum(), o))(attn(q, k, v)),
         argnums=(0, 1, 2), has_aux=True)(q, k, v)
     return (o, *grads)
+
+
+@pytest.mark.parametrize("S,H,D,causal,use_flash", [
+    (200, 4, 64, True, True),       # two heads a tile, a ragged tail
+    (256, 3, 64, False, True),      # an odd head: padded to a whole tile
+    (128, 2, 128, True, True),      # a head a tile
+    (128, 2, 16, True, True),       # GPT2Config.debug's: eight a tile
+    (200, 4, 64, True, False),      # off the kernels: cut, the reference
+], ids=["d64-h4-s200", "d64-h3-full", "d128-h2", "d16-h2", "reference"])
+def test_packed_attention_matches_slices_through_the_reference(
+        S, H, D, causal, use_flash):
+    """``packed_attention`` on a qkv projection's ONE product
+    [B, S, 3 * H * D] (the kernels interpreted): o, and the ONE packed
+    gradient, against q, k and v cut out of it and taken through
+    ``reference_attention``."""
+    from ray_tpu.ops.attention import packed_attention
+
+    rng = np.random.default_rng(5)
+    B = 2
+    qkv = jnp.asarray(rng.normal(size=(B, S, 3 * H * D)), jnp.float32)
+    w = jnp.asarray(rng.normal(size=(B, S, H * D)), jnp.float32)
+
+    def cut(qkv):
+        return (qkv.reshape(B, S, 3, H, D)[:, :, n] for n in range(3))
+
+    def through(attn):
+        return jax.value_and_grad(
+            lambda x: (lambda o: ((o * w).sum(), o))(attn(x)),
+            has_aux=True)(qkv)
+
+    (_, o), grad = through(lambda x: packed_attention(
+        x, H, causal=causal, use_flash=use_flash))
+    (_, want), want_grad = through(lambda x: reference_attention(
+        *cut(x), causal=causal).reshape(B, S, H * D))
+    assert o.shape == (B, S, H * D) and grad.shape == qkv.shape
+    np.testing.assert_allclose(np.asarray(o), np.asarray(want),
+                               rtol=2e-4, atol=2e-5)
+    for name, a, b in zip("qkv", cut(grad), cut(want_grad)):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b),
+                                   rtol=2e-4, atol=2e-5, err_msg="d" + name)
+    calls = str(jax.make_jaxpr(lambda x: packed_attention(
+        x, H, causal=causal, use_flash=use_flash))(qkv)).count("pallas_call")
+    assert calls == (1 if use_flash else 0)
 
 
 @pytest.mark.parametrize("causal", [True, False], ids=["causal", "full"])
@@ -456,8 +510,8 @@ def test_flash_attention_past_residency_takes_the_scan(monkeypatch):
     (8192, 128, jnp.bfloat16, True, False),     # LlamaConfig.max_seq_len
     (2816, 64, jnp.bfloat16, False, True),
     (4096, 128, jnp.bfloat16, False, False),    # twice the causal tiles
-    (3328, 256, jnp.float32, True, True),       # 56 + 8 MiB: half of 128
-    (3456, 256, jnp.float32, True, False),
+    (2944, 256, jnp.float32, True, True),       # 55 + 8 MiB: half of 128
+    (3072, 256, jnp.float32, True, False),
 ])
 def test_flash_residency_by_shape(S, D, dtype, causal, resident):
     """What stays in VMEM, sized for the v5e where no chip is: every
@@ -474,6 +528,9 @@ def test_flash_residency_by_shape(S, D, dtype, causal, resident):
     (8192, 8, 8, 128, True, False),      # the scan inside flash_attention
     (1024, 16, 16, 80, False, False),    # a head width that does not tile
     (128, 8, 8, 128, False, False),      # short: the reference is faster
+    (2048, 32, 8, 64, True, True),       # llama3_1b: two heads a lane tile
+    (1024, 15, 15, 64, False, False),    # an odd head would be padded
+    (1024, 8, 1, 64, False, False),      # and so would a lone kv head
 ])
 def test_dispatcher_reads_the_shapes_on_a_tpu(monkeypatch, S, H, Hkv, D,
                                               tiles, kernels):
@@ -496,7 +553,8 @@ def test_dispatcher_reads_the_shapes_on_a_tpu(monkeypatch, S, H, Hkv, D,
 
     text = program()
     assert ("pallas_call" in text) is kernels
-    assert ("scan" in text) is (tiles and not kernels)
+    # (the forward kernel itself loops over a shared lane tile's heads)
+    assert ("scan" in text and not kernels) is (tiles and not kernels)
     for kw in (dict(use_flash=False),
                dict(positions_q=jnp.arange(S), positions_k=jnp.arange(S))):
         text = program(**kw)
