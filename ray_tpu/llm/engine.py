@@ -60,8 +60,11 @@ is the in-tree TPU-native equivalent (BASELINE.md config 5):
 - per-request TTFT / throughput stats (the reference's
   `release/llm_tests/serve/benchmark/load_test.py` metrics);
 - the loop accounts for itself: every part of ``step()`` runs inside one
-  of seven sibling PHASES (``_Phase``), each a span on the profiler's
-  clock and a seconds counter in ``stats`` (docs/serving.md).
+  of eight sibling PHASES (``_Phase``), each a span on the profiler's
+  clock and a seconds counter in ``stats``; and for the device, with no
+  profiler: where it meets the device it asks ``jax.Array.is_ready()``
+  who was ahead, and counts the seconds the device stood starved and
+  the steps whose pace the device set (docs/serving.md).
 """
 
 from __future__ import annotations
@@ -254,7 +257,7 @@ class _Phase:
     thread CPU time is kept out of ``stats["cpu_host_s"]``."""
 
     __slots__ = ("engine", "name", "key", "waits", "attrs", "span", "t0",
-                 "c0")
+                 "t1", "c0")
 
     def __init__(self, engine, name: str, key: str, waits: bool = False,
                  **attrs):
@@ -272,7 +275,8 @@ class _Phase:
 
     def __exit__(self, *exc):
         eng = self.engine
-        eng._stats[self.key] += time.perf_counter() - self.t0
+        self.t1 = time.perf_counter()
+        eng._stats[self.key] += self.t1 - self.t0
         if self.waits:
             eng._cpu_waiting += time.thread_time() - self.c0
         self.span.__exit__(*exc)
@@ -413,9 +417,16 @@ class ContinuousBatchingEngine:
         # (request, token) in order, ``None`` for a stream's end: what
         # ``_emit`` decided and the streams have not been handed yet
         self._undelivered: List[tuple] = []
-        # (tokens on the device, active slots) of the decode step that is
-        # dispatched and not read yet, between two ``step()``s
+        # (tokens on the device, active slots, dispatched a step ahead?)
+        # of the decode step that is dispatched and not read yet, between
+        # two ``step()``s
         self._in_flight: Optional[tuple] = None
+        # the host time at which this thread last KNEW the device's queue
+        # empty and has enqueued nothing since (None: not known to be);
+        # and the end of the last decode read-back if it waited for the
+        # device (``_enqueued``, ``_decode_step``)
+        self._device_dry_at: Optional[float] = None
+        self._read_waited_at: Optional[float] = None
         self._lock = threading.Lock()
         self._readers = _StreamReaders()
         self._rng_key = jax.random.key(0)
@@ -470,7 +481,9 @@ class ContinuousBatchingEngine:
         # meanings in docs/serving.md. ``t_*_s`` are the phases' wall
         # seconds (``_Phase``); ``t_step_s`` is all of ``step()`` from
         # before it takes the lock; ``cpu_host_s`` is this thread's CPU
-        # time in ``step()`` outside the phases that wait for the device.
+        # time in ``step()`` outside the phases that wait for the device;
+        # ``t_lock_wait_s`` is ``step()``'s wait for the lock (a float, no
+        # span); ``t_now_s`` is the clock as ``stats`` was last asked.
         sparse = model.sparse_decode_plan()
         self._index_topk = sparse["index_topk"]
         state_bytes = sum(math.prod(self.kv[name].shape)
@@ -522,7 +535,21 @@ class ContinuousBatchingEngine:
                       "t_prefill_s": 0.0, "t_host_arrays_s": 0.0,
                       "t_enqueue_s": 0.0, "t_readback_s": 0.0,
                       "t_emit_s": 0.0, "t_deliver_s": 0.0, "t_idle_s": 0.0,
-                      "cpu_host_s": 0.0,
+                      "t_lock_wait_s": 0.0, "cpu_host_s": 0.0, "t_now_s": 0.0,
+                      # the device's account, with no profiler
+                      # (``_enqueued``, ``_decode_step``): the seconds the
+                      # device's queue stood KNOWN to be empty before a
+                      # program was handed to it (a lower bound of the
+                      # device time lost to the host); decode read-backs
+                      # that found their tokens not ready, and of those
+                      # the steps that ran back to back with the step
+                      # before them on the device, with the seconds
+                      # between their read-backs' ends: the decode
+                      # program's time, seen from the host
+                      "t_device_starved_s": 0.0,
+                      "decode_steps_waited": 0,
+                      "decode_steps_device_paced": 0,
+                      "t_device_paced_s": 0.0,
                       # the stream path (docs/serving.md, "The stream
                       # path"): items put on the requests' streams
                       # (tokens and end markers; this thread, or under
@@ -619,6 +646,8 @@ class ContinuousBatchingEngine:
                 moe_assignments_held=int(
                     load[:, first:first + n_held].sum()),
                 moe_expert_load=load.tolist())
+        # last: the read above may have waited for a program in flight
+        self._stats["t_now_s"] = time.perf_counter()
         return self._stats
 
     def _check_eva_tiles(self) -> None:
@@ -822,10 +851,17 @@ class ContinuousBatchingEngine:
         all active slots. Returns number of active slots."""
         t0 = time.perf_counter()
         with self._lock:
+            # ``submit_prefilled`` and a dead loop's sweep hold the lock:
+            # what ``t_step_s`` holds before the first phase
+            self._stats["t_lock_wait_s"] += time.perf_counter() - t0
             c0 = time.thread_time()
             self._cpu_waiting = 0.0
             self._admit()
             active = self._decode_step()
+            if not self._admit_order and not self.waiting:
+                # nothing left to run: from here the device idles for
+                # want of requests (``t_idle_s``'s), not for the host
+                self._device_dry_at = None
             cpu = time.thread_time() - c0
             self._stats["cpu_host_s"] += cpu - self._cpu_waiting
             self._stats["engine_thread_cpu_s"] += cpu
@@ -1054,6 +1090,7 @@ class ContinuousBatchingEngine:
                 self._stats["prefill_tokens"] += len(seq)
             last_logits, small = self._prefill(
                 self.params, jnp.asarray(toks), jnp.asarray(lengths))
+            self._enqueued()
             self._scatter(small, [(alloc, 0, nb) for _, _, alloc in group],
                           nb, n_pad)
             self._set_state_rows(small, [slot for slot, _, _ in group], n_pad)
@@ -1115,6 +1152,7 @@ class ContinuousBatchingEngine:
                 self._stats["prefill_tokens"] += len(suffix)
             ids = self._block_ids(prefix, pb_pad, n_pad, gather=True)
             pk, pv = self._gather(self.kv, jnp.asarray(ids))
+            self._enqueued()
             last_logits, small = self._prefill_prefix(
                 self.params, jnp.asarray(toks), pk, pv,
                 jnp.asarray(plens), jnp.asarray(slens))
@@ -1154,6 +1192,7 @@ class ContinuousBatchingEngine:
         if self.eva is None:
             ids = self._block_ids([(alloc, 0, pb)], pb_pad, 1, gather=True)
             pk, pv = self._gather(self.kv, jnp.asarray(ids))
+            self._enqueued()
             if self.recurrent:
                 # the chunk starts from the state the chunk before left
                 last_logits, small = self._prefill_prefix(
@@ -1177,6 +1216,7 @@ class ContinuousBatchingEngine:
             pk, pv, sk, sv = self._gather(
                 self.kv, jnp.asarray([exact], np.int32),
                 jnp.asarray(summ[None]))
+            self._enqueued()
             last_logits, small = self._prefill_prefix(
                 self.params, jnp.asarray(toks), pk, pv, plen, slen,
                 jnp.asarray([first * bs], np.int32), (sk, sv))
@@ -1251,7 +1291,10 @@ class ContinuousBatchingEngine:
             top_ks[row] = req.sampling.top_k
         toks, self._rng_key = self._sample(
             logits, jnp.asarray(temps), jnp.asarray(top_ks), self._rng_key)
-        return np.asarray(toks)
+        toks = np.asarray(toks)
+        # the newest program is read: the device's queue is empty
+        self._device_dry_at = time.perf_counter()
+        return toks
 
     # -- decode ------------------------------------------------------------
     def _preempt(self, slot: int) -> None:
@@ -1321,18 +1364,40 @@ class ContinuousBatchingEngine:
             if self._in_flight is None:
                 self._deliver_deferred()
                 return 0
-        toks, active = self._in_flight
-        self._in_flight = (self._dispatch_decode(ahead=True)
-                           if self._may_run_ahead(active) else None)
+        toks, active, ahead = self._in_flight
+        if self._may_run_ahead(active):
+            if toks.is_ready():
+                # the step in flight is done and the one after it is
+                # not there yet: the device waits from here, at least
+                self._device_dry_at = time.perf_counter()
+            self._in_flight = self._dispatch_decode(ahead=True)
+        else:
+            self._in_flight = None
         # the step before's tokens reach their streams now, under the
         # program just dispatched: each put wakes a stream thread, and
         # their part of a token (~11 ms of Python for 32 streams) then
         # runs while this thread waits for the device, not while the
         # device waits for this thread to have the GIL back
         self._deliver_deferred()
+        # not ready: the host came first, the wait is the device's
+        waited = not toks.is_ready()
         with _Phase(self, "engine.sample_readback", "t_readback_s",
-                    waits=True):
+                    waits=True, waited=int(waited),
+                    step=self._stats["decode_steps"] + 1) as read:
             toks = np.asarray(toks)
+        if waited:
+            self._stats["decode_steps_waited"] += 1
+            if ahead and self._read_waited_at is not None:
+                # this step was queued behind the last one while that
+                # still ran, and the host stood waiting as each ended:
+                # between the two ends lies one program's device time
+                self._stats["decode_steps_device_paced"] += 1
+                self._stats["t_device_paced_s"] += (
+                    read.t1 - self._read_waited_at)
+        self._read_waited_at = read.t1 if waited else None
+        if self._in_flight is None:
+            # the newest program is read: the device's queue is empty
+            self._device_dry_at = read.t1
         with _Phase(self, "engine.emit", "t_emit_s"):
             self._stats["decode_steps"] += 1
             for i in active:
@@ -1380,9 +1445,9 @@ class ContinuousBatchingEngine:
 
     def _dispatch_decode(self, ahead: bool):
         """Enqueue one decode step, sampling included: ``(tokens on the
-        device, active slots)``, or None with no active slot. ``ahead``:
-        the step before is still in flight, so every active slot stands
-        one token further than the host's ``offsets`` say."""
+        device, active slots, ahead)``, or None with no active slot.
+        ``ahead``: the step before is still in flight, so every active
+        slot stands one token further than the host's ``offsets`` say."""
         with _Phase(self, "engine.schedule", "t_schedule_s"):
             if not ahead:
                 self._grow_or_preempt()
@@ -1420,6 +1485,7 @@ class ContinuousBatchingEngine:
             if load is not None:
                 self._ffn_counts = (
                     load, expected + len(active) * self._ffn_rows_per_slot)
+            self._enqueued()
         with _Phase(self, "engine.host_arrays", "t_host_arrays_s"):
             # the NEXT step's offsets go now, under the running program:
             # every active slot will have advanced by one, unless a slot
@@ -1463,7 +1529,17 @@ class ContinuousBatchingEngine:
                 first = np.maximum(pos - self.window + 1, 0) // bs
                 self._stats["decode_kv_blocks_live_window"] += int(
                     (pos // bs - first + 1).sum())
-        return self._dev_tokens, active
+        return self._dev_tokens, active, ahead
+
+    def _enqueued(self) -> None:
+        """A program has just been handed to the device (a decode step,
+        or the first program of a prefill group or chunk). If this
+        thread knew the device's queue empty, the device has stood
+        starved since at least then: booked, and the mark cleared."""
+        if self._device_dry_at is not None:
+            self._stats["t_device_starved_s"] += (
+                time.perf_counter() - self._device_dry_at)
+            self._device_dry_at = None
 
     def _emit(self, slot: int, tok: int) -> None:
         """Book one sampled token: the request's output, the stop test,

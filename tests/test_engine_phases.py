@@ -1,8 +1,10 @@
 """The engine loop accounts for itself: eight sibling phases, each a
 ``jax.profiler.TraceAnnotation`` span and a seconds counter in
 ``engine.stats`` (``llm/engine.py:_Phase``), and counts taken at the
-same boundaries. CPU, debug widths, no timing thresholds: what is
-checked is names, nesting, exact counts and that the sums close."""
+same boundaries; and for the device with no profiler: readiness probes
+where the host meets the device (``_decode_step``, ``_enqueued``). CPU,
+debug widths, no timing thresholds: what is checked is names, nesting,
+exact counts, exact seconds on a stubbed clock and that the sums close."""
 
 import glob
 import json
@@ -11,9 +13,11 @@ import re
 import threading
 
 import jax
+import numpy as np
 import pytest
 
 from ray_tpu.llm import ContinuousBatchingEngine, SamplingParams
+from ray_tpu.llm import engine as engine_module
 from ray_tpu.models.llama import LlamaConfig, LlamaModel
 
 PHASES = {"engine.schedule": "t_schedule_s", "engine.prefill": "t_prefill_s",
@@ -22,7 +26,12 @@ PHASES = {"engine.schedule": "t_schedule_s", "engine.prefill": "t_prefill_s",
           "engine.sample_readback": "t_readback_s",
           "engine.emit": "t_emit_s", "engine.deliver": "t_deliver_s",
           "engine.idle": "t_idle_s"}
-IN_STEP = [k for name, k in PHASES.items() if name != "engine.idle"]
+# what tiles ``step()``: its phases and, before the first, the lock's wait
+IN_STEP = [k for name, k in PHASES.items() if name != "engine.idle"] + [
+    "t_lock_wait_s"]
+# the device's account (docs/serving.md, "Is my chip waiting for my host?")
+DEVICE_KEYS = {"t_device_starved_s", "decode_steps_waited",
+               "decode_steps_device_paced", "t_device_paced_s"}
 
 
 @pytest.fixture(scope="module")
@@ -119,8 +128,20 @@ def test_spans_are_the_eight_phases_siblings_on_one_thread(tiny_model,
     # tests/test_stream_phases.py)
     assert all(set(a) == {"step"} for n, a, _, _ in driven
                if n == "engine.deliver")
+    # the read-back says what its readiness probe found (0 / 1) and which
+    # decode step it reads: a step is read and delivered under ONE number
+    reads = [a for n, a, _, _ in driven if n == "engine.sample_readback"]
+    assert all(set(a) == {"waited", "step"} and a["waited"] in (0, 1)
+               for a in reads)
+    first = before["decode_steps"] + 1
+    assert [a["step"] for a in reads] == list(range(first, first + steps))
+    assert [a["step"] for n, a, _, _ in driven if n == "engine.deliver"] \
+        == [a["step"] for a in reads]
+    assert eng.stats["decode_steps_waited"] - before["decode_steps_waited"] \
+        == sum(a["waited"] for a in reads)
     assert all(not a for n, a, _, _ in driven
-               if n not in ("engine.prefill", "engine.deliver"))
+               if n not in ("engine.prefill", "engine.deliver",
+                            "engine.sample_readback"))
 
 
 def test_streams_get_every_token_in_order_then_their_end(tiny_model):
@@ -289,9 +310,12 @@ def test_stats_keys_are_fixed_plain_monotone_and_the_phases_sum_to_the_step():
     try:
         eng = server.engine
         first = server.stats()
-        assert set(PHASES.values()) | {
-            "t_step_s", "cpu_host_s", "admitted", "queue_wait_s",
-            "prefill_tokens", "prefill_padded_tokens"} <= set(first)
+        assert set(PHASES.values()) | DEVICE_KEYS | {
+            "t_step_s", "t_lock_wait_s", "t_now_s", "cpu_host_s", "admitted",
+            "queue_wait_s", "prefill_tokens", "prefill_padded_tokens"} <= set(first)
+        # nothing ran yet: the device's account is empty, the clock is not
+        assert all(first[k] == 0 for k in DEVICE_KEYS)
+        assert first["t_now_s"] > 0.0
         # numbers, but for an expert model's per-expert rows (a list,
         # empty for this dense one: tests/test_olmoe_serving.py) and the
         # names of what implements its grouped matmuls and of its router
@@ -332,6 +356,17 @@ def test_stats_keys_are_fixed_plain_monotone_and_the_phases_sum_to_the_step():
     assert json.loads(json.dumps(last)) == last
     assert all(last[k] > 0.0 for k in PHASES.values())
     assert 0.0 < sum(last[k] for k in IN_STEP) <= last["t_step_s"]
+    # two reads of ``stats`` carry their own clock: what an operator
+    # divides by ("Is my chip waiting for my host?", docs/serving.md)
+    assert snaps[-1]["t_now_s"] < last["t_now_s"]
+    # one request at a time on two slots: no step ran ahead, so every
+    # decode dispatch found the queue it had just read empty, none was
+    # paced by the device, and the starved seconds lie inside the steps
+    steps = last["decode_steps"]
+    assert steps == 3 * 4 and last["decode_steps_device_paced"] == 0
+    assert last["t_device_paced_s"] == 0.0
+    assert 0.0 < last["t_device_starved_s"] < last["t_step_s"]
+    assert last["decode_steps_waited"] <= steps
     assert 0.0 < last["cpu_host_s"] <= last["t_step_s"]
 
 
@@ -363,6 +398,162 @@ def test_the_real_profiler_records_the_phases_on_its_host_plane(tiny_model,
     assert all(ev.duration_ns > 0 for evs in seen.values() for ev in evs)
     prefill, = seen["engine.prefill"]
     assert dict(prefill.stats) == {"bucket": 16, "n": 1, "n_pad": 1}
+    # what the read-back's readiness probe found rides on its span: the
+    # steps with ``waited`` 0 are where to look for the host in a trace
+    # (docs/serving.md, "Is my chip waiting for my host?")
+    reads = [dict(ev.stats) for ev in seen["engine.sample_readback"]]
+    assert sorted(r["step"] for r in reads) == [2, 3]
+    assert all(set(r) == {"waited", "step"} and r["waited"] in (0, 1)
+               for r in reads)
+    assert not any(dict(ev.stats) for ev in seen["engine.decode_enqueue"])
+
+
+class StubbedDevice:
+    """An engine whose clock moves only where this says, and whose
+    decode tokens answer ``is_ready()`` as told. Seconds: 1 to take the
+    lock, 3 in ``_may_run_ahead`` (in no phase: BEFORE the probe), 5 in
+    the decode program's dispatch, 2 in a delivery (AFTER the enqueue),
+    7 in a decode step's blocking read. Prefills run as they are and
+    take no time."""
+
+    LOCK_S, AHEAD_S, ENQUEUE_S, DELIVER_S, READ_S = 1.0, 3.0, 5.0, 2.0, 7.0
+
+    def __init__(self, eng, monkeypatch, ready: bool):
+        import time
+        import types
+
+        self.now, self.read_ends, self.ahead_calls = 1000.0, [], 0
+        stub = self
+        monkeypatch.setattr(engine_module, "time", types.SimpleNamespace(
+            perf_counter=lambda: stub.now, thread_time=time.thread_time,
+            sleep=time.sleep))
+
+        class Tokens:
+            def __init__(self, arr):
+                self.arr = arr
+
+            def is_ready(self):
+                return ready
+
+            def __array__(self, dtype=None, copy=None):
+                stub.now += stub.READ_S
+                stub.read_ends.append(stub.now)
+                return np.asarray(self.arr)
+
+        class Lock:
+            def __enter__(self):
+                stub.now += stub.LOCK_S
+
+            def __exit__(self, *exc):
+                return False
+
+        decode = eng._decode
+
+        def dispatch(params, tokens, *rest):
+            out = decode(params, getattr(tokens, "arr", tokens), *rest)
+            stub.now += stub.ENQUEUE_S
+            return (Tokens(out[0]), *out[1:])
+
+        eng._decode = dispatch
+        eng._lock = Lock()
+        may_run_ahead, deliver = eng._may_run_ahead, eng._deliver
+
+        def slow_may_run_ahead(active):
+            stub.now += stub.AHEAD_S
+            stub.ahead_calls += 1
+            return may_run_ahead(active)
+
+        def slow_deliver():
+            stub.now += stub.DELIVER_S
+            deliver()
+
+        eng._may_run_ahead, eng._deliver = slow_may_run_ahead, slow_deliver
+
+
+def run_one_slot(tiny_model, monkeypatch, ready):
+    """One request on an engine of one slot, so every step after the
+    first is dispatched ahead (``test_a_step_runs_ahead_only_where...``):
+    the stats after the first ``step()`` and at the end."""
+    eng = make_engine(tiny_model, max_slots=1)
+    dev = StubbedDevice(eng, monkeypatch, ready)
+    req = eng.submit(distinct(9, 0), SamplingParams(max_tokens=12))
+    eng.step()                  # the prefill, step 1 read, step 2 in flight
+    first = dict(eng.stats)
+    while eng.has_work():
+        eng.step()
+    assert len(req.output) == 12 and eng.stats["decode_steps"] == 11
+    return dev, first, dict(eng.stats)
+
+
+def test_a_device_that_is_never_ready_sets_the_pace_and_is_never_starved(
+        tiny_model, monkeypatch):
+    dev, first, last = run_one_slot(tiny_model, monkeypatch, ready=False)
+    # the first dispatch came after the prefill's blocking read: starved,
+    # while the host delivered the first token and enqueued; its
+    # read-back waited, and there was no read-back before it to measure
+    # a program from
+    after_prefill = dev.DELIVER_S + dev.ENQUEUE_S
+    assert first["t_device_starved_s"] == after_prefill
+    assert first["decode_steps_waited"] == 1
+    assert first["decode_steps_device_paced"] == 0
+    # from then on a step ahead stands on the device whenever the host
+    # looks: never starved again, and every step waited for and paced
+    assert last["t_device_starved_s"] == after_prefill
+    assert last["decode_steps_waited"] == 11
+    assert last["decode_steps_device_paced"] == 10
+    assert len(dev.read_ends) == 11
+    assert last["t_device_paced_s"] == dev.read_ends[-1] - dev.read_ends[0]
+    # the seven phases inside ``step()`` and the lock's wait tile it:
+    # what is left is what the stub spent outside them
+    assert last["t_lock_wait_s"] == 11 * dev.LOCK_S
+    assert last["t_step_s"] - sum(last[k] for k in IN_STEP) \
+        == pytest.approx(dev.ahead_calls * dev.AHEAD_S)
+
+
+def test_a_device_that_is_always_ready_is_starved_from_probe_to_enqueue(
+        tiny_model, monkeypatch):
+    dev, first, last = run_one_slot(tiny_model, monkeypatch, ready=True)
+    # eleven decode dispatches, each on a device known to be done: from
+    # the probe (or the prefill's read) to the enqueue's end and no
+    # further. The host's seconds before the probe (``_may_run_ahead``)
+    # and after the enqueue (the delivery, the read) are not the
+    # device's account: it MAY have been busy then
+    after_prefill = dev.DELIVER_S + dev.ENQUEUE_S
+    assert first["t_device_starved_s"] == after_prefill + dev.ENQUEUE_S
+    assert last["t_device_starved_s"] == after_prefill + 10 * dev.ENQUEUE_S
+    assert last["decode_steps_waited"] == 0
+    assert last["decode_steps_device_paced"] == 0
+    assert last["t_device_paced_s"] == 0.0
+
+
+def test_an_engine_without_work_books_no_starved_device(tiny_model,
+                                                        monkeypatch):
+    eng = make_engine(tiny_model)               # four slots: no step ahead
+    stop = threading.Event()
+    loop = threading.Thread(target=eng.run_forever, args=(stop, 0.001))
+    loop.start()                         # nothing to do: the loop idles
+    while eng.stats["t_idle_s"] == 0.0 and loop.is_alive():
+        stop.wait(0.001)
+    stop.set()
+    loop.join(30)
+    assert not loop.is_alive()
+    assert all(eng.stats[k] == 0 for k in DEVICE_KEYS)
+    # nor is the time between two requests the host's: the mark goes
+    # with the last request, and the next one's prefill finds none
+    dev = StubbedDevice(eng, monkeypatch, ready=False)
+    for k in range(2):
+        eng.generate([distinct(9, 70 * k)], SamplingParams(max_tokens=4))
+        dev.now += 100.0                        # nobody asks for anything
+        assert eng.step() == 0
+    assert eng.stats["decode_steps"] == 6
+    # each decode dispatch came after a blocking read of the newest
+    # program: starved from that read's end to the enqueue's, which is a
+    # delivery and an enqueue after a prefill's first token, the next
+    # ``step()``'s lock and an enqueue after a step's
+    assert eng.stats["t_device_starved_s"] == 2 * (
+        dev.DELIVER_S + dev.ENQUEUE_S + 2 * (dev.LOCK_S + dev.ENQUEUE_S))
+    assert eng.stats["decode_steps_waited"] == 6
+    assert eng.stats["decode_steps_device_paced"] == 0
 
 
 def test_program_names_the_benchmark_readers_match_are_pinned(tiny_model):
