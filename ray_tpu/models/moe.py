@@ -17,11 +17,12 @@ capacity are dropped), as one-hot einsums or as an explicit all-to-all
 (``moe_dispatch``); the mesh decides, not a flag.
 
 The three expert matmuls are ``ops.moe_dispatch.grouped_matmul``: on a
-TPU backend, at widths XLA's ``ragged_dot`` tiles narrower than 512 x
-512, jax's Pallas grouped matmul at a tiling chosen from the call's
-shapes (rows, k, n); ``jax.lax.ragged_dot`` elsewhere and where no
-tiling is legal; ``grouped_matmul_plan`` says which for an engine's
-``stats`` (``moe_grouped_impl``). Training runs the same forward, with
+TPU backend the Pallas grouped matmul at a tiling chosen from the
+call's shapes (rows, k, n), at every expert width that has one, the
+three walking the groups by ONE table computed a layer;
+``jax.lax.ragged_dot`` off the chip and where no tiling is legal;
+``grouped_matmul_plan`` says which for an engine's ``stats``
+(``moe_grouped_impl``). Training runs the same forward, with
 ``ragged_dot``'s backward.
 
 The serving programs hold the expert stacks WHOLE: their layer scans

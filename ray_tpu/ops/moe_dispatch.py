@@ -16,15 +16,28 @@
   (``grouped_matmul_impl``, by the platform and the call's shapes, the
   way ``ops.paged_attention.default_impl`` resolves decode attention: no
   flag, no environment variable, no configuration key). On a TPU backend,
-  at widths where XLA's heuristic for ``ragged_dot`` falls to small
-  weight tiles (Mellum2's 2304 x 896: 256 x 128, 63 grid steps a group,
-  three times OLMoE's time a byte: PERF.md, PR 40), jax's Pallas grouped
-  matmul (``megablox.gmm``: bf16 operands, float32 accumulator) at the
-  tiling ``gmm_tiling(m, k, n, itemsize)`` gives, and where an expert is
-  more than eight of XLA's 512 x 512 tiles (3584 x 1024, 7168 x 2048);
-  everywhere else, for a small expert XLA tiles 512 x 512 (OLMoE's 2048
-  x 1024: ``xla_tiles_wide``) and for a shape with no legal tiling,
-  ``jax.lax.ragged_dot`` (the kernel's reference in the tests).
+  wherever ``gmm_tiling(m, k, n, itemsize)`` has a tiling, the Pallas
+  grouped matmul (``gmm``: bf16 operands, float32 accumulator) at that
+  tiling, whatever XLA's own heuristic for ``ragged_dot`` would have
+  tiled (256 x 128 at Mellum2's 2304 x 896, 63 grid steps a group:
+  PERF.md, PR 40; 512 x 512 at OLMoE's 2048 x 1024, where the kernel's
+  one tile an expert still reads a third faster a call: PR 53); off the
+  chip and for a shape with no legal tiling, ``jax.lax.ragged_dot`` (the
+  kernel's reference in the tests). The kernel is megablox's
+  (``jax.experimental.pallas.ops.tpu``), kept in this module since PR 53
+  for what a call costs a process's SET-UP, which no compile cache
+  saves: a program that holds it traces and lowers it on the host first,
+  and the chip's host runs that Python six times slower than a desk's.
+  Here the walk over the groups (``group_tiles``) is a dozen ``jax.lax``
+  primitives computed ONCE a layer (megablox computes it inside every
+  call, out of ``jax.numpy``'s ``repeat``, ``histogram``,
+  ``searchsorted`` and ``roll``, each an inner ``jit`` traced and
+  lowered apart); ``gmm`` is one ``jit`` of the module, traced once a
+  (rows, k, n, tiling) a process (gate and up are one trace); and where
+  a k tile is the whole of k the kernel body holds no accumulator and no
+  ``cond``. Results are megablox's bit for bit. PERF.md (PR 53) counts
+  the programs and their seconds; tests/test_moe_grouped_matmul.py holds
+  both halves.
   TRAINING on a TPU runs the same kernel forward; its backward is
   ``ragged_dot``'s transposes (``pallas_grouped_matmul``'s
   ``custom_vjp``), which no cell measures.
@@ -48,6 +61,7 @@ from typing import Optional, Tuple
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 from ray_tpu._private.platform import on_chip, pallas_interpret
 
@@ -152,7 +166,7 @@ def gmm_tiling(m: int, k: int, n: int, itemsize: int = 2
       a step. Small tiles are what made these calls slow: a fixed cost a
       grid step, 63 steps a group at 256 x 128.
     - tm: 128 rows, 256 from 2,048 rows on, and the largest power of two
-      under that which divides m (megablox requires it; 16 at least, a
+      under that which divides m (the kernel requires it; 16 at least, a
       bf16 tile's sublanes). A step's multiply takes as long as its
       weights' fetch at ~240 rows (197 TFLOP/s over 819 GB/s, bf16), so
       256 is the largest row tile that is not compute-bound. The
@@ -161,9 +175,7 @@ def gmm_tiling(m: int, k: int, n: int, itemsize: int = 2
       1.6 x slower there; at 12,288 rows, which no cell runs, 128 read
       12 % ahead again at 2304 x 896.
     """
-    target = 256 if m >= 2048 else 128
-    tm = next((t for t in (256, 128, 64, 32, 16)
-               if t <= target and m % t == 0), None)
+    tm = gmm_row_tile(m)
     if tm is None:
         return None
     fits = [(tk, tn) for tk in lane_divisors(k) for tn in lane_divisors(n)
@@ -174,44 +186,23 @@ def gmm_tiling(m: int, k: int, n: int, itemsize: int = 2
     return tm, tk, tn
 
 
-# An expert of at most this many of XLA's widest (512 x 512) tiles keeps
-# ``ragged_dot``: ``grouped_matmul_impl`` says what was measured either side
-XLA_WIDE_TILES = 8
-
-
-def xla_tiles_wide(k: int, n: int) -> bool:
-    """Does XLA's own heuristic for ``ragged_dot`` reach its widest (k,
-    n) tile, 512 x 512? It tiles a width by its largest power-of-two
-    factor up to 512 (the v5e compiler prints ``ragged_dot_tiling=
-    "256,512,512"`` at 2048 x 1024, ``"128,512,512"`` at 3584 x 1024 and
-    7168 x 2048, and ``"256,256,128"`` at 2304 x 896)."""
-    return k % 512 == 0 and n % 512 == 0
-
-
 def grouped_matmul_impl(m: int, k: int, n: int, itemsize: int
                         ) -> Tuple[str, Optional[Tuple[int, int, int]]]:
     """``("pallas_gmm", tiling)`` or ``("ragged_dot", None)`` for ``lhs
     [m, k] @ rhs [G, k, n]``: the one place that decides, by the
     platform and the shapes (as ``ops.paged_attention.default_impl``
-    does for decode attention). The Pallas kernel on a TPU backend where
-    ``gmm_tiling`` has a tiling and XLA's own falls short of 512 x 512
-    or an expert is more than ``XLA_WIDE_TILES`` such tiles;
-    ``jax.lax.ragged_dot`` everywhere else.
+    does for decode attention). The Pallas kernel on a TPU backend
+    wherever ``gmm_tiling`` has a tiling; ``jax.lax.ragged_dot`` off the
+    chip and where none is legal.
 
-    Why a small expert that XLA tiles well keeps ``ragged_dot`` (PERF.md,
-    PR 40): at 2048 x 1024 (8 tiles) the kernel reads a third faster a
-    call (0.37 for 0.56 ms at 256 rows) where at 2304 x 896 it reads 4.4
-    times faster (0.39 for 1.72), and every program that holds it pays
-    ~0.1 s of Mosaic lowering before the compile cache is asked, ~2 s of
-    a process's set-up: a fifth of the set-up of the one cell with such
-    widths, whose step the host sets, so that the kernel gave it nothing
-    back (its clients received 7 % FEWER tokens a second then). A larger
-    expert pays the same lowering once and saves more a step: at 3584 x
-    1024 (14 tiles, 64 experts, four layers) the kernel's two tiles an
-    expert took 1.3 ms off a 13.0 ms decode program (PERF.md, PR 50).
+    No width keeps ``ragged_dot`` for XLA's tiles' sake (PERF.md, PR 53):
+    at 2048 x 1024, which XLA tiles 512 x 512, the kernel's one tile an
+    expert reads a third faster a call (0.37 for 0.56 ms at 256 rows),
+    4.4 times at 2304 x 896 (0.39 for 1.72). What the kernel costs is
+    set-up, tracing and Mosaic lowering on the host in every program
+    that holds it: the module docstring says how that is kept small.
     """
-    if not on_chip() or (xla_tiles_wide(k, n)
-                         and (k // 512) * (n // 512) <= XLA_WIDE_TILES):
+    if not on_chip():
         return "ragged_dot", None
     tiling = gmm_tiling(m, k, n, itemsize)
     if tiling is None:
@@ -223,19 +214,160 @@ def grouped_matmul_impl(m: int, k: int, n: int, itemsize: int
     return "pallas_gmm", tiling
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5))
-def pallas_grouped_matmul(lhs, rhs, group_sizes, dtype, tiling,
+def gmm_row_tile(m: int) -> Optional[int]:
+    """The kernel's row tile for ``m`` rows, which ``gmm_tiling`` takes
+    from the rows alone: a layer's gate, up and down share it, and with
+    it ONE walk over the groups (``group_tiles``)."""
+    target = 256 if m >= 2048 else 128
+    return next((t for t in (256, 128, 64, 32, 16)
+                 if t <= target and m % t == 0), None)
+
+
+def group_tiles(group_sizes, m: int, tm: int):
+    """What the kernel's grid walks, from the groups' sizes alone:
+    ``(offsets [G+1], group_ids [L], m_tile_ids [L], num_tiles)``. The
+    visits are the (group, row tile) pairs that hold a row, by group,
+    then by tile: a group of no rows has none, a group that crosses a
+    tile's edge one a tile. ``L = m // tm + G - 1`` bounds their number
+    (a tile is visited once, and once more for every further group that
+    begins inside it); only the first ``num_tiles`` entries are walked.
+    ``offsets[g]`` is group ``g``'s first row.
+
+    A dozen integer primitives on ``[G]`` and ``[L, G]``, written in
+    ``jax.lax`` (every ``jax.numpy`` call and operator is an inner
+    ``jit`` a program traces, ``repeat``, ``histogram``, ``searchsorted``
+    and ``roll`` ones it lowers apart as well: on the chip's host that
+    was most of what a program that holds the kernel paid to start), and
+    ONE call a layer: gate, up and down walk the same rows."""
+    lax = jax.lax
+    G = group_sizes.shape[0]
+    tiles_m = m // tm
+    L = tiles_m + G - 1
+    sizes = group_sizes.astype(jnp.int32)
+    ends = lax.cumsum(sizes)
+    first = lax.div(lax.sub(ends, sizes), np.int32(tm))   # first row tile
+    tiles = lax.select(
+        lax.gt(sizes, np.int32(0)),
+        lax.add(lax.sub(lax.div(lax.sub(ends, np.int32(1)), np.int32(tm)),
+                        first), np.int32(1)),
+        lax.full_like(sizes, 0))
+    tile_ends = lax.cumsum(tiles)
+    # a visit's group: how many groups' visits end at or before it
+    passed = lax.le(lax.broadcast_in_dim(tile_ends, (L, G), (1,)),
+                    lax.broadcasted_iota(jnp.int32, (L, G), 0))
+    group_ids = lax.min(
+        lax.reduce_sum(passed.astype(jnp.int32), (1,)), np.int32(G - 1))
+    # its row tile: the group's first, and on by the visits since then
+    start = lax.sub(first, lax.sub(tile_ends, tiles))
+    m_tile_ids = lax.min(
+        lax.add(start.at[group_ids].get(mode="promise_in_bounds"),
+                lax.iota(jnp.int32, L)), np.int32(tiles_m - 1))
+    offsets = lax.pad(ends, np.int32(0), ((1, 0, 0),))
+    return offsets, group_ids, m_tile_ids, tile_ends[-1]
+
+
+def _gmm_kernel(offsets, group_ids, m_tile_ids, lhs, rhs, out, *acc,
+                tiles_k: int, dtype):
+    """One grid step (n tile, visit, k tile): the row tile times the
+    visit's group's (tk, tn) weight tile, summed over k in ``acc``
+    (float32); at the last k tile the rows that ARE the group's are
+    stored, the tile's other rows left as the visits of their own groups
+    wrote them. Where a k tile is the whole of k (``tiles_k`` 1: no
+    ``acc``) the product is the sum."""
+    import jax.experimental.pallas as pl
+
+    visit, k_i = pl.program_id(1), pl.program_id(2)
+    product = jax.lax.dot_general(
+        lhs[...], rhs[...], (((1,), (0,)), ((), ())),
+        preferred_element_type=jnp.float32)
+
+    def store(total):
+        g = group_ids[visit]
+        tm, tn = out.shape
+        lax = jax.lax
+        row = lax.add(lax.broadcasted_iota(jnp.int32, (tm, tn), 0),
+                      lax.mul(m_tile_ids[visit], np.int32(tm)))
+        mine = lax.bitwise_and(lax.ge(row, offsets[g]),
+                               lax.lt(row, offsets[lax.add(g, np.int32(1))]))
+        out[...] = lax.convert_element_type(lax.select(
+            mine, total, lax.convert_element_type(out[...], jnp.float32)), dtype)
+
+    if tiles_k == 1:
+        return store(product)
+    acc, = acc
+
+    @pl.when(k_i == 0)
+    def _():
+        acc[...] = jnp.zeros_like(acc)
+
+    acc[...] += product
+
+    @pl.when(k_i == tiles_k - 1)
+    def _():
+        store(acc[...])
+
+
+@functools.partial(jax.jit, static_argnames=("dtype", "tiling", "interpret"))
+def gmm(lhs, rhs, offsets, group_ids, m_tile_ids, num_tiles, *, dtype,
+        tiling, interpret: bool = False):
+    """The Pallas call: a grid of (n tiles, visits, k tiles), the three
+    arrays of ``group_tiles`` prefetched as scalars, which the block
+    index maps read: a visit's row tile of ``lhs`` and ``out``, its
+    group's weight tile of ``rhs``. A ``jit`` of this module, so one
+    process traces it once a (rows, k, n, tiling, dtype) however many
+    call sites and programs hold it (gate and up are one trace)."""
+    import jax.experimental.pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    (m, k), n = lhs.shape, rhs.shape[2]
+    tm, tk, tn = tiling
+    if m % tm or k % tk or n % tn:
+        raise ValueError(f"tiling {tiling} does not divide {(m, k, n)}")
+    tiles_k, tiles_n = k // tk, n // tn
+    itemsize = jnp.dtype(lhs.dtype).itemsize
+    return pl.pallas_call(
+        functools.partial(_gmm_kernel, tiles_k=tiles_k, dtype=dtype),
+        out_shape=jax.ShapeDtypeStruct((m, n), dtype),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=3,
+            in_specs=[
+                pl.BlockSpec((tm, tk), lambda n_i, v, k_i, off, gid, mt:
+                             (mt[v], k_i)),
+                pl.BlockSpec((None, tk, tn),
+                             lambda n_i, v, k_i, off, gid, mt:
+                             (gid[v], k_i, n_i))],
+            out_specs=pl.BlockSpec(
+                (tm, tn), lambda n_i, v, k_i, off, gid, mt: (mt[v], n_i)),
+            grid=(tiles_n, num_tiles, tiles_k),
+            scratch_shapes=[pltpu.VMEM((tm, tn), jnp.float32)] * (
+                tiles_k > 1)),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary", "arbitrary")),
+        cost_estimate=pl.CostEstimate(
+            flops=2 * m * k * n, transcendentals=0,
+            bytes_accessed=(m * k * itemsize * tiles_n
+                            + k * n * itemsize * group_ids.size
+                            + m * n * jnp.dtype(dtype).itemsize)),
+        interpret=interpret,
+    )(offsets, group_ids, m_tile_ids, lhs, rhs)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6))
+def pallas_grouped_matmul(lhs, rhs, group_sizes, tiles, dtype, tiling,
                           interpret: bool = False):
-    """jax's Pallas grouped matmul (``megablox.gmm``: bf16 operands,
-    float32 accumulator, a grid of (n tiles, non-empty (group, row tile)
-    pairs, k tiles)) at ``tiling``. Its gradient is ``ragged_dot``'s
+    """The Pallas grouped matmul at ``tiling`` (bf16 operands, float32
+    accumulator, a grid of (n tiles, non-empty (group, row tile) pairs,
+    k tiles): megablox's kernel, ``jax.experimental.pallas.ops.tpu``,
+    kept here since PR 53 with the groups' walk computed apart, once a
+    layer: ``tiles`` is ``group_tiles(group_sizes, m, tiling[0])``;
+    results are megablox's bit for bit). Its gradient is ``ragged_dot``'s
     (XLA's transposes): no training cell measures a tiled backward."""
-    from jax.experimental.pallas.ops.tpu.megablox.gmm import gmm
-    return gmm(lhs, rhs, group_sizes, dtype, tiling, interpret=interpret)
+    return gmm(lhs, rhs, *tiles, dtype=dtype, tiling=tiling,
+               interpret=interpret)
 
 
-def _pallas_gmm_fwd(lhs, rhs, group_sizes, dtype, tiling, interpret):
-    out = pallas_grouped_matmul(lhs, rhs, group_sizes, dtype, tiling,
+def _pallas_gmm_fwd(lhs, rhs, group_sizes, tiles, dtype, tiling, interpret):
+    out = pallas_grouped_matmul(lhs, rhs, group_sizes, tiles, dtype, tiling,
                                 interpret)
     return out, (lhs, rhs, group_sizes)
 
@@ -244,22 +376,28 @@ def _pallas_gmm_bwd(dtype, tiling, interpret, residuals, grad):
     lhs, rhs, group_sizes = residuals
     _, vjp = jax.vjp(lambda l, r: jax.lax.ragged_dot(
         l, r, group_sizes, preferred_element_type=dtype), lhs, rhs)
-    return (*vjp(grad), None)
+    return (*vjp(grad), None, None)
 
 
 pallas_grouped_matmul.defvjp(_pallas_gmm_fwd, _pallas_gmm_bwd)
 
 
-def grouped_matmul(lhs, rhs, group_sizes, dtype):
+def grouped_matmul(lhs, rhs, group_sizes, dtype, tiles=None):
     """``lhs [m, k]``'s rows, sorted by group, each times its group's
     ``rhs [G, k, n]``: ``[m, n]`` in ``dtype``, float32 sums. Groups of
-    no rows are neither visited nor fetched."""
+    no rows are neither visited nor fetched. ``tiles``: the kernel's
+    walk over the groups, ``group_tiles(group_sizes, m,
+    gmm_row_tile(m))``, from a caller whose calls share it (a layer's
+    gate, up and down); computed here for a call that stands alone."""
+    m = lhs.shape[0]
     impl, tiling = grouped_matmul_impl(
-        lhs.shape[0], rhs.shape[1], rhs.shape[2], jnp.dtype(dtype).itemsize)
+        m, rhs.shape[1], rhs.shape[2], jnp.dtype(dtype).itemsize)
     if impl == "ragged_dot":
         return jax.lax.ragged_dot(lhs, rhs, group_sizes,
                                   preferred_element_type=dtype)
-    return pallas_grouped_matmul(lhs, rhs, group_sizes, dtype, tiling,
+    if tiles is None:
+        tiles = group_tiles(group_sizes, m, tiling[0])
+    return pallas_grouped_matmul(lhs, rhs, group_sizes, tiles, dtype, tiling,
                                  pallas_interpret())
 
 
@@ -335,8 +473,12 @@ def dropless_expert_ffn(x, router, e_gate, e_up, e_down, *, top_k: int,
                 jnp.zeros((e_up.shape[0],), jnp.int32), sizes,
                 (first_expert,))
     with jax.named_scope("moe_experts"):
+        # the kernel's walk over the groups: one for the three calls
+        tm = gmm_row_tile(T * top_k) if on_chip() else None
+        tiles = None if tm is None else group_tiles(sizes, T * top_k, tm)
+
         def grouped(lhs, w):
-            return grouped_matmul(lhs, w.astype(dtype), sizes, dtype)
+            return grouped_matmul(lhs, w.astype(dtype), sizes, dtype, tiles)
         if e_gate is None:
             act = jnp.square(jax.nn.relu(grouped(rows, e_up)))
         else:

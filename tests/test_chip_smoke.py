@@ -350,21 +350,24 @@ def test_grouped_matmul_lowers_at_its_own_tilings(v5e, k, n):
     1,536-token prefill's 12,288, on a whole stack of 8 x 64 groups:
     Mosaic takes every one (what ``gmm_vmem_bytes`` reckons under its
     budget fits the v5e's scoped VMEM), forward and under ``grad``."""
-    from ray_tpu.ops.moe_dispatch import gmm_tiling, pallas_grouped_matmul
+    from ray_tpu.ops.moe_dispatch import (gmm_tiling, group_tiles,
+                                          pallas_grouped_matmul)
+
+    def kernel(a, b, s, t):
+        return pallas_grouped_matmul(a, b, s, group_tiles(s, a.shape[0], t[0]),
+                                     jnp.bfloat16, t)
 
     G = 512
     for m in (16, 256, 2048, 4096, 12288):
         tiling = gmm_tiling(m, k, n, 2)
         assert tiling is not None, m
         assert _mosaic(jax.jit(
-            lambda a, b, s, t=tiling: pallas_grouped_matmul(
-                a, b, s, jnp.bfloat16, t)).lower(
+            lambda a, b, s, t=tiling: kernel(a, b, s, t)).lower(
             v5e(m, k), v5e(G, k, n), v5e(G, dtype=jnp.int32))), (m, tiling)
     # training: the kernel forward, ragged_dot's transposes backward
     tiling = gmm_tiling(2048, k, n, 2)
-    text = jax.jit(jax.grad(lambda a, b, s: jnp.sum(pallas_grouped_matmul(
-        a, b, s, jnp.bfloat16, tiling).astype(jnp.float32)),
-        (0, 1))).lower(v5e(2048, k), v5e(64, k, n),
+    text = jax.jit(jax.grad(lambda a, b, s: jnp.sum(kernel(
+        a, b, s, tiling).astype(jnp.float32)), (0, 1))).lower(v5e(2048, k), v5e(64, k, n),
                        v5e(64, dtype=jnp.int32)).compile().as_text()
     assert "ragged_dot_tiling" in text
 
@@ -375,9 +378,11 @@ def test_olmoe_cell_decode_program_fits_the_v5e(v5e, placed, monkeypatch):
     matmul weights in bf16 as the engine holds them): the v5e's compiler
     takes it (5.74 GiB of 15.75: 5.73 of arguments, 2.73 weights + 3.00
     pool; 11.56 on float32 weights, whose bf16 copies were 3.10 GiB of
-    temporaries), with the paged kernel and the grouped matmuls as
-    Mosaic calls (the matmuls XLA's ``ragged_dot`` at its own 256 x 512
-    x 512: these widths keep it, PR 40). The temporaries that remain (0.014 GiB) hold nothing
+    temporaries), with the paged kernel and the three grouped matmuls as
+    Mosaic calls at ``gmm_tiling``'s (128, 2048, 1024) and (128, 1024,
+    2048), the whole expert one grid step a group, and no ``ragged_dot``
+    (PR 53: until then these widths kept XLA's own at 256 x 512 x 512).
+    The temporaries that remain (0.014 GiB) hold nothing
     stack-shaped: the grouped-matmul calls read the expert stacks whole
     and in place (PR 37). Until then ONE layer's slice of a stack at a
     time was copied out as their operand: 0.25 GiB of temporaries, 5.98
@@ -399,13 +404,10 @@ def test_olmoe_cell_decode_program_fits_the_v5e(v5e, placed, monkeypatch):
         placed(jax.eval_shape(lambda: model.init_kv_pool(B * maxb + 1, bs))),
         v5e(B, maxb, dtype=jnp.int32), v5e(B, dtype=jnp.int32),
         *_sampling(v5e, B), v5e(L, E, dtype=jnp.int32)).compile()
-    # one attention kernel + three grouped matmuls in the layer scan:
-    # XLA's own ragged_dot, which tiles these widths 512 x 512 (PR 40:
-    # the Pallas kernel is for widths it tiles narrower)
-    text = compiled.as_text()
-    assert text.count("tpu_custom_call") >= 4
-    assert 'ragged_dot_tiling="256,512,512"' in text
-    assert model.grouped_matmul_plan(B)["moe_grouped_impl"] == "ragged_dot"
+    plan = model.grouped_matmul_plan(B)
+    assert [plan[f"moe_gmm_tiling_{c}"] for c in ("gate", "up", "down")] \
+        == ["128x2048x1024", "128x2048x1024", "128x1024x2048"]
+    _holds_the_tiled_grouped_matmuls(compiled.as_text(), model, B)
     mem = compiled.memory_analysis()
     total = (mem.argument_size_in_bytes + mem.temp_size_in_bytes
              + mem.output_size_in_bytes - mem.alias_size_in_bytes)
@@ -497,8 +499,8 @@ def test_mla_cell_decode_program_fits_the_v5e(v5e, placed, monkeypatch):
     pool rows. The v5e's compiler takes it at 10.57 GiB of 15.75 (10.56
     of arguments: 5.87 weights + 4.69 pool; 0.01 of temporaries), with
     the latent kernel of both scans and the three grouped matmuls as
-    Mosaic calls (2048 x 768 is no width XLA tiles 512 x 512, so
-    ``grouped_matmul_impl`` takes the Pallas kernel), and nothing of an
+    Mosaic calls (``grouped_matmul_impl`` takes the Pallas kernel
+    wherever ``gmm_tiling`` has a tiling), and nothing of an
     expert stack's shape among the temporaries."""
     from benchmark import run as harness
     from benchmark.builders import kanana

@@ -156,14 +156,12 @@ def test_gmm_tiling_at_28_lane_tiles_is_legal(rows, k, n, want, monkeypatch):
     """3,584 = 28 x 128: no power of two, so a k or n tile is 3584, 1792,
     896, 512, 256 or 128; the rule finds one that divides, inside the
     VMEM budget, in two grid steps a group, and the resolver takes it on
-    a TPU backend: XLA's own 512 x 512 tiles (``xla_tiles_wide``) would
-    make fourteen steps of such an expert (tools/moe_gmm_bench.py ``wide``
-    times both)."""
+    a TPU backend: XLA's own 512 x 512 tiles would make fourteen steps
+    of such an expert (tools/moe_gmm_bench.py ``wide`` times both)."""
     from ray_tpu.ops import moe_dispatch
     from ray_tpu.ops.moe_dispatch import (GMM_VMEM_BUDGET, gmm_tiling,
                                           gmm_vmem_bytes,
-                                          grouped_matmul_impl, lane_divisors,
-                                          xla_tiles_wide)
+                                          grouped_matmul_impl, lane_divisors)
     assert lane_divisors(3584) == [3584, 1792, 896, 512, 256, 128]
     tiling = gmm_tiling(rows, k, n, 2)
     assert tiling == want
@@ -172,7 +170,6 @@ def test_gmm_tiling_at_28_lane_tiles_is_legal(rows, k, n, want, monkeypatch):
     assert tk % 128 == 0 and tn % 128 == 0
     assert gmm_vmem_bytes(*tiling, 2) <= GMM_VMEM_BUDGET
     assert (k // tk) * (n // tn) == 2
-    assert xla_tiles_wide(k, n)
     monkeypatch.setattr(moe_dispatch, "on_chip", lambda: True)
     assert grouped_matmul_impl(rows, k, n, 2) == ("pallas_gmm", want)
 

@@ -56,12 +56,13 @@ choice and on ``ragged_dot``.
 **``wide`` (PR 50)**: ``chosen`` at 64 experts of 3584 x 1024
 (xing4.0-29b-a4b-d5: 4 expert layers, top-4), a decode step's 128 rows
 and a 512-token chunk's 2,048. 3,584 = 7 x 512, so XLA's own tiling is
-512 x 512 (``xla_tiles_wide``), fourteen tiles an expert; the resolver
-takes the Pallas kernel at ``gmm_tiling``'s two ((128, 3584, 512) and
-(128, 1024, 1792) at 128 rows: legal though 28 lane tiles are no power of
-two). The line also times the kernel at ``gmm_tiling``'s choice whatever
-the resolver says (``ms_gmm_tiling``): it was the reading that moved the
-rule (1.689 for 2.074 ms at 128 rows, 2.374 for 4.912 at 2,048).
+512 x 512, fourteen tiles an expert; the resolver takes the Pallas
+kernel at ``gmm_tiling``'s two ((128, 3584, 512) and (128, 1024, 1792)
+at 128 rows: legal though 28 lane tiles are no power of two). The line
+also times the kernel at ``gmm_tiling``'s choice whatever the resolver
+says (``ms_gmm_tiling``): it was the reading that first moved the rule
+(1.689 for 2.074 ms at 128 rows, 2.374 for 4.912 at 2,048; since PR 53
+the rule is the kernel wherever a tiling exists).
 
 **``held`` (PR 43)**: an expert layer that HOLDS A SHARE of its router's
 experts (deepseek-v3.2-d5: 16 of 256 experts of 7168 x 2048, top-8, 4
