@@ -53,6 +53,14 @@ is the in-tree TPU-native equivalent (BASELINE.md config 5):
   slot's row in place, preemption drops it (the re-prefill rebuilds it)
   and a prefix hit is REFUSED: the shared pages would come without the
   state at their end (docs/serving.md, "Recurrent state");
+- a model that generates BY DIFFUSION OVER BLOCKS (``model.cfg.
+  block_length`` > 1) is served by the same loop: a step is a PASS over
+  every slot's current block, whose yield is a count a slot (0 or a
+  whole block), decided on the device by the pass's own sampler and
+  unmask rule; a prefill samples nothing and hands the loop a first
+  block (docs/serving.md, "Generation by diffusion over blocks"). The
+  one-token models are the case of a block of one, and their programs
+  are the ones they were;
 - the parameters are held in the dtype the programs compute in: the
   matmul weights cast once at construction (``model.serving_params``),
   not by every program that reads them; norms and an expert model's
@@ -114,6 +122,10 @@ class Request:
         self.prompt = list(prompt_tokens)
         self.sampling = sampling
         self.output: List[int] = []
+        # a block-diffusion engine's: for each token of ``output``, the
+        # denoising pass of its block that placed it (1 the first); None
+        # on every other engine. Written before the token is streamed
+        self.unmasked_at: Optional[List[int]] = None
         # ``(token, decode step that delivered it)``; ``(None, step)``
         # ends the stream
         self.stream: "queue.Queue" = queue.Queue()
@@ -334,6 +346,11 @@ class ContinuousBatchingEngine:
         # in the cache tree (() for every other model, for which nothing
         # here differs from what it always was)
         self.recurrent = bool(getattr(model, "recurrent", False))
+        # Tokens a slot's block holds (1: one token a step, every model
+        # but a block-diffusion one), read as ``eva`` and the window are
+        self.block_length = int(getattr(model.cfg, "block_length", 1))
+        if self.block_length > 1:
+            self._check_block_diffusion()
         self._state_names = (tuple(model.state_row_shapes())
                              if self.recurrent else ())
         covers = block_size * (self.eva[1] if self.eva else 1)
@@ -405,6 +422,20 @@ class ContinuousBatchingEngine:
         # sampler's key never has a host copy: every program that
         # samples returns the next one (``_rng_key``).
         self._last_tokens = np.zeros(max_slots, np.int32)
+        if self.block_length > 1:
+            # a block-diffusion model's step input is each slot's BLOCK
+            # STATE [2n + 1]: the block's tokens (the mask id where
+            # nothing stands yet), the pass that placed each, the passes
+            # the block has had; the program hands the next one on, the
+            # host's copy follows with every read-back. ``_given``: how
+            # many of a slot's first block the prompt gave
+            n = self.block_length
+            self._last_tokens = np.zeros((max_slots, 2 * n + 1), np.int32)
+            self._last_tokens[:, :n] = model.cfg.mask_token_id
+            self._given = np.zeros(max_slots, np.int32)
+            # [slot-passes, of them commits, tokens placed, of them by
+            # the threshold], summed by the program, read by ``stats``
+            self._block_counts = jnp.zeros(4, jnp.int32)
         self._dev_tokens = None
         self._dev_tables = None
         self._dev_offsets = None            # sent a step ahead, see there
@@ -453,9 +484,11 @@ class ContinuousBatchingEngine:
             self._ffn_counts = (jnp.zeros(load_shape, jnp.int32), 0)
             # the EXPERT layers' (a model may lead with dense ones)
             self._ffn_rows_per_slot = (model.cfg.expert_top_k
-                                       * load_shape[0])
+                                       * load_shape[0] * self.block_length)
         # ONE program a decode step: the model's step and the sampler
-        self._decode = jax.jit(self._decode_step_paged, donate_argnums=(2,))
+        self._decode = jax.jit(
+            self._decode_step_paged if self.block_length == 1
+            else self._decode_step_paged_blocks, donate_argnums=(2,))
         self._prefill = jax.jit(self._prefill_impl)
         self._prefill_prefix = jax.jit(model.prefill_with_prefix)
         self._insert = jax.jit(
@@ -495,6 +528,21 @@ class ContinuousBatchingEngine:
                       # temperature > 0 / top_k > 0: the steps whose
                       # program took the sampler's draw / its sort
                       "decode_steps_sampled": 0, "decode_steps_topk": 0,
+                      # generation by diffusion over blocks (1 and zeros
+                      # for every other model): a block's positions; live
+                      # slots x passes, and of those the passes that
+                      # COMMITTED a block (ran it clean and kept its
+                      # rows); tokens the denoising passes placed, and
+                      # of those the ones the confidence threshold let
+                      # through where the pass's quota alone would not
+                      # have (all four summed by the program, read when
+                      # ``stats`` is asked); blocks whose tokens the host
+                      # has handed out. ``decode_steps`` counts passes
+                      "block_length": self.block_length,
+                      "block_slot_passes": 0, "block_commit_passes": 0,
+                      "block_tokens_unmasked": 0,
+                      "block_tokens_unmasked_by_confidence": 0,
+                      "blocks_committed": 0,
                       "prefills": 0,
                       "prefix_prefills": 0, "prefix_tokens_reused": 0,
                       "preemptions": 0,
@@ -594,7 +642,8 @@ class ContinuousBatchingEngine:
                       # matmuls ("pallas_gmm" / "ragged_dot") and their
                       # (rows, k, n) tilings, as the model resolves them
                       # from the platform and the step's shapes
-                      **model.grouped_matmul_plan(max_slots),
+                      **model.grouped_matmul_plan(
+                          max_slots * self.block_length),
                       # the router of an expert model's FFN ("softmax" /
                       # "sigmoid"; "" for a dense model)
                       "moe_router_kind": getattr(model.cfg, "router_kind",
@@ -646,6 +695,12 @@ class ContinuousBatchingEngine:
                 moe_assignments_held=int(
                     load[:, first:first + n_held].sum()),
                 moe_expert_load=load.tolist())
+        if self.block_length > 1:
+            self._stats.update(zip(
+                ("block_slot_passes", "block_commit_passes",
+                 "block_tokens_unmasked",
+                 "block_tokens_unmasked_by_confidence"),
+                map(int, np.asarray(self._block_counts))))
         # last: the read above may have waited for a program in flight
         self._stats["t_now_s"] = time.perf_counter()
         return self._stats
@@ -664,7 +719,89 @@ class ContinuousBatchingEngine:
             raise ValueError(
                 f"the EVA window {window} is no multiple of block_size {bs}")
 
+    def _check_block_diffusion(self) -> None:
+        """A block-diffusion model against the engine's sizes: a block
+        never straddles a page (so a pass writes ONE page a slot, and a
+        hashed page holds whole blocks: its K/V depend on nothing behind
+        it), and the model's cache is plain rows of tokens."""
+        n = self.block_length
+        if self.block_size % n:
+            raise ValueError(
+                f"block_size {self.block_size} is no multiple of the "
+                f"model's block_length {n}")
+        if (self.eva is not None or self.recurrent
+                or self.model.layer_kinds):
+            raise NotImplementedError(
+                "generation by diffusion over blocks beside a pool a "
+                "kind, a part or a recurrent state")
+
     # -- jitted internals --------------------------------------------------
+    def _decode_step_paged_blocks(self, params, state, pool, block_tables,
+                                  offsets, temps, top_ks, key, ffn_load,
+                                  counts):
+        """One PASS over every slot's block as ONE program (a model with
+        ``block_length`` n > 1): the model's n rows a slot, the sampler,
+        the unmask rule and the block's state machine, none of which
+        leaves the device. ``state`` [B, 2n + 1]: the block's tokens,
+        the pass that placed each (0: the prompt's), the denoising
+        passes it has had. A block with a mask left is DENOISED: the
+        pass's proposals that the rule keeps go in, and its K/V rows,
+        computed from masked inputs, are overwritten by the next pass.
+        A block with none left is COMMITTED: this pass ran it clean, so
+        its rows stay, the slot's offset moves on by n and its next
+        block starts all masked. Slots stand at different phases of
+        their blocks in one call; an idle slot (its table points at the
+        scratch block) keeps what it has.
+
+        Returns the next state, the pool, the next offsets, the next
+        key, the expert load, ``counts`` with this pass's added, and
+        what the host reads, [B, 4n + 2]: the block as it ENTERED the
+        pass with its passes and a flag (on a commit: the tokens to
+        hand out), then the next state (the host's copy of it)."""
+        from ray_tpu.ops.block_diffusion import (confidence,
+                                                 transfer_quotas,
+                                                 unmask_step)
+        cfg = self.model.cfg
+        n, mask_id = self.block_length, cfg.mask_token_id
+        block, placed_at, passes = (state[:, :n], state[:, n:2 * n],
+                                    state[:, 2 * n])
+        live = block_tables[:, 0] != self.num_blocks
+        logits, pool, extras = self.model.block_step_paged_counted(
+            params, block, pool, block_tables, offsets, live)
+        if ffn_load is not None:
+            ffn_load = ffn_load + extras["load"]
+        with jax.named_scope("blockdiff_unmask"):
+            # the decode program's sampler over the slots' n rows each
+            x0, key = self._sample_impl(
+                logits.reshape(-1, logits.shape[-1]), jnp.repeat(temps, n),
+                jnp.repeat(top_ks, n), key)
+            x0 = x0.reshape(block.shape)
+            denoised, placed, by_confidence = unmask_step(
+                block, x0, confidence(logits, x0, temps), passes,
+                mask_id=mask_id,
+                quotas=transfer_quotas(n, cfg.denoising_steps),
+                threshold=cfg.confidence_threshold,
+                dynamic=cfg.remasking == "low_confidence_dynamic")
+        with jax.named_scope("blockdiff_commit"):
+            commit = live & ~jnp.any(block == mask_id, axis=-1)
+            denoise = (live & ~commit)[:, None]
+            placed &= denoise
+            next_block = jnp.where(commit[:, None], mask_id,
+                                   jnp.where(denoise, denoised, block))
+            next_at = jnp.where(commit[:, None], 0, jnp.where(
+                placed, passes[:, None] + 1, placed_at))
+            next_passes = jnp.where(commit, 0, passes + denoise[:, 0])
+            state = jnp.concatenate(
+                [next_block, next_at, next_passes[:, None]], axis=-1)
+            counts = counts + jnp.stack([
+                jnp.sum(live), jnp.sum(commit), jnp.sum(placed),
+                jnp.sum(placed & by_confidence)]).astype(counts.dtype)
+            report = jnp.concatenate(
+                [block, placed_at, commit[:, None].astype(state.dtype),
+                 state], axis=-1)
+        return (state, pool, offsets + n * commit, key, ffn_load, counts,
+                report)
+
     def _decode_step_paged(self, params, tokens, pool, block_tables, offsets,
                            temps, top_ks, key, ffn_load):
         """One decode step as ONE program: the model's step, then the
@@ -833,6 +970,8 @@ class ContinuousBatchingEngine:
                sampling: Optional[SamplingParams] = None) -> Request:
         req = Request(prompt_tokens, sampling or SamplingParams(),
                       self._readers)
+        if self.block_length > 1:
+            req.unmasked_at = []
         self._stats["requests"] += 1
         # deque.append is atomic — submitters never contend on the
         # engine-step lock (a step can span a whole prefill+decode)
@@ -867,6 +1006,12 @@ class ContinuousBatchingEngine:
             self._stats["engine_thread_cpu_s"] += cpu
         self._stats["t_step_s"] += time.perf_counter() - t0
         return active
+
+    def _prefilled(self, n: int) -> int:
+        """Of a context of ``n`` tokens, those a prefill caches: all of
+        them, or a block-diffusion model's whole blocks (what is left
+        of the prompt stands in the first block the passes fill)."""
+        return n - n % self.block_length
 
     def _bucket_for(self, n: int) -> Optional[int]:
         for b in self.buckets:
@@ -922,8 +1067,10 @@ class ContinuousBatchingEngine:
                                                covers)
                 self._stats["prefix_hits_refused_recurrent"] += bool(
                     first and first[0] in self.pool._by_hash)
-            # +1 so the first decode write never needs a growth step
-            alloc = allocate_slot(self.pool, toks, n + 1,
+            # +1 (a whole first block) so the first decode write never
+            # needs a growth step
+            alloc = allocate_slot(self.pool, toks,
+                                  self._prefilled(n) + self.block_length,
                                   window_pool=self.window_pool,
                                   window=self.window,
                                   share=not self.recurrent)
@@ -1084,7 +1231,8 @@ class ContinuousBatchingEngine:
             toks = np.zeros((n_pad, bucket), np.int32)
             for row, (slot, req, alloc) in enumerate(group):
                 seq = req.cache_tokens()
-                lengths[row] = len(seq)
+                seq = seq[:self._prefilled(len(seq))]
+                lengths[row] = max(len(seq), 1)
                 toks[row, :len(seq)] = seq
                 self._slide(alloc, len(seq), len(seq) + 1)
                 self._stats["prefill_tokens"] += len(seq)
@@ -1101,8 +1249,7 @@ class ContinuousBatchingEngine:
         with _Phase(self, "engine.emit", "t_emit_s"):
             now = time.perf_counter()
             for row, (slot, req, alloc) in enumerate(group):
-                self._activate(slot, req, alloc, int(lengths[row]), now)
-                self._emit(slot, int(toks_out[row]))
+                self._activate(slot, req, alloc, now, toks_out[row])
             self._deliver()
 
     def _set_state_rows(self, state, slots: List[int], n_pad: int) -> None:
@@ -1140,10 +1287,10 @@ class ContinuousBatchingEngine:
             for row, (slot, req, alloc, shared) in enumerate(group):
                 seq = req.cache_tokens()
                 pb = shared // bs
-                suffix = seq[shared:]
+                suffix = seq[shared:self._prefilled(len(seq))]
                 toks[row, :len(suffix)] = suffix
                 plens[row] = shared
-                slens[row] = len(suffix)
+                slens[row] = max(len(suffix), 1)
                 self._slide(alloc, shared, len(seq) + 1)
                 prefix.append((alloc, 0, pb))
                 fresh.append((alloc, pb, pb + nb))
@@ -1164,9 +1311,7 @@ class ContinuousBatchingEngine:
         with _Phase(self, "engine.emit", "t_emit_s"):
             now = time.perf_counter()
             for row, (slot, req, alloc, shared) in enumerate(group):
-                self._activate(slot, req, alloc, len(req.cache_tokens()),
-                               now)
-                self._emit(slot, int(toks_out[row]))
+                self._activate(slot, req, alloc, now, toks_out[row])
             self._deliver()
 
     def _prefill_chunk(self, alloc: SlotAllocation, seq: List[int],
@@ -1236,7 +1381,7 @@ class ContinuousBatchingEngine:
         context longer than the largest bucket prefills in bucket-sized
         chunks (vLLM's chunked prefill)."""
         seq = req.cache_tokens()
-        n = len(seq)
+        n = self._prefilled(len(seq))
         if shared_tok > 0:
             self._stats["prefix_prefills"] += 1
             self._stats["prefix_tokens_reused"] += shared_tok
@@ -1244,7 +1389,8 @@ class ContinuousBatchingEngine:
         big = self.buckets[-1]
         last_logits = None
         # one phase for all its chunks; ``bucket`` is the first chunk's
-        with self._prefill_phase(self._bucket_for(min(big, n - pos)), 1, 1):
+        with self._prefill_phase(self._bucket_for(
+                min(big, max(n - pos, 1))), 1, 1):
             if self.recurrent:
                 self._chunk_state = self._zero_state
             while pos < n:
@@ -1256,14 +1402,21 @@ class ContinuousBatchingEngine:
                 self._set_state_rows(self._chunk_state, [slot], 1)
             toks_out = self._sample_batch(last_logits, [req], 1)
         with _Phase(self, "engine.emit", "t_emit_s"):
-            self._activate(slot, req, alloc, n, time.perf_counter())
-            self._emit(slot, int(toks_out[0]))
+            self._activate(slot, req, alloc, time.perf_counter(),
+                           toks_out[0])
             self._deliver()
 
     def _activate(self, slot: int, req: Request, alloc: SlotAllocation,
-                  n_cached: int, now: float) -> None:
+                  now: float, first: Optional[int]) -> None:
+        """The prefill is in: the request takes its slot and decodes
+        from here. ``first`` is the token its prefill sampled, the
+        request's first; a block-diffusion model's prefill samples none
+        (``None``): the slot starts on a first BLOCK instead, what the
+        prefill left of the context and masks behind it."""
+        context = req.cache_tokens()
+        n_cached = self._prefilled(len(context))
         if self.eva is None:
-            seal_prompt_blocks(self.pool, alloc, req.cache_tokens())
+            seal_prompt_blocks(self.pool, alloc, context)
         else:       # an EVA model's blocks are not hashed (paged_cache.py)
             self._stats["kv_summary_rows_written"] += n_cached // self.eva[1]
         if self.window_pool is not None:
@@ -1272,8 +1425,6 @@ class ContinuousBatchingEngine:
             self._slide(alloc, n_cached, n_cached + 1)
             seal_window_blocks(self.window_pool, alloc.window)
             self._set_window_table(slot, alloc)
-        if req.first_token_at is None:
-            req.first_token_at = now
         self.slots[slot] = req
         self.allocs[slot] = alloc
         self.offsets[slot] = n_cached
@@ -1282,8 +1433,23 @@ class ContinuousBatchingEngine:
         self._dev_tokens = self._dev_tables = self._dev_sampling = None
         self._dev_offsets = None
         self._admit_order.append(slot)
+        if first is not None:
+            if req.first_token_at is None:
+                req.first_token_at = now
+            self._emit(slot, int(first))
+            return
+        n = self.block_length
+        given = context[n_cached:]
+        self._given[slot] = len(given)
+        self._last_tokens[slot] = 0
+        self._last_tokens[slot, :n] = self.model.cfg.mask_token_id
+        self._last_tokens[slot, :len(given)] = given
 
     def _sample_batch(self, logits, reqs: List[Request], n_pad: int):
+        """The requests' first tokens [n_pad], off their prefill's last
+        logits; ``None`` a row where the model's prefill samples none."""
+        if self.block_length > 1:
+            return [None] * n_pad
         temps = np.zeros(n_pad, np.float32)
         top_ks = np.zeros(n_pad, np.int32)
         for row, req in enumerate(reqs):
@@ -1326,8 +1492,8 @@ class ContinuousBatchingEngine:
         self._admit_order.remove(slot)
 
     def _grow_or_preempt(self) -> None:
-        """Every active slot must have capacity for its next token's
-        K/V before the batched decode runs. Exhaustion preempts the
+        """Every active slot must have capacity for its next token's (its
+        block's) K/V before the batched decode runs. Exhaustion preempts the
         YOUNGEST slot (recompute is cheapest for it) until the older
         ones fit — the victim may be the grower itself."""
         for slot in list(self._admit_order):      # oldest first
@@ -1335,8 +1501,9 @@ class ContinuousBatchingEngine:
                 continue
             alloc = self.allocs[slot]
             held = len(alloc.blocks)
-            while not ensure_capacity(self.pool, alloc,
-                                      int(self.offsets[slot]) + 1):
+            while not ensure_capacity(
+                    self.pool, alloc,
+                    int(self.offsets[slot]) + self.block_length):
                 # chunked prefill re-admits ANY context length, so plain
                 # youngest-first is always safe (and discards the least
                 # computed work)
@@ -1400,9 +1567,12 @@ class ContinuousBatchingEngine:
             self._device_dry_at = read.t1
         with _Phase(self, "engine.emit", "t_emit_s"):
             self._stats["decode_steps"] += 1
-            for i in active:
-                self.offsets[i] += 1
-                self._emit(i, int(toks[i]))
+            if self.block_length > 1:
+                self._emit_blocks(active, toks)
+            else:
+                for i in active:
+                    self.offsets[i] += 1
+                    self._emit(i, int(toks[i]))
         if self._in_flight is not None or not self._admit_order:
             # the device is busy with the next step, or the last slot
             # ended and there is no next dispatch to deliver under
@@ -1418,7 +1588,10 @@ class ContinuousBatchingEngine:
         dispatch (the device's inputs still stand), the step in flight
         ends no request (none stops on a token's VALUE, none reaches
         its length), and every slot has room for one more token without
-        a preemption."""
+        a preemption. Where a step yields up to a BLOCK a slot, "one
+        more token" reads "one more block" throughout: the step in
+        flight may commit one, and the step ahead then writes the next."""
+        n = self.block_length
         if (len(active) < self.max_slots or self.waiting
                 or self._dev_tokens is None or self._dev_offsets is None):
             return False
@@ -1426,14 +1599,14 @@ class ContinuousBatchingEngine:
             req = self.slots[i]
             sampling = req.sampling
             if (sampling.stop_token_ids
-                    or len(req.output) + 1 >= sampling.max_tokens
-                    or self.offsets[i] + 2 >= self.max_seq):
+                    or len(req.output) + n >= sampling.max_tokens
+                    or self.offsets[i] + 2 * n >= self.max_seq):
                 return False
         for i in active:
             alloc = self.allocs[i]
             held = len(alloc.blocks)
             if not ensure_capacity(self.pool, alloc,
-                                   int(self.offsets[i]) + 2):
+                                   int(self.offsets[i]) + 2 * n):
                 return False
             if len(alloc.blocks) != held:
                 self._tables[i, :len(alloc.blocks)] = alloc.blocks
@@ -1479,13 +1652,31 @@ class ContinuousBatchingEngine:
         # dispatch only: the call returns before the device is done
         with _Phase(self, "engine.decode_enqueue", "t_enqueue_s"):
             load, expected = self._ffn_counts or (None, 0)
-            self._dev_tokens, self.kv, self._rng_key, load = self._decode(
-                self.params, self._dev_tokens, self.kv, self._dev_tables,
-                self._dev_offsets, *self._dev_sampling, self._rng_key, load)
+            if self.block_length > 1:
+                # the pass hands on its own next state and offsets (a
+                # slot's offset moves when the DEVICE finds its block
+                # clean) and, apart, what the host reads of it
+                (self._dev_tokens, self.kv, self._dev_offsets, self._rng_key,
+                 load, self._block_counts, report) = self._decode(
+                    self.params, self._dev_tokens, self.kv, self._dev_tables,
+                    self._dev_offsets, *self._dev_sampling, self._rng_key,
+                    load, self._block_counts)
+            else:
+                self._dev_tokens, self.kv, self._rng_key, load = self._decode(
+                    self.params, self._dev_tokens, self.kv, self._dev_tables,
+                    self._dev_offsets, *self._dev_sampling, self._rng_key,
+                    load)
             if load is not None:
                 self._ffn_counts = (
                     load, expected + len(active) * self._ffn_rows_per_slot)
             self._enqueued()
+        self._stats["decode_steps_sampled"] += self._sampling_asked[0]
+        self._stats["decode_steps_topk"] += self._sampling_asked[1]
+        if self.block_length > 1:
+            # (what this pass's attention reads is counted when it is
+            # read back, ``_emit_blocks``: where a step ahead stands is
+            # the device's to know until then)
+            return report, active, ahead
         with _Phase(self, "engine.host_arrays", "t_host_arrays_s"):
             # the NEXT step's offsets go now, under the running program:
             # every active slot will have advanced by one, unless a slot
@@ -1496,8 +1687,6 @@ class ContinuousBatchingEngine:
             following = at.copy()
             following[active] += 1
             self._dev_offsets = jnp.asarray(following)
-        self._stats["decode_steps_sampled"] += self._sampling_asked[0]
-        self._stats["decode_steps_topk"] += self._sampling_asked[1]
         # what the dispatched program's attention reads (the kernel:
         # ceil((offset + 1) / bs) blocks a slot) of what its tables hold;
         # counted under the running program, in no phase's span
@@ -1553,11 +1742,56 @@ class ContinuousBatchingEngine:
                 or len(req.output) >= req.sampling.max_tokens
                 or self.offsets[slot] + 1 >= self.max_seq)
         if stop:
-            req.finish_reason = ("stop" if tok in req.sampling.stop_token_ids
+            self._finish(slot, "stop" if tok in req.sampling.stop_token_ids
+                         else "length")
+
+    def _finish(self, slot: int, reason: str) -> None:
+        req = self.slots[slot]
+        req.finish_reason = reason
+        req.finished_at = time.perf_counter()
+        self._undelivered.append((req, None))
+        self._release(slot)
+
+    def _emit_blocks(self, active: List[int], report: np.ndarray) -> None:
+        """Book one pass of a block-diffusion model, ``report`` [B, 4n +
+        2] as ``_decode_step_paged_blocks`` hands it back: the host's
+        copy of the slots' state follows the device's; a slot whose
+        block was COMMITTED moves on by a block and its tokens are
+        handed out, in sequence order, never to be taken back (all but
+        those the prompt gave of it), each with the pass that placed it.
+        ``max_tokens`` and a stop token cut the block where they fall."""
+        n, bs = self.block_length, self.block_size
+        # what this pass's attention read: each slot's rows up to its
+        # block's end, of what its table holds
+        self._stats["decode_kv_blocks_table"] += (
+            len(active) * self.blocks_per_slot)
+        self._stats["decode_kv_blocks_live"] += int(
+            ((self.offsets[active] + n + bs - 1) // bs).sum())
+        self._last_tokens[:] = report[:, 2 * n + 1:]
+        now = None
+        for i in active:
+            if not report[i, 2 * n]:
+                continue
+            self.offsets[i] += n
+            self._stats["blocks_committed"] += 1
+            req, sampling = self.slots[i], self.slots[i].sampling
+            given, self._given[i] = int(self._given[i]), 0
+            if req.first_token_at is None:
+                req.first_token_at = now = now or time.perf_counter()
+            for tok, at in zip(report[i, given:n].tolist(),
+                               report[i, n + given:2 * n].tolist()):
+                req.output.append(tok)
+                req.unmasked_at.append(at)
+                self._undelivered.append((req, tok))
+                self._stats["tokens_generated"] += 1
+                if (tok in sampling.stop_token_ids
+                        or len(req.output) >= sampling.max_tokens):
+                    self._finish(i, "stop" if tok in sampling.stop_token_ids
                                  else "length")
-            req.finished_at = time.perf_counter()
-            self._undelivered.append((req, None))
-            self._release(slot)
+                    break
+            else:
+                if self.offsets[i] + n >= self.max_seq:
+                    self._finish(i, "length")
 
     def _put_end(self, req: Request) -> None:
         """End a stream that never ran (a prompt refused): the marker,
@@ -1605,6 +1839,10 @@ class ContinuousBatchingEngine:
         return kv, np.asarray(last_logits[0]), n
 
     def _no_handoff_for_eva(self) -> None:
+        if self.block_length > 1:
+            raise NotImplementedError(
+                "the prefill/decode handoff ends in a sampled token; a "
+                "block-diffusion model's prefill samples none")
         if self.recurrent:
             raise NotImplementedError(
                 "the prefill/decode handoff carries K/V rows only; a "
@@ -1651,8 +1889,8 @@ class ContinuousBatchingEngine:
             toks_out = self._sample_batch(jnp.asarray(last_logits)[None],
                                           [req], 1)
             self._stats["requests"] += 1
-            self._activate(slot, req, alloc, n, time.perf_counter())
-            self._emit(slot, int(toks_out[0]))
+            self._activate(slot, req, alloc, time.perf_counter(),
+                           toks_out[0])
             self._deliver()
         return req
 

@@ -130,6 +130,11 @@ class LLMServer:
                 chunks = [{**head, "delta": decode([tok]),
                            "token_id": int(tok), "index": i}
                           for i, tok in enumerate(tokens, index)]
+                if req.unmasked_at is not None:
+                    # a block-diffusion model's: the denoising pass of
+                    # its block that placed the token
+                    for chunk in chunks:
+                        chunk["pass"] = req.unmasked_at[chunk["index"]]
                 if ended:
                     chunks.append({
                         **head, "finish_reason": req.finish_reason,

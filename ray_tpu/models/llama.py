@@ -83,6 +83,7 @@ FULL, SLIDING = 0, 1
 EVA_KIND = "eva_attention"
 # a full layer's window: past any position, so ``i - j < window`` holds
 NO_WINDOW = 2 ** 30
+REMASKING = ("low_confidence_dynamic", "low_confidence_static")
 
 
 @dataclasses.dataclass(frozen=True)
@@ -152,6 +153,20 @@ class LlamaConfig:
     hc_sinkhorn_iters: int = 20
     hc_eps: float = 1e-6
     hc_res_clamp: Tuple[float, float] = (-30.0, 30.0)
+    # GENERATION BY DIFFUSION OVER BLOCKS (``block_length`` > 1; 1: one
+    # token a step, left to right). Key j is visible to query i iff
+    # ``j // block_length <= i // block_length``, the logits of position
+    # i score the token AT i, and a block of ``block_length`` positions
+    # starts as ``mask_token_id`` and is filled over at most
+    # ``denoising_steps`` passes (``block_unmask``): "low_confidence_
+    # dynamic" unmasks what is surer than ``confidence_threshold``, or
+    # the pass's quota of the surest; "low_confidence_static" the quota
+    # alone. The engine reads these as it reads ``eva`` or the window
+    block_length: int = 1
+    denoising_steps: int = 1
+    remasking: str = "low_confidence_dynamic"
+    confidence_threshold: float = 0.9
+    mask_token_id: Optional[int] = None
 
     def __post_init__(self):
         if self.attention_impl not in ("ring", "ulysses", "flash", "xla"):
@@ -194,6 +209,27 @@ class LlamaConfig:
                 f"hc_mult ({self.hc_mult}) streams, at least one, and "
                 f"hc_sinkhorn_iters ({self.hc_sinkhorn_iters}) rounds, "
                 f"none or more")
+        if self.block_length > 1:
+            self._check_block_diffusion()
+
+    def _check_block_diffusion(self) -> None:
+        if self.remasking not in REMASKING:
+            raise ValueError(
+                f"remasking must be one of {REMASKING}, got "
+                f"{self.remasking!r}")
+        if (not 1 <= self.denoising_steps <= self.block_length
+                or self.mask_token_id is None
+                or not 0 <= self.mask_token_id < self.vocab_size):
+            raise ValueError(
+                f"a block of {self.block_length} positions is filled over 1 "
+                f"to {self.block_length} denoising_steps (got "
+                f"{self.denoising_steps}) from a mask_token_id among the "
+                f"{self.vocab_size} ids (got {self.mask_token_id})")
+        if (self.layer_types is not None or self.hc_mult > 1
+                or self.num_pred_heads > 1):
+            raise ValueError(
+                "generation by diffusion over blocks is the plain decoder "
+                "layer's: no layer kinds, streams or further heads")
 
     def _check_eva(self) -> None:
         if set(self.layer_types) != {EVA_KIND}:
@@ -566,6 +602,16 @@ class LlamaModel:
         for the plain model."""
         return None if kind is None else self._windows[kind]
 
+    def _mask_positions(self, positions):
+        """The position a prefill takes a query's causal mask at: its
+        own, or under ``block_length`` > 1 its block's last (every row
+        of a block sees the whole block, and RoPE still turns by the true
+        position). The one-token models get back what they handed in."""
+        n = self.cfg.block_length
+        if n == 1:
+            return positions
+        return positions // n * n + (n - 1)
+
     def _attention(self, q, k, v, positions, window=None, layer=None):
         """The training program's attention over this call's own rows
         (``layer``: for a model whose rows need its weights to be read)."""
@@ -811,6 +857,11 @@ class LlamaModel:
         """``apply`` and, stacked over layers, each layer's ``_ffn``
         extra (``None`` for the dense layer)."""
         cfg = self.cfg
+        if cfg.block_length > 1:
+            raise NotImplementedError(
+                "a block-diffusion model is trained on a doubled sequence "
+                "(noised and clean) under a mask of its own; only its "
+                "serving programs are built")
 
         def layer_fn(x, layer_and_kind, stacks=None):
             layer, kind = layer_and_kind
@@ -929,6 +980,7 @@ class LlamaModel:
         B, T = tokens.shape
         S = cache["k"].shape[2]
         q_pos = offsets[:, None] + jnp.arange(T)[None, :]        # [B, T]
+        mask_pos = self._mask_positions(q_pos)
         batch_idx = jnp.arange(B)[:, None]
 
         def step(x, layer_and_cache, stacks):
@@ -946,7 +998,7 @@ class LlamaModel:
                     return o, (k_all, v_all, ks, vs)
                 with jax.named_scope("attention"):
                     # attend over cache positions <= own position
-                    o = self._attend_rows(q, k_all, v_all, layer, q_pos,
+                    o = self._attend_rows(q, k_all, v_all, layer, mask_pos,
                                           jnp.arange(S), self._window(kind))
                 return o, (k_all, v_all)
 
@@ -1161,6 +1213,83 @@ class LlamaModel:
                     v=v_out.reshape(pool["v"].shape))
         return self._head(params, x)[:, 0], pool, extras
 
+    def block_step_paged_counted(self, params: Params, tokens: jax.Array,
+                                 pool: Params, block_tables: jax.Array,
+                                 offsets: jax.Array,
+                                 live: Optional[jax.Array] = None):
+        """One pass over every slot's CURRENT BLOCK against the block
+        pool (a model with ``block_length`` > 1): tokens [B, n] at
+        positions ``offsets .. offsets + n - 1`` (``offsets`` [B]
+        multiples of n; the page size is one too, so a block lies in ONE
+        page). The block's K/V rows are written there, over whatever
+        the pass before left (a denoise pass's rows stay only until the
+        next pass; the commit pass's, computed from the clean block,
+        are the ones later blocks read), and every one of its n x H
+        query rows attends the slot's ``offsets + n`` rows: the whole
+        block and all before it. -> (logits [B, n, V] of the block's
+        OWN positions, the pool, the layers' ``_ffn`` extras), the pool
+        carried whole and written in place as
+        ``decode_step_paged_counted`` carries it.
+
+        The paged attention is the decode step's, kernel and reference
+        alike: its head axis carries the block's rows (q [B, n, H, hd]
+        laid KV-head-major as [B, Hkv * n * H/Hkv, hd]: all rows of a
+        slot see one length)."""
+        cfg = self.cfg
+        if type(self)._attend_pages is not LlamaModel._attend_pages:
+            raise NotImplementedError(
+                "a block's rows ride the head axis of the plain paged "
+                "attention; this model's pages hold something else")
+        L, NB, bs = pool["k"].shape[:3]
+        B, n = tokens.shape
+        if bs % n:
+            raise ValueError(
+                f"a page of {bs} rows does not hold whole blocks of {n}")
+
+        def whole(a):
+            return a.reshape((L * NB,) + a.shape[2:])
+
+        dest_block = jnp.take_along_axis(
+            block_tables, (offsets // bs)[:, None], axis=-1)       # [B, 1]
+        dest_off = (offsets % bs)[:, None] + jnp.arange(n)[None, :]  # [B, n]
+        lengths = offsets + n
+        q_pos = offsets[:, None] + jnp.arange(n)[None, :]
+        impl = self.paged_decode_impl()
+        H, Hkv = cfg.n_heads, cfg.n_kv_heads
+
+        def step(carry, layer_and_base, stacks):
+            x, k_pool, v_pool = carry
+            layer, base = layer_and_base
+
+            def attend(q, k_new, v_new):
+                with jax.named_scope("blockdiff_kv_update"):
+                    k_all = k_pool.at[base + dest_block, dest_off].set(k_new)
+                    v_all = v_pool.at[base + dest_block, dest_off].set(v_new)
+                with jax.named_scope("blockdiff_attention"):
+                    rows = q.reshape(B, n, Hkv, H // Hkv, -1).transpose(
+                        0, 2, 1, 3, 4).reshape(B, n * H, -1)
+                    o = self._attend_pages(
+                        rows, k_all, v_all, layer, block_tables, lengths,
+                        impl=impl, starts=None, first_block=base,
+                        num_blocks=NB)
+                    o = o.reshape(B, Hkv, n, H // Hkv, -1).transpose(
+                        0, 2, 1, 3, 4).reshape(B, n, H, -1)
+                return o, (k_all, v_all)
+
+            x, (k_pool, v_pool), extra = self._layer(
+                x, layer, q_pos, attend, live=live, stacks=stacks)
+            return (x, k_pool, v_pool), extra
+
+        main = self._whole_leaves(params["layers"])
+        (x, k_out, v_out), extras = self._scan_layers(
+            step,
+            (self._embed(params, tokens), whole(pool["k"]),
+             whole(pool["v"])),
+            params, main, (jnp.arange(L, dtype=jnp.int32) * NB,))
+        pool = dict(pool, k=k_out.reshape(pool["k"].shape),
+                    v=v_out.reshape(pool["v"].shape))
+        return self._head(params, x), pool, extras
+
     def _decode_step_eva(self, params: Params, tokens: jax.Array,
                          pool: Params, block_tables: jax.Array,
                          offsets: jax.Array, live=None):
@@ -1338,6 +1467,7 @@ class LlamaModel:
             jnp.arange(Pmax)[None, :] < prefix_len[:, None],
             jnp.arange(Pmax)[None, :], far)                          # [N,Pmax]
         pos_k = jnp.concatenate([pos_prefix, pos_q], axis=1)      # [N,P+Tb]
+        mask_pos = self._mask_positions(pos_q)
 
         def step(x, layer_and_prefix, stacks):
             layer, kp, vp, kind = layer_and_prefix  # kp/vp [N, Pmax, Hkv, D]
@@ -1349,7 +1479,7 @@ class LlamaModel:
                                            axis=1),
                         jnp.concatenate([vp.astype(v_new.dtype), v_new],
                                         axis=1),
-                        layer, pos_q, pos_k, self._window(kind))
+                        layer, mask_pos, pos_k, self._window(kind))
                 return o, (k_new, v_new)
 
             x, kv, _ = self._layer(x, layer, pos_q, attend, kind=kind,

@@ -4,7 +4,8 @@ native capability).
 
 ``MoEModel`` is ``LlamaModel`` with two methods overridden: ``_ffn`` (a
 router and ``num_experts`` SwiGLU experts, ``expert_top_k`` a token) and
-``_qk_norm`` (OLMoE's RMSNorm over all heads' lanes of q and of k). The
+``_qk_norm`` (OLMoE's RMSNorm over all heads' lanes of q and of k, or
+Qwen3's over each head's). The
 parent's one decoder layer (``LlamaModel._layer``) calls both, so every
 program built on it — training ``apply``/``loss``, ``forward_step``,
 ``decode_step_paged``, ``prefill_with_prefix``, so Serve and the engine —
@@ -63,6 +64,9 @@ class MoEConfig(LlamaConfig):
     # RMSNorm of q and of k over ALL heads' lanes, before the split into
     # heads and before RoPE (OLMoE); adds ``q_norm``/``k_norm`` params
     qk_norm: bool = False
+    # with ``qk_norm``: one RMS over EACH head's lanes instead, one
+    # weight of ``head_dim`` shared by the heads (Qwen3's)
+    qk_norm_per_head: bool = False
     router_z_loss: float = 1e-3
     load_balance_loss: float = 1e-2
     # Under an ``ep`` mesh axis only (off it the FFN is dropless):
@@ -156,7 +160,9 @@ class MoEConfig(LlamaConfig):
         d = self.dim
         q = self.n_heads * self.head_dim
         kv = self.n_kv_heads * self.head_dim
-        return 2 * d * q + 2 * d * kv + (q + kv if self.qk_norm else 0)
+        norms = (0 if not self.qk_norm
+                 else 2 * self.head_dim if self.qk_norm_per_head else q + kv)
+        return 2 * d * q + 2 * d * kv + norms
 
     def num_params(self) -> int:
         d, f, v, E = self.dim, self.ffn_dim, self.vocab_size, self.num_experts
@@ -188,6 +194,21 @@ class MoEConfig(LlamaConfig):
             rope_theta=10_000.0, num_experts=8, expert_top_k=2,
             norm_topk_prob=False, qk_norm=True), **overrides})
 
+    @staticmethod
+    def debug_sdar(vocab_size: int = 512, max_seq_len: int = 128,
+                   **overrides) -> "MoEConfig":
+        """SDAR's block at debug widths: 8 experts, top-2 renormalised,
+        QK-norm a head, GQA 4/2, and generation by diffusion over blocks
+        of four (four passes, the published default rule, the last id
+        as the mask)."""
+        return MoEConfig(**{**dict(
+            vocab_size=vocab_size, dim=64, n_layers=2, n_heads=4,
+            n_kv_heads=2, head_dim=16, ffn_dim=32, max_seq_len=max_seq_len,
+            remat=False, rope_theta=1_000_000.0, norm_eps=1e-6,
+            num_experts=8, expert_top_k=2, norm_topk_prob=True,
+            qk_norm=True, qk_norm_per_head=True, block_length=4,
+            denoising_steps=4, mask_token_id=vocab_size - 1), **overrides})
+
 
 def moe_param_logical_axes(cfg: MoEConfig) -> Params:
     from ray_tpu.models.llama import param_logical_axes
@@ -206,7 +227,9 @@ def moe_param_logical_axes(cfg: MoEConfig) -> Params:
         layers["s_down"] = (None, "mlp", "embed_in")
     if cfg.leading_layers:
         axes["leading_layers"] = param_logical_axes(cfg)["layers"]
-    if cfg.qk_norm:
+    if cfg.qk_norm and cfg.qk_norm_per_head:
+        layers["q_norm"] = layers["k_norm"] = (None, None)
+    elif cfg.qk_norm:
         layers["q_norm"] = (None, "heads", None)
         layers["k_norm"] = (None, "kv_heads", None)
     axes["layers"] = layers
@@ -262,7 +285,10 @@ class MoEModel(LlamaModel):
             keys[2], (L, H, d, f), jnp.float32) * d ** -0.5
         layers["e_down"] = jax.random.normal(
             keys[3], (L, H, f, d), jnp.float32) * f ** -0.5
-        if cfg.qk_norm:
+        if cfg.qk_norm and cfg.qk_norm_per_head:
+            layers["q_norm"] = jnp.ones((L, cfg.head_dim), jnp.float32)
+            layers["k_norm"] = jnp.ones((L, cfg.head_dim), jnp.float32)
+        elif cfg.qk_norm:
             layers["q_norm"] = jnp.ones(
                 (L, cfg.n_heads, cfg.head_dim), jnp.float32)
             layers["k_norm"] = jnp.ones(
@@ -296,6 +322,8 @@ class MoEModel(LlamaModel):
             return q, k
 
         def norm(x, w):
+            if cfg.qk_norm_per_head:    # one RMS a head, w [hd]
+                return rms_norm(x, w, eps=cfg.norm_eps)
             # one RMS over every head's lanes: [B, T, H, hd] as [B, T, H*hd]
             flat = rms_norm(x.reshape(*x.shape[:2], -1), w.reshape(-1),
                             eps=cfg.norm_eps)

@@ -1032,3 +1032,77 @@ def test_ssm_cell_decode_program_fits_the_v5e(v5e, placed, monkeypatch):
     assert total < 11.0 * 2**30
     one_slots_state = 5 * 8 * 128 * 1024 * 4
     assert mem.temp_size_in_bytes < 4 * one_slots_state
+
+
+# sdar-30b-a3b-chat-d6.block_decode: 32 slots x 8,192 at block 32 (256
+# table entries a slot), a block of four rows a slot: the paged kernel's
+# head axis carries 4 x 32 query rows over 4 KV heads
+BLOCK_CELL_TABLE, BLOCK_CELL_ROWS, BLOCK_CELL_HEADS = 256, 4, (32, 4)
+
+
+def test_paged_decode_kernel_lowers_at_the_block_cells_rows(v5e):
+    """The kernel's signature stays ``[B, H, D]``: a block's rows ride
+    its head axis, 128 of them over 4 KV heads at 16 pages a chunk."""
+    from ray_tpu.ops.paged_attention import paged_decode_attention_pallas
+
+    B, (H, Hkv), D, bs = CELL_SLOTS, BLOCK_CELL_HEADS, 128, CELL_BS
+    maxb, rows = BLOCK_CELL_TABLE, BLOCK_CELL_ROWS
+    pool = v5e(B * maxb + 1, bs, Hkv, D)
+    assert _mosaic(paged_decode_attention_pallas.lower(
+        v5e(B, rows * H, D), pool, pool, v5e(B, maxb, dtype=jnp.int32),
+        v5e(B, dtype=jnp.int32), interpret=False))
+
+
+def test_sdar_cell_decode_program_fits_the_v5e(v5e, placed, monkeypatch):
+    """``sdar-30b-a3b-chat-d6.block_decode``'s decode program as the
+    engine jits it (``_decode_step_paged_blocks``: the model's four rows a
+    slot, the sampler, the unmask rule and the block's state machine):
+    32 slots x 8,192, depth 6, all 128 experts, the whole vocabulary,
+    matmul weights in bf16. The v5e's compiler takes it with the paged
+    kernel and the three grouped matmuls as Mosaic calls (1,024 rows over
+    128 experts) and no ``ragged_dot``, the pool written in place."""
+    from benchmark import run as harness
+    from benchmark.builders import sdar
+    from ray_tpu.llm.engine import ContinuousBatchingEngine
+
+    _as_on_the_chip(monkeypatch)
+    cfg = harness.load_json(harness.ROOT,
+                            "benchmark/configs/sdar-30b-a3b-chat-d6.json")
+    assert harness.load_json(harness.HERE, "traffic", "block_decode.json")[
+        "engine"] == {"max_slots": CELL_SLOTS,
+                      "max_seq": BLOCK_CELL_TABLE * CELL_BS,
+                      "block_size": CELL_BS, "max_ongoing_requests": 64}
+    B, bs, maxb, n, E = (CELL_SLOTS, CELL_BS, BLOCK_CELL_TABLE,
+                         BLOCK_CELL_ROWS, 128)
+    model = sdar.build_model(cfg, maxb * bs)
+    assert model.cfg.block_length == n
+    assert (model.cfg.n_heads, model.cfg.n_kv_heads) == BLOCK_CELL_HEADS
+    assert model.paged_decode_impl() == "pallas"
+    assert model.ffn_load_shape() == (6, E)
+    assert model.grouped_matmul_plan(B * n)["moe_grouped_impl"] \
+        == "pallas_gmm"
+    params = _engine_params(model)
+    assert sum(a.size for a in jax.tree.leaves(params)) == cfg["parameters"]
+    eng = object.__new__(ContinuousBatchingEngine)
+    eng.model, eng.num_blocks, eng.block_length = model, B * maxb, n
+    pool = jax.eval_shape(lambda: model.init_kv_pool(B * maxb + 1, bs))
+    compiled = jax.jit(eng._decode_step_paged_blocks,
+                       donate_argnums=(2,)).lower(
+        placed(params), v5e(B, 2 * n + 1, dtype=jnp.int32), placed(pool),
+        v5e(B, maxb, dtype=jnp.int32), v5e(B, dtype=jnp.int32),
+        *_sampling(v5e, B), v5e(6, E, dtype=jnp.int32),
+        v5e(4, dtype=jnp.int32)).compile()
+    text = compiled.as_text()
+    assert text.count("paged_decode_attention_pallas") >= 1
+    assert "ragged_dot_tiling" not in text and "ragged-dot" not in text
+    mem = compiled.memory_analysis()
+    total = (mem.argument_size_in_bytes + mem.temp_size_in_bytes
+             + mem.output_size_in_bytes - mem.alias_size_in_bytes)
+    print("sdar cell fit GiB:", {
+        "arguments": mem.argument_size_in_bytes / 2**30,
+        "temporaries": mem.temp_size_in_bytes / 2**30,
+        "total": total / 2**30})
+    assert 11.0 * 2**30 < total < 11.6 * 2**30
+    # the pool is written in place and no expert stack is copied out
+    one_stack_a_layer = E * 2048 * 768 * 2
+    assert mem.temp_size_in_bytes < one_stack_a_layer

@@ -1,0 +1,262 @@
+"""Generation by diffusion over blocks through ``ContinuousBatchingEngine``,
+end to end against the reference's ``generate`` (``benchmark/reference/
+sdar.py``: the published procedure as a Python loop with no cache): the
+same tokens in the same order, each placed by the same pass of its block,
+whatever the prompt leaves of its last block, wherever ``max_tokens`` or
+a stop token cuts, with slots at different phases of their blocks, a step
+run ahead or not, a preemption in mid-block, a prefix hit. Float32
+compute on seeded weights at debug widths."""
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from benchmark.reference import sdar as reference
+from ray_tpu.llm.engine import ContinuousBatchingEngine, SamplingParams
+from ray_tpu.llm.serving import LLMConfig, LLMServer
+from tests.test_sdar_serving import make, ref_kw, ref_params
+
+N = 4
+
+
+def prompt_of(cfg, n, seed):
+    return [int(t) for t in np.random.default_rng(seed).integers(
+        1, cfg.mask_token_id, n)]
+
+
+def want_of(cfg, params, prompt, n_tokens, **kw):
+    with jax.default_matmul_precision("highest"):
+        return reference.generate(
+            ref_params(params), prompt, n_tokens, block_length=N,
+            denoising_steps=cfg.denoising_steps, mask_id=cfg.mask_token_id,
+            remasking=cfg.remasking,
+            confidence_threshold=cfg.confidence_threshold, **ref_kw(cfg),
+            **kw)
+
+
+def engine_of(model, params, **kw):
+    kw = {**dict(max_slots=4, max_seq=64, prefill_buckets=(8, 16, 32),
+                 block_size=8), **kw}
+    return ContinuousBatchingEngine(model, params, **kw)
+
+
+def run(eng, prompts, sampling):
+    with jax.default_matmul_precision("highest"):
+        return eng.generate(prompts, sampling)
+
+
+CASES = {
+    # P mod 4 = 0, 1, 3 (the prompt's last partial block decodes with the
+    # first generated one), max_tokens no multiple of 4
+    "ragged_prompts": ((8, 9, 11), 10, {}),
+    # more requests than slots: slots stand at different phases of their
+    # blocks, and a freed slot's next tenant starts on a block of its own
+    "slots_at_different_phases": ((8, 13, 6, 21, 10, 3), 7,
+                                  {"max_slots": 2}),
+    "chunked_prefill": ((40, 9), 6, {}),
+    # the published default is the dynamic rule; the static one beside it
+    "static_rule": ((8, 10), 9, {"remasking": "low_confidence_static"}),
+    "two_passes_a_block": ((8, 10), 9, {"denoising_steps": 2}),
+}
+
+
+@pytest.mark.parametrize("case", sorted(CASES))
+def test_engine_generates_what_the_reference_generates(case):
+    lens, n_out, kw = CASES[case]
+    model_kw = {k: v for k, v in kw.items() if k != "max_slots"}
+    cfg, model, params = make(**model_kw)
+    eng = engine_of(model, params,
+                    **{k: v for k, v in kw.items() if k == "max_slots"})
+    prompts = [prompt_of(cfg, n, i) for i, n in enumerate(lens)]
+    reqs = run(eng, prompts, SamplingParams(max_tokens=n_out))
+    for prompt, req in zip(prompts, reqs):
+        tokens, passes = want_of(cfg, params, prompt, n_out)
+        assert req.output == tokens and req.unmasked_at == passes
+        assert req.finish_reason == "length"
+        # in order, none retracted: the stream is the output
+        streamed = []
+        while True:
+            tok, _ = req.stream.get_nowait()
+            if tok is None:
+                break
+            streamed.append(tok)
+        assert streamed == tokens
+    stats = eng.stats
+    assert stats["block_length"] == N
+    assert stats["tokens_generated"] == n_out * len(prompts)
+    assert stats["moe_assignments"] == stats["moe_assignments_expected"] > 0
+    assert stats["block_commit_passes"] == stats["blocks_committed"] > 0
+    assert stats["decode_steps"] <= stats["block_slot_passes"]
+    # every placed token was counted by the pass that placed it (a block
+    # that ``max_tokens`` cut was placed whole)
+    assert stats["block_tokens_unmasked"] >= stats["tokens_generated"]
+    assert eng.pool.num_free == eng.num_blocks
+
+
+def test_seeded_weights_place_the_quota_alone_and_a_sure_model_more():
+    """On seeded weights no confidence passes 0.9: one token a denoising
+    pass, a commit in five. With the threshold under every confidence a
+    block is done in one denoising pass, and the counter that tells the
+    threshold's work from the quota's says so."""
+    cfg, model, params = make()
+    eng = engine_of(model, params)
+    run(eng, [prompt_of(cfg, 8, 0)], SamplingParams(max_tokens=8))
+    stats = eng.stats
+    assert stats["block_tokens_unmasked_by_confidence"] == 0
+    assert stats["block_slot_passes"] == 10
+    assert stats["block_commit_passes"] == 2
+    assert stats["block_tokens_unmasked"] == 8
+
+    cfg, model, params = make(confidence_threshold=0.0)
+    eng = engine_of(model, params)
+    prompt = prompt_of(cfg, 8, 0)
+    req, = run(eng, [prompt], SamplingParams(max_tokens=8))
+    assert (req.output, req.unmasked_at) == want_of(cfg, params, prompt, 8)
+    assert set(req.unmasked_at) == {1}
+    stats = eng.stats
+    assert stats["block_slot_passes"] == 4          # 2 x (denoise + commit)
+    assert stats["block_tokens_unmasked_by_confidence"] == 6
+
+
+def test_a_stop_token_inside_a_block_cuts_the_block_there():
+    cfg, model, params = make()
+    prompt = prompt_of(cfg, 9, 3)
+    tokens, _ = want_of(cfg, params, prompt, 12)
+    stop = tokens[5]                    # the sixth token: inside a block
+    first = tokens.index(stop)
+    eng = engine_of(model, params)
+    req, = run(eng, [prompt], SamplingParams(max_tokens=12,
+                                             stop_token_ids=(stop,)))
+    assert req.finish_reason == "stop"
+    assert req.output == tokens[:first + 1]
+    assert want_of(cfg, params, prompt, 12,
+                   stop_token_ids=(stop,))[0] == req.output
+
+
+@pytest.mark.parametrize("full", [True, False])
+def test_a_pass_runs_ahead_only_where_no_request_can_end_within_it(full):
+    """Every slot taken, no stop token, room for two more blocks: the
+    pass after the one in flight is dispatched before that one is read,
+    and the tokens are the reference's all the same. With a slot free
+    (or a request within a block of its end) it is not."""
+    cfg, model, params = make()
+    prompts = [prompt_of(cfg, n, i) for i, n in enumerate((8, 11))]
+    eng = engine_of(model, params, max_slots=2 if full else 3)
+    reqs = [eng.submit(p, SamplingParams(max_tokens=12)) for p in prompts]
+    flying = []
+    with jax.default_matmul_precision("highest"):
+        while eng.has_work():
+            eng.step()
+            flying.append(eng._in_flight is not None)
+            if eng._in_flight is not None:
+                # the pass just read, with this one queued behind it,
+                # ended no request
+                assert all(len(r.output) < 12 for r in reqs)
+    assert any(flying) == full
+    assert eng._in_flight is None
+    for prompt, req in zip(prompts, reqs):
+        assert (req.output, req.unmasked_at) == want_of(cfg, params,
+                                                        prompt, 12)
+
+
+def test_a_preemption_in_mid_block_redoes_the_block():
+    """A pool too small for three long generations: the youngest slot is
+    preempted with a block in flight; its committed tokens fold into its
+    context, the block is dropped and redone, and every request still
+    reads the reference's tokens."""
+    cfg, model, params = make()
+    prompts = [prompt_of(cfg, n, i) for i, n in enumerate((20, 21, 22))]
+    eng = engine_of(model, params, num_blocks=10)
+    reqs = run(eng, prompts, SamplingParams(max_tokens=12))
+    assert eng.stats["preemptions"] > 0
+    assert any(r.preemptions for r in reqs)
+    for prompt, req in zip(prompts, reqs):
+        assert (req.output, req.unmasked_at) == want_of(cfg, params,
+                                                        prompt, 12)
+    assert eng.pool.num_free == eng.num_blocks
+
+
+def test_a_prefix_hit_of_whole_pages_generates_what_a_cold_prefill_does():
+    """A page of 8 rows holds two whole blocks, so its K/V depend on
+    nothing behind it: the second request takes the first's two pages
+    from the index and reads the reference's tokens all the same."""
+    cfg, model, params = make()
+    head = prompt_of(cfg, 16, 50)
+    prompts = [head + prompt_of(cfg, n, i) for i, n in enumerate((3, 7))]
+    eng = engine_of(model, params)
+    reqs = [run(eng, [p], SamplingParams(max_tokens=6))[0] for p in prompts]
+    assert eng.stats["prefix_prefills"] == 1
+    assert eng.stats["prefix_tokens_reused"] == 16
+    for prompt, req in zip(prompts, reqs):
+        assert (req.output, req.unmasked_at) == want_of(cfg, params,
+                                                        prompt, 6)
+
+
+def test_a_page_that_would_split_a_block_is_refused_by_the_engine():
+    cfg, model, params = make()
+    with pytest.raises(ValueError, match="block_length"):
+        ContinuousBatchingEngine(model, params, max_slots=2, max_seq=36,
+                                 prefill_buckets=(6, 12), block_size=6)
+    # the default page of 32 rows holds eight whole blocks
+    assert 32 % cfg.block_length == 0
+
+
+def test_live_blocks_are_counted_up_to_the_blocks_end():
+    cfg, model, params = make()
+    eng = engine_of(model, params)
+    run(eng, [prompt_of(cfg, 8, 0)], SamplingParams(max_tokens=4))
+    stats = eng.stats
+    # five passes over rows 8..11 behind 8 cached ones: ceil(12 / 8) pages
+    assert stats["decode_steps"] == 5
+    assert stats["decode_kv_blocks_live"] == 5 * 2
+    assert stats["decode_kv_blocks_table"] == 5 * eng.blocks_per_slot
+
+
+def test_the_handoff_of_a_prefill_is_refused():
+    cfg, model, params = make()
+    eng = engine_of(model, params)
+    with pytest.raises(NotImplementedError, match="samples none"):
+        eng.prefill_only(prompt_of(cfg, 8, 0))
+
+
+def test_llm_server_streams_each_token_with_the_pass_that_placed_it():
+    """Through ``LLMServer``: the generation kind is the model's
+    configuration's, read by the engine; a streamed chunk carries the
+    ``pass`` beside ``index``, a one-token model's does not."""
+    cfg = make()[0]
+    server = LLMServer(LLMConfig(model_config=cfg, max_slots=2, max_seq=64,
+                                 block_size=8))
+    try:
+        assert server.engine.block_length == N
+        prompt = prompt_of(cfg, 9, 1)
+        chunks = list(server.stream({"prompt": prompt, "max_tokens": 7,
+                                     "stream": True}))
+        chunks = [c for run_ in chunks
+                  for c in (run_ if isinstance(run_, list) else [run_])]
+        toks = [c for c in chunks if "token_id" in c]
+        assert [c["index"] for c in toks] == list(range(7))
+        assert all(1 <= c["pass"] <= cfg.denoising_steps for c in toks)
+        assert chunks[-1]["done"] and chunks[-1]["finish_reason"] == "length"
+        out = server({"prompt": prompt, "max_tokens": 7})
+        assert out["token_ids"] == [c["token_id"] for c in toks]
+        assert server.stats()["blocks_committed"] >= 4
+    finally:
+        server._stop.set()
+        server._thread.join(10)
+
+
+def test_a_one_token_model_has_no_pass_and_zero_block_counters():
+    from ray_tpu.models import MoEConfig, model_for
+    cfg = MoEConfig.debug_olmoe(dtype=jnp.float32)
+    model = model_for(cfg)
+    eng = ContinuousBatchingEngine(
+        model, model.init(jax.random.key(0)), max_slots=2, max_seq=64,
+        prefill_buckets=(8, 16), block_size=8)
+    req, = eng.generate([[1, 2, 3]], SamplingParams(max_tokens=3))
+    assert req.unmasked_at is None and len(req.output) == 3
+    stats = eng.stats
+    assert stats["block_length"] == 1
+    assert all(stats[k] == 0 for k in (
+        "block_slot_passes", "block_commit_passes", "block_tokens_unmasked",
+        "block_tokens_unmasked_by_confidence", "blocks_committed"))
