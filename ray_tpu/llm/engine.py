@@ -57,7 +57,9 @@ is the in-tree TPU-native equivalent (BASELINE.md config 5):
   block_length`` > 1) is served by the same loop: a step is a PASS over
   every slot's current block, whose yield is a count a slot (0 or a
   whole block), decided on the device by the pass's own sampler and
-  unmask rule; a prefill samples nothing and hands the loop a first
+  unmask rule; the block a slot has just finished rides the next
+  block's first pass, clean, and leaves its K/V rows there (no pass
+  places no token); a prefill samples nothing and hands the loop a first
   block (docs/serving.md, "Generation by diffusion over blocks"). The
   one-token models are the case of a block of one, and their programs
   are the ones they were;
@@ -424,18 +426,27 @@ class ContinuousBatchingEngine:
         self._last_tokens = np.zeros(max_slots, np.int32)
         if self.block_length > 1:
             # a block-diffusion model's step input is each slot's BLOCK
-            # STATE [2n + 1]: the block's tokens (the mask id where
+            # STATE [3n + 2]: the block's tokens (the mask id where
             # nothing stands yet), the pass that placed each, the passes
-            # the block has had; the program hands the next one on, the
-            # host's copy follows with every read-back. ``_given``: how
-            # many of a slot's first block the prompt gave
+            # the block has had; then the block BEHIND it and whether its
+            # rows are still owed (``_decode_step_paged_blocks``); the
+            # program hands the next one on, the host's copy follows
+            # with every read-back. ``_given``: how many of a slot's
+            # first block the prompt gave
             n = self.block_length
-            self._last_tokens = np.zeros((max_slots, 2 * n + 1), np.int32)
+            self._last_tokens = np.zeros((max_slots, 3 * n + 2), np.int32)
             self._last_tokens[:, :n] = model.cfg.mask_token_id
             self._given = np.zeros(max_slots, np.int32)
-            # [slot-passes, of them commits, tokens placed, of them by
-            # the threshold], summed by the program, read by ``stats``
-            self._block_counts = jnp.zeros(4, jnp.int32)
+            # [slot-passes, of them commits that stood alone, tokens
+            # placed, of them by the threshold, commits that rode a
+            # denoising pass], summed by the program, read by ``stats``
+            self._block_counts = jnp.zeros(5, jnp.int32)
+            # blocks behind a pass has room for: HALF the slots'. At
+            # ``denoising_steps`` passes a block one slot in so many
+            # owes a commit in a pass, at two passes a block every
+            # other; every row of room is a block's rows through every
+            # layer whether a slot takes it or not (PERF.md, PR 56)
+            self._behind_slots = -(-max_slots // 2)
         self._dev_tokens = None
         self._dev_tables = None
         self._dev_offsets = None            # sent a step ahead, see there
@@ -477,6 +488,9 @@ class ContinuousBatchingEngine:
         # no step gains a transfer. ``_ffn_counts`` pairs that array
         # [layers, experts] with the host's count of what a dropless FFN
         # must process; the loop replaces the pair after each dispatch.
+        # (A block-diffusion model's holds, third, the same program's
+        # ``_block_counts``: the blocks that rode behind are rows too,
+        # and only the program knows how many they were.)
         load_shape = model.ffn_load_shape()
         if load_shape is None:
             self._ffn_counts = None
@@ -530,18 +544,23 @@ class ContinuousBatchingEngine:
                       "decode_steps_sampled": 0, "decode_steps_topk": 0,
                       # generation by diffusion over blocks (1 and zeros
                       # for every other model): a block's positions; live
-                      # slots x passes, and of those the passes that
-                      # COMMITTED a block (ran it clean and kept its
-                      # rows); tokens the denoising passes placed, and
-                      # of those the ones the confidence threshold let
-                      # through where the pass's quota alone would not
-                      # have (all four summed by the program, read when
-                      # ``stats`` is asked); blocks whose tokens the host
-                      # has handed out. ``decode_steps`` counts passes
+                      # slots x passes; of those the passes that ran a
+                      # finished block clean to keep its rows (its
+                      # COMMIT) ALONE and so placed nothing (more slots
+                      # owed one than the pass had room for); tokens the
+                      # denoising passes placed, and of those the ones
+                      # the confidence threshold let through where the
+                      # pass's quota alone would not have; slot-passes
+                      # that committed the block behind WHILE denoising
+                      # the next (all five summed by the program, read
+                      # when ``stats`` is asked); blocks whose tokens
+                      # the host has handed out. ``decode_steps`` counts
+                      # passes
                       "block_length": self.block_length,
                       "block_slot_passes": 0, "block_commit_passes": 0,
                       "block_tokens_unmasked": 0,
                       "block_tokens_unmasked_by_confidence": 0,
+                      "block_commits_fused": 0,
                       "blocks_committed": 0,
                       "prefills": 0,
                       "prefix_prefills": 0, "prefix_tokens_reused": 0,
@@ -641,9 +660,13 @@ class ContinuousBatchingEngine:
                       # what implements the decode step's three grouped
                       # matmuls ("pallas_gmm" / "ragged_dot") and their
                       # (rows, k, n) tilings, as the model resolves them
-                      # from the platform and the step's shapes
+                      # from the platform and the step's shapes (a
+                      # block-diffusion model's pass: the slots' blocks
+                      # and the blocks behind it has room for)
                       **model.grouped_matmul_plan(
-                          max_slots * self.block_length),
+                          max_slots * self.block_length
+                          + (self._behind_slots * self.block_length
+                             if self.block_length > 1 else 0)),
                       # the router of an expert model's FFN ("softmax" /
                       # "sigmoid"; "" for a dense model)
                       "moe_router_kind": getattr(model.cfg, "router_kind",
@@ -686,8 +709,14 @@ class ContinuousBatchingEngine:
         the stream readers' cells."""
         self._stats.update(self._readers.sums())
         if self._ffn_counts is not None:
-            load, expected = self._ffn_counts       # one pair, one step
+            # one tuple, one step
+            load, expected, *block_counts = self._ffn_counts
             load = np.asarray(load)
+            if block_counts:
+                # a block that rode behind ran its rows through the FFN
+                # beside the slot's own
+                expected += (int(np.asarray(block_counts[0])[4])
+                             * self._ffn_rows_per_slot)
             first, n_held = self.model.cfg.held
             self._stats.update(
                 moe_assignments=int(load.sum()),
@@ -699,7 +728,8 @@ class ContinuousBatchingEngine:
             self._stats.update(zip(
                 ("block_slot_passes", "block_commit_passes",
                  "block_tokens_unmasked",
-                 "block_tokens_unmasked_by_confidence"),
+                 "block_tokens_unmasked_by_confidence",
+                 "block_commits_fused"),
                 map(int, np.asarray(self._block_counts))))
         # last: the read above may have waited for a program in flight
         self._stats["t_now_s"] = time.perf_counter()
@@ -740,34 +770,62 @@ class ContinuousBatchingEngine:
                                   offsets, temps, top_ks, key, ffn_load,
                                   counts):
         """One PASS over every slot's block as ONE program (a model with
-        ``block_length`` n > 1): the model's n rows a slot, the sampler,
-        the unmask rule and the block's state machine, none of which
-        leaves the device. ``state`` [B, 2n + 1]: the block's tokens,
-        the pass that placed each (0: the prompt's), the denoising
-        passes it has had. A block with a mask left is DENOISED: the
-        pass's proposals that the rule keeps go in, and its K/V rows,
-        computed from masked inputs, are overwritten by the next pass.
-        A block with none left is COMMITTED: this pass ran it clean, so
-        its rows stay, the slot's offset moves on by n and its next
-        block starts all masked. Slots stand at different phases of
-        their blocks in one call; an idle slot (its table points at the
-        scratch block) keeps what it has.
+        ``block_length`` n > 1): the model's rows, the sampler, the
+        unmask rule and the block's state machine, none of which leaves
+        the device. ``state`` [B, 3n + 2]: the block's tokens, the pass
+        that placed each (0: the prompt's), the denoising passes it has
+        had; then the block BEHIND it (at ``offset - n``), clean, and
+        whether its K/V rows are still OWED. A block with a mask left is
+        DENOISED: the pass's proposals that the rule keeps go in, and
+        its K/V rows, computed from masked inputs, are overwritten by
+        the next pass. The pass that leaves it with none FINISHES it:
+        its tokens are the host's to hand out, the slot's offset moves
+        on by n, the next block starts all masked and the finished one
+        stands behind it, owed. The pass after that carries both (the
+        model's ``behind``): the block behind runs clean once more,
+        beside the next block's first denoising pass, and its rows stay
+        (its COMMIT): a pass places tokens in every slot it runs.
+
+        The call has room for ``_behind_slots`` blocks behind (the owed
+        slots' first so many, compacted). A slot that owes beyond them
+        commits ALONE: its own rows of this pass are the block behind's,
+        nothing is denoised and nothing placed, and its next pass starts
+        the next block with nothing owed, as a slot that found room
+        does. A block that ENTERS clean (none does, as the host starts
+        them) is finished by the pass that finds it so. Slots stand at
+        different phases of their blocks in one call; an idle slot (its
+        table points at the scratch block) keeps what it has and is
+        owed nothing.
 
         Returns the next state, the pool, the next offsets, the next
         key, the expert load, ``counts`` with this pass's added, and
-        what the host reads, [B, 4n + 2]: the block as it ENTERED the
-        pass with its passes and a flag (on a commit: the tokens to
-        hand out), then the next state (the host's copy of it)."""
+        what the host reads, [B, 4n + 3]: the pass that placed each of
+        the block's tokens as the pass LEFT it, a flag (the block was
+        finished: its tokens are the next state's block behind), then
+        the next state (the host's copy of it)."""
         from ray_tpu.ops.block_diffusion import (confidence,
                                                  transfer_quotas,
                                                  unmask_step)
         cfg = self.model.cfg
         n, mask_id = self.block_length, cfg.mask_token_id
+        B, room = state.shape[0], self._behind_slots
         block, placed_at, passes = (state[:, :n], state[:, n:2 * n],
                                     state[:, 2 * n])
         live = block_tables[:, 0] != self.num_blocks
+        behind = state[:, 2 * n + 1:3 * n + 1]
+        owed = live & (state[:, 3 * n + 1] != 0)
+        with jax.named_scope("blockdiff_commit"):
+            # the owed slots that find room, in the rows they find it in
+            rank = jnp.cumsum(owed) - 1
+            fused = owed & (rank < room)
+            alone = owed & ~fused
+            rows = jnp.zeros(room, jnp.int32).at[
+                jnp.where(fused, rank, room)].set(jnp.arange(B), mode="drop")
+            taken = jnp.arange(room) < jnp.sum(fused)
         logits, pool, extras = self.model.block_step_paged_counted(
-            params, block, pool, block_tables, offsets, live)
+            params, jnp.where(alone[:, None], behind, block), pool,
+            block_tables, offsets - n * alone, live,
+            (behind[rows], rows, taken))
         if ffn_load is not None:
             ffn_load = ffn_load + extras["load"]
         with jax.named_scope("blockdiff_unmask"):
@@ -783,23 +841,26 @@ class ContinuousBatchingEngine:
                 threshold=cfg.confidence_threshold,
                 dynamic=cfg.remasking == "low_confidence_dynamic")
         with jax.named_scope("blockdiff_commit"):
-            commit = live & ~jnp.any(block == mask_id, axis=-1)
-            denoise = (live & ~commit)[:, None]
-            placed &= denoise
-            next_block = jnp.where(commit[:, None], mask_id,
-                                   jnp.where(denoise, denoised, block))
-            next_at = jnp.where(commit[:, None], 0, jnp.where(
-                placed, passes[:, None] + 1, placed_at))
-            next_passes = jnp.where(commit, 0, passes + denoise[:, 0])
-            state = jnp.concatenate(
-                [next_block, next_at, next_passes[:, None]], axis=-1)
+            runs = live & ~alone
+            denoise = runs & jnp.any(block == mask_id, axis=-1)
+            placed &= denoise[:, None]
+            block = jnp.where(denoise[:, None], denoised, block)
+            placed_at = jnp.where(placed, passes[:, None] + 1, placed_at)
+            done = runs & ~jnp.any(block == mask_id, axis=-1)
+            state = jnp.concatenate([
+                jnp.where(done[:, None], mask_id, block),
+                jnp.where(done[:, None], 0, placed_at),
+                jnp.where(done, 0, passes + denoise)[:, None],
+                jnp.where(done[:, None], block, behind),
+                done[:, None].astype(state.dtype)], axis=-1)
             counts = counts + jnp.stack([
-                jnp.sum(live), jnp.sum(commit), jnp.sum(placed),
-                jnp.sum(placed & by_confidence)]).astype(counts.dtype)
+                jnp.sum(live), jnp.sum(alone), jnp.sum(placed),
+                jnp.sum(placed & by_confidence),
+                jnp.sum(fused)]).astype(counts.dtype)
             report = jnp.concatenate(
-                [block, placed_at, commit[:, None].astype(state.dtype),
-                 state], axis=-1)
-        return (state, pool, offsets + n * commit, key, ffn_load, counts,
+                [placed_at, done[:, None].astype(state.dtype), state],
+                axis=-1)
+        return (state, pool, offsets + n * done, key, ffn_load, counts,
                 report)
 
     def _decode_step_paged(self, params, tokens, pool, block_tables, offsets,
@@ -1441,7 +1502,7 @@ class ContinuousBatchingEngine:
         n = self.block_length
         given = context[n_cached:]
         self._given[slot] = len(given)
-        self._last_tokens[slot] = 0
+        self._last_tokens[slot] = 0     # (and nothing behind it is owed)
         self._last_tokens[slot, :n] = self.model.cfg.mask_token_id
         self._last_tokens[slot, :len(given)] = given
 
@@ -1589,8 +1650,11 @@ class ContinuousBatchingEngine:
         ends no request (none stops on a token's VALUE, none reaches
         its length), and every slot has room for one more token without
         a preemption. Where a step yields up to a BLOCK a slot, "one
-        more token" reads "one more block" throughout: the step in
-        flight may commit one, and the step ahead then writes the next."""
+        more token" reads "one more block" throughout: the pass in
+        flight may finish one (at ``offset``), and the pass ahead then
+        writes that block's rows to stay and, at ``offset + n``, the
+        next block's: ``offset + 2n`` rows of room, and no request that
+        the block in flight can end."""
         n = self.block_length
         if (len(active) < self.max_slots or self.waiting
                 or self._dev_tokens is None or self._dev_offsets is None):
@@ -1651,16 +1715,17 @@ class ContinuousBatchingEngine:
                                         bool((top_ks > 0).any()))
         # dispatch only: the call returns before the device is done
         with _Phase(self, "engine.decode_enqueue", "t_enqueue_s"):
-            load, expected = self._ffn_counts or (None, 0)
+            load, expected, *block_counts = self._ffn_counts or (None, 0)
             if self.block_length > 1:
                 # the pass hands on its own next state and offsets (a
-                # slot's offset moves when the DEVICE finds its block
-                # clean) and, apart, what the host reads of it
+                # slot's offset moves when the DEVICE finishes its
+                # block) and, apart, what the host reads of it
                 (self._dev_tokens, self.kv, self._dev_offsets, self._rng_key,
                  load, self._block_counts, report) = self._decode(
                     self.params, self._dev_tokens, self.kv, self._dev_tables,
                     self._dev_offsets, *self._dev_sampling, self._rng_key,
                     load, self._block_counts)
+                block_counts = [self._block_counts]
             else:
                 self._dev_tokens, self.kv, self._rng_key, load = self._decode(
                     self.params, self._dev_tokens, self.kv, self._dev_tables,
@@ -1668,7 +1733,8 @@ class ContinuousBatchingEngine:
                     load)
             if load is not None:
                 self._ffn_counts = (
-                    load, expected + len(active) * self._ffn_rows_per_slot)
+                    load, expected + len(active) * self._ffn_rows_per_slot,
+                    *block_counts)
             self._enqueued()
         self._stats["decode_steps_sampled"] += self._sampling_asked[0]
         self._stats["decode_steps_topk"] += self._sampling_asked[1]
@@ -1754,23 +1820,26 @@ class ContinuousBatchingEngine:
 
     def _emit_blocks(self, active: List[int], report: np.ndarray) -> None:
         """Book one pass of a block-diffusion model, ``report`` [B, 4n +
-        2] as ``_decode_step_paged_blocks`` hands it back: the host's
+        3] as ``_decode_step_paged_blocks`` hands it back: the host's
         copy of the slots' state follows the device's; a slot whose
-        block was COMMITTED moves on by a block and its tokens are
+        block the pass FINISHED moves on by a block and its tokens are
         handed out, in sequence order, never to be taken back (all but
         those the prompt gave of it), each with the pass that placed it.
+        (Its K/V rows come with the slot's next pass: a request that
+        ends here leaves them owed, and nobody reads them.)
         ``max_tokens`` and a stop token cut the block where they fall."""
         n, bs = self.block_length, self.block_size
-        # what this pass's attention read: each slot's rows up to its
-        # block's end, of what its table holds
+        # what this pass's attention had to read: each slot's rows up to
+        # its block's end, of what its table holds (the rows a block
+        # behind read over again are the implementation's, not counted)
         self._stats["decode_kv_blocks_table"] += (
             len(active) * self.blocks_per_slot)
         self._stats["decode_kv_blocks_live"] += int(
             ((self.offsets[active] + n + bs - 1) // bs).sum())
-        self._last_tokens[:] = report[:, 2 * n + 1:]
+        self._last_tokens[:] = report[:, n + 1:]
         now = None
         for i in active:
-            if not report[i, 2 * n]:
+            if not report[i, n]:
                 continue
             self.offsets[i] += n
             self._stats["blocks_committed"] += 1
@@ -1778,8 +1847,9 @@ class ContinuousBatchingEngine:
             given, self._given[i] = int(self._given[i]), 0
             if req.first_token_at is None:
                 req.first_token_at = now = now or time.perf_counter()
-            for tok, at in zip(report[i, given:n].tolist(),
-                               report[i, n + given:2 * n].tolist()):
+            finished = self._last_tokens[i, 2 * n + 1:3 * n + 1]
+            for tok, at in zip(finished[given:].tolist(),
+                               report[i, given:n].tolist()):
                 req.output.append(tok)
                 req.unmasked_at.append(at)
                 self._undelivered.append((req, tok))

@@ -1216,25 +1216,43 @@ class LlamaModel:
     def block_step_paged_counted(self, params: Params, tokens: jax.Array,
                                  pool: Params, block_tables: jax.Array,
                                  offsets: jax.Array,
-                                 live: Optional[jax.Array] = None):
+                                 live: Optional[jax.Array] = None,
+                                 behind: Optional[Tuple[jax.Array, ...]]
+                                 = None):
         """One pass over every slot's CURRENT BLOCK against the block
         pool (a model with ``block_length`` > 1): tokens [B, n] at
         positions ``offsets .. offsets + n - 1`` (``offsets`` [B]
         multiples of n; the page size is one too, so a block lies in ONE
         page). The block's K/V rows are written there, over whatever
-        the pass before left (a denoise pass's rows stay only until the
-        next pass; the commit pass's, computed from the clean block,
-        are the ones later blocks read), and every one of its n x H
-        query rows attends the slot's ``offsets + n`` rows: the whole
-        block and all before it. -> (logits [B, n, V] of the block's
-        OWN positions, the pool, the layers' ``_ffn`` extras), the pool
-        carried whole and written in place as
-        ``decode_step_paged_counted`` carries it.
+        the pass before left (a denoising pass's rows stay only until
+        the next pass; those of a pass over the CLEAN block are the ones
+        later blocks read), and every one of its n x H query rows
+        attends the slot's ``offsets + n`` rows: the whole block and all
+        before it. -> (logits [B, n, V] of the block's OWN positions,
+        the pool, the layers' ``_ffn`` extras), the pool carried whole
+        and written in place as ``decode_step_paged_counted`` carries it.
+
+        ``behind`` = (tokens [C, n], slots [C] int32, wanted [C] bool),
+        C <= B: the same pass carries, for the slots that ``slots`` names
+        and ``wanted`` marks, THE BLOCK BEHIND as well, clean, at
+        ``offsets - n .. offsets - 1``: its rows are written there to
+        stay (its commit) and its query rows see the rows before
+        ``offsets``, their own among them, so the slot's current block
+        reads the block behind as this very pass wrote it. The call then
+        runs C + B rows of n, the blocks behind first: through the
+        projections, the norms and the FFN as one batch, through the
+        paged attention as C + B grid rows of ONE call over the slots'
+        tables (``offsets`` rows for a block behind, ``offsets + n`` for
+        a current one). A row not ``wanted`` is DEAD: it writes nothing,
+        sees nothing (length 0), counts for nothing in ``live`` and is
+        dropped with every other row behind before the head, which runs
+        on the B current blocks alone. The extras are the C + B rows'.
+        Without ``behind`` this is the program it was.
 
         The paged attention is the decode step's, kernel and reference
         alike: its head axis carries the block's rows (q [B, n, H, hd]
         laid KV-head-major as [B, Hkv * n * H/Hkv, hd]: all rows of a
-        slot see one length)."""
+        slot's block see one length)."""
         cfg = self.cfg
         if type(self)._attend_pages is not LlamaModel._attend_pages:
             raise NotImplementedError(
@@ -1249,10 +1267,32 @@ class LlamaModel:
         def whole(a):
             return a.reshape((L * NB,) + a.shape[2:])
 
-        dest_block = jnp.take_along_axis(
-            block_tables, (offsets // bs)[:, None], axis=-1)       # [B, 1]
-        dest_off = (offsets % bs)[:, None] + jnp.arange(n)[None, :]  # [B, n]
         lengths = offsets + n
+        # a scatter's default; "drop" where dead rows point past the stack
+        mode = None
+        if behind is not None:
+            tokens_behind, slots, wanted = behind
+
+            def both(a, b):
+                return jnp.concatenate([a, b])
+
+            tokens = both(tokens_behind, tokens)
+            lengths = both(jnp.where(wanted, offsets[slots], 0), lengths)
+            # (a dead row's positions: anywhere RoPE has an angle for)
+            offsets = both(jnp.maximum(offsets[slots] - n, 0), offsets)
+            block_tables = both(block_tables[slots], block_tables)
+            every = jnp.ones((B,), bool)
+            live = both(wanted if live is None else wanted & live[slots],
+                        every if live is None else live)
+            mode = "drop"
+        R = tokens.shape[0]
+        # a block and the block behind it may lie in different pages
+        dest_block = jnp.take_along_axis(
+            block_tables, (offsets // bs)[:, None], axis=-1)       # [R, 1]
+        if behind is not None:
+            dest_block = jnp.where(both(wanted, every)[:, None], dest_block,
+                                   L * NB)
+        dest_off = (offsets % bs)[:, None] + jnp.arange(n)[None, :]  # [R, n]
         q_pos = offsets[:, None] + jnp.arange(n)[None, :]
         impl = self.paged_decode_impl()
         H, Hkv = cfg.n_heads, cfg.n_kv_heads
@@ -1263,17 +1303,19 @@ class LlamaModel:
 
             def attend(q, k_new, v_new):
                 with jax.named_scope("blockdiff_kv_update"):
-                    k_all = k_pool.at[base + dest_block, dest_off].set(k_new)
-                    v_all = v_pool.at[base + dest_block, dest_off].set(v_new)
+                    k_all = k_pool.at[base + dest_block, dest_off].set(
+                        k_new, mode=mode)
+                    v_all = v_pool.at[base + dest_block, dest_off].set(
+                        v_new, mode=mode)
                 with jax.named_scope("blockdiff_attention"):
-                    rows = q.reshape(B, n, Hkv, H // Hkv, -1).transpose(
-                        0, 2, 1, 3, 4).reshape(B, n * H, -1)
+                    rows = q.reshape(R, n, Hkv, H // Hkv, -1).transpose(
+                        0, 2, 1, 3, 4).reshape(R, n * H, -1)
                     o = self._attend_pages(
                         rows, k_all, v_all, layer, block_tables, lengths,
                         impl=impl, starts=None, first_block=base,
                         num_blocks=NB)
-                    o = o.reshape(B, Hkv, n, H // Hkv, -1).transpose(
-                        0, 2, 1, 3, 4).reshape(B, n, H, -1)
+                    o = o.reshape(R, Hkv, n, H // Hkv, -1).transpose(
+                        0, 2, 1, 3, 4).reshape(R, n, H, -1)
                 return o, (k_all, v_all)
 
             x, (k_pool, v_pool), extra = self._layer(
@@ -1288,7 +1330,8 @@ class LlamaModel:
             params, main, (jnp.arange(L, dtype=jnp.int32) * NB,))
         pool = dict(pool, k=k_out.reshape(pool["k"].shape),
                     v=v_out.reshape(pool["v"].shape))
-        return self._head(params, x), pool, extras
+        return (self._head(params, x if behind is None else x[R - B:]), pool,
+                extras)
 
     def _decode_step_eva(self, params: Params, tokens: jax.Array,
                          pool: Params, block_tables: jax.Array,

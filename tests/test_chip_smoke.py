@@ -1055,12 +1055,15 @@ def test_paged_decode_kernel_lowers_at_the_block_cells_rows(v5e):
 
 def test_sdar_cell_decode_program_fits_the_v5e(v5e, placed, monkeypatch):
     """``sdar-30b-a3b-chat-d6.block_decode``'s decode program as the
-    engine jits it (``_decode_step_paged_blocks``: the model's four rows a
-    slot, the sampler, the unmask rule and the block's state machine):
-    32 slots x 8,192, depth 6, all 128 experts, the whole vocabulary,
-    matmul weights in bf16. The v5e's compiler takes it with the paged
-    kernel and the three grouped matmuls as Mosaic calls (1,024 rows over
-    128 experts) and no ``ragged_dot``, the pool written in place."""
+    engine jits it (``_decode_step_paged_blocks``: the model's rows, every
+    slot's block of four and sixteen blocks behind, the sampler, the
+    unmask rule and the block's state machine): 32 slots x 8,192, depth
+    6, all 128 experts, the whole vocabulary, matmul weights in bf16. The
+    v5e's compiler takes it with the paged kernel (ONE call a layer, 48
+    grid rows) and the three grouped matmuls as Mosaic calls (1,536 rows
+    over 128 experts, a row tile of 128) and no ``ragged_dot``, the pool
+    written in place; the head and the sampler see the 128 rows being
+    denoised alone."""
     from benchmark import run as harness
     from benchmark.builders import sdar
     from ray_tpu.llm.engine import ContinuousBatchingEngine
@@ -1079,21 +1082,27 @@ def test_sdar_cell_decode_program_fits_the_v5e(v5e, placed, monkeypatch):
     assert (model.cfg.n_heads, model.cfg.n_kv_heads) == BLOCK_CELL_HEADS
     assert model.paged_decode_impl() == "pallas"
     assert model.ffn_load_shape() == (6, E)
-    assert model.grouped_matmul_plan(B * n)["moe_grouped_impl"] \
-        == "pallas_gmm"
+    room = B // 2                   # blocks behind a pass has room for
+    plan = model.grouped_matmul_plan((B + room) * n)
+    assert plan["moe_grouped_impl"] == "pallas_gmm"
+    assert plan["moe_gmm_tiling_gate"].startswith("128x")
     params = _engine_params(model)
     assert sum(a.size for a in jax.tree.leaves(params)) == cfg["parameters"]
     eng = object.__new__(ContinuousBatchingEngine)
     eng.model, eng.num_blocks, eng.block_length = model, B * maxb, n
+    eng._behind_slots = room
     pool = jax.eval_shape(lambda: model.init_kv_pool(B * maxb + 1, bs))
     compiled = jax.jit(eng._decode_step_paged_blocks,
                        donate_argnums=(2,)).lower(
-        placed(params), v5e(B, 2 * n + 1, dtype=jnp.int32), placed(pool),
+        placed(params), v5e(B, 3 * n + 2, dtype=jnp.int32), placed(pool),
         v5e(B, maxb, dtype=jnp.int32), v5e(B, dtype=jnp.int32),
         *_sampling(v5e, B), v5e(6, E, dtype=jnp.int32),
-        v5e(4, dtype=jnp.int32)).compile()
+        v5e(5, dtype=jnp.int32)).compile()
     text = compiled.as_text()
     assert text.count("paged_decode_attention_pallas") >= 1
+    # the logits are the current blocks' alone
+    assert f"f32[{B},{n},{cfg['vocab_size']}]" in text
+    assert f"f32[{B + room},{n},{cfg['vocab_size']}]" not in text
     assert "ragged_dot_tiling" not in text and "ragged-dot" not in text
     mem = compiled.memory_analysis()
     total = (mem.argument_size_in_bytes + mem.temp_size_in_bytes

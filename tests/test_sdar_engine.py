@@ -4,8 +4,11 @@ sdar.py``: the published procedure as a Python loop with no cache): the
 same tokens in the same order, each placed by the same pass of its block,
 whatever the prompt leaves of its last block, wherever ``max_tokens`` or
 a stop token cuts, with slots at different phases of their blocks, a step
-run ahead or not, a preemption in mid-block, a prefix hit. Float32
-compute on seeded weights at debug widths."""
+run ahead or not, a preemption in mid-block or with a finished block's
+rows still owed, a prefix hit. A finished block's commit rides the next
+block's first denoising pass: four passes a block of four at the quota's
+floor, none that places no token. Float32 compute on seeded weights at
+debug widths."""
 
 import jax
 import jax.numpy as jnp
@@ -58,6 +61,12 @@ CASES = {
     # the published default is the dynamic rule; the static one beside it
     "static_rule": ((8, 10), 9, {"remasking": "low_confidence_static"}),
     "two_passes_a_block": ((8, 10), 9, {"denoising_steps": 2}),
+    # one slot, three of four and every slot taken (a pass then runs
+    # ahead), prompts of different lengths: in one call some slots commit
+    # the block behind, some do not, and they stand at different passes
+    "one_slot": ((9,), 14, {"max_slots": 1}),
+    "three_of_four_slots": ((8, 9, 14), 13, {}),
+    "every_slot_taken": ((8, 9, 11, 14), 13, {}),
 }
 
 
@@ -86,7 +95,12 @@ def test_engine_generates_what_the_reference_generates(case):
     assert stats["block_length"] == N
     assert stats["tokens_generated"] == n_out * len(prompts)
     assert stats["moe_assignments"] == stats["moe_assignments_expected"] > 0
-    assert stats["block_commit_passes"] == stats["blocks_committed"] > 0
+    # every block but a request's last was committed: by the pass that
+    # began the next or, where more slots owed one at once than a call
+    # has room for, by a pass of its own
+    assert stats["block_commits_fused"] > 0
+    assert stats["block_commits_fused"] + stats["block_commit_passes"] == (
+        stats["blocks_committed"] - len(prompts))
     assert stats["decode_steps"] <= stats["block_slot_passes"]
     # every placed token was counted by the pass that placed it (a block
     # that ``max_tokens`` cut was placed whole)
@@ -94,20 +108,44 @@ def test_engine_generates_what_the_reference_generates(case):
     assert eng.pool.num_free == eng.num_blocks
 
 
-def test_seeded_weights_place_the_quota_alone_and_a_sure_model_more():
+@pytest.mark.parametrize("requests,blocks,alone", [
+    (1, 2, 0), (1, 5, 0), (2, 3, 0), (3, 3, 1), (4, 3, 2)])
+def test_a_block_of_four_costs_four_passes_at_the_quotas_floor(requests,
+                                                                blocks,
+                                                                alone):
     """On seeded weights no confidence passes 0.9: one token a denoising
-    pass, a commit in five. With the threshold under every confidence a
-    block is done in one denoising pass, and the counter that tells the
-    threshold's work from the quota's says so."""
+    pass, and no pass beside them: the commit of a block rides the next
+    block's first pass, and a request's last block is owed none. Four
+    slots have room for TWO blocks behind a call: of three or four
+    requests in step, those beyond two commit their first block alone,
+    once, and stand a pass apart from then on (every slot taken: a pass
+    runs ahead all the while)."""
     cfg, model, params = make()
     eng = engine_of(model, params)
-    run(eng, [prompt_of(cfg, 8, 0)], SamplingParams(max_tokens=8))
+    assert eng._behind_slots == 2
+    prompts = [prompt_of(cfg, 8, i) for i in range(requests)]
+    reqs = run(eng, prompts, SamplingParams(max_tokens=N * blocks))
+    for prompt, req in zip(prompts, reqs):
+        assert (req.output, req.unmasked_at) == want_of(cfg, params, prompt,
+                                                        N * blocks)
     stats = eng.stats
     assert stats["block_tokens_unmasked_by_confidence"] == 0
-    assert stats["block_slot_passes"] == 10
-    assert stats["block_commit_passes"] == 2
-    assert stats["block_tokens_unmasked"] == 8
+    assert stats["block_slot_passes"] == N * blocks * requests + alone
+    assert stats["decode_steps"] == N * blocks + (alone > 0)
+    assert stats["block_commit_passes"] == alone
+    assert stats["block_commits_fused"] == (blocks - 1) * requests - alone
+    assert stats["blocks_committed"] == blocks * requests
+    assert stats["block_tokens_unmasked"] == N * blocks * requests
+    # the blocks behind ran through the experts too, and were counted
+    assert stats["moe_assignments"] == stats["moe_assignments_expected"] == (
+        (N * blocks + blocks - 1) * requests * N * cfg.expert_top_k
+        * cfg.n_layers)
 
+
+def test_a_sure_model_fills_a_block_in_one_pass():
+    """With the threshold under every confidence a block is done in one
+    denoising pass, and the counter that tells the threshold's work from
+    the quota's says so."""
     cfg, model, params = make(confidence_threshold=0.0)
     eng = engine_of(model, params)
     prompt = prompt_of(cfg, 8, 0)
@@ -115,7 +153,8 @@ def test_seeded_weights_place_the_quota_alone_and_a_sure_model_more():
     assert (req.output, req.unmasked_at) == want_of(cfg, params, prompt, 8)
     assert set(req.unmasked_at) == {1}
     stats = eng.stats
-    assert stats["block_slot_passes"] == 4          # 2 x (denoise + commit)
+    assert stats["block_slot_passes"] == 2          # a pass a block
+    assert stats["block_commits_fused"] == 1
     assert stats["block_tokens_unmasked_by_confidence"] == 6
 
 
@@ -177,20 +216,75 @@ def test_a_preemption_in_mid_block_redoes_the_block():
     assert eng.pool.num_free == eng.num_blocks
 
 
-def test_a_prefix_hit_of_whole_pages_generates_what_a_cold_prefill_does():
+@pytest.mark.parametrize("n_out", [6, 14])
+def test_a_prefix_hit_of_whole_pages_generates_what_a_cold_prefill_does(
+        n_out):
     """A page of 8 rows holds two whole blocks, so its K/V depend on
     nothing behind it: the second request takes the first's two pages
-    from the index and reads the reference's tokens all the same."""
+    from the index and reads the reference's tokens all the same, over
+    two blocks and over four (commits ride across a page's edge behind
+    the shared pages). Only a prefill's pages are ever offered to the
+    index: a page the passes filled is hashed by nobody, so a last
+    block's owed rows are owed to nobody."""
     cfg, model, params = make()
     head = prompt_of(cfg, 16, 50)
     prompts = [head + prompt_of(cfg, n, i) for i, n in enumerate((3, 7))]
     eng = engine_of(model, params)
-    reqs = [run(eng, [p], SamplingParams(max_tokens=6))[0] for p in prompts]
+    reqs = []
+    for p in prompts:
+        reqs += run(eng, [p], SamplingParams(max_tokens=n_out))
+        assert len(eng.pool._by_hash) == 2          # the head's two pages
     assert eng.stats["prefix_prefills"] == 1
     assert eng.stats["prefix_tokens_reused"] == 16
     for prompt, req in zip(prompts, reqs):
         assert (req.output, req.unmasked_at) == want_of(cfg, params,
-                                                        prompt, 6)
+                                                        prompt, n_out)
+
+
+def test_a_preemption_with_a_finished_blocks_rows_owed_recomputes_them():
+    """A slot is preempted between its block's last denoising pass and
+    the pass that would have committed it: the block's tokens were
+    handed out and fold into the context, whose prefill computes their
+    rows; nothing relied on the rows that were owed."""
+    cfg, model, params = make()
+    prompts = [prompt_of(cfg, n, i) for i, n in enumerate((9, 12))]
+    eng = engine_of(model, params, max_slots=3)     # a slot free: no pass
+    reqs = [eng.submit(p, SamplingParams(max_tokens=12)) for p in prompts]
+    hit = False
+    with jax.default_matmul_precision("highest"):
+        while eng.has_work():
+            eng.step()
+            owed = [i for i, r in enumerate(eng.slots) if r is not None
+                    and eng._last_tokens[i, -1] and len(r.output) == 7]
+            if owed and not hit:
+                assert eng._in_flight is None
+                hit = True
+                eng._preempt(owed[0])
+    assert hit and reqs[0].preemptions == 1
+    for prompt, req in zip(prompts, reqs):
+        assert (req.output, req.unmasked_at) == want_of(cfg, params,
+                                                        prompt, 12)
+    assert eng.pool.num_free == eng.num_blocks
+
+
+@pytest.mark.parametrize("slots", [1, 2])
+def test_a_request_that_reaches_max_seq_ends_on_its_last_whole_block(slots):
+    """``max_seq`` 32 behind a prompt of 20: blocks at 20 and 24, and the
+    block at 28 would touch the last row: the request ends with the
+    second block's tokens (every slot taken or not: no pass runs ahead
+    into rows the table does not hold)."""
+    cfg, model, params = make()
+    prompts = [prompt_of(cfg, 20, i) for i in range(slots)]
+    eng = engine_of(model, params, max_slots=slots, max_seq=32)
+    reqs = run(eng, prompts, SamplingParams(max_tokens=30))
+    for prompt, req in zip(prompts, reqs):
+        assert req.finish_reason == "length" and len(req.output) == 8
+        assert (req.output, req.unmasked_at) == want_of(cfg, params,
+                                                        prompt, 8)
+    stats = eng.stats           # (two slots in step: room for one behind)
+    assert stats["block_commits_fused"] + stats["block_commit_passes"] \
+        == slots
+    assert eng.pool.num_free == eng.num_blocks
 
 
 def test_a_page_that_would_split_a_block_is_refused_by_the_engine():
@@ -207,10 +301,10 @@ def test_live_blocks_are_counted_up_to_the_blocks_end():
     eng = engine_of(model, params)
     run(eng, [prompt_of(cfg, 8, 0)], SamplingParams(max_tokens=4))
     stats = eng.stats
-    # five passes over rows 8..11 behind 8 cached ones: ceil(12 / 8) pages
-    assert stats["decode_steps"] == 5
-    assert stats["decode_kv_blocks_live"] == 5 * 2
-    assert stats["decode_kv_blocks_table"] == 5 * eng.blocks_per_slot
+    # four passes over rows 8..11 behind 8 cached ones: ceil(12 / 8) pages
+    assert stats["decode_steps"] == 4
+    assert stats["decode_kv_blocks_live"] == 4 * 2
+    assert stats["decode_kv_blocks_table"] == 4 * eng.blocks_per_slot
 
 
 def test_the_handoff_of_a_prefill_is_refused():
@@ -259,4 +353,5 @@ def test_a_one_token_model_has_no_pass_and_zero_block_counters():
     assert stats["block_length"] == 1
     assert all(stats[k] == 0 for k in (
         "block_slot_passes", "block_commit_passes", "block_tokens_unmasked",
-        "block_tokens_unmasked_by_confidence", "blocks_committed"))
+        "block_tokens_unmasked_by_confidence", "block_commits_fused",
+        "blocks_committed"))

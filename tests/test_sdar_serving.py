@@ -181,6 +181,79 @@ def test_block_step_through_the_pool_is_the_references_pass(impl):
             assert float(err) < F32_TOL, (k, at)
 
 
+@pytest.mark.parametrize("state,rows", [(0, (0, 1, 2, 3)), (1, (0, 1, 2, 3)),
+                                        (0, (1, 0))],
+                         ids=["all_masked", "subset", "compacted"])
+@pytest.mark.parametrize("impl", ["xla", "pallas"])
+def test_a_fused_pass_is_the_commit_and_the_denoising_pass_after_it(
+        impl, state, rows):
+    """One call that carries the clean block BEHIND beside the block
+    being denoised against the two calls it stands for (commit k, then
+    denoise k + 1): block k + 1's logits and the pool's rows of BOTH
+    blocks, to 1e-5. Slot 0 has both blocks in one page of 8 rows, slot 1
+    a page's edge between them, slot 2 wants no commit (its rows behind
+    stay as they were) and slot 3 is idle; the blocks behind a row a
+    slot, or those of the two slots that want one alone, in any order;
+    kernel (interpreted) and reference alike."""
+    cfg, model, params = make(decode_attention=impl)
+    toks, prompt = seqs(cfg, (4, 32)), 16
+    pool, tables = paged_prefill(model, params, toks, prompt)
+    scratch = pool["k"].shape[1] - 1
+    step = jax.jit(model.block_step_paged_counted)
+    masked = jnp.full((4, N), cfg.mask_token_id, I32)
+
+    def at(off):
+        return jnp.full((4,), off, I32)
+
+    # what a schedule leaves behind it: rows 16..19 from a denoising pass
+    # everywhere; in slot 1 committed, with a denoising pass's at 20..23
+    _, pool, _ = step(params, masked, pool, tables, at(16))
+    only_1 = tables.at[jnp.asarray([0, 2, 3])].set(scratch)
+    _, pool, _ = step(params, toks[:, 16:20], pool, only_1, at(16))
+    _, pool, _ = step(params, masked, pool, only_1, at(20))
+
+    offsets = jnp.asarray([20, 24, 20, 0], I32)
+    wanted = jnp.asarray([True, True, False, False])
+    live = jnp.asarray([True, True, True, False])
+    tables = tables.at[3].set(scratch)
+    pick = jax.vmap(lambda row, off: jax.lax.dynamic_slice(row, (off,), (N,)))
+    clean_behind = pick(toks, jnp.maximum(offsets - N, 0))
+    current = jnp.where(
+        live[:, None],
+        pick(states_of(cfg, toks[:, prompt:])[state], offsets - prompt),
+        masked)
+
+    # the two calls: the commit of the slots that want one, then the pass
+    _, two, _ = step(params, clean_behind, pool,
+                     jnp.where(wanted[:, None], tables, scratch),
+                     offsets - N, wanted)
+    want, two, want_extras = step(params, current, two, tables, offsets, live)
+    rows = jnp.asarray(rows)
+    got, one, extras = step(params, current, pool, tables, offsets, live,
+                            (clean_behind[rows], rows, wanted[rows]))
+    assert got.shape == want.shape
+    assert float(jnp.max(jnp.abs(got[:3] - want[:3]))) < 1e-5
+    for name in ("k", "v"):
+        real = slice(0, scratch)          # the scratch page holds anything
+        assert float(jnp.max(jnp.abs(one[name][:, real]
+                                     - two[name][:, real]))) < 1e-5
+        # the commit moved slot 0's rows behind; slot 2's stayed
+        page, first = int(tables[0, 2]), slice(0, N)
+        assert float(jnp.max(jnp.abs(one[name][:, page, first]
+                                     - pool[name][:, page, first]))) > 1e-3
+        page = int(tables[2, 2])
+        assert float(jnp.max(jnp.abs(one[name][:, page, first]
+                                     - pool[name][:, page, first]))) == 0.0
+    # the expert load counts the live rows of both halves and no other
+    assert int(extras["load"].sum()) == (
+        (3 + 2) * N * cfg.expert_top_k * cfg.n_layers)
+    assert int(want_extras["load"].sum()) == (
+        3 * N * cfg.expert_top_k * cfg.n_layers)
+    assert extras["experts"].shape[1] == len(rows) + 4     # behind first
+    np.testing.assert_array_equal(extras["experts"][:, len(rows):][:, :3],
+                                  want_extras["experts"][:, :3])
+
+
 def test_teacher_forced_is_denoise_logits_block_by_block():
     """The reference's one-forward route (what the benchmark's check
     runs) against its plain one."""
