@@ -1115,3 +1115,74 @@ def test_sdar_cell_decode_program_fits_the_v5e(v5e, placed, monkeypatch):
     # the pool is written in place and no expert stack is copied out
     one_stack_a_layer = E * 2048 * 768 * 2
     assert mem.temp_size_in_bytes < one_stack_a_layer
+
+
+# jamba2-3b.long_decode_mamba1: 32 slots x 24,576 at block 32 (768 table
+# entries a slot), the WHOLE published model, 28 layers in five scanned runs
+MAMBA1_CELL_SLOTS, MAMBA1_CELL_TABLE = 32, 768
+
+
+def test_mamba1_kernels_lower_at_the_cells_shapes(v5e):
+    """The Mamba-1 decode update (a traced layer index into the stack of
+    26 x 32 rows of S [16, 5120] float32, aliased) and the prefill scan
+    (a chunk-prefill call's 512 positions and the check's two-row bucket
+    of 1,088, ``u`` in bf16) compile for the v5e as Mosaic calls."""
+    from ray_tpu.ops import ssm1
+
+    f32, i32 = jnp.float32, jnp.int32
+    L, B, N, W = 26, MAMBA1_CELL_SLOTS, 16, 5120
+    update = jax.jit(ssm1.state_update_pallas, donate_argnums=0).lower(
+        v5e(L, B, N, W, dtype=f32), v5e(dtype=i32), v5e(N, W, dtype=f32),
+        v5e(B, W, dtype=f32), v5e(B, W, dtype=f32), v5e(B, N, dtype=f32),
+        v5e(B, N, dtype=f32))
+    assert _mosaic(update)
+    # in place: nothing of the stack's size, nor a [B, N, W] decay, beside it
+    assert update.compile().memory_analysis().temp_size_in_bytes < N * W * 4
+    for rows, T in ((1, 512), (2, 1088)):
+        scan = jax.jit(ssm1.selective_scan_pallas).lower(
+            v5e(rows, T, W), v5e(rows, T, W, dtype=f32), v5e(N, W, dtype=f32),
+            v5e(rows, T, N, dtype=f32), v5e(rows, T, N, dtype=f32),
+            v5e(rows, N, W, dtype=f32))
+        assert _mosaic(scan)
+
+
+def test_mamba1_cell_decode_program_fits_the_v5e(v5e, placed, monkeypatch):
+    """``jamba2-3b.long_decode_mamba1``'s decode program as the engine jits
+    it: 32 slots x 24,576, ALL 28 layers of the published model (3.03 B
+    parameters, 5.64 GiB in bf16) in five scanned runs: three Mosaic
+    state-update calls (one a run of Mamba layers, each rewriting its
+    layer's rows of S IN PLACE) and two paged attention calls over ONE
+    K/V head under 20 query heads. The v5e's compiler takes it at 6.69
+    GiB of 15.75 (5.65 of weights, 1.03 of pool and state, 0.02 of
+    temporaries): no layer's slice of a weight stack, no stack of state
+    and no [32, 16, 5120] decay is among the temporaries."""
+    from benchmark import run as harness
+    from benchmark.builders import jamba
+
+    _as_on_the_chip(monkeypatch)
+    cfg = harness.load_json(harness.ROOT, "benchmark/configs/jamba2-3b.json")
+    B, bs, maxb = MAMBA1_CELL_SLOTS, CELL_BS, MAMBA1_CELL_TABLE
+    model = jamba.build_model(cfg, maxb * bs)
+    assert model.recurrent and model.paged_decode_impl() == "pallas"
+    assert model.ffn_load_shape() is None
+    pool = jax.eval_shape(lambda: model.init_kv_pool(B * maxb + 1, bs, B))
+    assert pool["k"].shape == (2, B * maxb + 1, bs, 1, 128)
+    assert pool["ssm"].shape == (26, B, 1, 16, 5120)
+    assert pool["ssm"].dtype == jnp.float32
+    assert pool["conv"].shape == (26, B, 3, 5120)
+    params = _engine_params(model)
+    assert sum(a.size for a in jax.tree.leaves(params)) == 3_029_337_472
+    compiled = _engine_decode(model, B * maxb).lower(
+        placed(params), v5e(B, dtype=jnp.int32), placed(pool),
+        v5e(B, maxb, dtype=jnp.int32), v5e(B, dtype=jnp.int32),
+        *_sampling(v5e, B), None).compile()
+    text = compiled.as_text()
+    # a state kernel a RUN of Mamba layers, an attention kernel a layer
+    assert text.count("ssm1_state_update_pallas") >= 3
+    assert text.count("tpu_custom_call") >= 5
+    mem = compiled.memory_analysis()
+    total = (mem.argument_size_in_bytes + mem.temp_size_in_bytes
+             + mem.output_size_in_bytes - mem.alias_size_in_bytes)
+    assert total < 6.8 * 2**30
+    # under one layer's SwiGLU matrix (40 MiB): no weight slice is copied
+    assert mem.temp_size_in_bytes < 2560 * 8192 * 2
