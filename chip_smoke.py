@@ -543,12 +543,17 @@ def check_engine(rep: Report, eng, impl: str) -> None:
     from ray_tpu._private.platform import on_chip
 
     st = eng.stats
+    # a hit is taken in whole runs of blocks (docs/serving.md, "Blocks
+    # and runs"): all 96 shared tokens where a block is a copy
+    a_run = st["kv_run_blocks"] * eng.block_size
+    reused = 96 // a_run * a_run
     rep.check("prefix reuse and chunked prefill ran",
-              st["prefix_prefills"] == 1
-              and st["prefix_tokens_reused"] == 96
+              st["prefix_prefills"] == (reused > 0)
+              and st["prefix_tokens_reused"] == reused
               and st["prefills"] == 6 and st["preemptions"] == 0,
               f"stats {st} (6 prefills = 4 whole prompts + 2 chunks of "
-              f"the 600-token one)")
+              f"the 600-token one; {reused} of 96 shared tokens reused in "
+              f"runs of {st['kv_run_blocks']} blocks)")
     deadline = time.monotonic() + 10     # done is set just before unref
     while (eng.pool.num_free != eng.num_blocks
            and time.monotonic() < deadline):

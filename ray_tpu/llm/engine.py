@@ -37,6 +37,10 @@ is the in-tree TPU-native equivalent (BASELINE.md config 5):
   is freed as the slot's window leaves it (``llm/paged_cache.py``,
   docs/serving.md). A model with one kind is served as it always was:
   the same pool, tables, counters and programs;
+- a pool whose block is NARROW (one K/V head: 8 KB) lays a slot's blocks
+  in aligned runs, and the paged kernel copies a run as one page
+  (``kv_run``; docs/serving.md, "Blocks and runs"): blocks, tables and
+  counters stay in blocks of ``block_size`` rows;
 - an EVA model (``model.eva``: an exact window that resets, chunk
   summaries of everything before it) gets a pool and a table a PART:
   the summary part grows one block per ``block_size * chunk`` positions,
@@ -357,15 +361,37 @@ class ContinuousBatchingEngine:
                              if self.recurrent else ())
         covers = block_size * (self.eva[1] if self.eva else 1)
         self.blocks_per_slot = (max_seq + covers - 1) // covers
+        # "pallas" (the Mosaic kernel that reads only the live blocks)
+        # or "xla" (the gather over every table): chosen by the model
+        # from its configuration and the platform when the engine is built
+        self.decode_attention_impl = model.paged_decode_impl()
+        # Blocks the kernel copies as one page (docs/serving.md, "Blocks
+        # and runs"): more than 1 where the model's block is narrow, and
+        # the full kind's blocks then lie in runs. A block stays what
+        # the caller sized, what a prefill scatters, what the prefix
+        # index hashes and what every counter counts. The gather has no
+        # page to widen, and no run outgrows a slot's table
+        self.kv_run = (model.paged_run_blocks(block_size)
+                       if self.decode_attention_impl == "pallas" else 1)
+        while self.kv_run > self.blocks_per_slot:
+            self.kv_run //= 2
+
+        def whole_runs(n):
+            # a layer's window of the device stack and a slot's table
+            # hold whole runs: every layer's first block starts a run
+            return -(-n // self.kv_run) * self.kv_run
+
         if num_blocks is None:
-            num_blocks = max_slots * self.blocks_per_slot
+            num_blocks = max_slots * whole_runs(self.blocks_per_slot)
         self.num_blocks = num_blocks
-        self.pool = BlockPool(num_blocks, covers)
+        self.pool = BlockPool(num_blocks, covers, self.kv_run)
+
         # +1: physical block ``num_blocks`` is the SCRATCH block — every
         # padded table/scatter entry points there, so inactive slots and
         # bucket padding write garbage into scratch instead of a live
         # block, and every device index stays in-bounds (no OOB DMA for
-        # the Pallas path to trip on)
+        # the Pallas path to trip on). (Where blocks lie in runs it is
+        # the first of a scratch RUN.)
         # A model with sliding-window layers: a second pool, of the
         # blocks a layer of THAT kind holds, a second table, and on the
         # device one stack with each layer's window of its kind's size
@@ -377,7 +403,7 @@ class ContinuousBatchingEngine:
                 "a recurrent state beside a pool a kind or a part")
         self.window: Optional[int] = (
             model.cfg.sliding_window if kinds and SLIDING in kinds else None)
-        table_width = self.blocks_per_slot
+        table_width = whole_runs(self.blocks_per_slot)
         if self.eva is not None:
             # the exact part: the blocks of one window a slot (and, as
             # for a sliding layer, a tail block and one prefill chunk's),
@@ -396,17 +422,18 @@ class ContinuousBatchingEngine:
             self.window_pool = self._tables_win = None
             # (a recurrent model: the state rows, one a slot, in the tree)
             self.kv = model.init_kv_pool(
-                num_blocks + 1, block_size,
+                whole_runs(num_blocks + 1), block_size,
                 *((max_slots,) if self.recurrent else ()))
         else:
             self._layer_kinds = np.asarray(kinds, np.int32)
             self.num_window_blocks = max_slots * window_blocks_per_slot(
                 self.window, block_size, self.buckets[-1])
             self.window_pool = BlockPool(self.num_window_blocks, block_size)
-            # each pool's last block is its kind's scratch block
+            # each pool's block past its last is its kind's scratch block
             self.kv = model.init_kv_pools(
-                (num_blocks + 1, self.num_window_blocks + 1), block_size)
-            self._tables_win = np.full((max_slots, self.blocks_per_slot),
+                (whole_runs(num_blocks + 1),
+                 whole_runs(self.num_window_blocks + 1)), block_size)
+            self._tables_win = np.full((max_slots, table_width),
                                        self.num_window_blocks, np.int32)
 
         self.slots: List[Optional[Request]] = [None] * max_slots
@@ -475,10 +502,6 @@ class ContinuousBatchingEngine:
         self.error: Optional[BaseException] = None   # set once, by run_forever
 
         # jitted programs ------------------------------------------------
-        # "pallas" (the Mosaic kernel that reads only the live blocks)
-        # or "xla" (the gather over every table): chosen by the model
-        # from its configuration and the platform when the engine is built
-        self.decode_attention_impl = model.paged_decode_impl()
         if self.recurrent:      # the state update's kernel or its twin
             self.decode_attention_impl += (
                 f"+ssm_{self.decode_attention_impl}")
@@ -569,6 +592,14 @@ class ContinuousBatchingEngine:
                       "prefill_tokens": 0, "prefill_padded_tokens": 0,
                       "decode_kv_blocks_live": 0,
                       "decode_kv_blocks_table": 0,
+                      # blocks the paged kernel copies as one page (1:
+                      # a block a copy), and a gauge, read when ``stats``
+                      # is asked: blocks the slots hold AHEAD of the one
+                      # their next token goes to (what lying in runs
+                      # costs the pool: up to ``run - 1`` a slot, and a
+                      # run more while a step ahead has reserved it)
+                      "kv_run_blocks": self.kv_run,
+                      "kv_blocks_reserved_unfilled": 0,
                       # a model with sliding layers (0 with one kind):
                       # blocks of the slots' SLIDING-kind tables a
                       # step's attention reads, blocks that kind freed
@@ -708,6 +739,11 @@ class ContinuousBatchingEngine:
         what reads an expert model's load back from the device and sums
         the stream readers' cells."""
         self._stats.update(self._readers.sums())
+        covers = self.pool.block_size
+        self._stats["kv_blocks_reserved_unfilled"] = sum(
+            max(0, len(alloc.blocks) - int(at) // covers - 1)
+            for alloc, at in zip(self.allocs, self.offsets)
+            if alloc is not None)
         if self._ffn_counts is not None:
             # one tuple, one step
             load, expected, *block_counts = self._ffn_counts
@@ -825,7 +861,7 @@ class ContinuousBatchingEngine:
         logits, pool, extras = self.model.block_step_paged_counted(
             params, jnp.where(alone[:, None], behind, block), pool,
             block_tables, offsets - n * alone, live,
-            (behind[rows], rows, taken))
+            (behind[rows], rows, taken), run=self.kv_run)
         if ffn_load is not None:
             ffn_load = ffn_load + extras["load"]
         with jax.named_scope("blockdiff_unmask"):
@@ -873,13 +909,15 @@ class ContinuousBatchingEngine:
         points at the scratch block)."""
         if ffn_load is None:
             logits, pool = self.model.decode_step_paged(
-                params, tokens, pool, block_tables, offsets)
+                params, tokens, pool, block_tables, offsets,
+                run=self.kv_run)
         else:
             full = (block_tables if block_tables.ndim == 2
                     else block_tables[FULL])
             live = full[:, 0] != self.num_blocks
             logits, pool, extras = self.model.decode_step_paged_counted(
-                params, tokens, pool, block_tables, offsets, live)
+                params, tokens, pool, block_tables, offsets, live,
+                run=self.kv_run)
             ffn_load = ffn_load + extras["load"]
         tokens, key = self._sample_impl(logits, temps, top_ks, key)
         return tokens, pool, key, ffn_load
@@ -1113,7 +1151,7 @@ class ContinuousBatchingEngine:
             toks = req.cache_tokens()
             n = len(toks)
             covers = self.pool.block_size
-            never_fits = (n + 1 + covers - 1) // covers > self.num_blocks
+            never_fits = not self.pool.holds((n + 1 + covers - 1) // covers)
             if n >= self.max_seq or never_fits:
                 req.finish_reason = ("length" if req.output
                                      else "prompt_too_long")

@@ -47,6 +47,17 @@ Design (vLLM-v1-shaped, TPU-adapted):
   prompts are never prefix hits: a hit would need the summary blocks of
   every earlier window AND the exact blocks of the window it ends in,
   and the exact ones are gone one window later.
+- A pool whose block is NARROW (few K/V heads: a block of a few KB) is
+  built with ``run`` > 1 (``ops.paged_attention.run_blocks``): the paged
+  kernel then copies RUNS of that many blocks as one page, so a slot's
+  blocks must LIE in runs. The pool hands out, takes back and evicts
+  whole aligned runs (blocks ``[r*run, (r+1)*run)``): every table is
+  made of them (``table[r*run + k] == table[r*run] + k``), a slot that
+  needs the first block of a run holds all of it (the rest RESERVED
+  until its length reaches them), and a prefix hit is taken in whole
+  runs. A block stays what it was to every caller: ``block_size``
+  tokens, one hash, one entry of a table, one count. With ``run`` 1
+  every method hands out what it always did, in the same order.
 """
 
 from __future__ import annotations
@@ -60,15 +71,20 @@ _NO_HASH = None
 class BlockPool:
     """Refcounted physical blocks + content-hash prefix index."""
 
-    def __init__(self, num_blocks: int, block_size: int):
-        if num_blocks < 1 or block_size < 1:
-            raise ValueError("num_blocks and block_size must be >= 1")
+    def __init__(self, num_blocks: int, block_size: int, run: int = 1):
+        if num_blocks < 1 or block_size < 1 or run < 1:
+            raise ValueError("num_blocks, block_size and run must be >= 1")
         self.num_blocks = num_blocks
         self.block_size = block_size
+        # blocks handed out together: run ``r`` is blocks [r*run,
+        # (r+1)*run); what ``num_blocks`` leaves over is never handed out
+        self.run = run
         self.refcount = [0] * num_blocks
-        # LRU order: oldest-freed first == evicted first
+        # the free RUNS (with ``run`` 1: the free blocks), LRU order:
+        # oldest-freed first == evicted first. A run is free while none
+        # of its blocks is referenced
         self._free: "OrderedDict[int, None]" = OrderedDict(
-            (i, None) for i in range(num_blocks))
+            (i, None) for i in range(num_blocks // run))
         # content hash -> physical block (live or cached-free)
         self._by_hash: Dict[int, int] = {}
         self._hash_of: List[Optional[int]] = [_NO_HASH] * num_blocks
@@ -78,11 +94,21 @@ class BlockPool:
     # -- introspection ----------------------------------------------------
     @property
     def num_free(self) -> int:
-        return len(self._free)
+        """Blocks an ``alloc`` can still hand out."""
+        return len(self._free) * self.run
+
+    def _blocks_of(self, run_id: int) -> range:
+        return range(run_id * self.run, (run_id + 1) * self.run)
+
+    def holds(self, n: int) -> bool:
+        """Could ``n`` blocks EVER be allocated together (in whole runs,
+        from the runs the pool has)?"""
+        return -(-n // self.run) <= self.num_blocks // self.run
 
     def cached_free_blocks(self) -> int:
         """Free blocks still carrying reusable prefix content."""
-        return sum(1 for b in self._free if self._hash_of[b] is not None)
+        return sum(1 for r in self._free for b in self._blocks_of(r)
+                   if self._hash_of[b] is not None)
 
     # -- hashing ----------------------------------------------------------
     @staticmethod
@@ -125,28 +151,47 @@ class BlockPool:
         return out
 
     def ref(self, block: int) -> None:
-        """Take a reference; resurrects a cached-free block."""
+        """Take a reference; resurrects a cached-free block (and takes
+        its run off the free list: a hit takes the run's other blocks
+        too, ``allocate_slot``)."""
         if self.refcount[block] == 0:
-            self._free.pop(block, None)
+            self._free.pop(block // self.run, None)
         self.refcount[block] += 1
 
     def alloc(self, n: int) -> Optional[List[int]]:
         """Allocate ``n`` fresh (private, writable) blocks, or None if
-        the pool can't cover it. Eviction = reusing the LRU free block,
-        dropping whatever prefix content it still cached."""
-        if n > len(self._free):
+        the pool can't cover it: in whole runs, so ``n`` rounded up to
+        one (the blocks past ``n`` are the caller's all the same,
+        reserved). Eviction = reusing the LRU free run, dropping
+        whatever prefix content it still cached."""
+        runs = -(-n // self.run)
+        if runs > len(self._free):
             return None
         out = []
-        for _ in range(n):
-            b, _ = self._free.popitem(last=False)
-            old = self._hash_of[b]
-            if old is not None:
-                self._by_hash.pop(old, None)
-                self._hash_of[b] = _NO_HASH
-                self.stats["evictions"] += 1
-            self.refcount[b] = 1
-            out.append(b)
+        for _ in range(runs):
+            r, _ = self._free.popitem(last=False)
+            for b in self._blocks_of(r):
+                old = self._hash_of[b]
+                if old is not None:
+                    self._by_hash.pop(old, None)
+                    self._hash_of[b] = _NO_HASH
+                    self.stats["evictions"] += 1
+                self.refcount[b] = 1
+                out.append(b)
         return out
+
+    def whole_runs(self, blocks: Sequence[int]) -> int:
+        """How many of ``blocks``, from the first on, lie as whole runs:
+        aligned, contiguous, ``run`` at a time (all of them at ``run``
+        1). What a table of runs can share of a matched prefix."""
+        run, n = self.run, 0
+        if run == 1:
+            return len(blocks)
+        while (n + run <= len(blocks) and blocks[n] % run == 0
+               and all(blocks[n + k] == blocks[n] + k
+                       for k in range(1, run))):
+            n += run
+        return n
 
     def seal(self, block: int, content_hash: int) -> None:
         """Mark a full block's content, making it prefix-shareable. If
@@ -160,13 +205,16 @@ class BlockPool:
         self._hash_of[block] = content_hash
 
     def unref(self, block: int) -> None:
-        """Drop a reference; at zero the block joins the free list but
-        keeps its prefix-index entry (cached-free) until reallocated."""
+        """Drop a reference; at zero the block joins the free list (its
+        run does, once none of its blocks is referenced) but keeps its
+        prefix-index entry (cached-free) until reallocated."""
         if self.refcount[block] <= 0:
             raise ValueError(f"unref of unreferenced block {block}")
         self.refcount[block] -= 1
-        if self.refcount[block] == 0:
-            self._free[block] = None   # append = most-recently-freed
+        r = block // self.run
+        if self.refcount[block] == 0 and not any(
+                self.refcount[b] for b in self._blocks_of(r)):
+            self._free[r] = None       # append = most-recently-freed
 
     def unref_all(self, blocks: Sequence[int]) -> None:
         for b in blocks:
@@ -301,6 +349,12 @@ def allocate_slot(pool: BlockPool, prompt: Sequence[int],
     holds (a model with a recurrent state: the shared pages would come
     without the state at their end); every block is fresh.
 
+    A pool of RUNS shares a prefix in whole runs (the hit rounds down to
+    ``run * block_size`` tokens, and ends where the matched blocks stop
+    lying as one) and reserves the fresh blocks in whole runs too: the
+    allocation's ``blocks`` then hold those past ``reserve_tokens`` that
+    fill its last run.
+
     Returns (allocation, shared_token_count) or None if the pool cannot
     cover the non-shared remainder right now.
     """
@@ -313,6 +367,7 @@ def allocate_slot(pool: BlockPool, prompt: Sequence[int],
     # last-token logits — keep >=1 token of real prefill.
     if len(shared) * bs >= len(prompt):
         shared = shared[:max(0, (len(prompt) - 1) // bs)]
+    shared = shared[:pool.whole_runs(shared)]
     window_alloc = None
     if window_pool is not None:
         def in_window(n):      # the sliding kind's blocks a hit of n needs
@@ -321,7 +376,7 @@ def allocate_slot(pool: BlockPool, prompt: Sequence[int],
         n = len(shared)
         while n and any(hashes[j] not in window_pool._by_hash
                         for j in in_window(n)):
-            n -= 1
+            n -= pool.run
         shared = shared[:n]
         held = [window_pool._by_hash[hashes[j]] for j in in_window(n)]
         for b in held:
@@ -346,8 +401,9 @@ def allocate_slot(pool: BlockPool, prompt: Sequence[int],
 
 def ensure_capacity(pool: BlockPool, alloc: SlotAllocation,
                     needed_tokens: int) -> bool:
-    """Grow ``alloc`` until it covers ``needed_tokens``. False = pool
-    exhausted (caller preempts someone)."""
+    """Grow ``alloc`` until it covers ``needed_tokens`` (by whole runs,
+    where the pool has them). False = pool exhausted (caller preempts
+    someone)."""
     bs = pool.block_size
     need = (needed_tokens + bs - 1) // bs - len(alloc.blocks)
     if need <= 0:

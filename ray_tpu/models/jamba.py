@@ -481,7 +481,8 @@ class JambaModel(LlamaModel):
     def decode_step_paged_counted(self, params: Params, tokens: jax.Array,
                                   pool: Params, block_tables: jax.Array,
                                   offsets: jax.Array,
-                                  live: Optional[jax.Array] = None):
+                                  live: Optional[jax.Array] = None,
+                                  run: int = 1):
         """One decode step for every slot: the attention layers against
         the block pool (``LlamaModel``'s: the pool as ONE stack ``[La*NB,
         ...]``, layer ``j``'s pages from ``j*NB`` on), the Mamba layers
@@ -492,7 +493,8 @@ class JambaModel(LlamaModel):
         idle computes on whatever its row holds; its next tenant's
         activation overwrites the row.
         -> (logits [B, V], the pool, None: the dense SwiGLU counts
-        nothing)."""
+        nothing).
+        ``run``: ``LlamaModel.decode_step_paged``'s."""
         cfg = self.cfg
         B = tokens.shape[0]
         impl = self.paged_decode_impl()
@@ -540,7 +542,7 @@ class JambaModel(LlamaModel):
                     o = self._attend_pages(
                         q[:, 0], k_all, v_all, None, block_tables, lengths,
                         impl=impl, starts=None, first_block=j * NB,
-                        num_blocks=NB)
+                        num_blocks=NB, run=run)
                 return o[:, None], dict(store, k=k_all, v=v_all)
 
             return self._attention_mixer(h, layer, q_pos, attend)

@@ -1068,9 +1068,23 @@ class LlamaModel:
             return "xla"
         return default_impl(self.cfg.head_dim, self.cfg.n_kv_heads)
 
+    def paged_run_blocks(self, block_size: int) -> int:
+        """Blocks of ``block_size`` rows the paged kernel should copy as
+        one page (``ops.paged_attention.run_blocks``: more than 1 where
+        a block of this model's K/V heads is a few KB), for an engine
+        that lays the blocks so and hands the number back as ``run``. 1
+        for a model whose pages another kernel reads, and for an EVA
+        model, whose exact blocks go a window at a time."""
+        from ray_tpu.ops.paged_attention import run_blocks
+        if (self.eva is not None
+                or type(self)._attend_pages is not LlamaModel._attend_pages):
+            return 1
+        return run_blocks(block_size, self.cfg.n_kv_heads, self.cfg.head_dim,
+                          jnp.dtype(self.kv_dtype).itemsize)
+
     def decode_step_paged(self, params: Params, tokens: jax.Array,
                           pool: Params, block_tables: jax.Array,
-                          offsets: jax.Array
+                          offsets: jax.Array, run: int = 1
                           ) -> Tuple[jax.Array, Params]:
         """One decode step for every slot against the block pool.
 
@@ -1081,11 +1095,15 @@ class LlamaModel:
                a model with kinds also [kinds, B, MAXB], a table a kind
                (given one table, every kind reads it)
         offsets [B] tokens already cached per slot
+        run    blocks the tables lay in aligned, contiguous runs of,
+               which the kernel then copies as one page
+               (``paged_decode_attention``); a sliding kind's table
+               lays none, whatever ``run`` says
         Returns (logits [B, V], updated pool). Slots whose table rows
         point at garbage simply compute garbage that the engine masks.
         """
         return self.decode_step_paged_counted(
-            params, tokens, pool, block_tables, offsets)[:2]
+            params, tokens, pool, block_tables, offsets, run=run)[:2]
 
     def ffn_load_shape(self) -> Optional[Tuple[int, int]]:
         """Shape of the per-layer counts ``decode_step_paged_counted``
@@ -1112,7 +1130,8 @@ class LlamaModel:
     def decode_step_paged_counted(self, params: Params, tokens: jax.Array,
                                   pool: Params, block_tables: jax.Array,
                                   offsets: jax.Array,
-                                  live: Optional[jax.Array] = None):
+                                  live: Optional[jax.Array] = None,
+                                  run: int = 1):
         """``decode_step_paged`` and, third, each layer's ``_ffn`` extra
         stacked over layers (``None`` for the dense layer), counted over
         the slots ``live`` [B] bool marks.
@@ -1137,6 +1156,12 @@ class LlamaModel:
         skips, the rows behind its window) or a pool a kind
         (``init_kv_pools``: the stack as it comes, ``bases`` beside it)
         with a table a kind.
+
+        ``run`` > 1 (``decode_step_paged``): the tables lay blocks in
+        runs and the kernel's page is a run. Where there are kinds only
+        the FULL kind's table lays them so (a sliding layer's blocks go
+        one at a time behind its window), and the layer's kind picks the
+        call: the kernel over runs, or over single blocks.
 
         A model's ``WHOLE_LAYER_LEAVES`` (an expert model's stacks) live
         through the step whole too: the body closes over them and
@@ -1188,12 +1213,19 @@ class LlamaModel:
                         k_new[:, 0])
                     v_all = v_pool.at[base + own(dest_block), dest_off].set(
                         v_new[:, 0])
-                with jax.named_scope("attention"):
-                    o = self._attend_pages(
+                def pages(blocks):       # a page: so many blocks
+                    return self._attend_pages(
                         jax.tree.map(lambda a: a[:, 0], q), k_all, v_all,
                         layer, own(block_tables),
                         lengths, impl=impl, starts=own(starts),
-                        first_block=base, num_blocks=NB)
+                        first_block=base, num_blocks=NB, run=blocks)
+
+                with jax.named_scope("attention"):
+                    if run > 1 and kind is not None:
+                        o = jax.lax.cond(kind == FULL, lambda: pages(run),
+                                         lambda: pages(1))
+                    else:
+                        o = pages(run)
                 return o[:, None], (k_all, v_all)
 
             x, (k_pool, v_pool), extra = self._layer(
@@ -1218,7 +1250,7 @@ class LlamaModel:
                                  offsets: jax.Array,
                                  live: Optional[jax.Array] = None,
                                  behind: Optional[Tuple[jax.Array, ...]]
-                                 = None):
+                                 = None, run: int = 1):
         """One pass over every slot's CURRENT BLOCK against the block
         pool (a model with ``block_length`` > 1): tokens [B, n] at
         positions ``offsets .. offsets + n - 1`` (``offsets`` [B]
@@ -1252,7 +1284,7 @@ class LlamaModel:
         The paged attention is the decode step's, kernel and reference
         alike: its head axis carries the block's rows (q [B, n, H, hd]
         laid KV-head-major as [B, Hkv * n * H/Hkv, hd]: all rows of a
-        slot's block see one length)."""
+        slot's block see one length); ``run`` is the decode step's."""
         cfg = self.cfg
         if type(self)._attend_pages is not LlamaModel._attend_pages:
             raise NotImplementedError(
@@ -1313,7 +1345,7 @@ class LlamaModel:
                     o = self._attend_pages(
                         rows, k_all, v_all, layer, block_tables, lengths,
                         impl=impl, starts=None, first_block=base,
-                        num_blocks=NB)
+                        num_blocks=NB, run=run)
                     o = o.reshape(R, Hkv, n, H // Hkv, -1).transpose(
                         0, 2, 1, 3, 4).reshape(R, n, H, -1)
                 return o, (k_all, v_all)

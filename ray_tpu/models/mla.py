@@ -523,10 +523,14 @@ class MLAModel(MoEModel):
 
     def _attend_pages(self, q, k_pool, v_pool, layer: Params, block_tables,
                       lengths, *, impl, starts=None, first_block=0,
-                      num_blocks=None):
+                      num_blocks=None, run=1):
         """The ABSORBED form: q [B, H, nope + rope] against the latent
         pages (the pools of ``"k"`` and ``"v"``); no row of the cache is
-        up-projected."""
+        up-projected. Its kernels copy a block a page (``run`` 1:
+        ``paged_run_blocks`` says so to the engine)."""
+        if run != 1:
+            raise NotImplementedError(
+                "the latent pages' kernels copy single blocks")
         cfg: MLAConfig = self.cfg
         dt, nope = cfg.dtype, cfg.qk_nope_head_dim
         if self.indexed:

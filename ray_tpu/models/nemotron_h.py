@@ -536,7 +536,8 @@ class NemotronHModel(LlamaModel):
     def decode_step_paged_counted(self, params: Params, tokens: jax.Array,
                                   pool: Params, block_tables: jax.Array,
                                   offsets: jax.Array,
-                                  live: Optional[jax.Array] = None):
+                                  live: Optional[jax.Array] = None,
+                                  run: int = 1):
         """One decode step for every slot: the attention layers against
         the block pool (``LlamaModel``'s: the pool as ONE stack ``[La*NB,
         ...]``, layer ``j``'s pages from ``j*NB`` on), the Mamba layers
@@ -546,7 +547,8 @@ class NemotronHModel(LlamaModel):
         ``ops.ssm.state_step`` whole, layer ``j``'s rows from ``j*rows``
         on, ``rows`` the state's rows a layer, which is the batch). A slot that is idle computes on whatever its row holds; its
         next tenant's activation overwrites the row.
-        -> (logits [B, V], the pool, {"load": [Le, E], ...} or None)."""
+        -> (logits [B, V], the pool, {"load": [Le, E], ...} or None).
+        ``run``: ``LlamaModel.decode_step_paged``'s."""
         cfg = self.cfg
         B = tokens.shape[0]
         impl = self.paged_decode_impl()
@@ -585,7 +587,7 @@ class NemotronHModel(LlamaModel):
                     o = self._attend_pages(
                         q[:, 0], k_all, v_all, None, block_tables, lengths,
                         impl=impl, starts=None, first_block=j * NB,
-                        num_blocks=NB)
+                        num_blocks=NB, run=run)
                 pools["kv"] = (k_all, v_all)
                 return o[:, None], None
             return attend

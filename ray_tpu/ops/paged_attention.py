@@ -267,6 +267,20 @@ def _paged_kernel(*refs, block_size: int, pages: int, max_blocks: int,
 # one-page slot 0.055 ms for 0.038 (PERF.md, PR 25). 8 of those pages:
 CHUNK_ROWS = 2048
 LANES = 128
+# Bytes a page copy should move. A copy's descriptor costs this chip
+# ~17 ns to start and ~3 ns to wait for whatever it moves
+# (``tools/dsa_row_copy_bench.py``), a chunk's are issued one after
+# another, and HBM moves ~16 KB in that time: under it the descriptors,
+# not the bytes, are the kernel's time. The kernel alone on the v5e at
+# 32-row blocks copied 1 / 2 / 4 / 8 to a page, ms
+# (``tools/paged_run_readings.py``, PERF.md, PR 58): ONE K/V head of 128
+# (8 KB a block; 0.38 ms of bytes) 1.19 / 0.71 / 0.47 / 0.46; two heads
+# (16 KB; 0.76) 1.42 / 0.94 / 0.90 / 0.90; four (32 KB; 0.80) 0.94 /
+# 0.90 / 0.90, and under 128 query rows a slot (0.42) 0.70 / 0.57 /
+# 0.56; eight (64 KB; 0.32) 0.375 / 0.381. A copy of 32 KB still gains
+# from being 64, one of 64 KB gains nothing more: 64 KB, which is also
+# the page the chunk sweep above was tuned at.
+RUN_BYTES = 64 * 1024
 
 
 def _lane_pack(head_dim: int, kv_heads: int) -> int:
@@ -274,6 +288,22 @@ def _lane_pack(head_dim: int, kv_heads: int) -> int:
     lanes, where the head count divides so."""
     return math.gcd(LANES // head_dim, kv_heads) if LANES % head_dim == 0 \
         else 1
+
+
+def run_blocks(block_size: int, kv_heads: int, head_dim: int,
+               itemsize: int) -> int:
+    """Blocks of a pool the kernel should copy as ONE page, a RUN: the
+    smallest power of two whose K (or V) rows reach ``RUN_BYTES``, as far
+    as one chunk holds it. 1 where a block alone is that large already
+    (8 K/V heads of 128 in bf16 at 32 rows). A run is a page only where
+    the allocator lays a slot's blocks in aligned, contiguous runs
+    (``llm/paged_cache.py``, ``BlockPool(run=...)``)."""
+    row_heads = kv_heads // _lane_pack(head_dim, kv_heads)
+    run = 1
+    while (run * block_size * kv_heads * head_dim * itemsize < RUN_BYTES
+           and 2 * run * block_size * row_heads <= CHUNK_ROWS):
+        run *= 2
+    return run
 
 
 def kernel_lowers(head_dim: int, kv_heads: int) -> bool:
@@ -409,7 +439,7 @@ def paged_decode_attention(q, k_pool, v_pool, block_tables, lengths, *,
                            impl: str, starts=None, first_block=0,
                            num_blocks: Optional[int] = None,
                            scale: Optional[float] = None,
-                           stats: bool = False):
+                           stats: bool = False, run: int = 1):
     """One algorithm, two implementations: ``impl`` is "pallas" (the
     kernel, interpreted where the backend is the CPU) or "xla" (its
     oracle).
@@ -426,7 +456,30 @@ def paged_decode_attention(q, k_pool, v_pool, block_tables, lengths, *,
     the softmax's output with its running max and sum [B, H]: ONE PART
     of a softmax over several page lists, which the caller joins
     (``ops.eva.merge_softmax_parts``: an EVA layer's exact pages and its
-    summary pages). Without it both sides are the programs they were."""
+    summary pages). Without it both sides are the programs they were.
+
+    ``run`` (static) > 1: every slot's blocks lie in aligned, contiguous
+    RUNS of that many (``table[r*run + k] == table[r*run] + k``, the
+    first a multiple of ``run``, as ``BlockPool(run=...)`` lays them; a
+    window's first and number of blocks multiples too), and a run is the
+    page: the pools are viewed as ``[NB/run, run*bs, Hkv, D]`` (the same
+    bytes) under the table of runs, so a narrow pool's copies are
+    ``run`` times as large and as few (``run_blocks``). The rows, their
+    order and the chunks are those of ``run`` 1; the rows of a run past
+    a slot's length are masked as a page's tail is."""
+    if run > 1:
+        if block_tables.shape[1] % run or k_pool.shape[0] % run or (
+                num_blocks is not None and num_blocks % run):
+            raise ValueError(
+                f"runs of {run} blocks: the table's width "
+                f"{block_tables.shape[1]}, the pools' {k_pool.shape[0]} "
+                f"blocks and a window's {num_blocks} must be multiples")
+        k_pool, v_pool = (pool.reshape(
+            (pool.shape[0] // run, run * pool.shape[1]) + pool.shape[2:])
+            for pool in (k_pool, v_pool))
+        block_tables = block_tables[:, ::run] // run
+        first_block = first_block // run
+        num_blocks = None if num_blocks is None else num_blocks // run
     if impl == "pallas":
         return paged_decode_attention_pallas(
             q, k_pool, v_pool, block_tables, lengths, starts,

@@ -169,15 +169,23 @@ def _engine_params(model):
                           jax.random.key(0))
 
 
-def _engine_decode(model, num_blocks):
+def _engine_decode(model, num_blocks, run=1):
     """The engine's decode program (the model's step and the sampler,
     ``_decode_step_paged``) of an engine that is never built: building
-    one allocates its pool, and a described chip holds no array."""
+    one allocates its pool, and a described chip holds no array.
+    ``run``: the blocks its kernel copies as one (``kv_run``: the pool's
+    windows and the table are then whole runs, as the engine sizes
+    them)."""
     from ray_tpu.llm.engine import ContinuousBatchingEngine
 
     eng = object.__new__(ContinuousBatchingEngine)
-    eng.model, eng.num_blocks = model, num_blocks
+    eng.model, eng.num_blocks, eng.kv_run = model, num_blocks, run
     return jax.jit(eng._decode_step_paged, donate_argnums=(2,))
+
+
+def _whole_runs(blocks, run):
+    """A window of the pool as the engine sizes it: whole runs."""
+    return -(-blocks // run) * run
 
 
 def _sampling(v5e, B):
@@ -446,10 +454,14 @@ def test_hybrid_cell_decode_program_fits_the_v5e(v5e, placed, monkeypatch):
     window = B * window_blocks_per_slot(cfg["sliding_window"], bs, 512)
     assert window == B * 50
 
+    # the full kind's blocks lie in runs of 2 (32 KB a block), and every
+    # layer's window of the stack is whole runs
+    run = model.paged_run_blocks(bs)
+    assert run == 2
     pool = jax.eval_shape(lambda: model.init_kv_pools(
-        (full + 1, window + 1), bs))
-    assert pool["k"].shape[0] == 2 * (full + 1) + 6 * (window + 1)
-    compiled = _engine_decode(model, full).lower(
+        (full + 2, window + 2), bs))
+    assert pool["k"].shape[0] == 2 * (full + 2) + 6 * (window + 2)
+    compiled = _engine_decode(model, full, run).lower(
         placed(_engine_params(model)), v5e(B, dtype=jnp.int32), placed(pool),
         v5e(2, B, maxb, dtype=jnp.int32), v5e(B, dtype=jnp.int32),
         *_sampling(v5e, B), v5e(L, E, dtype=jnp.int32)).compile()
@@ -1010,14 +1022,17 @@ def test_ssm_cell_decode_program_fits_the_v5e(v5e, placed, monkeypatch):
     assert plan["moe_grouped_impl"] == "pallas_gmm"
     assert plan["moe_gmm_tiling_up"] == "128x512x2688"
     assert plan["moe_gmm_tiling_down"] == "128x2688x512"
-    pool = jax.eval_shape(lambda: model.init_kv_pool(B * maxb + 1, bs, B))
-    assert pool["k"].shape == (1, B * maxb + 1, bs, 2, 128)
+    run = model.paged_run_blocks(bs)
+    assert run == 4                     # 16 KB a block: 64 KB a copy
+    NB = _whole_runs(B * maxb + 1, run)
+    pool = jax.eval_shape(lambda: model.init_kv_pool(NB, bs, B))
+    assert pool["k"].shape == (1, NB, bs, 2, 128)
     assert pool["ssm"].shape == (5, B, 8, 128, 1024)
     assert pool["ssm"].dtype == jnp.float32
     assert pool["conv"].shape == (5, B, 3, 10240)
     params = _engine_params(model)
     assert sum(a.size for a in jax.tree.leaves(params)) == 4_648_163_712
-    compiled = _engine_decode(model, B * maxb).lower(
+    compiled = _engine_decode(model, B * maxb, run).lower(
         placed(params), v5e(B, dtype=jnp.int32), placed(pool),
         v5e(B, maxb, dtype=jnp.int32), v5e(B, dtype=jnp.int32),
         *_sampling(v5e, B), v5e(5, 512, dtype=jnp.int32)).compile()
@@ -1091,7 +1106,9 @@ def test_sdar_cell_decode_program_fits_the_v5e(v5e, placed, monkeypatch):
     eng = object.__new__(ContinuousBatchingEngine)
     eng.model, eng.num_blocks, eng.block_length = model, B * maxb, n
     eng._behind_slots = room
-    pool = jax.eval_shape(lambda: model.init_kv_pool(B * maxb + 1, bs))
+    eng.kv_run = model.paged_run_blocks(bs)
+    assert eng.kv_run == 2              # 32 KB a block: 64 KB a copy
+    pool = jax.eval_shape(lambda: model.init_kv_pool(B * maxb + 2, bs))
     compiled = jax.jit(eng._decode_step_paged_blocks,
                        donate_argnums=(2,)).lower(
         placed(params), v5e(B, 3 * n + 2, dtype=jnp.int32), placed(pool),
@@ -1155,7 +1172,10 @@ def test_mamba1_cell_decode_program_fits_the_v5e(v5e, placed, monkeypatch):
     K/V head under 20 query heads. The v5e's compiler takes it at 6.69
     GiB of 15.75 (5.65 of weights, 1.03 of pool and state, 0.02 of
     temporaries): no layer's slice of a weight stack, no stack of state
-    and no [32, 16, 5120] decay is among the temporaries."""
+    and no [32, 16, 5120] decay is among the temporaries. The pool's
+    blocks of 8 KB lie in runs of 8 and the two attention calls read it
+    as pages of 256 rows, a view that costs nothing: no pool-sized
+    temporary."""
     from benchmark import run as harness
     from benchmark.builders import jamba
 
@@ -1165,14 +1185,17 @@ def test_mamba1_cell_decode_program_fits_the_v5e(v5e, placed, monkeypatch):
     model = jamba.build_model(cfg, maxb * bs)
     assert model.recurrent and model.paged_decode_impl() == "pallas"
     assert model.ffn_load_shape() is None
-    pool = jax.eval_shape(lambda: model.init_kv_pool(B * maxb + 1, bs, B))
-    assert pool["k"].shape == (2, B * maxb + 1, bs, 1, 128)
+    run = model.paged_run_blocks(bs)
+    assert run == 8                     # 8 KB a block: 64 KB a copy
+    NB = _whole_runs(B * maxb + 1, run)
+    pool = jax.eval_shape(lambda: model.init_kv_pool(NB, bs, B))
+    assert pool["k"].shape == (2, NB, bs, 1, 128)
     assert pool["ssm"].shape == (26, B, 1, 16, 5120)
     assert pool["ssm"].dtype == jnp.float32
     assert pool["conv"].shape == (26, B, 3, 5120)
     params = _engine_params(model)
     assert sum(a.size for a in jax.tree.leaves(params)) == 3_029_337_472
-    compiled = _engine_decode(model, B * maxb).lower(
+    compiled = _engine_decode(model, B * maxb, run).lower(
         placed(params), v5e(B, dtype=jnp.int32), placed(pool),
         v5e(B, maxb, dtype=jnp.int32), v5e(B, dtype=jnp.int32),
         *_sampling(v5e, B), None).compile()
@@ -1180,6 +1203,15 @@ def test_mamba1_cell_decode_program_fits_the_v5e(v5e, placed, monkeypatch):
     # a state kernel a RUN of Mamba layers, an attention kernel a layer
     assert text.count("ssm1_state_update_pallas") >= 3
     assert text.count("tpu_custom_call") >= 5
+    # both attention calls take the stack as pages of 256 rows (a run of
+    # 8 blocks of 32), none as pages of 32
+    paged = [line for line in text.splitlines()
+             if "tpu_custom_call" in line
+             and "paged_decode_attention_pallas" in line]
+    assert len(paged) == 2
+    for line in paged:
+        assert f"bf16[{2 * NB // run},{run * bs},128]" in line
+        assert f"bf16[{2 * NB},{bs},128]" not in line
     mem = compiled.memory_analysis()
     total = (mem.argument_size_in_bytes + mem.temp_size_in_bytes
              + mem.output_size_in_bytes - mem.alias_size_in_bytes)

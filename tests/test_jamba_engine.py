@@ -107,6 +107,82 @@ def test_a_reused_slot_sees_no_stale_state(built):
     assert eng.stats["state_rows_written"] == 3
 
 
+def _tables_lie_in_runs(eng):
+    """Every live slot's table is made of aligned, contiguous runs, and
+    no block is in two slots' (a recurrent model shares none)."""
+    run, seen = eng.kv_run, set()
+    for slot, alloc in enumerate(eng.allocs):
+        if alloc is None:
+            continue
+        blocks = list(eng._tables[slot, :len(alloc.blocks)])
+        assert blocks == alloc.blocks and len(blocks) % run == 0
+        for r in range(0, len(blocks), run):
+            assert blocks[r] % run == 0
+            assert blocks[r:r + run] == list(range(blocks[r],
+                                                   blocks[r] + run))
+        assert not seen & set(blocks)
+        seen |= set(blocks)
+    assert len(seen) + eng.pool.num_free == eng.num_blocks // run * run
+
+
+def runs_decode_what_single_blocks_decode(built, monkeypatch, engine, alone,
+                                          lens, outs, max_seq, num_blocks,
+                                          run):
+    """Requests of ``lens`` prompt and ``outs`` output tokens through an
+    engine whose kernel (forced, interpreted) copies runs of ``run``
+    blocks, in a pool small enough to preempt: every table lies in runs
+    at every step, and the greedy tokens equal those of an engine whose
+    allocator is told ``run`` 1 (single blocks, the parent's layout:
+    ``paged_run_blocks`` patched, a test's argument and not a user's)
+    and a fresh engine's, a request at a time. ``engine`` / ``alone``:
+    the calling module's."""
+    cfg, _, params = built
+    model = model_for(dataclasses.replace(cfg, decode_attention="pallas"))
+    prompts = [_prompt(cfg, n, 20 + i) for i, n in enumerate(lens)]
+
+    def drive(eng):
+        with jax.default_matmul_precision("highest"):
+            reqs = [eng.submit(p, SamplingParams(max_tokens=n))
+                    for p, n in zip(prompts, outs)]
+            while eng.has_work():
+                eng.step()
+                _tables_lie_in_runs(eng)
+                ahead = eng.stats["kv_blocks_reserved_unfilled"]
+                assert ahead <= eng.kv_run * sum(
+                    a is not None for a in eng.allocs)
+        return [r.output for r in reqs]
+
+    runs = engine(model, params, max_seq=max_seq, num_blocks=num_blocks)
+    assert runs.kv_run == runs.pool.run == runs.stats["kv_run_blocks"] == run
+    assert runs.kv["k"].shape[1] == num_blocks + run       # a scratch RUN
+    got = drive(runs)
+    assert runs.stats["preemptions"] > 0
+    assert runs.pool.num_free == num_blocks
+    assert runs.stats["kv_blocks_reserved_unfilled"] == 0
+
+    monkeypatch.setattr(type(model), "paged_run_blocks",
+                        lambda self, block_size: 1)
+    single = engine(model, params, max_seq=max_seq, num_blocks=num_blocks)
+    assert single.kv_run == single.pool.run == 1
+    assert single.stats["kv_run_blocks"] == 1
+    assert single.kv["k"].shape[1] == num_blocks + 1
+    assert drive(single) == got
+    for p, n, out in zip(prompts, outs, got):
+        assert out == alone(model, params, p, n)
+
+
+def test_blocks_in_runs_decode_what_single_blocks_decode(built, monkeypatch):
+    """The claimed cell's mechanism at debug widths: ONE K/V head, a
+    block of 8 rows, runs of 8 (what a table of 12 blocks holds), the
+    kernel interpreted over pages of 64 rows. Four requests through
+    three slots of a pool of TWO runs: a slot that grows into a second
+    run finds none and the youngest is preempted, refilled later and
+    re-prefilled."""
+    runs_decode_what_single_blocks_decode(
+        built, monkeypatch, engine, alone, lens=(42, 13, 9, 30),
+        outs=(30, 12, 7, 8), max_seq=96, num_blocks=16, run=8)
+
+
 def test_a_prefix_hit_is_refused_and_counted(built):
     """Two requests with a shared prefix of two blocks, one after the
     other: the second finds the first's pages in the index and does NOT

@@ -96,6 +96,196 @@ def test_ensure_capacity_growth_and_exhaustion():
 
 
 # ---------------------------------------------------------------------------
+# Blocks in runs (a narrow pool's blocks lie in aligned, contiguous runs)
+# ---------------------------------------------------------------------------
+
+@pytest.mark.parametrize("config,kv_heads,head_dim,run", [
+    ("jamba2-3b", 1, 128, 8),
+    ("nemotron-3-super-d11", 2, 128, 4),
+    ("mellum2-12b-a2.5b-d8", 4, 128, 2),
+    ("sdar-30b-a3b-chat-d6", 4, 128, 2),
+    ("mistral-7b-v0.3-d6", 8, 128, 1),
+    ("olmoe-1b-7b-d3", 16, 128, 1),
+    ("evabyte-6.5b-d8", 32, 128, 1),
+    ("llama3_1b", 8, 64, 2),          # two heads a row: 32 KB a block
+])
+def test_run_blocks_by_the_pools_row(config, kv_heads, head_dim, run):
+    """The rule at the serve cells' block of 32 rows in bf16: the
+    smallest power of two of blocks that reaches 64 KiB."""
+    from ray_tpu.ops.paged_attention import RUN_BYTES, run_blocks
+    assert RUN_BYTES == 64 * 1024
+    assert run_blocks(32, kv_heads, head_dim, 2) == run
+
+
+def test_run_blocks_stops_at_a_chunk_and_at_a_large_block(monkeypatch):
+    from ray_tpu.ops import paged_attention
+    from ray_tpu.ops.paged_attention import run_blocks
+    assert run_blocks(256, 1, 128, 2) == 1          # 64 KB a block already
+    assert run_blocks(16, 1, 128, 2) == 16
+    # float32 debug widths: 2 KB a block, two heads a row of the lanes
+    assert run_blocks(8, 2, 32, 4) == 32
+    # a run never outgrows the kernel's chunk of rows
+    monkeypatch.setattr(paged_attention, "CHUNK_ROWS", 128)
+    assert run_blocks(32, 1, 128, 2) == 4
+    assert run_blocks(32, 2, 128, 2) == 2
+    assert run_blocks(32, 2, 64, 2) == 4            # two heads a row
+
+
+def _drive_pool(pc, pool, seed, steps=600, slots=5):
+    """A random sequence of admit / grow / preempt / release on ``pool``
+    through ``pc``'s functions (``ray_tpu.llm.paged_cache``, or the
+    parent commit's), as an engine makes them: prompts that share
+    prefixes, growth a few tokens at a time, exhaustion preempting the
+    youngest first. Yields after every operation ``(what, live)``:
+    ``live`` maps a slot to its allocation."""
+    rng = np.random.default_rng(seed)
+    bs = pool.block_size
+    stems = [rng.integers(1, 50, size=20 * bs).tolist() for _ in range(3)]
+    live, order = {}, []          # slot -> [alloc, tokens held]; oldest first
+
+    def release(slot):
+        pool.unref_all(live.pop(slot)[0].blocks)
+        order.remove(slot)
+
+    for _ in range(steps):
+        op = str(rng.choice(["admit", "grow", "grow", "grow", "release",
+                             "preempt"]))
+        if op == "admit" and len(live) < slots:
+            stem = stems[rng.integers(len(stems))]
+            prompt = (stem[:int(rng.integers(1, len(stem)))]
+                      + rng.integers(50, 99, size=int(rng.integers(0, bs))
+                                     ).tolist())
+            got = pc.allocate_slot(pool, prompt, len(prompt) + 1)
+            if got is None:
+                yield ("full", None, None), live
+                continue
+            alloc, shared = got
+            pc.seal_prompt_blocks(pool, alloc, prompt)
+            slot = min(set(range(slots)) - set(live))
+            live[slot] = [alloc, len(prompt) + 1]
+            order.append(slot)
+            yield ("admit", shared, tuple(alloc.blocks)), live
+        elif op == "grow" and live:
+            slot = int(rng.choice(sorted(live)))
+            alloc = live[slot][0]
+            live[slot][1] += int(rng.integers(1, 3 * bs))
+            preempted = []
+            while not pc.ensure_capacity(pool, alloc, live[slot][1]):
+                victim = ([s for s in order if s != slot] or [slot])[-1]
+                preempted.append(victim)
+                release(victim)
+                if victim == slot:
+                    break
+            yield ("grow", tuple(preempted), tuple(alloc.blocks)), live
+        elif op in ("release", "preempt") and live:
+            slot = order[-1] if op == "preempt" else int(
+                rng.choice(sorted(live)))
+            release(slot)
+            yield (op, slot, None), live
+
+
+@pytest.mark.parametrize("run", [2, 4, 8])
+@pytest.mark.parametrize("seed", [0, 1])
+def test_pool_of_runs_keeps_its_invariants(run, seed):
+    """Every table aligned and contiguous a run; a block's refcount is
+    the number of slots that hold it (a fresh block is in one slot); a
+    run is free only when whole; ``num_free`` is what ``alloc`` can
+    hand out; exhaustion preempted the youngest first."""
+    from ray_tpu.llm import paged_cache as pc
+    pool = pc.BlockPool(16 * run + run - 1, 4, run)   # the leftover: never out
+    usable = 16 * run
+    grown = shared_hits = 0
+    for (what, a, b), live in _drive_pool(pc, pool, seed):
+        holders = np.zeros(pool.num_blocks, int)
+        for alloc, tokens in live.values():
+            blocks = alloc.blocks
+            assert len(blocks) % run == 0
+            assert len(blocks) * 4 >= tokens
+            assert len(blocks) * 4 - tokens < run * 4       # no run too many
+            assert pool.whole_runs(blocks) == len(blocks)
+            for r in range(0, len(blocks), run):
+                assert blocks[r] % run == 0
+                assert blocks[r:r + run] == list(
+                    range(blocks[r], blocks[r] + run))
+            holders[blocks] += 1
+        assert holders.tolist() == pool.refcount
+        assert not holders[usable:].any()
+        held_runs = {b // run for b in np.flatnonzero(holders)}
+        assert set(pool._free) == set(range(16)) - held_runs
+        assert pool.num_free == (16 - len(held_runs)) * run
+        if what == "admit":
+            assert a % (run * 4) == 0                  # a hit in whole runs
+            shared_hits += a > 0
+        if what == "grow":
+            grown += 1
+    assert grown > 50 and shared_hits > 3
+    for alloc, _ in list(live.values()):
+        pool.unref_all(alloc.blocks)
+    assert pool.num_free == usable
+    got = pool.alloc(usable)
+    assert sorted(got) == list(range(usable)) and pool.alloc(1) is None
+
+
+# What ``_drive_pool`` logged on the PARENT commit's ``BlockPool(40, 4)``
+# (59f2076, before a pool knew of runs): the digest of the whole log of
+# each seed, its length, and its last allocation's blocks. Recorded by
+# running this file's driver on ``git show 59f2076:ray_tpu/llm/
+# paged_cache.py``.
+_PARENT_POOL_LOGS = {
+    0: ("de2d1631589e45a21b698f2b1d7de84b"
+        "70f82cb7935f9f853c1fed5ff8dd553a", 243,
+        (26,)),
+    1: ("22d0e6c0266129b975c57e206c8ab7cd"
+        "c0c97e72c1c7176a8d87401df138d990", 315,
+        (21, 18, 17, 26, 35, 22, 27, 12, 8, 10, 4, 5, 33, 28)),
+    2: ("596fe8e0bb0c8b08cfec6bb9430915ba"
+        "d9454aaeeb73e8480391fe9da96f7e13", 307,
+        (27, 37, 36, 16, 18, 14, 0)),
+}
+
+
+@pytest.mark.parametrize("seed", sorted(_PARENT_POOL_LOGS))
+def test_pool_of_single_blocks_is_the_parents(seed):
+    """``run`` 1 (the default): the same sequence hands out the SAME
+    block ids, in the same order, as the allocator did before it knew of
+    runs."""
+    import hashlib
+    from ray_tpu.llm import paged_cache as pc
+    for pool in (pc.BlockPool(40, 4), pc.BlockPool(40, 4, run=1)):
+        log = [entry for entry, _ in _drive_pool(pc, pool, seed)]
+        last = [e[2] for e in log if e[2] is not None][-1]
+        digest = hashlib.sha256(repr(log).encode()).hexdigest()
+        assert (digest, len(log), last) == _PARENT_POOL_LOGS[seed]
+
+
+def test_prefix_hit_ends_where_the_blocks_stop_lying_as_one_run():
+    """Two slots share a stem's first run and each seals what follows:
+    the index then holds blocks of two slots for one chain, and a third
+    request takes the whole runs only."""
+    from ray_tpu.llm.paged_cache import (BlockPool, allocate_slot,
+                                         seal_prompt_blocks)
+    pool = BlockPool(32, 2, run=4)
+    stem = list(range(1, 13))                      # 6 blocks
+    first, _ = allocate_slot(pool, stem + [90, 91, 92, 93], 16)
+    seal_prompt_blocks(pool, first, stem + [90, 91, 92, 93])
+    assert first.blocks == list(range(8))
+    # 6 blocks match, 4 lie as a whole run: the hit is 8 tokens, not 12
+    second, shared = allocate_slot(pool, stem + [80, 81, 82, 83], 16)
+    assert shared == 8 and second.blocks == [0, 1, 2, 3, 8, 9, 10, 11]
+    seal_prompt_blocks(pool, second, stem + [80, 81, 82, 83])
+    # blocks 4, 5 of the chain are the first slot's, 6, 7 the second's
+    third, shared = allocate_slot(pool, stem + [80, 81, 82, 83, 70], 18)
+    assert shared == 8 and third.blocks[:4] == [0, 1, 2, 3]
+    assert pool.whole_runs(third.blocks) == len(third.blocks) == 12
+    # a run goes back when its LAST holder lets go of it
+    for alloc in (first, second):
+        pool.unref_all(alloc.blocks)
+    assert pool.num_free == 32 - 12
+    pool.unref_all(third.blocks)
+    assert pool.num_free == 32
+
+
+# ---------------------------------------------------------------------------
 # Engine end-to-end on the debug model
 # ---------------------------------------------------------------------------
 
@@ -174,6 +364,47 @@ def test_prefix_reuse_concurrent_requests(tiny_model):
     assert eng.stats["prefix_tokens_reused"] == 16
     for p, r in zip(prompts, reqs):
         assert r.output == _greedy(model, params, p, 8)
+
+
+def test_engine_shares_a_prefix_in_whole_runs(tiny_model):
+    """The kernel forced (interpreted) on the debug model: 2 KB blocks,
+    so runs of 8 (a slot's table of 15 holds no longer one). Three
+    requests on one stem of 9 blocks decode together: each takes the
+    stem's one whole run (64 of its 72 tokens) from the first, its
+    blocks are referenced by all three, every table lies in runs, and
+    the tokens are the uncached greedy ones. A request that ends in a
+    run's second block holds all of it: the rest is counted reserved."""
+    import dataclasses
+    model, params = tiny_model
+    forced = LlamaModel(dataclasses.replace(model.cfg,
+                                            decode_attention="pallas"))
+    eng = ContinuousBatchingEngine(forced, params, max_slots=4, max_seq=120,
+                                   prefill_buckets=(8, 16, 32),
+                                   block_size=8)
+    assert eng.kv_run == eng.pool.run == 8 == eng.stats["kv_run_blocks"]
+    # a slot's 15 blocks are two runs, in the pool and in its table
+    assert eng.num_blocks == 4 * 16 and eng.kv["k"].shape[1] == 4 * 16 + 8
+    assert eng.blocks_per_slot == 15 and eng._tables.shape == (4, 16)
+    stem = [(7 * i + 3) % 500 for i in range(72)]
+    prompts = [stem + [100 + i, 7] for i in range(3)]
+    r0 = eng.submit(prompts[0], SamplingParams(max_tokens=6))
+    eng.step()                       # chunked prefill, the stem sealed
+    assert eng.stats["kv_blocks_reserved_unfilled"] == 16 - 74 // 8 - 1
+    rest = [eng.submit(p, SamplingParams(max_tokens=6))
+            for p in prompts[1:]]
+    eng.step()
+    assert eng.stats["prefix_prefills"] == 2
+    assert eng.stats["prefix_tokens_reused"] == 2 * 64
+    for alloc in eng.allocs[:3]:
+        assert alloc.blocks[:8] == eng.allocs[0].blocks[:8]
+        assert eng.pool.whole_runs(alloc.blocks) == len(alloc.blocks) == 16
+    assert [eng.pool.refcount[b] for b in eng.allocs[0].blocks] == (
+        [3] * 8 + [1] * 8)
+    while eng.has_work():
+        eng.step()
+    assert eng.pool.num_free == 4 * 16
+    for p, r in zip(prompts, [r0] + rest):
+        assert r.output == _greedy(model, params, p, 6)
 
 
 def test_preemption_by_recompute(tiny_model):
@@ -325,6 +556,130 @@ def test_paged_attention_reads_a_window_of_a_stack(case):
         other = paged_decode_attention(q, k3, v3, tables, lengths, impl=impl,
                                        first_block=2 * NB, num_blocks=NB)
         assert float(jnp.max(jnp.abs(other - alone))) > 0.5
+
+
+def _pool_in_runs(run, lengths, *, bs=8, Hkv=1, D=128, maxb=32, windows=1,
+                  dtype=jnp.float32, seed=3):
+    """Kernel inputs as an engine of ``BlockPool(run=...)`` lays them:
+    every slot's table made of aligned runs of ``run`` blocks in a
+    random order, entries past the slot's last run the scratch block
+    (the first of a scratch run), ``windows`` windows of the pools one
+    after another with other values in the others."""
+    rng = np.random.default_rng(seed)
+    B = len(lengths)
+    NB = B * maxb + run                       # a window: whole runs
+    order = rng.permutation(B * maxb // run).reshape(B, maxb // run)
+    tables = (order[:, :, None] * run + np.arange(run)).reshape(B, maxb)
+    for b, n in enumerate(lengths):
+        tables[b, -(-n // (bs * run)) * run:] = B * maxb
+    q = jnp.asarray(rng.normal(size=(B, 4 * Hkv, D)), dtype)
+    kp, vp = (jnp.asarray(rng.normal(size=(windows * NB, bs, Hkv, D)),
+                          dtype) for _ in range(2))
+    return (q, kp, vp, jnp.asarray(tables, jnp.int32),
+            jnp.asarray(lengths, jnp.int32)), NB
+
+
+@pytest.mark.parametrize("case,run", [
+    ("one_head", 2), ("one_head", 4), ("one_head", 8), ("stats", 2),
+    ("stats", 8), ("gqa_packed_rows", 4), ("window", 8), ("sliding", 2)])
+def test_paged_kernel_over_runs_is_the_kernel_over_blocks(case, run,
+                                                          monkeypatch):
+    """``run`` > 1 reads the same rows in the same chunks: equal to the
+    reference, and BIT-equal to ``run`` 1 on the same pool, at ragged
+    lengths (0, 1, one that ends mid-run, a chunk's last row, the whole
+    table)."""
+    from ray_tpu.ops import paged_attention
+    from ray_tpu.ops.paged_attention import paged_decode_attention
+    monkeypatch.setattr(paged_attention, "CHUNK_ROWS", 128)   # 2 chunks
+    lengths = [0, 1, 37, 128, 256]
+    shape = {"gqa_packed_rows": dict(Hkv=2, D=64, bs=4, maxb=64)}.get(
+        case, {})
+    windows = 3 if case == "window" else 1
+    (q, kp, vp, tables, lens), NB = _pool_in_runs(run, lengths,
+                                                  windows=windows, **shape)
+    kw = {}
+    if case == "window":
+        kw = dict(first_block=jnp.int32(NB), num_blocks=NB)
+    if case == "stats":
+        kw = dict(stats=True)
+    if case == "sliding":       # a FULL layer beside sliding ones: starts 0
+        kw = dict(starts=jnp.zeros_like(lens))
+    call = jax.jit(functools.partial(paged_decode_attention, **kw),
+                   static_argnames=("impl", "run", "num_blocks", "stats"))
+    ref = call(q, kp, vp, tables, lens, impl="xla")
+    one = call(q, kp, vp, tables, lens, impl="pallas")
+    got = call(q, kp, vp, tables, lens, impl="pallas", run=run)
+    by_runs = call(q, kp, vp, tables, lens, impl="xla", run=run)
+    live = np.asarray(lens) > 0
+    for o, o1, r, rr in zip(*(x if case == "stats" else (x,)
+                              for x in (got, one, ref, by_runs))):
+        assert np.array_equal(np.asarray(o), np.asarray(o1))
+        np.testing.assert_allclose(np.asarray(o)[live], np.asarray(r)[live],
+                                   atol=2e-5, rtol=2e-5)
+        np.testing.assert_allclose(np.asarray(rr)[live],
+                                   np.asarray(r)[live], atol=2e-5, rtol=2e-5)
+    assert not np.asarray(got[0] if case == "stats" else got)[~live].any()
+
+
+def test_runs_need_tables_and_windows_of_whole_runs():
+    from ray_tpu.ops.paged_attention import paged_decode_attention
+    (q, kp, vp, tables, lens), NB = _pool_in_runs(4, [5, 70])
+    for bad in (dict(block_tables=tables[:, :30]), dict(k_pool=kp[:-1]),
+                dict(num_blocks=NB - 2)):
+        args = {**dict(q=q, k_pool=kp, v_pool=vp, block_tables=tables,
+                       lengths=lens), **bad}
+        with pytest.raises(ValueError, match="multiples"):
+            paged_decode_attention(**args, impl="pallas", run=4)
+
+
+def _primitives(jaxpr, into):
+    """Count every equation of ``jaxpr`` and of the jaxprs inside it."""
+    for eqn in jaxpr.eqns:
+        into[eqn.primitive.name] = into.get(eqn.primitive.name, 0) + 1
+        for value in eqn.params.values():
+            for inner in (value if isinstance(value, (list, tuple))
+                          else [value]):
+                inner = getattr(inner, "jaxpr", inner)
+                if hasattr(inner, "eqns"):
+                    _primitives(inner, into)
+    return into
+
+
+def test_kernel_at_run_1_is_the_parents_and_at_8_starts_a_copy_a_run():
+    """The claimed cell's call (20 q heads on ONE K/V head of 128, blocks
+    of 32 rows, the second layer's window of the stack). ``run`` 1, said
+    or not, traces what ``paged_decode_attention_pallas`` traces when
+    called as the parent commit called it, equation for equation: 2,201
+    of them, a chunk's 64 pages x (K, V) started in three places (the
+    call's first chunk, the slot's next, the next slot's first) and
+    waited for in one, as counted on the parent (59f2076). ``run`` 8:
+    the same program with 8 pages of 256 rows a chunk."""
+    import re
+    from ray_tpu.ops.paged_attention import (paged_decode_attention,
+                                             paged_decode_attention_pallas)
+    S = jax.ShapeDtypeStruct
+    pool = S((2 * 264, 32, 1, 128), jnp.bfloat16)
+    args = (S((4, 20, 128), jnp.bfloat16), pool, pool,
+            S((4, 96), jnp.int32), S((4,), jnp.int32))
+    window = dict(first_block=jnp.int32(264), num_blocks=264)
+
+    def program(fn):
+        jaxpr = jax.make_jaxpr(fn)(*args)
+        return (re.sub(r"0x[0-9a-f]+", "", str(jaxpr)),
+                _primitives(jaxpr.jaxpr, {}))
+
+    parents, counts = program(lambda *a: paged_decode_attention_pallas(
+        *a, None, scale=None, interpret=True, stats=False, **window))
+    assert sum(counts.values()) == 2201
+    assert (counts["dma_start"], counts["dma_wait"]) == (3 * 64 * 2, 64 * 2)
+    for said in ({}, {"run": 1}):
+        text, _ = program(lambda *a: paged_decode_attention(
+            *a, impl="pallas", **window, **said))
+        assert text == parents
+    _, counts = program(lambda *a: paged_decode_attention(
+        *a, impl="pallas", run=8, **window))
+    assert (counts["dma_start"], counts["dma_wait"]) == (3 * 8 * 2, 8 * 2)
+    assert counts["dot_general"] == 2
 
 
 @pytest.mark.parametrize("head_dim,kv_heads,pack,lowers", [

@@ -12,6 +12,7 @@ import numpy as np
 import pytest
 
 from ray_tpu.llm.engine import ContinuousBatchingEngine, SamplingParams
+from tests.test_jamba_engine import runs_decode_what_single_blocks_decode
 from tests.test_nemotron_h_serving import make
 
 KW = dict(max_slots=3, max_seq=96, prefill_buckets=(8, 16), block_size=8)
@@ -87,6 +88,17 @@ def test_preemption_by_recompute_rebuilds_the_state(built):
     assert eng.stats["preemptions"] > 0
     for p, req in zip(prompts, reqs):
         assert req.output == alone(model, params, p, 12)
+
+
+def test_blocks_in_runs_decode_what_single_blocks_decode(built, monkeypatch):
+    """Its cell's mechanism at debug widths: two K/V heads, a block of 8
+    rows, runs of 4 (what a table of 7 blocks holds), the kernel
+    interpreted over pages of 32 rows. Four requests through three slots
+    of a pool of three runs, small enough to preempt
+    (``tests/test_jamba_engine.py`` has the body)."""
+    runs_decode_what_single_blocks_decode(
+        built, monkeypatch, engine, alone, lens=(26, 13, 9, 20),
+        outs=(20, 12, 22, 8), max_seq=56, num_blocks=12, run=4)
 
 
 def test_a_prefix_hit_is_refused_and_counted(built):

@@ -18,7 +18,9 @@ leans on, each ALONE at the cell's shapes (PERF.md, PR 57, quotes them).
   by a kernel that does nothing else (``exp_rate``);
 - the PAGED DECODE ATTENTION of one MQA layer, 20 query heads over ONE
   K/V head of 128, 32 slots x 19,000 positions, in pages of 32 rows
-  (8 KB) and of 256 rows (64 KB), against the K/V bytes' least time.
+  (8 KB), of 256 rows (64 KB), and in blocks of 32 rows copied in RUNS
+  of 8 (``run=8``: what the engine does since PR 58, and the same 64 KB
+  a copy), against the K/V bytes' least time.
 
 One JSON line, to ``chiprun_out/jamba_kernel_readings.json`` too.
 """
@@ -92,7 +94,8 @@ def main():
     from benchmark.costs import ssm1_hybrid_transformer as costs
     from benchmark.lib.peaks import peaks_for
     from ray_tpu.ops import ssm1
-    from ray_tpu.ops.paged_attention import paged_decode_attention
+    from ray_tpu.ops.paged_attention import (paged_decode_attention,
+                                             run_blocks)
 
     cfg = harness.load_json(harness.ROOT, "benchmark/configs/jamba2-3b.json")
     peaks = peaks_for(jax.devices()[0].device_kind)
@@ -162,6 +165,16 @@ def main():
                 q, kp, vp, tables, lengths, impl=impl))
             att[f"{impl}_block{bs}_ms"] = 1e3 * timed(f, q, pool, pool,
                                                       reps=30)
+        if bs == 32:
+            # the same pool, its blocks read in runs of 8: every slot's
+            # blocks lie one after another, so its table is made of runs
+            run = run_blocks(bs, 1, D, 2)
+            pool8 = pool[:B * maxb]
+            f = jax.jit(lambda q, kp, vp: paged_decode_attention(
+                q, kp, vp, tables, lengths, impl="pallas", run=run))
+            att[f"pallas_block{bs}_run{run}_ms"] = 1e3 * timed(
+                f, q, pool8, pool8, reps=30)
+            del pool8
         del pool
     line["paged_attention_1_kv_head"] = att
 
