@@ -3,71 +3,18 @@ widths (float32 compute): admission, chunked prefill, a prefix hit that
 brings index keys with it, preemption and resume, full slots decoding,
 against ``benchmark/reference/deepseek_v32.py``; and the engine's
 counters of the share and of the selection. (The model's own comparisons
-are ``tests/test_deepseek_v32_serving.py``'s, whose helpers these use.)"""
+are ``tests/test_deepseek_v32_serving.py``'s; the description both share
+is ``tests/serving_family.py``'s ``DEEPSEEK_V32``.)"""
 
-import jax
-import jax.numpy as jnp
+import dataclasses
+
 import numpy as np
-import pytest
 
-from ray_tpu.llm.engine import ContinuousBatchingEngine, SamplingParams
-from tests.test_deepseek_v32_serving import I32, make, ref_forward
+from tests import serving_family as serving
 
 
-def _prompt(cfg, n, seed):
-    return [int(t) for t in np.random.default_rng(seed).integers(
-        1, cfg.vocab_size, n)]
-
-
-ENGINE_CASES = {
-    # name: (prompt lengths, engine kwargs, the stats key that must move)
-    "bucket_prefill": ((12, 14), {}, "prefills"),
-    "chunked_prefill": ((40, 9), {}, "prefills"),
-    "prefix_prefill": ("shared", {}, "prefix_prefills"),
-    "preemption_by_recompute": ((20, 21, 22), {"num_blocks": 10},
-                                "preemptions"),
-}
-
-
-@pytest.mark.parametrize("case", sorted(ENGINE_CASES))
-def test_engine_greedy_tokens_are_the_references_argmax(case):
-    """Through ``ContinuousBatchingEngine`` in float32 compute: every
-    generated token is the reference's first choice given the prompt and
-    the tokens before it (teacher forced) unless the reference has its
-    first two within 1e-3; the counters of the share and of the
-    selection add up."""
-    cfg, model, params = make()
-    lens, kwargs, moved = ENGINE_CASES[case]
-    if lens == "shared":
-        # 16 shared rows: the second request's chunk scores index keys
-        # that the first request wrote
-        head = _prompt(cfg, 16, 50)
-        prompts = [head + _prompt(cfg, n, i) for i, n in enumerate((3, 7))]
-    else:
-        prompts = [_prompt(cfg, n, i) for i, n in enumerate(lens)]
-    eng = ContinuousBatchingEngine(
-        model, params, max_slots=4, max_seq=64, prefill_buckets=(8, 16, 32),
-        block_size=8, **kwargs)
-    n_out = 12 if case == "preemption_by_recompute" else 6
-    with jax.default_matmul_precision("highest"):
-        if lens == "shared":        # the second finds the first's blocks
-            reqs = [eng.generate([p], SamplingParams(max_tokens=n_out))[0]
-                    for p in prompts]
-        else:
-            reqs = eng.generate(prompts, SamplingParams(max_tokens=n_out))
-    # ONE reference forward for the case: a row's logits depend on
-    # nothing behind it, so the sequences go in padded to one length
-    seqs = [prompt + req.output for prompt, req in zip(prompts, reqs)]
-    width = max(map(len, seqs))
-    logits = np.asarray(ref_forward(cfg, params, jnp.asarray(
-        [seq + [0] * (width - len(seq)) for seq in seqs], I32)))
-    for prompt, req, rows in zip(prompts, reqs, logits):
-        assert len(req.output) == n_out
-        want = rows[len(prompt) - 1:len(prompt) - 1 + n_out]
-        for row, tok in zip(want, req.output):
-            assert row.max() - row[tok] < 1e-3
-    stats = eng.stats
-    assert stats[moved] > 0
+def engine_stats(eng, stats, cfg, model):
+    """The counters of the share and of the selection add up."""
     assert eng.decode_attention_impl == stats["decode_attention_impl"] \
         == "dsa_xla"
     assert stats["decode_indexer_impl"] == "dsa_indexer_xla"
@@ -86,3 +33,14 @@ def test_engine_greedy_tokens_are_the_references_argmax(case):
         stats["tokens_generated"])
     assert stats["decode_kv_rows_selected"] <= 8 * stats[
         "decode_kv_blocks_live"]
+
+
+FAMILY = dataclasses.replace(
+    serving.DEEPSEEK_V32,
+    # 16 shared rows: the prefix case's second request's chunk scores
+    # index keys that the first request wrote
+    engine_cases=serving.engine_cases(bucket_prefill=(
+        (12, 14), 6, "prefills", serving.TEN_BLOCKS, {})),
+    engine_stats=engine_stats)
+
+globals().update(serving.cases_of(FAMILY))

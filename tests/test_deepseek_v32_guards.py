@@ -5,6 +5,8 @@ programs held to their StableHLO (DeepSeek-V3.2's to its parent's)."""
 
 import pytest
 
+from tests.program_readers import lowered_programs
+
 
 @pytest.mark.parametrize("fault", [False, True])
 def test_the_timed_path_check_holds_layer_one_rows_and_sees_a_fault(
@@ -51,13 +53,11 @@ def _hash(lowered) -> str:
 
 @pytest.fixture(scope="module")
 def kanana_programs():
-    from tests.test_one_kind_programs import lowered_programs
     return lowered_programs("kanana-2-30b-a3b-d5")
 
 
 @pytest.fixture(scope="module")
 def deepseek_v32_programs():
-    from tests.test_one_kind_programs import lowered_programs
     return lowered_programs("deepseek-v3.2-d5")
 
 

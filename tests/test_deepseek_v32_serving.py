@@ -15,6 +15,7 @@ the reference FORCED to the system's experts and rows.
 
 import collections
 import dataclasses
+import functools
 
 import jax
 import jax.numpy as jnp
@@ -26,63 +27,15 @@ from benchmark.reference import deepseek_v32 as reference
 from ray_tpu.models import MLAConfig, model_for
 from ray_tpu.ops import dsa
 from ray_tpu.ops.moe_dispatch import route_topk
+from tests import serving_family as serving
+from tests.serving_family import I32, rel_rms, seqs
 
 F32_TOL = 1e-4          # max |logit difference|, logits of RMS ~1
 # bf16 compute against the float32 reference forced to the system's
 # experts and rows, relative RMS of the logits (kanana's block reads
 # 0.011-0.014 at these widths; the readings here are 0.012-0.016)
 BF16_REL_RMS = 0.025
-I32 = jnp.int32
 HELD = (4, 8)           # experts 4..11 of the router's 16
-
-
-def make(dtype=jnp.float32, seed=1, held=HELD, **overrides):
-    cfg = MLAConfig.debug_deepseek_v32(
-        dtype=dtype, first_expert_held=held[0], experts_held=held[1],
-        **overrides)
-    model = model_for(cfg)
-    params = jax.jit(model.init)(jax.random.key(seed))
-    key = jax.random.key(seed + 100)
-    for stack in ("layers", "leading_layers"):
-        layers = params[stack]
-        for name in ("kv_norm", "q_norm", "attn_norm", "mlp_norm",
-                     "idx_k_norm"):
-            key, sub = jax.random.split(key)
-            # scales about 1, the LayerNorm's bias about 0
-            layers[name] = layers[name] + 0.3 * jax.random.normal(
-                sub, layers[name].shape)
-    # router logits of sigma 0.9, as the init gives at the published width
-    params["layers"]["router"] *= (7168 / cfg.dim) ** 0.5
-    return cfg, model, params
-
-
-def ref_kwargs(cfg):
-    y = cfg.yarn
-    return dict(
-        qk_nope_head_dim=cfg.qk_nope_head_dim,
-        qk_rope_head_dim=cfg.qk_rope_head_dim, kv_lora_rank=cfg.kv_lora_rank,
-        rope_theta=cfg.rope_theta,
-        yarn=(y.factor, y.original_max_position, y.beta_fast, y.beta_slow),
-        mscale_all_dim=cfg.yarn_mscale_all_dim, rms_norm_eps=cfg.norm_eps,
-        index_topk=cfg.index_topk, top_k=cfg.expert_top_k,
-        n_group=cfg.router_n_group, topk_group=cfg.router_topk_group,
-        routed_scaling_factor=cfg.routed_scaling_factor,
-        norm_topk_prob=cfg.norm_topk_prob, experts_held=cfg.held)
-
-
-def ref_forward(cfg, params, tokens, **kw):
-    return reference.forward(
-        builder.reference_params({"tie_word_embeddings": False}, params),
-        tokens, **{**ref_kwargs(cfg), **kw})
-
-
-def seqs(cfg, shape=(2, 48), seed=0):
-    return jnp.asarray(np.random.default_rng(seed).integers(
-        1, cfg.vocab_size, shape), I32)
-
-
-def rel_rms(got, want):
-    return float(jnp.sqrt(jnp.mean((got - want) ** 2) / jnp.mean(want ** 2)))
 
 
 class Selections:
@@ -130,96 +83,103 @@ class Selections:
         return out
 
 
-def full_forward(model, params, toks, sel):
-    logits = jax.jit(model.apply)(params, toks)
+def apply_and_rows(model, params, toks, sel):
+    logits = serving.full_forward(model, params, toks)
     return logits, sel.prefill(model.cfg.n_layers)
 
 
-def prefill_then_paged_decode(model, params, toks, sel, prompt=24, bs=8):
+def paged_decode_and_rows(model, params, toks, sel, prompt=24):
     """``check_logits``'s route: a prefill (EXPANDED, the selection as a
-    mask) into a slot-major cache, scattered into pool blocks by the
-    names ``"k"`` / ``"v"``, then paged decode steps (index scores over
-    the pages, row numbers, the absorbed form over the selected rows).
-    24 rows are prefilled, so the decode steps select 12 of 25..48, and
-    cross block edges at 32 and 40."""
-    B, total = toks.shape
-    L = model.cfg.n_layers
-    nb = -(-total // bs)
-    cache = model.init_kv_cache(B, nb * bs)
-    padded = jnp.zeros((B, nb * bs), I32).at[:, :prompt].set(toks[:, :prompt])
-    pre, cache = jax.jit(model.forward_step)(params, padded, cache,
-                                             jnp.zeros((B,), I32))
-    chosen = [sel.prefill(L)[:, :, :prompt, :total]]
-    pool = model.init_kv_pool(B * nb + 1, bs)
-    ids = jnp.arange(B * nb)
-    pool = {k: pool[k].at[:, ids].set(
-        cache[k].reshape(L, B * nb, bs, *cache[k].shape[3:]))
-        for k in ("k", "v")}
-    tables = ids.astype(I32).reshape(B, nb)
-    out = [pre[:, :prompt]]
-    step = jax.jit(model.decode_step_paged)
-    for pos in range(prompt, total):
-        logits, pool = step(
-            params, toks[:, pos], pool, tables, jnp.full((B,), pos, I32))
-        out.append(logits[:, None])
-        chosen.append(sel.decode(L, total)[:, :, None])
-    return jnp.concatenate(out, axis=1), np.concatenate(chosen, axis=2)
+    mask), then paged decode steps (index scores over the pages, row
+    numbers, the absorbed form over the selected rows). 24 rows are
+    prefilled, so the decode steps select 12 of 25..48, and cross block
+    edges at 32 and 40."""
+    L, total = model.cfg.n_layers, toks.shape[1]
+    chosen = []
+
+    def seen(kind):
+        chosen.append(sel.prefill(L)[:, :, :prompt, :total]
+                      if kind == "prefill"
+                      else sel.decode(L, total)[:, :, None])
+
+    logits = serving.prefill_then_paged_decode(model, params, toks, prompt,
+                                               seen=seen)
+    return logits, np.concatenate(chosen, axis=2)
 
 
-def prefix_prefill(model, params, toks, sel, prefix=32):
-    """A suffix prefill of 16 rows over a cached prefix of 32 (from a
-    plain prefill), padded as the engine pads: the chunk's queries score
-    the gathered prefix's index keys and their own."""
-    B, total = toks.shape
-    L = model.cfg.n_layers
-    cache = model.init_kv_cache(B, prefix)
-    _, cache = jax.jit(model.forward_step)(params, toks[:, :prefix], cache,
-                                           jnp.zeros((B,), I32))
-    sel.masks.clear()
-    padded = {n: jnp.pad(a, ((0, 0), (0, 0), (0, 8)) + ((0, 0),) * (
-        a.ndim - 3)) for n, a in cache.items()}
-    suffix = jnp.zeros((B, 32), I32).at[:, :total - prefix].set(
-        toks[:, prefix:])
-    logits, rows = jax.jit(model.prefill_with_prefix)(
-        params, suffix, padded["k"], padded["v"], jnp.full((B,), prefix, I32),
-        jnp.full((B,), total - prefix, I32))
-    assert rows["k"].shape[:3] == (L, B, 32)
+def prefix_and_rows(model, params, toks, sel, prefix=32):
+    """A suffix prefill of 16 rows over a cached prefix of 32: the chunk's
+    queries score the gathered prefix's index keys and their own."""
+    total = toks.shape[1]
+    logits = serving.prefix_prefill(model, params, toks, prefix,
+                                    seen=lambda kind: sel.masks.clear())
     # the keys: 40 of the padded prefix, then the suffix's own
-    masks = sel.prefill(L)[:, :, :total - prefix]
+    masks = sel.prefill(model.cfg.n_layers)[:, :, :total - prefix]
     chosen = np.concatenate([masks[..., :prefix],
                              masks[..., 40:40 + total - prefix]], axis=-1)
-    return logits[:, None], chosen       # the position total - 1's logits
+    return logits, chosen                # the position total - 1's logits
 
 
-PATHS = {"full_forward": full_forward,
-         "prefill_then_paged_decode": prefill_then_paged_decode,
-         "prefix_prefill": prefix_prefill}
+@functools.lru_cache(maxsize=None)
+def wanted_rows():
+    cfg, _, params, toks, _ = serving.honest(FAMILY)
+    _, choices = ref_forward(cfg, params, toks, with_choices=True)
+    return cfg, np.asarray(choices["selection"])
 
 
-@pytest.mark.parametrize("path", sorted(PATHS))
-def test_float32_compute_matches_the_reference_logits_and_rows(
-        path, monkeypatch):
-    cfg, model, params = make()
-    assert not model.word_rows and model.paged_decode_impl() == "dsa_xla"
-    toks = seqs(cfg)
-    sel = Selections(monkeypatch)
-    with jax.default_matmul_precision("highest"):
-        got, chosen = PATHS[path](model, params, toks, sel)
-    want, choices = ref_forward(cfg, params, toks, with_choices=True)
-    want_rows = np.asarray(choices["selection"])
-    if path == "prefix_prefill":
-        want, want_rows = want[:, -1:], want_rows[:, :, 32:]
-        np.testing.assert_allclose(got, want, atol=F32_TOL)
-    else:
-        np.testing.assert_allclose(got, want, atol=F32_TOL)
-    # the selected SETS, every layer, every position: 12 rows once there
-    # are so many, every row before
-    assert chosen.shape == want_rows.shape
-    assert (chosen == want_rows).all()
-    counts = want_rows.sum(-1)
-    first = 32 if path == "prefix_prefill" else 0
-    assert (counts[0, 0] == np.minimum(np.arange(first, 48) + 1,
-                                       cfg.index_topk)).all()
+def and_rows(run, first=0):
+    """A path of ``FAMILY.paths``: ``run``'s logits, with the selected
+    SETS held to the reference's on the way, every layer, every position
+    from ``first``: 12 rows once there are so many, every row before."""
+    def path(model, params, toks):
+        model = serving.fresh(model)    # its traces record what they select
+        assert not model.word_rows and model.paged_decode_impl() == "dsa_xla"
+        with pytest.MonkeyPatch.context() as patch:
+            got, chosen = run(model, params, toks, Selections(patch))
+        cfg, want_rows = wanted_rows()
+        want_rows = want_rows[:, :, first:]
+        assert chosen.shape == want_rows.shape
+        assert (chosen == want_rows).all()
+        assert (want_rows.sum(-1)[0, 0] == np.minimum(
+            np.arange(first, 48) + 1, cfg.index_topk)).all()
+        return got
+    return path
+
+
+def after_serving_params(cfg, model, params, served):
+    assert served["layers"]["e_gate"].shape[:2] == (2, HELD[1])
+    assert served["layers"]["router"].shape == (2, cfg.dim, 16)
+    assert float(jnp.std(served["layers"]["router_bias"])) > 0   # drawn
+
+
+FAMILY = dataclasses.replace(
+    serving.DEEPSEEK_V32, f32_tol=F32_TOL, bf16_rel_rms=BF16_REL_RMS,
+    paths={"full_forward": (and_rows(apply_and_rows), 0),
+           "prefill_then_paged_decode": (and_rows(paged_decode_and_rows), 0),
+           "prefix_prefill": (and_rows(prefix_and_rows, 32), -1)},
+    # -- controls: each must fail, against ``apply``'s honest logits
+    fault_path="full_forward", faults=reference.FAULTS,
+    fault_floors=lambda fault: (0.05, 100 * F32_TOL),
+    scopes={
+        "forward_step": (("mla_q_down", "mla_q_up", "dsa_indexer_q",
+                          "dsa_indexer_k", "dsa_indexer_scores",
+                          "dsa_select", "dsa_masked_attention",
+                          "mla_kv_down", "mla_kv_up", "moe_group_limit",
+                          "moe_router", "moe_shared_expert",
+                          "dense_ffn_leading"), ("mla_q_proj",)),
+        "decode_step_paged": (("mla_q_down", "mla_q_up", "dsa_indexer_q",
+                               "dsa_indexer_k", "dsa_indexer_scores",
+                               "dsa_select", "dsa_attention", "mla_q_absorb",
+                               "mla_v_up", "moe_group_limit", "moe_router"),
+                              ("mla_q_proj",))},
+    f32_leaves=frozenset({"attn_norm", "mlp_norm", "kv_norm", "q_norm",
+                          "idx_k_norm", "router", "router_bias"}),
+    after_serving_params=after_serving_params)
+ref_forward = functools.partial(serving.reference, FAMILY)
+
+
+make = functools.partial(serving.make, FAMILY)
+globals().update(serving.cases_of(FAMILY))
 
 
 def make_word_rows(dtype=jnp.bfloat16, **kw):
@@ -229,29 +189,19 @@ def make_word_rows(dtype=jnp.bfloat16, **kw):
 
 
 def bf16_full_forward(model, params, toks, sel):
-    logits, extras = jax.jit(model._apply_with_extras)(params, toks)
-    return logits, extras["experts"], sel.prefill(model.cfg.n_layers)
+    logits, experts = serving.bf16_full_forward(model, params, toks)
+    return logits, experts, sel.prefill(model.cfg.n_layers)
 
 
-def bf16_paged_decode_from_empty(model, params, toks, sel, bs=8):
-    """Every position by a paged decode step (index scores over the
-    pages, row numbers, the absorbed form over the selected rows), with
-    the experts and the rows each step chose."""
-    B, total = toks.shape
-    L = model.cfg.n_layers
-    nb = -(-total // bs)
-    pool = model.init_kv_pool(B * nb + 1, bs)
-    tables = jnp.arange(B * nb, dtype=I32).reshape(B, nb)
-    logits, experts, chosen = [], [], []
-    step = jax.jit(model.decode_step_paged_counted)
-    for pos in range(total):
-        out, pool, extras = step(
-            params, toks[:, pos], pool, tables, jnp.full((B,), pos, I32))
-        logits.append(out[:, None])
-        experts.append(extras["experts"])
-        chosen.append(sel.decode(L, total)[:, :, None])
-    return (jnp.concatenate(logits, 1), jnp.concatenate(experts, 2),
-            np.concatenate(chosen, 2))
+def bf16_paged_decode_from_empty(model, params, toks, sel):
+    """Every position by a paged decode step, with the experts and the
+    rows each step chose."""
+    L, total = model.cfg.n_layers, toks.shape[1]
+    chosen = []
+    logits, experts = serving.paged_decode_from_empty(
+        model, params, toks,
+        seen=lambda kind: chosen.append(sel.decode(L, total)[:, :, None]))
+    return logits, experts, np.concatenate(chosen, 2)
 
 
 @pytest.mark.parametrize("seed", [1])
@@ -262,8 +212,8 @@ def test_bf16_compute_with_the_reference_forced_to_its_experts_and_rows(
         path, impl, seed, monkeypatch):
     """Rows held as words; the decode step's kernels interpreted."""
     cfg, model, params = make_word_rows(seed=seed)
+    model = model_for(dataclasses.replace(cfg, decode_attention=impl))
     if impl is not None:
-        model = model_for(dataclasses.replace(cfg, decode_attention=impl))
         assert model.word_rows
         assert model.paged_decode_impl() == "dsa_" + impl
     toks = seqs(cfg, (2, 40), seed=seed)
@@ -273,7 +223,7 @@ def test_bf16_compute_with_the_reference_forced_to_its_experts_and_rows(
     got, experts, chosen = run(model, model.serving_params(params), toks, sel)
     want = ref_forward(cfg, params, toks, forced_experts=experts,
                        forced_selection=jnp.asarray(chosen))
-    assert rel_rms(got.astype(jnp.float32), want) < BF16_REL_RMS
+    assert rel_rms(got, want) < BF16_REL_RMS
     # free, the same logits stand far off: both kinds of near-tie swap
     assert (chosen.sum(-1)[0, 0] == np.minimum(np.arange(40) + 1, 12)).all()
 
@@ -736,64 +686,6 @@ def test_the_shares_of_a_layer_add_up_to_the_uncut_layer():
     np.testing.assert_allclose(np.asarray(out)[none_held],
                                np.asarray(dense)[none_held], atol=1e-4)
     assert int(ex["load"].sum()) == 2 * 24 * 4      # every choice counted
-
-
-# -- controls: each must fail ----------------------------------------------
-@pytest.fixture(scope="module")
-def served_logits():
-    cfg, model, params = make()
-    toks = seqs(cfg)
-    with jax.default_matmul_precision("highest"):
-        got = model.apply(params, toks)
-    return cfg, params, toks, got
-
-
-@pytest.mark.parametrize("fault", reference.FAULTS)
-def test_a_faulty_block_is_refused(fault, served_logits):
-    cfg, params, toks, got = served_logits
-    want = ref_forward(cfg, params, toks, fault=fault)
-    assert float(jnp.max(jnp.abs(got - want))) > 100 * F32_TOL
-    assert rel_rms(got, want) > 0.05
-
-
-# -- the parameters ---------------------------------------------------------
-def test_serving_params_keep_the_float32_leaves():
-    cfg, model, params = make(jnp.bfloat16)
-    served = model.serving_params(params)
-    f32 = {"attn_norm", "mlp_norm", "kv_norm", "q_norm", "idx_k_norm",
-           "router", "router_bias"}
-    for stack in ("layers", "leading_layers"):
-        for name, a in served[stack].items():
-            assert a.dtype == (jnp.float32 if name in f32
-                               else jnp.bfloat16), (stack, name)
-    assert served["layers"]["e_gate"].shape[:2] == (2, HELD[1])
-    assert served["layers"]["router"].shape == (2, cfg.dim, 16)
-    assert float(jnp.std(served["layers"]["router_bias"])) > 0   # drawn
-    assert cfg.num_params() == sum(a.size for a in jax.tree.leaves(params))
-
-
-@pytest.mark.parametrize("method,scopes", [
-    ("forward_step", ("mla_q_down", "mla_q_up", "dsa_indexer_q",
-                      "dsa_indexer_k", "dsa_indexer_scores", "dsa_select",
-                      "dsa_masked_attention", "mla_kv_down", "mla_kv_up",
-                      "moe_group_limit", "moe_router", "moe_shared_expert",
-                      "dense_ffn_leading")),
-    ("decode_step_paged", ("mla_q_down", "mla_q_up", "dsa_indexer_q",
-                           "dsa_indexer_k", "dsa_indexer_scores",
-                           "dsa_select", "dsa_attention", "mla_q_absorb",
-                           "mla_v_up", "moe_group_limit", "moe_router"))])
-def test_scopes_are_in_the_lowered_programs_metadata(method, scopes):
-    cfg, model, params = make()
-    two = jnp.zeros((2,), I32)
-    args = {"forward_step": (params, jnp.ones((2, 16), I32),
-                             model.init_kv_cache(2, 16), two),
-            "decode_step_paged": (params, two, model.init_kv_pool(9, 8),
-                                  jnp.zeros((2, 4), I32), two)}[method]
-    text = jax.jit(getattr(model, method)).lower(*args).as_text(
-        debug_info=True)
-    for scope in scopes:
-        assert scope in text, scope
-    assert "mla_q_proj" not in text
 
 
 def test_the_config_refuses_what_it_cannot_be():

@@ -19,6 +19,7 @@ path, at debug widths on the CPU, against the plain float32 reference
 """
 
 import dataclasses
+import functools
 
 import jax
 import jax.numpy as jnp
@@ -26,39 +27,48 @@ import numpy as np
 import pytest
 
 from benchmark.reference import evabyte as reference
-from ray_tpu.llm.engine import ContinuousBatchingEngine, SamplingParams
+from ray_tpu.llm.engine import SamplingParams
 from ray_tpu.llm.paged_cache import (BlockPool, WindowAllocation,
                                      eva_window_block, slide_window)
-from ray_tpu.models import model_for
 from ray_tpu.models.llama import EVA_KIND, LlamaConfig
+from tests import serving_family as serving
+from tests.serving_family import rel_rms
 
 W, C, BS, V = 32, 4, 8, 320
 BF16_REL_RMS = 0.02     # the dense block's bf16 floor at debug widths
 KW = dict(rope_theta=1e5, rms_norm_eps=1e-5, window=W, chunk=C)
+REFERENCE = jax.jit(lambda params, toks: reference.forward(params, toks, **KW))
 
 
-def make(dtype=jnp.float32, impl=None, **more):
-    cfg = LlamaConfig(
+def config(dtype, impl=None, **more):
+    return LlamaConfig(
         vocab_size=V, dim=64, n_layers=4, n_heads=4, n_kv_heads=4,
         ffn_dim=128, max_seq_len=256, rope_theta=1e5, norm_eps=1e-5,
         dtype=dtype, remat=False, layer_types=(EVA_KIND,) * 4, eva_window=W,
         eva_chunk=C, norm_add_unit_offset=True, fp32_residual=True,
         num_pred_heads=8, decode_attention=impl, **more)
-    model = model_for(cfg)
-    params = model.init(jax.random.key(0))
+
+
+def seeded(model, seed):
+    params = model.init(jax.random.key(seed))
     # norm offsets that are not 0, so that ``1 + g`` is held to account
     for name in ("attn_norm", "mlp_norm"):
         params["layers"][name] = 0.1 * jax.random.normal(
             jax.random.key(7), params["layers"][name].shape)
-    return cfg, model, params
+    return params
+
+
+FAMILY = serving.Family(
+    config=config, seeded=seeded,
+    reference=lambda cfg, params, toks: REFERENCE(params, toks),
+    engine_kw=dict(max_slots=3, max_seq=256, prefill_buckets=(8, 16, 32),
+                   block_size=BS))
+make = functools.partial(serving.make, FAMILY, seed=0)
+engine = functools.partial(serving.engine_of, FAMILY)
 
 
 def tokens(n, rows=2, seed=0):
-    return jnp.asarray(
-        np.random.default_rng(seed).integers(1, V, (rows, n)), jnp.int32)
-
-
-REFERENCE = jax.jit(lambda params, toks: reference.forward(params, toks, **KW))
+    return serving.seqs(make()[0], (rows, n), seed)
 
 
 def want_logits(params, toks, every_head=False):
@@ -66,8 +76,11 @@ def want_logits(params, toks, every_head=False):
     return out if every_head else out[..., :V]
 
 
-def rel_rms(got, want):
-    return float(jnp.sqrt(jnp.mean((got - want) ** 2) / jnp.mean(want ** 2)))
+def paged_after_bucket_prefill(model, params, toks, prompt_len):
+    """The harness's logits check by hand: ``forward_step`` into a slot
+    cache, the cache as a uniform pool, then paged decode steps."""
+    return serving.prefill_then_paged_decode(model, params, toks, prompt_len,
+                                             BS)
 
 
 # two window ends and a bit, ending mid-chunk
@@ -93,7 +106,7 @@ def test_apply_gives_every_heads_logits_as_the_reference():
     _, model, params = make()
     toks = tokens(T)
     with jax.default_matmul_precision("highest"):
-        got = model.apply(params, toks)
+        got = serving.full_forward(model, params, toks)
     assert got.shape == (2, T, 8 * V)
     np.testing.assert_allclose(got, want_logits(params, toks, True),
                                atol=1e-4)
@@ -105,31 +118,6 @@ def test_apply_gives_every_heads_logits_as_the_reference():
             changed["layers"]["eva_" + name] = (
                 factor * params["layers"]["eva_" + name])
         assert rel_rms(want_logits(changed, toks), base) > 0.01, control
-
-
-def paged_after_bucket_prefill(model, params, toks, prompt_len):
-    """The harness's logits check by hand: ``forward_step`` into a slot
-    cache, the cache as a uniform pool, then paged decode steps."""
-    total = toks.shape[1]
-    nb = -(-total // BS)
-    padded = np.zeros((2, nb * BS), np.int32)
-    padded[:, :prompt_len] = toks[:, :prompt_len]
-    small = model.init_kv_cache(2, nb * BS)
-    logits, small = jax.jit(model.forward_step)(
-        params, jnp.asarray(padded), small, jnp.zeros((2,), jnp.int32))
-    pool = model.init_kv_pool(2 * nb + 1, BS)
-    ids = jnp.arange(2 * nb)
-    pool = {n: pool[n].at[:, ids].set(
-        small[n].reshape(small[n].shape[0], 2 * nb, BS, *small[n].shape[3:]))
-        for n in ("k", "v")}
-    tables = jnp.arange(2 * nb, dtype=jnp.int32).reshape(2, nb)
-    out = [logits[:, :prompt_len]]
-    decode = jax.jit(model.decode_step_paged)
-    for pos in range(prompt_len, total):
-        step, pool = decode(params, toks[:, pos], pool, tables,
-                            jnp.full((2,), pos, jnp.int32))
-        out.append(step[:, None])
-    return jnp.concatenate(out, axis=1)
 
 
 @pytest.mark.parametrize("impl", ["xla", "pallas"])
@@ -147,14 +135,9 @@ def test_bf16_compute_is_at_the_dense_blocks_floor():
     want = want_logits(params, toks)
     served = model.serving_params(params)
     got = paged_after_bucket_prefill(model, served, toks, W + 7)
-    assert rel_rms(got.astype(jnp.float32), want) < BF16_REL_RMS
-    assert rel_rms(model.apply(params, toks)[..., :V], want) < BF16_REL_RMS
-
-
-def engine(model, params, **kw):
-    kw = {"max_slots": 3, "max_seq": 256, "prefill_buckets": (8, 16, 32),
-          "block_size": BS, **kw}
-    return ContinuousBatchingEngine(model, params, **kw)
+    assert rel_rms(got, want) < BF16_REL_RMS
+    assert rel_rms(serving.full_forward(model, params, toks)[..., :V],
+                   want) < BF16_REL_RMS
 
 
 def engine_logits(eng, prompt, steps):
@@ -163,7 +146,7 @@ def engine_logits(eng, prompt, steps):
     model's paged step on the ENGINE's pools, tables and offsets (not
     donated), before the engine takes the same step."""
     model = eng.model
-    probe = jax.jit(model.decode_step_paged)
+    probe = serving.jitted(model, "decode_step_paged")
     req = eng.submit(list(prompt), SamplingParams(max_tokens=steps + 1))
     eng._admit()
     out = []

@@ -12,6 +12,7 @@ the system's routing at the dense models' bf16 tolerance.
 """
 
 import dataclasses
+import functools
 import json
 
 import jax
@@ -21,34 +22,14 @@ import pytest
 
 from benchmark.builders import kanana as builder
 from benchmark.reference import kanana as reference
-from ray_tpu.llm.engine import ContinuousBatchingEngine, SamplingParams
+from ray_tpu.llm.engine import ContinuousBatchingEngine
 from ray_tpu.models import MLAConfig, MLAModel, model_for
 from ray_tpu.ops import mla_attention
+from tests import serving_family as serving
+from tests.program_readers import scans
+from tests.serving_family import I32, seqs
 
 F32_TOL = 1e-4          # max |logit difference|, logits of RMS ~1
-# bf16 compute against the float32 reference forced to the system's
-# routing, relative RMS of the logits: the dense block's floor at debug
-# widths reads 0.016 (benchmark/tests/test_references.py); the readings
-# here are 0.011-0.014 over the seeds below
-BF16_REL_RMS = 0.02
-I32 = jnp.int32
-
-
-def make(dtype=jnp.float32, seed=1, **overrides):
-    cfg = MLAConfig.debug_kanana(dtype=dtype, **overrides)
-    model = model_for(cfg)
-    params = jax.jit(model.init)(jax.random.key(seed))
-    key = jax.random.key(seed + 100)
-    for stack in ("layers", "leading_layers"):
-        layers = params[stack]
-        for name in ("kv_norm", "attn_norm", "mlp_norm"):
-            key, sub = jax.random.split(key)
-            layers[name] = 1.0 + 0.3 * jax.random.normal(sub,
-                                                         layers[name].shape)
-    # the init's 0.02 gives router logits of sigma 0.9 at the published
-    # width 2048; the same at this width
-    params["layers"]["router"] *= (2048 / cfg.dim) ** 0.5
-    return cfg, model, params
 
 
 def ref_kwargs(cfg):
@@ -60,100 +41,92 @@ def ref_kwargs(cfg):
                 norm_topk_prob=cfg.norm_topk_prob)
 
 
-def ref_forward(cfg, params, tokens, **kw):
+def plain_reference(cfg, params, tokens, **kw):
     return reference.forward(
         builder.reference_params({"tie_word_embeddings": False}, params),
         tokens, **ref_kwargs(cfg), **kw)
 
 
-def seqs(cfg, shape=(2, 24), seed=0):
-    return jnp.asarray(np.random.default_rng(seed).integers(
-        1, cfg.vocab_size, shape), I32)
+def kernel_path(model, params, toks):
+    assert serving.forced(model, "pallas").paged_decode_impl() == "mla_pallas"
+    return serving.paged_decode_with_the_kernel(model, params, toks)
 
 
-def rel_rms(got, want):
-    return float(jnp.sqrt(jnp.mean((got - want) ** 2) / jnp.mean(want ** 2)))
+def after_serving_params(cfg, model, params, served):
+    assert served["lm_head"].dtype == jnp.bfloat16
+    assert "router" not in served["leading_layers"]
+    assert float(jnp.std(served["layers"]["router_bias"])) > 0   # drawn
 
 
-def full_forward(model, params, toks):
-    return model.apply(params, toks)
+def engine_stats(eng, stats, cfg, model):
+    """The expert FFN processed exactly what a dropless FFN must, over the
+    EXPERT layers alone; the cache holds the ONE latent row."""
+    assert stats["moe_router_kind"] == "sigmoid"
+    assert eng.decode_attention_impl == "mla_xla"
+    assert stats["kv_row_bytes"] == 4 * (cfg.kv_lora_rank + model.pe_lanes)
+    assert eng.kv["k"].shape[2:] == (8, cfg.kv_lora_rank + model.pe_lanes)
+    assert eng.kv["v"].shape[2:] == (8, 0)
+    assert stats["kv_pool_bytes"] == sum(a.nbytes for a in eng.kv.values())
+    assert stats["moe_assignments"] == stats["moe_assignments_expected"] > 0
+    load = np.asarray(stats["moe_expert_load"])
+    assert load.shape == (cfg.n_layers - cfg.leading_layers, cfg.num_experts)
+    assert load.sum() == stats["moe_assignments"]
+    assert len(set(load.sum(1).tolist())) == 1
 
 
-def prefill_then_paged_decode(model, params, toks, prompt=16, bs=8):
-    """``check_logits``'s route: bucket prefill (EXPANDED) into a
-    slot-major cache of latent rows, scattered into pool blocks by the
-    names ``"k"`` / ``"v"``, then paged decode steps (ABSORBED)."""
-    B, total = toks.shape
-    nb = -(-total // bs)
-    cache = model.init_kv_cache(B, nb * bs)
-    padded = jnp.zeros((B, nb * bs), I32).at[:, :prompt].set(toks[:, :prompt])
-    pre, cache = jax.jit(model.forward_step)(params, padded, cache,
-                                             jnp.zeros((B,), I32))
-    pool = model.init_kv_pool(B * nb + 1, bs)
-    L = cache["k"].shape[0]
-    ids = jnp.arange(B * nb)
-    pool = {k: pool[k].at[:, ids].set(
-        cache[k].reshape(L, B * nb, bs, *cache[k].shape[3:]))
-        for k in ("k", "v")}
-    tables = ids.astype(I32).reshape(B, nb)
-    out = [pre[:, :prompt]]
-    step = jax.jit(model.decode_step_paged)
-    for pos in range(prompt, total):
-        logits, pool = step(
-            params, toks[:, pos], pool, tables, jnp.full((B,), pos, I32))
-        out.append(logits[:, None])
-    return jnp.concatenate(out, axis=1)
+FAMILY = serving.Family(
+    config=MLAConfig.debug_kanana, reference=plain_reference,
+    seeded=serving.drawn(("kv_norm", "attn_norm", "mlp_norm"), 2048,
+                         ("layers", "leading_layers")),
+    f32_tol=F32_TOL,
+    # bf16 compute against the float32 reference forced to the system's
+    # routing, relative RMS of the logits: the dense block's floor at debug
+    # widths reads 0.016 (benchmark/tests/test_references.py); the readings
+    # here are 0.011-0.014 over the seeds below
+    bf16_rel_rms=0.02,
+    # the prefills EXPANDED, the decode steps ABSORBED, the suffix prefill
+    # over a cached prefix of latent rows
+    paths={"full_forward": (serving.full_forward, 0),
+           "prefill_then_paged_decode": (serving.prefill_then_paged_decode,
+                                         0),
+           "paged_decode_with_the_kernel": (kernel_path, 0),
+           "prefix_prefill": (serving.prefix_prefill, -1)},
+    # as it is (``kv_lora_rank`` 32): the debug row, ``c | k_pe`` as they
+    # are; 128: a latent width that fills a lane tile, so ``k_pe`` is
+    # zero-padded to ``PE_LANES`` in the cache, the layout of the published
+    # widths (512 + 128) that the cell times
+    f32_overrides=({}, {"kv_lora_rank": 128}),
+    bf16_paths={"full_forward": serving.bf16_full_forward,
+                "paged_decode": serving.paged_decode_from_empty},
+    bf16_cases=tuple((path, seed) for path in ("full_forward",
+                                               "paged_decode")
+                     for seed in (1, 2, 3)),
+    faults=tuple(f for f in reference.FAULTS if f != "skip_last_layer"),
+    fault_floors=lambda fault: (0.03, 100 * F32_TOL),
+    scopes={
+        "forward_step": (("mla_q_proj", "mla_kv_down", "mla_kv_up",
+                          "mla_attention", "moe_shared_expert",
+                          "dense_ffn_leading", "moe_router"), ()),
+        "decode_step_paged": (("mla_q_proj", "mla_kv_down", "mla_q_absorb",
+                               "mla_attention", "mla_v_up",
+                               "moe_shared_expert", "dense_ffn_leading",
+                               "moe_router"), ("mla_kv_up",))},
+    f32_leaves=frozenset({"attn_norm", "mlp_norm", "kv_norm", "router",
+                          "router_bias"}),
+    after_serving_params=after_serving_params,
+    # at the published widths' layout, k_pe padded to a lane tile (an
+    # engine of its own): the second request's prefill gathers the first's
+    # rows and decodes over them: insert, prefix gather, decode over the
+    # ONE row (the other cases insert, gather and decode the 48-lane row)
+    engine_cases=serving.engine_cases(prefix_prefill=(
+        "shared", 6, "prefix_prefills", {}, {"kv_lora_rank": 128})),
+    engine_stats=engine_stats)
 
 
-def paged_decode_with_the_kernel(model, params, toks):
-    kernel = model_for(dataclasses.replace(model.cfg,
-                                           decode_attention="pallas"))
-    assert kernel.paged_decode_impl() == "mla_pallas"
-    return prefill_then_paged_decode(kernel, params, toks)
+make = functools.partial(serving.make, FAMILY)
 
 
-def prefix_prefill(model, params, toks, prefix=8):
-    """The last-token logits of a suffix prefill over a cached prefix of
-    latent rows (from a plain prefill), padded as the engine pads."""
-    B, total = toks.shape
-    cache = model.init_kv_cache(B, prefix)
-    _, cache = model.forward_step(params, toks[:, :prefix], cache,
-                                  jnp.zeros((B,), I32))
-    padded = {n: jnp.pad(a, ((0, 0), (0, 0), (0, 8), (0, 0)))
-              for n, a in cache.items()}
-    suffix = jnp.zeros((B, 32), I32).at[:, :total - prefix].set(
-        toks[:, prefix:])
-    logits, rows = model.prefill_with_prefix(
-        params, suffix, padded["k"], padded["v"], jnp.full((B,), prefix, I32),
-        jnp.full((B,), total - prefix, I32))
-    assert rows["k"].shape == (model.cfg.n_layers, B, 32,
-                               *model.kv_row_shapes()[0])
-    return logits[:, None]                       # position total - 1
-
-
-PATHS = {"full_forward": (full_forward, 0),
-         "prefill_then_paged_decode": (prefill_then_paged_decode, 0),
-         "paged_decode_with_the_kernel": (paged_decode_with_the_kernel, 0),
-         "prefix_prefill": (prefix_prefill, -1)}
-
-
-# 32: the debug row, ``c | k_pe`` as they are; 128: a latent width that
-# fills a lane tile, so ``k_pe`` is zero-padded to ``PE_LANES`` in the
-# cache, the layout of the published widths (512 + 128) that the cell times
-@pytest.mark.parametrize("kv_lora_rank", [32, 128])
-@pytest.mark.parametrize("path", sorted(PATHS))
-def test_float32_compute_matches_the_reference(path, kv_lora_rank):
-    cfg, model, params = make(kv_lora_rank=kv_lora_rank)
-    assert model.kv_row_shapes() == ((
-        kv_lora_rank + (mla_attention.PE_LANES if kv_lora_rank == 128
-                        else cfg.qk_rope_head_dim),), (0,))
-    toks = seqs(cfg)
-    run, first = PATHS[path]
-    with jax.default_matmul_precision("highest"):
-        got = run(model, params, toks)
-    want = ref_forward(cfg, params, toks)[:, first:]
-    assert got.shape == want.shape
-    np.testing.assert_allclose(got, want, atol=F32_TOL)
+globals().update(serving.cases_of(FAMILY))
 
 
 def test_absorbed_and_expanded_forms_agree_on_the_same_rows():
@@ -185,6 +158,7 @@ def test_a_long_prefill_scores_a_block_of_queries_at_a_time(monkeypatch):
     rows out, by the largest block that divides them (24 -> 8)."""
     from ray_tpu.models import mla
     cfg, model, params = make()
+    model = serving.fresh(model)
     toks = seqs(cfg)
     cache = model.init_kv_cache(2, 24)
     offsets = jnp.asarray([0, 0], I32)
@@ -203,68 +177,13 @@ def test_a_long_prefill_scores_a_block_of_queries_at_a_time(monkeypatch):
     np.testing.assert_allclose(got_rows["k"], rows["k"], atol=1e-6)
 
 
-def paged_decode_from_empty(model, params, toks, bs=8):
-    """Every position by a paged decode step (ABSORBED attention), with
-    the experts each step chose: -> (logits [B, S, V], experts [L_moe, B,
-    S, K])."""
-    B, total = toks.shape
-    nb = -(-total // bs)
-    pool = model.init_kv_pool(B * nb + 1, bs)
-    tables = jnp.arange(B * nb, dtype=I32).reshape(B, nb)
-    step = jax.jit(model.decode_step_paged_counted)
-    logits, experts = [], []
-    for pos in range(total):
-        out, pool, extras = step(params, toks[:, pos], pool, tables,
-                                 jnp.full((B,), pos, I32))
-        logits.append(out[:, None])
-        experts.append(extras["experts"])
-    return jnp.concatenate(logits, 1), jnp.concatenate(experts, 2)
-
-
-def bf16_full_forward(model, params, toks):
-    logits, extras = model._apply_with_extras(params, toks)
-    return logits, extras["experts"]
-
-
-@pytest.mark.parametrize("seed", [1, 2, 3])
-@pytest.mark.parametrize("path", ["full_forward", "paged_decode"])
-def test_bf16_compute_with_the_reference_forced_to_its_routing(path, seed):
-    cfg, model, params = make(jnp.bfloat16, seed)
-    toks = seqs(cfg, seed=seed)
-    run = bf16_full_forward if path == "full_forward" \
-        else paged_decode_from_empty
-    got, experts = run(model, model.serving_params(params), toks)
-    want = ref_forward(cfg, params, toks, forced_experts=experts)
-    assert rel_rms(got.astype(jnp.float32), want) < BF16_REL_RMS
-
-
-@pytest.fixture(scope="module")
-def served_logits():
-    cfg, model, params = make()
-    toks = seqs(cfg)
-    with jax.default_matmul_precision("highest"):
-        return cfg, params, toks, prefill_then_paged_decode(model, params,
-                                                            toks)
-
-
-# Each of these, done to the REFERENCE, has to show in the comparison:
-# the system computes the published block and not the faulty one.
-@pytest.mark.parametrize("fault", [f for f in reference.FAULTS
-                                   if f != "skip_last_layer"])
-def test_a_faulty_block_is_refused(fault, served_logits):
-    cfg, params, toks, got = served_logits
-    wrong = ref_forward(cfg, params, toks, fault=fault)
-    assert rel_rms(got, wrong) > 0.03, fault
-    assert float(jnp.max(jnp.abs(got - wrong))) > 100 * F32_TOL
-
-
 def test_rows_of_logits_are_the_logits_rows():
     cfg, model, params = make()
     toks = seqs(cfg)
     rows = reference.forward_rows(
         builder.reference_params({"tie_word_embeddings": False}, params),
         toks, **ref_kwargs(cfg))
-    want = ref_forward(cfg, params, toks)
+    want = plain_reference(cfg, params, toks)       # op by op, as ``rows``
     np.testing.assert_allclose(rows[:, 5:9], want[:, 5:9], atol=1e-6)
     np.testing.assert_allclose(rows[0, 20:], want[0, 20:], atol=1e-6)
     # through jit, as the harness returns it
@@ -363,6 +282,9 @@ def test_cache_rows_have_no_head_axis_and_count_every_layer():
 def test_rows_of_and_row_parts_are_inverses(kv_lora_rank):
     cfg = MLAConfig.debug_kanana(kv_lora_rank=kv_lora_rank)
     model = model_for(cfg)
+    assert model.kv_row_shapes() == ((
+        kv_lora_rank + (mla_attention.PE_LANES if kv_lora_rank == 128
+                        else cfg.qk_rope_head_dim),), (0,))
     k = jax.random.split(jax.random.key(3), 2)
     c = jax.random.normal(k[0], (2, 5, kv_lora_rank))
     k_pe = jnp.pad(jax.random.normal(k[1], (2, 5, cfg.qk_rope_head_dim)),
@@ -376,23 +298,6 @@ def test_rows_of_and_row_parts_are_inverses(kv_lora_rank):
     np.testing.assert_array_equal(got_pe, k_pe)
 
 
-def test_serving_params_keep_the_float32_leaves():
-    cfg, model, params = make(jnp.bfloat16)
-    served = model.serving_params(params)
-    f32 = {"attn_norm", "mlp_norm", "kv_norm", "router", "router_bias"}
-    for stack in ("layers", "leading_layers"):
-        for name, a in served[stack].items():
-            assert a.dtype == (jnp.float32 if name in f32
-                               else jnp.bfloat16), (stack, name)
-    assert served["embed"].dtype == served["lm_head"].dtype == jnp.bfloat16
-    assert served["norm_f"].dtype == jnp.float32
-    assert "router" not in served["leading_layers"]
-    assert float(jnp.std(served["layers"]["router_bias"])) > 0   # drawn
-    again = model.serving_params(served)
-    assert all(a is b for a, b in zip(jax.tree.leaves(again),
-                                      jax.tree.leaves(served)))
-
-
 def test_num_params_counts_what_init_makes_and_the_published_sizes():
     cfg, _, params = make()
     assert cfg.num_params() == sum(a.size for a in jax.tree.leaves(params))
@@ -403,16 +308,6 @@ def test_num_params_counts_what_init_makes_and_the_published_sizes():
         {**pub, "num_hidden_layers": n}, 128).num_params()
     assert at(5) == pub["parameters"] == 3_149_554_688
     assert at(48) == 30_670_815_104
-
-
-def _scans(jaxpr, out=None):
-    out = [] if out is None else out
-    for eqn in jaxpr.eqns:
-        if eqn.primitive.name == "scan":
-            out.append(eqn)
-        for sub in jax.core.jaxprs_in_params(eqn.params):
-            _scans(sub, out)
-    return out
 
 
 @pytest.mark.parametrize("method", ["forward_step", "decode_step_paged",
@@ -435,7 +330,7 @@ def test_each_serving_program_is_two_layer_scans_and_no_weight_sized_copy(
             "prefill_with_prefix": (params, toks, prefix["k"], prefix["v"],
                                     two + 8, two + 16)}[method]
     jaxpr = jax.make_jaxpr(getattr(model, method))(*args).jaxpr
-    lengths = sorted(e.params["length"] for e in _scans(jaxpr)
+    lengths = sorted(e.params["length"] for e in scans(jaxpr)
                      if e.params["length"] in (1, 2))
     assert lengths == [1, 2]
     sliced = set()
@@ -453,96 +348,6 @@ def test_each_serving_program_is_two_layer_scans_and_no_weight_sized_copy(
         shape = tuple(layers[name].shape)
         assert shape not in sliced and (shape[0] * shape[1],) + shape[2:] \
             not in sliced, name
-
-
-@pytest.mark.parametrize("method,scopes", [
-    ("forward_step", ("mla_q_proj", "mla_kv_down", "mla_kv_up",
-                      "mla_attention", "moe_shared_expert",
-                      "dense_ffn_leading", "moe_router")),
-    ("decode_step_paged", ("mla_q_proj", "mla_kv_down", "mla_q_absorb",
-                           "mla_attention", "mla_v_up", "moe_shared_expert",
-                           "dense_ffn_leading", "moe_router"))])
-def test_scopes_are_in_the_lowered_programs_metadata(method, scopes):
-    cfg, model, params = make()
-    two = jnp.zeros((2,), I32)
-    args = {"forward_step": (params, jnp.ones((2, 16), I32),
-                             model.init_kv_cache(2, 16), two),
-            "decode_step_paged": (params, two, model.init_kv_pool(9, 8),
-                                  jnp.zeros((2, 4), I32), two)}[method]
-    text = jax.jit(getattr(model, method)).lower(*args).as_text(
-        debug_info=True)
-    for scope in scopes:
-        assert scope in text, scope
-    assert ("mla_kv_up" in text) == (method == "forward_step")
-
-
-# -- the engine ------------------------------------------------------------------
-def _prompt(cfg, n, seed):
-    return [int(t) for t in np.random.default_rng(seed).integers(
-        1, cfg.vocab_size, n)]
-
-
-ENGINE_CASES = {
-    # name: (prompt lengths, engine kwargs, the stats key that must move,
-    # the model's overrides)
-    "bucket_prefill": ((5, 12, 20), {}, "prefills", {}),
-    "chunked_prefill": ((40, 9), {}, "prefills", {}),
-    # at the published widths' layout, k_pe padded to a lane tile: the
-    # second request's prefill gathers the first's rows and decodes over
-    # them (the other cases insert, gather and decode the 48-lane row)
-    "prefix_prefill": ("shared", {}, "prefix_prefills",
-                       {"kv_lora_rank": 128}),
-    "preemption_by_recompute": ((20, 21, 22), {"num_blocks": 10},
-                                "preemptions", {}),
-}
-
-
-@pytest.mark.parametrize("case", sorted(ENGINE_CASES))
-def test_engine_greedy_tokens_are_the_references_argmax(case):
-    """Through ``ContinuousBatchingEngine`` in float32 compute: every
-    generated token is the reference's first choice given the prompt and
-    the tokens before it (teacher forced) unless the reference has its
-    first two within 1e-3, and the expert FFN processed exactly what a
-    dropless FFN must, over the EXPERT layers alone. The shared
-    prompts' second request reads the first's rows back: insert, prefix
-    gather, decode over the ONE row."""
-    lens, kwargs, moved, overrides = ENGINE_CASES[case]
-    cfg, model, params = make(**overrides)
-    if lens == "shared":
-        head = _prompt(cfg, 16, 50)
-        prompts = [head + _prompt(cfg, n, i) for i, n in enumerate((3, 7))]
-    else:
-        prompts = [_prompt(cfg, n, i) for i, n in enumerate(lens)]
-    eng = ContinuousBatchingEngine(
-        model, params, max_slots=4, max_seq=64, prefill_buckets=(8, 16, 32),
-        block_size=8, **kwargs)
-    n_out = 12 if case == "preemption_by_recompute" else 6
-    with jax.default_matmul_precision("highest"):
-        if lens == "shared":        # the second finds the first's blocks
-            reqs = [eng.generate([p], SamplingParams(max_tokens=n_out))[0]
-                    for p in prompts]
-        else:
-            reqs = eng.generate(prompts, SamplingParams(max_tokens=n_out))
-    for prompt, req in zip(prompts, reqs):
-        assert len(req.output) == n_out
-        want = np.asarray(ref_forward(
-            cfg, params, jnp.asarray([prompt + req.output], I32))[0][
-                len(prompt) - 1:len(prompt) - 1 + n_out])
-        for row, tok in zip(want, req.output):
-            assert row.max() - row[tok] < 1e-3
-    stats = eng.stats
-    assert stats[moved] > 0
-    assert stats["moe_router_kind"] == "sigmoid"
-    assert eng.decode_attention_impl == "mla_xla"
-    assert stats["kv_row_bytes"] == 4 * (cfg.kv_lora_rank + model.pe_lanes)
-    assert eng.kv["k"].shape[2:] == (8, cfg.kv_lora_rank + model.pe_lanes)
-    assert eng.kv["v"].shape[2:] == (8, 0)
-    assert stats["kv_pool_bytes"] == sum(a.nbytes for a in eng.kv.values())
-    assert stats["moe_assignments"] == stats["moe_assignments_expected"] > 0
-    load = np.asarray(stats["moe_expert_load"])
-    assert load.shape == (cfg.n_layers - cfg.leading_layers, cfg.num_experts)
-    assert load.sum() == stats["moe_assignments"]
-    assert len(set(load.sum(1).tolist())) == 1
 
 
 def test_a_dense_models_engine_reports_its_row_and_no_router():

@@ -16,6 +16,7 @@ from ray_tpu.llm import ContinuousBatchingEngine, SamplingParams
 from ray_tpu.llm.paged_cache import (BlockPool, allocate_slot,
                                      ensure_capacity, seal_prompt_blocks)
 from ray_tpu.models.llama import LlamaConfig, LlamaModel
+from tests.program_readers import layer_scan_operands
 
 
 # ---------------------------------------------------------------------------
@@ -912,33 +913,6 @@ def _deep_model(kind, impl, layers=3, dtype=jnp.float32):
     model = model_for(dataclasses.replace(
         cfg, n_layers=layers, dtype=dtype, decode_attention=impl))
     return model, jax.jit(model.init)(jax.random.key(3))
-
-
-def _scans(jaxpr):
-    """Every ``scan`` equation of a jaxpr, nested ones included."""
-    for eqn in jaxpr.eqns:
-        if eqn.primitive.name == "scan":
-            yield eqn
-        for sub in jax.core.jaxprs_in_params(eqn.params):
-            yield from _scans(sub)
-
-
-def layer_scan_operands(model, params, slots=2, num_blocks=7, bs=8, maxb=2):
-    """(xs shapes, ys shapes, carry shapes, the pool's per-layer shape,
-    the stack's shape) of the layer scan of ``decode_step_paged``."""
-    pool = model.init_kv_pool(num_blocks, bs)
-    jaxpr = jax.make_jaxpr(model.decode_step_paged)(
-        params, jnp.zeros((slots,), jnp.int32), pool,
-        jnp.zeros((slots, maxb), jnp.int32), jnp.zeros((slots,), jnp.int32))
-    L = model.cfg.n_layers
-    scan, = [e for e in _scans(jaxpr.jaxpr) if e.params["length"] == L]
-    n_consts, n_carry = scan.params["num_consts"], scan.params["num_carry"]
-    shapes = lambda vs: [tuple(v.aval.shape) for v in vs]
-    per_layer = tuple(pool["k"].shape[1:])
-    return (shapes(scan.invars[n_consts + n_carry:]),
-            shapes(scan.outvars[n_carry:]),
-            shapes(scan.invars[n_consts:n_consts + n_carry]),
-            per_layer, (L * per_layer[0],) + per_layer[1:])
 
 
 @pytest.mark.parametrize("impl", ["xla", "pallas"])

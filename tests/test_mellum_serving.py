@@ -15,6 +15,7 @@ layer's freed blocks were never needed again.
 """
 
 import dataclasses
+import functools
 import math
 
 import jax
@@ -28,10 +29,12 @@ from ray_tpu.llm.engine import ContinuousBatchingEngine, SamplingParams
 from ray_tpu.models import LlamaConfig, MoEConfig, model_for
 from ray_tpu.models.llama import FULL, LAYER_KINDS, SLIDING
 from ray_tpu.ops import paged_attention, rope
+from tests import serving_family as serving
+from tests.serving_family import (I32, bucket_prefill,
+                                  prefill_then_paged_decode, rel_rms)
 
 F32_TOL = 1e-4          # max |logit difference|, logits of RMS ~1
 BF16_REL_RMS = 0.02     # the dense block's bf16 floor at debug widths
-I32 = jnp.int32
 WINDOW = 16
 PATTERN = ("sliding_attention",) * 3 + ("full_attention",)
 ROPE = {
@@ -54,20 +57,7 @@ def config(dtype=jnp.float32, **overrides):
         dtype=dtype), **overrides})
 
 
-def make(dtype=jnp.float32, seed=1, **overrides):
-    cfg = config(dtype, **overrides)
-    model = model_for(cfg)
-    params = jax.jit(model.init)(jax.random.key(seed))
-    layers = params["layers"]
-    key = jax.random.key(seed + 100)
-    for name in ("attn_norm", "mlp_norm"):
-        key, sub = jax.random.split(key)
-        layers[name] = 1.0 + 0.3 * jax.random.normal(sub, layers[name].shape)
-    layers["router"] = layers["router"] * (2304 / cfg.dim) ** 0.5
-    return cfg, model, params
-
-
-def ref_forward(cfg, params, tokens, **kw):
+def plain_reference(cfg, params, tokens, **kw):
     kw = {**dict(layer_types=cfg.layer_types,
                  sliding_window=cfg.sliding_window, rope_parameters=ROPE,
                  norm_topk_prob=cfg.norm_topk_prob), **kw}
@@ -77,95 +67,39 @@ def ref_forward(cfg, params, tokens, **kw):
         tokens, rms_norm_eps=cfg.norm_eps, top_k=cfg.expert_top_k, **kw)
 
 
-def seqs(cfg, shape=(2, 56), seed=0):
-    return jnp.asarray(np.random.default_rng(seed).integers(
-        1, cfg.vocab_size, shape), I32)
+# -- the model's programs, each returning logits for tokens[:, from:]: the
+# paged decode goes through the UNIFORM pool (every layer holds every
+# token, one table) across the window's edge (24 rows are prefilled: a
+# sliding layer skips the rows behind its window); the suffix prefill runs
+# over a cached prefix longer than the window
+def prefill_24(model, params, toks):
+    return prefill_then_paged_decode(model, params, toks, prompt=24)
 
 
-def rel_rms(got, want):
-    return float(jnp.sqrt(jnp.mean((got - want) ** 2) / jnp.mean(want ** 2)))
+FAMILY = serving.Family(
+    config=config, reference=plain_reference, shape=(2, 56),
+    seeded=serving.drawn(("attn_norm", "mlp_norm"), 2304),
+    f32_tol=F32_TOL, bf16_rel_rms=BF16_REL_RMS,
+    paths={"apply": (serving.full_forward, 0),
+           "forward_step": (bucket_prefill, 0),
+           "prefill_then_paged_decode": (prefill_24, 0),
+           "paged_decode_with_the_kernel": (
+               lambda model, params, toks:
+               serving.paged_decode_with_the_kernel(model, params, toks,
+                                                    prompt=24), 0),
+           "prefix_prefill": (
+               lambda model, params, toks:
+               serving.prefix_prefill(model, params, toks, prefix=24), -1)})
 
 
-# -- the model's programs, each returning logits for tokens[:, from:] ------
-def full_forward(model, params, toks):
-    return model.apply(params, toks)
+make = functools.partial(serving.make, FAMILY)
+ref_forward = functools.partial(serving.reference, FAMILY)
 
 
-def bucket_prefill(model, params, toks):
-    B, total = toks.shape
-    logits, _ = model.forward_step(params, toks,
-                                   model.init_kv_cache(B, total),
-                                   jnp.zeros((B,), I32))
-    return logits
+seqs = functools.partial(serving.seqs, shape=FAMILY.shape)
 
 
-def prefill_then_paged_decode(model, params, toks, prompt=24, bs=8):
-    """``check_logits``'s route: bucket prefill into a slot-major cache,
-    scattered into the UNIFORM pool (every layer holds every token, one
-    table), then paged decode steps across the window's edge: a sliding
-    layer skips the rows behind its window."""
-    B, total = toks.shape
-    nb = -(-total // bs)
-    cache = model.init_kv_cache(B, nb * bs)
-    padded = jnp.zeros((B, nb * bs), I32).at[:, :prompt].set(toks[:, :prompt])
-    pre, cache = model.forward_step(params, padded, cache,
-                                    jnp.zeros((B,), I32))
-    pool = model.init_kv_pool(B * nb + 1, bs)
-    L = cache["k"].shape[0]
-    ids = jnp.arange(B * nb)
-    pool = {k: pool[k].at[:, ids].set(
-        cache[k].reshape(L, B * nb, bs, *cache[k].shape[3:]))
-        for k in ("k", "v")}
-    tables = ids.astype(I32).reshape(B, nb)
-    out = [pre[:, :prompt]]
-    decode = jax.jit(model.decode_step_paged)
-    for pos in range(prompt, total):
-        logits, pool = decode(params, toks[:, pos], pool, tables,
-                              jnp.full((B,), pos, I32))
-        out.append(logits[:, None])
-    return jnp.concatenate(out, axis=1)
-
-
-def paged_decode_with_the_kernel(model, params, toks):
-    forced = model_for(dataclasses.replace(model.cfg,
-                                           decode_attention="pallas"))
-    return prefill_then_paged_decode(forced, params, toks)
-
-
-def prefix_prefill(model, params, toks, prefix=24):
-    """The last-token logits of a suffix prefill over a cached prefix
-    longer than the window, padded as the engine pads."""
-    B, total = toks.shape
-    cache = model.init_kv_cache(B, prefix)
-    _, cache = model.forward_step(params, toks[:, :prefix], cache,
-                                  jnp.zeros((B,), I32))
-    pad = jnp.zeros((cache["k"].shape[0], B, 8) + cache["k"].shape[3:],
-                    cache["k"].dtype)
-    suffix = jnp.zeros((B, 32), I32).at[:, :total - prefix].set(
-        toks[:, prefix:])
-    logits, _ = model.prefill_with_prefix(
-        params, suffix, jnp.concatenate([cache["k"], pad], 2),
-        jnp.concatenate([cache["v"], pad], 2), jnp.full((B,), prefix, I32),
-        jnp.full((B,), total - prefix, I32))
-    return logits[:, None]                       # position total - 1
-
-
-PATHS = {"apply": (full_forward, 0),
-         "forward_step": (bucket_prefill, 0),
-         "prefill_then_paged_decode": (prefill_then_paged_decode, 0),
-         "paged_decode_with_the_kernel": (paged_decode_with_the_kernel, 0),
-         "prefix_prefill": (prefix_prefill, -1)}
-
-
-@pytest.mark.parametrize("path", sorted(PATHS))
-def test_float32_compute_matches_the_reference(path):
-    cfg, model, params = make()
-    toks = seqs(cfg)
-    run, start = PATHS[path]
-    with jax.default_matmul_precision("highest"):
-        got = run(model, params, toks)
-        want = ref_forward(cfg, params, toks)[:, start:]
-    assert float(jnp.max(jnp.abs(got - want))) < F32_TOL
+globals().update(serving.cases_of(FAMILY))
 
 
 @pytest.mark.parametrize("control,kw", [
@@ -184,8 +118,8 @@ def test_the_comparison_sees_each_mechanism(control, kw):
     cfg, model, params = make()
     toks = seqs(cfg)
     with jax.default_matmul_precision("highest"):
-        got = model.apply(params, toks)
-        want = ref_forward(cfg, params, toks, **kw)
+        got = serving.full_forward(model, params, toks)
+        want = plain_reference(cfg, params, toks, **kw)  # dicts: op by op
     assert rel_rms(got, want) > 0.01, control
 
 
@@ -193,10 +127,10 @@ def test_the_comparison_sees_each_mechanism(control, kw):
 def test_bf16_compute_matches_the_reference_forced_to_its_routing(path):
     cfg, model, params = make(jnp.bfloat16)
     toks = seqs(cfg)
-    _, extras = jax.jit(model._apply_with_extras)(params, toks)
-    got = PATHS[path][0](model, model.serving_params(params), toks)
-    want = ref_forward(cfg, params, toks, forced_experts=extras["experts"])
-    assert rel_rms(got.astype(jnp.float32), want) < BF16_REL_RMS
+    _, experts = serving.bf16_full_forward(model, params, toks)
+    got = FAMILY.paths[path][0](model, model.serving_params(params), toks)
+    want = ref_forward(cfg, params, toks, forced_experts=experts)
+    assert rel_rms(got, want) < BF16_REL_RMS
 
 
 def test_a_dense_model_with_kinds_matches_its_oracle_and_a_plain_one_has_none():
@@ -220,10 +154,10 @@ def test_a_dense_model_with_kinds_matches_its_oracle_and_a_plain_one_has_none():
     params = model.init(jax.random.key(0))
     assert params["layers"]["wq"].shape == (4, 64, 4, 32)
     assert cfg.num_params() == sum(a.size for a in jax.tree.leaves(params))
-    toks = seqs(cfg, (2, 40))
+    toks = seqs(cfg, shape=(2, 40))
     with jax.default_matmul_precision("highest"):
         want = bucket_prefill(model, params, toks)
-        got = prefill_then_paged_decode(model, params, toks)
+        got = prefill_24(model, params, toks)
         assert float(jnp.max(jnp.abs(got - want))) < F32_TOL
         assert float(jnp.max(jnp.abs(model.apply(params, toks) - want))) \
             < F32_TOL

@@ -16,7 +16,7 @@ from jax.sharding import Mesh
 
 from ray_tpu.models import LlamaConfig, LlamaModel, MoEConfig, MoEModel
 from ray_tpu.ops import moe_dispatch
-from tests.test_llm_paged import _scans
+from tests.program_readers import scans as _scans
 
 I32 = jnp.int32
 EXPERT_LEAVES = ("e_gate", "e_up", "e_down")

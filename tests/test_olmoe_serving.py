@@ -13,6 +13,7 @@ probabilities is under ``NEAR_TIE``.
 """
 
 import dataclasses
+import functools
 import re
 
 import jax
@@ -26,6 +27,9 @@ from ray_tpu.models import (LlamaConfig, LlamaModel, MoEConfig, MoEModel,
                             model_for)
 from ray_tpu.ops import moe_dispatch
 from ray_tpu.ops.norms import rms_norm
+from tests import serving_family as serving
+from tests.program_readers import layer_scan_operands
+from tests.serving_family import I32, same_sets, seqs
 
 F32_TOL = 1e-4          # max |logit difference|, logits of RMS ~1
 # bf16 compute against the float32 reference, relative RMS of the logits:
@@ -38,25 +42,9 @@ BF16_REL_RMS = 0.02
 # that did read 0.00102; ONE swap in 96 pairs takes the unforced
 # comparison from 0.010 to 0.030, past the tolerance
 NEAR_TIE = 0.005
-I32 = jnp.int32
 
 
-def make(dtype=jnp.float32, seed=1, **overrides):
-    cfg = MoEConfig.debug_olmoe(dtype=dtype, **overrides)
-    model = model_for(cfg)
-    params = jax.jit(model.init)(jax.random.key(seed))
-    layers = params["layers"]
-    key = jax.random.key(seed + 100)
-    for name in ("q_norm", "k_norm", "attn_norm", "mlp_norm"):
-        key, sub = jax.random.split(key)
-        layers[name] = 1.0 + 0.3 * jax.random.normal(sub, layers[name].shape)
-    # the init's 0.02 gives router logits of sigma 0.9 at the published
-    # width 2048; the same at this width
-    layers["router"] = layers["router"] * (2048 / cfg.dim) ** 0.5
-    return cfg, model, params
-
-
-def ref_forward(cfg, params, tokens, **kw):
+def plain_reference(cfg, params, tokens, **kw):
     layers = [{k: v[i] for k, v in params["layers"].items()}
               for i in range(cfg.n_layers)]
     return reference.forward(
@@ -66,133 +54,23 @@ def ref_forward(cfg, params, tokens, **kw):
         top_k=cfg.expert_top_k, norm_topk_prob=cfg.norm_topk_prob, **kw)
 
 
-def seqs(cfg, shape=(2, 24), seed=0):
-    return jnp.asarray(np.random.default_rng(seed).integers(
-        1, cfg.vocab_size, shape), I32)
-
-
-def rel_rms(got, want):
-    return float(jnp.sqrt(jnp.mean((got - want) ** 2) / jnp.mean(want ** 2)))
-
-
-def same_sets(a, b):
-    """[..., K] expert ids -> [...] bool: the same experts, any order."""
-    return jnp.all(jnp.sort(a, -1) == jnp.sort(b, -1), axis=-1)
-
-
-# -- the serving paths, each returning logits for tokens[:, from:] ---------
-def full_forward(model, params, toks):
-    return model.apply(params, toks)
-
-
-def prefill_then_paged_decode(model, params, toks, prompt=16, bs=8):
-    """``check_logits``'s route: bucket prefill into a slot-major cache,
-    scattered into pool blocks, then paged decode steps."""
-    B, total = toks.shape
-    nb = -(-total // bs)
-    cache = model.init_kv_cache(B, nb * bs)
-    padded = jnp.zeros((B, nb * bs), I32).at[:, :prompt].set(toks[:, :prompt])
-    pre, cache = model.forward_step(params, padded, cache,
-                                    jnp.zeros((B,), I32))
-    pool = model.init_kv_pool(B * nb + 1, bs)
-    L = cache["k"].shape[0]
-    ids = jnp.arange(B * nb)
-    pool = {k: pool[k].at[:, ids].set(
-        cache[k].reshape(L, B * nb, bs, *cache[k].shape[3:]))
-        for k in ("k", "v")}
-    tables = ids.astype(I32).reshape(B, nb)
-    out = [pre[:, :prompt]]
-    for pos in range(prompt, total):
-        logits, pool = model.decode_step_paged(
-            params, toks[:, pos], pool, tables, jnp.full((B,), pos, I32))
-        out.append(logits[:, None])
-    return jnp.concatenate(out, axis=1)
-
-
-def prefix_prefill(model, params, toks, prefix=8):
-    """The last-token logits of a suffix prefill over a cached prefix
-    (K/V of the prefix from a plain prefill), padded as the engine pads."""
-    B, total = toks.shape
-    cache = model.init_kv_cache(B, prefix)
-    _, cache = model.forward_step(params, toks[:, :prefix], cache,
-                                  jnp.zeros((B,), I32))
-    pad = jnp.zeros((cache["k"].shape[0], B, 8) + cache["k"].shape[3:],
-                    cache["k"].dtype)
-    suffix = jnp.zeros((B, 32), I32).at[:, :total - prefix].set(
-        toks[:, prefix:])
-    logits, _ = model.prefill_with_prefix(
-        params, suffix, jnp.concatenate([cache["k"], pad], 2),
-        jnp.concatenate([cache["v"], pad], 2), jnp.full((B,), prefix, I32),
-        jnp.full((B,), total - prefix, I32))
-    return logits[:, None]                       # position total - 1
-
-
-PATHS = {"full_forward": (full_forward, 0),
-         "prefill_then_paged_decode": (prefill_then_paged_decode, 0),
-         "prefix_prefill": (prefix_prefill, -1)}
-
-
-@pytest.mark.parametrize("path", sorted(PATHS))
-def test_float32_compute_matches_the_reference(path):
-    cfg, model, params = make()
-    toks = seqs(cfg)
-    run, start = PATHS[path]
-    with jax.default_matmul_precision("highest"):
-        got = jax.jit(lambda p, t: run(model, p, t))(params, toks)
-    want = ref_forward(cfg, params, toks)[:, start:]
-    assert got.shape == want.shape
-    assert float(jnp.abs(got - want).max()) <= F32_TOL
+def near_ties_alone(cfg, model, params, served, toks, experts):
+    """The system's own choices differ from the reference's only at
+    near-ties of the reference."""
+    _, routing = ref_forward(cfg, params, toks, with_routing=True)
+    differs = ~same_sets(experts, routing["experts"])
+    assert float(jnp.max(jnp.where(differs, routing["gap"], 0.0))) < NEAR_TIE
+    assert float(jnp.mean(differs)) < 0.1
 
 
 def test_float32_routing_is_the_references_everywhere():
     cfg, model, params = make()
     toks = seqs(cfg)
     with jax.default_matmul_precision("highest"):
-        _, extras = jax.jit(model._apply_with_extras)(params, toks)
+        _, extras = serving.jitted(model, "_apply_with_extras")(params, toks)
     _, routing = ref_forward(cfg, params, toks, with_routing=True)
     assert extras["experts"].shape == routing["experts"].shape
     assert bool(jnp.all(same_sets(extras["experts"], routing["experts"])))
-
-
-def paged_decode_from_empty(model, params, toks, bs=8):
-    """Every position through ``decode_step_paged_counted`` (an empty
-    pool, one token a step): logits and the routing of every position."""
-    B, total = toks.shape
-    nb = -(-total // bs)
-    pool = model.init_kv_pool(B * nb + 1, bs)
-    tables = jnp.arange(B * nb, dtype=I32).reshape(B, nb)
-
-    def step(pool, pos):
-        logits, pool, extras = model.decode_step_paged_counted(
-            params, toks[:, pos], pool, tables, jnp.full((B,), pos, I32))
-        return pool, (logits, extras["experts"][:, :, 0])
-
-    _, (logits, experts) = jax.lax.scan(step, pool, jnp.arange(total))
-    # [S, B, V] -> [B, S, V]; [S, L, B, K] -> [L, B, S, K]
-    return logits.transpose(1, 0, 2), experts.transpose(1, 2, 0, 3)
-
-
-def bf16_full_forward(model, params, toks):
-    logits, extras = model._apply_with_extras(params, toks)
-    return logits, extras["experts"]
-
-
-@pytest.mark.parametrize("path", ["full_forward", "paged_decode"])
-@pytest.mark.parametrize("seed", [1, 2, 3])
-def test_bf16_compute_with_the_reference_forced_to_its_routing(path, seed):
-    cfg, model, params = make(jnp.bfloat16, seed)
-    toks = seqs(cfg, seed=seed)
-    run = bf16_full_forward if path == "full_forward" else \
-        paged_decode_from_empty
-    got, experts = jax.jit(lambda p, t: run(model, p, t))(params, toks)
-    want_free, routing = ref_forward(cfg, params, toks, with_routing=True)
-    want = ref_forward(cfg, params, toks, forced_experts=experts)
-    assert rel_rms(got, want) <= BF16_REL_RMS
-    # the system's own choices differ from the reference's only at
-    # near-ties of the reference
-    differs = ~same_sets(experts, routing["experts"])
-    assert float(jnp.max(jnp.where(differs, routing["gap"], 0.0))) < NEAR_TIE
-    assert float(jnp.mean(differs)) < 0.1
 
 
 # -- what the tolerance must refuse -----------------------------------------
@@ -229,8 +107,7 @@ FAULTS = {
 }
 
 
-@pytest.mark.parametrize("fault", sorted(FAULTS))
-def test_a_faulty_block_is_refused(fault):
+def faulty(fault):
     """Each in float32 compute, so nothing but the fault differs: far
     outside the bf16 tolerance, let alone the float32 one (relative RMS
     0.11 for the capacity drop, 0.31-0.46 for the other three)."""
@@ -238,9 +115,44 @@ def test_a_faulty_block_is_refused(fault):
     toks = seqs(cfg, shape=(4, 32))
     with jax.default_matmul_precision("highest"):
         got = jax.jit(FAULTS[fault](cfg).apply)(params, toks)
-    want = ref_forward(cfg, params, toks)
-    assert rel_rms(got, want) > 2 * BF16_REL_RMS
-    assert float(jnp.abs(got - want).max()) > 100 * F32_TOL
+    return got, ref_forward(cfg, params, toks)
+
+
+def engine_stats(eng, stats, cfg, model):
+    """The expert FFN processed exactly what a dropless FFN must."""
+    assert stats["moe_assignments"] == stats["moe_assignments_expected"] > 0
+    load = np.asarray(stats["moe_expert_load"])
+    assert load.shape == (cfg.n_layers, cfg.num_experts)
+    assert load.sum() == stats["moe_assignments"]
+    # K rows a live slot a layer: each layer saw the same number
+    assert len(set(load.sum(1).tolist())) == 1
+
+
+FAMILY = serving.Family(
+    config=MoEConfig.debug_olmoe, reference=plain_reference,
+    seeded=serving.drawn(("q_norm", "k_norm", "attn_norm", "mlp_norm"), 2048),
+    f32_tol=F32_TOL, bf16_rel_rms=BF16_REL_RMS,
+    paths={"full_forward": (serving.full_forward, 0),
+           "prefill_then_paged_decode": (serving.prefill_then_paged_decode,
+                                         0),
+           "prefix_prefill": (serving.prefix_prefill, -1)},
+    bf16_paths={"full_forward": serving.bf16_full_forward,
+                "paged_decode": serving.paged_decode_from_empty},
+    bf16_cases=tuple((path, seed) for seed in (1, 2, 3)
+                     for path in ("full_forward", "paged_decode")),
+    after_bf16=near_ties_alone,
+    faults=tuple(sorted(FAULTS)), faulty=faulty,
+    fault_floors=lambda fault: (2 * BF16_REL_RMS, 100 * F32_TOL),
+    engine_cases=serving.engine_cases(),
+    greedy_margin=0.0,      # the reference's first choice and no other
+    engine_stats=engine_stats)
+
+
+make = functools.partial(serving.make, FAMILY)
+ref_forward = functools.partial(serving.reference, FAMILY)
+
+
+globals().update(serving.cases_of(FAMILY))
 
 
 # -- the dropless FFN itself -------------------------------------------------
@@ -305,61 +217,6 @@ def test_a_1536_token_prefill_builds_no_token_by_expert_by_slot_array():
 
 
 # -- the engine and Serve ------------------------------------------------------
-def _prompt(cfg, n, seed):
-    return [int(t) for t in np.random.default_rng(seed).integers(
-        1, cfg.vocab_size, n)]
-
-
-ENGINE_CASES = {
-    # name: (prompt lengths, engine kwargs, the stats key that must move)
-    "bucket_prefill": ((5, 12, 20), {}, "prefills"),
-    "chunked_prefill": ((40, 9), {}, "prefills"),
-    "prefix_prefill": ("shared", {}, "prefix_prefills"),
-    "preemption_by_recompute": ((20, 21, 22), {"num_blocks": 10},
-                                "preemptions"),
-}
-
-
-@pytest.mark.parametrize("case", sorted(ENGINE_CASES))
-def test_engine_greedy_tokens_are_the_references_argmax(case):
-    """Through ``ContinuousBatchingEngine`` in float32 compute: every
-    generated token is the reference's first choice given the prompt and
-    the tokens before it (teacher forced), and the expert FFN processed
-    exactly what a dropless FFN must."""
-    cfg, model, params = make()
-    lens, kwargs, moved = ENGINE_CASES[case]
-    if lens == "shared":
-        head = _prompt(cfg, 16, 50)
-        prompts = [head + _prompt(cfg, n, i) for i, n in enumerate((3, 7))]
-    else:
-        prompts = [_prompt(cfg, n, i) for i, n in enumerate(lens)]
-    eng = ContinuousBatchingEngine(
-        model, params, max_slots=4, max_seq=64, prefill_buckets=(8, 16, 32),
-        block_size=8, **kwargs)
-    n_out = 12 if case == "preemption_by_recompute" else 6
-    with jax.default_matmul_precision("highest"):
-        if lens == "shared":        # the second finds the first's blocks
-            reqs = [eng.generate([p], SamplingParams(max_tokens=n_out))[0]
-                    for p in prompts]
-        else:
-            reqs = eng.generate(prompts, SamplingParams(max_tokens=n_out))
-    for prompt, req in zip(prompts, reqs):
-        assert len(req.output) == n_out
-        want = ref_forward(cfg, params,
-                           jnp.asarray([prompt + req.output], I32))[0]
-        first = [int(t) for t in jnp.argmax(
-            want[len(prompt) - 1:len(prompt) - 1 + n_out], -1)]
-        assert req.output == first
-    stats = eng.stats
-    assert stats[moved] > 0
-    assert stats["moe_assignments"] == stats["moe_assignments_expected"] > 0
-    load = np.asarray(stats["moe_expert_load"])
-    assert load.shape == (cfg.n_layers, cfg.num_experts)
-    assert load.sum() == stats["moe_assignments"]
-    # K rows a live slot a layer: each layer saw the same number
-    assert len(set(load.sum(1).tolist())) == 1
-
-
 def test_expert_counters_are_zero_and_the_decode_program_the_models_own_for_a_dense_model():
     model = LlamaModel(LlamaConfig.debug(vocab_size=256, max_seq_len=64))
     eng = ContinuousBatchingEngine(
@@ -459,8 +316,6 @@ def test_the_expert_models_layer_scan_stacks_its_extras_and_never_the_pool():
     overrides the q/k treatment and the FFN, not the scan): the pool
     rides its carry as one stack, and all it stacks up over layers are
     its FFN's extras: router loss, per-expert load, chosen experts."""
-    from tests.test_llm_paged import layer_scan_operands
-
     cfg, model, params = make(n_layers=3)
     xs, ys, carry, per_layer, stack = layer_scan_operands(model, params)
     assert per_layer not in xs and per_layer not in ys

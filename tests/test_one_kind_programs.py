@@ -29,14 +29,12 @@ are the parent's.
 """
 
 import hashlib
-import importlib
 
 import jax
 import jax.numpy as jnp
 import pytest
 
-from benchmark import run as harness
-from ray_tpu.llm.engine import ContinuousBatchingEngine
+from tests.program_readers import lowered_programs
 
 # sha256[:16] of ``lowered.as_text()``, computed on c95a537 (``decode``: on
 # PR 33's tree; mellum2: on f9165a5; the expert models' ``decode``,
@@ -55,41 +53,6 @@ PARENT = {
         "insert": "8b3a1532733cbdc8", "gather": "54aa86b2efd8fa61",
         "prefill_prefix": "62933ba3014f7270"},
 }
-
-
-def lowered_programs(name: str) -> dict:
-    cfg = harness.load_json(harness.ROOT, f"benchmark/configs/{name}.json")
-    builder = importlib.import_module("benchmark.builders." + cfg["builder"])
-    model = builder.build_model({**cfg, **cfg["tiny_cpu"]}, 128)
-    params = jax.eval_shape(
-        lambda key: model.serving_params(model.init(key)), jax.random.key(0))
-    eng = ContinuousBatchingEngine(
-        model, jax.tree.map(lambda a: jnp.zeros(a.shape, a.dtype), params),
-        max_slots=4, max_seq=128, prefill_buckets=(16, 32), block_size=8)
-    assert eng.eva is None and model.eva is None
-    # a table a kind, and ids a kind, where the model has two
-    kinds = () if model.layer_kinds is None else (2,)
-    assert (eng.window is None) == (model.layer_kinds is None)
-
-    def S(*shape):
-        return jax.ShapeDtypeStruct(shape, jnp.int32)
-
-    pool = jax.eval_shape(lambda: eng.kv)
-    decode = [params, S(4), pool, S(*kinds, 4, eng.blocks_per_slot), S(4),
-              jax.ShapeDtypeStruct((4,), jnp.float32), S(4),
-              jax.eval_shape(lambda: jax.random.key(0)),
-              eng._ffn_counts and S(*eng._ffn_counts[0].shape)]
-    prefix = jax.eval_shape(lambda: model.init_kv_cache(1, 32))
-    return {
-        "decode": eng._decode.lower(*decode),
-        "prefill": eng._prefill.lower(params, S(2, 32), S(2)),
-        "insert": eng._insert.lower(
-            pool, jax.eval_shape(lambda: model.init_kv_cache(2, 32)),
-            S(*kinds, 8)),
-        "gather": eng._gather.lower(pool, S(*kinds, 1, 4)),
-        "prefill_prefix": eng._prefill_prefix.lower(
-            params, S(1, 16), prefix["k"], prefix["v"], S(1), S(1)),
-    }
 
 
 @pytest.fixture(scope="module", params=sorted(PARENT))

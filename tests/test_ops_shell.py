@@ -317,3 +317,9 @@ def test_dashboard_web_ui_and_profiling(ray_start_regular):
         del blob
     finally:
         stop_dashboard()
+        # the memory endpoint starts tracemalloc (16 frames an allocation)
+        # and nothing stops it: every file this xdist worker ran afterwards
+        # ran ~10x slower (tests/test_deepseek_v32_guards.py 22 s -> 262 s
+        # behind this file, PR 59), whichever files the schedule put there
+        import tracemalloc
+        tracemalloc.stop()

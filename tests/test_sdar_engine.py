@@ -10,43 +10,42 @@ block's first denoising pass: four passes a block of four at the quota's
 floor, none that places no token. Float32 compute on seeded weights at
 debug widths."""
 
+import functools
+
 import jax
 import jax.numpy as jnp
-import numpy as np
 import pytest
 
 from benchmark.reference import sdar as reference
 from ray_tpu.llm.engine import ContinuousBatchingEngine, SamplingParams
 from ray_tpu.llm.serving import LLMConfig, LLMServer
-from tests.test_sdar_serving import make, ref_kw, ref_params
+from tests import serving_family as serving
+from tests.serving_family import moved, prompt_of
+from tests.serving_family import generate as run
 
 N = 4
+FAMILY = serving.SDAR
+make = functools.partial(serving.make, FAMILY)
+engine_of = functools.partial(serving.engine_of, FAMILY)
 
 
-def prompt_of(cfg, n, seed):
-    return [int(t) for t in np.random.default_rng(seed).integers(
-        1, cfg.mask_token_id, n)]
+_WANTS = {}
 
 
 def want_of(cfg, params, prompt, n_tokens, **kw):
-    with jax.default_matmul_precision("highest"):
-        return reference.generate(
-            ref_params(params), prompt, n_tokens, block_length=N,
-            denoising_steps=cfg.denoising_steps, mask_id=cfg.mask_token_id,
-            remasking=cfg.remasking,
-            confidence_threshold=cfg.confidence_threshold, **ref_kw(cfg),
-            **kw)
-
-
-def engine_of(model, params, **kw):
-    kw = {**dict(max_slots=4, max_seq=64, prefill_buckets=(8, 16, 32),
-                 block_size=8), **kw}
-    return ContinuousBatchingEngine(model, params, **kw)
-
-
-def run(eng, prompts, sampling):
-    with jax.default_matmul_precision("highest"):
-        return eng.generate(prompts, sampling)
+    """The reference's generation, a Python loop with no cache (seconds a
+    call): what several tests ask of one (model, prompt, length) is
+    computed once (every test's ``params`` are ``make``'s at seed 1)."""
+    key = (cfg, tuple(prompt), n_tokens, tuple(sorted(kw.items())))
+    if key not in _WANTS:
+        with jax.default_matmul_precision("highest"):
+            _WANTS[key] = reference.generate(
+                serving.sdar_ref_params(params), prompt, n_tokens,
+                block_length=N, denoising_steps=cfg.denoising_steps,
+                mask_id=cfg.mask_token_id, remasking=cfg.remasking,
+                confidence_threshold=cfg.confidence_threshold,
+                **serving.sdar_ref_kw(cfg), **kw)
+    return _WANTS[key]
 
 
 CASES = {
@@ -72,11 +71,14 @@ CASES = {
 
 @pytest.mark.parametrize("case", sorted(CASES))
 def test_engine_generates_what_the_reference_generates(case):
+    """An engine a (model, ``max_slots``), which the cases of the same
+    share: every counter is read before the case and after it."""
     lens, n_out, kw = CASES[case]
-    model_kw = {k: v for k, v in kw.items() if k != "max_slots"}
+    slots = {k: kw[k] for k in kw if k == "max_slots"}
+    model_kw = {k: kw[k] for k in kw if k != "max_slots"}
     cfg, model, params = make(**model_kw)
-    eng = engine_of(model, params,
-                    **{k: v for k, v in kw.items() if k == "max_slots"})
+    eng = serving.shared_engine(FAMILY, model_kw, **slots)
+    before = dict(eng.stats)
     prompts = [prompt_of(cfg, n, i) for i, n in enumerate(lens)]
     reqs = run(eng, prompts, SamplingParams(max_tokens=n_out))
     for prompt, req in zip(prompts, reqs):
@@ -91,20 +93,24 @@ def test_engine_generates_what_the_reference_generates(case):
                 break
             streamed.append(tok)
         assert streamed == tokens
-    stats = eng.stats
-    assert stats["block_length"] == N
-    assert stats["tokens_generated"] == n_out * len(prompts)
-    assert stats["moe_assignments"] == stats["moe_assignments_expected"] > 0
+    assert eng.stats["block_length"] == N
+    (generated, assigned, expected, fused, alone, committed, steps,
+     slot_passes, unmasked) = moved(
+        eng, before, "tokens_generated", "moe_assignments",
+        "moe_assignments_expected", "block_commits_fused",
+        "block_commit_passes", "blocks_committed", "decode_steps",
+        "block_slot_passes", "block_tokens_unmasked")
+    assert generated == n_out * len(prompts)
+    assert assigned == expected > 0
     # every block but a request's last was committed: by the pass that
     # began the next or, where more slots owed one at once than a call
     # has room for, by a pass of its own
-    assert stats["block_commits_fused"] > 0
-    assert stats["block_commits_fused"] + stats["block_commit_passes"] == (
-        stats["blocks_committed"] - len(prompts))
-    assert stats["decode_steps"] <= stats["block_slot_passes"]
+    assert fused > 0
+    assert fused + alone == committed - len(prompts)
+    assert steps <= slot_passes
     # every placed token was counted by the pass that placed it (a block
     # that ``max_tokens`` cut was placed whole)
-    assert stats["block_tokens_unmasked"] >= stats["tokens_generated"]
+    assert unmasked >= generated
     assert eng.pool.num_free == eng.num_blocks
 
 
@@ -121,23 +127,25 @@ def test_a_block_of_four_costs_four_passes_at_the_quotas_floor(requests,
     once, and stand a pass apart from then on (every slot taken: a pass
     runs ahead all the while)."""
     cfg, model, params = make()
-    eng = engine_of(model, params)
+    eng = serving.shared_engine(FAMILY)
     assert eng._behind_slots == 2
+    before = dict(eng.stats)
     prompts = [prompt_of(cfg, 8, i) for i in range(requests)]
     reqs = run(eng, prompts, SamplingParams(max_tokens=N * blocks))
     for prompt, req in zip(prompts, reqs):
         assert (req.output, req.unmasked_at) == want_of(cfg, params, prompt,
                                                         N * blocks)
-    stats = eng.stats
-    assert stats["block_tokens_unmasked_by_confidence"] == 0
-    assert stats["block_slot_passes"] == N * blocks * requests + alone
-    assert stats["decode_steps"] == N * blocks + (alone > 0)
-    assert stats["block_commit_passes"] == alone
-    assert stats["block_commits_fused"] == (blocks - 1) * requests - alone
-    assert stats["blocks_committed"] == blocks * requests
-    assert stats["block_tokens_unmasked"] == N * blocks * requests
+    assert moved(
+        eng, before, "block_tokens_unmasked_by_confidence",
+        "block_slot_passes", "decode_steps", "block_commit_passes",
+        "block_commits_fused", "blocks_committed", "block_tokens_unmasked"
+    ) == (0, N * blocks * requests + alone, N * blocks + (alone > 0), alone,
+          (blocks - 1) * requests - alone, blocks * requests,
+          N * blocks * requests)
     # the blocks behind ran through the experts too, and were counted
-    assert stats["moe_assignments"] == stats["moe_assignments_expected"] == (
+    assigned, expected = moved(eng, before, "moe_assignments",
+                               "moe_assignments_expected")
+    assert assigned == expected == (
         (N * blocks + blocks - 1) * requests * N * cfg.expert_top_k
         * cfg.n_layers)
 
@@ -164,7 +172,7 @@ def test_a_stop_token_inside_a_block_cuts_the_block_there():
     tokens, _ = want_of(cfg, params, prompt, 12)
     stop = tokens[5]                    # the sixth token: inside a block
     first = tokens.index(stop)
-    eng = engine_of(model, params)
+    eng = serving.shared_engine(FAMILY)
     req, = run(eng, [prompt], SamplingParams(max_tokens=12,
                                              stop_token_ids=(stop,)))
     assert req.finish_reason == "stop"
@@ -298,20 +306,19 @@ def test_a_page_that_would_split_a_block_is_refused_by_the_engine():
 
 def test_live_blocks_are_counted_up_to_the_blocks_end():
     cfg, model, params = make()
-    eng = engine_of(model, params)
+    eng = serving.shared_engine(FAMILY)
+    before = dict(eng.stats)
     run(eng, [prompt_of(cfg, 8, 0)], SamplingParams(max_tokens=4))
-    stats = eng.stats
     # four passes over rows 8..11 behind 8 cached ones: ceil(12 / 8) pages
-    assert stats["decode_steps"] == 4
-    assert stats["decode_kv_blocks_live"] == 4 * 2
-    assert stats["decode_kv_blocks_table"] == 4 * eng.blocks_per_slot
+    assert moved(eng, before, "decode_steps", "decode_kv_blocks_live",
+                 "decode_kv_blocks_table") == (4, 4 * 2,
+                                               4 * eng.blocks_per_slot)
 
 
 def test_the_handoff_of_a_prefill_is_refused():
-    cfg, model, params = make()
-    eng = engine_of(model, params)
+    cfg = make()[0]
     with pytest.raises(NotImplementedError, match="samples none"):
-        eng.prefill_only(prompt_of(cfg, 8, 0))
+        serving.shared_engine(FAMILY).prefill_only(prompt_of(cfg, 8, 0))
 
 
 def test_llm_server_streams_each_token_with_the_pass_that_placed_it():

@@ -7,77 +7,31 @@ on crafted confidences, and the planted faults, each of which has to
 move the comparison past its tolerance. The engine end to end:
 ``tests/test_sdar_engine.py``."""
 
+import functools
+
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
 from benchmark.reference import sdar as reference
-from ray_tpu.models import MoEConfig, MoEModel, model_for
+from ray_tpu.models import MoEConfig, MoEModel
 from ray_tpu.ops.block_diffusion import (confidence, transfer_quotas,
                                          unmask_step)
+from tests import serving_family as serving
+from tests.serving_family import (I32, jitted, max_abs, paged_prefill,
+                                  rel_rms, seqs)
+from tests.serving_family import sdar_ref_kw as ref_kw
+from tests.serving_family import sdar_ref_params as ref_params
 
 F32_TOL = 1e-4          # max |logit difference|, logits of RMS ~1
 BF16_REL_RMS = 0.02     # bf16 compute, the reference forced to its routing
 FAULT_REL_RMS = 1e-2    # what a planted fault has to pass (the honest
                         # float32 comparison reads ~1e-6)
-I32 = jnp.int32
 N = 4                   # the debug configuration's block
-
-
-def make(dtype=jnp.float32, seed=1, **overrides):
-    cfg = MoEConfig.debug_sdar(dtype=dtype, **overrides)
-    model = model_for(cfg)
-    params = jax.jit(model.init)(jax.random.key(seed))
-    layers = params["layers"]
-    key = jax.random.key(seed + 100)
-    # seeded norm weights are 1: a fault in a norm would hide behind them
-    for name in ("q_norm", "k_norm", "attn_norm", "mlp_norm"):
-        key, sub = jax.random.split(key)
-        layers[name] = 1.0 + 0.3 * jax.random.normal(sub, layers[name].shape)
-    layers["router"] = layers["router"] * (2048 / cfg.dim) ** 0.5
-    return cfg, model, params
-
-
-def ref_params(params):
-    return {name: params[name]
-            for name in ("embed", "layers", "norm_f", "lm_head")}
-
-
-def ref_kw(cfg):
-    return dict(rope_theta=cfg.rope_theta, rms_norm_eps=cfg.norm_eps,
-                top_k=cfg.expert_top_k, norm_topk_prob=cfg.norm_topk_prob)
-
-
-def ref_forward(cfg, params, tokens, **kw):
-    return reference.forward(ref_params(params), tokens, cfg.block_length,
-                             **ref_kw(cfg), **kw)
-
-
-def seqs(cfg, shape=(2, 32), seed=0):
-    return jnp.asarray(np.random.default_rng(seed).integers(
-        1, cfg.mask_token_id, shape), I32)
-
-
-def rel_rms(got, want):
-    return float(jnp.sqrt(jnp.mean((got - want) ** 2) / jnp.mean(want ** 2)))
-
-
-def paged_prefill(model, params, toks, prompt, bs=8):
-    """``check_logits_blocks``'s route: a bucket prefill of the first
-    ``prompt`` tokens, scattered into pool pages: (pool, tables)."""
-    B, total = toks.shape
-    nb = -(-total // bs)
-    cache = model.init_kv_cache(B, nb * bs)
-    padded = jnp.zeros((B, nb * bs), I32).at[:, :prompt].set(toks[:, :prompt])
-    _, cache = model.forward_step(params, padded, cache, jnp.zeros((B,), I32))
-    pool = model.init_kv_pool(B * nb + 1, bs)
-    L = cache["k"].shape[0]
-    ids = jnp.arange(B * nb)
-    pool = {k: pool[k].at[:, ids].set(
-        cache[k].reshape(L, B * nb, bs, *cache[k].shape[3:]))
-        for k in ("k", "v")}
-    return pool, ids.astype(I32).reshape(B, nb)
+FAMILY = serving.SDAR
+ref_forward = functools.partial(serving.reference, FAMILY)
+make = functools.partial(serving.make, FAMILY)
 
 
 def states_of(cfg, tail, seed=5):
@@ -96,9 +50,9 @@ def blocks_through_the_pool(model, params, toks, prompt, states):
     """Every block of ``toks[:, prompt:]`` through the block step, in
     each of ``states`` in turn, the clean pass last (its rows stay):
     logits [states, B, total - prompt, V]."""
-    pool, tables = paged_prefill(model, params, toks, prompt)
+    _, pool, tables = paged_prefill(model, params, toks, prompt)
     B, total = toks.shape
-    step = jax.jit(model.block_step_paged_counted)
+    step = jitted(model, "block_step_paged_counted")
     out = [[] for _ in states]
     for at in range(prompt, total, N):
         for k, state in enumerate(states):
@@ -116,11 +70,10 @@ def test_bucket_prefill_of_ragged_lengths_is_the_block_causal_forward():
     lengths = (8, 20, 32)
     padded = jnp.stack([jnp.where(jnp.arange(32) < n, row, 0)
                         for row, n in zip(toks, lengths)])
-    got, _ = model.forward_step(params, padded, model.init_kv_cache(3, 32),
-                                jnp.zeros((3,), I32))
+    got = serving.bucket_prefill(model, params, padded)
     for row, n in enumerate(lengths):
         want = ref_forward(cfg, params, toks[row:row + 1, :n])
-        assert float(jnp.max(jnp.abs(got[row, :n] - want[0]))) < F32_TOL
+        assert max_abs(got[row, :n], want[0]) < F32_TOL
 
 
 def test_a_block_sees_all_of_itself_and_nothing_after_it():
@@ -129,9 +82,7 @@ def test_a_block_sees_all_of_itself_and_nothing_after_it():
     first moves none of them."""
     cfg, model, params = make()
     toks = seqs(cfg, (1, 16))
-    def run(t):
-        return model.forward_step(params, t, model.init_kv_cache(1, 16),
-                                  jnp.zeros((1,), I32))[0]
+    run = functools.partial(serving.bucket_prefill, model, params)
     base = run(toks)
     inside = run(toks.at[0, 7].set(toks[0, 7] % 100 + 1))
     after = run(toks.at[0, 8].set(toks[0, 8] % 100 + 1))
@@ -144,19 +95,9 @@ def test_chunked_prefill_over_a_prefix_is_the_block_causal_forward(prefix,
                                                                    suffix):
     cfg, model, params = make()
     toks = seqs(cfg, (2, prefix + suffix))
-    cache = model.init_kv_cache(2, prefix)
-    _, cache = model.forward_step(params, toks[:, :prefix], cache,
-                                  jnp.zeros((2,), I32))
-    pad = jnp.zeros((cfg.n_layers, 2, 8) + cache["k"].shape[3:])
-    padded = jnp.zeros((2, 32), I32).at[:, :suffix].set(toks[:, prefix:])
-    got, small = model.prefill_with_prefix(
-        params, padded, jnp.concatenate([cache["k"], pad], 2),
-        jnp.concatenate([cache["v"], pad], 2), jnp.full((2,), prefix, I32),
-        jnp.full((2,), suffix, I32))
-    want = ref_forward(cfg, params, toks)[:, -1]
-    assert float(jnp.max(jnp.abs(got - want))) < F32_TOL
-    assert small["k"].shape == (cfg.n_layers, 2, 32, cfg.n_kv_heads,
-                                cfg.head_dim)
+    got = serving.prefix_prefill(model, params, toks, prefix)
+    want = ref_forward(cfg, params, toks)[:, -1:]
+    assert max_abs(got, want) < F32_TOL
 
 
 # -- (b) the block step through the paged pool ------------------------------
@@ -197,9 +138,9 @@ def test_a_fused_pass_is_the_commit_and_the_denoising_pass_after_it(
     kernel (interpreted) and reference alike."""
     cfg, model, params = make(decode_attention=impl)
     toks, prompt = seqs(cfg, (4, 32)), 16
-    pool, tables = paged_prefill(model, params, toks, prompt)
+    _, pool, tables = paged_prefill(model, params, toks, prompt)
     scratch = pool["k"].shape[1] - 1
-    step = jax.jit(model.block_step_paged_counted)
+    step = jitted(model, "block_step_paged_counted")
     masked = jnp.full((4, N), cfg.mask_token_id, I32)
 
     def at(off):
@@ -281,7 +222,7 @@ def test_bf16_block_step_with_the_reference_forced_to_its_routing():
     toks = seqs(cfg, (2, 12))
     pool = model.init_kv_pool(2 * 2 + 1, 8)
     tables = jnp.arange(4, dtype=I32).reshape(2, 2)
-    step = jax.jit(model.block_step_paged_counted)
+    step = jitted(model, "block_step_paged_counted")
     kept, errs = [], []
     for at in range(0, 12, N):
         for block in (jnp.full((2, N), cfg.mask_token_id, I32),
@@ -362,11 +303,19 @@ def test_a_proposals_confidence_is_its_probability():
 
 
 # -- (e) the planted faults --------------------------------------------------
-def _system_and_reference(fault):
+@functools.lru_cache(maxsize=None)
+def _honest_system():
+    """The honest float32 system's logits, once for every fault."""
     cfg, model, params = make()
     toks, prompt = seqs(cfg, (2, 28)), 16
     states = states_of(cfg, toks[:, prompt:])
-    got = blocks_through_the_pool(model, params, toks, prompt, states)
+    return params, toks, prompt, states, blocks_through_the_pool(
+        model, params, toks, prompt, states)
+
+
+def _system_and_reference(fault):
+    cfg = make()[0]
+    params, toks, prompt, states, got = _honest_system()
     commit, *rows = reference.teacher_forced(
         ref_params(params), toks, states[:2], prompt, N, fault=fault,
         **ref_kw(cfg))
