@@ -486,9 +486,9 @@ class ContinuousBatchingEngine:
         # (request, token) in order, ``None`` for a stream's end: what
         # ``_emit`` decided and the streams have not been handed yet
         self._undelivered: List[tuple] = []
-        # (tokens on the device, active slots, dispatched a step ahead?)
-        # of the decode step that is dispatched and not read yet, between
-        # two ``step()``s
+        # (tokens on the device, active slots, the requests those rows
+        # were dispatched for, dispatched a step ahead?) of the decode
+        # step that is dispatched and not read yet, between two ``step()``s
         self._in_flight: Optional[tuple] = None
         # the host time at which this thread last KNEW the device's queue
         # empty and has enqueued nothing since (None: not known to be);
@@ -565,6 +565,11 @@ class ContinuousBatchingEngine:
                       # temperature > 0 / top_k > 0: the steps whose
                       # program took the sampler's draw / its sort
                       "decode_steps_sampled": 0, "decode_steps_topk": 0,
+                      # decode steps dispatched while the step before
+                      # them was unread, and rows of such steps read and
+                      # NOT booked: their request had ended in the step
+                      # before (``_may_run_ahead``)
+                      "decode_steps_ahead": 0, "decode_rows_dropped": 0,
                       # generation by diffusion over blocks (1 and zeros
                       # for every other model): a block's positions; live
                       # slots x passes; of those the passes that ran a
@@ -1081,7 +1086,8 @@ class ContinuousBatchingEngine:
         return req
 
     def has_work(self) -> bool:
-        return (bool(self.waiting)
+        # (a step ahead whose every request ended is still to be read)
+        return (bool(self.waiting) or self._in_flight is not None
                 or any(s is not None for s in self.slots))
 
     def step(self) -> int:
@@ -1621,22 +1627,25 @@ class ContinuousBatchingEngine:
 
     def _decode_step(self) -> int:
         """Read one decode step's tokens; before that, put the next step
-        on the device where nothing can come between (``_may_run_ahead``):
+        on the device for the slots as they stand (``_may_run_ahead``):
         the device then goes from one step to the next with no host in
         the way, and the read-back, the emit and the streams' work all
-        run under a program."""
+        run under a program. A row is booked only if its slot still
+        holds the request it was dispatched for: one whose request ended
+        in the step before is dropped as it is read."""
         if self._in_flight is None:
             self._in_flight = self._dispatch_decode(ahead=False)
             if self._in_flight is None:
                 self._deliver_deferred()
                 return 0
-        toks, active, ahead = self._in_flight
+        toks, active, reqs, ahead = self._in_flight
         if self._may_run_ahead(active):
             if toks.is_ready():
                 # the step in flight is done and the one after it is
                 # not there yet: the device waits from here, at least
                 self._device_dry_at = time.perf_counter()
             self._in_flight = self._dispatch_decode(ahead=True)
+            self._stats["decode_steps_ahead"] += 1
         else:
             self._in_flight = None
         # the step before's tokens reach their streams now, under the
@@ -1669,9 +1678,12 @@ class ContinuousBatchingEngine:
             if self.block_length > 1:
                 self._emit_blocks(active, toks)
             else:
-                for i in active:
-                    self.offsets[i] += 1
-                    self._emit(i, int(toks[i]))
+                for i, req in zip(active, reqs):
+                    if self.slots[i] is req:
+                        self.offsets[i] += 1
+                        self._emit(i, int(toks[i]))
+                    else:   # ended at the read before: nothing is its
+                        self._stats["decode_rows_dropped"] += 1
         if self._in_flight is not None or not self._admit_order:
             # the device is busy with the next step, or the last slot
             # ended and there is no next dispatch to deliver under
@@ -1680,30 +1692,64 @@ class ContinuousBatchingEngine:
 
     def _may_run_ahead(self, active: List[int]) -> bool:
         """May the step after the one in flight be dispatched before the
-        one in flight is read? Only if that changes nothing for anyone:
-        every slot is taken (with one free, a request that arrives now
-        would find its prefill queued behind a whole further step: TTFT
-        paid for TPOT) and no request waits, nothing came in since the
-        dispatch (the device's inputs still stand), the step in flight
-        ends no request (none stops on a token's VALUE, none reaches
-        its length), and every slot has room for one more token without
-        a preemption. Where a step yields up to a BLOCK a slot, "one
-        more token" reads "one more block" throughout: the pass in
+        one in flight is read? It is dispatched FOR THE SLOTS AS THEY
+        STAND, at any occupancy, and is speculative a ROW: whether the
+        step in flight ends a request (a stop on a token's value, its
+        length) is found when it is read, and the row the step ahead
+        computed for that slot is then dropped (``_decode_step``). What
+        has to hold: no request waits (it is admitted next, and its
+        prefill goes before any further step), nothing came in or left
+        since the dispatch (the device's tokens and offsets still
+        stand), no slot stands at the table's end, every slot has room
+        for one more token without a preemption, and not EVERY request
+        reaches its length in the step in flight (the host knows: that
+        step ahead would be device time nobody reads, in front of the
+        next arrival).
+
+        Why a dropped row harms nobody. It wrote one K/V row at ``offset
+        + 1`` of the ended request, in the tail block that request held
+        alone: room for it was reserved before the dispatch, and the
+        sealed prompt blocks others may share are full and lie before
+        it. The blocks were released when the step before was read, and
+        whatever is allocated or scattered into them afterwards is
+        enqueued AFTER the step ahead on the one device stream, so it
+        lands on top; a recurrent model's state rows of the slot are
+        set anew at its next admission (``_set_state_rows``). A slot
+        freed at one read may hold a new request by the next
+        (``step()`` admits before it decodes), so a row is known by its
+        REQUEST, not its slot, and touches neither ``offsets`` nor
+        ``_last_tokens`` nor a stream. The sampler's key advances a
+        step whether a row is dropped or not, so every other slot draws
+        what it would have. The price is a request's first token: one
+        that arrives under a step ahead finds its prefill queued behind
+        it, once a request, where every token used to pay the host's
+        round trip (docs/serving.md, "A step ahead").
+
+        A block-diffusion engine (``block_length`` > 1) keeps the rule
+        it had, all or nothing a pass: every slot taken, no stop token,
+        no request that the block in flight can end. Its pass ahead also
+        commits the block behind and its offsets are the device's to
+        know, so a dropped pass is another proof (ROADMAP S13). There
+        "one more token" reads "one more block" throughout: the pass in
         flight may finish one (at ``offset``), and the pass ahead then
         writes that block's rows to stay and, at ``offset + n``, the
-        next block's: ``offset + 2n`` rows of room, and no request that
-        the block in flight can end."""
+        next block's: ``offset + 2n`` rows of room."""
         n = self.block_length
-        if (len(active) < self.max_slots or self.waiting
-                or self._dev_tokens is None or self._dev_offsets is None):
+        if (self.waiting or self._dev_tokens is None
+                or self._dev_offsets is None):
             return False
-        for i in active:
-            req = self.slots[i]
-            sampling = req.sampling
-            if (sampling.stop_token_ids
-                    or len(req.output) + n >= sampling.max_tokens
-                    or self.offsets[i] + 2 * n >= self.max_seq):
+        reqs = [self.slots[i] for i in active]
+        if n > 1:
+            if len(active) < self.max_slots or any(
+                    req.sampling.stop_token_ids
+                    or len(req.output) + n >= req.sampling.max_tokens
+                    for req in reqs):
                 return False
+        elif all(len(req.output) + 1 >= req.sampling.max_tokens
+                 for req in reqs):
+            return False
+        if any(self.offsets[i] + 2 * n >= self.max_seq for i in active):
+            return False
         for i in active:
             alloc = self.allocs[i]
             held = len(alloc.blocks)
@@ -1720,13 +1766,16 @@ class ContinuousBatchingEngine:
 
     def _dispatch_decode(self, ahead: bool):
         """Enqueue one decode step, sampling included: ``(tokens on the
-        device, active slots, ahead)``, or None with no active slot.
-        ``ahead``: the step before is still in flight, so every active
-        slot stands one token further than the host's ``offsets`` say."""
+        device, active slots, their requests, ahead)``, or None with no
+        active slot. ``ahead``: the step before is still in flight, so
+        every active slot stands one token further than the host's
+        ``offsets`` say. The REQUESTS say whose the rows are when they
+        are read: a slot may have changed hands by then."""
         with _Phase(self, "engine.schedule", "t_schedule_s"):
             if not ahead:
                 self._grow_or_preempt()
             active = [i for i, r in enumerate(self.slots) if r is not None]
+            reqs = [self.slots[i] for i in active]
         if not active:
             return None
         with _Phase(self, "engine.host_arrays", "t_host_arrays_s"):
@@ -1780,7 +1829,7 @@ class ContinuousBatchingEngine:
             # (what this pass's attention reads is counted when it is
             # read back, ``_emit_blocks``: where a step ahead stands is
             # the device's to know until then)
-            return report, active, ahead
+            return report, active, reqs, ahead
         with _Phase(self, "engine.host_arrays", "t_host_arrays_s"):
             # the NEXT step's offsets go now, under the running program:
             # every active slot will have advanced by one, unless a slot
@@ -1822,7 +1871,7 @@ class ContinuousBatchingEngine:
                 first = np.maximum(pos - self.window + 1, 0) // bs
                 self._stats["decode_kv_blocks_live_window"] += int(
                     (pos // bs - first + 1).sum())
-        return self._dev_tokens, active, ahead
+        return self._dev_tokens, active, reqs, ahead
 
     def _enqueued(self) -> None:
         """A program has just been handed to the device (a decode step,

@@ -472,6 +472,52 @@ def drive(eng, prompts, outs, each_step=None):
     return reqs
 
 
+def reads_first(eng):
+    """``eng`` made to read every step before it dispatches the next, as
+    an engine with a free slot did before PR 60: the same programs and
+    arithmetic with no step ahead, so what a step ahead must not change a
+    token of. -> ``eng``."""
+    eng._may_run_ahead = lambda active: False
+    return eng
+
+
+def ended(k):
+    """A ``drive_arrivals`` due time: once request ``k`` has ended."""
+    return lambda reqs: reqs[k].done.is_set()
+
+
+def drive_arrivals(eng, arrivals):
+    """``arrivals`` = [(due, prompt, sampling)]: each is submitted before
+    the first ``step()`` at which ``due(requests so far)`` holds (``None``:
+    at the start), in order; then step to the end. -> (the requests; how
+    many ENDED WITH A STEP AHEAD ON THE DEVICE, each of which has that
+    step's row dropped as it is read; how many were ADMITTED under one)."""
+    reqs, ended, admitted = [], [0], [0]
+    finish, plan = eng._finish, eng._plan_admission
+
+    def counting_finish(slot, reason):
+        ended[0] += eng._in_flight is not None
+        finish(slot, reason)
+
+    def counting_plan():
+        before = eng.stats["admitted"]
+        out = plan()
+        admitted[0] += (eng.stats["admitted"] - before) * (
+            eng._in_flight is not None)
+        return out
+
+    eng._finish, eng._plan_admission = counting_finish, counting_plan
+    arrivals = list(arrivals)
+    with jax.default_matmul_precision("highest"):
+        while arrivals or eng.has_work():
+            while arrivals and (arrivals[0][0] is None
+                                or arrivals[0][0](reqs)):
+                _, prompt, sampling = arrivals.pop(0)
+                reqs.append(eng.submit(prompt, sampling))
+            eng.step()
+    return reqs, ended[0], admitted[0]
+
+
 def moved(eng, before, *keys):
     """What the counters ``keys`` of a shared engine gained since
     ``before`` (a copy of its ``stats``)."""

@@ -147,6 +147,9 @@ def engine_logits(eng, prompt, steps):
     donated), before the engine takes the same step."""
     model = eng.model
     probe = serving.jitted(model, "decode_step_paged")
+    # the probe stands where the engine's next step will: no step ahead
+    # may have moved the pools and the offsets on
+    serving.reads_first(eng)
     req = eng.submit(list(prompt), SamplingParams(max_tokens=steps + 1))
     eng._admit()
     out = []

@@ -51,6 +51,30 @@ def test_a_reused_slot_sees_no_stale_state():
     assert eng.stats["state_rows_written"] == 3
 
 
+def test_a_dropped_step_ahead_leaves_no_trace_in_the_state():
+    """Two slots, lengths that end one after the other, and a request
+    that arrives as the first ends: it takes that slot while the step
+    dispatched for the ended request is still unread. That step advanced
+    the slot's state rows for nobody; the arrival's activation, enqueued
+    behind it, sets them anew (PR 60: the step ahead is speculative a
+    row). Tokens equal a fresh engine's, one request at a time."""
+    cfg, model, params = serving.make(FAMILY)
+    prompts = [prompt_of(cfg, n, 60 + i) for i, n in enumerate((11, 18, 7))]
+    outs = (5, 16, 8)
+    eng = engine_of(FAMILY, model, params, max_slots=2)
+    reqs, ended_ahead, admitted_ahead = serving.drive_arrivals(eng, [
+        (due, p, SamplingParams(max_tokens=n)) for due, p, n in zip(
+            (None, None, serving.ended(0)), prompts, outs)])
+    for p, n, req in zip(prompts, outs, reqs):
+        assert req.output == alone(FAMILY, model, params, p, n, max_slots=2)
+    stats = eng.stats
+    # the first and the third end beside the second; the second ends alone
+    assert stats["decode_rows_dropped"] == ended_ahead == 2
+    assert admitted_ahead == 1 and stats["state_rows_written"] == 3
+    assert stats["decode_steps_ahead"] > 0.7 * stats["decode_steps"]
+    assert eng.pool.num_free == eng.num_blocks and eng._in_flight is None
+
+
 @pytest.mark.parametrize("block", [8, 32])
 def test_one_kv_head_under_twenty_query_heads(block):
     """The published attention shape, 20 query heads over ONE K/V head of

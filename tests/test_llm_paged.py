@@ -801,8 +801,13 @@ def test_engine_counts_the_blocks_decode_reads(tiny_model):
         assert len(offs) == 2
         live += sum(-(-o // 8) for o in offs)
         table += len(offs) * eng.blocks_per_slot
-        assert eng.stats["decode_kv_blocks_live"] == live
-        assert eng.stats["decode_kv_blocks_table"] == table
+        # the counters are taken at the DISPATCH, and the step after this
+        # one stands on the device already, a token further a slot
+        assert eng._in_flight is not None
+        assert eng.stats["decode_kv_blocks_live"] == live + sum(
+            -(-(o + 1) // 8) for o in offs)
+        assert eng.stats["decode_kv_blocks_table"] == (
+            table + len(offs) * eng.blocks_per_slot)
     assert eng.blocks_per_slot == 8 and table == 6 * 2 * 8
     assert 0 < live < table
 

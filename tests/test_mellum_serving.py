@@ -446,6 +446,30 @@ def test_engine_runs_a_step_ahead_at_full_occupancy_and_frees_under_it(
     assert eng.window_pool.num_free == eng.num_window_blocks
 
 
+def test_a_dropped_step_ahead_leaves_both_kinds_blocks_as_they_were(served):
+    """Two of four slots, lengths that end one after the other across
+    window frees, and a request that arrives as the first ends, with the
+    step dispatched for the ended one unread (PR 60: the step ahead is
+    speculative a row): that step wrote a row into a tail block of EACH
+    kind that the request had held alone; both went back to their pools
+    at the read before, and what takes them is enqueued behind it."""
+    cfg, model, params, oracle = served
+    eng = engine(model, params)
+    ps, outs = prompts((20, 27, 9), seed=5), (12, 45, 30)
+    reqs, ended_ahead, admitted_ahead = serving.drive_arrivals(eng, [
+        (due, p, SamplingParams(max_tokens=n)) for due, p, n in zip(
+            (None, None, serving.ended(0)), ps, outs)])
+    for p, n, r in zip(ps, outs, reqs):
+        assert r.output == oracle(p, n), len(p)
+    st = eng.stats
+    # the first and the third end beside the second, which ends alone
+    assert st["decode_rows_dropped"] == ended_ahead == 2
+    assert admitted_ahead == 1 and st["kv_window_blocks_freed"] > 0
+    assert st["decode_steps_ahead"] > 0.8 * st["decode_steps"]
+    assert eng.window_pool.num_free == eng.num_window_blocks
+    assert eng.pool.num_free == eng.num_blocks and eng._in_flight is None
+
+
 def test_engine_preempts_and_readmits_a_two_kind_request(served):
     cfg, model, params, oracle = served
     eng = engine(model, params, num_blocks=14)

@@ -209,8 +209,8 @@ def test_a_decode_step_is_one_dispatch_of_one_program(built, monkeypatch):
     while eng.has_work():
         if len(reqs) == 2 and reqs[1].done.is_set():
             # the sampled request left: a greedy one beside the first,
-            # with a stop token (never sampled) that keeps the engine
-            # from running a step ahead
+            # with a stop token (never sampled), which holds no step
+            # ahead back: a stop is found as its step is read
             reqs.append(eng.submit(prompt(9, 80), SamplingParams(
                 max_tokens=5, stop_token_ids=(9999,))))
             eng.step()
@@ -235,7 +235,9 @@ def test_counters_say_how_often_the_branches_engaged(tiny_model):
     assert eng.stats["decode_steps_sampled"] == 0
     assert eng.stats["decode_steps_topk"] == 0
     # a sampled request of 4 tokens (3 decode steps) beside a greedy one
-    # of 9: the batch holds a sampled row for 3 of its 8 steps
+    # of 9: the batch holds a sampled row for 3 of its 8 steps, and the
+    # step dispatched ahead as that request ended drew for its row too
+    # (the row is dropped as it is read: ``decode_rows_dropped``)
     eng = make_engine(tiny_model, max_slots=2)
     eng.generate([prompt(9, 0)], SamplingParams(max_tokens=1))   # no decode
     a = eng.submit(prompt(9, 80), SamplingParams(max_tokens=9))
@@ -244,7 +246,8 @@ def test_counters_say_how_often_the_branches_engaged(tiny_model):
     run(eng, [a, b])
     assert (len(a.output), len(b.output)) == (9, 4)
     assert eng.stats["decode_steps"] == 8
-    assert eng.stats["decode_steps_sampled"] == 3
+    assert eng.stats["decode_steps_sampled"] == 3 + 1
+    assert eng.stats["decode_rows_dropped"] == 1
     assert eng.stats["decode_steps_topk"] == 0
     # top-k on a GREEDY row is counted as asked (the program's sort
     # stands behind the draw's conditional and does not run)

@@ -202,6 +202,11 @@ def test_a_pass_runs_ahead_only_where_no_request_can_end_within_it(full):
                 assert all(len(r.output) < 12 for r in reqs)
     assert any(flying) == full
     assert eng._in_flight is None
+    # a block engine keeps the rule whole (PR 60 made the step ahead
+    # speculative a row for one-token-a-step engines alone): with a slot
+    # free no pass is dispatched ahead, and no pass's row is ever dropped
+    assert eng.stats["decode_steps_ahead"] == sum(flying)
+    assert eng.stats["decode_rows_dropped"] == 0
     for prompt, req in zip(prompts, reqs):
         assert (req.output, req.unmasked_at) == want_of(cfg, params,
                                                         prompt, 12)
