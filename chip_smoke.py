@@ -281,7 +281,7 @@ def kernels_phase(rep: Report, sz: dict) -> None:
     from ray_tpu.ops.attention import (_stays_resident, flash_attention,
                                        packed_attention, reference_attention)
     from ray_tpu.ops.paged_attention import (
-        default_impl, paged_decode_attention_pallas,
+        default_impl, packed_row, paged_decode_attention_pallas,
         paged_decode_attention_reference)
 
     interpret = pallas_interpret()
@@ -308,8 +308,9 @@ def kernels_phase(rep: Report, sz: dict) -> None:
         shape = f"H{H}/Hkv{Hkv}/D{D}"
         maxb = S // bs
         nb = B * maxb + 1
-        args = (normal(B, H, D), normal(nb, bs, Hkv, D),
-                normal(nb, bs, Hkv, D),
+        # the pool as a model holds it: heads of 64 two to a 128-lane row
+        row = packed_row(Hkv, D)
+        args = (normal(B, H, D), normal(nb, bs, *row), normal(nb, bs, *row),
                 jnp.asarray(rng.permutation(nb - 1)[:B * maxb]
                             .reshape(B, maxb), jnp.int32),
                 jnp.asarray(rng.integers(1, S + 1, B), jnp.int32))

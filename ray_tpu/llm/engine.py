@@ -502,9 +502,8 @@ class ContinuousBatchingEngine:
         self.error: Optional[BaseException] = None   # set once, by run_forever
 
         # jitted programs ------------------------------------------------
-        if self.recurrent:      # the state update's kernel or its twin
-            self.decode_attention_impl += (
-                f"+ssm_{self.decode_attention_impl}")
+        if self.recurrent:      # what advances the state, by the model
+            self.decode_attention_impl += "+" + model.state_update_impl()
         # An expert model's FFN reports, each decode step, the rows it
         # handed to each expert: summed ON THE DEVICE by the decode
         # program itself and read only when ``stats`` is asked for, so
@@ -713,6 +712,9 @@ class ContinuousBatchingEngine:
                       "kv_row_bytes": sum(
                           math.prod(row) for row in model.kv_row_shapes()
                       ) * jnp.dtype(model.kv_dtype).itemsize,
+                      # K/V heads a row of the pool holds (2 where heads
+                      # of 64 lanes lie packed; 1 in every other pool)
+                      "kv_lane_pack": getattr(model, "kv_lane_pack", 1),
                       "kv_pool_bytes": sum(
                           math.prod(a.shape) * a.dtype.itemsize
                           for name, a in self.kv.items()

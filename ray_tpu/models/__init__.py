@@ -11,6 +11,7 @@ GPT-2, MLP (Fashion-MNIST baseline), ViT (ImageNet streaming).
 
 from ray_tpu.models.gpt2 import GPT2Config, GPT2Model
 from ray_tpu.models.jamba import JambaConfig, JambaModel
+from ray_tpu.models.lfm2 import Lfm2Config, Lfm2Model
 from ray_tpu.models.llama import LlamaConfig, LlamaModel
 from ray_tpu.models.mla import MLAConfig, MLAModel
 from ray_tpu.models.mlp import MLPConfig, MLPModel
@@ -21,11 +22,12 @@ from ray_tpu.models.vit import ViTConfig, ViTModel
 __all__ = ["LlamaConfig", "LlamaModel", "MLPConfig", "MLPModel",
            "GPT2Config", "GPT2Model", "ViTConfig", "ViTModel",
            "MoEConfig", "MoEModel", "MLAConfig", "MLAModel", "NemotronHConfig",
-           "NemotronHModel", "JambaConfig", "JambaModel", "model_for"]
+           "NemotronHModel", "JambaConfig", "JambaModel", "Lfm2Config",
+           "Lfm2Model", "model_for"]
 
 _MODEL_OF = {LlamaConfig: LlamaModel, MoEConfig: MoEModel,
              MLAConfig: MLAModel, NemotronHConfig: NemotronHModel,
-             JambaConfig: JambaModel,
+             JambaConfig: JambaModel, Lfm2Config: Lfm2Model,
              GPT2Config: GPT2Model, MLPConfig: MLPModel,
              ViTConfig: ViTModel}
 

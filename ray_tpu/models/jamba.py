@@ -48,6 +48,7 @@ from typing import Any, Dict, List, Optional, Tuple
 import jax
 import jax.numpy as jnp
 
+from ray_tpu.models.layer_runs import LayerRuns
 from ray_tpu.models.llama import LlamaConfig, LlamaModel, Params
 from ray_tpu.ops import ssm, ssm1
 from ray_tpu.ops.norms import rms_norm
@@ -121,10 +122,12 @@ class JambaConfig(LlamaConfig):
         return JambaConfig(**{**base, **kw})
 
 
-class JambaModel(LlamaModel):
+class JambaModel(LayerRuns, LlamaModel):
     """``LlamaModel``'s embedding, norms, SwiGLU, head and sampler around a
-    walk of the runs of like layers over two stacks (module docstring). No
-    mesh: the kinds' stacks and the state have no partitioning rule yet."""
+    walk of the runs of like layers over two stacks (module docstring;
+    ``LayerRuns`` has the runs' loop and the attention layers' side of
+    the serving programs). No mesh: the kinds' stacks and the state have
+    no partitioning rule yet."""
 
     # what ``serving_params`` casts to the compute dtype, either stack;
     # ``A_log``, ``D``, ``b_dt`` and every norm are used in float32 and
@@ -173,6 +176,12 @@ class JambaModel(LlamaModel):
     def init_state(self, rows: int) -> Params:
         return {name: jnp.zeros((self.cfg.mamba_layers, rows) + shape, dtype)
                 for name, (shape, dtype) in self.state_row_shapes().items()}
+
+    def state_update_impl(self) -> str:
+        """What advances the state in a decode step, for an engine's
+        ``decode_attention_impl``: the kernel or its twin, as the
+        attention's."""
+        return f"ssm_{self.paged_decode_impl()}"
 
     def state_heads(self, state: jax.Array) -> jax.Array:
         """``"ssm"`` rows ``[..., 1, N, inner]`` as ``[..., N, inner, 1]``:
@@ -292,17 +301,6 @@ class JambaModel(LlamaModel):
             out = jnp.einsum("bte,ed->btd", y, layer["w_out"].astype(dt_))
         return out, window, carried
 
-    def _attention_mixer(self, h, layer: Params, positions, attend):
-        """h [B, T, D] (normed) -> (out [B, T, D], ``attend``'s second
-        result: the calling program's store with this layer's rows in)."""
-        dt = self.cfg.dtype
-        with jax.named_scope("attention"):
-            q, k, v = self._qkv(h, layer, positions, None,
-                                lambda a, *names: a)
-        o, kv = attend(q, k, v)
-        with jax.named_scope("attention"):
-            return jnp.einsum("bshk,hkd->bsd", o, layer["wo"].astype(dt)), kv
-
     # -- the walk ----------------------------------------------------------------
     def _walk(self, params: Params, x, store, mamba_mixer, attn_mixer):
         """The runs of like layers over the two stacks (module docstring).
@@ -328,24 +326,7 @@ class JambaModel(LlamaModel):
                     return (x + down, store), None
             return body
 
-        carry = (x, store)
-        for kind, first, count in self.runs:
-            if count == 1:
-                carry, _ = body_of(kind)(carry, jnp.int32(first))
-            else:
-                carry, _ = jax.lax.scan(
-                    body_of(kind), carry,
-                    first + jnp.arange(count, dtype=jnp.int32))
-        return carry
-
-    @staticmethod
-    def _at(stack, j):
-        return jax.lax.dynamic_index_in_dim(stack, j, 0, keepdims=False)
-
-    @staticmethod
-    def _put(stack, j, value):
-        return jax.lax.dynamic_update_index_in_dim(
-            stack, value.astype(stack.dtype), j, 0)
+        return self._over_runs(body_of, (x, store))
 
     def _prefill_mamba(self, lengths):
         """``_walk``'s Mamba mixer for a prefill: the scan from the state
@@ -367,15 +348,6 @@ class JambaModel(LlamaModel):
                              ssm=self._put(store["ssm"], j, S[:, None]))
         return mixer
 
-    def _rows_attention(self, attend_rows, positions):
-        """``_walk``'s attention mixer for a prefill: ``attend_rows(q,
-        k_new, v_new, j, store) -> (o, store)``."""
-        def mixer(h, layer, j, store):
-            return self._attention_mixer(
-                h, layer, positions,
-                lambda q, k, v: attend_rows(q, k, v, j, store))
-        return mixer
-
     # -- training-style forward ----------------------------------------------------
     def _apply_with_extras(self, params: Params, tokens: jax.Array,
                            positions: Optional[jax.Array] = None):
@@ -386,24 +358,6 @@ class JambaModel(LlamaModel):
         return logits, None
 
     # -- the serving programs ----------------------------------------------------
-    def _kv_zeros(self, *leading: int) -> Params:
-        return {name: jnp.zeros((self.cfg.attn_layers,) + leading + row,
-                                self.kv_dtype)
-                for name, row in zip(("k", "v"), self.kv_row_shapes())}
-
-    def init_kv_cache(self, batch: int, max_seq: int) -> Params:
-        """Slot-major cache: k/v [La, B, S, Hkv, D] of the attention
-        layers and the recurrent state a row."""
-        return {**self._kv_zeros(batch, max_seq), **self.init_state(batch)}
-
-    def init_kv_pool(self, num_blocks: int, block_size: int,
-                     slots: int = 0) -> Params:
-        """The attention layers' block pool, k/v [La, num_blocks, bs,
-        Hkv, D], and with ``slots`` the recurrent state a SLOT beside it:
-        ONE tree, which the decode step takes and hands back whole."""
-        pool = self._kv_zeros(num_blocks, block_size)
-        return {**pool, **self.init_state(slots)} if slots else pool
-
     def forward_step(self, params: Params, tokens: jax.Array, cache: Params,
                      offsets: jax.Array,
                      lengths: Optional[jax.Array] = None
@@ -413,27 +367,10 @@ class JambaModel(LlamaModel):
         tokens of this call (None: all T), so padding behind a row's
         length neither advances ``S`` nor shifts the convolution's
         window. -> (logits [B, T, V], the cache after the call)."""
-        B, T = tokens.shape
-        S = cache["k"].shape[2] if "k" in cache else 0
-        q_pos = offsets[:, None] + jnp.arange(T)[None, :]
-        batch_idx = jnp.arange(B)[:, None]
-
-        def attend_rows(q, k_new, v_new, j, store):
-            with jax.named_scope("kv_update"):
-                k_all = self._at(store["k"], j).at[batch_idx, q_pos].set(
-                    k_new)
-                v_all = self._at(store["v"], j).at[batch_idx, q_pos].set(
-                    v_new)
-            with jax.named_scope("attention"):
-                o = self._attend_rows(q, k_all, v_all, None, q_pos,
-                                      jnp.arange(S))
-            return o, dict(store, k=self._put(store["k"], j, k_all),
-                           v=self._put(store["v"], j, v_all))
-
         x, cache = self._walk(
             params, self._embed(params, tokens), dict(cache),
             self._prefill_mamba(lengths),
-            self._rows_attention(attend_rows, q_pos))
+            self._slot_attention(cache, offsets, tokens.shape[1]))
         return self._head(params, x), cache
 
     def prefill_with_prefix(self, params: Params, tokens: jax.Array,
@@ -449,33 +386,14 @@ class JambaModel(LlamaModel):
         logits [N, V], the chunk's K/V rows and the state after its
         ``lengths`` tokens)."""
         N_, Tb = tokens.shape
-        Pmax = prefix_k.shape[2]
         if state is None:
             state = self.init_state(N_)
-        pos_q = prefix_len[:, None] + jnp.arange(Tb)[None, :]
-        far = jnp.int32(2 ** 30)
-        pos_prefix = jnp.where(
-            jnp.arange(Pmax)[None, :] < prefix_len[:, None],
-            jnp.arange(Pmax)[None, :], far)
-        pos_k = jnp.concatenate([pos_prefix, pos_q], axis=1)
-
-        def attend_rows(q, k_new, v_new, j, store):
-            with jax.named_scope("attention"):
-                o = self._attend_rows(
-                    q, jnp.concatenate([self._at(prefix_k, j).astype(
-                        k_new.dtype), k_new], axis=1),
-                    jnp.concatenate([self._at(prefix_v, j).astype(
-                        v_new.dtype), v_new], axis=1),
-                    None, pos_q, pos_k)
-            return o, dict(store, k=self._put(store["k"], j, k_new),
-                           v=self._put(store["v"], j, v_new))
-
         store = {**self._kv_zeros(N_, Tb),
                  **{name: state[name] for name in ("conv", "ssm")}}
         x, small = self._walk(
             params, self._embed(params, tokens), store,
             self._prefill_mamba(lengths),
-            self._rows_attention(attend_rows, pos_q))
+            self._prefix_attention(prefix_k, prefix_v, prefix_len, Tb))
         return self._head(params, x, last=lengths - 1)[:, 0], small
 
     def decode_step_paged_counted(self, params: Params, tokens: jax.Array,
@@ -495,19 +413,10 @@ class JambaModel(LlamaModel):
         -> (logits [B, V], the pool, None: the dense SwiGLU counts
         nothing).
         ``run``: ``LlamaModel.decode_step_paged``'s."""
-        cfg = self.cfg
         B = tokens.shape[0]
         impl = self.paged_decode_impl()
-        q_pos = offsets[:, None]
-        lengths = offsets + 1
-        store = {}
-        if "k" in pool:
-            La, NB, bs = pool["k"].shape[:3]
-            store["k"] = pool["k"].reshape((La * NB,) + pool["k"].shape[2:])
-            store["v"] = pool["v"].reshape((La * NB,) + pool["v"].shape[2:])
-            dest_block = jnp.take_along_axis(
-                block_tables, (offsets // bs)[:, None], axis=-1)[:, 0]
-            dest_off = offsets % bs
+        store, attn_mixer = self._paged_attention(pool, block_tables,
+                                                  offsets, run)
         if "ssm" in pool:
             if pool["ssm"].shape[1] != B:
                 raise ValueError(
@@ -531,28 +440,9 @@ class JambaModel(LlamaModel):
             return out, dict(store, ssm=stack,
                              conv=self._put(store["conv"], j, window))
 
-        def attn_mixer(h, layer, j, store):
-            def attend(q, k_new, v_new):
-                with jax.named_scope("kv_update"):
-                    k_all = store["k"].at[j * NB + dest_block, dest_off].set(
-                        k_new[:, 0])
-                    v_all = store["v"].at[j * NB + dest_block, dest_off].set(
-                        v_new[:, 0])
-                with jax.named_scope("attention"):
-                    o = self._attend_pages(
-                        q[:, 0], k_all, v_all, None, block_tables, lengths,
-                        impl=impl, starts=None, first_block=j * NB,
-                        num_blocks=NB, run=run)
-                return o[:, None], dict(store, k=k_all, v=v_all)
-
-            return self._attention_mixer(h, layer, q_pos, attend)
-
         x, store = self._walk(params, self._embed(params, tokens[:, None]),
                               store, mamba_mixer, attn_mixer)
-        pool = dict(pool)
-        if "k" in store:
-            pool["k"] = store["k"].reshape(pool["k"].shape)
-            pool["v"] = store["v"].reshape(pool["v"].shape)
+        pool = self._pages_back(pool, store)
         if "ssm" in store:
             pool["ssm"] = store["ssm"][:, :, None]
             pool["conv"] = store["conv"]

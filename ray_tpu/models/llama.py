@@ -69,6 +69,8 @@ from ray_tpu.ops.attention import (attention, reference_attention,
 from ray_tpu.ops.eva import (chunk_summaries, eva_attention,
                              merge_softmax_parts, visible_summaries)
 from ray_tpu.ops.norms import rms_norm
+from ray_tpu.ops.paged_attention import (pack_rows, packed_row,
+                                         unpack_rows)
 from ray_tpu.ops.ring_attention import ring_attention
 from ray_tpu.ops.rope import (YarnScaling, apply_rope, apply_rope_of_kind,
                               rope_frequencies, yarn_inv_freq)
@@ -615,6 +617,7 @@ class LlamaModel:
     def _attention(self, q, k, v, positions, window=None, layer=None):
         """The training program's attention over this call's own rows
         (``layer``: for a model whose rows need its weights to be read)."""
+        k, v = (unpack_rows(a, self.cfg.head_dim) for a in (k, v))
         if window is not None:
             # a layer of a model with kinds (training and the tests'
             # oracle; no cell trains one): the masked reference
@@ -720,14 +723,19 @@ class LlamaModel:
         q = pin(q, "batch", "seq", "heads", None)
         q = self._rope(q, positions, kind)
         k = self._rope(k, positions, kind)
+        if self.kv_lane_pack > 1:       # narrow heads: the cache's rows
+            k, v = pack_rows(k), pack_rows(v)
         return q, k, v
 
     def _attend_rows(self, q, k, v, layer: Params, positions_q, positions_k,
                      window=None):
         """A prefill's attention of q [B, T, H, hd] over DENSE rows k, v
         [B, S, ...] (a slot cache, or a gathered prefix and the call's
-        own rows) at ``positions_k``: -> o [B, T, H, hd]."""
-        return reference_attention(q, k, v, positions_q=positions_q,
+        own rows) at ``positions_k``: -> o [B, T, H, hd]. (Rows of narrow
+        heads, packed, are viewed back as heads.)"""
+        hd = self.cfg.head_dim
+        return reference_attention(q, unpack_rows(k, hd), unpack_rows(v, hd),
+                                   positions_q=positions_q,
                                    positions_k=positions_k, window=window)
 
     def _attend_pages(self, q, k_pool, v_pool, layer: Params, block_tables,
@@ -945,12 +953,36 @@ class LlamaModel:
         return carry, ys
 
     # -- KV-cache inference path (serving; BASELINE.md config 5) ----------
+    @property
+    def kv_lane_pack(self) -> int:
+        """K/V heads ONE row of the cache holds: as many as fill the
+        paged kernel's 128 lanes (``ops.paged_attention.packed_row``: 2
+        at ``head_dim`` 64; 1 where no number of them fills the lanes:
+        the kernel does not lower there), so that a pool of
+        narrow heads lies in the rows the kernel reads and neither pads
+        its lanes in HBM nor is re-tiled a layer a step. ``_qkv`` hands
+        every program such rows (the same numbers in the same order: a
+        reshape), the programs move them whatever they hold, and
+        ``_attend_rows`` / ``_attention`` view them back as heads. 1 at 128 lanes and more,
+        under a mesh (the kernel does not run there and the heads' axis
+        is sharded), for an EVA model (its two parts are read as heads
+        throughout) and for a model whose rows are its own
+        (``MLAModel``)."""
+        if (self.mesh is not None or self.eva is not None
+                or type(self)._attend_pages is not LlamaModel._attend_pages):
+            return 1
+        cfg = self.cfg
+        return cfg.n_kv_heads // packed_row(cfg.n_kv_heads, cfg.head_dim)[0]
+
     def kv_row_shapes(self) -> Tuple[Tuple[int, ...], Tuple[int, ...]]:
         """One position's row in each of the cache's two parts, ``"k"``
         and ``"v"``: what follows ``[L, B, S]`` in a slot cache and
-        ``[L, NB, bs]`` in a pool. The engine and the harness move rows by
+        ``[L, NB, bs]`` in a pool: ``(Hkv, D)``, or narrow heads packed
+        ``kv_lane_pack`` to a row. The engine and the harness move rows by
         these names whatever they hold (``llm/engine.py:_insert_impl``)."""
-        row = (self.cfg.n_kv_heads, self.cfg.head_dim)
+        cfg = self.cfg
+        row = (cfg.n_kv_heads // self.kv_lane_pack,
+               self.kv_lane_pack * cfg.head_dim)
         return row, row
 
     @property

@@ -86,6 +86,9 @@ class MoEConfig(LlamaConfig):
     router_kind: str = "softmax"
     routed_scaling_factor: float = 1.0
     router_bias_init_std: float = 0.0
+    # what the sigmoid router adds to the chosen scores' sum before it
+    # divides by it (``norm_topk_prob``): the family's (LFM2: 1e-6)
+    router_renorm_eps: float = 1e-20
     # the sigmoid router's GROUP LIMIT (``n_group``, ``topk_group``): the
     # experts are ``router_n_group`` groups of neighbours, scored by the
     # sum of their two best; the top-k is taken in the
@@ -375,7 +378,8 @@ class MoEModel(LlamaModel):
         rows_live = None if live is None else jnp.repeat(live, T)
         if cfg.router_kind == "sigmoid":
             shared.update(sigmoid_bias=layer["router_bias"],
-                          weight_scale=cfg.routed_scaling_factor)
+                          weight_scale=cfg.routed_scaling_factor,
+                          renorm_eps=cfg.router_renorm_eps)
         if cfg.router_n_group > 1:
             shared.update(groups=(cfg.router_n_group, cfg.router_topk_group))
         if cfg.experts_held is not None:

@@ -172,6 +172,12 @@ class NemotronHModel(LlamaModel):
         return {name: jnp.zeros((self.cfg.count("M"), rows) + shape, dtype)
                 for name, (shape, dtype) in self.state_row_shapes().items()}
 
+    def state_update_impl(self) -> str:
+        """What advances the state in a decode step, for an engine's
+        ``decode_attention_impl``: the kernel or its twin, as the
+        attention's."""
+        return f"ssm_{self.paged_decode_impl()}"
+
     def state_heads(self, state: jax.Array) -> jax.Array:
         """``"ssm"`` rows as ``[..., H, P, N]``, a head's ``S`` as the
         equations write it."""

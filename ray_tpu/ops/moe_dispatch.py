@@ -70,7 +70,7 @@ logger = logging.getLogger(__name__)
 
 def route_topk(xf, router, top_k: int, norm_topk_prob: bool,
                precision=None, sigmoid_bias=None, weight_scale: float = 1.0,
-               groups=(1, 1)):
+               groups=(1, 1), renorm_eps: float = 1e-20):
     """Router in float32: ``(logits [T, E], probs [T, E], weights [T, K],
     experts [T, K])``. The top-k softmax weights are used as they are
     unless ``norm_topk_prob`` (then they sum to 1).
@@ -79,7 +79,8 @@ def route_topk(xf, router, top_k: int, norm_topk_prob: bool,
     one group): scores ``s = sigmoid(logits)``; the experts are the
     top-k of ``s + bias``, a selection bias that is no part of the
     weights; the weights are ``s`` of the chosen, divided by their sum
-    (+ 1e-20) under ``norm_topk_prob``, times ``weight_scale``
+    (+ ``renorm_eps``: the family's, 1e-20 or LFM2's 1e-6) under
+    ``norm_topk_prob``, times ``weight_scale``
     (``routed_scaling_factor``). ``probs`` is then ``s``.
 
     ``groups`` = (``n_group``, ``topk_group``), the sigmoid form's GROUP
@@ -107,7 +108,7 @@ def route_topk(xf, router, top_k: int, norm_topk_prob: bool,
         gate_vals = jnp.take_along_axis(scores, gate_idx, axis=-1)
         if norm_topk_prob:
             gate_vals = gate_vals / (
-                jnp.sum(gate_vals, -1, keepdims=True) + 1e-20)
+                jnp.sum(gate_vals, -1, keepdims=True) + renorm_eps)
         return logits, scores, gate_vals * weight_scale, gate_idx
     probs = jax.nn.softmax(logits, axis=-1)
     gate_vals, gate_idx = jax.lax.top_k(probs, top_k)
@@ -406,7 +407,8 @@ def dropless_expert_ffn(x, router, e_gate, e_up, e_down, *, top_k: int,
                         first_expert=None,
                         z_coef: float = 0.0, lb_coef: float = 0.0,
                         sigmoid_bias=None, weight_scale: float = 1.0,
-                        groups=(1, 1), held=None, expert_input=None):
+                        groups=(1, 1), held=None, expert_input=None,
+                        renorm_eps: float = 1e-20):
     """x [T, D] -> ``(out [T, D] in ``dtype``, load [E] int32, experts
     [T, K] int32, aux)``.
 
@@ -421,8 +423,8 @@ def dropless_expert_ffn(x, router, e_gate, e_up, e_down, *, top_k: int,
     the tokens ``live`` [T] bool marks (all, if ``None``): it sums to
     ``live.sum() * top_k`` because nothing is dropped. ``aux`` is the
     training loss of ``router_aux_loss``. ``sigmoid_bias``,
-    ``weight_scale`` and ``groups`` are ``route_topk``'s: the sigmoid
-    router.
+    ``weight_scale``, ``groups`` and ``renorm_eps`` are ``route_topk``'s:
+    the sigmoid router.
 
     With ``held`` = (first, H) the layer HOLDS A SHARE of the router's E
     experts, ``first ... first + H``, and the weights are those H alone
@@ -449,7 +451,7 @@ def dropless_expert_ffn(x, router, e_gate, e_up, e_down, *, top_k: int,
         logits, probs, weights, experts = route_topk(
             x, router, top_k, norm_topk_prob,
             precision=jax.lax.Precision.HIGHEST, sigmoid_bias=sigmoid_bias,
-            weight_scale=weight_scale, groups=groups)
+            weight_scale=weight_scale, groups=groups, renorm_eps=renorm_eps)
         aux = router_aux_loss(logits, probs, z_coef, lb_coef)
     with jax.named_scope("moe_dispatch"):
         flat = by = experts.reshape(T * top_k)

@@ -30,13 +30,22 @@ TPU-native design:
   already is in HBM (rows are (token, kv head) pairs), one 2-D dot
   scores all H query heads against a chunk's rows, an additive bias
   drops the rows of the other KV heads, and one more dot weighs V.
-- Heads narrower than the 128 lanes (D 64: llama3_1b, gpt2) are read
-  ``128 // D`` KV heads to a row, the same bytes viewed as
-  ``[bs*Hkv*D/128, 128]``: q sits in its own head's lanes of a zero
-  row, so the same dot scores it against that head alone, and the
-  wrapper takes each q head's own lanes of the output.
+- Heads narrower than the 128 lanes (D 64: llama3_1b, LFM2) are read
+  ``128 // D`` KV heads to a row: q sits in its own head's lanes of a
+  zero row, so the same dot scores it against that head alone, and the
+  wrapper takes each q head's own lanes of the output. Such a pool LIES
+  in those rows, ``[NB, bs, Hkv/pack, pack*D]`` (``packed_row``: what a
+  model's ``kv_row_shapes`` gives its cache; ``pack_rows`` /
+  ``unpack_rows`` are the two views of one position's K or V, a reshape
+  of its ``Hkv*D`` numbers either way), so the kernel's page view is as
+  free as at 128 lanes. (Laid ``[.., Hkv, 64]`` the rows sit padded to
+  the lanes in HBM, twice their bytes, and the packed view is a copy of
+  a layer's whole window a layer a step: PERF.md, PR 25 and PR 61.)
 
-Shapes: q [B, H, D]; k_pool/v_pool [NB, bs, Hkv, D];
+Shapes: q [B, H, D]; k_pool/v_pool [NB, bs, Hkv/pack, pack*D]
+(``packed_row``: [NB, bs, Hkv, D] wherever ``pack`` is 1; the kernel
+refuses narrow heads laid otherwise, ``pool_heads``; the XLA reference
+views any rows back as heads);
 block_tables [B, MAXB] int32 (physical ids; entries past a slot's
 length are ignored); lengths [B] int32. The pools may be a STACK of
 windows of NB blocks (every layer's, ``[L*NB, bs, Hkv, D]``): the tables
@@ -62,8 +71,7 @@ from __future__ import annotations
 
 import functools
 import logging
-import math
-from typing import Optional
+from typing import Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -111,13 +119,14 @@ def paged_decode_attention_reference(q, k_pool, v_pool, block_tables,
                                      stats: bool = False):
     """XLA fallback: gather the slot's blocks into a dense view, then
     run the masked ragged reference. One extra HBM round-trip of the
-    active context vs the Pallas path — correct everywhere, slower."""
+    active context vs the Pallas path — correct everywhere, slower.
+    The rows are viewed back as heads, after the gather."""
     B, maxb = block_tables.shape
     bs = k_pool.shape[1]
     k = k_pool[block_tables]                     # [B, MAXB, bs, Hkv, D]
     v = v_pool[block_tables]
-    k = k.reshape(B, maxb * bs, *k.shape[3:])
-    v = v.reshape(B, maxb * bs, *v.shape[3:])
+    k = unpack_rows(k.reshape(B, maxb * bs, *k.shape[3:]), q.shape[-1])
+    v = unpack_rows(v.reshape(B, maxb * bs, *v.shape[3:]), q.shape[-1])
     return ragged_decode_attention_reference(q, k, v, lengths, starts=starts,
                                              scale=scale, stats=stats)
 
@@ -284,10 +293,47 @@ RUN_BYTES = 64 * 1024
 
 
 def _lane_pack(head_dim: int, kv_heads: int) -> int:
-    """KV heads the kernel reads as one row: as many as fill the 128
-    lanes, where the head count divides so."""
-    return math.gcd(LANES // head_dim, kv_heads) if LANES % head_dim == 0 \
-        else 1
+    """KV heads ONE row of a pool holds: as many as fill the 128 lanes,
+    where the head count divides so; 1 where no number of them fills a
+    row (a row is then a head, and the kernel does not lower:
+    ``kernel_lowers``)."""
+    pack = LANES // head_dim if LANES % head_dim == 0 else 1
+    return pack if kv_heads % pack == 0 else 1
+
+
+def packed_row(kv_heads: int, head_dim: int) -> Tuple[int, int]:
+    """One position's K (or V) as a pool holds it: ``(Hkv/pack,
+    pack*D)``, which is ``(Hkv, D)`` for every width that packs 1."""
+    pack = _lane_pack(head_dim, kv_heads)
+    return kv_heads // pack, pack * head_dim
+
+
+def pack_rows(x):
+    """Heads x [..., Hkv, D] as the rows a pool holds, [..., Hkv/pack,
+    pack*D]: the same numbers in the same order."""
+    return x.reshape(x.shape[:-2] + packed_row(*x.shape[-2:]))
+
+
+def unpack_rows(x, head_dim: int):
+    """A pool's rows [..., Hkv/pack, pack*D] viewed back as heads [...,
+    Hkv, D] (rows that pack 1 are that already)."""
+    return x.reshape(x.shape[:-2] + (-1, head_dim))
+
+
+def pool_heads(pool, head_dim: int) -> int:
+    """The K/V heads of a pool ``[.., rows, lanes]`` laid as ``packed_row``
+    lays it. THE KERNEL READS THAT ONE LAYOUT: narrow heads handed over as
+    ``[.., Hkv, D]`` sit padded to the lanes in HBM and would have to be
+    copied into rows, the whole stack a layer a step, so they are refused
+    (``pack_rows`` the pool once, where it is built)."""
+    rows, lanes = pool.shape[-2:]
+    kv_heads = rows * lanes // head_dim
+    if lanes % head_dim or (rows, lanes) != packed_row(kv_heads, head_dim):
+        raise ValueError(
+            f"a pool of {kv_heads} K/V heads of {head_dim} lies in rows of "
+            f"{packed_row(kv_heads, head_dim)}, got {(rows, lanes)}: lay it "
+            f"by ops.paged_attention.pack_rows / packed_row")
+    return kv_heads
 
 
 def run_blocks(block_size: int, kv_heads: int, head_dim: int,
@@ -312,11 +358,9 @@ def kernel_lowers(head_dim: int, kv_heads: int) -> bool:
     return _lane_pack(head_dim, kv_heads) * head_dim % LANES == 0
 
 
-@functools.partial(jax.jit, static_argnames=("num_blocks", "scale",
-                                             "interpret", "stats"))
+@functools.partial(jax.jit, static_argnames=("scale", "interpret", "stats"))
 def paged_decode_attention_pallas(q, k_pool, v_pool, block_tables,
                                   lengths, starts=None, *, first_block=0,
-                                  num_blocks: Optional[int] = None,
                                   scale: Optional[float] = None,
                                   interpret: bool = False,
                                   stats: bool = False):
@@ -324,19 +368,12 @@ def paged_decode_attention_pallas(q, k_pool, v_pool, block_tables,
     from jax.experimental.pallas import tpu as pltpu
 
     B, H, D = q.shape
-    _, bs, Hkv, _ = k_pool.shape
-    pack = _lane_pack(D, Hkv)                    # KV heads read as one row
-    block_tables = block_tables.astype(jnp.int32)
-    if num_blocks is None or pack == 1:
-        # a page's view below is free: the kernel reads the window's
-        # pages out of the pools where they lie
-        block_tables = block_tables + first_block
-    else:
-        # packed rows are a copy of what is viewed (below): of the
-        # window alone, not of a stack of L windows once a layer
-        k_pool, v_pool = (jax.lax.dynamic_slice_in_dim(
-            pool, first_block, num_blocks) for pool in (k_pool, v_pool))
-    NB = k_pool.shape[0]
+    NB, bs, row_heads, lanes = k_pool.shape
+    Hkv = pool_heads(k_pool, D)
+    pack = lanes // D                            # KV heads read as one row
+    # a page's view below is free: the kernel reads the window's pages
+    # out of the pools where they lie
+    block_tables = block_tables.astype(jnp.int32) + first_block
     maxb = block_tables.shape[1]
     if not (interpret or kernel_lowers(D, Hkv)):
         raise ValueError(
@@ -344,7 +381,6 @@ def paged_decode_attention_pallas(q, k_pool, v_pool, block_tables,
             f"head_dim {D} x {Hkv} KV heads does not fill them; use the "
             f"XLA reference (impl='xla')")
     scale = scale if scale is not None else D ** -0.5
-    row_heads, lanes = Hkv // pack, pack * D     # a row: ``pack`` KV heads
     kv_head = jnp.arange(H) // (H // Hkv)        # of each q head
     if pack > 1:
         # q head h in the lanes of its KV head's place in the row, zeros
@@ -388,13 +424,11 @@ def paged_decode_attention_pallas(q, k_pool, v_pool, block_tables,
             pltpu.VMEM((H, lanes), jnp.float32),
         ],
     )
-    # A pool block as one 2-D [bs*Hkv, D] tile, rows (token, kv head):
-    # at D % 128 == 0 the pool's own order in HBM under XLA's tiling of
-    # its two minor dims, so this view is free. ([bs, Hkv*D] is NOT: XLA
-    # copies the whole pool to build it, 0.6 ms a layer at 201 MB;
-    # PERF.md, PR 25.) A narrower D sits padded to the lanes in HBM, and
-    # XLA re-tiles the pool (the window, above) into the packed rows,
-    # one copy a layer.
+    # A pool block as one 2-D [bs*rows, lanes] tile, rows (token, kv
+    # head or group of packed kv heads): at lanes % 128 == 0 the pool's
+    # own order in HBM under XLA's tiling of its two minor dims, so this
+    # view is free. ([bs, Hkv*D] is NOT: XLA copies the whole pool to
+    # build it, 0.6 ms a layer at 201 MB; PERF.md, PR 25.)
     out = pl.pallas_call(
         functools.partial(_paged_kernel, block_size=bs, pages=pages,
                           max_blocks=maxb, scale=scale, row_heads=row_heads,
@@ -449,8 +483,8 @@ def paged_decode_attention(q, k_pool, v_pool, block_tables, lengths, *,
     ``[first_block, first_block + num_blocks)``, one layer's of the
     stack ``[L*NB, bs, Hkv, D]`` that ``decode_step_paged`` carries.
     Both sides read it through ``first_block + block_tables`` with no
-    slice of the stack built; only the kernel's packed rows (D < 128),
-    a copy in any case, copy the window alone. ``starts`` [B]: a
+    slice of the stack built, at every head width: narrow heads lie
+    packed in the pool (the module's docstring). ``starts`` [B]: a
     sliding-window layer's first visible positions (the module's
     docstring). ``stats``: ``(o, m, l)`` in float32 instead of ``o``,
     the softmax's output with its running max and sum [B, H]: ONE PART
@@ -483,7 +517,7 @@ def paged_decode_attention(q, k_pool, v_pool, block_tables, lengths, *,
     if impl == "pallas":
         return paged_decode_attention_pallas(
             q, k_pool, v_pool, block_tables, lengths, starts,
-            first_block=first_block, num_blocks=num_blocks, scale=scale,
+            first_block=first_block, scale=scale,
             interpret=pallas_interpret(), stats=stats)
     if impl != "xla":
         raise ValueError(f"impl must be 'xla' or 'pallas', got {impl!r}")

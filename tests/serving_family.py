@@ -149,6 +149,7 @@ class Family:
     state_impls: Tuple[Optional[str], ...] = (None,)
     state_f32_tol: float = 1e-4
     state_bf16_tol: float = 0.04
+    state_layers: int = 3              # of the debug model
     routed: bool = False
     runs: Dict[str, Any] = _dict()
     state_stats: Callable[..., None] = _no_hook
@@ -794,12 +795,13 @@ def state_continuous_batching(family):
         for p, n, req in zip(prompts, outs, reqs):
             assert req.output == alone(family, model, params, p, n), len(p)
         stats = eng.stats
-        assert stats["state_rows_written"] == 5 and stats["state_layers"] == 3
+        assert stats["state_rows_written"] == 5
+        assert stats["state_layers"] == family.state_layers
         # the 42-token prompt: chunks of 16, 16 and 10; two started from a
         # state
         assert stats["state_chunks_carried"] == 2
         assert stats["state_bytes"] == 3 * stats["state_row_bytes"] == sum(
-            eng.kv[n].nbytes for n in ("conv", "ssm"))
+            eng.kv[n].nbytes for n in model.state_row_shapes())
         assert stats["kv_pool_bytes"] == (eng.kv["k"].nbytes
                                           + eng.kv["v"].nbytes)
         assert stats["prefix_hits_refused_recurrent"] == 0
@@ -1044,5 +1046,28 @@ def _nemotron_h():
                   stacks=("mamba", "attn", "moe"), engine_kw=STATE_ENGINE)
 
 
-DEEPSEEK_V32, SDAR, JAMBA, NEMOTRON_H = (
-    _deepseek_v32(), _sdar(), _jamba(), _nemotron_h())
+def _lfm2():
+    from benchmark.builders import lfm2 as builder
+    from benchmark.reference import lfm2 as reference
+    from ray_tpu.models import Lfm2Config
+
+    def config(dtype, pattern="ccacca", **kw):
+        return Lfm2Config.debug(pattern, dtype=dtype, **kw)
+
+    def ref_forward(cfg, params, tokens, **kw):
+        return reference.forward(
+            builder.reference_params({}, params), tokens,
+            layer_types=cfg.mixer_types,
+            num_dense_layers=cfg.num_dense_layers, num_heads=cfg.n_heads,
+            num_kv_heads=cfg.n_kv_heads, head_dim=cfg.head_dim,
+            top_k=cfg.expert_top_k, norm_topk_prob=cfg.norm_topk_prob,
+            routed_scaling_factor=cfg.routed_scaling_factor,
+            rope_theta=cfg.rope_theta, eps=cfg.norm_eps, **kw)
+
+    return Family(config=config, reference=ref_forward, routed=True,
+                  stacks=("conv_dense", "conv_moe", "attn_moe"),
+                  state_layers=4, engine_kw=STATE_ENGINE)
+
+
+DEEPSEEK_V32, SDAR, JAMBA, NEMOTRON_H, LFM2 = (
+    _deepseek_v32(), _sdar(), _jamba(), _nemotron_h(), _lfm2())

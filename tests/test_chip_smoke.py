@@ -196,11 +196,13 @@ def _sampling(v5e, B):
 
 
 def test_paged_decode_kernel_lowers_for_v5e(v5e, smoke_sizes):
-    from ray_tpu.ops.paged_attention import paged_decode_attention_pallas
+    """(The pools laid as a model lays them: heads of 64 two to a row.)"""
+    from ray_tpu.ops.paged_attention import (packed_row,
+                                             paged_decode_attention_pallas)
 
     bs, maxb = 32, smoke_sizes["kernel_seq"] // 32
     for B, H, Hkv, D in smoke_sizes["kernel_shapes"]:
-        pool = v5e(B * maxb + 1, bs, Hkv, D)
+        pool = v5e(B * maxb + 1, bs, *packed_row(Hkv, D))
         assert _mosaic(paged_decode_attention_pallas.lower(
             v5e(B, H, D), pool, pool, v5e(B, maxb, dtype=jnp.int32),
             v5e(B, dtype=jnp.int32), interpret=False))
@@ -216,10 +218,11 @@ def test_paged_decode_kernel_lowers_at_other_blocks_and_widths(v5e, shape):
     """The kernel's VMEM is bounded by rows, not pages: it lowers (the
     compiler refuses a kernel over its scoped VMEM) whatever block_size
     the engine is built with."""
-    from ray_tpu.ops.paged_attention import paged_decode_attention_pallas
+    from ray_tpu.ops.paged_attention import (packed_row,
+                                             paged_decode_attention_pallas)
 
     B, H, Hkv, D, bs, maxb = shape
-    pool = v5e(B * maxb + 1, bs, Hkv, D)
+    pool = v5e(B * maxb + 1, bs, *packed_row(Hkv, D))
     assert _mosaic(paged_decode_attention_pallas.lower(
         v5e(B, H, D), pool, pool, v5e(B, maxb, dtype=jnp.int32),
         v5e(B, dtype=jnp.int32), interpret=False))
@@ -333,6 +336,48 @@ def test_llama3_1b_decode_program_holds_the_kernel(v5e, placed, monkeypatch,
         placed(_engine_params(model)), v5e(B, dtype=jnp.int32),
         placed(jax.eval_shape(lambda: model.init_kv_pool(B * maxb + 1, bs))),
         v5e(B, maxb, dtype=jnp.int32), v5e(B, dtype=jnp.int32)))
+
+
+def test_a_pool_of_64_lane_heads_is_read_where_it_lies(v5e, placed,
+                                                       monkeypatch,
+                                                       smoke_sizes):
+    """llama3_1b's heads (32 / 8 of 64) at two layers, 32 slots x 2,048
+    positions: the pool lies two K/V heads to a 128-lane row (``[2, NB,
+    32, 4, 128]``: its bytes are its rows', nothing padded to the lanes),
+    the decode program reads a layer's pages where they lie, and its
+    temporaries stay under ONE layer's window of K. Laid ``[.., 8, 64]``
+    the program copied a layer's window of K and of V into packed rows a
+    layer a step (the kernel's own comment before PR 61; PERF.md, PR 25:
+    75 us of kernel beside 489 us of copies)."""
+    from ray_tpu.models.llama import LlamaModel
+
+    _as_on_the_chip(monkeypatch)
+    cfg = dataclasses.replace(smoke_sizes["serve_cfg"], n_layers=2,
+                              max_seq_len=2048)
+    model = LlamaModel(cfg)
+    assert model.paged_decode_impl() == "pallas"
+    assert model.kv_lane_pack == 2
+    B, bs, maxb = 32, 32, 2048 // 32
+    run = model.paged_run_blocks(bs)
+    assert run == 2
+    NB = _whole_runs(B * maxb + 1, run)
+    pool = placed(jax.eval_shape(lambda: model.init_kv_pool(NB, bs)))
+    assert pool["k"].shape == (2, NB, bs, 4, 128)
+    window = NB * bs * 8 * 64 * 2                  # one layer's K, bf16
+    params = placed(_engine_params(model))
+    compiled = _engine_decode(model, B * maxb, run).lower(
+        params, v5e(B, dtype=jnp.int32), pool,
+        v5e(B, maxb, dtype=jnp.int32), v5e(B, dtype=jnp.int32),
+        *_sampling(v5e, B), None).compile()
+    mem = compiled.memory_analysis()
+    assert "tpu_custom_call" in compiled.as_text()
+    assert mem.temp_size_in_bytes < window, mem.temp_size_in_bytes / 2**20
+    held = sum(a.size * a.dtype.itemsize
+               for a in jax.tree.leaves((params, pool)))
+    assert 4 * window == sum(a.size * 2 for a in jax.tree.leaves(pool))
+    # the arguments are the weights and the pool's own bytes (and a few
+    # vectors): a pool padded to the lanes would be twice its rows
+    assert mem.argument_size_in_bytes < held + 2**20
 
 
 def _holds_the_tiled_grouped_matmuls(text, model, slots):

@@ -16,6 +16,7 @@ from ray_tpu.llm import ContinuousBatchingEngine, SamplingParams
 from ray_tpu.llm.paged_cache import (BlockPool, allocate_slot,
                                      ensure_capacity, seal_prompt_blocks)
 from ray_tpu.models.llama import LlamaConfig, LlamaModel
+from ray_tpu.ops.paged_attention import pack_rows
 from tests.program_readers import layer_scan_operands
 
 
@@ -507,8 +508,9 @@ def _engine_like_case(name):
     if name == "idle_slot":
         tables[[0, 2]] = scratch                    # the whole row
     q = jnp.asarray(rng.normal(size=(B, Hkv * G, D)), dtype)
-    kp = jnp.asarray(rng.normal(size=(scratch + 1, bs, Hkv, D)), dtype)
-    vp = jnp.asarray(rng.normal(size=(scratch + 1, bs, Hkv, D)), dtype)
+    # (the pools laid as a model lays them: four heads of 32 a row)
+    kp, vp = (pack_rows(jnp.asarray(
+        rng.normal(size=(scratch + 1, bs, Hkv, D)), dtype)) for _ in "kv")
     return (q, kp, vp, jnp.asarray(tables, jnp.int32),
             jnp.asarray(lengths, jnp.int32)), tol
 
@@ -521,9 +523,10 @@ def test_paged_kernel_on_engine_inputs(case):
         CHUNK_ROWS, _lane_pack, paged_decode_attention_pallas,
         paged_decode_attention_reference)
     args, tol = _engine_like_case(case)
-    _, bs, Hkv, D = args[1].shape
+    (_, _, D), (_, bs, page_heads, lanes) = args[0].shape, args[1].shape
+    Hkv = page_heads * lanes // D
     assert _lane_pack(D, Hkv) == (1 if case == "one_head_a_row" else 4)
-    page_rows = bs * Hkv // _lane_pack(D, Hkv)
+    page_rows = bs * page_heads
     assert args[3].shape[1] * page_rows > 2 * CHUNK_ROWS   # three chunks
     ref = paged_decode_attention_reference(*args)
     out = paged_decode_attention_pallas(*args, interpret=True)
@@ -559,6 +562,115 @@ def test_paged_attention_reads_a_window_of_a_stack(case):
         assert float(jnp.max(jnp.abs(other - alone))) > 0.5
 
 
+@pytest.mark.parametrize("run", [1, 2])
+def test_heads_of_64_read_from_a_pool_laid_in_packed_rows(run):
+    """Eight K/V heads of 64 lanes (llama3_1b's, LFM2's): the pool lies
+    ``[.., 4, 128]``, two heads a row, which is what ``pack_rows`` makes
+    of ``[.., 8, 64]`` (the same numbers in the same order). Kernel
+    (interpreted) and reference read the packed pool, a window of a stack
+    of three, in single blocks and in runs of two, and give what the
+    reference gives on the pool laid as heads, which the KERNEL REFUSES
+    (one layout: it would have to copy the stack into rows); the kernel's
+    page operand is the packed pool viewed ``[NB, bs*4, 128]``, with no
+    slice or copy of a window before it."""
+    from ray_tpu.ops.paged_attention import (
+        pack_rows, packed_row, paged_decode_attention, unpack_rows)
+    rng = np.random.default_rng(11)
+    B, H, Hkv, D, bs, maxb = 3, 32, 8, 64, 8, 8
+    NB = B * maxb + 2                       # + a scratch run
+    assert packed_row(Hkv, D) == (4, 128)
+    q = jnp.asarray(rng.normal(size=(B, H, D)), jnp.float32)
+    heads = [jnp.asarray(rng.normal(size=(3 * NB, bs, Hkv, D)), jnp.float32)
+             for _ in range(2)]
+    packed = [pack_rows(p) for p in heads]
+    assert packed[0].shape == (3 * NB, bs, 4, 128)
+    np.testing.assert_array_equal(unpack_rows(packed[0], D), heads[0])
+    np.testing.assert_array_equal(packed[0][5, 3, 1, 64:], heads[0][5, 3, 3])
+    runs = rng.permutation(B * maxb // 2).reshape(B, maxb // 2)
+    tables = jnp.asarray((2 * runs[:, :, None] + np.arange(2)).reshape(
+        B, maxb), jnp.int32)
+    lengths = jnp.asarray([1, 29, 64], jnp.int32)
+    window = dict(first_block=jnp.int32(NB), num_blocks=NB, run=run)
+    want = paged_decode_attention(q, *heads, tables, lengths, impl="xla",
+                                  **window)
+    assert want.shape == (B, H, D)
+    for impl in ("xla", "pallas"):
+        got = paged_decode_attention(q, *packed, tables, lengths, impl=impl,
+                                     **window)
+        np.testing.assert_allclose(got, want, atol=2e-5, rtol=2e-5)
+    with pytest.raises(ValueError, match=r"rows of \(4, 128\)"):
+        paged_decode_attention(q, *heads, tables, lengths, impl="pallas",
+                               **window)
+    other = paged_decode_attention(q, *packed, tables, lengths, impl="pallas",
+                                   first_block=0, num_blocks=NB, run=run)
+    assert float(jnp.max(jnp.abs(other - want))) > 0.1
+
+    jaxpr = jax.make_jaxpr(functools.partial(
+        paged_decode_attention, impl="pallas", num_blocks=NB, run=run))(
+            q, *packed, tables, lengths, first_block=jnp.int32(NB))
+    found = _primitives(jaxpr.jaxpr, {})
+    assert "dynamic_slice" not in found and "copy" not in found
+    inner = jaxpr.jaxpr.eqns[-1].params["jaxpr"]
+    call, = [e for e in _flat(inner) if e.primitive.name == "pallas_call"]
+    pool_ops = [v.aval.shape for v in call.invars if v.aval.ndim == 3
+                and v.aval.shape[-1] == 128 and v.aval.shape[0] > B]
+    assert pool_ops == [(3 * NB // run, run * bs * 4, 128)] * 2
+
+
+def _flat(jaxpr):
+    for eqn in jaxpr.eqns:
+        yield eqn
+        for value in eqn.params.values():
+            inner = getattr(value, "jaxpr", value)
+            if hasattr(inner, "eqns"):
+                yield from _flat(inner)
+
+
+def test_a_model_that_packs_one_keeps_its_pool_and_its_kernel_call():
+    """Heads of 128 lanes (every serve cell of the benchmark before LFM2):
+    the rows are heads, the pool ``[L, NB, bs, Hkv, D]``, and the decode
+    program hands the kernel that stack viewed ``[L*NB, bs*Hkv, D]`` under
+    the table it was given: what they were. Heads of 64 (llama3_1b's)
+    pack two to a row in the same program; heads that fill no row (the
+    debug widths' 16 x 2) and a model under a mesh stay heads."""
+    from ray_tpu.models.llama import LlamaConfig, LlamaModel
+
+    def decode_operands(**widths):
+        cfg = LlamaConfig(vocab_size=64, dim=256, n_layers=2, ffn_dim=64,
+                          max_seq_len=64, remat=False,
+                          decode_attention="pallas", **widths)
+        model = LlamaModel(cfg)
+        pool = jax.eval_shape(lambda: model.init_kv_pool(9, 8))
+        params = jax.eval_shape(model.init, jax.random.key(0))
+        S = jax.ShapeDtypeStruct
+        jaxpr = jax.make_jaxpr(model.decode_step_paged)(
+            params, S((2,), jnp.int32), pool, S((2, 4), jnp.int32),
+            S((2,), jnp.int32))
+        call, = [e for e in _flat(jaxpr.jaxpr)
+                 if e.primitive.name == "pallas_call"]
+        return model, pool, [v.aval.shape for v in call.invars]
+
+    model, pool, operands = decode_operands(n_heads=2, n_kv_heads=2,
+                                            head_dim=128)
+    assert model.kv_lane_pack == 1
+    assert model.kv_row_shapes() == ((2, 128), (2, 128))
+    assert pool["k"].shape == (2, 9, 8, 2, 128)
+    # lengths, tables, q, bias, K pages, V pages
+    assert operands == [(2,), (2, 4), (2, 2, 128), (2, 64),
+                        (18, 16, 128), (18, 16, 128)]
+
+    model, pool, operands = decode_operands(n_heads=4, n_kv_heads=2,
+                                            head_dim=64)
+    assert model.kv_lane_pack == 2
+    assert model.kv_row_shapes() == ((1, 128), (1, 128))
+    assert pool["k"].shape == (2, 9, 8, 1, 128)
+    assert operands[-2:] == [(18, 8, 128), (18, 8, 128)]
+
+    assert LlamaModel(LlamaConfig.debug()).kv_lane_pack == 1
+    assert LlamaModel(LlamaConfig.debug()).kv_row_shapes() == (
+        (2, 16), (2, 16))
+
+
 def _pool_in_runs(run, lengths, *, bs=8, Hkv=1, D=128, maxb=32, windows=1,
                   dtype=jnp.float32, seed=3):
     """Kernel inputs as an engine of ``BlockPool(run=...)`` lays them:
@@ -574,8 +686,9 @@ def _pool_in_runs(run, lengths, *, bs=8, Hkv=1, D=128, maxb=32, windows=1,
     for b, n in enumerate(lengths):
         tables[b, -(-n // (bs * run)) * run:] = B * maxb
     q = jnp.asarray(rng.normal(size=(B, 4 * Hkv, D)), dtype)
-    kp, vp = (jnp.asarray(rng.normal(size=(windows * NB, bs, Hkv, D)),
-                          dtype) for _ in range(2))
+    kp, vp = (pack_rows(jnp.asarray(
+        rng.normal(size=(windows * NB, bs, Hkv, D)), dtype))
+        for _ in range(2))
     return (q, kp, vp, jnp.asarray(tables, jnp.int32),
             jnp.asarray(lengths, jnp.int32)), NB
 
@@ -670,7 +783,8 @@ def test_kernel_at_run_1_is_the_parents_and_at_8_starts_a_copy_a_run():
                 _primitives(jaxpr.jaxpr, {}))
 
     parents, counts = program(lambda *a: paged_decode_attention_pallas(
-        *a, None, scale=None, interpret=True, stats=False, **window))
+        *a, None, scale=None, interpret=True, stats=False,
+        first_block=window["first_block"]))
     assert sum(counts.values()) == 2201
     assert (counts["dma_start"], counts["dma_wait"]) == (3 * 64 * 2, 64 * 2)
     for said in ({}, {"run": 1}):
@@ -702,11 +816,12 @@ def test_kernel_lane_packing_rule(head_dim, kv_heads, pack, lowers):
 def test_chunk_is_sized_by_rows_not_pages():
     """The kernel's VMEM follows CHUNK_ROWS whatever the block_size: a
     chunk holds as many whole pages as fit, at least one."""
-    from ray_tpu.ops.paged_attention import (CHUNK_ROWS,
+    from ray_tpu.ops.paged_attention import (CHUNK_ROWS, packed_row,
                                              paged_decode_attention_pallas)
 
     def chunk_rows(bs, Hkv, D, maxb):
-        pool = jax.ShapeDtypeStruct((9, bs, Hkv, D), jnp.float32)
+        pool = jax.ShapeDtypeStruct((9, bs, *packed_row(Hkv, D)),
+                                    jnp.float32)
         jaxpr = jax.make_jaxpr(functools.partial(
             paged_decode_attention_pallas, interpret=True))(
             jax.ShapeDtypeStruct((2, Hkv, D), jnp.float32), pool, pool,
@@ -914,6 +1029,11 @@ def _deep_model(kind, impl, layers=3, dtype=jnp.float32):
            # and the stack's pages where they lie (no packed copy)
            "dense_d128": LlamaConfig(
                vocab_size=512, dim=256, n_heads=2, n_kv_heads=1,
+               ffn_dim=256, max_seq_len=128, remat=False),
+           # heads of 64 lanes: two to a row of the pool, which lies
+           # [L, NB, bs, 1, 128] (llama3_1b's and LFM2's layout)
+           "dense_d64": LlamaConfig(
+               vocab_size=512, dim=256, n_heads=4, n_kv_heads=2,
                ffn_dim=256, max_seq_len=128, remat=False)}[kind]
     model = model_for(dataclasses.replace(
         cfg, n_layers=layers, dtype=dtype, decode_attention=impl))
@@ -986,7 +1106,8 @@ def paged_against_dense(model, params, prompt=13, steps=6, bs=8, seed=0):
 
 
 @pytest.mark.parametrize("impl", ["xla", "pallas"])
-@pytest.mark.parametrize("kind", ["dense", "olmoe", "dense_d128"])
+@pytest.mark.parametrize("kind", ["dense", "olmoe", "dense_d128",
+                                  "dense_d64"])
 def test_paged_decode_keeps_each_layers_blocks_apart(kind, impl):
     """Six steps (across a block boundary) of ``decode_step_paged``
     against ``forward_step``'s dense cache on the same tokens, in
@@ -996,8 +1117,9 @@ def test_paged_decode_keeps_each_layers_blocks_apart(kind, impl):
     from ray_tpu.ops.paged_attention import _lane_pack
     model, params = _deep_model(kind, impl)
     assert model.cfg.n_layers == 3 and model.paged_decode_impl() == impl
-    assert (_lane_pack(model.cfg.head_dim, model.cfg.n_kv_heads) == 1) == (
-        kind == "dense_d128")
+    # (the debug widths' heads of 16 fill no row: they pack 1 too)
+    assert (_lane_pack(model.cfg.head_dim, model.cfg.n_kv_heads) == 2) == (
+        kind == "dense_d64") == (model.kv_lane_pack == 2)
     worst, per_layer = paged_against_dense(model, params)
     assert worst < 1e-4, worst
     assert max(per_layer) < 1e-5, per_layer
